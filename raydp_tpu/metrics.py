@@ -260,6 +260,9 @@ _ALL_METRICS = [
        "Feed-pipeline phase walls (decode / stage / h2d), one observation "
        "per timed section — the registry twin of PipelineTimings.",
        label="phase"),
+    _m("feed_staged_tables_total", COUNTER, "1", "feed",
+       "Multi-column Arrow tables decoded to host arrays, by the path that "
+       "decoded them: the native staging kernel or numpy.", label="path"),
     _m("train_epoch_seconds", HISTOGRAM, "s", "training",
        "Wall-clock of one training epoch (both estimators)."),
     _m("train_param_bytes_per_process", GAUGE, "bytes", "training",

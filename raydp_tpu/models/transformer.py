@@ -9,11 +9,13 @@ dispatches by configuration:
   :func:`raydp_tpu.ops.ring_attention.ring_attention_sharded`: K/V blocks
   rotate around the mesh's ``seq`` axis with ``ppermute`` (ICI neighbor links),
   memory O(T / seq_devices) per device;
-- ``attention="flash"`` — single-device memory-efficient attention via the
-  first-party Pallas kernel (:mod:`raydp_tpu.ops.flash_attention`);
+- ``attention="flash"`` — memory-efficient attention via the first-party
+  Pallas kernel (:mod:`raydp_tpu.ops.flash_attention`), mapped over the
+  mesh's batch and head axes when a mesh is given; on a TPU backend a shape
+  the kernel cannot take raises (off the chip the op runs its jnp path);
 - ``attention="dense"`` — reference path for tests;
 - ``attention="auto"`` — ring when the mesh has a ``seq`` axis > 1, else flash
-  on TPU, else dense.
+  on TPU for shapes the kernel takes, else dense.
 
 Architecture: pre-RMSNorm blocks, rotary position embeddings, SwiGLU MLP —
 all plain dense ops XLA tiles onto the MXU; bf16-friendly throughout
@@ -60,18 +62,21 @@ class Attention(nn.Module):
     mesh: Any = None
     dtype: Any = jnp.float32
 
-    def _dispatch(self) -> str:
+    def _dispatch(self, t: int, head_dim: int) -> str:
+        from raydp_tpu.ops.flash_attention import kernel_ineligible
         from raydp_tpu.parallel.mesh import seq_extent
 
         if self.attention != "auto":
             return self.attention
         if self.mesh is not None and seq_extent(self.mesh) > 1:
             return "ring"
-        return "flash" if jax.default_backend() == "tpu" else "dense"
+        on_kernel = (jax.default_backend() == "tpu"
+                     and kernel_ineligible(t, head_dim) is None)
+        return "flash" if on_kernel else "dense"
 
     @nn.compact
     def __call__(self, x):
-        from raydp_tpu.ops.flash_attention import flash_attention
+        from raydp_tpu.ops.flash_attention import flash_attention_sharded
         from raydp_tpu.ops.ring_attention import (
             dense_attention, ring_attention_sharded)
 
@@ -86,11 +91,11 @@ class Attention(nn.Module):
         q = rotary_embedding(q, positions)
         k = rotary_embedding(k, positions)
 
-        kind = self._dispatch()
+        kind = self._dispatch(t, head_dim)
         if kind == "ring":
             out = ring_attention_sharded(q, k, v, self.mesh, causal=True)
         elif kind == "flash":
-            out = flash_attention(q, k, v, causal=True)
+            out = flash_attention_sharded(q, k, v, self.mesh, causal=True)
         else:
             out = dense_attention(q, k, v, causal=True)
         return nn.DenseGeneral(dim, axis=(-2, -1), name="o", dtype=self.dtype,

@@ -1,56 +1,31 @@
 """ctypes binding + on-demand build of the C++ shared-memory arena.
 
-The C core (``csrc/store/arena.cpp``) is compiled once per machine into
-``raydp_tpu/native/_lib/librdtstore.so`` the first time a session needs it
-(guarded by a file lock so concurrently-spawning actor processes don't race the
-compiler). Readers of arena-resident objects do not need this library at all —
-they attach the segment with :mod:`multiprocessing.shared_memory` and slice a
-zero-copy memoryview; only writers (``rdt_alloc``) and the head's free path
+The C core (``csrc/store/arena.cpp``) is compiled into
+``raydp_tpu/native/_lib/`` the first time a session needs it, under a name
+that carries the hash of its source (:mod:`raydp_tpu.native.build`). Readers
+of arena-resident objects do not need this library at all — they attach the
+segment with :mod:`multiprocessing.shared_memory` and slice a zero-copy
+memoryview; only writers (``rdt_alloc``) and the head's free path
 (``rdt_free``) go through the native calls.
 """
 
 from __future__ import annotations
 
 import ctypes
-import fcntl
 import os
-import subprocess
 import threading
 from typing import Dict, Optional, Tuple
 
 from raydp_tpu.log import get_logger
+from raydp_tpu.native.build import CSRC_DIR, build_library
 
 logger = get_logger("native.arena")
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "csrc", "store", "arena.cpp")
-_LIB_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_lib")
-_LIB = os.path.join(_LIB_DIR, "librdtstore.so")
+_SRC = os.path.join(CSRC_DIR, "store", "arena.cpp")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
-
-
-def _build() -> None:
-    os.makedirs(_LIB_DIR, exist_ok=True)
-    lock_path = os.path.join(_LIB_DIR, ".build.lock")
-    with open(lock_path, "w") as lock_file:
-        fcntl.flock(lock_file, fcntl.LOCK_EX)
-        try:
-            if os.path.exists(_LIB) and (
-                    not os.path.exists(_SRC)  # prebuilt lib shipped sans csrc/
-                    or os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-                return
-            tmp = _LIB + ".tmp"
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 "-o", tmp, _SRC, "-lpthread", "-lrt"],
-                check=True, capture_output=True, text=True)
-            os.replace(tmp, _LIB)
-            logger.info("built native store core -> %s", _LIB)
-        finally:
-            fcntl.flock(lock_file, fcntl.LOCK_UN)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -59,8 +34,8 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            _build()
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(
+                build_library(_SRC, "rdtstore", ["-lpthread", "-lrt"]))
             lib.rdt_arena_create.restype = ctypes.c_void_p
             lib.rdt_arena_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
             lib.rdt_arena_attach.restype = ctypes.c_void_p

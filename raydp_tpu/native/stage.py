@@ -2,11 +2,12 @@
 
 ``csrc/feed/stage.cpp`` fuses the Arrow-column -> [rows, features] cast and
 interleave into one pass per column (the numpy path pays astype + np.stack =
-two passes and an intermediate per column). The streaming feed's
-``_as_numpy`` calls :func:`stage_table` and silently falls back to numpy
-whenever a column is ineligible (nulls, non-primitive, unsupported dtype) or
-the toolchain is absent — behavior is identical either way, pinned by
-tests/test_native_stage.py parity tests.
+two passes and an intermediate per column). The feed's ``_as_numpy`` calls
+:func:`stage_table` and decodes with numpy whenever a column is ineligible
+(nulls, non-primitive, unsupported dtype) or the library cannot be built
+(one warning) — output is identical either way, pinned by
+tests/test_native_stage.py parity tests, and which path decoded each table
+is counted in ``feed_staged_tables_total{path}``.
 
 Float→int dtype pairs are DECLINED (here and in the kernel's own dispatch):
 ``static_cast`` from a float to an integer is undefined behavior in C++ for
@@ -23,9 +24,7 @@ device compute via the DeviceFeed prefetch thread).
 from __future__ import annotations
 
 import ctypes
-import fcntl
 import os
-import subprocess
 import threading
 from typing import List, Optional, Sequence
 
@@ -34,13 +33,11 @@ import pyarrow as pa
 
 from raydp_tpu import knobs
 from raydp_tpu.log import get_logger
+from raydp_tpu.native.build import CSRC_DIR, build_library
 
 logger = get_logger("native.stage")
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "csrc", "feed", "stage.cpp")
-_LIB_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_lib")
-_LIB = os.path.join(_LIB_DIR, "librdtstage.so")
+_SRC = os.path.join(CSRC_DIR, "feed", "stage.cpp")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -68,35 +65,13 @@ _ARROW_NUMERIC = {
 }
 
 
-def _build() -> None:
-    os.makedirs(_LIB_DIR, exist_ok=True)
-    lock_path = os.path.join(_LIB_DIR, ".build.lock")
-    with open(lock_path, "w") as lock_file:
-        fcntl.flock(lock_file, fcntl.LOCK_EX)
-        try:
-            if os.path.exists(_LIB) and (
-                    not os.path.exists(_SRC)  # prebuilt lib sans csrc/
-                    or os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-                return
-            tmp = _LIB + ".tmp"
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 "-o", tmp, _SRC, "-lpthread"],
-                check=True, capture_output=True, text=True)
-            os.replace(tmp, _LIB)
-            logger.info("built native staging kernel -> %s", _LIB)
-        finally:
-            fcntl.flock(lock_file, fcntl.LOCK_UN)
-
-
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _lib_failed
     with _lib_lock:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            _build()
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(build_library(_SRC, "rdtstage", ["-lpthread"]))
             lib.rdt_stage_cast.restype = ctypes.c_int
             lib.rdt_stage_cast.argtypes = [
                 ctypes.c_void_p, ctypes.c_int, ctypes.c_int64,

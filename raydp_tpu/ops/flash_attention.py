@@ -12,12 +12,15 @@ blocks, dq walking k blocks, both with the causal block skip); elsewhere a
 blockwise ``lax.scan`` computes the same math — memory stays O(T·blk) in both
 directions.
 
-Dispatch: on TPU (and block-aligned shapes) the Pallas kernel runs; elsewhere a
-fused jnp path computes the same math (tests compare both, and run the kernel
-in interpret mode). The TPU build adds this op beyond reference parity — the
-reference has no attention anywhere (SURVEY.md §2.4). It is the single-device
-attention of :class:`raydp_tpu.models.transformer.TransformerLM`; the
-sequence-sharded path uses :mod:`raydp_tpu.ops.ring_attention` instead.
+Dispatch: on a TPU backend the Pallas kernel runs, and a shape it cannot take
+is an error that says why — never a quiet switch to another path. Off the chip
+a fused jnp path computes the same math (it materializes the [T, T] scores, so
+it is the CPU implementation for tests, which also run the kernel in interpret
+mode). The TPU build adds this op beyond reference parity — the reference has
+no attention anywhere (SURVEY.md §2.4). It is the per-device attention of
+:class:`raydp_tpu.models.transformer.TransformerLM`
+(:func:`flash_attention_sharded` maps it over a mesh's batch and head axes);
+the sequence-sharded path uses :mod:`raydp_tpu.ops.ring_attention` instead.
 """
 
 from __future__ import annotations
@@ -29,11 +32,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# swept on TPU v5e at T=8192, H=8, D=64 (benchmarks/flash_block_sweep.py,
-# 2026-07-30): fwd 9.1ms @128x128 -> 1.23ms @1024x1024 (55.9 TFLOP/s);
-# fwd+bwd with the Pallas backward kernels 2.41ms @512x1024 vs 2.44ms
-# @1024x1024 (~100 TFLOP/s, within 1.5%) — the fwd winner decides.
-# 2048-wide blocks gain nothing (and 2048x2048 fails VMEM).
+# 1024x1024 compiles under the default scoped-VMEM limit at head_dim 64 and
+# 128 (jax 0.9.0 / libtpu 0.0.34, TPU v5 lite, 2026-09-26) and was the
+# fastest of the five shapes from 256x512 up, forward and forward+backward,
+# at T=8192; the sweep is benchmarks/flash_block_sweep.py, its record PERF.md.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 _NEG_INF = -1e30
@@ -108,6 +110,7 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
 
     bh, t, d = q3.shape
     grid = (bh, t // blk_q, t // blk_k)
+    vma = jax.typeof(q3).vma     # inside a shard_map the outputs vary as q does
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -124,8 +127,8 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
             pl.BlockSpec((1, 1, blk_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct((bh, t, d), q3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 128), jnp.float32),   # m (lane-padded)
@@ -141,7 +144,7 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
 
 
 # ---------------------------------------------------------------------------
-# Fused jnp path (non-TPU fallback; also the forward for lse on that path)
+# Fused jnp path: the implementation off the chip (materializes [T, T] scores)
 # ---------------------------------------------------------------------------
 def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool):
     s = jnp.einsum("bqd,bkd->bqk", q3.astype(jnp.float32),
@@ -268,6 +271,7 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
                     axis=-1).reshape(bh, 1, t)
     lse3 = lse.reshape(bh, 1, t)
     num_q, num_k = t // blk_q, t // blk_k
+    vma = jax.typeof(q3).vma
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
@@ -286,8 +290,8 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
             pl.BlockSpec((1, blk_k, d), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v3.dtype),
+            jax.ShapeDtypeStruct((bh, t, d), k3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, d), v3.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
@@ -313,7 +317,7 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
         out_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, qi, ki: (b, qi, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -370,20 +374,40 @@ def _fit_block(t: int, blk: int) -> int:
     return max(blk, 1)
 
 
+def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
+                      block_k: int = DEFAULT_BLOCK_K) -> Optional[str]:
+    """Why the compiled Pallas kernel cannot take a [.., T=t, .., D=d] call
+    (None when it can). Block dims equal to the full array dim satisfy TPU
+    tiling, so d needs no 128 alignment; the q/k blocks must be sublane-
+    aligned themselves — ``_fit_block`` caps them at t, which need not be a
+    multiple of 8 (t=20 → blk=20) — and the (1, 1, blk_q) LSE blocks put
+    blk_q on the lanes."""
+    blk_q, blk_k = _fit_block(t, block_q), _fit_block(t, block_k)
+    if d % 8:
+        return f"head_dim {d} is not a multiple of 8"
+    if blk_q % 8 or blk_k % 8:
+        return (f"sequence length {t} only divides into blocks "
+                f"({blk_q}, {blk_k}) that are not multiples of 8")
+    if blk_q % 128 and blk_q != t:
+        return (f"sequence length {t} only divides into q blocks of "
+                f"{blk_q}, neither a multiple of 128 nor the whole sequence")
+    return None
+
+
 def _use_pallas(t: int, d: int, blk_q: int, blk_k: int,
                 interpret: bool) -> bool:
-    aligned = t % blk_q == 0 and t % blk_k == 0
     if interpret:
-        return aligned
+        return t % blk_q == 0 and t % blk_k == 0
     if jax.default_backend() != "tpu":
         return False
-    # block dims equal to the full array dim satisfy TPU tiling, so d needs no
-    # 128 alignment; q/k blocks must be sublane-aligned themselves —
-    # ``_fit_block`` caps blocks at t, which is not necessarily a multiple of
-    # 8 (e.g. t=20 → blk=20), so check it here rather than assume
-    return (aligned and d % 8 == 0
-            and blk_q >= 8 and blk_k >= 8
-            and blk_q % 8 == 0 and blk_k % 8 == 0)
+    why = kernel_ineligible(t, d, blk_q, blk_k)
+    if why is not None:
+        raise ValueError(
+            f"flash_attention cannot run its Pallas kernel on this TPU "
+            f"backend: {why}. The jnp path would materialize the "
+            f"[B*H, {t}, {t}] scores the kernel exists to avoid, so it is "
+            f"not substituted; pad the sequence or use dense_attention.")
+    return True
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -430,3 +454,25 @@ def flash_attention(q, k, v, causal: bool = True,
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, blk_q, blk_k,
                   interpret)
     return out3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+def flash_attention_sharded(q, k, v, mesh, causal: bool = True, **kwargs):
+    """:func:`flash_attention` mapped over the mesh (None or one device: the
+    plain call): batch over the data axes, heads over ``tensor`` when present
+    — attention is independent along both, and the partitioner cannot split a
+    custom call, so without the map q/k/v would be all-gathered and every
+    chip would run the full batch. The sequence stays whole per device (a >1
+    ``seq`` axis is ring attention's job)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from raydp_tpu.parallel.mesh import data_axes
+
+    fn = functools.partial(flash_attention, causal=causal, **kwargs)
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v)
+    batch = data_axes(mesh)
+    heads = "tensor" if mesh.shape.get("tensor", 1) > 1 else None
+    spec = P(batch if len(batch) > 1 else batch[0], None, heads, None)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec)(q, k, v)

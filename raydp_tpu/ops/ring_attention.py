@@ -115,10 +115,7 @@ def ring_attention(q, k, v, axis_name: str = "seq", causal: bool = True,
     q_positions = my_index * t_local + jnp.arange(t_local)  # global q positions
 
     from raydp_tpu.parallel.mesh import vary_manual
-    try:
-        vma = tuple(jax.typeof(q).vma) or (axis_name,)
-    except Exception:
-        vma = (axis_name,)
+    vma = tuple(jax.typeof(q).vma) or (axis_name,)
     m0 = vary_manual(jnp.full((b, h, t_local), -jnp.inf, jnp.float32), vma)
     l0 = vary_manual(jnp.zeros((b, h, t_local), jnp.float32), vma)
     acc0 = vary_manual(jnp.zeros((b, t_local, h, d), jnp.float32), vma)
@@ -168,10 +165,7 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = True,
     sharding out. Ring + head sharding compose: each (seq, tensor) tile ships
     only its own heads' K/V around the ring."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     batch = tuple(a for a in batch_axes if a in mesh.axis_names
                   and mesh.shape[a] > 1)

@@ -83,25 +83,14 @@ def make_mesh(spec: Optional[Union[MeshSpec, Dict[str, int]]] = None,
 
 def vary_manual(x, axes: Sequence[str]):
     """Mark ``x`` varying over the manual mesh ``axes`` it is not already
-    varying over — the newer-jax shard_map vma compat shim (carry inits made
-    with ``zeros_like`` are invariant and must be cast before mixing with
-    varying values; ``pcast`` rejects axes already in the input's vma).
-    No-op on older jax. Shared by ring attention and the pipeline."""
+    varying over (carry inits made with ``zeros_like`` are invariant and
+    must be cast before mixing with varying values; ``pcast`` rejects axes
+    already in the input's vma). Shared by ring attention and the pipeline."""
     import jax
     from jax import lax
 
-    if not axes or not (hasattr(lax, "pcast") or hasattr(lax, "pvary")):
-        return x
-    try:
-        cur = set(jax.typeof(x).vma)
-    except Exception:
-        cur = set()
-    need = tuple(a for a in axes if a not in cur)
-    if not need:
-        return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, need, to="varying")
-    return lax.pvary(x, need)
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return lax.pcast(x, need, to="varying") if need else x
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

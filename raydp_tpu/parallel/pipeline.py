@@ -56,10 +56,7 @@ def _pipeline_local(stage_params, x_micro, *, fn, stage_axis: str,
     # they mix with — the inputs' axes plus stage (state mixes with
     # params-derived activations from tick 1 on)
     from raydp_tpu.parallel.mesh import vary_manual
-    try:
-        in_vma = tuple(jax.typeof(x_micro).vma)
-    except Exception:
-        in_vma = ()
+    in_vma = tuple(jax.typeof(x_micro).vma)
     vma = tuple(dict.fromkeys(in_vma + (stage_axis,)))
     state0 = vary_manual(jnp.zeros_like(x_micro[0]), vma)
     out0 = vary_manual(jnp.zeros_like(x_micro), vma)
@@ -106,10 +103,7 @@ def pipeline_apply(fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     pipeline), with stage-sharded gradients landing on their stage.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from raydp_tpu.parallel.mesh import data_axes
 

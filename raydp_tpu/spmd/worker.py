@@ -93,11 +93,6 @@ def main() -> None:
 
     if knobs.get(ENV_JAX_DIST):
         import jax
-        # interpreter startup may have pre-registered a hardware platform;
-        # backend init is lazy, so re-assert the requested platform before
-        # the first device touch (same dance as tests/conftest.py)
-        if os.environ.get("JAX_PLATFORMS"):
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
         coordinator = knobs.get(ENV_COORDINATOR)  # test/ops override
         if not coordinator:
             if rank == 0:

@@ -10,6 +10,7 @@ Capability parity with the reference's ``python/raydp/utils.py``: memory-size pa
 from __future__ import annotations
 
 import math
+import os
 import re
 import socket
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -127,3 +128,23 @@ def find_free_port(host: str = "127.0.0.1") -> int:
     with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
         s.bind((host, 0))
         return s.getsockname()[1]
+
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def compile_cache_dir() -> str:
+    """Directory of JAX's persistent compile cache for an entry point at the
+    root of the checkout (``chip_smoke.py``, ``bench.py``). Call it BEFORE
+    importing jax; children inherit the variable. Where the environment
+    already names a directory that one is used and nothing is set here;
+    otherwise ``<checkout>/.jax_cache`` — a fixed path, because the path is
+    part of the cache key and a directory that moves never hits."""
+    path = os.environ.get(COMPILE_CACHE_ENV)
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        os.makedirs(path, exist_ok=True)
+        os.environ[COMPILE_CACHE_ENV] = path
+    return path

@@ -6,6 +6,8 @@ astype+np.stack double pass in ``feed._as_numpy``; these tests pin the two
 paths byte-identical so the fast path can never silently change training
 inputs."""
 
+import os
+
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -139,14 +141,58 @@ def test_stage_threads_parity(monkeypatch):
 
 def test_as_numpy_uses_native_path_when_available():
     """feed._as_numpy output is identical whether or not the kernel engages
-    (the integration contract: silent fallback, same bytes)."""
+    (the integration contract: same bytes), and which path decoded each
+    table is counted — never silent."""
+    from raydp_tpu import metrics
     from raydp_tpu.data.feed import _as_numpy
+
+    def staged():
+        return dict(metrics.snapshot()["counters"].get(
+            "feed_staged_tables_total", {}))
 
     rng = np.random.RandomState(2)
     table = pa.table({"x": rng.randn(64), "y": rng.randn(64),
                       "z": rng.randint(0, 9, 64)})
+    before = staged()
     got = _as_numpy(table, ("x", "y", "z"), np.float32)
     np.testing.assert_array_equal(
         got, _numpy_path(table, ["x", "y", "z"], np.float32))
+    assert staged().get("native", 0) == before.get("native", 0) + 1
+    # a null-bearing column is ineligible: numpy decodes it, and says so
+    nulls = pa.table({"x": [1.0, None], "y": [2.0, 3.0]})
+    _as_numpy(nulls, ("x", "y"), np.float32)
+    assert staged().get("numpy", 0) == before.get("numpy", 0) + 1
     # single column keeps the 1-D contract
     assert _as_numpy(table, ("x",), np.float32).shape == (64,)
+
+
+def test_library_name_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """A library built from other source can never load: the file name
+    carries the hash of the source it was compiled from, so a stale or
+    foreign ``.so`` riding along in a copied tree (whatever its mtime) is
+    simply not the file the loader opens."""
+    from raydp_tpu.native import build
+
+    monkeypatch.setattr(build, "LIB_DIR", str(tmp_path / "_lib"))
+    src = tmp_path / "probe.cpp"
+    src.write_text('extern "C" int rdt_probe() { return 1; }\n')
+    first = build.build_library(str(src), "rdtprobe")
+    assert os.path.basename(first).startswith("librdtprobe-")
+    assert build.build_library(str(src), "rdtprobe") == first   # built once
+
+    # the source changes; the old binary stays where it was, with a NEWER
+    # mtime than the source — the mtime rule would have loaded it
+    src.write_text('extern "C" int rdt_probe() { return 2; }\n')
+    os.utime(first, (2e9, 2e9))
+    second = build.library_path(str(src), "rdtprobe")
+    assert second != first and not os.path.exists(second)
+    built = build.build_library(str(src), "rdtprobe")
+    assert built == second
+    import ctypes
+    assert ctypes.CDLL(built).rdt_probe() == 2
+    assert not os.path.exists(first)        # the stale build is swept
+
+    # no source, no library: nothing prebuilt is trusted
+    os.unlink(src)
+    with pytest.raises(FileNotFoundError):
+        build.build_library(str(src), "rdtprobe")

@@ -164,8 +164,8 @@ def test_jax_distributed_gang():
         def allreduce(ctx):
             import jax
             import jax.numpy as jnp
+            from jax import shard_map
             from jax.sharding import Mesh, PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
 
             devices = np.array(jax.devices())
             assert devices.size == ctx.world_size
@@ -237,3 +237,31 @@ def test_gang_ring_attention_across_processes():
         job.stop()
     assert len(errors) == 2
     assert all(e < 2e-5 for e in errors), errors
+
+
+def test_gang_on_a_tpu_host_is_refused_not_hung(monkeypatch):
+    """Ranks that would each open every local TPU chip cannot start: the
+    launcher says so before spawning anything, instead of letting them fail
+    (or hang) in backend init. Ranks held to the CPU are untouched."""
+    from raydp_tpu.spmd import job as job_mod
+
+    monkeypatch.setattr(job_mod, "_local_tpu_chips", lambda: 4)
+    monkeypatch.setattr(
+        job_mod.SPMDJob, "_spawn_rank",
+        lambda self, rank: pytest.fail("a refused gang spawns nothing"))
+    job = create_spmd_job("t-chips", world_size=2, jax_distributed=True,
+                          env={"JAX_PLATFORMS": "tpu,cpu"})
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        job.start()
+    assert job._placement_group_id is None and job._server is None
+
+    # the same gang held to CPU devices starts as before
+    monkeypatch.undo()
+    monkeypatch.setattr(job_mod, "_local_tpu_chips", lambda: 4)
+    job = create_spmd_job("t-chips-cpu", world_size=2, jax_distributed=True,
+                          env={"JAX_PLATFORMS": "cpu"}, timeout=120)
+    job.start()
+    try:
+        assert job.run(lambda ctx: ctx.rank, timeout=60) == [0, 1]
+    finally:
+        job.stop()

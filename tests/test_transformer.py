@@ -69,6 +69,67 @@ def test_flash_pallas_bwd_interpret_matches_dense(causal, blocks):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4)
 
 
+def test_explicit_flash_on_a_tpu_backend_raises_where_the_kernel_cannot_run(
+        monkeypatch):
+    """On the chip an explicit ``flash`` never quietly runs the jnp path
+    (which materializes the T×T scores): a shape the kernel cannot take
+    raises and says why. Off the chip the same call is the jnp path."""
+    from raydp_tpu.models.transformer import Attention
+    from raydp_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(t=20, d=32)      # 20 = the whole sequence, not 8-aligned
+    ref = dense_attention(q, k, v, causal=True)
+    got = flash_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="not multiples of 8"):
+        flash_attention(q, k, v, causal=True)
+    with pytest.raises(ValueError, match="head_dim 12"):
+        flash_attention(*_qkv(t=128, d=12), causal=True)
+    assert "neither" in fa.kernel_ineligible(2112, 128)  # 64·33: blocks of 64
+    assert fa.kernel_ineligible(8192, 128) is None
+    assert fa.kernel_ineligible(1024, 64) is None
+    # `auto` picks the kernel only for shapes it takes; explicit flash stays
+    assert Attention(num_heads=2)._dispatch(20, 32) == "dense"
+    assert Attention(num_heads=2)._dispatch(8192, 128) == "flash"
+    assert Attention(num_heads=2, attention="flash")._dispatch(20, 32) == "flash"
+
+
+def test_flash_sharded_maps_kernel_over_batch_and_heads():
+    """``flash_attention_sharded`` runs the kernel per (data, tensor) tile —
+    no gather of q/k/v ahead of it — and matches dense attention, forward
+    and backward (kernel in interpret mode on the virtual mesh)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from raydp_tpu.ops.flash_attention import flash_attention_sharded
+    from raydp_tpu.parallel import MeshSpec, make_mesh
+
+    mesh = make_mesh(MeshSpec(data=4, tensor=2))
+    q, k, v = _qkv(b=4, t=256, d=64)
+    sharding = NamedSharding(mesh, P("data", None, "tensor", None))
+    qs, ks, vs = (jax.device_put(x, sharding) for x in (q, k, v))
+
+    def sharded(q, k, v, causal=True):
+        return flash_attention_sharded(q, k, v, mesh, causal=causal,
+                                       interpret=True, block_q=64,
+                                       block_k=128)
+
+    fwd = jax.jit(sharded)
+    np.testing.assert_allclose(
+        np.asarray(fwd(qs, ks, vs)),
+        np.asarray(dense_attention(q, k, v, causal=True)), atol=2e-5)
+    assert "all-gather" not in fwd.lower(qs, ks, vs).compile().as_text()
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v, causal=True) ** 2)
+
+    g_ref = jax.grad(loss(dense_attention), argnums=(0, 1, 2))(q, k, v)
+    g_got = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(qs, ks, vs)
+    for a, b in zip(g_ref, g_got):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4)
+
+
 def _tokens(b, t, vocab, seed=0):
     rng = np.random.RandomState(seed)
     return jnp.asarray(rng.randint(0, vocab, size=(b, t)).astype(np.int32))
