@@ -1,0 +1,179 @@
+"""Reduce a JAX profiler trace (``.xplane.pb``) to the device numbers the
+benchmark reports. Read with nothing but JAX (``jax.profiler.ProfileData``).
+
+A device is a plane named ``/device:TPU:<n>``. Its line ``XLA Ops`` holds one
+event per operation the chip executed (a loop's op spans its body's, so only
+ops that hold no other op count) and ``XLA Modules`` one per program
+execution. Busy time is the union of those op intervals; the traced span of a
+chip is taken between two marks the caller gives (on the trace's own clock)
+or, without them, from the first op's start to the last op's end over all
+chips. Everything here works on intervals and names only, so the same
+reduction serves any program.
+"""
+
+from __future__ import annotations
+
+import bisect
+import glob
+import os
+import re
+from typing import Dict, List, Optional, Tuple
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+COLLECTIVE = re.compile(
+    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute"
+    r"|collective-broadcast|ragged-all-to-all)", re.IGNORECASE)
+TOP = 10
+
+
+def find_xplane(trace_dir: str) -> Optional[str]:
+    """The newest ``.xplane.pb`` under a ``jax.profiler`` trace directory."""
+    found = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return max(found, key=os.path.getmtime) if found else None
+
+
+def _events(plane, line_name: str) -> List[Tuple[float, float, str]]:
+    out = []
+    for line in plane.lines:
+        if line.name == line_name:
+            out.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                       for e in line.events)
+    out.sort()
+    return out
+
+
+def leaf_ops(ops):
+    """The ops that hold no other op. ``XLA Ops`` nests: a ``while`` or a
+    ``conditional`` spans the ops of its body, and counting it would call the
+    whole loop busy and hide the gaps between its small ops. Its own time
+    (loop control on the scalar core) then counts as idle inside the program.
+    """
+    out, stack = [], []         # stack of [op, holds another op]
+    for op in sorted(ops, key=lambda o: (o[0], -o[1])):
+        while stack and stack[-1][0][1] <= op[0]:
+            top, holds = stack.pop()
+            if not holds:
+                out.append(top)
+        if stack and op[1] <= stack[-1][0][1]:
+            stack[-1][1] = True
+        stack.append([op, False])
+    out.extend(op for op, holds in stack if not holds)
+    out.sort()
+    return out
+
+
+def union_seconds(intervals) -> float:
+    """Length of the union of ``(start_ns, end_ns, ...)`` intervals, sorted
+    by start, in seconds."""
+    total, cur_s, cur_e = 0.0, None, None
+    for s, e, *_ in intervals:
+        if cur_e is None or s > cur_e:
+            if cur_e is not None:
+                total += cur_e - cur_s
+            cur_s, cur_e = s, e
+        else:
+            cur_e = max(cur_e, e)
+    if cur_e is not None:
+        total += cur_e - cur_s
+    return total / 1e9
+
+
+def _gaps(ops, modules) -> Dict[str, float]:
+    """Idle seconds between consecutive ops, by where the gap sits: inside one
+    program execution (the device stalled on itself) or between two (the host
+    had not dispatched the next program yet)."""
+    starts = [m[0] for m in modules]
+
+    def module_at(t):       # index of the program execution that holds t
+        i = bisect.bisect_right(starts, t) - 1
+        return i if i >= 0 and t <= modules[i][1] else None
+
+    out: Dict[str, float] = {}
+    cur_e = None
+    for s, e, _ in ops:
+        if cur_e is not None and s > cur_e:
+            before, after = module_at(cur_e), module_at(s)
+            if before is not None and before == after:
+                key = f"inside {modules[before][2]}"
+            else:
+                name = "unknown" if after is None else modules[after][2]
+                key = f"between programs, before {name}"
+            out[key] = out.get(key, 0.0) + (s - cur_e) / 1e9
+        cur_e = e if cur_e is None else max(cur_e, e)
+    return out
+
+
+def _strip(name: str) -> str:
+    """``%fusion.12 = ...`` or ``fusion.12`` -> ``fusion.12``."""
+    return name.split(" = ")[0].lstrip("%").strip()
+
+
+def reduce(xplane_path: str) -> Optional[dict]:
+    """Device numbers of one trace, or None when no TPU op is in it."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(xplane_path)
+    chips = []
+    for plane in data.planes:
+        m = DEVICE_PLANE.match(plane.name)
+        if not m:
+            continue
+        ops = leaf_ops(_events(plane, OPS_LINE))
+        if ops:
+            chips.append((int(m.group(1)), ops, _events(plane, MODULES_LINE)))
+    if not chips:
+        return None
+    start = min(ops[0][0] for _, ops, _ in chips)
+    end = max(max(e for _, e, _ in ops) for _, ops, _ in chips)
+    window_s = (end - start) / 1e9
+    per_chip, op_time, gap_time = [], {}, {}
+    for chip, ops, modules in sorted(chips):
+        busy = union_seconds(ops)
+        coll = union_seconds([o for o in ops if COLLECTIVE.search(o[2])])
+        per_chip.append({"chip": chip, "busy_s": busy, "collective_s": coll,
+                         "idle_share": 1.0 - busy / window_s,
+                         "ops": len(ops), "programs": len(modules)})
+        for s, e, name in ops:
+            key = _strip(name)
+            op_time[key] = op_time.get(key, 0.0) + (e - s) / 1e9
+        for key, sec in _gaps(ops, modules).items():
+            gap_time[key] = gap_time.get(key, 0.0) + sec
+    n = len(per_chip)
+
+    def top(d):     # seconds averaged over the chips, most first
+        return [[k, v / n] for k, v in sorted(
+            d.items(), key=lambda kv: -kv[1])[:TOP]]
+
+    return {
+        "window_s": window_s,
+        "busy_s": sum(c["busy_s"] for c in per_chip) / n,
+        "collective_s": sum(c["collective_s"] for c in per_chip) / n,
+        "per_chip": per_chip,
+        "device_ops": top(op_time),
+        "idle_gaps": top(gap_time),
+    }
+
+
+def describe(xplane_path: str, head: int = 4) -> None:
+    """Print every plane and line of a trace with its event count and first
+    event names: look at a trace by hand before trusting a reduction of it."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(xplane_path).planes:
+        print(f"plane {plane.name!r}")
+        for line in plane.lines:
+            events = list(line.events)
+            names = [e.name[:60] for e in events[:head]]
+            print(f"  line {line.name!r}: {len(events)} events, first {names}")
+
+
+if __name__ == "__main__":
+    import json
+    import sys
+
+    target = sys.argv[1]
+    target = target if target.endswith(".pb") else find_xplane(target)
+    describe(target)
+    print(json.dumps(reduce(target), indent=1))
