@@ -574,6 +574,14 @@ class PipelineTimings:
     OVERLAP the consumer's dispatch wall by design — pipeline wall-clock
     under the sum of phase walls is the overlap win, measured directly by
     ``benchmarks/host_decode_bench.py --overlap``.
+
+    What the walls are NOT: ``h2d`` times the ``device_put`` *call*, which
+    returns before the copy ends; and the estimators' ``dispatch_time_s``
+    beside these includes the time the jitted call waits for the device's
+    queue, so on a busy chip it measures the device, not the host. The real
+    thing is on the device trace's clock: the ``feed:h2d`` row of a trace
+    (``profiler.step``) against the device's transfers, and the device idle
+    time under ``train:dispatch`` (chipbench's ``idle_dispatch_share``).
     """
 
     KEYS = ("decode", "stage", "h2d")
@@ -615,7 +623,11 @@ class DevicePrefetcher:
     ``pull_key``/``work_key`` name the :class:`PipelineTimings` phases the
     ``next(src)`` pull and the ``fn`` call accumulate into (the host stage
     times its pulls as ``decode``; the device stage's placement is timed by
-    the feed so the sync path measures identically).
+    the feed so the sync path measures identically). ``pull_span`` names the
+    STEP span of that pull (the host stage's is ``feed:decode``; a stage
+    whose pull only waits on the stage before it has none). ``count_pulls``
+    marks the stage a train loop pulls from: its consumer side counts
+    ``feed_pulls_total{ready|empty}``.
     """
 
     _DONE = object()
@@ -623,12 +635,16 @@ class DevicePrefetcher:
     def __init__(self, src, fn=None, depth: int = 2, timings=None,
                  pull_key: Optional[str] = None,
                  work_key: Optional[str] = None,
-                 name: str = "devicefeed-prefetch"):
+                 name: str = "devicefeed-prefetch",
+                 pull_span: Optional[str] = None,
+                 count_pulls: bool = False):
         self._src = src
         self._fn = fn
         self._timings = timings
         self._pull_key = pull_key
         self._work_key = work_key
+        self._pull_span = pull_span
+        self._count_pulls = count_pulls
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._stop = threading.Event()
         # the prefetch thread must trace under the constructing context
@@ -646,10 +662,15 @@ class DevicePrefetcher:
     def _run_inner(self):
         try:
             src = iter(self._src)
+            pull_span = self._pull_span
             while not self._stop.is_set():
                 t0 = time.perf_counter()
                 try:
-                    item = next(src)
+                    if pull_span is None:
+                        item = next(src)
+                    else:
+                        with profiler.step(pull_span):
+                            item = next(src)
                 except StopIteration:
                     break
                 if self._timings is not None and self._pull_key:
@@ -677,12 +698,20 @@ class DevicePrefetcher:
     def _put(self, item) -> bool:
         """Blocking put that stays responsive to :meth:`close` (the timeout
         only ticks while the queue is FULL, i.e. the pipeline is ahead)."""
-        while not self._stop.is_set():
-            try:
-                self._q.put(item, timeout=0.2)
-                return True
-            except queue.Full:
-                continue
+        if self._stop.is_set():
+            return False
+        try:
+            self._q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with profiler.step("feed:put_wait"):
+            while not self._stop.is_set():
+                try:
+                    self._q.put(item, timeout=0.2)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def _close_src(self) -> None:
@@ -704,11 +733,17 @@ class DevicePrefetcher:
         self._thread.start()
         try:
             while True:
+                # the queue's state at the moment of the pull: a batch was
+                # ready, or the consumer now waits for the producer
+                empty = self._count_pulls and self._q.empty()
                 item = self._q.get()
                 if item is self._DONE:
                     return
                 if isinstance(item, BaseException):
                     raise item
+                if self._count_pulls:
+                    metrics.inc("feed_pulls_total",
+                                label="empty" if empty else "ready")
                 yield item
         finally:
             self.close()
@@ -830,14 +865,18 @@ class DeviceFeed:
     def _host_batches(self):
         """Host batches decoded ``prefetch`` ahead on a background thread;
         the pull wall (Arrow→numpy decode, native staging kernel included)
-        accumulates as the ``decode`` phase."""
+        accumulates as the ``decode`` phase. With synchronous placement this
+        is the stage the train loop pulls from."""
         return iter(DevicePrefetcher(
             self.host_iter, depth=self.prefetch, timings=self.timings,
-            pull_key="decode", name="devicefeed-host"))
+            pull_key="decode", name="devicefeed-host",
+            pull_span="feed:decode",
+            count_pulls=self.prefetch_to_device <= 0))
 
     def _timed_place(self, batch, sharding=None, **kw):
         t0 = time.perf_counter()
-        out = self._place(batch, sharding=sharding, **kw)
+        with profiler.step("feed:h2d"):
+            out = self._place(batch, sharding=sharding, **kw)
         self.timings.add("h2d", time.perf_counter() - t0)
         return out
 
@@ -852,7 +891,7 @@ class DeviceFeed:
             return
         yield from DevicePrefetcher(
             items, fn=place_fn, depth=self.prefetch_to_device,
-            name="devicefeed-device")
+            name="devicefeed-device", count_pulls=True)
 
     def __iter__(self):
         yield from self._placed(self._host_batches(), self._timed_place)

@@ -46,6 +46,14 @@ COUNTER = "counter"
 GAUGE = "gauge"
 HISTOGRAM = "histogram"
 
+#: the two span classes (``Span.kind``). A PHASE span happens a handful of
+#: times an action or a fit: ``profiler.trace``/``open_span`` record it in the
+#: process's span ring, always. A STEP span happens once or more a batch:
+#: ``profiler.step`` records it only while a ``jax.profiler`` session captures
+#: a device trace, and then into that trace - it never enters the ring.
+PHASE = "phase"
+STEP = "step"
+
 #: histograms are summary-shaped (count/sum/min/max), not bucketed: every
 #: producer is a wall-clock or size observation whose tails the driver can
 #: read off max, and bucket layouts would be one more thing to keep in sync
@@ -76,6 +84,9 @@ class Span:
     #: (f-strings); the linter only checks literal names, these rows exist
     #: so the doc table is the complete span vocabulary
     dynamic: bool = False
+    #: PHASE (ring, through ``profiler.trace``/``open_span``) or STEP (device
+    #: trace only, through ``profiler.step``)
+    kind: str = PHASE
 
 
 @dataclass(frozen=True)
@@ -263,6 +274,14 @@ _ALL_METRICS = [
     _m("feed_staged_tables_total", COUNTER, "1", "feed",
        "Multi-column Arrow tables decoded to host arrays, by the path that "
        "decoded them: the native staging kernel or numpy.", label="path"),
+    _m("feed_pulls_total", COUNTER, "1", "feed",
+       "Batches the train (or eval) loop pulled from the device feed, by "
+       "whether one was ready or the feed's queue was empty at the moment of "
+       "the pull. A high empty share means the loop waits for the feed; the "
+       "chip starves only if the loop is not running ahead of it (a loop "
+       "ramping up its run-ahead at an epoch's start also pulls from an "
+       "empty queue).",
+       label="state"),
     _m("train_epoch_seconds", HISTOGRAM, "s", "training",
        "Wall-clock of one training epoch (both estimators)."),
     _m("train_param_bytes_per_process", GAUGE, "bytes", "training",
@@ -291,8 +310,9 @@ METRICS: Dict[str, Metric] = {m.name: m for m in _ALL_METRICS}
 assert len(METRICS) == len(_ALL_METRICS), "duplicate metric declaration"
 
 
-def _s(name, subsystem, doc, dynamic=False):
-    return Span(name=name, subsystem=subsystem, doc=doc, dynamic=dynamic)
+def _s(name, subsystem, doc, dynamic=False, kind=PHASE):
+    return Span(name=name, subsystem=subsystem, doc=doc, dynamic=dynamic,
+                kind=kind)
 
 
 _ALL_SPANS = [
@@ -345,7 +365,25 @@ _ALL_SPANS = [
     _s("stream:window", "stream",
        "One windowed-aggregation merge over the epoch partials of a "
        "closing window (including any replay rounds)."),
-    # ---- training -----------------------------------------------------------
+    # ---- training: the phases of one fit -------------------------------------
+    _s("fit:run", "training",
+       "Root span of one FlaxEstimator.fit_on_frame call (estimator, epochs "
+       "and batch size ride in args); mints the trace_id every span of the "
+       "fit inherits, so the fit's start-up reads as its children."),
+    _s("fit:convert", "training",
+       "Frame to dataset conversion (estimator.py:_convert_frames); the ETL "
+       "action under it parents its etl:action / stage:run / task:* spans "
+       "here."),
+    _s("fit:shuffle", "training",
+       "The random_shuffle pass over the converted dataset before a "
+       "streaming fit (one O(dataset) pass through the object store)."),
+    _s("fit:feed", "training",
+       "Feed set-up of a fit: the residency decision and DeviceEpochCache / "
+       "DeviceFeed construction (args: route=resident|stream), and the first "
+       "host batch the state is shaped from (args: what=first_batch)."),
+    _s("fit:init", "training",
+       "Eager state initialisation: model.init, optimizer state creation "
+       "and the sharding rules, before placement."),
     _s("train:place", "training",
        "Sharded placement of the train state onto the mesh (host → device "
        "under each leaf's PartitionSpec; covers the initial FSDP/TP scatter "
@@ -358,6 +396,44 @@ _ALL_SPANS = [
        "Compilation + activation-residency analysis of the pipelined "
        "(stage-stacked shard_map GPipe) train step — the train:accum twin "
        "for stage>1 fits."),
+    _s("train:first_dispatch", "training",
+       "The fit's first call of its jitted step program (train step, chained "
+       "steps or resident epoch): trace, lower, compile or compile-cache "
+       "load — the synchronous part of the first call."),
+    _s("train:epoch", "training",
+       "One epoch of the train loop, loop top to after the callbacks (args: "
+       "epoch, steps); epoch 0 holds train:first_dispatch."),
+    _s("ckpt:save", "training",
+       "One checkpoint write (args: step, bytes); children ckpt:import, "
+       "ckpt:d2h and ckpt:write."),
+    _s("ckpt:import", "training",
+       "The lazy `import orbax.checkpoint` of the first save or restore in "
+       "a process (seconds on a cold machine, microseconds afterwards)."),
+    _s("ckpt:d2h", "training",
+       "Device to host copy of the state being saved."),
+    _s("ckpt:write", "training",
+       "Host state to disk: the orbax (or sharded npz) write, the extra.json "
+       "sidecar and retention pruning."),
+    # ---- training: step spans (device trace only) ---------------------------
+    _s("train:feed_wait", "training",
+       "The train loop in next() on the feed: it has no batch to dispatch.",
+       kind=STEP),
+    _s("train:dispatch", "training",
+       "One call of the jitted step program from the train loop (returns "
+       "once the work is enqueued; blocks while the device's queue is full).",
+       kind=STEP),
+    _s("train:epoch_end", "training",
+       "Between the last dispatch of an epoch and the end of its callbacks: "
+       "loss fetch, report assembly, eval pass, callbacks.", kind=STEP),
+    _s("feed:decode", "feed",
+       "One host batch pulled by the feed's host stage (Arrow to numpy, "
+       "native staging kernel included).", kind=STEP),
+    _s("feed:h2d", "feed",
+       "One batch placed on the device(s) by the feed (the device_put call).",
+       kind=STEP),
+    _s("feed:put_wait", "feed",
+       "A feed stage blocked on its full output queue: the stage is ahead "
+       "of its consumer.", kind=STEP),
 ]
 
 SPANS: Dict[str, Span] = {s.name: s for s in _ALL_SPANS}
@@ -366,6 +442,8 @@ assert len(SPANS) == len(_ALL_SPANS), "duplicate span declaration"
 #: exact names literal ``profiler.trace(...)`` calls may use (the linter's
 #: check set); dynamic families are prefixes of runtime-formatted names
 SPAN_NAMES = frozenset(s.name for s in _ALL_SPANS if not s.dynamic)
+#: the subset ``profiler.step`` takes (and ``trace``/``open_span`` must not)
+STEP_SPAN_NAMES = frozenset(s.name for s in _ALL_SPANS if s.kind == STEP)
 SPAN_PREFIXES = tuple(s.name for s in _ALL_SPANS if s.dynamic)
 
 
@@ -700,7 +778,7 @@ def dump(out_dir: Optional[str] = None) -> Dict[str, str]:
     """Write the merged report as ``metrics.json`` + ``metrics.prom`` into
     ``out_dir`` (default: ``<session_dir>/metrics``); returns the paths."""
     if out_dir is None:
-        out_dir = os.path.join(_session_dir(), "metrics")
+        out_dir = os.path.join(session_dir(), "metrics")
     os.makedirs(out_dir, exist_ok=True)
     report = metrics_report()
     json_path = os.path.join(out_dir, "metrics.json")
@@ -712,14 +790,17 @@ def dump(out_dir: Optional[str] = None) -> Dict[str, str]:
     return {"json": json_path, "prom": prom_path}
 
 
-def _session_dir() -> str:
+def session_dir() -> str:
+    """Where telemetry files go: the live session's directory, else
+    ``<tempdir>/raydp_tpu`` (the platform's temp dir, so ``TMPDIR`` moves it)."""
     try:
         from raydp_tpu.runtime import head as head_mod
         if head_mod.runtime_initialized():
             return head_mod.get_runtime().session_dir
     except Exception:  # noqa: BLE001 - no runtime: the default dir stands
         pass
-    return "/tmp/raydp_tpu"
+    import tempfile
+    return os.path.join(tempfile.gettempdir(), "raydp_tpu")
 
 
 # ---- flight-recorder blackbox bundles ---------------------------------------
@@ -755,7 +836,7 @@ def write_blackbox(action: str, error: Optional[BaseException] = None,
     }
     if extra:
         bundle["extra"] = extra
-    out_dir = os.path.join(_session_dir(), "blackbox")
+    out_dir = os.path.join(session_dir(), "blackbox")
     os.makedirs(out_dir, exist_ok=True)
     suffix = "" if n == 0 else f"-{n}"
     path = os.path.join(out_dir, f"blackbox-{safe}{suffix}.json")
@@ -780,11 +861,11 @@ def generate_table(tag: str) -> str:
                 f"{('`' + m.label + '`') if m.label else '—'} | "
                 f"{m.subsystem} | {m.doc} |")
     elif tag == "spans":
-        lines = ["| Span | Subsystem | Description |",
-                 "| --- | --- | --- |"]
+        lines = ["| Span | Class | Subsystem | Description |",
+                 "| --- | --- | --- | --- |"]
         for s in _ALL_SPANS:
             name = f"`{s.name}…` *(dynamic)*" if s.dynamic else f"`{s.name}`"
-            lines.append(f"| {name} | {s.subsystem} | {s.doc} |")
+            lines.append(f"| {name} | {s.kind} | {s.subsystem} | {s.doc} |")
     elif tag == "events":
         lines = ["| Event | Subsystem | Description |",
                  "| --- | --- | --- |"]

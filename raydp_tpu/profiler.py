@@ -24,9 +24,18 @@ per-process lanes:
   per-process clock offsets measured against each peer (``__rdt_clock__``
   round-trip handshake) so the merged timeline is aligned to the driver's
   clock — see doc/observability.md for the method and its limits.
-- :func:`jax_trace` — wraps ``jax.profiler.trace`` so device-level XLA
-  traces (TensorBoard format) land in the session directory next to the
-  span trace.
+- :func:`step` — the second span class: a span that happens once or more a
+  batch (the train loop's feed wait and dispatch, the feed's decode and
+  placement). It is a ``jax.profiler.TraceAnnotation`` and nothing else: one
+  atomic check while no profiler session is active, an event on the device
+  trace's own clock while one is, and never an entry in the ring. While a
+  session is active :func:`trace` mirrors its phase spans as annotations
+  too (tagged with their ``sid``), so the ``.xplane.pb`` holds every program
+  span of the traced stretch beside ``XLA Ops``, and a ring-only span is
+  placed on that clock by the offset of a mirrored one.
+- :func:`jax_trace` — the operator's way to such a trace: wraps
+  ``jax.profiler`` around a few epochs; the result opens in Perfetto or
+  TensorBoard with the ``train:*`` / ``feed:*`` rows above the device's.
 
 Span/metric/event *names* are registered in ``raydp_tpu/metrics.py`` and
 statically checked by rdtlint's ``telemetry-registry`` rule; the registry
@@ -41,6 +50,7 @@ import contextvars
 import json
 import os
 import secrets
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -97,6 +107,32 @@ def thread_names() -> Dict[int, str]:
 def set_enabled(value: bool) -> None:
     global _enabled
     _enabled = value
+
+
+# ---- the device trace's clock ------------------------------------------------
+# ETL executors import this module and never load jax, so jax is never imported
+# from here: the annotation class is taken once the process has loaded jax by
+# itself (every process that can hold a profiler session has)
+_annotation = None
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def step(name: str):
+    """A STEP span (``metrics.STEP``) around the body of a ``with``: recorded
+    only while a ``jax.profiler`` session captures a device trace, and then
+    into that trace, on the calling thread's line of the ``/host:CPU`` plane.
+    With no session it costs one object and one atomic check (well under a
+    microsecond), which is what lets it sit in per-batch code."""
+    ann = _annotation or _trace_annotation()
+    return _NO_SPAN if ann is None else ann(name)
 
 
 # ---- trace context -----------------------------------------------------------
@@ -173,8 +209,14 @@ def open_span(name: str, category: str = "app",
     if par is not None:
         span["par"] = par
     if args:
-        span["args"] = {k: str(v) for k, v in args.items()}
+        add_args(span, **args)
     return span
+
+
+def add_args(span: Dict[str, Any], **args) -> None:
+    """Attach args to a span that is still open (a value known only at its
+    end: an epoch's steps, a checkpoint's bytes)."""
+    span.setdefault("args", {}).update({k: str(v) for k, v in args.items()})
 
 
 def span_context(span: Dict[str, Any]) -> Optional[Tuple[str, str]]:
@@ -193,8 +235,7 @@ def close_span(span: Dict[str, Any], **args) -> None:
     span["_closed"] = True
     span["dur"] = max(0, time.time_ns() // 1000 - span["ts"])
     if args:
-        span.setdefault("args", {}).update(
-            {k: str(v) for k, v in args.items()})
+        add_args(span, **args)
     rec = {k: v for k, v in span.items() if k != "_closed"}
     _append(rec)
 
@@ -206,14 +247,18 @@ def trace(name: str, category: str = "app", **args):
     The span joins the active trace as a child (minting a fresh trace_id
     when there is none — every driver-initiated action's root span is such
     a mint) and becomes the parent of any span opened inside the body,
-    including across RPC boundaries."""
+    including across RPC boundaries. Yields the open span (for
+    :func:`add_args`). While a ``jax.profiler`` session is active the span
+    is mirrored into the device trace as an annotation carrying its ``sid``."""
     if not _enabled:
-        yield
+        yield {"_noop": True}
         return
     span = open_span(name, category, **args)
     token = _ctx.set(span_context(span))
+    ann = _annotation or _trace_annotation()
     try:
-        yield
+        with (_NO_SPAN if ann is None else ann(name, sid=span["sid"])):
+            yield span
     finally:
         _ctx.reset(token)
         close_span(span)
@@ -354,10 +399,8 @@ def collect_chrome_trace(path: Optional[str] = None,
 
     from raydp_tpu.runtime import head as head_mod
 
-    session_dir = "/tmp/raydp_tpu"
     if head_mod.runtime_initialized():
         rt = head_mod.get_runtime()
-        session_dir = rt.session_dir
         if include_actors:
             from raydp_tpu.runtime.actor import ActorHandle
             pid = 1
@@ -382,14 +425,10 @@ def collect_chrome_trace(path: Optional[str] = None,
                     skipped += 1
                     pid += 1
                     continue
-                if isinstance(payload, dict):  # current wire format
-                    actor_spans = payload.get("spans", [])
-                    threads = payload.get("threads", {})
-                    dropped[role] = int(payload.get("dropped", 0))
-                else:  # a peer running the pre-causal profiler
-                    actor_spans, threads = payload, {}
-                events.extend(_label_spans(actor_spans, role, pid, threads,
-                                           offset_us))
+                dropped[role] = int(payload.get("dropped", 0))
+                events.extend(_label_spans(
+                    payload.get("spans", []), role, pid,
+                    payload.get("threads", {}), offset_us))
                 offsets[role] = offset_us
                 actors += 1
                 pid += 1
@@ -418,8 +457,9 @@ def collect_chrome_trace(path: Optional[str] = None,
     events.extend(flows)
 
     if path is None:
-        os.makedirs(os.path.join(session_dir, "traces"), exist_ok=True)
-        path = os.path.join(session_dir, "traces", "trace.json")
+        traces = os.path.join(metrics.session_dir(), "traces")
+        os.makedirs(traces, exist_ok=True)
+        path = os.path.join(traces, "trace.json")
     else:
         parent = os.path.dirname(os.path.abspath(path))
         os.makedirs(parent, exist_ok=True)
@@ -446,14 +486,16 @@ def collect_chrome_trace(path: Optional[str] = None,
 
 @contextlib.contextmanager
 def jax_trace(log_dir: Optional[str] = None):
-    """Capture an XLA device trace (TensorBoard profile) around the body."""
+    """Capture a device trace (``<log_dir>/plugins/profile/<time>/
+    *.xplane.pb``, TensorBoard's profile format) around the body. Every STEP
+    span and every phase span that starts and ends inside the body is in it,
+    in the ``/host:CPU`` plane, one line per thread, on the clock of the
+    device's ``XLA Ops`` (doc/observability.md, "A device trace with the
+    program's spans"). Wrap a few epochs, not a fit: traces are large."""
     import jax
 
     if log_dir is None:
-        from raydp_tpu.runtime import head as head_mod
-        base = (head_mod.get_runtime().session_dir
-                if head_mod.runtime_initialized() else "/tmp/raydp_tpu")
-        log_dir = os.path.join(base, "traces", "jax")
+        log_dir = os.path.join(metrics.session_dir(), "traces", "jax")
     os.makedirs(log_dir, exist_ok=True)
     jax.profiler.start_trace(log_dir)
     try:

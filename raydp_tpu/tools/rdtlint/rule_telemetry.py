@@ -5,11 +5,13 @@ are fresh.
 
 Five checks:
 
-1. **Span names** — a literal first argument of ``profiler.trace(...)`` /
-   ``profiler.open_span(...)`` must be a registered span name (or fall
-   under a registered dynamic family prefix like ``task:``). F-string span
-   names are skipped — the registry documents their family via the prefix
-   rows.
+1. **Span names + classes** — a literal first argument of
+   ``profiler.trace(...)`` / ``profiler.open_span(...)`` must be a
+   registered PHASE span name (or fall under a registered dynamic family
+   prefix like ``task:``), and one of ``profiler.step(...)`` a registered
+   STEP span name: a per-batch span in the ring would flood it, a per-fit
+   span outside it would be lost. F-string span names are skipped — the
+   registry documents their family via the prefix rows.
 2. **Metric names + kinds** — ``metrics.inc`` / ``metrics.set_gauge`` /
    ``metrics.observe`` with a literal name must name a registered metric of
    the matching kind (counter / gauge / histogram).
@@ -36,7 +38,7 @@ RULE = "telemetry-registry"
 
 _METRIC_FUNCS = {"inc": "counter", "set_gauge": "gauge",
                  "observe": "histogram"}
-_SPAN_FUNCS = ("trace", "open_span")
+_SPAN_FUNCS = {"trace": "phase", "open_span": "phase", "step": "step"}
 _REGEN = "python -m raydp_tpu.metrics --write-docs"
 
 
@@ -99,6 +101,7 @@ def check(project: Project) -> List[Violation]:
     try:
         mod = _load_registry(reg_src.path)
         span_names = set(mod.SPAN_NAMES)
+        step_names = set(getattr(mod, "STEP_SPAN_NAMES", ()))
         span_prefixes = tuple(mod.SPAN_PREFIXES)
         metrics_reg = mod.METRICS
         events_reg = mod.EVENTS
@@ -141,6 +144,13 @@ def check(project: Project) -> List[Violation]:
                         message=(f"span {name!r} is not declared in the "
                                  "telemetry registry "
                                  "(raydp_tpu/metrics.py SPANS)")))
+                elif (name in step_names) != (_SPAN_FUNCS[attr] == "step"):
+                    declared = "step" if name in step_names else "phase"
+                    out.append(Violation(
+                        rule=RULE, path=src.rel, line=node.lineno,
+                        message=(f"profiler.{attr}({name!r}): declared as a "
+                                 f"{declared} span, but {attr}() records "
+                                 f"{_SPAN_FUNCS[attr]} spans")))
             # ---- metric names + kinds -----------------------------------
             elif recv in met_aliases and attr in _METRIC_FUNCS:
                 name = _literal_arg0(node)

@@ -30,6 +30,7 @@ from typing import Any, Optional, Tuple
 
 import numpy as np
 
+from raydp_tpu import profiler
 from raydp_tpu.log import get_logger
 
 logger = get_logger("train.checkpoint")
@@ -152,7 +153,11 @@ def _checkpointer():
     every barrier to the calling process.
     """
     import jax
-    import orbax.checkpoint as ocp
+    # lazy on purpose: a process that never saves never pays it. The first
+    # import of a process walks every installed distribution
+    # (google.api_core) — seconds on a cold machine, hence its own span
+    with profiler.trace("ckpt:import", "training"):
+        import orbax.checkpoint as ocp
 
     if jax.process_count() > 1:
         from orbax.checkpoint.options import MultiprocessingOptions
@@ -203,9 +208,10 @@ def _entry_array(npz, e: dict) -> np.ndarray:
 
 
 def _save_sharded(ckpt_dir: str, state: Any, step: int,
-                  extra: Optional[dict]) -> str:
+                  extra: Optional[dict]) -> Tuple[str, int]:
     """Every gang process writes its owned shards; barriers make the write a
-    gang-wide atomic step (COMPLETE marker last, chief-only)."""
+    gang-wide atomic step (COMPLETE marker last, chief-only). Returns the
+    path and the bytes this process wrote."""
     import jax
     from jax.experimental import multihost_utils
 
@@ -217,6 +223,26 @@ def _save_sharded(ckpt_dir: str, state: Any, step: int,
             shutil.rmtree(path)
         os.makedirs(path)
     multihost_utils.sync_global_devices(f"rdt_ckpt_mk_{step}")
+
+    with profiler.trace("ckpt:d2h", "training"):
+        arrays, manifest = _owned_shards(state, me)
+    with profiler.trace("ckpt:write", "training"):
+        np.savez(os.path.join(path, f"shard_{me}.npz"), **arrays)
+        with open(os.path.join(path, f"manifest_{me}.json"), "w") as f:
+            json.dump(manifest, f)
+        multihost_utils.sync_global_devices(f"rdt_ckpt_done_{step}")
+        if me == 0:
+            if extra is not None:
+                _write_extra(path, ckpt_dir, step, extra)
+            open(os.path.join(path, "COMPLETE"), "w").close()
+            _prune(ckpt_dir, step)
+    return path, sum(a.nbytes for a in arrays.values())
+
+
+def _owned_shards(state: Any, me: int):
+    """``(arrays, manifest)`` of the shards process ``me`` writes, copied to
+    the host."""
+    import jax
 
     flat, _ = _flatten_with_keys(state)
     arrays, manifest = {}, []
@@ -251,16 +277,7 @@ def _save_sharded(ckpt_dir: str, state: Any, step: int,
                              "index": [[0, s] for s in arr.shape],
                              "shape": list(arr.shape),
                              "dtype": str(arr.dtype)})
-    np.savez(os.path.join(path, f"shard_{me}.npz"), **arrays)
-    with open(os.path.join(path, f"manifest_{me}.json"), "w") as f:
-        json.dump(manifest, f)
-    multihost_utils.sync_global_devices(f"rdt_ckpt_done_{step}")
-    if me == 0:
-        if extra is not None:
-            _write_extra(path, ckpt_dir, step, extra)
-        open(os.path.join(path, "COMPLETE"), "w").close()
-        _prune(ckpt_dir, step)
-    return path
+    return arrays, manifest
 
 
 def _prune(ckpt_dir: str, written_step: int) -> None:
@@ -283,18 +300,26 @@ def save(ckpt_dir: str, state: Any, step: int,
     restarted gang's result is not truncated to post-restart epochs)."""
     import jax
 
-    if jax.process_count() > 1:
-        return _save_sharded(ckpt_dir, state, step, extra)
-
-    os.makedirs(ckpt_dir, exist_ok=True)
-    path = os.path.join(os.path.abspath(ckpt_dir), f"step_{step}")
-    if os.path.exists(path):
-        shutil.rmtree(path)
-    with _checkpointer() as ckptr:
-        ckptr.save(path, jax.device_get(state))
-    if extra is not None:
-        _write_extra(path, ckpt_dir, step, extra)
-    _prune(ckpt_dir, step)
+    with profiler.trace("ckpt:save", "training", step=step) as span:
+        if jax.process_count() > 1:
+            path, nbytes = _save_sharded(ckpt_dir, state, step, extra)
+        else:
+            os.makedirs(ckpt_dir, exist_ok=True)
+            path = os.path.join(os.path.abspath(ckpt_dir), f"step_{step}")
+            if os.path.exists(path):
+                shutil.rmtree(path)
+            ckptr = _checkpointer()
+            with profiler.trace("ckpt:d2h", "training"):
+                host_state = jax.device_get(state)
+            with profiler.trace("ckpt:write", "training"):
+                with ckptr:
+                    ckptr.save(path, host_state)
+                if extra is not None:
+                    _write_extra(path, ckpt_dir, step, extra)
+                _prune(ckpt_dir, step)
+            nbytes = sum(getattr(leaf, "nbytes", 0)
+                         for leaf in jax.tree.leaves(host_state))
+        profiler.add_args(span, bytes=nbytes)
     return path
 
 

@@ -218,6 +218,7 @@ class FrameEstimatorInterface(ABC):
         data survives (parity: torch/estimator.py:358-390, dataset.py:137-158).
         Shared by every concrete estimator's ``fit_on_frame``."""
         import raydp_tpu
+        from raydp_tpu import profiler
         from raydp_tpu.data import from_frame, from_frame_recoverable
 
         def convert(df, tag):
@@ -231,13 +232,14 @@ class FrameEstimatorInterface(ABC):
                 return from_frame(session.read.parquet(path))
             return from_frame_recoverable(df)
 
-        train_ds = convert(train_df, "train")
-        eval_ds = convert(evaluate_df, "eval")
-        if stop_etl_after_conversion:
-            train_ds.transfer_to_master()
-            if eval_ds is not None:
-                eval_ds.transfer_to_master()
-            raydp_tpu.stop(cleanup_data=False)
+        with profiler.trace("fit:convert", "training"):
+            train_ds = convert(train_df, "train")
+            eval_ds = convert(evaluate_df, "eval")
+            if stop_etl_after_conversion:
+                train_ds.transfer_to_master()
+                if eval_ds is not None:
+                    eval_ds.transfer_to_master()
+                raydp_tpu.stop(cleanup_data=False)
         return train_ds, eval_ds
 
 

@@ -29,7 +29,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from raydp_tpu import faults, knobs
+from raydp_tpu import faults, knobs, profiler
+from raydp_tpu import metrics as rdt_metrics
 from raydp_tpu.log import get_logger
 from raydp_tpu.train.estimator import (
     EstimatorInterface,
@@ -697,42 +698,49 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # device-resident fast path: dataset pinned in HBM, whole epoch in one
         # jitted dispatch with on-device shuffling (falls back to the
         # streaming feed when too large / multi-process / ragged-batch)
-        cache = feed = None
-        if DeviceEpochCache.eligible(train_ds, columns, self.batch_size,
-                                     self.drop_last):
-            cache = DeviceEpochCache(train_ds, columns, mesh=mesh)
-        if cache is None:
-            feed = DeviceFeed(train_ds, self.batch_size, columns, mesh=mesh,
-                              shuffle=self.shuffle, seed=self.seed,
-                              drop_remainder=self.drop_last,
-                              pad_remainder=pad_tail and not self.drop_last,
-                              prefetch_to_device=self.prefetch_to_device,
-                              seq=use_seq)
-        eval_feed = eval_cache = None
-        eval_tail_ok = False
-        if evaluate_ds is not None:
-            # the ragged final batch: fine as-is under a size-1 data extent
-            # (and no pipeline — a stage>1 forward cannot reshape a ragged
-            # batch), pad-and-masked under a >1 one (dropped only when
-            # padding is opted out — the pre-PR-16 behavior)
-            eval_tail_ok = (dp_total == 1 and stage_total == 1) or pad_tail
-            # eval goes resident alongside the train set: the whole eval
-            # pass becomes one scan dispatch (+ one for the ragged tail)
-            # instead of one dispatch per batch, every epoch. The budget is
-            # COMBINED: train + eval residency together stay under the cap
-            if (cache is not None
-                    and DeviceEpochCache.eligible(evaluate_ds, columns,
-                                                  1, True)
-                    and cache.nbytes + DeviceEpochCache.estimate_bytes(
-                        evaluate_ds, columns) <= DeviceEpochCache.cap_bytes()):
-                eval_cache = DeviceEpochCache(evaluate_ds, columns, mesh=mesh)
-            else:
-                eval_feed = DeviceFeed(evaluate_ds, self.batch_size, columns,
-                                       mesh=mesh, shuffle=False,
-                                       drop_remainder=not eval_tail_ok,
-                                       pad_remainder=pad_tail,
-                                       prefetch_to_device=self.prefetch_to_device,
-                                       seq=use_seq)
+        with profiler.trace("fit:feed", "training") as feed_span:
+            cache = feed = None
+            if DeviceEpochCache.eligible(train_ds, columns, self.batch_size,
+                                         self.drop_last):
+                cache = DeviceEpochCache(train_ds, columns, mesh=mesh)
+            if cache is None:
+                feed = DeviceFeed(
+                    train_ds, self.batch_size, columns, mesh=mesh,
+                    shuffle=self.shuffle, seed=self.seed,
+                    drop_remainder=self.drop_last,
+                    pad_remainder=pad_tail and not self.drop_last,
+                    prefetch_to_device=self.prefetch_to_device, seq=use_seq)
+            eval_feed = eval_cache = None
+            eval_tail_ok = False
+            if evaluate_ds is not None:
+                # the ragged final batch: fine as-is under a size-1 data
+                # extent (and no pipeline — a stage>1 forward cannot reshape
+                # a ragged batch), pad-and-masked under a >1 one (dropped
+                # only when padding is opted out — the pre-PR-16 behavior)
+                eval_tail_ok = (dp_total == 1 and stage_total == 1) \
+                    or pad_tail
+                # eval goes resident alongside the train set: the whole eval
+                # pass becomes one scan dispatch (+ one for the ragged tail)
+                # instead of one dispatch per batch, every epoch. The budget
+                # is COMBINED: train + eval residency together stay under
+                # the cap
+                if (cache is not None
+                        and DeviceEpochCache.eligible(evaluate_ds, columns,
+                                                      1, True)
+                        and cache.nbytes + DeviceEpochCache.estimate_bytes(
+                            evaluate_ds, columns)
+                        <= DeviceEpochCache.cap_bytes()):
+                    eval_cache = DeviceEpochCache(evaluate_ds, columns,
+                                                  mesh=mesh)
+                else:
+                    eval_feed = DeviceFeed(
+                        evaluate_ds, self.batch_size, columns, mesh=mesh,
+                        shuffle=False, drop_remainder=not eval_tail_ok,
+                        pad_remainder=pad_tail,
+                        prefetch_to_device=self.prefetch_to_device,
+                        seq=use_seq)
+            profiler.add_args(
+                feed_span, route="resident" if cache is not None else "stream")
 
         state, history = self._train_loop(
             mesh, feed, eval_feed, ckpt_dir, max_retries=max_retries,
@@ -769,28 +777,28 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         metrics = self._metrics
 
         # ---- init params from one host batch's shapes ----
-        first = cache.init_row if cache is not None \
-            else next(iter(feed.host_iter))
-        inputs0, _ = self._split_batch(
-            {k: jnp.asarray(v[:1]) for k, v in first.items()})
-        rng = jax.random.PRNGKey(self.seed)
-        takes_train = _takes_train(model)
-        init_kwargs = {"train": False} if takes_train else {}
-        variables = model.init(rng, inputs0, **init_kwargs)
-        batch_stats = variables.get("batch_stats")
+        with profiler.trace("fit:feed", "training", what="first_batch"):
+            first = cache.init_row if cache is not None \
+                else next(iter(feed.host_iter))
+        with profiler.trace("fit:init", "training"):
+            inputs0, _ = self._split_batch(
+                {k: jnp.asarray(v[:1]) for k, v in first.items()})
+            rng = jax.random.PRNGKey(self.seed)
+            takes_train = _takes_train(model)
+            init_kwargs = {"train": False} if takes_train else {}
+            variables = model.init(rng, inputs0, **init_kwargs)
+            batch_stats = variables.get("batch_stats")
 
-        class _State(train_state.TrainState):
-            # models with BatchNorm carry running stats beside params
-            batch_stats: Any = None
+            class _State(train_state.TrainState):
+                # models with BatchNorm carry running stats beside params
+                batch_stats: Any = None
 
-        state = _State.create(
-            apply_fn=model.apply, params=variables["params"], tx=tx,
-            batch_stats=batch_stats)
+            state = _State.create(
+                apply_fn=model.apply, params=variables["params"], tx=tx,
+                batch_stats=batch_stats)
 
-        shardings_of = param_sharding_rules(mesh, self.param_rules)
-        state_sharding = shardings_of(state)
-        from raydp_tpu import metrics as rdt_metrics
-        from raydp_tpu import profiler
+            shardings_of = param_sharding_rules(mesh, self.param_rules)
+            state_sharding = shardings_of(state)
         from raydp_tpu.parallel.roles import addressable_nbytes
         with profiler.trace("train:place", "training"):
             state = self._place_state(state, state_sharding)
@@ -880,6 +888,18 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         jit_train = jax.jit(train_step, donate_argnums=(0, 3))
         jit_eval = jax.jit(eval_step, donate_argnums=(3, 4))
 
+        step_span = profiler.step
+        first_dispatch = [True]
+
+        def _dispatch(fn, *args):
+            """Call the fit's step program; its first call (trace, lower,
+            compile or compile-cache load, all synchronous) is a span."""
+            if first_dispatch[0]:
+                first_dispatch[0] = False
+                with profiler.trace("train:first_dispatch", "training"):
+                    return fn(*args)
+            return fn(*args)
+
         chain = self.steps_per_dispatch
         jit_chain = None
         if chain > 1 and cache is None:
@@ -967,122 +987,140 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                 if extra and "history" in extra:
                     history = list(extra["history"])
                 logger.info("resuming from checkpoint step %d", done_epoch)
-        from raydp_tpu import profiler
 
         while epoch < self.num_epochs:
             try:
                 rule = faults.check("estimator.epoch", key=str(epoch))
                 if rule is not None:  # chaos tests provoke the retry path here
                     faults.apply(rule, "estimator.epoch")
-                t0 = time.perf_counter()
-                mstats = tuple(m.init() for m in metrics)
-                loss_sum = np.zeros((), np.float32)
-                steps, samples = 0, 0
-                t_feed = t_disp = 0.0
-                if cache is not None:
-                    td = time.perf_counter()
-                    ekey = jax.random.fold_in(
-                        jax.random.PRNGKey(self.seed), epoch)
-                    if not measured[0]:
-                        _note_activation(jit_epoch, (state, loss_sum, mstats),
-                                         cache.arrays, ekey)
-                    state, loss_sum, mstats = jit_epoch(
-                        (state, loss_sum, mstats), cache.arrays, ekey)
-                    # dispatch is async: fetch the loss scalar INSIDE this
-                    # window so dispatch_time_s carries the epoch's device
-                    # time (otherwise the report's sync slot absorbs it and
-                    # this path reads as "zero dispatch cost")
-                    loss_sum = np.float32(loss_sum)
-                    t_disp = time.perf_counter() - td
-                    steps = cache_steps
-                    samples = cache_steps * self.batch_size
-                else:
-                    feed.set_epoch(epoch)
-                    it = feed.chained(chain) if chain > 1 else iter(feed)
-                    while True:
-                        tf = time.perf_counter()
-                        item = next(it, None)
-                        t_feed += time.perf_counter() - tf
-                        if item is None:
-                            break
+                with profiler.trace("train:epoch", "training",
+                                    epoch=epoch) as epoch_span:
+                    t0 = time.perf_counter()
+                    mstats = tuple(m.init() for m in metrics)
+                    loss_sum = np.zeros((), np.float32)
+                    steps, samples = 0, 0
+                    t_feed = t_disp = 0.0
+                    if cache is not None:
                         td = time.perf_counter()
-                        if chain > 1:
-                            batches, k = item
+                        ekey = jax.random.fold_in(
+                            jax.random.PRNGKey(self.seed), epoch)
+                        with step_span("train:dispatch"):
                             if not measured[0]:
-                                _note_activation(jit_chain, state, batches,
-                                                 mstats, loss_sum)
-                            state, loss_sum, mstats = jit_chain(
-                                state, batches, mstats, loss_sum)
-                        else:
-                            k = 1
-                            if not measured[0]:
-                                _note_activation(jit_train, state, item,
-                                                 mstats, loss_sum)
-                            state, loss_sum, mstats = jit_train(
-                                state, item, mstats, loss_sum)
-                        t_disp += time.perf_counter() - td
-                        steps += k
-                        samples += self.batch_size * k
-                # fetch the accumulated loss BEFORE reading the clock:
-                # dispatch is async, so only a host scalar fetch makes the
-                # epoch wall include the device work
-                ts = time.perf_counter()
-                train_loss = float(loss_sum) / steps if steps else float("nan")
-                t_sync = time.perf_counter() - ts
-                dt = time.perf_counter() - t0
-                # registry twin of the epoch report (metrics_report() sees
-                # epoch walls without re-publishing the history dicts)
-                from raydp_tpu import metrics as rdt_metrics
-                rdt_metrics.observe("train_epoch_seconds", dt)
-                # the feed's thread-side phase split (decode/stage/h2d): these
-                # walls OVERLAP dispatch by design (that is the prefetch win),
-                # so they attribute the epoch, they don't sum to it
-                pipe = feed.timings.take() if feed is not None else {}
-                report = {
-                    "epoch": epoch,
-                    "train_loss": train_loss,
-                    "steps": steps,
-                    "samples_per_s": samples / dt if dt > 0 else 0.0,
-                    "epoch_time_s": dt,
-                    "feed_time_s": t_feed,
-                    "decode_time_s": pipe.get("decode", 0.0),
-                    "stage_time_s": pipe.get("stage", 0.0),
-                    "h2d_time_s": pipe.get("h2d", 0.0),
-                    "dispatch_time_s": t_disp,
-                    "sync_time_s": t_sync,
-                }
-                for m, s in zip(metrics, mstats):
-                    report[f"train_{m.name}"] = m.compute(
-                        jax.tree.map(np.asarray, s))
-
-                if eval_feed is not None or eval_cache is not None:
-                    estats = tuple(m.init() for m in metrics)
-                    esum = np.zeros((), np.float32)
-                    ecnt = np.zeros((), np.float32)
-                    if eval_cache is not None:
-                        _, estats, esum, ecnt = jit_eval_epoch(
-                            (state, estats, esum, ecnt), eval_cache.arrays,
-                            jax.random.PRNGKey(0))  # unused: shuffle=False
-                        if eval_tail is not None:
-                            esum, ecnt, estats = jit_eval(
-                                state, eval_tail, estats, esum, ecnt)
+                                _note_activation(
+                                    jit_epoch, (state, loss_sum, mstats),
+                                    cache.arrays, ekey)
+                            state, loss_sum, mstats = _dispatch(
+                                jit_epoch, (state, loss_sum, mstats),
+                                cache.arrays, ekey)
+                            # dispatch is async: fetch the loss scalar INSIDE
+                            # this window so dispatch_time_s carries the
+                            # epoch's device time (otherwise the report's sync
+                            # slot absorbs it and this path reads as "zero
+                            # dispatch cost")
+                            loss_sum = np.float32(loss_sum)
+                        t_disp = time.perf_counter() - td
+                        steps = cache_steps
+                        samples = cache_steps * self.batch_size
                     else:
-                        for batch in eval_feed:
-                            esum, ecnt, estats = jit_eval(state, batch,
-                                                          estats, esum, ecnt)
-                    rows = float(ecnt)  # real rows only: pad rows mask to 0
-                    report["eval_loss"] = (float(esum) / rows) if rows \
-                        else float("nan")
-                    for m, s in zip(metrics, estats):
-                        report[f"eval_{m.name}"] = m.compute(
-                            jax.tree.map(np.asarray, s))
+                        feed.set_epoch(epoch)
+                        it = feed.chained(chain) if chain > 1 else iter(feed)
+                        while True:
+                            tf = time.perf_counter()
+                            with step_span("train:feed_wait"):
+                                item = next(it, None)
+                            t_feed += time.perf_counter() - tf
+                            if item is None:
+                                break
+                            td = time.perf_counter()
+                            with step_span("train:dispatch"):
+                                if chain > 1:
+                                    batches, k = item
+                                    if not measured[0]:
+                                        _note_activation(
+                                            jit_chain, state, batches,
+                                            mstats, loss_sum)
+                                    state, loss_sum, mstats = _dispatch(
+                                        jit_chain, state, batches, mstats,
+                                        loss_sum)
+                                else:
+                                    k = 1
+                                    if not measured[0]:
+                                        _note_activation(
+                                            jit_train, state, item, mstats,
+                                            loss_sum)
+                                    state, loss_sum, mstats = _dispatch(
+                                        jit_train, state, item, mstats,
+                                        loss_sum)
+                            t_disp += time.perf_counter() - td
+                            steps += k
+                            samples += self.batch_size * k
+                    with step_span("train:epoch_end"):
+                        # fetch the accumulated loss BEFORE reading the
+                        # clock: dispatch is async, so only a host scalar
+                        # fetch makes the epoch wall include the device work
+                        ts = time.perf_counter()
+                        train_loss = float(loss_sum) / steps if steps \
+                            else float("nan")
+                        t_sync = time.perf_counter() - ts
+                        dt = time.perf_counter() - t0
+                        # registry twin of the epoch report (metrics_report()
+                        # sees epoch walls without re-publishing the history
+                        # dicts)
+                        rdt_metrics.observe("train_epoch_seconds", dt)
+                        # the feed's thread-side phase split (decode/stage/
+                        # h2d): these walls OVERLAP dispatch by design (that
+                        # is the prefetch win), so they attribute the epoch,
+                        # they don't sum to it
+                        pipe = feed.timings.take() if feed is not None else {}
+                        report = {
+                            "epoch": epoch,
+                            "train_loss": train_loss,
+                            "steps": steps,
+                            "samples_per_s": samples / dt if dt > 0 else 0.0,
+                            "epoch_time_s": dt,
+                            "feed_time_s": t_feed,
+                            "decode_time_s": pipe.get("decode", 0.0),
+                            "stage_time_s": pipe.get("stage", 0.0),
+                            "h2d_time_s": pipe.get("h2d", 0.0),
+                            "dispatch_time_s": t_disp,
+                            "sync_time_s": t_sync,
+                        }
+                        for m, s in zip(metrics, mstats):
+                            report[f"train_{m.name}"] = m.compute(
+                                jax.tree.map(np.asarray, s))
 
-                history.append(report)
-                for cb in self.callbacks:
-                    cb(report)
-                logger.info("epoch %d: %s", epoch,
+                        if eval_feed is not None or eval_cache is not None:
+                            estats = tuple(m.init() for m in metrics)
+                            esum = np.zeros((), np.float32)
+                            ecnt = np.zeros((), np.float32)
+                            if eval_cache is not None:
+                                _, estats, esum, ecnt = jit_eval_epoch(
+                                    (state, estats, esum, ecnt),
+                                    eval_cache.arrays,
+                                    jax.random.PRNGKey(0))  # unused: no shuffle
+                                if eval_tail is not None:
+                                    esum, ecnt, estats = jit_eval(
+                                        state, eval_tail, estats, esum, ecnt)
+                            else:
+                                for batch in eval_feed:
+                                    esum, ecnt, estats = jit_eval(
+                                        state, batch, estats, esum, ecnt)
+                            # real rows only: pad rows mask to 0
+                            rows = float(ecnt)
+                            report["eval_loss"] = (float(esum) / rows) \
+                                if rows else float("nan")
+                            for m, s in zip(metrics, estats):
+                                report[f"eval_{m.name}"] = m.compute(
+                                    jax.tree.map(np.asarray, s))
+
+                        history.append(report)
+                        for cb in self.callbacks:
+                            cb(report)
+                        logger.info(
+                            "epoch %d: %s", epoch,
                             {k: (round(v, 5) if isinstance(v, float) else v)
                              for k, v in report.items()})
+                    profiler.add_args(epoch_span, steps=steps)
                 if save_epoch_now(epoch, self.checkpoint_interval,
                                   self.num_epochs):
                     ckpt.save(ckpt_dir, state, step=epoch,
@@ -1238,7 +1276,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # the two cannot drift
         _apply, step_accum, step_remat, accum, n_stages = self._make_forward(
             model, mesh, takes_train, state.params)
-        from raydp_tpu import metrics as rdt_metrics
         rdt_metrics.set_gauge("train_accum_steps", accum)
         if isinstance(model, PipelineModel):
             rdt_metrics.set_gauge("train_pipeline_stages", n_stages)
@@ -1434,25 +1471,32 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                      stop_etl_after_conversion: bool = False,
                      max_retries: int = 0,
                      num_workers: Optional[int] = None) -> TrainingResult:
-        train_ds, eval_ds = self._convert_frames(
-            train_df, evaluate_df, fs_directory=fs_directory,
-            stop_etl_after_conversion=stop_etl_after_conversion)
+        with profiler.trace("fit:run", "training",
+                            estimator=type(self).__name__,
+                            epochs=self.num_epochs, batch=self.batch_size):
+            train_ds, eval_ds = self._convert_frames(
+                train_df, evaluate_df, fs_directory=fs_directory,
+                stop_etl_after_conversion=stop_etl_after_conversion)
 
-        gang = num_workers is not None and num_workers > 1
-        if self.shuffle:
-            # parity: random_shuffle before training (torch/estimator.py:335-338)
-            # — except on the single-process device-resident path, whose
-            # on-device per-epoch permutation IS a uniform row shuffle: the
-            # extra O(dataset) pass through the object store buys nothing
-            from raydp_tpu.data.feed import DeviceEpochCache
-            resident = not gang and DeviceEpochCache.eligible(
-                train_ds, self._columns(), self.batch_size, self.drop_last)
-            if not resident:
-                train_ds = train_ds.random_shuffle(seed=self.seed)
-        if gang:
-            return self.fit_gang(train_ds, eval_ds, num_workers=num_workers,
-                                 max_retries=max_retries)
-        return self.fit(train_ds, eval_ds, max_retries=max_retries)
+            gang = num_workers is not None and num_workers > 1
+            if self.shuffle:
+                # parity: random_shuffle before training
+                # (torch/estimator.py:335-338) — except on the single-process
+                # device-resident path, whose on-device per-epoch permutation
+                # IS a uniform row shuffle: the extra O(dataset) pass through
+                # the object store buys nothing
+                from raydp_tpu.data.feed import DeviceEpochCache
+                resident = not gang and DeviceEpochCache.eligible(
+                    train_ds, self._columns(), self.batch_size,
+                    self.drop_last)
+                if not resident:
+                    with profiler.trace("fit:shuffle", "training"):
+                        train_ds = train_ds.random_shuffle(seed=self.seed)
+            if gang:
+                return self.fit_gang(train_ds, eval_ds,
+                                     num_workers=num_workers,
+                                     max_retries=max_retries)
+            return self.fit(train_ds, eval_ds, max_retries=max_retries)
 
     # ---------------------------------------------------------------- predict
     def predict(self, ds, batch_size: Optional[int] = None) -> np.ndarray:

@@ -1192,6 +1192,40 @@ def test_telemetry_rule_flags_unregistered_names_and_kind_mismatch(tmp_path):
     assert len(msgs) == 4  # the registered/dynamic/f-string uses are clean
 
 
+def test_telemetry_rule_flags_a_span_recorded_as_the_wrong_class(tmp_path):
+    """A step span through ``trace`` would flood the ring; a phase span
+    through ``step`` would be lost outside a device trace."""
+    registry = _TELEMETRY_REGISTRY.replace(
+        '_ALL_SPANS = [Span("good:span"),',
+        '_ALL_SPANS = [Span("good:span"), Span("hot:step"),') + """
+    STEP_SPAN_NAMES = frozenset({"hot:step"})
+"""
+    report = _lint(tmp_path, {
+        "pkg/metrics.py": registry,
+        "pkg/user.py": """
+            from raydp_tpu import metrics, profiler
+
+
+            def f():
+                with profiler.trace("good:span"), profiler.step("hot:step"):
+                    metrics.inc("good_total")
+                    metrics.set_gauge("depth_now", 2)
+                    metrics.observe("lat_seconds", 1.0)
+                    metrics.record_event("good_event")
+                with profiler.step("good:span"):
+                    pass
+                with profiler.trace("hot:step"):
+                    pass
+                profiler.open_span("hot:step")
+        """,
+    }, rules=["telemetry-registry"])
+    msgs = _msgs(report, "telemetry-registry")
+    assert len(msgs) == 3
+    assert sum("declared as a step span" in m for m in msgs) == 2
+    assert any("profiler.step('good:span')" in m
+               and "declared as a phase span" in m for m in msgs)
+
+
 def test_telemetry_rule_flags_dead_registry_entries(tmp_path):
     report = _lint(tmp_path, {
         "pkg/metrics.py": _TELEMETRY_REGISTRY,
