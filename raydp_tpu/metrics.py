@@ -291,6 +291,13 @@ _ALL_METRICS = [
     _m("train_padded_rows_total", COUNTER, "rows", "training",
        "Zero rows appended by pad-and-mask feeds to square a ragged final "
        "batch; each padded row is masked out of losses and metrics."),
+    _m("train_table_updates_total", COUNTER, "1", "training",
+       "Embedding tables a model declared, counted once a built train step "
+       "(one a table a fit), by how the step updates them: row-wise (only "
+       "the rows a batch looked up are differentiated, updated and written) "
+       "or dense (the whole table; the fit's log names why: probe, shape, "
+       "accum, pipeline). doc/training.md, the row-wise update.",
+       label="path"),
     _m("train_accum_steps", GAUGE, "1", "training",
        "Gradient-accumulation microbatches per optimizer step this fit is "
        "running with (1 = unaccumulated; the RDT_TRAIN_ACCUM_STEPS / "

@@ -12,6 +12,21 @@ row-wise over the mesh's ``expert`` axis via
 gathers with the appropriate collectives, which is the reference's
 "sparse embeddings want a model axis even for DP" hard part (SURVEY.md §7
 step 5) solved by sharding annotation instead of a parameter server.
+
+**The lookups are declared**, so the train step can update a table by the rows
+a batch looked up instead of sweeping all of it (doc/training.md, "Embedding
+tables: the row-wise update"). The protocol is two things on the module:
+
+- ``lookups(inputs) -> {parameter path: ids [B]}``: which parameter (a tuple
+  of keys into ``params``) each looked-up id tensor indexes along dim 0;
+- ``__call__(inputs, rows=None)``: ``rows`` maps some of those paths to the
+  float32 rows ``take(table, ids)`` already gathered by the caller; the
+  forward then uses them (cast to the compute dtype) and never touches that
+  table. Paths it is not given are looked up in the module's own parameters.
+
+Both forms gather float32 rows first and cast the gathered rows: ``astype`` is
+element-wise, so the values are those of casting the table and gathering, and
+no table-sized cast is left in the step.
 """
 
 from __future__ import annotations
@@ -45,6 +60,16 @@ class DotInteraction(nn.Module):
         return jnp.concatenate([bottom_out, flat, pad], axis=1)
 
 
+class _Table(nn.Embed):
+    """``nn.Embed`` (same parameter: ``<name>/embedding``) that gathers first
+    and casts the gathered rows, where ``nn.Embed`` casts the whole table to
+    ``dtype`` on every call."""
+
+    def __call__(self, ids):
+        return jnp.take(self.embedding, ids, axis=0).astype(
+            self.dtype or self.embedding.dtype)
+
+
 class DLRM(nn.Module):
     categorical_sizes: Sequence[int]
     num_dense: int = 13
@@ -53,8 +78,15 @@ class DLRM(nn.Module):
     top_mlp: Sequence[int] = (1024, 1024, 512, 256, 1)
     dtype: Optional[jnp.dtype] = None
 
+    @nn.nowrap
+    def lookups(self, inputs: Dict[str, jnp.ndarray]):
+        """The lookup declaration: parameter path -> the ids ``[B]`` that
+        index it (one categorical column a table)."""
+        return {(f"embedding_{i}", "embedding"): inputs["sparse"][:, i]
+                for i in range(len(self.categorical_sizes))}
+
     @nn.compact
-    def __call__(self, inputs: Dict[str, jnp.ndarray]):
+    def __call__(self, inputs: Dict[str, jnp.ndarray], rows=None):
         dense = inputs["dense"]          # [B, num_dense] float
         sparse = inputs["sparse"]        # [B, num_tables] int
         dtype = self.dtype or dense.dtype
@@ -65,8 +97,12 @@ class DLRM(nn.Module):
 
         embs = []
         for i, vocab in enumerate(self.categorical_sizes):
-            table = nn.Embed(vocab, self.embedding_dim, dtype=dtype,
-                             name=f"embedding_{i}")
+            given = (rows or {}).get((f"embedding_{i}", "embedding"))
+            if given is not None:
+                embs.append(given.astype(dtype))
+                continue
+            table = _Table(vocab, self.embedding_dim, dtype=dtype,
+                           name=f"embedding_{i}")
             embs.append(table(sparse[:, i]))
         vectors = jnp.stack([bottom_out] + embs, axis=1)  # [B, 1+T, D]
 

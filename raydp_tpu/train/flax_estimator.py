@@ -170,15 +170,19 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     one source for the split/cast/mutable-batch-stats/squeeze policy, so the
     online twin cannot drift from the epoch loop.
 
-    Returns ``apply_fn(params, bstats, batch, train) ->
-    (preds_f32, labels, new_bstats)``."""
+    Returns ``apply_fn(params, bstats, batch, train, rows=None) ->
+    (preds_f32, labels, new_bstats)``. Where the model declares its lookups
+    (``raydp_tpu/train/rowwise.py``), ``apply_fn.lookups(batch)`` gives them
+    and ``rows`` hands the forward the rows the step already gathered."""
     import jax.numpy as jnp
 
-    def apply_fn(params, bstats, batch, train: bool):
+    def apply_fn(params, bstats, batch, train: bool, rows=None):
         inputs, labels = split_batch(batch)
         inputs = _cast_floating(inputs, compute_dtype)
         variables = {"params": params}
         kwargs = {"train": train} if takes_train else {}
+        if rows is not None:
+            kwargs["rows"] = rows
         if bstats is not None:
             variables["batch_stats"] = bstats
             if train:
@@ -195,6 +199,8 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
             preds = preds.squeeze(-1)
         return preds.astype(jnp.float32), labels, new_bstats
 
+    if callable(getattr(model, "lookups", None)):
+        apply_fn.lookups = lambda batch: model.lookups(split_batch(batch)[0])
     return apply_fn
 
 
@@ -344,6 +350,12 @@ def _make_pipeline_apply(model: "PipelineModel", split_batch, compute_dtype,
             preds = preds.squeeze(-1)
         return preds.astype(jnp.float32), labels, None
 
+    if callable(getattr(embed_mod, "lookups", None)):
+        # declared, and kept dense: the step counts it and says why
+        apply_fn.lookups = lambda batch: {
+            ("embed",) + tuple(path): ids for path, ids in
+            embed_mod.lookups(split_batch(batch)[0]).items()}
+        apply_fn.rowwise_dense_because = "pipeline"
     return apply_fn
 
 
@@ -378,9 +390,18 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
 
     from raydp_tpu.parallel.roles import apply_remat
 
-    def _microbatch_grads(params, bstats, batch, mask):
+    from raydp_tpu.train import rowwise
+
+    counted: list = []      # the table counter is bumped once a built step
+
+    def _microbatch_grads(params, bstats, batch, mask, inv=None):
         def _loss(p):
-            preds, labels, new_bstats = apply_fn(p, bstats, batch, train=True)
+            # with ``inv``, p is a row view: a row-wise table's leaf holds
+            # its uniq rows, and uniq[inv] are the rows the batch looked up
+            given = {"rows": {path: rowwise.leaf_at(p, path)[i]
+                              for path, i in inv.items()}} if inv else {}
+            preds, labels, new_bstats = apply_fn(p, bstats, batch,
+                                                 train=True, **given)
             lv = loss_fn(preds, labels, mask=mask) if mask is not None \
                 else loss_fn(preds, labels)
             return lv, (preds, labels, new_bstats)
@@ -388,13 +409,38 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         fwd = apply_remat(_loss, remat_mode)
         return jax.value_and_grad(fwd, has_aux=True)(params)
 
+    def _update(state, batch, mask, tables):
+        """One optimizer update from one batch. The declared ``tables`` (if
+        any) are differentiated and updated in the rows the batch looked up
+        (``train/rowwise.py``): the user's ``tx.update`` runs once, on the
+        row view of params and opt_state."""
+        if not tables:
+            out, grads = _microbatch_grads(state.params, state.batch_stats,
+                                           batch, mask)
+            return state.apply_gradients(grads=grads), out
+        uniq, inv = {}, {}
+        for path, ids in tables.items():
+            uniq[path], inv[path] = rowwise.unique_rows(
+                ids, rowwise.leaf_at(state.params, path).shape[0])
+        whole = (state.params, state.opt_state)
+        idx = rowwise.index_trees(state.tx, *whole, uniq)
+        view_params, view_opt = rowwise.take_rows(whole, idx)
+        out, grads = _microbatch_grads(view_params, state.batch_stats, batch,
+                                       mask, inv)
+        new_view = state.replace(
+            params=view_params, opt_state=view_opt).apply_gradients(
+                grads=grads)
+        new_params, new_opt = rowwise.put_rows(
+            whole, (new_view.params, new_view.opt_state), idx)
+        return new_view.replace(params=new_params, opt_state=new_opt), out
+
     def train_step(state, batch, mstats, loss_sum):
         batch, mask = _strip_mask(batch)
+        tables = rowwise.tables_to_update(apply_fn, state, batch, accum,
+                                          counted)
         if accum <= 1:
-            (loss_val, (preds, labels, new_bstats)), grads = \
-                _microbatch_grads(state.params, state.batch_stats, batch,
-                                  mask)
-            new_state = state.apply_gradients(grads=grads)
+            new_state, (loss_val, (preds, labels, new_bstats)) = _update(
+                state, batch, mask, tables)
             if new_bstats is not None:
                 new_state = new_state.replace(batch_stats=new_bstats)
             new_mstats = tuple(

@@ -1,0 +1,270 @@
+"""The row-wise embedding-table update: a train step that differentiates and
+updates the rows a batch looked up, never the table (doc/training.md,
+"Embedding tables: the row-wise update").
+
+A model *declares* its lookups (``lookups(inputs) -> {parameter path: ids}``
+and a forward that takes pre-gathered ``rows``; :class:`raydp_tpu.models.DLRM`
+is the one that does). For each declared table with ids ``[B]`` the step
+
+1. de-duplicates the ids at the static size ``B`` (:func:`unique_rows`);
+2. builds a *row view* of the parameters and of the optimizer state: every
+   leaf that mirrors the table replaced by its ``uniq`` rows, everything else
+   whole (:func:`index_trees`, :func:`take_rows`);
+3. differentiates the loss w.r.t. that view — the forward reads
+   ``view[inv]``, so the float32 gradient of a row is the sum over its
+   duplicates — and calls the user's ``tx.update`` once on the view;
+4. writes the ``uniq`` rows back into the donated table (:func:`put_rows`).
+
+Whether that equals ``tx``'s dense result is a property of the optimizer, so
+it is tried on a tiny tree before the step engages (:func:`same_as_dense`).
+
+Shapes are static (nothing is lowered for a new batch), but the work is not:
+a row read from or written to a table in HBM costs the chip 50 and 120 ns
+(PERF.md, PR 25), and a Zipf batch of 4096 ids has some 1400 distinct ones.
+So the lookup and the write-back walk ``uniq`` in passes of :data:`CHUNK`
+rows and stop after the last real one; the fill ids past it are never
+touched.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Tuple
+
+import numpy as np
+
+from raydp_tpu import metrics
+from raydp_tpu.log import get_logger
+
+logger = get_logger("train.rowwise")
+
+#: in an index tree: this leaf is no row-wise table and stays whole
+WHOLE = object()
+#: rows a pass of the lookup and of the write-back handles
+CHUNK = 256
+#: a table this small (bytes of its rows padded to the chip's 128 lanes) is
+#: not walked in passes. The TPU compiler stages such a table through fast
+#: memory (128 MiB on a v5e; a shard of a table sharded two ways counts) in a
+#: lane-padded row-major layout, and a scatter into it then costs a sweep of
+#: it however few rows it writes: six passes ran 1.28 ms against 0.23 ms for
+#: one pass over all B ids (143,091 x 32 float32, PERF.md PR 25).
+STAGED_BYTES = 256 << 20
+
+Path = Tuple[str, ...]
+
+
+class Rows:
+    """The rows a batch looked up in one table: ``uniq`` ``[B]`` (sorted, the
+    real ids first) and how many of them are real. A leaf of an index tree
+    (deliberately no pytree)."""
+
+    __slots__ = ("uniq", "count")
+
+    def __init__(self, uniq, count):
+        self.uniq, self.count = uniq, count
+
+    def passes(self, table, visit, carry):
+        """``carry`` after ``visit(start, ids, carry)`` over ``uniq`` in
+        chunks, as many as hold real ids (one chunk of all of ``uniq`` for a
+        ``table`` under :data:`STAGED_BYTES`). The last chunk is pulled back
+        inside ``uniq``; rows it visits twice get the same values twice."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        b = self.uniq.shape[0]
+        lanes = -(-int(np.prod(table.shape[1:], dtype=np.int64)) // 128) * 128
+        if b <= CHUNK or (table.shape[0] * lanes * table.dtype.itemsize
+                          <= STAGED_BYTES):
+            return visit(0, self.uniq, carry)
+
+        def body(i, carry):
+            start = jnp.minimum(i * CHUNK, b - CHUNK)
+            return visit(start, lax.dynamic_slice(self.uniq, (start,),
+                                                  (CHUNK,)), carry)
+
+        return lax.fori_loop(0, (self.count + CHUNK - 1) // CHUNK, body,
+                             carry)
+
+
+def _names(path) -> Path:
+    return tuple(str(getattr(k, "key", getattr(k, "name", k))) for k in path)
+
+
+def leaf_at(tree, path: Path):
+    """The leaf of a nested dict at a path of keys."""
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
+def gains(table_shape, ids_shape) -> bool:
+    """From shapes: does a table gain from the row-wise update? Only one that
+    has more rows than the step looks up (one id a batch row)."""
+    return len(ids_shape) == 1 and table_shape[0] > ids_shape[0]
+
+
+def tables_to_update(apply_fn, state, batch, accum: int, seen: list):
+    """``{parameter path: ids}`` of the declared tables THIS step updates by
+    row, decided from what the step can observe: the model's declaration
+    (``apply_fn.lookups``, set by the estimator's ``_make_apply``), no
+    accumulation or pipeline, the shapes, and the probe of ``state.tx``.
+    Counts every declared table once a built step (``seen``) and logs why the
+    dense ones stayed dense."""
+    lookups = getattr(apply_fn, "lookups", None)
+    if lookups is None:
+        return {}
+    ids = {tuple(path): i for path, i in lookups(batch).items()}
+    blocked = getattr(apply_fn, "rowwise_dense_because", None) or (
+        "accum" if accum > 1 else None)
+    because = {}
+    for path, i in ids.items():
+        if blocked:
+            because[path] = blocked
+        elif not gains(leaf_at(state.params, path).shape, i.shape):
+            because[path] = "shape"
+    wide = set(ids) - set(because)
+    if wide and not same_as_dense(state.tx, state.params, wide):
+        because.update(dict.fromkeys(wide, "probe"))
+    if not seen:
+        seen.append(True)
+        for path in ids:
+            metrics.inc("train_table_updates_total",
+                        label="dense" if path in because else "rowwise")
+        if because:
+            why: Dict[str, list] = {}
+            for path, reason in because.items():
+                why.setdefault(reason, []).append("/".join(path))
+            logger.info(
+                "embedding tables: %d of %d declared update row-wise; dense "
+                "because of %s", len(ids) - len(because), len(ids),
+                "; ".join(f"{r}: {', '.join(t)}" for r, t in why.items()))
+    return {p: i for p, i in ids.items() if p not in because}
+
+
+def unique_rows(ids, num_rows: int):
+    """``(rows, inv)`` of the ids ``[B]`` at the static size ``B``:
+    ``rows.uniq`` sorted, its tail filled with *distinct* ids past the table's
+    end (so a scatter may be told its indices are sorted and unique, and
+    ``mode="drop"`` drops them); ``rows.uniq[inv] == ids``."""
+    import jax.numpy as jnp
+
+    b = ids.shape[0]
+    uniq, inv = jnp.unique(ids, size=b, fill_value=num_rows,
+                           return_inverse=True)
+    real = uniq < num_rows
+    uniq = jnp.where(real, uniq, num_rows + jnp.arange(b, dtype=uniq.dtype))
+    return Rows(uniq, jnp.sum(real, dtype=jnp.int32)), inv.reshape(b)
+
+
+def index_trees(tx, params, opt_state, uniq: Dict[Path, Rows]):
+    """``(params_idx, state_idx)``: trees shaped like ``params`` and
+    ``opt_state`` whose leaves are the table's :class:`Rows` where the leaf
+    mirrors a row-wise table and :data:`WHOLE` elsewhere. A state leaf is
+    matched to its parameter by where ``tx.init`` puts parameter trees, not
+    by shape."""
+    import jax
+    import optax
+
+    params_idx = jax.tree_util.tree_map_with_path(
+        lambda path, _: uniq.get(_names(path), WHOLE), params)
+    state_idx = optax.tree_utils.tree_map_params(
+        tx, lambda _, u: u, opt_state, params_idx,
+        transform_non_params=lambda _: WHOLE)
+    return params_idx, state_idx
+
+
+def take_rows(tree, idx):
+    """The row view of ``tree``: indexed leaves replaced by their ``uniq``
+    rows ``[B, ...]`` (zeros where ``uniq`` holds a fill id; what is computed
+    on those is never written)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def take(a, rows):
+        if rows is WHOLE:
+            return a
+        return rows.passes(
+            a, lambda start, ids, out: lax.dynamic_update_slice_in_dim(
+                out, jnp.take(a, ids, axis=0, mode="clip",
+                              indices_are_sorted=True, unique_indices=True),
+                start, axis=0),
+            jnp.zeros(rows.uniq.shape + a.shape[1:], a.dtype))
+
+    return jax.tree.map(take, tree, idx)
+
+
+def put_rows(tree, view, idx):
+    """``tree`` with the view written back: whole leaves replaced, indexed
+    leaves updated in their real ``uniq`` rows and nowhere else."""
+    import jax
+    from jax import lax
+
+    def put(a, v, rows):
+        if rows is WHOLE:
+            return v
+        return rows.passes(
+            a, lambda start, ids, table: table.at[ids].set(
+                lax.dynamic_slice_in_dim(v, start, ids.shape[0], axis=0)
+                .astype(a.dtype), mode="drop", indices_are_sorted=True,
+                unique_indices=True), a)
+
+    return jax.tree.map(put, tree, view, idx)
+
+
+def same_as_dense(tx, params, tables) -> bool:
+    """The probe: does updating a row view give ``tx``'s own dense result?
+
+    Tried, eagerly on the host, on a tiny tree of ``params``' structure (so a
+    transformation that treats parameters by name sees the names): after one
+    ordinary update, a gradient whose table rows 2 and 3 are zero goes through
+    ``tx.update`` dense and through the row view of rows 0 and 1. They must
+    agree bit for bit in the updates (zero on the rows the view skipped) and in
+    every state leaf. ``adagrad`` and plain ``sgd`` do; ``adam`` (its moments
+    decay on a skipped row), momentum and weight decay do not — for them a
+    skipped row is a different result, and the step stays dense. Anything that
+    raises on the way stays dense too."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    def tiny(path, p):
+        shape = (4,) + (2,) * (p.ndim - 1) if _names(path) in tables \
+            else (2,) * p.ndim
+        n = int(np.prod(shape))
+        return jnp.asarray(np.linspace(0.25, 1.0, n).reshape(shape), p.dtype)
+
+    def touched(path, g):
+        # the table rows a batch of ids {0, 1} looked up; 2 and 3 get nothing
+        if _names(path) not in tables:
+            return g
+        return g * (jnp.arange(4) < 2).astype(g.dtype).reshape(
+            (4,) + (1,) * (g.ndim - 1))
+
+    try:
+        cpu = jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        cpu = None
+    try:
+        with jax.ensure_compile_time_eval(), jax.default_device(cpu):
+            p0 = jax.tree_util.tree_map_with_path(tiny, params)
+            g1 = jax.tree.map(lambda p: p * 0.5 - 0.4, p0)
+            g2 = jax.tree_util.tree_map_with_path(
+                touched, jax.tree.map(lambda p: p * -0.75 + 0.3, p0))
+            u1, s1 = tx.update(g1, tx.init(p0), p0)
+            p1 = optax.apply_updates(p0, u1)
+            u_dense, s_dense = tx.update(g2, s1, p1)
+
+            rows = Rows(jnp.asarray([0, 1, 6], jnp.int32), 2)  # 6: a fill id
+            p_idx, s_idx = index_trees(tx, p1, s1, {t: rows for t in tables})
+            u_view, s_view = tx.update(take_rows(g2, p_idx),
+                                       take_rows(s1, s_idx),
+                                       take_rows(p1, p_idx))
+            u_rows = put_rows(jax.tree.map(jnp.zeros_like, u_dense), u_view,
+                              p_idx)
+            s_rows = put_rows(s1, s_view, s_idx)
+            dense, dense_def = jax.tree.flatten((u_dense, s_dense))
+            rowwise, rowwise_def = jax.tree.flatten((u_rows, s_rows))
+            return dense_def == rowwise_def and all(
+                np.array_equal(a, b) for a, b in zip(dense, rowwise))
+    except Exception:  # noqa: BLE001 - an optimizer the view cannot serve
+        return False

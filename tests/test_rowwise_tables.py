@@ -1,0 +1,582 @@
+"""The row-wise embedding-table update (raydp_tpu/train/rowwise.py,
+doc/training.md): the step differentiates and updates the rows a batch looked
+up, gives the dense step's results, engages only where the code can see that
+it may, and leaves everything else on the step it had."""
+
+import logging
+import re
+
+import numpy as np
+import pytest
+
+NUM_DENSE = 4
+SIZES = [1000, 16, 600, 8, 300, 48]     # three tables wider than the batch
+WIDE = [0, 2, 4]
+B = 64
+STEPS = 20
+
+
+def _model(**kw):
+    from raydp_tpu.models import DLRM
+
+    return DLRM(categorical_sizes=SIZES, num_dense=NUM_DENSE, embedding_dim=8,
+                bottom_mlp=(16, 8), top_mlp=(16, 1), **kw)
+
+
+class _Undeclared:
+    """The same model with its lookup declaration hidden: the dense step."""
+
+    def __init__(self, model):
+        self.init, self.apply = model.init, model.apply
+
+
+def _batches(n=STEPS, tail_rows=None, seed=0):
+    """Zipf ids, so every batch looks a row up more than once. With
+    ``tail_rows`` the last batch is a pad-and-mask tail: zero rows past it."""
+    import jax.numpy as jnp
+
+    from raydp_tpu.data.feed import MASK_KEY
+
+    rng = np.random.default_rng(seed)
+    out = []
+    for k in range(n):
+        sparse = np.stack([np.minimum(rng.zipf(1.2, B), v) - 1
+                           for v in SIZES], 1)
+        assert all(len(np.unique(sparse[:, j])) < B for j in WIDE)
+        feats = np.concatenate([rng.random((B, NUM_DENSE)), sparse], 1)
+        batch = {"features": feats.astype(np.float32),
+                 "label": rng.integers(0, 2, B).astype(np.float32)}
+        if tail_rows is not None:
+            real = B if k < n - 1 else tail_rows
+            batch = {key: np.where(
+                (np.arange(B) < real).reshape((B,) + (1,) * (a.ndim - 1)),
+                a, 0).astype(a.dtype) for key, a in batch.items()}
+            batch[MASK_KEY] = (np.arange(B) < real).astype(np.float32)
+        out.append({key: jnp.asarray(a) for key, a in batch.items()})
+    return out
+
+
+def _state(model, tx, mesh=None):
+    import jax
+    import jax.numpy as jnp
+    from flax.training import train_state
+
+    from raydp_tpu.models import dlrm_param_rules
+    from raydp_tpu.parallel import param_sharding_rules
+
+    class State(train_state.TrainState):
+        batch_stats: object = None
+
+    v = model.init(jax.random.PRNGKey(0), {
+        "dense": jnp.zeros((1, NUM_DENSE)),
+        "sparse": jnp.zeros((1, len(SIZES)), jnp.int32)})
+    state = State.create(apply_fn=model.apply, params=v["params"], tx=tx,
+                         batch_stats=None)
+    if mesh is None:
+        return state
+    return jax.device_put(state, param_sharding_rules(
+        mesh, dlrm_param_rules("expert"))(state))
+
+
+def _step(model, mesh=None, accum=1):
+    from raydp_tpu.models import criteo_batch_preprocessor
+    from raydp_tpu.parallel import batch_sharding
+    from raydp_tpu.train.flax_estimator import (_make_apply, _make_train_step,
+                                                _resolve_loss)
+
+    apply_fn = _make_apply(model, False,
+                           criteo_batch_preprocessor(NUM_DENSE), None)
+    mb = (batch_sharding(mesh), None) if mesh is not None else None
+    return _make_train_step(apply_fn, _resolve_loss("bce"), [], accum, "none",
+                            mb_shardings=mb)
+
+
+def _run(step, state, batches, mesh=None):
+    import jax
+    import jax.numpy as jnp
+
+    from raydp_tpu.parallel import batch_sharding
+
+    jitted = jax.jit(step)
+    loss = jnp.zeros(())
+    for batch in batches:
+        if mesh is not None:
+            batch = jax.device_put(batch, batch_sharding(mesh))
+        state, loss, _ = jitted(state, batch, (), loss)
+    return state, float(loss)
+
+
+def _table_counts():
+    from raydp_tpu import metrics
+
+    return dict(metrics.snapshot()["counters"].get(
+        "train_table_updates_total", {}))
+
+
+def _counted(before):
+    after = _table_counts()
+    return {k: after.get(k, 0) - before.get(k, 0)
+            for k in ("rowwise", "dense")}
+
+
+@pytest.fixture
+def step_log():
+    """What the estimator's logger said (it does not propagate)."""
+    lines = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    logger = logging.getLogger("raydp_tpu.train.rowwise")
+    handler = Keep(level=logging.INFO)
+    logger.addHandler(handler)
+    yield lines
+    logger.removeHandler(handler)
+
+
+def _mesh(placement):
+    import jax
+
+    from raydp_tpu.parallel import make_mesh
+
+    if placement == "one_device":
+        return None
+    return make_mesh(dict(data=2, expert=2), devices=jax.devices()[:4])
+
+
+# ------------------------------------------------- (a) row-wise against dense
+@pytest.mark.parametrize("walk", ["one_pass", "in_passes"])
+@pytest.mark.parametrize("tail", [None, 37], ids=["full", "masked_tail"])
+@pytest.mark.parametrize("placement", ["one_device", "data2_expert2"])
+def test_rowwise_matches_dense(placement, tail, walk, monkeypatch):
+    import jax
+    import optax
+
+    from raydp_tpu.train import rowwise
+
+    if walk == "in_passes":
+        # what a table of millions of rows gets: uniq walked in chunks, as
+        # many as hold real ids (here 16 rows a pass, 2 to 4 passes a table)
+        monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+        monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh = _mesh(placement)
+    model = _model()
+    batches = _batches(tail_rows=tail)
+    tx = optax.adagrad(0.05)
+    before = _table_counts()
+    row_step = _step(model, mesh)
+    row, row_loss = _run(row_step, _state(model, tx, mesh), batches, mesh)
+    assert _counted(before) == {"rowwise": 3, "dense": 3}
+    text = str(jax.make_jaxpr(row_step)(_state(model, tx), batches[0], (),
+                                        0.0))
+    assert ("while" in text) == (walk == "in_passes")
+    dense, dense_loss = _run(_step(_Undeclared(model), mesh),
+                             _state(model, tx, mesh), batches, mesh)
+    assert row_loss == pytest.approx(dense_loss, rel=1e-6)
+    a = jax.tree_util.tree_leaves_with_path((row.params, row.opt_state))
+    b = jax.tree.leaves((dense.params, dense.opt_state))
+    assert len(a) == len(b)
+    for (path, x), y in zip(a, b):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=1e-7, err_msg=str(path))
+
+    # rows no batch looked up hold the bits they were initialised with
+    start = _state(model, tx)
+    sparse = np.concatenate([np.asarray(b_["features"])[:, NUM_DENSE:]
+                             for b_ in batches]).astype(np.int64)
+    for j in WIDE:
+        name = f"embedding_{j}"
+        untouched = np.setdiff1d(np.arange(SIZES[j]), sparse[:, j])
+        assert len(untouched) > SIZES[j] // 4
+        for tree0, tree1 in ((start.params, row.params),
+                             (start.opt_state[0].sum_of_squares,
+                              row.opt_state[0].sum_of_squares)):
+            np.testing.assert_array_equal(
+                np.asarray(tree1[name]["embedding"])[untouched],
+                np.asarray(tree0[name]["embedding"])[untouched])
+        if mesh is not None:     # and the table is still sharded by rows
+            table = row.params[name]["embedding"]
+            assert table.sharding.shard_shape(table.shape)[0] == SIZES[j] // 2
+
+
+# ------------------------------------------------------------ (b) the probe
+def _optimizers():
+    import optax
+
+    return {
+        "adagrad": (optax.adagrad(0.05), True),
+        "sgd": (optax.sgd(0.1), True),
+        "adam": (optax.adam(1e-2), False),
+        "sgd_momentum": (optax.sgd(0.1, momentum=0.9), False),
+        "adagrad_weight_decay": (optax.chain(
+            optax.add_decayed_weights(1e-4), optax.adagrad(0.05)), False),
+    }
+
+
+@pytest.mark.parametrize("name", ["adagrad", "sgd", "adam", "sgd_momentum",
+                                  "adagrad_weight_decay"])
+def test_probe_decides_and_result_is_the_optimizers_own(name, step_log):
+    """Whichever way the probe decides, the step gives what the parent's step
+    gave: ``value_and_grad`` of the plain model and one ``tx.update`` on the
+    whole tree, written out here with nothing of the estimator's."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.models import criteo_batch_preprocessor
+    from raydp_tpu.train.flax_estimator import _resolve_loss
+
+    tx, rowwise = _optimizers()[name]
+    model = _model()
+    batches = _batches(6)
+    before = _table_counts()
+    got, _ = _run(_step(model), _state(model, tx), batches)
+    assert _counted(before) == ({"rowwise": 3, "dense": 3} if rowwise
+                                else {"rowwise": 0, "dense": 6})
+    said = [line for line in step_log if "embedding tables" in line]
+    assert len(said) == 1 and ("probe" in said[0]) == (not rowwise)
+
+    prep, loss_fn = criteo_batch_preprocessor(NUM_DENSE), _resolve_loss("bce")
+
+    @jax.jit
+    def plain(params, opt_state, batch):
+        def loss(p):
+            inputs, labels = prep(batch)
+            return loss_fn(model.apply({"params": p}, inputs).squeeze(-1)
+                           .astype(jnp.float32), labels)
+
+        grads = jax.grad(loss)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    start = _state(model, tx)
+    params, opt_state = start.params, start.opt_state
+    for batch in batches:
+        params, opt_state = plain(params, opt_state, batch)
+    for x, y in zip(jax.tree.leaves((got.params, got.opt_state)),
+                    jax.tree.leaves((params, opt_state))):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=1e-7)
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("sgd_schedule", True), ("clip_then_adagrad", True), ("lars", False),
+    ("rmsprop", False), ("adamw", False)])
+def test_probe_on_other_transformations(name, expected):
+    """The probe tries the row view itself, so it also passes what only counts
+    steps (a schedule) or sums over gradients (a global-norm clip), and fails
+    what reads a whole leaf's parameters (LARS) or decays a moment."""
+    import optax
+
+    from raydp_tpu.train import rowwise
+
+    tx = {"sgd_schedule": optax.sgd(optax.linear_schedule(0.1, 0.01, 10)),
+          "clip_then_adagrad": optax.chain(optax.clip_by_global_norm(1.0),
+                                           optax.adagrad(0.05)),
+          "lars": optax.lars(0.1), "rmsprop": optax.rmsprop(0.01),
+          "adamw": optax.adamw(1e-3)}[name]
+    params = _state(_model(), optax.sgd(0.1)).params
+    tables = {(f"embedding_{j}", "embedding") for j in WIDE}
+    assert rowwise.same_as_dense(tx, params, tables) is expected
+
+
+def test_two_tables_of_one_shape_are_told_apart_by_path():
+    """State leaves are matched to their parameter by path: with two tables of
+    one shape and only one of them row-wise, the other's accumulator is swept
+    whole (its untouched rows still accumulate nothing, but its view is the
+    table) and both come out as the dense step's."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.train import rowwise
+
+    tx = optax.adagrad(0.1)
+    params = {"a": {"embedding": jnp.ones((8, 2))},
+              "b": {"embedding": jnp.ones((8, 2)) * 2}}
+    state = tx.init(params)
+    uniq = {("b", "embedding"): rowwise.Rows(
+        jnp.asarray([1, 5, 8], jnp.int32), 2)}
+    p_idx, s_idx = rowwise.index_trees(tx, params, state, uniq)
+    view = rowwise.take_rows((params, state), (p_idx, s_idx))
+    shapes = [x.shape for x in jax.tree.leaves(view)]
+    assert shapes == [(8, 2), (3, 2), (8, 2), (3, 2)]
+    new = jax.tree.map(lambda x: x + 1, view)
+    back = rowwise.put_rows((params, state), new, (p_idx, s_idx))
+    b = np.asarray(back[0]["b"]["embedding"])
+    assert (b[[1, 5]] == 3).all() and (np.delete(b, [1, 5], 0) == 2).all()
+    assert (np.asarray(back[0]["a"]["embedding"]) == 2).all()
+
+
+def test_unique_rows_is_sorted_unique_and_inverts():
+    import jax.numpy as jnp
+
+    from raydp_tpu.train import rowwise
+
+    ids = jnp.asarray([7, 3, 7, 7, 0, 3, 9, 0], jnp.int32)
+    rows, inv = rowwise.unique_rows(ids, 10)
+    uniq, inv = np.asarray(rows.uniq), np.asarray(inv)
+    assert int(rows.count) == 4
+    assert list(uniq[:4]) == [0, 3, 7, 9]
+    assert (uniq[4:] >= 10).all() and len(set(uniq)) == len(uniq)
+    assert (np.diff(uniq) > 0).all()
+    assert (uniq[inv] == np.asarray(ids)).all()
+
+
+# ------------------------------------- (c) accumulated and pipelined: dense
+def test_accumulated_step_stays_dense_and_says_why(step_log):
+    import optax
+
+    model = _model()
+    before = _table_counts()
+    _run(_step(model, accum=2), _state(model, optax.adagrad(0.05)),
+         _batches(2))
+    assert _counted(before) == {"rowwise": 0, "dense": 6}
+    said = [line for line in step_log if "embedding tables" in line]
+    assert len(said) == 1 and "accum" in said[0]
+
+
+def test_pipeline_model_stays_dense_and_says_why(step_log):
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from flax.training import train_state
+
+    from raydp_tpu.parallel import make_mesh
+    from raydp_tpu.train import PipelineModel
+    from raydp_tpu.train.flax_estimator import (_make_pipeline_apply,
+                                                _make_train_step,
+                                                _resolve_loss)
+
+    class Embed(nn.Module):
+        @nn.nowrap
+        def lookups(self, inputs):
+            return {("table", "embedding"): inputs[:, 0]}
+
+        @nn.compact
+        def __call__(self, inputs, rows=None):
+            return nn.Embed(1000, 8, name="table")(inputs[:, 0])
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return x + nn.Dense(8)(x)
+
+    class Head(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(1)(x)
+
+    model = PipelineModel([Block(), Block()], embed=Embed(), head=Head())
+    split = lambda b: (b["features"].astype(jnp.int32), b["label"])  # noqa: E731
+    batch = {"features": jnp.arange(16).reshape(16, 1) % 7,
+             "label": jnp.zeros((16,))}
+    params = model.init(jax.random.PRNGKey(0), split(batch)[0])["params"]
+
+    class State(train_state.TrainState):
+        batch_stats: object = None
+
+    state = State.create(apply_fn=model.apply, params=params,
+                         tx=optax.adagrad(0.05), batch_stats=None)
+    mesh = make_mesh(dict(data=1), devices=jax.devices()[:1])
+    apply_fn = _make_pipeline_apply(model, split, None, mesh, 2, {})
+    step = _make_train_step(apply_fn, _resolve_loss("mse"), [], 1, "none")
+    before = _table_counts()
+    new, _, _ = jax.jit(step)(state, batch, (), jnp.zeros(()))
+    assert _counted(before) == {"rowwise": 0, "dense": 1}
+    said = [line for line in step_log if "embedding tables" in line]
+    assert len(said) == 1 and "pipeline: embed/table/embedding" in said[0]
+    assert new.params["embed"]["table"]["embedding"].shape == (1000, 8)
+
+
+# ---------------------------------------- (d) a model that declares nothing
+def test_undeclared_model_lowers_no_sort_unique_or_scatter():
+    """An MLP's step (and any model's without a declaration) takes the branch
+    it took: nothing of the row-wise path is in its jaxpr, and no table is
+    counted."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from flax.training import train_state
+
+    from raydp_tpu.models import MLP
+    from raydp_tpu.train.flax_estimator import (_make_apply, _make_train_step,
+                                                _resolve_loss)
+
+    model = MLP(features=(16, 8), use_batch_norm=False)
+    batch = {"features": jnp.ones((B, 3)), "label": jnp.ones((B,))}
+    params = model.init(jax.random.PRNGKey(0), batch["features"])["params"]
+
+    class State(train_state.TrainState):
+        batch_stats: object = None
+
+    state = State.create(apply_fn=model.apply, params=params,
+                         tx=optax.adagrad(0.05), batch_stats=None)
+    step = _make_train_step(
+        _make_apply(model, False, lambda b: (b["features"], b["label"]),
+                    None), _resolve_loss("mse"), [], 1, "none")
+    before = _table_counts()
+    text = str(jax.make_jaxpr(step)(state, batch, (), jnp.zeros(())))
+    assert _counted(before) == {"rowwise": 0, "dense": 0}
+    for word in ("sort", "unique", "scatter", "gather", "cumsum"):
+        assert word not in text, word
+
+    # the row-wise step of the DLRM does hold them (the check can fail)
+    dlrm = _model()
+    text = str(jax.make_jaxpr(_step(dlrm))(
+        _state(dlrm, optax.adagrad(0.05)), _batches(1)[0], (),
+        jnp.zeros(())))
+    assert "sort" in text and "scatter" in text
+
+
+# ------------------------- (e) what a CPU can count in the compiled program
+#: what may touch an array with more rows than the batch: the program's
+#: arguments and results, the lookups and the row write-back, and what only
+#: carries or re-labels a buffer. Everything else (element-wise arithmetic,
+#: converts, broadcasts, copies, every collective) may not.
+_MAY_HOLD_A_TABLE = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                     "gather", "scatter", "fusion", "while", "call",
+                     "conditional", "dynamic-slice", "dynamic-update-slice"}
+
+
+def _ops_on_tables(hlo: str, batch: int):
+    """``{opcode: [instruction, ...]}`` of the instructions whose result or an
+    operand has more than ``batch`` rows, fused computations included."""
+    rows = {}
+    lines = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?)(\w+)\[([\d,]*)\]"
+                     r".*? ([\w\-]+)\((.*)", line)
+        if not m:
+            continue
+        name, is_tuple, _, dims, opcode, rest = m.groups()
+        dims = [int(d) for d in dims.split(",") if d]
+        rows[name] = 0 if is_tuple or not dims else dims[0]
+        lines.append((name, opcode, rest))
+    found = {}
+    for name, opcode, rest in lines:
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+        if max([rows[name]] + [rows.get(o, 0) for o in operands]) > batch:
+            found.setdefault(opcode, []).append(name)
+    return found
+
+
+@pytest.mark.parametrize("declared", [True, False],
+                         ids=["rowwise", "dense_for_contrast"])
+def test_compiled_step_on_the_mesh_touches_no_table(declared):
+    """The DLRM step compiled for the 8-device mesh (data 2 x expert 4): on a
+    row-wise table's shard nothing but the lookup and the write-back runs: no
+    collective, no element-wise op, no copy. The dense step fails the same
+    check, so the check can see."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.parallel import batch_sharding, make_mesh
+
+    mesh = make_mesh(dict(data=2, expert=4))
+    model = _model()
+    state = _state(model, optax.adagrad(0.05), mesh)
+    batch = jax.device_put(_batches(1)[0], batch_sharding(mesh))
+    step = _step(model if declared else _Undeclared(model), mesh)
+    before = _table_counts()
+    compiled = jax.jit(step, donate_argnums=(0, 3)).lower(
+        state, batch, (), jnp.zeros(())).compile()
+    # every wide table's shard (75 rows and more) is wider than the batch
+    assert min(SIZES[j] for j in WIDE) // 4 > B
+    found = _ops_on_tables(compiled.as_text(), B)
+    assert "parameter" in found
+    extra = set(found) - _MAY_HOLD_A_TABLE
+    if declared:
+        assert _counted(before) == {"rowwise": 3, "dense": 3}
+        assert not extra, {k: found[k][:3] for k in extra}
+        assert len(found["scatter"]) == 6       # 3 tables + 3 accumulators
+    else:
+        assert _counted(before) == {"rowwise": 0, "dense": 0}
+        assert extra
+
+
+# ------------------------------- (f) a dense fit's checkpoint, row-wise step
+def test_dense_checkpoint_resumes_under_the_rowwise_step(tmp_path):
+    """One tree: what a dense step (here: accumulated) saved restores into the
+    state a row-wise step runs on, and the next step from it is the dense
+    step's."""
+    import jax
+    import optax
+
+    from raydp_tpu.parallel import make_mesh, param_sharding_rules
+    from raydp_tpu.train import checkpoint as ckpt
+
+    model = _model()
+    tx = optax.adagrad(0.05)
+    batches = _batches(5)
+    dense, _ = _run(_step(model, accum=2), _state(model, tx), batches[:4])
+    ckpt.save(str(tmp_path), dense, step=0)
+
+    template = _state(model, tx)
+    shardings = param_sharding_rules(
+        make_mesh(dict(data=1), devices=jax.devices()[:1]), None)(template)
+    restored, step = ckpt.restore_placed(str(tmp_path), template, shardings)
+    assert step == 0
+    assert jax.tree.structure((restored.params, restored.opt_state)) \
+        == jax.tree.structure((dense.params, dense.opt_state))
+    before = _table_counts()
+    row, _ = _run(_step(model), restored, batches[4:])
+    assert _counted(before) == {"rowwise": 3, "dense": 3}
+    again, _ = _run(_step(_Undeclared(model)), dense, batches[4:])
+    for x, y in zip(jax.tree.leaves((row.params, row.opt_state)),
+                    jax.tree.leaves((again.params, again.opt_state))):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-6,
+                                   atol=1e-7)
+
+
+# ------------------------------------------------- through the estimator
+def test_fit_engages_from_the_model_and_optimizer_alone(session, step_log):
+    """An ordinary ``fit_on_frame``: no knob, no argument. The DLRM with
+    Adagrad trains its wide tables row-wise; the forward gathers float32 rows
+    and casts the rows, so a bf16 model's parameters stay float32."""
+    import jax.numpy as jnp
+    import optax
+    import pandas as pd
+
+    from raydp_tpu.models import criteo_batch_preprocessor
+    from raydp_tpu.train import FlaxEstimator
+
+    rng = np.random.RandomState(0)
+    n = 4 * B
+    data = {"_c0": rng.randint(0, 2, n).astype(np.float64)}
+    for i in range(1, NUM_DENSE + 1):
+        data[f"_c{i}"] = rng.random_sample(n)
+    for j, vocab in enumerate(SIZES):
+        data[f"_c{NUM_DENSE + 1 + j}"] = np.minimum(
+            rng.zipf(1.2, n), vocab) - 1
+    df = session.createDataFrame(pd.DataFrame(data), num_partitions=2)
+    est = FlaxEstimator(
+        model=_model(dtype=jnp.bfloat16), optimizer=optax.adagrad(0.05),
+        loss="bce_with_logits",
+        feature_columns=[f"_c{i}" for i in range(1, NUM_DENSE + 1 + len(SIZES))],
+        label_column="_c0", feature_dtype=np.float64, batch_size=B,
+        num_epochs=2, batch_preprocessor=criteo_batch_preprocessor(NUM_DENSE))
+    before = _table_counts()
+    result = est.fit_on_frame(df)
+    assert _counted(before) == {"rowwise": 3, "dense": 3}
+    assert result.history[-1]["train_loss"] < result.history[0]["train_loss"]
+    table = result.state.params["embedding_0"]["embedding"]
+    assert table.dtype == jnp.float32 and table.shape == (SIZES[0], 8)
+    assert any("3 of 6 declared update row-wise" in line and "shape" in line
+               for line in step_log)
+
+
+def test_counter_is_registered_and_documented():
+    import os
+
+    from raydp_tpu import metrics
+
+    m = metrics.METRICS["train_table_updates_total"]
+    assert (m.kind, m.label) == (metrics.COUNTER, "path")
+    doc = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "doc", "observability.md")
+    with open(doc) as fh:
+        assert "| `train_table_updates_total` | counter |" in fh.read()
