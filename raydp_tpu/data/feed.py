@@ -110,21 +110,38 @@ def _as_numpy(table: pa.Table, columns: Sequence[str], dtype) -> np.ndarray:
     host buffer staging"); null-bearing/non-primitive columns and a library
     that cannot be built take the numpy path below, output-identical
     (tests/test_native_stage.py). Which one ran is counted, so a run can say
-    what it measured."""
-    if len(columns) > 1:
-        from raydp_tpu.native.stage import stage_table
-        staged = stage_table(table, columns, dtype)
+    what it measured.
+
+    One column of a fixed-size-list type (one row = one packed sequence of
+    tokens) comes out ``[rows, list_size]``: the child values cast in one
+    flat pass, natively where eligible, and counted the same way."""
+    if len(columns) == 1:
+        col = table.column(columns[0])
+        if not pa.types.is_fixed_size_list(col.type):
+            return col.to_numpy(zero_copy_only=False).astype(dtype,
+                                                             copy=False)
+        from raydp_tpu.native.stage import stage_list_column
+        staged = stage_list_column(col, dtype)
         metrics.inc("feed_staged_tables_total",
                     label="numpy" if staged is None else "native")
         if staged is not None:
             return staged
-    arrays = []
-    for c in columns:
-        col = table.column(c)
-        arrays.append(col.to_numpy(zero_copy_only=False).astype(dtype, copy=False))
-    if len(arrays) == 1:
-        return arrays[0]
-    return np.stack(arrays, axis=1)
+        if col.null_count:
+            raise ValueError(f"list column {columns[0]!r} holds nulls")
+        flat = [c.flatten().to_numpy(zero_copy_only=False)
+                for c in col.chunks]
+        return (np.concatenate(flat) if flat else np.empty(0)).reshape(
+            len(col), col.type.list_size).astype(dtype, copy=False)
+    from raydp_tpu.native.stage import stage_table
+    staged = stage_table(table, columns, dtype)
+    metrics.inc("feed_staged_tables_total",
+                label="numpy" if staged is None else "native")
+    if staged is not None:
+        return staged
+    return np.stack([
+        table.column(c).to_numpy(zero_copy_only=False).astype(dtype,
+                                                              copy=False)
+        for c in columns], axis=1)
 
 
 class HostBatchIterator:
@@ -526,7 +543,14 @@ class DeviceEpochCache:
     def estimate_bytes(dataset,
                        columns: Dict[str, Tuple[ColumnSpec, np.dtype]]) -> int:
         rows = sum(dataset.block_sizes())
-        per_row = sum(len(cnames) * np.dtype(dt).itemsize
+        schema = dataset.schema
+
+        def width(name):    # a fixed-size-list column decodes to its size
+            t = schema.field(name).type if name in schema.names else None
+            return t.list_size if t is not None \
+                and pa.types.is_fixed_size_list(t) else 1
+
+        per_row = sum(sum(map(width, cnames)) * np.dtype(dt).itemsize
                       for cnames, dt in _normalize_columns(columns).values())
         return rows * per_row
 

@@ -53,6 +53,12 @@ HISTOGRAM = "histogram"
 #: a device trace, and then into that trace - it never enters the ring.
 PHASE = "phase"
 STEP = "step"
+#: a third class names no host span at all: a SCOPE is a ``jax.named_scope``
+#: (or a flax module's name) inside a model, which the compiler carries into
+#: every device op's ``op_name``; a device trace's ops are attributed to it
+#: (``chipbench/trace/scopes.py``). Declared here so that the table of spans
+#: is the whole vocabulary a trace is read with.
+SCOPE = "scope"
 
 #: histograms are summary-shaped (count/sum/min/max), not bucketed: every
 #: producer is a wall-clock or size observation whose tails the driver can
@@ -84,8 +90,8 @@ class Span:
     #: (f-strings); the linter only checks literal names, these rows exist
     #: so the doc table is the complete span vocabulary
     dynamic: bool = False
-    #: PHASE (ring, through ``profiler.trace``/``open_span``) or STEP (device
-    #: trace only, through ``profiler.step``)
+    #: PHASE (ring, through ``profiler.trace``/``open_span``), STEP (device
+    #: trace only, through ``profiler.step``) or SCOPE (device ops' op_name)
     kind: str = PHASE
 
 
@@ -298,6 +304,14 @@ _ALL_METRICS = [
        "or dense (the whole table; the fit's log names why: probe, shape, "
        "accum, pipeline). doc/training.md, the row-wise update.",
        label="path"),
+    _m("moe_slots_total", COUNTER, "1", "training",
+       "Expert slots (token, expert choices) the sparse expert layers of a "
+       "training model routed, summed on the device inside the train step "
+       "and added here with each epoch's loss: `all` is every slot "
+       "(experts a token x tokens x expert layers), `max_expert` the slots "
+       "of each layer's fullest expert. max_expert / (all / experts) is the "
+       "load imbalance a dropless layer pays for. doc/training.md.",
+       label="kind"),
     _m("train_accum_steps", GAUGE, "1", "training",
        "Gradient-accumulation microbatches per optimizer step this fit is "
        "running with (1 = unaccumulated; the RDT_TRAIN_ACCUM_STEPS / "
@@ -441,6 +455,27 @@ _ALL_SPANS = [
     _s("feed:put_wait", "feed",
        "A feed stage blocked on its full output queue: the stage is ahead "
        "of its consumer.", kind=STEP),
+    # ---- model: scopes in the device ops' op_name ---------------------------
+    _s("attn", "model",
+       "A transformer block's attention (`models/transformer.py`): the "
+       "projections, QK-norm, RoPE and the flash kernels "
+       "(`rdt_flash_fwd`, `rdt_flash_bwd_dkdv`, `rdt_flash_bwd_dq`).",
+       kind=SCOPE),
+    _s("moe/router", "model",
+       "Sparse expert layer (`models/moe.py`): float32 router product, "
+       "softmax, top-k, group sizes and both auxiliary losses.", kind=SCOPE),
+    _s("moe/dispatch", "model",
+       "Sparse expert layer: the sort of the slots by expert and the gather "
+       "of the tokens into that order.", kind=SCOPE),
+    _s("moe/experts", "model",
+       "Sparse expert layer: the grouped expert products (`ragged-dot`), "
+       "SiLU and the gate.", kind=SCOPE),
+    _s("moe/combine", "model",
+       "Sparse expert layer: back to token order and the weighted sum over "
+       "a token's experts.", kind=SCOPE),
+    _s("lm_head_loss", "model",
+       "The language model's head fused into its loss: the head's product, "
+       "softmax and cross entropy, chunk by chunk in a scan.", kind=SCOPE),
 ]
 
 SPANS: Dict[str, Span] = {s.name: s for s in _ALL_SPANS}
@@ -448,10 +483,13 @@ assert len(SPANS) == len(_ALL_SPANS), "duplicate span declaration"
 
 #: exact names literal ``profiler.trace(...)`` calls may use (the linter's
 #: check set); dynamic families are prefixes of runtime-formatted names
-SPAN_NAMES = frozenset(s.name for s in _ALL_SPANS if not s.dynamic)
+SPAN_NAMES = frozenset(s.name for s in _ALL_SPANS
+                       if not s.dynamic and s.kind != SCOPE)
 #: the subset ``profiler.step`` takes (and ``trace``/``open_span`` must not)
 STEP_SPAN_NAMES = frozenset(s.name for s in _ALL_SPANS if s.kind == STEP)
 SPAN_PREFIXES = tuple(s.name for s in _ALL_SPANS if s.dynamic)
+#: the model scopes a device trace's ops are attributed to
+SCOPE_NAMES = frozenset(s.name for s in _ALL_SPANS if s.kind == SCOPE)
 
 
 def _e(kind, subsystem, doc):

@@ -9,6 +9,10 @@ two passes and an intermediate per column). The feed's ``_as_numpy`` calls
 tests/test_native_stage.py parity tests, and which path decoded each table
 is counted in ``feed_staged_tables_total{path}``.
 
+A single fixed-size-list column (one row = one packed token sequence) is one
+flat cast of its child values into ``[rows, list_size]``
+(:func:`stage_list_column`): no Python per row or per token.
+
 Float→int dtype pairs are DECLINED (here and in the kernel's own dispatch):
 ``static_cast`` from a float to an integer is undefined behavior in C++ for
 NaN/out-of-range values, while numpy's astype has different,
@@ -166,4 +170,39 @@ def stage_table(table: pa.Table, columns: Sequence[str],
                                   len(columns), c, row0):
                 return None
             row0 += n_rows
+    return out
+
+
+def stage_list_column(col: pa.ChunkedArray,
+                      dtype: np.dtype) -> Optional[np.ndarray]:
+    """``[rows, list_size]`` array of ``dtype`` from one fixed-size-list
+    column of a primitive type, decoded natively: the child values of each
+    chunk are one contiguous run, cast in one call. None when the column is
+    ineligible (nulls, a non-primitive child, an unsupported dtype pair) or
+    the library is missing; the caller then decodes with numpy."""
+    dtype = np.dtype(dtype)
+    dst_code = _DST_CODES.get(dtype)
+    lib = _load() if dst_code is not None else None
+    if lib is None or col.null_count:
+        return None
+    width = col.type.list_size
+    plans = []
+    for chunk in col.chunks:
+        values = chunk.values           # the whole child; the chunk's rows
+        ptr = _chunk_ptr(values)        # start at chunk.offset * width
+        if ptr is None:
+            return None
+        src = _ARROW_NUMERIC[values.type]
+        code = _DTYPE_CODES[src]
+        if dst_code in (4, 5) and code in (0, 1):
+            return None                 # float -> int: declined (module doc)
+        plans.append((ptr + chunk.offset * width * src.itemsize, code,
+                      len(chunk) * width))
+    out = np.empty((len(col), width), dtype)
+    at = 0
+    for ptr, code, n in plans:
+        if lib.rdt_stage_cast(ptr, code, n, out.ctypes.data, dst_code, 1, 0,
+                              at):
+            return None
+        at += n
     return out

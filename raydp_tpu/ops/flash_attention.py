@@ -39,6 +39,9 @@ from jax import lax
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 _NEG_INF = -1e30
+#: the three kernels' names as a device trace shows them (forward, the
+#: backward's dk/dv walk, its dq walk): what a roofline reader sums
+KERNEL_NAMES = ("rdt_flash_fwd", "rdt_flash_bwd_dkdv", "rdt_flash_bwd_dq")
 
 
 # ---------------------------------------------------------------------------
@@ -139,6 +142,7 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=KERNEL_NAMES[0],
     )(q3, k3, v3)
     return out, lse.reshape(bh, t)
 
@@ -300,6 +304,7 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=KERNEL_NAMES[1],
     )(q3, k3, v3, do, lse3, delta)
 
     dq = pl.pallas_call(
@@ -322,6 +327,7 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=KERNEL_NAMES[2],
     )(q3, k3, v3, do, lse3, delta)[0]
     return dq, dk, dv
 
@@ -396,18 +402,34 @@ def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
 
 def _use_pallas(t: int, d: int, blk_q: int, blk_k: int,
                 interpret: bool) -> bool:
+    """Can the Pallas kernel take this call? In interpret mode: whenever the
+    blocks divide the sequence. Otherwise whenever the compiled kernel is
+    eligible; on a TPU backend an ineligible shape is an error. Whether the
+    kernel then *runs* is decided when the program is lowered
+    (:func:`_by_platform`): for a TPU it does, for anything else the jnp
+    path."""
     if interpret:
         return t % blk_q == 0 and t % blk_k == 0
-    if jax.default_backend() != "tpu":
-        return False
     why = kernel_ineligible(t, d, blk_q, blk_k)
-    if why is not None:
+    if why is None:
+        return True
+    if jax.default_backend() == "tpu":
         raise ValueError(
             f"flash_attention cannot run its Pallas kernel on this TPU "
             f"backend: {why}. The jnp path would materialize the "
             f"[B*H, {t}, {t}] scores the kernel exists to avoid, so it is "
             f"not substituted; pad the sequence or use dense_attention.")
-    return True
+    return False
+
+
+def _by_platform(pallas_fn, jnp_fn, interpret: bool, *args):
+    """The kernel where the program is lowered for a TPU, the jnp path
+    elsewhere: chosen at lowering, not from the process's default backend,
+    so a step compiled ahead of time for a described TPU (no chip attached)
+    holds the kernel it will run. Interpret mode always takes the kernel."""
+    if interpret:
+        return pallas_fn(*args)
+    return lax.platform_dependent(*args, tpu=pallas_fn, default=jnp_fn)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -418,20 +440,27 @@ def _flash(q3, k3, v3, scale, causal, blk_q, blk_k, interpret):
 
 def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret):
     t, d = q3.shape[1], q3.shape[2]
+    jnp_fn = functools.partial(_fwd_jnp, scale=scale, causal=causal)
     if _use_pallas(t, d, blk_q, blk_k, interpret):
-        out, lse = _fwd_pallas(q3, k3, v3, scale=scale, causal=causal,
-                               blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+        out, lse = _by_platform(
+            functools.partial(_fwd_pallas, scale=scale, causal=causal,
+                              blk_q=blk_q, blk_k=blk_k, interpret=interpret),
+            jnp_fn, interpret, q3, k3, v3)
     else:
-        out, lse = _fwd_jnp(q3, k3, v3, scale=scale, causal=causal)
+        out, lse = jnp_fn(q3, k3, v3)
     return out, (q3, k3, v3, out, lse)
 
 
 def _flash_bwd(scale, causal, blk_q, blk_k, interpret, res, g):
     t, d = res[0].shape[1], res[0].shape[2]
+    jnp_fn = functools.partial(_bwd_blockwise, scale=scale, causal=causal,
+                               blk_k=blk_k)
     if _use_pallas(t, d, blk_q, blk_k, interpret):
-        return _bwd_pallas(res, g, scale=scale, causal=causal,
-                           blk_q=blk_q, blk_k=blk_k, interpret=interpret)
-    return _bwd_blockwise(res, g, scale=scale, causal=causal, blk_k=blk_k)
+        return _by_platform(
+            functools.partial(_bwd_pallas, scale=scale, causal=causal,
+                              blk_q=blk_q, blk_k=blk_k, interpret=interpret),
+            jnp_fn, interpret, res, g)
+    return jnp_fn(res, g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -13,6 +13,8 @@ that role wants on this mesh:
 - **projection / dense kernels** (≥ 2-D): Megatron-style ``tensor`` on the
   output (last) dim, ``fsdp`` on the largest remaining dim — FSDP all-gathers
   params per layer so its dim choice is a memory layout, not a math change;
+- **stacked expert kernels** (path names an expert, 3-D ``[E, in, out]``):
+  dim 0 over the mesh's ``expert`` axis, the inner dims as a kernel's;
 - **biases / norm scales / scalars** (≤ 1-D): replicated — sharding a few
   hundred bytes buys nothing and costs a gather.
 
@@ -46,13 +48,20 @@ EMBEDDING_TOKENS = ("embed",)
 #: its inner dims).
 STAGE_TOKENS = ("stage_stack",)
 
+#: path substrings that mark the stacked kernels of a sparse expert layer
+#: (``raydp_tpu/models/moe.py``: ``experts_gate`` / ``experts_up`` /
+#: ``experts_down``, each ``[E, in, out]``). The router is 2-D: a kernel.
+EXPERT_TOKENS = ("expert",)
+
 REPLICATED = "replicated"
 EMBEDDING = "embedding"
 KERNEL = "kernel"
+EXPERT = "expert"
 
 
 def classify_param(path: str, shape: Tuple[int, ...]) -> str:
-    """The role of one leaf: ``embedding`` | ``kernel`` | ``replicated``.
+    """The role of one leaf: ``embedding`` | ``expert`` | ``kernel`` |
+    ``replicated``.
 
     Works on parameter paths AND their optimizer-state mirrors (e.g.
     ``opt_state/0/mu/Dense_0/kernel`` classifies like the kernel itself);
@@ -64,6 +73,8 @@ def classify_param(path: str, shape: Tuple[int, ...]) -> str:
     low = path.lower()
     if ndim == 2 and any(tok in low for tok in EMBEDDING_TOKENS):
         return EMBEDDING
+    if ndim == 3 and any(tok in low for tok in EXPERT_TOKENS):
+        return EXPERT
     return KERNEL
 
 
@@ -97,6 +108,19 @@ def role_partition_spec(mesh, path: str, shape: Tuple[int, ...]):
     fsdp = int(mesh.shape.get("fsdp", 1))
     tensor = int(mesh.shape.get("tensor", 1))
     role = classify_param(path, shape)
+    if role == EXPERT:
+        # the expert stack over ``expert`` where it divides; each expert's
+        # [in, out] kernel then takes a kernel's spec on its own dims
+        expert = int(mesh.shape.get("expert", 1))
+        head = "expert" if _divides(shape[0], expert) else None
+        inner_path = low
+        for tok in EXPERT_TOKENS:
+            inner_path = inner_path.replace(tok, "")
+        spec = [head, *role_partition_spec(mesh, inner_path,
+                                           tuple(shape[1:]))]
+        while spec and spec[-1] is None:
+            spec.pop()
+        return PartitionSpec(*spec)
     if role == REPLICATED or (fsdp <= 1 and tensor <= 1):
         return PartitionSpec()
 
@@ -172,7 +196,7 @@ def apply_remat(fn, mode: str):
 #: the roles a remat policy may key on: the param-role vocabulary plus
 #: ``default`` (the fallback mode — a bare mode string is sugar for
 #: ``default=<mode>``, which keeps the pre-r20 global knob meaning).
-REMAT_ROLES = (REPLICATED, EMBEDDING, KERNEL, "default")
+REMAT_ROLES = (REPLICATED, EMBEDDING, KERNEL, EXPERT, "default")
 
 
 def parse_remat_policy(spec: str) -> Dict[str, str]:
@@ -180,7 +204,7 @@ def parse_remat_policy(spec: str) -> Dict[str, str]:
 
     Accepts either a bare mode (``"dots"`` — the pre-r20 global form, now
     meaning *default policy for every role*) or a comma-separated
-    ``role=mode`` list (``"embedding=none,kernel=dots,default=full"``).
+    ``role=mode`` list (``"embedding=none,kernel=dots,expert=dots,default=full"``).
     Roles come from :data:`REMAT_ROLES`, modes from :data:`REMAT_MODES`;
     anything else raises ``ValueError`` — validated eagerly, long before any
     compile. The returned dict always carries a ``default`` entry

@@ -37,7 +37,8 @@ from raydp_tpu.train.estimator import (
     FrameEstimatorInterface,
     save_epoch_now,
 )
-from raydp_tpu.train.metrics import Metric, build_metrics
+from raydp_tpu.train.metrics import (Metric, build_metrics,
+                                     model_counters)
 
 logger = get_logger("train.flax_estimator")
 
@@ -173,35 +174,59 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     Returns ``apply_fn(params, bstats, batch, train, rows=None) ->
     (preds_f32, labels, new_bstats)``. Where the model declares its lookups
     (``raydp_tpu/train/rowwise.py``), ``apply_fn.lookups(batch)`` gives them
-    and ``rows`` hands the forward the rows the step already gathered."""
+    and ``rows`` hands the forward the rows the step already gathered.
+
+    A model that declares ``loss_rows(inputs, labels)`` hands the step its
+    loss itself (``apply_fn.model_loss``): the forward calls that method in
+    place of ``__call__`` and ``preds`` is what it returns, the pair (loss a
+    row ``[B]``, what ``model.loss_counters`` counts); :func:`_step_loss`
+    then averages the rows. A language model's ``[B, T, vocab]`` logits need
+    never exist this way; called plainly the model still returns them."""
     import jax.numpy as jnp
+
+    model_loss = callable(getattr(model, "loss_rows", None))
 
     def apply_fn(params, bstats, batch, train: bool, rows=None):
         inputs, labels = split_batch(batch)
         inputs = _cast_floating(inputs, compute_dtype)
         variables = {"params": params}
+        args = (inputs,)
         kwargs = {"train": train} if takes_train else {}
         if rows is not None:
             kwargs["rows"] = rows
+        if model_loss:
+            args, kwargs["method"] = (inputs, labels), model.loss_rows
         if bstats is not None:
             variables["batch_stats"] = bstats
             if train:
                 preds, updates = model.apply(
-                    variables, inputs, mutable=["batch_stats"], **kwargs)
+                    variables, *args, mutable=["batch_stats"], **kwargs)
                 new_bstats = updates["batch_stats"]
             else:
-                preds = model.apply(variables, inputs, **kwargs)
+                preds = model.apply(variables, *args, **kwargs)
                 new_bstats = bstats
         else:
-            preds = model.apply(variables, inputs, **kwargs)
+            preds = model.apply(variables, *args, **kwargs)
             new_bstats = None
+        if model_loss:
+            return preds, labels, new_bstats
         if preds.ndim == labels.ndim + 1 and preds.shape[-1] == 1:
             preds = preds.squeeze(-1)
         return preds.astype(jnp.float32), labels, new_bstats
 
+    apply_fn.model_loss = model_loss
     if callable(getattr(model, "lookups", None)):
         apply_fn.lookups = lambda batch: model.lookups(split_batch(batch)[0])
     return apply_fn
+
+
+def _step_loss(apply_fn, loss_fn) -> Callable:
+    """The loss a step built round ``apply_fn`` takes: the estimator's, or,
+    where the model brings its own (:func:`_make_apply`), the mean of its
+    rows (real rows only under a pad-and-mask feed)."""
+    if not getattr(apply_fn, "model_loss", False):
+        return loss_fn
+    return lambda preds, labels, mask=None: _masked_mean(preds[0], mask)
 
 
 class PipelineModel:
@@ -392,6 +417,7 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
 
     from raydp_tpu.train import rowwise
 
+    loss_fn = _step_loss(apply_fn, loss_fn)
     counted: list = []      # the table counter is bumped once a built step
 
     def _microbatch_grads(params, bstats, batch, mask, inv=None):
@@ -880,8 +906,12 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # same order — a rank that is one step behind deadlocks the gang. With
         # in-jit accumulation the only host reads are float() of replicated
         # scalars at epoch end (also one fewer host sync single-process).
-        train_step = _make_train_step(_apply, loss_fn, metrics, step_accum,
-                                      step_remat,
+        loss_fn = _step_loss(_apply, loss_fn)      # eval_step's, below
+        # what the model's own loss counts rides the train metrics' slot:
+        # summed inside the step, fetched with the epoch's loss
+        train_metrics = metrics + model_counters(model)
+        train_step = _make_train_step(_apply, loss_fn, train_metrics,
+                                      step_accum, step_remat,
                                       mb_shardings=(b_sharding, seq_sharding))
 
         # publish the compiled step's peak temp (activation) bytes when the
@@ -1042,7 +1072,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                 with profiler.trace("train:epoch", "training",
                                     epoch=epoch) as epoch_span:
                     t0 = time.perf_counter()
-                    mstats = tuple(m.init() for m in metrics)
+                    mstats = tuple(m.init() for m in train_metrics)
                     loss_sum = np.zeros((), np.float32)
                     steps, samples = 0, 0
                     t_feed = t_disp = 0.0
@@ -1131,9 +1161,10 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                             "dispatch_time_s": t_disp,
                             "sync_time_s": t_sync,
                         }
-                        for m, s in zip(metrics, mstats):
-                            report[f"train_{m.name}"] = m.compute(
-                                jax.tree.map(np.asarray, s))
+                        for m, s in zip(train_metrics, mstats):
+                            value = m.compute(jax.tree.map(np.asarray, s))
+                            if value is not None:
+                                report[f"train_{m.name}"] = value
 
                         if eval_feed is not None or eval_cache is not None:
                             estats = tuple(m.init() for m in metrics)

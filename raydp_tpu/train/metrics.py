@@ -97,6 +97,35 @@ class BinaryCrossEntropy(Metric):
                 "count": stats["count"] + jnp.sum(w)}
 
 
+class ModelCounters(Metric):
+    """What a model that brings its own loss counts beside it (the second
+    output of its ``loss_rows``; ``model.loss_counters`` names each entry as
+    a (registry counter, label) pair): summed inside the jitted step like any
+    metric's statistics and, at the epoch's end, added to the registry's
+    counters. It reports nothing into the epoch's history."""
+
+    name = "model_counters"
+
+    def __init__(self, names):
+        self.names = tuple(names)
+
+    def init(self):
+        return np.zeros(len(self.names), np.float32)
+
+    def update(self, stats, preds, labels, mask=None):
+        return stats + preds[1]
+
+    def compute(self, stats) -> None:
+        from raydp_tpu import metrics as registry
+        for (counter, label), value in zip(self.names, stats):
+            registry.inc(counter, float(value), label)
+
+
+def model_counters(model) -> List[Metric]:
+    names = getattr(model, "loss_counters", ())
+    return [ModelCounters(names)] if names else []
+
+
 _REGISTRY = {m.name: m for m in (MSE(), RMSE(), MAE(), Accuracy(),
                                  BinaryCrossEntropy())}
 _REGISTRY["mean_squared_error"] = _REGISTRY["mse"]

@@ -1,0 +1,334 @@
+"""OLMoE-style sparse-expert LM: the program's model, loss and train step
+against the plain reference (``chipbench/reference/olmoe-1b-7b.py``: float32
+``jax.numpy``, dense attention, every expert on every token), at a tiny size
+on the CPU, seeded random weights. Widths are small here, and only here.
+"""
+
+import copy
+import os
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TINY = {"hidden_size": 32, "num_attention_heads": 4, "intermediate_size": 16,
+        "num_experts": 8, "num_experts_per_tok": 2, "vocab_size": 64,
+        "max_position_embeddings": 16, "layers": 2, "compared_positions": 4,
+        "compute_dtype": "float32", "attention": "dense", "init_std": 0.3}
+
+
+def _files():
+    from chipbench import manifest
+    cfg = manifest.load_json(ROOT, "configs", "olmoe-1b-7b.json")
+    cfg.update(copy.deepcopy(TINY))
+    cfg["input"] = dict(cfg["input"], eos_id=63)
+    return (cfg, manifest.load_module(ROOT, "pipelines", "olmoe-1b-7b.py"),
+            manifest.load_module(ROOT, "reference", "olmoe-1b-7b.py"))
+
+
+def _tokens(cfg, rows, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, cfg["vocab_size"], (rows, cfg["max_position_embeddings"]),
+        dtype=np.int32)
+
+
+def _params(model, tokens, routing, seed=0):
+    """Seeded weights. ``skewed``: a constant feature in the embedding and a
+    router row that reads it, so that expert 0 is in every token's top-2 and
+    experts 5-7 are in nobody's: one expert holds half of all slots, three
+    groups are empty."""
+    import jax
+    params = jax.tree.map(np.array, model.init(
+        jax.random.PRNGKey(seed), tokens[:1])["params"])
+    if routing == "skewed":
+        params["embed"]["embedding"][:, 0] = 25.0
+        for name in (n for n in params if n.startswith("block_")):
+            router = params[name]["moe"]["router"]
+            router[0] = [6.0, 0, 0, 0, 0, -6.0, -6.0, -6.0]
+    return params
+
+
+def _leaves(tree):
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(getattr(k, "key", k)) for k in path): np.asarray(v)
+            for path, v in flat}
+
+
+def _system_loss(model, params, tokens):
+    rows, counts = model.apply({"params": params}, tokens, tokens,
+                               method=model.loss_rows)
+    return rows.mean(), counts
+
+
+# float32 against float32-highest: what is left is summation order (a sorted
+# grouped product against a dense masked one). A bfloat16 router moves
+# near-tied top-k choices and a bfloat16 loss rounds at 2**-8: either fails
+# these by orders of magnitude.
+F32_TOL = 2e-5
+# bfloat16 activations, float32 router and loss: 4 ulps of bfloat16 on the
+# relative RMS error of the logits (the chip's check (a) and its TOLERANCE)
+BF16_TOL = 4 * 2.0 ** -8
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", F32_TOL),
+                                       ("bfloat16", BF16_TOL)])
+def test_forward_logits_match_the_reference(dtype, tol):
+    from chipbench.harness import relative_rms_error
+    cfg, pipeline, reference = _files()
+    cfg["compute_dtype"] = dtype
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 3)
+    params = _params(model, tokens, "uniform")
+    got = pipeline.compared(model.apply({"params": params}, tokens), cfg)
+    want = reference.forward({"params": params}, tokens, cfg)
+    assert got.shape == want.shape == (3, 4, cfg["vocab_size"])
+    assert relative_rms_error(got, want) <= tol
+    if dtype == "bfloat16":     # and the tolerance does separate precisions
+        assert relative_rms_error(got, want) > F32_TOL
+
+
+@pytest.mark.parametrize("routing", ["uniform", "skewed"])
+def test_loss_and_every_gradient_leaf_match_the_reference(routing):
+    """Dropless under imbalance: with the skewed router one expert holds
+    half of all slots and three hold none, and every slot still contributes
+    (the gradients of the full experts, the empty experts' zeros and the
+    router's all match a reference that computes every expert densely)."""
+    import jax
+    cfg, pipeline, reference = _files()
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 4, seed=1)
+    params = _params(model, tokens, routing)
+
+    (loss, counts), grads = jax.value_and_grad(
+        lambda p: _system_loss(model, p, tokens), has_aux=True)(params)
+    want_loss, want_grads = jax.value_and_grad(reference.loss)(
+        params, tokens, cfg)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    got, want = _leaves(grads), _leaves(want_grads)
+    assert set(got) == set(want)
+    for name, g in got.items():
+        scale = max(np.abs(want[name]).max(), 1e-3)
+        assert np.abs(g - want[name]).max() <= 10 * F32_TOL * scale, name
+
+    slots = tokens.size * cfg["num_experts_per_tok"] * cfg["layers"]
+    assert float(counts[1]) == slots
+    ids = np.stack(reference.top_k_ids(params, tokens, cfg))
+    per_expert = np.stack([np.bincount(layer.ravel(), minlength=8)
+                           for layer in ids])
+    assert float(counts[0]) == per_expert.max(axis=1).sum()
+    if routing == "skewed":
+        assert (per_expert[:, 0] == tokens.size).all()      # half of all
+        assert (per_expert[:, 5:] == 0).all()               # empty groups
+        gate = got["block_0/moe/experts_gate"]
+        assert np.abs(gate[0]).max() > 0 and np.abs(gate[5:]).max() == 0
+    else:
+        assert (per_expert > 0).all()
+
+
+def test_the_fused_loss_is_lm_loss_on_materialised_logits():
+    from raydp_tpu.models.transformer import lm_loss
+    cfg, pipeline, _ = _files()
+    cfg["num_experts"] = 0      # the head's loss alone: no auxiliary term
+    cfg["model_type"] = "dense"
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 3, seed=2)
+    params = _params(model, tokens, "uniform")
+    loss, counts = _system_loss(model, params, tokens)
+    want = lm_loss(model.apply({"params": params}, tokens), tokens)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    assert counts.shape == (0,) and model.loss_counters == ()
+
+
+def _token_frame(session, tmp_path, cfg, pipeline, rows, seed):
+    import pyarrow.parquet as pq
+    path = str(tmp_path / "tokens")
+    os.makedirs(path)
+    table = pipeline.generate(rows, seed, cfg)
+    for i in range(2):
+        pq.write_table(table.slice(i * rows // 2, rows // 2),
+                       os.path.join(path, f"part-{i}.parquet"))
+    wl = {"seq_len": cfg["max_position_embeddings"]}
+    df, info = pipeline.etl(session.read.parquet(path), cfg, wl)
+    return df.persist(), info, table
+
+
+def _estimator(cfg, pipeline, info, mesh, **fit):
+    from raydp_tpu.train import FlaxEstimator
+    return FlaxEstimator(
+        model=pipeline.build_model(cfg, mesh), loss=None,
+        optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
+        columns_spec={"tokens": (info["tokens"], np.int32)},
+        batch_preprocessor=lambda b: (b["tokens"], b["tokens"]),
+        shuffle=False, batch_size=4, seed=0, **fit)
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+def test_fit_on_frame_reproduces_an_optax_loop_over_the_reference(
+        session, tmp_path, accum):
+    """Token rows from the ETL plane through ``fit_on_frame`` (list column,
+    feed, the model's own loss, AdamW with clipping) against a hand-written
+    loop: ``jax.grad`` of the reference's loss, the same optimizer. With
+    ``accum_steps`` 2 each half batch has its own auxiliary losses (they are
+    statistics of the tokens routed together), and the halves' gradients are
+    averaged."""
+    import jax
+    import optax
+    from raydp_tpu import metrics as registry
+    from raydp_tpu.parallel import make_mesh
+
+    cfg, pipeline, reference = _files()
+    df, info, table = _token_frame(session, tmp_path, cfg, pipeline, 8, 5)
+    mesh = make_mesh(None, devices=jax.devices()[:1])
+    before = registry.snapshot()["counters"]
+    est = _estimator(cfg, pipeline, info, mesh, num_epochs=2,
+                     accum_steps=accum)
+    history = est.fit_on_frame(df).history
+
+    tokens = pipeline.reference_inputs(table, info)
+    assert tokens.shape == (8, 16) and tokens.dtype == np.int32
+    tx = pipeline.build_optimizer(cfg)
+    params = jax.tree.map(np.asarray, est._build_model().init(
+        jax.random.PRNGKey(0), tokens[:1])["params"])
+    opt_state = tx.init(params)
+    grad = jax.value_and_grad(reference.loss)
+    want = []
+    for _ in range(2):
+        losses = []
+        for at in (0, 4):
+            halves = np.split(tokens[at:at + 4], accum)
+            pairs = [grad(params, h, cfg) for h in halves]
+            losses.append(np.mean([float(v) for v, _ in pairs]))
+            g = jax.tree.map(lambda *gs: sum(gs) / accum,
+                             *[g for _, g in pairs])
+            updates, opt_state = tx.update(g, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        want.append(np.mean(losses))
+    got = [e["train_loss"] for e in history]
+    # float32 both sides; two epochs of AdamW steps amplify summation order
+    np.testing.assert_allclose(got, want, rtol=2e-4)
+    assert got[-1] < got[0]
+
+    # (f) no lookups declared: the row-wise table update does not engage for
+    # the LM's embedding (AdamW decays untouched rows: not row-wise), and
+    # the routing counters arrived with the epochs' losses
+    assert not hasattr(est._build_model(), "lookups")
+    after = registry.snapshot()["counters"]
+    assert after.get("train_table_updates_total", {}) == before.get(
+        "train_table_updates_total", {})
+    moved = {k: v - before.get("moe_slots_total", {}).get(k, 0)
+             for k, v in after["moe_slots_total"].items()}
+    assert moved["all"] == 2 * 8 * 16 * 2 * 2   # epochs x tokens x top-2 x layers
+    assert moved["all"] / 8 <= moved["max_expert"] <= moved["all"]
+    staged = after["feed_staged_tables_total"]
+    assert staged.get("native", 0) > before.get(
+        "feed_staged_tables_total", {}).get("native", 0)
+    assert staged.get("numpy", 0) == before.get(
+        "feed_staged_tables_total", {}).get("numpy", 0)
+
+
+def test_an_expert_sharded_fit_gives_the_single_device_losses(
+        session, tmp_path):
+    """``expert`` 4 over four virtual devices: the stacked expert kernels
+    (and their AdamW mirrors) split on dim 0 by the role policy, same
+    losses."""
+    import jax
+    from raydp_tpu.parallel import make_mesh
+
+    cfg, pipeline, _ = _files()
+    df, info, _ = _token_frame(session, tmp_path, cfg, pipeline, 8, 6)
+    losses = {}
+    for name, devices, spec in (("one", 1, None), ("four", 4, {"expert": 4})):
+        mesh = make_mesh(spec, devices=jax.devices()[:devices])
+        est = _estimator(cfg, pipeline, info, mesh, num_epochs=2)
+        losses[name] = [e["train_loss"]
+                        for e in est.fit_on_frame(df).history]
+        if name == "four":
+            state = est.get_state()
+            gate = state.params["block_0"]["moe"]["experts_gate"]
+            assert gate.sharding.spec[0] == "expert"
+            assert {s.data.shape[0] for s in gate.addressable_shards} == {2}
+            mu = state.opt_state[1][0].mu["block_0"]["moe"]["experts_gate"]
+            assert mu.sharding.spec[0] == "expert"
+    np.testing.assert_allclose(losses["four"], losses["one"], rtol=1e-5)
+
+
+@pytest.mark.parametrize("mesh_spec,path,shape,role,spec", [
+    # the stacked expert kernels and their AdamW mirrors: dim 0 over expert
+    (dict(expert=4), "params/block_0/moe/experts_gate", (64, 32, 16),
+     "expert", ("expert",)),
+    (dict(expert=4), "opt_state/1/0/mu/block_0/moe/experts_down",
+     (64, 16, 32), "expert", ("expert",)),
+    (dict(expert=4), "opt_state/1/0/nu/block_0/moe/experts_up",
+     (8, 32, 16), "expert", ("expert",)),
+    # the axis does not divide the stack: replicated, never an error
+    (dict(expert=4), "params/block_0/moe/experts_gate", (6, 32, 16),
+     "expert", ()),
+    # no expert axis on the mesh: each expert's kernel takes a kernel's spec
+    (dict(fsdp=4, tensor=2), "params/block_0/moe/experts_gate",
+     (64, 32, 16), "expert", (None, "fsdp", "tensor")),
+    (dict(expert=2, tensor=2), "params/block_0/moe/experts_down",
+     (64, 16, 32), "expert", ("expert", None, "tensor")),
+    # the router is 2-D: a kernel; a 3-D leaf that names no expert too
+    (dict(expert=4), "params/block_0/moe/router", (32, 64), "kernel", ()),
+    (dict(expert=4), "params/block_0/attn/q/kernel", (32, 4, 8), "kernel",
+     ()),
+])
+def test_the_expert_role(mesh_spec, path, shape, role, spec):
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from raydp_tpu.parallel import make_mesh
+    from raydp_tpu.parallel.roles import classify_param, role_partition_spec
+
+    size = int(np.prod(list(mesh_spec.values())))
+    mesh = make_mesh(dict(mesh_spec, data=1), devices=jax.devices()[:size])
+    assert classify_param(path, shape) == role
+    assert role_partition_spec(mesh, path, shape) == P(*spec)
+
+
+def test_a_remat_policy_may_name_the_expert_role():
+    from raydp_tpu.parallel.roles import (parse_remat_policy,
+                                          remat_mode_for_role, segment_role)
+    policy = parse_remat_policy("expert=dots,default=none")
+    assert remat_mode_for_role(policy, "expert") == "dots"
+    assert remat_mode_for_role(policy, "kernel") == "none"
+    # a block whose bytes are mostly stacked expert kernels is an expert
+    # segment: that role's mode is what its forward runs under
+    tree = {"block_0": {"moe": {"experts_gate": np.zeros((8, 32, 16)),
+                                "router": np.zeros((32, 8))},
+                        "attn": {"q": {"kernel": np.zeros((32, 4, 8))}}}}
+    assert segment_role(tree) == "expert"
+
+
+@pytest.mark.parametrize("source,dtype", [("int32", np.int32),
+                                          ("int64", np.int32),
+                                          ("float32", np.float32),
+                                          ("float64", np.int32)])
+def test_a_token_column_is_staged_in_one_flat_pass(source, dtype):
+    """One fixed-size-list column -> ``[rows, list_size]``: natively where
+    the dtype pair is eligible, by numpy where it is not (float -> int is
+    declined), the same array either way, over chunks and a sliced offset."""
+    import pyarrow as pa
+    from raydp_tpu import metrics as registry
+    from raydp_tpu.data.feed import _as_numpy
+    from raydp_tpu.native.stage import native_stage_available
+
+    flat = np.arange(7 * 5, dtype=source)
+    whole = pa.FixedSizeListArray.from_arrays(pa.array(flat), 5)
+    col = pa.chunked_array([whole.slice(1, 2), whole.slice(3, 4)])
+    table = pa.table({"tokens": col})
+    before = registry.snapshot()["counters"].get(
+        "feed_staged_tables_total", {})
+    got = _as_numpy(table, ("tokens",), dtype)
+    assert got.dtype == dtype and got.shape == (6, 5)
+    np.testing.assert_array_equal(got, flat.reshape(7, 5)[1:].astype(dtype))
+    after = registry.snapshot()["counters"]["feed_staged_tables_total"]
+    native = native_stage_available() and source != "float64"
+    path = "native" if native else "numpy"
+    assert after.get(path, 0) == before.get(path, 0) + 1
+    holed = pa.table({"tokens": pa.array([[1, 2], None],
+                                         pa.list_(pa.int32(), 2))})
+    with pytest.raises(ValueError, match="nulls"):
+        _as_numpy(holed, ("tokens",), np.int32)
