@@ -615,17 +615,16 @@ def _lm_mode_run(mode: str, T: int) -> dict:
     # dispatch that ends in a fetched value
     from jax import lax
 
-    # BENCH_LM_FUSED: 0 = materialized [B,T,V] f32 logits, 1 = chunked fused
-    # CE with remat (smallest memory), 2 = chunked fused CE without remat
-    # (bf16 chunk logits stored; no head recompute). Measured on v5e at
-    # dim=512/T=8192 the three are within ~10% — see bench notes.
+    # BENCH_LM_FUSED: 0 = materialized [B,T,V] f32 logits; 1 and 2 both = the
+    # one chunked fused head loss (lm_loss_fused: both head gradients taken
+    # in the forward scan, no logits kept, nothing recomputed). "2" used to
+    # select a scan without remat; that choice is gone and it maps to "1".
     fused = os.environ.get("BENCH_LM_FUSED", "0")
 
     def step_loss(p, tokens):
         if fused in ("1", "2"):
             hidden = model.apply({"params": p}, tokens, return_hidden=True)
-            return lm_loss_fused(hidden, p["lm_head"]["kernel"], tokens,
-                                 remat=fused == "1")
+            return lm_loss_fused(hidden, p["lm_head"]["kernel"], tokens)
         return lm_loss(model.apply({"params": p}, tokens), tokens)
 
     @partial(jax.jit, donate_argnums=(0, 1))
@@ -712,10 +711,12 @@ def bench_transformer() -> dict:
         # marker line, and salvages it from partial stdout on a cap kill —
         # a later mode's compile stall can no longer cost these entries
         print(RESULT_MARK + json.dumps(out), flush=True)
-    # the named open item from ROOFLINE_LM.md: chunked fused CE WITHOUT remat
-    # (bf16 chunk logits kept for backward — no lm_head recompute). Run last
-    # (the checkpoint line above protects flash/dense); skipped on a CPU run
-    # (its scaled-down shape says nothing about the HBM/FLOPs trade).
+    # the named open item from ROOFLINE_LM.md: the chunked fused head loss
+    # with no lm_head recompute. There is one fused path now (both head
+    # gradients taken in the forward scan), so "2" runs it; the slot keeps
+    # its name. Run last (the checkpoint line above protects flash/dense);
+    # skipped on a CPU run (its scaled-down shape says nothing about the
+    # HBM/FLOPs trade).
     if not _on_cpu():
         out["flash_fused2"] = _one("flash", fused="2")
     errors = [f"{k}: {v['error']}" for k, v in out.items()

@@ -304,6 +304,14 @@ _ALL_METRICS = [
        "or dense (the whole table; the fit's log names why: probe, shape, "
        "accum, pipeline). doc/training.md, the row-wise update.",
        label="path"),
+    _m("train_head_loss_total", COUNTER, "1", "training",
+       "Train steps built round a model that brings its own loss "
+       "(`loss_rows`), counted once a built step by how the loss is "
+       "differentiated: `forward_grad` = the step handed the model the rows' "
+       "weights, so the loss takes its gradients inside its forward pass "
+       "(`TransformerLM`'s fused head loss: three head products a chunk, none "
+       "recomputed in the backward pass). doc/training.md.",
+       label="path"),
     _m("moe_slots_total", COUNTER, "1", "training",
        "Expert slots (token, expert choices) the sparse expert layers of a "
        "training model routed, summed on the device inside the train step "
@@ -475,7 +483,8 @@ _ALL_SPANS = [
        "a token's experts.", kind=SCOPE),
     _s("lm_head_loss", "model",
        "The language model's head fused into its loss: the head's product, "
-       "softmax and cross entropy, chunk by chunk in a scan.", kind=SCOPE),
+       "softmax, cross entropy and both of the head's gradient products, "
+       "chunk by chunk in one scan.", kind=SCOPE),
 ]
 
 SPANS: Dict[str, Span] = {s.name: s for s in _ALL_SPANS}

@@ -29,13 +29,14 @@ width), and the model's training loss carries its two auxiliary losses.
 
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
-head applied chunk by chunk (:func:`lm_loss_fused`'s scan), so the
-``[B, T, vocab]`` float32 logits never exist. Called plainly the model still
-returns them.
+head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
+head's two gradients as it goes), so the ``[B, T, vocab]`` float32 logits
+never exist. Called plainly the model still returns them.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -198,15 +199,16 @@ class TransformerLM(nn.Module):
     init_std: Optional[float] = None
 
     @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False, labels=None):
+    def __call__(self, tokens, return_hidden: bool = False, labels=None,
+                 weights=None):
         """``return_hidden=True`` yields the post-norm hidden states [B,T,D]
         (the lm_head weight is still created so the param tree is identical);
         pair it with :func:`lm_loss_fused`, which applies the head per
         T-chunk so the [B,T,V] float32 logits never materialize — at 32k
         vocab and T=8192 those logits are ~2 GB per direction of pure HBM
         traffic, the single largest non-kernel cost in the train step.
-        ``labels`` [B, T] (the tokens themselves) yields what
-        :meth:`loss_rows` returns."""
+        ``labels`` [B, T] (the tokens themselves) and ``weights`` [B] yield
+        what :meth:`loss_rows` returns."""
         init = _init(self.init_std, nn.linear.default_embed_init)
         x = nn.Embed(self.vocab_size, self.dim, name="embed",
                      dtype=self.dtype, embedding_init=init)(tokens)
@@ -229,15 +231,15 @@ class TransformerLM(nn.Module):
         head(x[:, :1])      # registers the kernel (result DCE'd); the head
         if labels is None:  # itself is applied chunk-wise by the fused loss
             return x
-        with jax.named_scope("lm_head_loss"):
-            rows = lm_rows_fused(x, head.variables["params"]["kernel"],
-                                 labels, chunk=max(128, 2048 // x.shape[0]))
+        loss, _ = lm_head_loss(x, head.variables["params"]["kernel"], labels,
+                               weights, chunk=max(128, 2048 // x.shape[0]))
         if not aux:
-            return rows, jnp.zeros((0,), jnp.float32)
+            return loss, jnp.zeros((0,), jnp.float32)
         mean = lambda key: sum(a[key] for a in aux) / len(aux)  # noqa: E731
-        rows = rows + (self.balance_loss_weight * mean("balance")
-                       + self.z_loss_weight * mean("z"))
-        return rows, jnp.stack([sum(a["slots_max"] for a in aux),
+        loss = loss + weights.sum() * (
+            self.balance_loss_weight * mean("balance")
+            + self.z_loss_weight * mean("z"))
+        return loss, jnp.stack([sum(a["slots_max"] for a in aux),
                                 sum(a["slots_all"] for a in aux)])
 
     @property
@@ -247,14 +249,18 @@ class TransformerLM(nn.Module):
         return (("moe_slots_total", "max_expert"),
                 ("moe_slots_total", "all")) if self.num_experts else ()
 
-    def loss_rows(self, tokens, labels):
-        """The training loss, a row: mean next-token cross entropy of each
-        sequence (head fused into the loss, float32) plus, with experts, the
-        weighted load-balancing and router z-losses of the batch (means over
-        the layers), and the counts of :attr:`loss_counters`. The mean over
-        rows is the loss; :class:`raydp_tpu.train.FlaxEstimator` takes it
-        from here, so no ``[B, T, vocab]`` logits exist in its train step."""
-        return self(tokens, labels=labels)
+    def loss_rows(self, tokens, labels, weights):
+        """The training loss over the rows, under the rows' ``weights`` [B]
+        (``1 / B`` each, or a pad-and-mask feed's ``mask / sum(mask)``): the
+        weighted sum of each sequence's mean next-token cross entropy (head
+        fused into the loss, float32: :func:`lm_head_loss`) plus, with
+        experts, ``sum(weights)`` times the weighted load-balancing and router
+        z-losses of the batch (means over the layers); and the counts of
+        :attr:`loss_counters`. The scalar is the loss
+        :class:`raydp_tpu.train.FlaxEstimator` differentiates, so no
+        ``[B, T, vocab]`` logits exist in its train step, and the weights
+        are what lets the head's gradients be taken in its forward scan."""
+        return self(tokens, labels=labels, weights=weights)
 
 
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -265,62 +271,133 @@ def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
         logits[:, :-1], tokens[:, 1:]).mean()
 
 
-def lm_rows_fused(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
-                  tokens: jnp.ndarray, chunk: int = 1024,
-                  remat: bool = True) -> jnp.ndarray:
-    """Mean next-token cross entropy of each row, ``[B]`` float32, with the
-    lm_head FUSED into the loss.
-
-    The head matmul + softmax-CE run per T-chunk of ``chunk`` positions under
-    ``jax.checkpoint`` inside a ``lax.scan``: forward keeps only the hidden
-    states (already live) and per-chunk row sums, backward recomputes each
-    chunk's logits — peak logits footprint is ``B×chunk×V`` instead of
-    ``B×T×V`` f32 (64× smaller at T=8192/chunk=1024/f32), while each chunk
-    matmul ``[B·chunk, D] @ [D, V]`` stays MXU-sized and accumulates in
-    float32 (the logits are never rounded to the activations' dtype). This
-    trades one extra head matmul (recompute) for ~4 GB of HBM round-trips per
-    step at the bench shape, which is bandwidth the step actually runs out of
-    — the round-2 gap between kernel MFU (51%) and e2e MFU (35%).
-
-    ``hidden`` [B, T, D] from ``model(tokens, return_hidden=True)``;
-    ``lm_head_kernel`` [D, V] = ``params["lm_head"]["kernel"]``.
-    """
-    import optax
-    from jax import lax
-
+def _head_chunks(hidden, tokens, chunk):
+    """Positions 0..T-2 predict tokens 1..T-1: both cut into ``[N, B, C, ...]``
+    chunks of ``C <= chunk`` positions (zero-padded to a whole number of
+    chunks), with the ``[N, 1, C]`` mask of the real positions."""
     B, T, D = hidden.shape
-    x = hidden[:, :-1]                   # predict positions 1..T-1
-    y = tokens[:, 1:]
     n = T - 1
+    x, y = hidden[:, :-1], tokens[:, 1:]
     chunk = min(chunk, n)
     pad = (-n) % chunk
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         y = jnp.pad(y, ((0, 0), (0, pad)))
-    mask = (jnp.arange(n + pad) < n).astype(jnp.float32)[None, :]
-    nchunks = (n + pad) // chunk
-    xs = x.reshape(B, nchunks, chunk, D).swapaxes(0, 1)      # [N, B, C, D]
-    ys = y.reshape(B, nchunks, chunk).swapaxes(0, 1)         # [N, B, C]
-    ms = mask.reshape(1, nchunks, chunk).swapaxes(0, 1)      # [N, 1, C]
+    mask = (jnp.arange(n + pad) < n).astype(jnp.float32)
+    cut = lambda a: a.reshape(  # noqa: E731
+        (a.shape[0], (n + pad) // chunk, chunk) + a.shape[2:]).swapaxes(0, 1)
+    return cut(x), cut(y), cut(mask[None])
 
-    def chunk_ce(total, xyz):
-        xc, yc, mc = xyz
-        logits = jnp.dot(xc, lm_head_kernel.astype(xc.dtype),
-                         preferred_element_type=jnp.float32)
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, yc)
-        return total + (ce * mc).sum(axis=1), None
 
-    body = jax.checkpoint(chunk_ce) if remat else chunk_ce
-    total, _ = lax.scan(body, jnp.zeros((B,), jnp.float32), (xs, ys, ms))
-    return total / n
+def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads):
+    """One scan over the chunks of :func:`_head_chunks`. A chunk's logits
+    (``[B, C, V]`` float32: operands in the activations' dtype, float32
+    accumulation) exist once, inside the scan's body; from them come the
+    chunk's cross entropy and, ``with_grads``, both gradients of
+    ``sum(weights * rows)`` at once: ``dlogits = (softmax - onehot) * mask *
+    weights / (T - 1)`` (float32) against the kernel for the hidden states'
+    and against the chunk's hidden states into a float32 ``[D, V]`` carry for
+    the kernel's, with the operand and accumulation types of the products
+    autodiff transposes out of the forward one. Returns ``(rows [B], d hidden
+    [B, T, D] in hidden's dtype, d kernel [D, V] float32)``, the last two
+    ``None`` without gradients."""
+    from jax import lax
+
+    B, T, D = hidden.shape
+    n = T - 1
+    xs, ys, ms = _head_chunks(hidden, tokens, chunk)
+    k = kernel.astype(hidden.dtype)      # cast once, not once a chunk
+    vocab = lax.broadcasted_iota(jnp.int32, (1, 1, k.shape[1]), 2)
+    scale = weights.astype(jnp.float32)[:, None] / n            # [B, 1]
+
+    def body(carry, chunk_of):
+        total, dk = carry
+        xc, yc, mc = chunk_of
+        logits = jnp.dot(xc, k, preferred_element_type=jnp.float32)
+        top = logits.max(axis=-1, keepdims=True)
+        e = jnp.exp(logits - top)
+        z = e.sum(axis=-1, keepdims=True)
+        label = vocab == yc[..., None]
+        ce = (jnp.log(z) + top)[..., 0] - jnp.where(
+            label, logits, 0.0).sum(axis=-1)
+        total = total + (ce * mc).sum(axis=1)
+        if not with_grads:
+            return (total, None), None
+        g = (scale * mc)[..., None]                             # [B, C, 1]
+        dlogits = e * (g / z) - jnp.where(label, g, 0.0)
+        dxc = lax.dot_general(dlogits, k, (((2,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        dk = dk + lax.dot_general(xc, dlogits, (((0, 1), (0, 1)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return (total, dk), dxc.astype(xc.dtype)
+
+    dk0 = jnp.zeros(k.shape, jnp.float32) if with_grads else None
+    (total, dk), dxs = lax.scan(
+        body, (jnp.zeros((B,), jnp.float32), dk0), (xs, ys, ms))
+    rows = total / n
+    if not with_grads:
+        return rows, None, None
+    dx = dxs.swapaxes(0, 1).reshape(B, -1, D)[:, :n]
+    return rows, jnp.pad(dx, ((0, 0), (0, 1), (0, 0))), dk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _head_loss(hidden, kernel, tokens, weights, chunk):
+    rows, _, _ = _head_scan(hidden, kernel, tokens, weights, chunk, False)
+    return jnp.sum(weights * rows), rows
+
+
+def _head_loss_fwd(hidden, kernel, tokens, weights, chunk):
+    rows, dh, dk = _head_scan(hidden, kernel, tokens, weights, chunk, True)
+    return (jnp.sum(weights * rows), rows), (dh, dk.astype(kernel.dtype),
+                                             rows)
+
+
+def _head_loss_bwd(chunk, residuals, cotangents):
+    dh, dk, rows = residuals
+    g, _ = cotangents           # the rows are reported, not differentiated
+    with jax.named_scope("lm_head_loss"):
+        return ((g * dh).astype(dh.dtype), (g * dk).astype(dk.dtype), None,
+                g * rows)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
+                 tokens: jnp.ndarray, weights: jnp.ndarray,
+                 chunk: int = 1024):
+    """Next-token cross entropy with the lm_head FUSED into the loss:
+    ``(sum(weights * rows), rows)``, ``rows`` ``[B]`` float32 the mean
+    cross entropy of each sequence (reported: no gradient flows from them).
+
+    The head's product and the softmax run per chunk of ``chunk`` positions
+    inside one ``lax.scan``, so the peak logits footprint is ``B×chunk×V``
+    float32 instead of ``B×T×V``; each chunk's product ``[B·chunk, D] @
+    [D, V]`` stays MXU-sized and accumulates in float32 (the logits are never
+    rounded to the activations' dtype). Differentiated, the scan takes both
+    gradients while a chunk's logits are in hand (a ``jax.custom_vjp``: the
+    rows' ``weights`` are what makes ``softmax - onehot`` final there), so a
+    chunk costs three products of head size and the backward pass none: it
+    scales what the forward kept, the hidden states' gradient ``[B, T, D]``
+    and the kernel's ``[D, V]``, by the loss's cotangent. Not differentiated,
+    it is the forward product and the loss alone.
+
+    ``hidden`` [B, T, D] from ``model(tokens, return_hidden=True)``;
+    ``lm_head_kernel`` [D, V] = ``params["lm_head"]["kernel"]``; ``weights``
+    [B] float32: ``1 / B`` each makes the sum the mean loss.
+    """
+    with jax.named_scope("lm_head_loss"):
+        return _head_loss(hidden, lm_head_kernel, tokens, weights, chunk)
 
 
 def lm_loss_fused(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
-                  tokens: jnp.ndarray, chunk: int = 1024,
-                  remat: bool = True) -> jnp.ndarray:
-    """Next-token cross entropy with the lm_head fused into the loss: the
-    mean of :func:`lm_rows_fused` over the rows."""
-    return lm_rows_fused(hidden, lm_head_kernel, tokens, chunk, remat).mean()
+                  tokens: jnp.ndarray, chunk: int = 1024) -> jnp.ndarray:
+    """Mean next-token cross entropy with the lm_head fused into the loss:
+    :func:`lm_head_loss` with uniform weights."""
+    rows = hidden.shape[0]
+    return lm_head_loss(hidden, lm_head_kernel, tokens,
+                        jnp.full((rows,), 1.0 / rows, jnp.float32), chunk)[0]
 
 
 def transformer_param_rules(axis: str = "tensor"):

@@ -91,6 +91,18 @@ def _masked_mean(x, mask):
     return jnp.sum(x * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+def _mean_weights(mask, rows: int):
+    """The rows' weights under which a weighted SUM over rows is
+    :func:`_masked_mean`: ``mask / max(sum(mask), 1)``, or ``1 / rows`` each
+    without a mask. What a model that brings its own loss is handed."""
+    import jax.numpy as jnp
+
+    if mask is None:
+        return jnp.full((rows,), 1.0 / rows, jnp.float32)
+    mask = mask.astype(jnp.float32)
+    return mask / jnp.maximum(jnp.sum(mask), 1.0)
+
+
 def _resolve_loss(loss) -> Callable:
     import jax.numpy as jnp
 
@@ -171,22 +183,27 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     one source for the split/cast/mutable-batch-stats/squeeze policy, so the
     online twin cannot drift from the epoch loop.
 
-    Returns ``apply_fn(params, bstats, batch, train, rows=None) ->
-    (preds_f32, labels, new_bstats)``. Where the model declares its lookups
+    Returns ``apply_fn(params, bstats, batch, train, rows=None, mask=None)
+    -> (preds_f32, labels, new_bstats)``. Where the model declares its lookups
     (``raydp_tpu/train/rowwise.py``), ``apply_fn.lookups(batch)`` gives them
     and ``rows`` hands the forward the rows the step already gathered.
 
-    A model that declares ``loss_rows(inputs, labels)`` hands the step its
-    loss itself (``apply_fn.model_loss``): the forward calls that method in
-    place of ``__call__`` and ``preds`` is what it returns, the pair (loss a
-    row ``[B]``, what ``model.loss_counters`` counts); :func:`_step_loss`
-    then averages the rows. A language model's ``[B, T, vocab]`` logits need
-    never exist this way; called plainly the model still returns them."""
+    A model that declares ``loss_rows(inputs, labels, weights)`` hands the
+    step its loss itself (``apply_fn.model_loss``): the forward calls that
+    method in place of ``__call__``, with the weights under which a sum over
+    rows is the step's mean over the real rows (:func:`_mean_weights` of the
+    batch's ``mask``; no other model's forward reads the mask), and
+    ``preds`` is what it returns, the pair (loss, what
+    ``model.loss_counters`` counts) with ``loss`` the scalar the step
+    differentiates (:func:`_step_loss`). With the weights in hand a model
+    can take gradients inside its forward (a language model's fused head
+    loss does), and its ``[B, T, vocab]`` logits need never exist; called
+    plainly the model still returns them."""
     import jax.numpy as jnp
 
     model_loss = callable(getattr(model, "loss_rows", None))
 
-    def apply_fn(params, bstats, batch, train: bool, rows=None):
+    def apply_fn(params, bstats, batch, train: bool, rows=None, mask=None):
         inputs, labels = split_batch(batch)
         inputs = _cast_floating(inputs, compute_dtype)
         variables = {"params": params}
@@ -195,7 +212,8 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
         if rows is not None:
             kwargs["rows"] = rows
         if model_loss:
-            args, kwargs["method"] = (inputs, labels), model.loss_rows
+            args = (inputs, labels, _mean_weights(mask, labels.shape[0]))
+            kwargs["method"] = model.loss_rows
         if bstats is not None:
             variables["batch_stats"] = bstats
             if train:
@@ -222,11 +240,12 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
 
 def _step_loss(apply_fn, loss_fn) -> Callable:
     """The loss a step built round ``apply_fn`` takes: the estimator's, or,
-    where the model brings its own (:func:`_make_apply`), the mean of its
-    rows (real rows only under a pad-and-mask feed)."""
+    where the model brings its own (:func:`_make_apply`), the scalar the
+    model returned: it was handed the rows' weights, so the mean over the
+    real rows is already taken."""
     if not getattr(apply_fn, "model_loss", False):
         return loss_fn
-    return lambda preds, labels, mask=None: _masked_mean(preds[0], mask)
+    return lambda preds, labels, mask=None: preds[0]
 
 
 class PipelineModel:
@@ -319,8 +338,8 @@ class PipelineModel:
 def _make_pipeline_apply(model: "PipelineModel", split_batch, compute_dtype,
                          mesh, n_micro: int, seg_modes: Dict[str, str]):
     """The pipeline twin of :func:`_make_apply`: same
-    ``apply_fn(params, bstats, batch, train) -> (preds_f32, labels, None)``
-    signature, but the forward splits the batch into ``n_micro`` microbatches
+    ``apply_fn(params, bstats, batch, train, mask=None) -> (preds_f32,
+    labels, None)`` signature, but the forward splits the batch into ``n_micro`` microbatches
     and marches them through the ``shard_map`` GPipe schedule
     (:func:`raydp_tpu.parallel.pipeline.pipeline_apply`).
 
@@ -355,8 +374,10 @@ def _make_pipeline_apply(model: "PipelineModel", split_batch, compute_dtype,
             lambda p, x: head_mod.apply({"params": p}, x),
             seg_modes.get("head", "none"))
 
-    def apply_fn(params, bstats, batch, train: bool):
-        del bstats, train  # pipeline blocks are stat-free and mode-free
+    def apply_fn(params, bstats, batch, train: bool, mask=None):
+        # pipeline blocks are stat-free and mode-free; the mask is for a
+        # model that brings its own loss, which a layer list does not
+        del bstats, train, mask
         inputs, labels = split_batch(batch)
         inputs = _cast_floating(inputs, compute_dtype)
         h = embed_fwd(params["embed"], inputs) if embed_fwd is not None \
@@ -418,6 +439,9 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     from raydp_tpu.train import rowwise
 
     loss_fn = _step_loss(apply_fn, loss_fn)
+    if getattr(apply_fn, "model_loss", False):
+        # once a built step, like the table counter below
+        rdt_metrics.inc("train_head_loss_total", label="forward_grad")
     counted: list = []      # the table counter is bumped once a built step
 
     def _microbatch_grads(params, bstats, batch, mask, inv=None):
@@ -426,8 +450,8 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
             # its uniq rows, and uniq[inv] are the rows the batch looked up
             given = {"rows": {path: rowwise.leaf_at(p, path)[i]
                               for path, i in inv.items()}} if inv else {}
-            preds, labels, new_bstats = apply_fn(p, bstats, batch,
-                                                 train=True, **given)
+            preds, labels, new_bstats = apply_fn(p, bstats, batch, train=True,
+                                                 mask=mask, **given)
             lv = loss_fn(preds, labels, mask=mask) if mask is not None \
                 else loss_fn(preds, labels)
             return lv, (preds, labels, new_bstats)
@@ -948,7 +972,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         def eval_step(state, batch, mstats, loss_sum, cnt_sum):
             batch, mask = _strip_mask(batch)
             preds, labels, _ = _apply(state.params, state.batch_stats, batch,
-                                      train=False)
+                                      train=False, mask=mask)
             if mask is None:
                 rows = jnp.float32(labels.shape[0])
                 loss_val = loss_fn(preds, labels).astype(jnp.float32)

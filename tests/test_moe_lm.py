@@ -56,10 +56,13 @@ def _leaves(tree):
             for path, v in flat}
 
 
-def _system_loss(model, params, tokens):
-    rows, counts = model.apply({"params": params}, tokens, tokens,
-                               method=model.loss_rows)
-    return rows.mean(), counts
+def _system_loss(model, params, tokens, weights=None):
+    """The model's own loss under the rows' weights (the mean's by default:
+    what the train step hands it without a mask)."""
+    if weights is None:
+        weights = np.full(len(tokens), 1.0 / len(tokens), np.float32)
+    return model.apply({"params": params}, tokens, tokens, weights,
+                       method=model.loss_rows)
 
 
 # float32 against float32-highest: what is left is summation order (a sorted
@@ -127,18 +130,86 @@ def test_loss_and_every_gradient_leaf_match_the_reference(routing):
         assert (per_expert > 0).all()
 
 
+def _steps_counted():
+    from raydp_tpu import metrics as registry
+    return registry.snapshot()["counters"].get(
+        "train_head_loss_total", {}).get("forward_grad", 0)
+
+
+def _dense(cfg):
+    """The same files, no experts: the head's loss with no auxiliary term."""
+    cfg["num_experts"], cfg["model_type"] = 0, "dense"
+    return cfg
+
+
 def test_the_fused_loss_is_lm_loss_on_materialised_logits():
     from raydp_tpu.models.transformer import lm_loss
     cfg, pipeline, _ = _files()
-    cfg["num_experts"] = 0      # the head's loss alone: no auxiliary term
-    cfg["model_type"] = "dense"
-    model = pipeline.build_model(cfg)
+    model = pipeline.build_model(_dense(cfg))
     tokens = _tokens(cfg, 3, seed=2)
     params = _params(model, tokens, "uniform")
     loss, counts = _system_loss(model, params, tokens)
     want = lm_loss(model.apply({"params": params}, tokens), tokens)
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
     assert counts.shape == (0,) and model.loss_counters == ()
+
+
+@pytest.mark.parametrize("weights", [[0.5, 0.25, 0.25, 0.0],
+                                     [0.5, 0.0, 0.5, 0.0],
+                                     [0.0, 0.0, 0.0, 0.0]])
+def test_the_model_loss_weighs_rows_and_a_masked_row_carries_no_gradient(
+        weights):
+    """``loss_rows`` under the rows' weights is the weighted sum of the
+    reference's loss a row plus ``sum(weights)`` times the batch's auxiliary
+    terms (what the mean over real rows gave); a zero-weight row adds nothing
+    to the cross entropy's gradients, and an all-pad microbatch is 0 with
+    zero gradients."""
+    import jax
+    cfg, pipeline, reference = _files()
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 4, seed=3)
+    params = _params(model, tokens, "uniform")
+    w = np.asarray(weights, np.float32)
+
+    def want_fn(p):
+        # a row's cross entropy alone: the reference on that row, less its
+        # auxiliary terms; the batch's: those of the reference on the batch
+        dense = dict(cfg, aux_loss=dict(cfg["aux_loss"], balance_weight=0.0,
+                                        z_weight=0.0))
+        rows = [reference.loss(p, tokens[i:i + 1], dense) for i in range(4)]
+        aux = reference.loss(p, tokens, cfg) - reference.loss(p, tokens, dense)
+        return sum(wi * r for wi, r in zip(w, rows)) + w.sum() * aux
+
+    (loss, _), grads = jax.value_and_grad(
+        lambda p: _system_loss(model, p, tokens, w), has_aux=True)(params)
+    want, want_grads = jax.value_and_grad(want_fn)(params)
+    assert abs(float(loss) - float(want)) <= F32_TOL * max(float(want), 1.0)
+    got, want_leaves = _leaves(grads), _leaves(want_grads)
+    for name, g in got.items():
+        scale = max(np.abs(want_leaves[name]).max(), 1e-3)
+        assert np.abs(g - want_leaves[name]).max() <= 10 * F32_TOL * scale, name
+    if not w.any():
+        assert float(loss) == 0.0
+        assert all(not g.any() for g in got.values())
+
+
+def test_a_masked_rows_tokens_do_not_reach_a_dense_models_gradients():
+    import jax
+    cfg, pipeline, _ = _files()
+    model = pipeline.build_model(_dense(cfg))
+    tokens = _tokens(cfg, 4, seed=4)
+    params = _params(model, tokens, "uniform")
+    w = np.asarray([0.5, 0.5, 0.0, 0.0], np.float32)
+    other = tokens.copy()
+    other[2:] = _tokens(cfg, 2, seed=9)
+    grad = jax.grad(lambda p, t: _system_loss(model, p, t, w)[0])
+    a, b = _leaves(grad(params, tokens)), _leaves(grad(params, other))
+    halves = _leaves(jax.grad(lambda p: _system_loss(
+        model, p, tokens[:2])[0])(params))
+    for name in a:
+        np.testing.assert_array_equal(a[name], b[name], err_msg=name)
+        scale = max(np.abs(halves[name]).max(), 1e-3)
+        assert np.abs(a[name] - halves[name]).max() <= 10 * F32_TOL * scale
 
 
 def _token_frame(session, tmp_path, cfg, pipeline, rows, seed):
@@ -252,6 +323,93 @@ def test_an_expert_sharded_fit_gives_the_single_device_losses(
             mu = state.opt_state[1][0].mu["block_0"]["moe"]["experts_gate"]
             assert mu.sharding.spec[0] == "expert"
     np.testing.assert_allclose(losses["four"], losses["one"], rtol=1e-5)
+
+
+@pytest.mark.parametrize("model_name,counted", [("lm", 1), ("dlrm", 0)])
+def test_the_head_loss_counter_counts_a_built_step_of_a_model_with_a_loss(
+        model_name, counted):
+    """``train_head_loss_total{forward_grad}``: once a built train step whose
+    model was handed the rows' weights; a model without ``loss_rows`` takes
+    the estimator's loss and is not counted."""
+    from raydp_tpu.models import DLRM, criteo_batch_preprocessor
+    from raydp_tpu.train.flax_estimator import (_make_apply, _make_train_step,
+                                                _resolve_loss)
+    if model_name == "lm":
+        cfg, pipeline, _ = _files()
+        model, split = pipeline.build_model(cfg), (
+            lambda b: (b["tokens"], b["tokens"]))
+    else:
+        model, split = DLRM(categorical_sizes=[16, 8], num_dense=4,
+                            embedding_dim=8, bottom_mlp=(16, 8),
+                            top_mlp=(16, 1)), criteo_batch_preprocessor(4)
+    before = _steps_counted()
+    apply_fn = _make_apply(model, False, split, None)
+    assert apply_fn.model_loss == (model_name == "lm")
+    _make_train_step(apply_fn, _resolve_loss("bce"), [], 1, "none")
+    assert _steps_counted() - before == counted
+
+
+def _plain_rows(model, params, tokens):
+    """Each row's mean next-token cross entropy from the model's plain
+    path: materialised float32 logits, no fused loss."""
+    import optax
+    logits = model.apply({"params": params}, tokens)
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], tokens[:, 1:]).mean(axis=1)
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+def test_a_pad_and_mask_tail_fit_is_the_mean_over_real_rows(
+        session, tmp_path, accum):
+    """Six rows in batches of four over ``data`` 2, ``drop_last=False``: the
+    second batch is two real rows and two pad rows under a mask. The step
+    hands the model ``mask / sum(mask)`` and differentiates the scalar it
+    gets back; losses, eval loss and parameters are those of a hand-written
+    loop over the plain loss's mean over the REAL rows (what the step's
+    ``_masked_mean`` of the rows gave before), with ``accum_steps`` 2 too
+    (the tail's second microbatch is all pad), and the step is counted
+    once."""
+    import jax
+    import optax
+    from raydp_tpu.parallel import make_mesh
+
+    cfg, pipeline, _ = _files()
+    _dense(cfg)
+    df, info, table = _token_frame(session, tmp_path, cfg, pipeline, 6, 7)
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    before = _steps_counted()
+    est = _estimator(cfg, pipeline, info, mesh, num_epochs=2,
+                     accum_steps=accum, drop_last=False)
+    history = est.fit_on_frame(df, evaluate_df=df).history
+    assert _steps_counted() == before + 1
+    assert [e["steps"] for e in history] == [2, 2]
+
+    tokens = pipeline.reference_inputs(table, info)
+    model = est._build_model()
+    tx = pipeline.build_optimizer(cfg)
+    params = jax.tree.map(np.asarray, model.init(
+        jax.random.PRNGKey(0), tokens[:1])["params"])
+    opt_state = tx.init(params)
+    grad = jax.value_and_grad(
+        lambda p, t: _plain_rows(model, p, t).mean())
+    want, want_eval = [], []
+    for _ in range(2):
+        losses = []
+        for batch in (tokens[:4], tokens[4:]):
+            loss, g = grad(params, batch)
+            losses.append(float(loss))
+            updates, opt_state = tx.update(g, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        want.append(np.mean(losses))
+        want_eval.append(float(_plain_rows(model, params, tokens).mean()))
+    np.testing.assert_allclose([e["train_loss"] for e in history], want,
+                               rtol=2e-4)
+    np.testing.assert_allclose([e["eval_loss"] for e in history], want_eval,
+                               rtol=2e-4)
+    got = _leaves(jax.tree.map(np.asarray, est.get_state().params))
+    for name, leaf in _leaves(params).items():
+        np.testing.assert_allclose(got[name], leaf, rtol=2e-3, atol=2e-5,
+                                   err_msg=name)
 
 
 @pytest.mark.parametrize("mesh_spec,path,shape,role,spec", [

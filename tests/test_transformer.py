@@ -306,6 +306,126 @@ def test_lm_loss_fused_matches_materialized():
                                    err_msg=str(path))
 
 
+# the fused head loss against the plain loss on materialised logits: float32
+# both sides, what is left is summation order (the tolerances of the test
+# above); with bfloat16 hidden states the logits are the same float32
+# accumulations and the gradients differ by one rounding to bfloat16
+_F32 = dict(rtol=2e-4, atol=1e-6)
+_BF16_REL = 2.0 ** -8
+
+
+def _head_case(dtype, B=3, T=37, D=16, V=97, seed=0):
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    hidden = jnp.asarray(rng.randn(B, T, D), dtype)
+    kernel = jnp.asarray(rng.randn(D, V) * 0.3, jnp.float32)
+    tokens = jnp.asarray(rng.randint(0, V, size=(B, T)), jnp.int32)
+    return hidden, kernel, tokens
+
+
+def _plain_head_loss(hidden, kernel, tokens, weights):
+    """Materialised float32 logits (the head's product as the model's plain
+    path makes it), each row's mean cross entropy, the weighted sum."""
+    import jax.numpy as jnp
+    import optax
+
+    logits = jnp.dot(hidden, kernel.astype(hidden.dtype),
+                     preferred_element_type=jnp.float32)
+    rows = optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], tokens[:, 1:]).mean(axis=1)
+    return jnp.sum(weights * rows), rows
+
+
+@pytest.mark.parametrize("name,weights,chunk,dtype", [
+    ("uniform", [1 / 3, 1 / 3, 1 / 3], 12, "float32"),
+    ("unequal", [0.5, 0.125, 0.375], 12, "float32"),
+    ("pad_row", [0.5, 0.5, 0.0], 12, "float32"),      # mask / sum(mask)
+    ("ragged_chunks", [0.25, 0.25, 0.5], 16, "float32"),  # 36 = 2 x 16 + 4
+    ("one_chunk", [0.25, 0.25, 0.5], 1024, "float32"),    # chunk > T - 1
+    ("bfloat16", [0.5, 0.125, 0.375], 12, "bfloat16"),
+])
+def test_fused_head_loss_value_and_gradients(name, weights, chunk, dtype):
+    """``lm_head_loss`` takes its two gradients inside its forward scan
+    (a custom rule, not autodiff): value, rows, the hidden states' gradient,
+    the kernel's and the weights' equal ``jax.value_and_grad`` of the plain
+    loss on materialised float32 logits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from raydp_tpu.models.transformer import lm_head_loss
+
+    hidden, kernel, tokens = _head_case(jnp.dtype(dtype))
+    weights = jnp.asarray(weights, jnp.float32)
+    (want, want_rows), want_g = jax.value_and_grad(
+        _plain_head_loss, argnums=(0, 1, 3), has_aux=True)(
+            hidden, kernel, tokens, weights)
+    (got, rows), got_g = jax.value_and_grad(
+        lambda h, k, w: lm_head_loss(h, k, tokens, w, chunk=chunk),
+        argnums=(0, 1, 2), has_aux=True)(hidden, kernel, weights)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    np.testing.assert_allclose(rows, want_rows, rtol=1e-5)
+    # not differentiated: the same value from the forward product alone
+    plain, plain_rows = jax.jit(
+        lambda h, k, w: lm_head_loss(h, k, tokens, w, chunk=chunk))(
+            hidden, kernel, weights)
+    np.testing.assert_allclose(float(plain), float(want), rtol=1e-5)
+    np.testing.assert_allclose(plain_rows, want_rows, rtol=1e-5)
+    for g, w in zip(got_g, want_g):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if dtype == "bfloat16":
+            assert np.abs(g - w).max() <= _BF16_REL * np.abs(w).max()
+        else:
+            np.testing.assert_allclose(g, w, **_F32)
+    if name == "pad_row":       # a masked row carries no gradient
+        assert not np.asarray(got_g[0][2]).any()
+        assert np.asarray(got_g[0][0]).any()
+    # the last position predicts nothing
+    assert not np.asarray(got_g[0][:, -1], np.float32).any()
+
+
+def _vocab_products(jaxpr, vocab, scans=()):
+    """``[(enclosing scan eqns, ...)]`` of every ``dot_general`` in the
+    jaxpr (and the jaxprs inside its equations) with a vocabulary-sized
+    dimension among its operands' or its result's."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+            if any(vocab in shape for shape in shapes):
+                found.append(scans)
+        inner = scans + (id(eqn),) if eqn.primitive.name == "scan" else scans
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _vocab_products(sub, vocab, inner)
+    return found
+
+
+@pytest.mark.parametrize("differentiated,products", [(True, 3), (False, 1)])
+def test_fused_head_loss_counts_its_head_products(differentiated, products):
+    """Differentiated, a chunk runs the head's product three times (logits,
+    the hidden states' gradient, the kernel's), all inside ONE scan: nothing
+    is recomputed in a backward scan (the checkpointed scan this replaces
+    had four products in two scans). Not differentiated: the one."""
+    import jax
+    import jax.numpy as jnp
+
+    from raydp_tpu.models.transformer import lm_loss_fused
+
+    hidden, kernel, tokens = _head_case(jnp.float32)
+    loss = lambda h, k: lm_loss_fused(h, k, tokens, chunk=12)  # noqa: E731
+    fn = jax.grad(loss, argnums=(0, 1)) if differentiated else loss
+    found = _vocab_products(jax.make_jaxpr(fn)(hidden, kernel).jaxpr, 97)
+    assert len(found) == products
+    assert len(set(found)) == 1 and len(found[0]) == 1    # one scan holds all
+
+
 def test_return_hidden_registers_head_params():
     """Init THROUGH the hidden path still creates the lm_head kernel, so a
     fused-loss training setup has the full param tree from the start."""
