@@ -52,10 +52,6 @@ EPOCHS = int(os.environ.get("BENCH_EPOCHS", "5"))
 BATCH = int(os.environ.get("BENCH_BATCH", "8192"))
 DLRM_ROWS = int(os.environ.get("BENCH_DLRM_ROWS", "120000"))
 SEQ_LEN = int(os.environ.get("BENCH_SEQ_LEN", "8192"))
-# train steps chained per dispatch (lax.scan): amortises the per-dispatch
-# host cost over this many steps; numerically identical to per-batch
-# dispatch (tests/test_train.py chain parity)
-CHAIN = int(os.environ.get("BENCH_CHAIN", "8"))
 
 CONFIG_ORDER = ["nyctaxi", "transformer", "gbdt", "dlrm", "dlrm_stream",
                 "keras", "gang"]
@@ -156,7 +152,7 @@ def _steady(history):
 def _feed_split(history) -> dict:
     """Aggregate the feed/dispatch/sync wall split the estimator records per
     epoch (host-boundness evidence, round-3 verdict Weak #2), plus the
-    pipeline's thread-side decode/stage/h2d phase split (ISSUE 1: the
+    pipeline's thread-side decode/h2d phase split (ISSUE 1: the
     measured attribution of host staging vs device time; phase walls overlap
     dispatch by design, so they attribute the epoch, they don't sum to it)."""
     rows = [r for r in history[1:] if "feed_time_s" in r]
@@ -170,7 +166,6 @@ def _feed_split(history) -> dict:
     if any(r.get("h2d_time_s") is not None for r in rows):
         out.update(
             decode_s=round(sum(r.get("decode_time_s", 0.0) for r in rows), 2),
-            stage_s=round(sum(r.get("stage_time_s", 0.0) for r in rows), 2),
             h2d_s=round(sum(r.get("h2d_time_s", 0.0) for r in rows), 2),
         )
     return out
@@ -211,7 +206,6 @@ def bench_nyctaxi() -> dict:
             batch_size=BATCH,
             num_epochs=EPOCHS,
             shuffle=True,
-            steps_per_dispatch=CHAIN,
         )
         t0 = time.perf_counter()
         result = est.fit_on_frame(data)
@@ -261,7 +255,6 @@ def bench_dlrm() -> dict:
             batch_size=min(4096, BATCH),
             num_epochs=max(STEADY_EPOCHS, 4),
             batch_preprocessor=criteo_batch_preprocessor(NUM_DENSE),
-            steps_per_dispatch=CHAIN,
         )
         result = est.fit_on_frame(df)
         wall = time.perf_counter() - t_etl
@@ -276,8 +269,8 @@ def bench_dlrm() -> dict:
 # ---------------------------------------------------------------- dlrm_stream
 def bench_dlrm_stream() -> dict:
     """The HBM-overflow regime: the residency gate forced off, so training
-    runs through the streaming DeviceFeed (background host decode + chained
-    per-dispatch transfers) instead of the resident epoch cache — the
+    runs through the streaming DeviceFeed (background host decode and
+    per-batch transfers) instead of the resident epoch cache — the
     realistic Criteo-at-scale case where the dataset cannot live in HBM
     (reference examples/pytorch_dlrm.ipynb). The
     feed/dispatch/sync split in the entry is the host-boundness evidence."""
@@ -322,8 +315,7 @@ def bench_keras() -> dict:
             model_builder=build, optimizer="adam", loss="mse",
             feature_columns=features, label_column=LABEL,
             batch_size=min(BATCH, 4096), num_epochs=epochs,
-            data_parallel=_num_chips() > 1,
-            steps_per_dispatch=CHAIN)
+            data_parallel=_num_chips() > 1)
         t0 = time.perf_counter()
         result = est.fit_on_frame(data)
         wall = time.perf_counter() - t0
@@ -407,7 +399,7 @@ def bench_gang() -> dict:
     train-loop delta (``collective_mechanism_ratio`` ≈ 1.9-2.0) — so the
     in-run microbench now measures 1/2/4 ranks (the 4-rank leg replaces the
     old extrapolation) and the per-rank histories
-    carry the feed pipeline's decode/stage/h2d split, so the residual
+    carry the feed pipeline's decode/h2d split, so the residual
     half is attributed by measurement (host-side staging/dispatch
     serialization vs collective latency) instead of narrated away. It is
     NOT duplicated per-rank decode: the steady clock excludes the compile
@@ -455,7 +447,6 @@ def bench_gang() -> dict:
                 batch_size=min(BATCH, 4096),
                 num_epochs=3,
                 shuffle=False,
-                steps_per_dispatch=CHAIN,
             )
             t0 = time.perf_counter()
             result = est.fit_gang(
@@ -546,7 +537,7 @@ def bench_gang() -> dict:
             "benchmarks/gang_collective_microbench.py) near 1 means the "
             "loss IS cross-process all-reduce latency; r5 recorded ~2, i.e. "
             "half the loss sits outside the collective mechanism — the "
-            "per-width decode/stage/h2d/dispatch/sync split in 'sweep' "
+            "per-width decode/h2d/dispatch/sync split in 'sweep' "
             "attributes that residual (duplicated decode would show in "
             "decode_s, host dispatch serialization in dispatch_s). feed_s "
             "~0 and the first_epoch/steady split rule out re-decode and "

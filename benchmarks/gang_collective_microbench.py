@@ -19,7 +19,7 @@ ms/step, while the recorded train-loop 2-rank steady delta is ~190-200
 ms/step — the collective mechanism accounts for roughly HALF the observed
 loss (``collective_mechanism_ratio`` ≈ 1.9-2.0), not all of it. The
 remainder was previously unattributed; the train loop now reports a
-per-phase feed split (``decode/stage/h2d`` beside ``dispatch/sync``, see
+per-phase feed split (``decode/h2d`` beside ``dispatch/sync``, see
 raydp_tpu/data/feed.py) so the residual shows up as measured host-side
 phases instead of a guess, and ``measure(4)`` below adds the 4-rank leg the
 r5 record explained only by extrapolation. On a real multi-host TPU mesh

@@ -8,7 +8,7 @@ bench workloads: NYCTaxi (25 f64 cols -> f32) and Criteo DLRM dense+cats
 
 ``--overlap`` runs the async double-buffered device feed (DevicePrefetcher,
 raydp_tpu/data/feed.py) against a jitted per-batch compute and records the
-per-phase split (decode/stage/h2d vs compute): the pipelined wall-clock
+per-phase split (decode/h2d vs compute): the pipelined wall-clock
 coming in UNDER the sum of the phase walls is the direct evidence that
 host staging and H2D placement are hidden behind device compute. The
 record is persisted to ``benchmarks/HOST_DECODE_DETAIL.json``
@@ -79,10 +79,10 @@ class _TableDataset:
         return self._tables[i]
 
 
-def overlap_run(rows=400_000, batch=8192, chain=4, hidden=256, layers=2,
+def overlap_run(rows=400_000, batch=8192, hidden=256, layers=2,
                 prefetch_to_device=2, out_path=None):
     """One epoch of the streaming pipeline against a jitted MLP-shaped
-    compute: per-phase walls (decode/stage/h2d from the feed's thread-side
+    compute: per-phase walls (decode/h2d from the feed's thread-side
     timers, compute on the consumer clock) vs the pipeline wall-clock.
 
     ``overlap_hidden_s = sum(phases) - wall`` > 0 means the host phases ran
@@ -115,35 +115,26 @@ def overlap_run(rows=400_000, batch=8192, chain=4, hidden=256, layers=2,
             h = jnp.tanh(h @ w2)
         return h.sum()
 
-    # warm the compile outside the timed window (the chained path folds the
-    # [k, B, C] stack into one [k*B, C] matmul batch)
-    warm_rows = batch * (chain if chain > 1 else 1)
-    jax.block_until_ready(compute(jnp.zeros((warm_rows, n_cols),
-                                            jnp.float32)))
+    # warm the compile outside the timed window
+    jax.block_until_ready(compute(jnp.zeros((batch, n_cols), jnp.float32)))
 
     compute_s = 0.0
     steps = 0
     t_wall = time.perf_counter()
-    for item, k in feed.chained(chain):
+    for item in feed:
         t0 = time.perf_counter()
-        feats = item["features"]
-        if feats.ndim == 3:   # stacked [k, B, C] chain (k may be 1 on the
-            # epoch tail): fold the scan dim
-            feats = feats.reshape((-1, feats.shape[-1]))
-        jax.block_until_ready(compute(feats))
+        jax.block_until_ready(compute(item["features"]))
         compute_s += time.perf_counter() - t0
-        steps += k
+        steps += 1
     wall = time.perf_counter() - t_wall
     phases = feed.timings.take()
-    sum_phases = (phases["decode"] + phases["stage"] + phases["h2d"]
-                  + compute_s)
+    sum_phases = phases["decode"] + phases["h2d"] + compute_s
     record = {
-        "rows": rows, "batch": batch, "chain": chain,
+        "rows": rows, "batch": batch,
         "prefetch_to_device": prefetch_to_device, "steps": steps,
         "platform": jax.devices()[0].platform,
         "wall_s": round(wall, 3),
         "decode_s": round(phases["decode"], 3),
-        "stage_s": round(phases["stage"], 3),
         "h2d_s": round(phases["h2d"], 3),
         "compute_s": round(compute_s, 3),
         "sum_phases_s": round(sum_phases, 3),

@@ -20,7 +20,7 @@ mesh-matrix tests run), each a fresh session:
    k+1 overlaps the jitted step of batch k) vs synchronous placement
    (``prefetch_to_device=0``). The prefetching epoch must not be slower,
    and the overlap must be visible: the feed-thread phase walls
-   (decode/stage/h2d) plus dispatch exceed the epoch wall only when the
+   (decode/h2d) plus dispatch exceed the epoch wall only when the
    phases actually ran concurrently.
 
 ``--smoke`` shrinks the model/rows, writes to /tmp (never the recorded
@@ -186,7 +186,6 @@ def run_overlap_config(smoke):
                 "epoch_time_s": round(h["epoch_time_s"], 4),
                 "dispatch_time_s": round(h["dispatch_time_s"], 4),
                 "feed_thread_s": round(h["decode_time_s"]
-                                       + h["stage_time_s"]
                                        + h["h2d_time_s"], 4),
                 "samples_per_s": round(h["samples_per_s"], 1),
                 "train_loss": round(float(h["train_loss"]), 6),

@@ -13,10 +13,10 @@ under jit.
 The streaming pipeline is ASYNC and DOUBLE-BUFFERED (:class:`DevicePrefetcher`):
 a host stage keeps ``prefetch`` decoded batches ahead, and a device stage keeps
 ``prefetch_to_device`` already-``device_put`` batches ahead, so the H2D transfer
-(and the chained path's stack assembly) for batch ``k+1`` overlaps the jitted
-compute of batch ``k``. The reference prefetches only *host* batches; pipelining
-the device side is what removes ``device_put`` from the step critical path.
-Per-phase walls (``decode``/``stage``/``h2d``) accumulate in
+for batch ``k+1`` overlaps the jitted compute of batch ``k``. The reference
+prefetches only *host* batches; pipelining the device side is what removes
+``device_put`` from the step critical path.
+Per-phase walls (``decode``/``h2d``) accumulate in
 :class:`PipelineTimings` and surface in the estimators' epoch reports.
 
 Multi-host: each process feeds its own shard and the global array is built with
@@ -317,6 +317,12 @@ class GangShardIterator:
     reference's per-worker dataset shard (torch/estimator.py:226-241 via
     ``divide_blocks``), strengthened to give bit-identical global batches for
     any world size.
+
+    The rows past the last full global batch are dropped, or with
+    ``pad_remainder`` travel as one more global batch that is zero-padded to
+    full size: every batch then carries this rank's slice of the validity
+    mask (:data:`MASK_KEY`, as :class:`HostBatchIterator` in that mode), and
+    a rank whose slice lies wholly past the last row yields padding alone.
     """
 
     def __init__(
@@ -329,6 +335,7 @@ class GangShardIterator:
         shuffle: bool = False,
         seed: int = 0,
         row_range: Optional[Tuple[int, int]] = None,
+        pad_remainder: bool = False,
     ):
         if not (0 <= rank < world_size):
             raise ValueError(f"rank {rank} out of range for world {world_size}")
@@ -352,6 +359,7 @@ class GangShardIterator:
         self.seed = seed
         self.row_range = (int(lo), int(hi))
         self.per_rank = int(hi) - int(lo)
+        self.pad_remainder = pad_remainder
         self._starts = np.cumsum([0] + list(dataset.block_sizes()))
         self.total = int(self._starts[-1])
         # decoded-block cache across epochs (HostBatchIterator's trick):
@@ -363,6 +371,8 @@ class GangShardIterator:
                               * (1 << 20))
 
     def __len__(self) -> int:
+        if self.pad_remainder:
+            return -(-self.total // self.global_batch)
         return self.total // self.global_batch
 
     def _runs(self, start: int, stop: int) -> List[Tuple[int, int, int]]:
@@ -423,14 +433,17 @@ class GangShardIterator:
             np.random.RandomState(self.seed).shuffle(order)
         for k in order:
             start = int(k) * self.global_batch + self.row_range[0]
-            parts = []
-            for b, off, length in self._runs(start, start + self.per_rank):
-                parts.append(self._decode_run(b, off, length))
-            if len(parts) == 1:
-                yield parts[0]
-            else:
-                yield {n: np.concatenate([p[n] for p in parts], axis=0)
-                       for n in self.columns}
+            # only the padded tail batch ends before its full extent; a slice
+            # wholly past the last row decodes an empty run (shapes, dtypes)
+            stop = min(start + self.per_rank, self.total)
+            runs = self._runs(start, stop) or [(len(self._starts) - 2, 0, 0)]
+            parts = [self._decode_run(b, off, length)
+                     for b, off, length in runs]
+            batch = parts[0] if len(parts) == 1 else {
+                n: np.concatenate([p[n] for p in parts], axis=0)
+                for n in self.columns}
+            yield pad_batch(batch, self.per_rank) \
+                if self.pad_remainder else batch
 
 
 class DeviceEpochCache:
@@ -446,7 +459,7 @@ class DeviceEpochCache:
 
     This replaces, for resident datasets, three O(dataset)-per-epoch host
     costs the streaming path pays: Arrow→numpy feed assembly, the per-epoch
-    executor-side re-shuffle, and one dispatch per chained step. The streaming
+    executor-side re-shuffle, and one dispatch per step. The streaming
     :class:`DeviceFeed` remains the path for datasets above the budget and
     for multi-process gangs (where each process owns only its shard).
     """
@@ -584,13 +597,11 @@ class DeviceEpochCache:
 class PipelineTimings:
     """Thread-safe per-phase wall accumulator for the feed pipeline.
 
-    Phases (surfaced per epoch as ``decode_time_s``/``stage_time_s``/
-    ``h2d_time_s`` by both estimators, aggregated into bench.py's detail
-    record):
+    Phases (surfaced per epoch as ``decode_time_s``/``h2d_time_s`` by both
+    estimators):
 
     - ``decode`` — host batch production: Arrow→numpy decode (native staging
       kernel included) plus the host iterator's own batch assembly.
-    - ``stage``  — dispatch-stack assembly (the chained path's ``np.stack``).
     - ``h2d``    — device placement: ``jax.device_put`` /
       ``make_array_from_process_local_data`` under the feed's sharding.
 
@@ -608,7 +619,7 @@ class PipelineTimings:
     time under ``train:dispatch`` (chipbench's ``idle_dispatch_share``).
     """
 
-    KEYS = ("decode", "stage", "h2d")
+    KEYS = ("decode", "h2d")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -644,10 +655,10 @@ class DevicePrefetcher:
     error cannot leak one producer per epoch. Single-use: one ``iter()`` per
     instance.
 
-    ``pull_key``/``work_key`` name the :class:`PipelineTimings` phases the
-    ``next(src)`` pull and the ``fn`` call accumulate into (the host stage
-    times its pulls as ``decode``; the device stage's placement is timed by
-    the feed so the sync path measures identically). ``pull_span`` names the
+    ``pull_key`` names the :class:`PipelineTimings` phase the ``next(src)``
+    pull accumulates into (the host stage times its pulls as ``decode``; the
+    device stage's placement is timed by the feed so the sync path measures
+    identically). ``pull_span`` names the
     STEP span of that pull (the host stage's is ``feed:decode``; a stage
     whose pull only waits on the stage before it has none). ``count_pulls``
     marks the stage a train loop pulls from: its consumer side counts
@@ -658,7 +669,6 @@ class DevicePrefetcher:
 
     def __init__(self, src, fn=None, depth: int = 2, timings=None,
                  pull_key: Optional[str] = None,
-                 work_key: Optional[str] = None,
                  name: str = "devicefeed-prefetch",
                  pull_span: Optional[str] = None,
                  count_pulls: bool = False):
@@ -666,7 +676,6 @@ class DevicePrefetcher:
         self._fn = fn
         self._timings = timings
         self._pull_key = pull_key
-        self._work_key = work_key
         self._pull_span = pull_span
         self._count_pulls = count_pulls
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
@@ -701,11 +710,7 @@ class DevicePrefetcher:
                     self._timings.add(self._pull_key,
                                       time.perf_counter() - t0)
                 if self._fn is not None:
-                    t1 = time.perf_counter()
                     item = self._fn(item)
-                    if self._timings is not None and self._work_key:
-                        self._timings.add(self._work_key,
-                                          time.perf_counter() - t1)
                 if not self._put(item):
                     break
             self._put(self._DONE)  # no-op if stopped
@@ -715,8 +720,9 @@ class DevicePrefetcher:
             if self._stop.is_set():
                 # stopped early: close() may already have run (and given up
                 # after its join timeout if THIS thread was mid-fn), so the
-                # upstream close falls to us — otherwise a chained host
-                # stage would keep decoding into its full queue forever
+                # upstream close falls to us — otherwise the host stage
+                # before this one would keep decoding into its full queue
+                # forever
                 self._close_src()
 
     def _put(self, item) -> bool:
@@ -739,10 +745,11 @@ class DevicePrefetcher:
         return False
 
     def _close_src(self) -> None:
-        """Best-effort upstream cleanup: a generator src (e.g. the chained
-        host stage's output) closes its own stage in its finally. Both the
-        consumer's close() and the producer's finally may race here —
-        generator.close() raises on the loser, swallowed below."""
+        """Best-effort upstream cleanup: a generator src (e.g. the host
+        stage's output, feeding the device stage) closes its own stage in
+        its finally. Both the consumer's close() and the producer's finally
+        may race here — generator.close() raises on the loser, swallowed
+        below."""
         src_close = getattr(self._src, "close", None)
         if src_close is not None:
             try:
@@ -802,7 +809,7 @@ class DeviceFeed:
     ahead, so H2D for batch ``k+1`` overlaps the compute of batch ``k``;
     ``0`` restores synchronous placement — bit-identical results either way,
     tests/test_feed_pipeline.py). ``timings`` carries the per-phase
-    decode/stage/h2d split the estimators report per epoch."""
+    decode/h2d split the estimators report per epoch."""
 
     def __init__(
         self,
@@ -865,19 +872,17 @@ class DeviceFeed:
             self._base_seed = self.host_iter.seed
         self.host_iter.seed = epoch_seed(self._base_seed, epoch + 1)
 
-    def _place(self, batch: Dict[str, np.ndarray], sharding=None,
-               seq_sharding=None, min_seq_ndim: int = 2):
+    def _place(self, batch: Dict[str, np.ndarray]):
         jax = self._jax
-        if sharding is None:
-            sharding, seq_sharding = self._sharding, self._seq_sharding
+        sharding, seq_sharding = self._sharding, self._seq_sharding
         if sharding is None:
             return {n: jax.device_put(a) for n, a in batch.items()}
 
         def pick(a):
-            # only leaves with a dim past the batch axes carry a sequence
+            # only leaves with a dim past the batch axis carry a sequence
             # dim (labels/masks are 1-D and keep the plain data sharding)
             return seq_sharding if (seq_sharding is not None
-                                    and a.ndim >= min_seq_ndim) else sharding
+                                    and a.ndim >= 2) else sharding
 
         if jax.process_count() > 1:
             return {
@@ -897,86 +902,24 @@ class DeviceFeed:
             pull_span="feed:decode",
             count_pulls=self.prefetch_to_device <= 0))
 
-    def _timed_place(self, batch, sharding=None, **kw):
+    def _timed_place(self, batch):
         t0 = time.perf_counter()
         with profiler.step("feed:h2d"):
-            out = self._place(batch, sharding=sharding, **kw)
+            out = self._place(batch)
         self.timings.add("h2d", time.perf_counter() - t0)
         return out
 
-    def _placed(self, items, place_fn):
-        """Run ``place_fn`` over ``items`` — through the async
+    def __iter__(self):
+        """Placed batches in the host stage's order — through the async
         :class:`DevicePrefetcher` stage when ``prefetch_to_device`` > 0,
         inline otherwise. Same values in the same order either way; the
-        async stage only moves the work off the consumer's critical path."""
+        async stage only moves the placement off the consumer's critical
+        path."""
         if self.prefetch_to_device <= 0:
-            for item in items:
-                yield place_fn(item)
+            for batch in self._host_batches():
+                yield self._timed_place(batch)
             return
         yield from DevicePrefetcher(
-            items, fn=place_fn, depth=self.prefetch_to_device,
-            name="devicefeed-device", count_pulls=True)
-
-    def __iter__(self):
-        yield from self._placed(self._host_batches(), self._timed_place)
-
-    def chained(self, k: int):
-        """Yield ``(placed_stack, n)``: up to ``k`` host batches stacked on a
-        new leading (scan) dim and placed with ONE transfer — the inputs of a
-        ``lax.scan``-chained train dispatch. Every dispatch costs host time,
-        so chaining k steps divides that overhead by k. The scan dim is
-        unsharded; the batch dim keeps the feed's data sharding. A smaller
-        final stack (the epoch remainder) compiles once more and is
-        otherwise fine.
-
-        With ``prefetch_to_device`` > 0 the stack assembly (the ``stage``
-        phase) AND the placement run on the device-prefetch thread, so both
-        overlap the consumer's dispatched compute."""
-        if k <= 1:
-            for batch in self:
-                yield batch, 1
-            return
-        stacked_sharding = stacked_seq = None
-        if self._sharding is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            stacked_sharding = NamedSharding(
-                self.mesh, PartitionSpec(None, *tuple(self._sharding.spec)))
-            if self._seq_sharding is not None:
-                stacked_seq = NamedSharding(
-                    self.mesh,
-                    PartitionSpec(None, *tuple(self._seq_sharding.spec)))
-
-        def _rows(b: Dict[str, np.ndarray]) -> int:
-            return next(iter(b.values())).shape[0]
-
-        def _stack(buf):
-            t0 = time.perf_counter()
-            stacked = {n: np.stack([b[n] for b in buf]) for n in buf[0]}
-            self.timings.add("stage", time.perf_counter() - t0)
-            return stacked, len(buf)
-
-        def _stacks():
-            buf: List[Dict[str, np.ndarray]] = []
-            for batch in self._host_batches():
-                if buf and _rows(batch) != _rows(buf[0]):
-                    # ragged batch (the drop_remainder=False epoch tail): it
-                    # cannot stack with full batches — flush what we have,
-                    # then let it travel alone
-                    yield _stack(buf)
-                    buf = []
-                buf.append(batch)
-                if len(buf) == k:
-                    yield _stack(buf)
-                    buf = []
-            if buf:
-                yield _stack(buf)
-
-        def _place_stack(item):
-            stacked, n = item
-            # the stack dim shifts everything right: a seq dim now sits at
-            # axis 2, and a stacked 1-D label is ndim-2 — hence the 3 floor
-            return self._timed_place(stacked, sharding=stacked_sharding,
-                                     seq_sharding=stacked_seq,
-                                     min_seq_ndim=3), n
-
-        yield from self._placed(_stacks(), _place_stack)
+            self._host_batches(), fn=self._timed_place,
+            depth=self.prefetch_to_device, name="devicefeed-device",
+            count_pulls=True)

@@ -274,7 +274,7 @@ _ALL_METRICS = [
        "(exactly-once replay rounds; each replayed epoch counts once)."),
     # ---- data feed / training -----------------------------------------------
     _m("feed_phase_seconds", HISTOGRAM, "s", "feed",
-       "Feed-pipeline phase walls (decode / stage / h2d), one observation "
+       "Feed-pipeline phase walls (decode / h2d), one observation "
        "per timed section — the registry twin of PipelineTimings.",
        label="phase"),
     _m("feed_staged_tables_total", COUNTER, "1", "feed",
@@ -426,9 +426,9 @@ _ALL_SPANS = [
        "(stage-stacked shard_map GPipe) train step — the train:accum twin "
        "for stage>1 fits."),
     _s("train:first_dispatch", "training",
-       "The fit's first call of its jitted step program (train step, chained "
-       "steps or resident epoch): trace, lower, compile or compile-cache "
-       "load — the synchronous part of the first call."),
+       "The fit's first call of its jitted step program (train step or "
+       "resident epoch): trace, lower, compile or compile-cache load — the "
+       "synchronous part of the first call."),
     _s("train:epoch", "training",
        "One epoch of the train loop, loop top to after the callbacks (args: "
        "epoch, steps); epoch 0 holds train:first_dispatch."),

@@ -580,7 +580,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         compute_dtype=None,
         drop_last: bool = True,
         callbacks: Optional[Sequence[Callable[[Dict], None]]] = None,
-        steps_per_dispatch: int = 1,
         checkpoint_interval: int = 1,
         prefetch_to_device: Optional[int] = None,
         accum_steps: Optional[int] = None,
@@ -612,11 +611,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         self.compute_dtype = compute_dtype
         self.drop_last = drop_last
         self.callbacks = list(callbacks or [])
-        #: chain this many train steps inside ONE jitted dispatch (lax.scan
-        #: over a stacked batch). Numerically identical to dispatching each
-        #: batch (same update sequence); the win is k× fewer dispatches, each
-        #: of which costs host time a small step cannot hide.
-        self.steps_per_dispatch = max(1, int(steps_per_dispatch))
         #: checkpoint every N-th epoch (the final epoch always saves). The
         #: reference checkpoints per epoch (default 1 keeps that); with the
         #: device-resident path an epoch can be cheaper than its checkpoint,
@@ -760,6 +754,13 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             "label": (self.label_column, self.label_dtype),
         }
 
+    def _may_pad_tail(self) -> bool:
+        """Can a ragged tail pad to a full batch and mask its pad rows out?
+        ``RDT_TRAIN_PAD_TAIL=0`` or a loss that takes no mask says no, and
+        the tail is dropped where it cannot travel as it is."""
+        return bool(knobs.get("RDT_TRAIN_PAD_TAIL")) \
+            and _loss_takes_mask(self._loss)
+
     def _split_batch(self, batch: Dict):
         if self.batch_preprocessor is not None:
             return self.batch_preprocessor(batch)
@@ -786,9 +787,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         from raydp_tpu.parallel.mesh import data_axes, stage_extent
         dp_total = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
         stage_total = stage_extent(mesh)
-        pad_tail = ((dp_total > 1 or stage_total > 1)
-                    and bool(knobs.get("RDT_TRAIN_PAD_TAIL"))
-                    and _loss_takes_mask(self._loss))
+        pad_tail = (dp_total > 1 or stage_total > 1) \
+            and self._may_pad_tail()
         use_seq = self._use_seq(mesh)
 
         # device-resident fast path: dataset pinned in HBM, whole epoch in one
@@ -947,11 +947,10 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # backend_compile monitoring event: 1 for the pair, either order).
         # Best-effort: some backends lack the analysis, and telemetry must
         # never fail (or slow an un-engaged) fit.
-        measured = [accum <= 1 and step_remat == "none" and not pipelined]
+        engaged = accum > 1 or step_remat != "none" or pipelined
         _compile_span = "train:pipeline" if pipelined else "train:accum"
 
         def _note_activation(fn, *args):
-            measured[0] = True
             try:
                 with profiler.trace(_compile_span, "training"):
                     mem = fn.lower(*args).compile().memory_analysis()
@@ -992,31 +991,17 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         first_dispatch = [True]
 
         def _dispatch(fn, *args):
-            """Call the fit's step program; its first call (trace, lower,
-            compile or compile-cache load, all synchronous) is a span."""
-            if first_dispatch[0]:
-                first_dispatch[0] = False
-                with profiler.trace("train:first_dispatch", "training"):
-                    return fn(*args)
-            return fn(*args)
-
-        chain = self.steps_per_dispatch
-        jit_chain = None
-        if chain > 1 and cache is None:
-            from jax import lax
-
-            def train_chain(state, batches, mstats, loss_sum):
-                def body(carry, batch):
-                    state, loss_sum, mstats = carry
-                    state, loss_sum, mstats = train_step(
-                        state, batch, mstats, loss_sum)
-                    return (state, loss_sum, mstats), ()
-
-                (state, loss_sum, mstats), _ = lax.scan(
-                    body, (state, loss_sum, mstats), batches)
-                return state, loss_sum, mstats
-
-            jit_chain = jax.jit(train_chain, donate_argnums=(0, 3))
+            """Call the fit's step program (the resident epoch scan or the
+            streaming step). Its first call (trace, lower, compile or
+            compile-cache load, all synchronous) is a span, and is where an
+            engaged activation plane publishes the step's temp bytes."""
+            if not first_dispatch[0]:
+                return fn(*args)
+            first_dispatch[0] = False
+            if engaged:
+                _note_activation(fn, *args)
+            with profiler.trace("train:first_dispatch", "training"):
+                return fn(*args)
 
         jit_epoch = None
         cache_steps = 0
@@ -1105,10 +1090,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                         ekey = jax.random.fold_in(
                             jax.random.PRNGKey(self.seed), epoch)
                         with step_span("train:dispatch"):
-                            if not measured[0]:
-                                _note_activation(
-                                    jit_epoch, (state, loss_sum, mstats),
-                                    cache.arrays, ekey)
                             state, loss_sum, mstats = _dispatch(
                                 jit_epoch, (state, loss_sum, mstats),
                                 cache.arrays, ekey)
@@ -1123,37 +1104,21 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                         samples = cache_steps * self.batch_size
                     else:
                         feed.set_epoch(epoch)
-                        it = feed.chained(chain) if chain > 1 else iter(feed)
+                        it = iter(feed)
                         while True:
                             tf = time.perf_counter()
                             with step_span("train:feed_wait"):
-                                item = next(it, None)
+                                batch = next(it, None)
                             t_feed += time.perf_counter() - tf
-                            if item is None:
+                            if batch is None:
                                 break
                             td = time.perf_counter()
                             with step_span("train:dispatch"):
-                                if chain > 1:
-                                    batches, k = item
-                                    if not measured[0]:
-                                        _note_activation(
-                                            jit_chain, state, batches,
-                                            mstats, loss_sum)
-                                    state, loss_sum, mstats = _dispatch(
-                                        jit_chain, state, batches, mstats,
-                                        loss_sum)
-                                else:
-                                    k = 1
-                                    if not measured[0]:
-                                        _note_activation(
-                                            jit_train, state, item, mstats,
-                                            loss_sum)
-                                    state, loss_sum, mstats = _dispatch(
-                                        jit_train, state, item, mstats,
-                                        loss_sum)
+                                state, loss_sum, mstats = _dispatch(
+                                    jit_train, state, batch, mstats, loss_sum)
                             t_disp += time.perf_counter() - td
-                            steps += k
-                            samples += self.batch_size * k
+                            steps += 1
+                            samples += self.batch_size
                     with step_span("train:epoch_end"):
                         # fetch the accumulated loss BEFORE reading the
                         # clock: dispatch is async, so only a host scalar
@@ -1167,8 +1132,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                         # sees epoch walls without re-publishing the history
                         # dicts)
                         rdt_metrics.observe("train_epoch_seconds", dt)
-                        # the feed's thread-side phase split (decode/stage/
-                        # h2d): these walls OVERLAP dispatch by design (that
+                        # the feed's thread-side phase split (decode, h2d):
+                        # these walls OVERLAP dispatch by design (that
                         # is the prefetch win), so they attribute the epoch,
                         # they don't sum to it
                         pipe = feed.timings.take() if feed is not None else {}
@@ -1180,7 +1145,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                             "epoch_time_s": dt,
                             "feed_time_s": t_feed,
                             "decode_time_s": pipe.get("decode", 0.0),
-                            "stage_time_s": pipe.get("stage", 0.0),
                             "h2d_time_s": pipe.get("h2d", 0.0),
                             "dispatch_time_s": t_disp,
                             "sync_time_s": t_sync,
@@ -1277,7 +1241,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
     # ------------------------------------------------------------ partial_fit
     def _partial_fit_epoch(self, ds, epoch: int) -> Dict[str, float]:
         """One online update: a single gradient pass over the epoch's rows
-        through the streaming ``DeviceFeed`` (decode/stage/H2D prefetch
+        through the streaming ``DeviceFeed`` (decode and H2D prefetch
         overlap the jitted steps, as in ``fit``). State persists on the
         estimator across epochs; ``self._result`` tracks it so
         ``get_model``/``export_serving`` work mid-stream."""
@@ -1336,7 +1300,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         """Build the persistent online-training state from the first
         epoch's schema: model/optimizer init, sharded placement, and the
         jitted train step (the same step shape as ``fit``'s, without the
-        chaining/device-resident variants — a stream epoch is small).
+        device-resident variant — a stream epoch is small).
         None when the epoch holds no rows to init from."""
         import jax
         import jax.numpy as jnp
@@ -1393,9 +1357,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # than one batch — dropping its tail silently skipped whole
         # micro-batches); RDT_TRAIN_PAD_TAIL=0 or a mask-blind custom loss
         # restores drop
-        pad_tail = ((dp_total > 1 or n_stages > 1)
-                    and bool(knobs.get("RDT_TRAIN_PAD_TAIL"))
-                    and _loss_takes_mask(self._loss))
+        pad_tail = (dp_total > 1 or n_stages > 1) and self._may_pad_tail()
         return {
             "mesh": mesh,
             "columns": columns,
@@ -1538,6 +1500,9 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         eval_feed = None
         if eval_payload is not None:
             eval_ds = DistributedDataset.from_portable(eval_payload)
+            # the ragged eval tail pads and masks, as in fit(): the gang's
+            # eval mean is over every row, like the single process's (where
+            # padding is opted out, or the loss takes no mask, it is dropped)
             eval_feed = DeviceFeed(
                 eval_ds, self.batch_size, columns, mesh=mesh,
                 prefetch_to_device=self.prefetch_to_device,
@@ -1545,7 +1510,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                 host_iter=GangShardIterator(
                     eval_ds, self.batch_size, ctx.world_size, ctx.rank,
                     columns, shuffle=False, seed=self.seed,
-                    row_range=row_range))
+                    row_range=row_range,
+                    pad_remainder=self._may_pad_tail()))
 
         state, history = self._train_loop(mesh, feed, eval_feed, ckpt_dir,
                                           max_retries=0, resume=True)

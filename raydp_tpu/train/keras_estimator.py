@@ -83,7 +83,6 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
         label_dtype=np.float32,
         drop_last: bool = True,
         fit_kwargs: Optional[Dict] = None,
-        steps_per_dispatch: int = 1,
         checkpoint_interval: int = 1,
         prefetch_to_device: Optional[int] = None,
     ):
@@ -111,10 +110,6 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
         self.label_dtype = label_dtype
         self.drop_last = drop_last
         self.fit_kwargs = dict(fit_kwargs or {})
-        #: chain k train steps per jitted dispatch (lax.scan over a stacked
-        #: batch) — k× fewer host→device round trips, numerically identical
-        #: (see FlaxEstimator.steps_per_dispatch)
-        self.steps_per_dispatch = max(1, int(steps_per_dispatch))
         #: checkpoint every N-th epoch, final epoch always (see the flax
         #: twin; model.save of a keras archive can outweigh a resident epoch)
         self.checkpoint_interval = max(1, int(checkpoint_interval))
@@ -456,21 +451,6 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
         jit_train = jax.jit(train_step, donate_argnums=(0, 1, 2, 3, 4))
         jit_eval = jax.jit(eval_step, donate_argnums=(2, 3))
 
-        chain = self.steps_per_dispatch
-        jit_chain = None
-        if chain > 1 and cache is None:
-            from jax import lax
-
-            def train_chain(tv, ntv, ov, mvars, loss_sum, batches):
-                def body(carry, batch):
-                    return train_step(*carry, batch), ()
-
-                carry, _ = lax.scan(body, (tv, ntv, ov, mvars, loss_sum),
-                                    batches)
-                return carry
-
-            jit_chain = jax.jit(train_chain, donate_argnums=(0, 1, 2, 3, 4))
-
         jit_epoch = None
         cache_steps = 0
         if cache is not None:
@@ -551,24 +531,19 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
                     samples = cache_steps * self.batch_size
                 else:
                     feed.set_epoch(epoch)
-                    it = feed.chained(chain)
+                    it = iter(feed)
                     while True:
                         tf = _time.perf_counter()
-                        nxt = next(it, None)
+                        batch = next(it, None)
                         t_feed += _time.perf_counter() - tf
-                        if nxt is None:
+                        if batch is None:
                             break
-                        item, k = nxt
                         td = _time.perf_counter()
-                        if chain > 1:  # item is a [k, B, ...] stack, at k=1 too
-                            tv, ntv, ov, mvars, loss_sum = jit_chain(
-                                tv, ntv, ov, mvars, loss_sum, item)
-                        else:
-                            tv, ntv, ov, mvars, loss_sum = jit_train(
-                                tv, ntv, ov, mvars, loss_sum, item)
+                        tv, ntv, ov, mvars, loss_sum = jit_train(
+                            tv, ntv, ov, mvars, loss_sum, batch)
                         t_disp += _time.perf_counter() - td
-                        steps += k
-                        samples += self.batch_size * k
+                        steps += 1
+                        samples += self.batch_size
                 # fetch the loss scalar BEFORE reading the clock: dispatch is
                 # async, so only a host fetch makes the epoch wall include
                 # the device work (stable across runs; see flax_estimator)
@@ -579,7 +554,7 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
                 # registry twin of the epoch report (see the flax estimator)
                 from raydp_tpu import metrics as rdt_metrics
                 rdt_metrics.observe("train_epoch_seconds", dt)
-                # the feed's thread-side decode/stage/h2d split — these walls
+                # the feed's thread-side decode / h2d split — these walls
                 # OVERLAP dispatch (the prefetch win), see the flax twin
                 pipe = feed.timings.take() if feed is not None else {}
                 report = {
@@ -589,7 +564,6 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
                     "samples_per_s": samples / dt if dt > 0 else 0.0,
                     "feed_time_s": t_feed,
                     "decode_time_s": pipe.get("decode", 0.0),
-                    "stage_time_s": pipe.get("stage", 0.0),
                     "h2d_time_s": pipe.get("h2d", 0.0),
                     "dispatch_time_s": t_disp,
                     "sync_time_s": t_sync,

@@ -5,7 +5,7 @@ Contract pinned here: the device-side prefetch stage only moves host
 staging + ``device_put`` OFF the consumer's critical path — it must never
 reorder, drop, or alter a batch (``prefetch_to_device=2`` bit-identical to
 ``=0`` through both estimators), it must propagate producer errors and shut
-its threads down on early exit, and the ``decode/stage/h2d`` timers it
+its threads down on early exit, and the ``decode/h2d`` timers it
 feeds must surface in the estimators' epoch reports (the measured split
 VERDICT r5 Weak #2 asked for)."""
 
@@ -86,12 +86,9 @@ def _linear_df(session, n=1344):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("chain", [1, 4])
-def test_flax_prefetch_to_device_parity(session, monkeypatch, chain):
+def test_flax_prefetch_to_device_parity(session, monkeypatch):
     """prefetch_to_device=2 must be BIT-IDENTICAL to =0 (same seed, same
-    shuffle): the async stage only overlaps placement with compute — and it
-    must compose with steps_per_dispatch chaining (the stacked path runs
-    through the same prefetcher)."""
+    shuffle): the async stage only overlaps placement with compute."""
     import optax
 
     from raydp_tpu.data import from_frame
@@ -112,7 +109,6 @@ def test_flax_prefetch_to_device_parity(session, monkeypatch, chain):
             num_epochs=2,
             shuffle=True,
             seed=0,
-            steps_per_dispatch=chain,
             prefetch_to_device=p2d,
         )
         return est.fit(ds)
@@ -161,9 +157,8 @@ def test_keras_prefetch_to_device_parity(session, monkeypatch):
 
 @pytest.mark.slow
 def test_timing_split_surfaced_in_reports(session, monkeypatch):
-    """Streaming epochs report a positive decode/stage/h2d split; the
-    device-resident path reports zeros (nothing streamed). These keys are
-    what bench.py aggregates into the detail record's per-phase split."""
+    """Streaming epochs report a positive decode/h2d split; the
+    device-resident path reports zeros (nothing streamed)."""
     import optax
 
     from raydp_tpu.data import from_frame
@@ -177,22 +172,19 @@ def test_timing_split_surfaced_in_reports(session, monkeypatch):
             model=MLP(features=(8,), use_batch_norm=False),
             optimizer=optax.adam(1e-2), loss="mse",
             feature_columns=["x1", "x2"], label_column="y",
-            batch_size=64, num_epochs=2, shuffle=False,
-            steps_per_dispatch=4)
+            batch_size=64, num_epochs=2, shuffle=False)
         return est.fit(ds)
 
     monkeypatch.setenv("RDT_DEVICE_CACHE", "0")
     streamed = run()
     for r in streamed.history:
         assert r["decode_time_s"] > 0.0
-        assert r["stage_time_s"] > 0.0  # the chained np.stack assembly
         assert r["h2d_time_s"] > 0.0
 
     monkeypatch.setenv("RDT_DEVICE_CACHE", "1")
     resident = run()
     for r in resident.history:
         assert r["decode_time_s"] == 0.0
-        assert r["stage_time_s"] == 0.0
         assert r["h2d_time_s"] == 0.0
 
 

@@ -27,8 +27,7 @@ def _linear_df(session, n=2048, parts=4):
     return session.createDataFrame(pdf, num_partitions=parts)
 
 
-def _estimator(num_epochs=3, callbacks=None, ckpt_dir=None,
-               steps_per_dispatch=1):
+def _estimator(num_epochs=3, callbacks=None, ckpt_dir=None):
     import optax
 
     return FlaxEstimator(
@@ -42,7 +41,6 @@ def _estimator(num_epochs=3, callbacks=None, ckpt_dir=None,
         shuffle=False,
         checkpoint_dir=ckpt_dir,
         callbacks=callbacks,
-        steps_per_dispatch=steps_per_dispatch,
     )
 
 
@@ -56,11 +54,7 @@ def test_gang_losses_match_single_process(session, tmp_path):
     single = _estimator(ckpt_dir=str(tmp_path / "single"))
     r1 = single.fit(train_ds, test_ds)
 
-    # the gang additionally runs CHAINED dispatch (lax.scan over stacked
-    # batches assembled with make_array_from_process_local_data): matching
-    # the unchained single-process run proves the chain is exact in the
-    # multi-process path too
-    gang = _estimator(ckpt_dir=str(tmp_path / "gang"), steps_per_dispatch=2)
+    gang = _estimator(ckpt_dir=str(tmp_path / "gang"))
     r2 = gang.fit_gang(train_ds, test_ds, num_workers=2, run_timeout=900.0)
 
     assert len(r2.history) == len(r1.history)
@@ -118,19 +112,23 @@ def test_gang_rejects_indivisible_batch():
                           columns={"x": ("x", np.float32)})
 
 
-def test_gang_iterator_covers_rows_exactly_once():
+@pytest.mark.parametrize("sizes, pad", [
+    ([7, 13, 5, 22, 1], False),   # 48 rows: three full global batches
+    ([7, 13, 5, 22, 6], True),    # 53 rows: a fourth, 5 rows and padding
+])
+def test_gang_iterator_covers_rows_exactly_once(sizes, pad):
     """_runs boundary math: every global batch row is read exactly once per
-    epoch, across uneven block boundaries and both ranks."""
-    from raydp_tpu.data.feed import GangShardIterator
+    epoch, across uneven block boundaries and both ranks. With
+    ``pad_remainder`` the rows past the last full batch come too, zero-padded
+    and masked; rank 1's slice of that batch is padding alone."""
+    import pyarrow as pa
 
-    sizes = [7, 13, 5, 22, 1]          # awkward block sizes, total 48
-    rows = np.arange(48, dtype=np.float64)
-    blocks = []
-    start = 0
-    for s in sizes:
-        import pyarrow as pa
-        blocks.append(pa.table({"x": rows[start:start + s]}))
-        start += s
+    from raydp_tpu.data.feed import MASK_KEY, GangShardIterator
+
+    total = sum(sizes)
+    rows = np.arange(total, dtype=np.float64)
+    starts = np.cumsum([0] + sizes)
+    blocks = [pa.table({"x": rows[a:b]}) for a, b in zip(starts, starts[1:])]
 
     class _Ds:
         def block_sizes(self):
@@ -142,13 +140,18 @@ def test_gang_iterator_covers_rows_exactly_once():
     got = []
     for rank in (0, 1):
         it = GangShardIterator(_Ds(), global_batch=16, world_size=2,
-                               rank=rank, columns={"x": ("x", np.float64)})
-        assert len(it) == 3
+                               rank=rank, columns={"x": ("x", np.float64)},
+                               pad_remainder=pad)
+        assert len(it) == (4 if pad else 3)
         for batch in it:
             assert batch["x"].shape == (8,)
-            got.extend(batch["x"].tolist())
-    # 3 global batches x 16 rows = rows 0..47 exactly once across both ranks
-    assert sorted(got) == list(range(48))
+            assert (MASK_KEY in batch) == pad
+            real = batch[MASK_KEY] > 0 if pad else slice(None)
+            got.extend(batch["x"][real].tolist())
+            if pad:
+                assert not batch["x"][~real].any()
+    # every row exactly once across both ranks, and no padding among them
+    assert sorted(got) == list(range(total))
 
 
 def test_gang_iterator_over_cap_decodes_slices_not_blocks(monkeypatch):

@@ -150,52 +150,11 @@ def test_estimator_sharded_batch(session):
     assert result.history[-1]["train_loss"] < result.history[0]["train_loss"]
 
 
-def test_steps_per_dispatch_chain_parity(session, monkeypatch):
-    """Chaining k train steps into one lax.scan dispatch must be numerically
-    IDENTICAL to dispatching each batch: same update sequence, same loss
-    history (the chain only amortizes host->device round trips). Also covers
-    the epoch-remainder stack (steps % k != 0) and BatchNorm state threading
-    through the scan carry."""
-    import optax
-
-    from raydp_tpu.data import from_frame
-
-    df = _linear_df(session, n=1344)  # 21 batches of 64 → 21 % 4 != 0
-    ds = from_frame(df)
-    # pin the STREAMING feed: the device-resident path neither chains nor
-    # streams, which would make this parity check vacuous
-    monkeypatch.setenv("RDT_DEVICE_CACHE", "0")
-
-    def run(chain):
-        est = FlaxEstimator(
-            model=MLP(features=(8,), use_batch_norm=True),
-            optimizer=optax.adam(1e-2),
-            loss="mse",
-            feature_columns=["x1", "x2"],
-            label_column="y",
-            batch_size=64,
-            num_epochs=2,
-            shuffle=False,
-            seed=0,
-            steps_per_dispatch=chain,
-        )
-        return est.fit(ds)
-
-    plain = run(1)
-    chained = run(4)
-    assert [r["steps"] for r in chained.history] == \
-        [r["steps"] for r in plain.history]
-    for a, b in zip(plain.history, chained.history):
-        np.testing.assert_allclose(a["train_loss"], b["train_loss"],
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_steps_per_dispatch_ragged_tail(session):
-    """drop_last=False + chaining: the smaller epoch-tail batch cannot stack
-    with full batches — the feed must flush and send it alone, and training
-    must see every row (code-review r4 finding). A ragged batch only shards
-    on a size-1 data axis (same rule the eval feed applies), so this runs on
-    a single-device mesh."""
+def test_streaming_ragged_tail(session):
+    """drop_last=False on the streaming feed: the smaller epoch-tail batch
+    travels as a step of its own, so training sees every row. A ragged batch
+    only shards on a size-1 data axis (same rule the eval feed applies), so
+    this runs on a single-device mesh."""
     import jax
     import optax
 
@@ -214,7 +173,6 @@ def test_steps_per_dispatch_ragged_tail(session):
         num_epochs=2,
         shuffle=False,
         drop_last=False,
-        steps_per_dispatch=4,
         mesh=make_mesh(MeshSpec(data=1), devices=jax.devices()[:1]),
     )
     result = est.fit(ds)
