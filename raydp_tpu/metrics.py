@@ -304,6 +304,15 @@ _ALL_METRICS = [
        "or dense (the whole table; the fit's log names why: probe, shape, "
        "accum, pipeline). doc/training.md, the row-wise update.",
        label="path"),
+    _m("train_table_walk_total", COUNTER, "1", "training",
+       "Row-wise embedding tables, counted once a built train step, by who "
+       "walks the rows a batch looked up: shard_local (the table's rows are "
+       "split over mesh axes and each shard reads and writes its own slice "
+       "of the batch's distinct ids) or global (every chip that holds a "
+       "part of the table walks all of them: no mesh, rows not split, or a "
+       "step that was not told the state's shardings). doc/training.md, the "
+       "row-wise update.",
+       label="path"),
     _m("train_head_loss_total", COUNTER, "1", "training",
        "Train steps built round a model that brings its own loss "
        "(`loss_rows`), counted once a built step by how the loss is "

@@ -406,7 +406,7 @@ def _make_pipeline_apply(model: "PipelineModel", split_batch, compute_dtype,
 
 
 def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
-                     mb_shardings=None):
+                     mb_shardings=None, state_shardings=None):
     """Build the jitted train-step body shared by ``fit`` and
     ``partial_fit``: one optimizer update from one global batch.
 
@@ -429,6 +429,12 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     accumulation exists for (measured 4× worse peak temp bytes on an 8-way
     mesh). Leaf rule matches the feed's: ndim >= 2 leaves take the
     seq-extended spec, 1-D leaves (labels, masks) the plain batch spec.
+
+    ``state_shardings`` — optional: the shardings the caller placed the state
+    with (``param_sharding_rules(mesh, rules)(state)``; a traced leaf carries
+    no spec of its own). With them a row-wise table whose rows are split over
+    mesh axes is read and written shard by shard (``train/rowwise.py``); not
+    told, every table is walked as one.
     """
     import jax
     import jax.numpy as jnp
@@ -442,7 +448,9 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     if getattr(apply_fn, "model_loss", False):
         # once a built step, like the table counter below
         rdt_metrics.inc("train_head_loss_total", label="forward_grad")
-    counted: list = []      # the table counter is bumped once a built step
+    counted: list = []      # the table counters are bumped once a built step
+    placed = None if state_shardings is None else (
+        state_shardings.params, state_shardings.opt_state)
 
     def _microbatch_grads(params, bstats, batch, mask, inv=None):
         def _loss(p):
@@ -474,20 +482,21 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
                 ids, rowwise.leaf_at(state.params, path).shape[0])
         whole = (state.params, state.opt_state)
         idx = rowwise.index_trees(state.tx, *whole, uniq)
-        view_params, view_opt = rowwise.take_rows(whole, idx)
+        view_params, view_opt = rowwise.take_rows(whole, idx, placed)
         out, grads = _microbatch_grads(view_params, state.batch_stats, batch,
                                        mask, inv)
         new_view = state.replace(
             params=view_params, opt_state=view_opt).apply_gradients(
                 grads=grads)
         new_params, new_opt = rowwise.put_rows(
-            whole, (new_view.params, new_view.opt_state), idx)
+            whole, (new_view.params, new_view.opt_state), idx, placed)
         return new_view.replace(params=new_params, opt_state=new_opt), out
 
     def train_step(state, batch, mstats, loss_sum):
         batch, mask = _strip_mask(batch)
-        tables = rowwise.tables_to_update(apply_fn, state, batch, accum,
-                                          counted)
+        tables = rowwise.tables_to_update(
+            apply_fn, state, batch, accum, counted,
+            getattr(state_shardings, "params", None))
         if accum <= 1:
             new_state, (loss_val, (preds, labels, new_bstats)) = _update(
                 state, batch, mask, tables)
@@ -936,7 +945,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         train_metrics = metrics + model_counters(model)
         train_step = _make_train_step(_apply, loss_fn, train_metrics,
                                       step_accum, step_remat,
-                                      mb_shardings=(b_sharding, seq_sharding))
+                                      mb_shardings=(b_sharding, seq_sharding),
+                                      state_shardings=state_sharding)
 
         # publish the compiled step's peak temp (activation) bytes when the
         # activation plane is engaged — the residency number accumulation/
@@ -1333,8 +1343,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         state = _State.create(apply_fn=model.apply,
                               params=variables["params"], tx=tx,
                               batch_stats=variables.get("batch_stats"))
-        state = self._place_state(
-            state, param_sharding_rules(mesh, self.param_rules)(state))
+        state_sharding = param_sharding_rules(mesh, self.param_rules)(state)
+        state = self._place_state(state, state_sharding)
 
         # the SAME step body as fit()'s (one source): the online path gets
         # gradient accumulation, remat AND pipeline placement for free, and
@@ -1348,7 +1358,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             _apply, loss_fn, metrics, step_accum, step_remat,
             mb_shardings=(batch_sharding(mesh),
                           batch_sharding(mesh, seq=True)
-                          if self._use_seq(mesh) else None))
+                          if self._use_seq(mesh) else None),
+            state_shardings=state_sharding)
 
         dp_total = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
         # the ragged micro-batch tail under a >1 data extent (or a >1 stage

@@ -24,6 +24,17 @@ a row read from or written to a table in HBM costs the chip 50 and 120 ns
 So the lookup and the write-back walk ``uniq`` in passes of :data:`CHUNK`
 rows and stop after the last real one; the fill ids past it are never
 touched.
+
+On a mesh the step is handed the shardings the state was placed with (a
+traced leaf carries none). A table whose rows are split over mesh axes
+(:func:`row_axes`) is then read and written per shard under ``shard_map``:
+``uniq`` is sorted and a shard holds one contiguous range of rows, so its ids
+are one slice of ``uniq``, and it walks that slice only (:func:`shard_rows`).
+One sum over those axes after the walk puts the looked-up rows together; no
+collective runs inside a pass, since the shards make different numbers of
+them. A table that is not split by rows, or a step that was not told, keeps
+the one walk of all of ``uniq``, which the partitioner then runs on every
+chip that holds a part of the table.
 """
 
 from __future__ import annotations
@@ -42,11 +53,14 @@ WHOLE = object()
 #: rows a pass of the lookup and of the write-back handles
 CHUNK = 256
 #: a table this small (bytes of its rows padded to the chip's 128 lanes) is
-#: not walked in passes. The TPU compiler stages such a table through fast
-#: memory (128 MiB on a v5e; a shard of a table sharded two ways counts) in a
-#: lane-padded row-major layout, and a scatter into it then costs a sweep of
-#: it however few rows it writes: six passes ran 1.28 ms against 0.23 ms for
-#: one pass over all B ids (143,091 x 32 float32, PERF.md PR 25).
+#: not walked in passes. The rule reads the table the walk is handed: the
+#: shard a chip holds where the walk runs per shard, the whole table where it
+#: does not (one chip; on a mesh the partitioner then splits what the rule
+#: judged whole). The TPU compiler stages such a table through fast memory
+#: (128 MiB on a v5e) in a lane-padded row-major layout, and a scatter into
+#: it then costs a sweep of it however few rows it writes: six passes ran
+#: 1.28 ms against 0.23 ms for one pass over all B ids (143,091 x 32
+#: float32, PERF.md PR 25).
 STAGED_BYTES = 256 << 20
 
 Path = Tuple[str, ...]
@@ -55,18 +69,20 @@ Path = Tuple[str, ...]
 class Rows:
     """The rows a batch looked up in one table: ``uniq`` ``[B]`` (sorted, the
     real ids first) and how many of them are real. A leaf of an index tree
-    (deliberately no pytree)."""
+    (deliberately no pytree). Inside a shard of the table (:func:`shard_rows`)
+    ``count`` ids from position ``first`` on are the shard's own."""
 
-    __slots__ = ("uniq", "count")
+    __slots__ = ("uniq", "count", "first")
 
-    def __init__(self, uniq, count):
-        self.uniq, self.count = uniq, count
+    def __init__(self, uniq, count, first=None):
+        self.uniq, self.count, self.first = uniq, count, first
 
     def passes(self, table, visit, carry):
         """``carry`` after ``visit(start, ids, carry)`` over ``uniq`` in
-        chunks, as many as hold real ids (one chunk of all of ``uniq`` for a
-        ``table`` under :data:`STAGED_BYTES`). The last chunk is pulled back
-        inside ``uniq``; rows it visits twice get the same values twice."""
+        chunks, as many as hold the ``count`` ids from ``first`` on (one chunk
+        of all of ``uniq`` for a ``table`` under :data:`STAGED_BYTES`). The
+        last chunk is pulled back inside ``uniq``; rows it visits twice get
+        the same values twice."""
         import jax.numpy as jnp
         from jax import lax
 
@@ -77,12 +93,52 @@ class Rows:
             return visit(0, self.uniq, carry)
 
         def body(i, carry):
-            start = jnp.minimum(i * CHUNK, b - CHUNK)
+            start = i * CHUNK if self.first is None \
+                else self.first + i * CHUNK
+            start = jnp.minimum(start, b - CHUNK)
             return visit(start, lax.dynamic_slice(self.uniq, (start,),
                                                   (CHUNK,)), carry)
 
         return lax.fori_loop(0, (self.count + CHUNK - 1) // CHUNK, body,
                              carry)
+
+
+def row_axes(sharding, shape):
+    """The mesh axes over which a leaf's rows are split, where each shard can
+    walk its own: dim 0 sharded (over one axis or several) in equal parts and
+    every other dim whole. ``None`` for anything else: no sharding told (the
+    step was not handed the state's), a mesh of one, dim 0 whole, a
+    column-sharded table."""
+    spec = getattr(sharding, "spec", None)
+    if not spec:
+        return None
+    extent = sharding.mesh.shape
+
+    def split(entry):
+        names = () if entry is None else \
+            entry if isinstance(entry, tuple) else (entry,)
+        return tuple(n for n in names if extent[n] > 1)
+
+    axes = split(spec[0])
+    shards = int(np.prod([extent[n] for n in axes]))
+    if not axes or shape[0] % shards or any(split(e) for e in spec[1:]):
+        return None
+    return axes
+
+
+def shard_rows(uniq, num_local: int, axes) -> Rows:
+    """Inside a ``shard_map`` over ``axes``: the looked-up rows as the shard
+    that holds ``num_local`` rows of the table sees them. Ids count from the
+    shard's first row (negative, or ``num_local`` and more: another shard's);
+    ``uniq`` is sorted and the table split in contiguous ranges, so the
+    shard's own ids are one slice of it, and the walk covers that slice."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    first_id = lax.axis_index(axes) * num_local
+    lo = jnp.sum(uniq < first_id, dtype=jnp.int32)
+    hi = jnp.sum(uniq < first_id + num_local, dtype=jnp.int32)
+    return Rows(uniq - first_id, hi - lo, lo)
 
 
 def _names(path) -> Path:
@@ -102,13 +158,15 @@ def gains(table_shape, ids_shape) -> bool:
     return len(ids_shape) == 1 and table_shape[0] > ids_shape[0]
 
 
-def tables_to_update(apply_fn, state, batch, accum: int, seen: list):
+def tables_to_update(apply_fn, state, batch, accum: int, seen: list,
+                     placed=None):
     """``{parameter path: ids}`` of the declared tables THIS step updates by
     row, decided from what the step can observe: the model's declaration
     (``apply_fn.lookups``, set by the estimator's ``_make_apply``), no
     accumulation or pipeline, the shapes, and the probe of ``state.tx``.
-    Counts every declared table once a built step (``seen``) and logs why the
-    dense ones stayed dense."""
+    Counts every declared table once a built step (``seen``), the row-wise
+    ones also by who walks them (``placed``: the parameters' shardings, where
+    the step was told), and logs why the dense ones stayed dense."""
     lookups = getattr(apply_fn, "lookups", None)
     if lookups is None:
         return {}
@@ -129,6 +187,11 @@ def tables_to_update(apply_fn, state, batch, accum: int, seen: list):
         for path in ids:
             metrics.inc("train_table_updates_total",
                         label="dense" if path in because else "rowwise")
+            if path not in because:
+                local = placed is not None and row_axes(
+                    leaf_at(placed, path), leaf_at(state.params, path).shape)
+                metrics.inc("train_table_walk_total",
+                            label="shard_local" if local else "global")
         if because:
             why: Dict[str, list] = {}
             for path, reason in because.items():
@@ -172,43 +235,95 @@ def index_trees(tx, params, opt_state, uniq: Dict[Path, Rows]):
     return params_idx, state_idx
 
 
-def take_rows(tree, idx):
+def take_rows(tree, idx, placed=None):
     """The row view of ``tree``: indexed leaves replaced by their ``uniq``
     rows ``[B, ...]`` (zeros where ``uniq`` holds a fill id; what is computed
-    on those is never written)."""
+    on those is never written). ``placed`` is ``tree``'s shardings where the
+    caller knows them: a leaf whose rows are split over mesh axes
+    (:func:`row_axes`) is read shard by shard, each shard its own slice of
+    ``uniq``, and one sum over those axes after the walk puts the view
+    together (one shard's rows and the others' zeros: exact)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from jax.sharding import PartitionSpec as P
 
-    def take(a, rows):
+    def take(a, rows, sharding=None):
         if rows is WHOLE:
             return a
-        return rows.passes(
-            a, lambda start, ids, out: lax.dynamic_update_slice_in_dim(
-                out, jnp.take(a, ids, axis=0, mode="clip",
-                              indices_are_sorted=True, unique_indices=True),
-                start, axis=0),
-            jnp.zeros(rows.uniq.shape + a.shape[1:], a.dtype))
+        axes = row_axes(sharding, a.shape)
+        if axes is None:
+            return rows.passes(
+                a, lambda start, ids, out: lax.dynamic_update_slice_in_dim(
+                    out, jnp.take(a, ids, axis=0, mode="clip",
+                                  indices_are_sorted=True,
+                                  unique_indices=True),
+                    start, axis=0),
+                jnp.zeros(rows.uniq.shape + a.shape[1:], a.dtype))
 
-    return jax.tree.map(take, tree, idx)
+        def shard(table, uniq):
+            def visit(start, ids, out):
+                own = (ids >= 0) & (ids < table.shape[0])
+                got = jnp.take(table, ids, axis=0, mode="clip",
+                               indices_are_sorted=True, unique_indices=True)
+                return lax.dynamic_update_slice_in_dim(
+                    out, jnp.where(lax.expand_dims(own, range(1, got.ndim)),
+                                   got, 0), start, axis=0)
+
+            # no collective inside the walk: shards differ in their passes
+            return lax.psum(shard_rows(uniq, table.shape[0], axes).passes(
+                table, visit, lax.pcast(
+                    jnp.zeros(uniq.shape + table.shape[1:], table.dtype),
+                    axes, to="varying")), axes)
+
+        return jax.shard_map(shard, mesh=sharding.mesh,
+                             in_specs=(P(axes), P()), out_specs=P())(
+                                 a, rows.uniq)
+
+    return jax.tree.map(take, tree, idx, *(() if placed is None
+                                          else (placed,)))
 
 
-def put_rows(tree, view, idx):
+def put_rows(tree, view, idx, placed=None):
     """``tree`` with the view written back: whole leaves replaced, indexed
-    leaves updated in their real ``uniq`` rows and nowhere else."""
+    leaves updated in their real ``uniq`` rows and nowhere else. With the
+    shardings ``placed``, a leaf whose rows are split over mesh axes is
+    written shard by shard, each shard its own slice of ``uniq``."""
     import jax
     from jax import lax
+    from jax.sharding import PartitionSpec as P
 
-    def put(a, v, rows):
+    def put(a, v, rows, sharding=None):
         if rows is WHOLE:
             return v
-        return rows.passes(
-            a, lambda start, ids, table: table.at[ids].set(
-                lax.dynamic_slice_in_dim(v, start, ids.shape[0], axis=0)
-                .astype(a.dtype), mode="drop", indices_are_sorted=True,
-                unique_indices=True), a)
+        axes = row_axes(sharding, a.shape)
+        if axes is None:
+            return rows.passes(
+                a, lambda start, ids, table: table.at[ids].set(
+                    lax.dynamic_slice_in_dim(v, start, ids.shape[0], axis=0)
+                    .astype(a.dtype), mode="drop", indices_are_sorted=True,
+                    unique_indices=True), a)
 
-    return jax.tree.map(put, tree, view, idx)
+        # lax.scatter, not .at[]: an id below the shard's first row is
+        # negative and dropped as it is (.at[] would count it from the end)
+        into_rows = lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(1, a.ndim)),
+            inserted_window_dims=(0,), scatter_dims_to_operand_dims=(0,))
+
+        def shard(table, v, uniq):
+            return shard_rows(uniq, table.shape[0], axes).passes(
+                table, lambda start, ids, table: lax.scatter(
+                    table, ids[:, None], lax.dynamic_slice_in_dim(
+                        v, start, ids.shape[0], axis=0).astype(table.dtype),
+                    into_rows, indices_are_sorted=True, unique_indices=True,
+                    mode="drop"), table)
+
+        return jax.shard_map(shard, mesh=sharding.mesh,
+                             in_specs=(P(axes), P(), P()),
+                             out_specs=P(axes))(a, v, rows.uniq)
+
+    return jax.tree.map(put, tree, view, idx, *(() if placed is None
+                                               else (placed,)))
 
 
 def same_as_dense(tx, params, tables) -> bool:

@@ -56,7 +56,7 @@ def _batches(n=STEPS, tail_rows=None, seed=0):
     return out
 
 
-def _state(model, tx, mesh=None):
+def _state(model, tx, mesh=None, rules=None):
     import jax
     import jax.numpy as jnp
     from flax.training import train_state
@@ -75,10 +75,12 @@ def _state(model, tx, mesh=None):
     if mesh is None:
         return state
     return jax.device_put(state, param_sharding_rules(
-        mesh, dlrm_param_rules("expert"))(state))
+        mesh, rules or dlrm_param_rules("expert"))(state))
 
 
-def _step(model, mesh=None, accum=1):
+def _step(model, mesh=None, accum=1, placed=None):
+    """The estimator's step. ``placed``: the state's shardings, as ``fit``
+    hands them over; without them every table is walked as one."""
     from raydp_tpu.models import criteo_batch_preprocessor
     from raydp_tpu.parallel import batch_sharding
     from raydp_tpu.train.flax_estimator import (_make_apply, _make_train_step,
@@ -88,22 +90,29 @@ def _step(model, mesh=None, accum=1):
                            criteo_batch_preprocessor(NUM_DENSE), None)
     mb = (batch_sharding(mesh), None) if mesh is not None else None
     return _make_train_step(apply_fn, _resolve_loss("bce"), [], accum, "none",
-                            mb_shardings=mb)
+                            mb_shardings=mb, state_shardings=placed)
 
 
-def _run(step, state, batches, mesh=None):
+def _run_each(step, state, batches, mesh=None):
+    """The state after the batches and the loss sum after each."""
     import jax
     import jax.numpy as jnp
 
     from raydp_tpu.parallel import batch_sharding
 
     jitted = jax.jit(step)
-    loss = jnp.zeros(())
+    loss, sums = jnp.zeros(()), []
     for batch in batches:
         if mesh is not None:
             batch = jax.device_put(batch, batch_sharding(mesh))
         state, loss, _ = jitted(state, batch, (), loss)
-    return state, float(loss)
+        sums.append(float(loss))
+    return state, sums
+
+
+def _run(step, state, batches, mesh=None):
+    state, sums = _run_each(step, state, batches, mesh)
+    return state, sums[-1]
 
 
 def _table_counts():
@@ -153,6 +162,8 @@ def test_rowwise_matches_dense(placement, tail, walk, monkeypatch):
     import jax
     import optax
 
+    from raydp_tpu.models import dlrm_param_rules
+    from raydp_tpu.parallel import param_sharding_rules
     from raydp_tpu.train import rowwise
 
     if walk == "in_passes":
@@ -165,7 +176,11 @@ def test_rowwise_matches_dense(placement, tail, walk, monkeypatch):
     batches = _batches(tail_rows=tail)
     tx = optax.adagrad(0.05)
     before = _table_counts()
-    row_step = _step(model, mesh)
+    # on the mesh the step is told the state's shardings, as fit tells it:
+    # every shard walks its own rows
+    row_step = _step(model, mesh, placed=None if mesh is None else
+                     param_sharding_rules(mesh, dlrm_param_rules("expert"))(
+                         _state(model, tx)))
     row, row_loss = _run(row_step, _state(model, tx, mesh), batches, mesh)
     assert _counted(before) == {"rowwise": 3, "dense": 3}
     text = str(jax.make_jaxpr(row_step)(_state(model, tx), batches[0], (),
@@ -498,7 +513,306 @@ def test_compiled_step_on_the_mesh_touches_no_table(declared):
         assert extra
 
 
-# ------------------------------- (f) a dense fit's checkpoint, row-wise step
+# ------------------------ (f) on a mesh: each shard walks its own rows
+#: placement -> (mesh axes, devices, the tables' rule): rows split two ways,
+#: four ways, and over two axes at once (what the role policy gives an
+#: embedding no rule names on an fsdp x tensor mesh)
+_PLACEMENTS = {
+    "data2_expert2": (dict(data=2, expert=2), 4, ("expert", None)),
+    "data2_expert4": (dict(data=2, expert=4), 8, ("expert", None)),
+    "fsdp2_tensor2": (dict(fsdp=2, tensor=2), 4, (("fsdp", "tensor"), None)),
+}
+
+
+def _placement(name):
+    import jax
+
+    from raydp_tpu.parallel import make_mesh
+
+    axes, n, spec = _PLACEMENTS[name]
+    mesh = make_mesh(axes, devices=jax.devices()[:n])
+    shards = int(np.prod([mesh.shape[a] for a in np.ravel(spec[0])]))
+    return mesh, [("embedding", spec)], shards
+
+
+def _ids(pattern, v, shards, rng):
+    """``B`` ids into a table of ``v`` rows held in ``shards`` equal ranges
+    (walked 16 ids a pass: ``uniq``'s last chunk is positions 48 to 63)."""
+    per = v // shards
+    if pattern == "spread":             # some for every shard, 8 repeated
+        ids = rng.integers(0, v, B - 8)
+        ids = np.concatenate([ids, ids[:8]])
+        assert len(np.unique(ids // per)) == shards
+    elif pattern == "one_shard":        # the other shards run no pass
+        ids = (shards - 1) * per + rng.integers(0, per, B)
+    elif pattern == "all_distinct":     # count == B: uniq holds no fill id
+        ids = rng.permutation(v)[:B]
+    else:                               # "pulled_back": shard 1's slice is
+        first = rng.permutation(per)[:53]       # positions 53 to 59 of uniq
+        ids = np.concatenate([first, per + rng.permutation(per)[:7],
+                              first[:4]])
+    return rng.permutation(ids)
+
+
+def _batches_of(pattern, shards, n=3, seed=0):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        sparse = np.stack([
+            _ids(pattern, v, shards, rng) if j in WIDE
+            else rng.integers(0, v, B) for j, v in enumerate(SIZES)], 1)
+        feats = np.concatenate([rng.random((B, NUM_DENSE)), sparse], 1)
+        out.append({"features": jnp.asarray(feats, jnp.float32),
+                    "label": jnp.asarray(rng.integers(0, 2, B), jnp.float32)})
+    return out
+
+
+def _walk_counts():
+    from raydp_tpu import metrics
+
+    return dict(metrics.snapshot()["counters"].get(
+        "train_table_walk_total", {}))
+
+
+def _walked(before):
+    after = _walk_counts()
+    return {k: after.get(k, 0) - before.get(k, 0)
+            for k in ("shard_local", "global")}
+
+
+def _same_state(a, b):
+    import jax
+
+    a = jax.tree_util.tree_leaves_with_path((a.params, a.opt_state))
+    b = jax.tree.leaves((b.params, b.opt_state))
+    assert len(a) == len(b)
+    for (path, x), y in zip(a, b):
+        assert x.sharding == y.sharding, str(path)
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=str(path))
+
+
+@pytest.mark.parametrize("placement", list(_PLACEMENTS))
+def test_sharded_walk_moves_the_rows_numpy_moves(placement, monkeypatch):
+    """The lookup and the write-back of a row-sharded table, shard by shard,
+    against numpy's gather and scatter of the same rows: nothing is computed,
+    so every bit is the table's or the view's, for every id pattern."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh, rules, shards = _placement(placement)
+    sharding = NamedSharding(mesh, PartitionSpec(*rules[0][1]))
+    v = SIZES[0]
+    rng = np.random.default_rng(3)
+    table = rng.standard_normal((v, 8)).astype(np.float32)
+
+    @jax.jit
+    def walk(table, ids, new):
+        rows, _ = rowwise.unique_rows(ids, v)
+        tree, idx, placed = {"t": table}, {"t": rows}, {"t": sharding}
+        view = rowwise.take_rows(tree, idx, placed)
+        return (rows.count, view["t"],
+                rowwise.put_rows(tree, {"t": new}, idx, placed)["t"])
+
+    for pattern in ("spread", "one_shard", "all_distinct", "pulled_back"):
+        ids = _ids(pattern, v, shards, rng)
+        new = rng.standard_normal((B, 8)).astype(np.float32)
+        count, view, back = walk(jax.device_put(table, sharding),
+                                 jnp.asarray(ids, jnp.int32), new)
+        uniq = np.unique(ids)
+        assert int(count) == len(uniq)
+        np.testing.assert_array_equal(np.asarray(view)[:len(uniq)],
+                                      table[uniq], err_msg=pattern)
+        assert not np.asarray(view)[len(uniq):].any()
+        want = table.copy()
+        want[uniq] = new[:len(uniq)]
+        np.testing.assert_array_equal(np.asarray(back), want, err_msg=pattern)
+        assert back.sharding == sharding
+
+
+@pytest.mark.parametrize("placement,pattern", [
+    (p, i) for p in ("data2_expert2", "data2_expert4")
+    for i in ("spread", "one_shard", "all_distinct", "pulled_back")
+] + [("fsdp2_tensor2", "spread"), ("fsdp2_tensor2", "pulled_back")])
+def test_local_walk_is_the_global_walk_bit_for_bit(placement, pattern,
+                                                   monkeypatch):
+    """Told the state's shardings, the step reads and writes a row-sharded
+    table shard by shard, each its own slice of ``uniq``; not told, every
+    shard walks all of it. Same parameters and losses, to the bit, three
+    steps. (Under ``sgd(0.5)``, whose update rounds once however a compiler
+    contracts the multiply and the add: with Adagrad the CPU compiler fuses
+    them in one of the two programs and not in the other on some meshes, and
+    one element in 8000 differs in its last bit. Adagrad's accumulators go
+    through ``test_sharded_walk_moves_the_rows_numpy_moves`` to the bit and
+    through ``test_rowwise_matches_dense`` on the mesh.)"""
+    import optax
+
+    from raydp_tpu.parallel import param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh, rules, shards = _placement(placement)
+    model, tx = _model(), optax.sgd(0.5)
+    batches = _batches_of(pattern, shards)
+    ids = np.asarray(batches[0]["features"])[:, NUM_DENSE + WIDE[0]]
+    assert len(np.unique(ids)) == {"spread": len(np.unique(ids)),
+                                   "one_shard": len(np.unique(ids)),
+                                   "all_distinct": B, "pulled_back": 60
+                                   }[pattern]
+    if pattern == "one_shard":
+        assert ids.min() >= SIZES[WIDE[0]] // shards * (shards - 1)
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+
+    before = _walk_counts()
+    local, local_sums = _run_each(_step(model, mesh, placed=placed),
+                                  _state(model, tx, mesh, rules), batches,
+                                  mesh)
+    assert _walked(before) == {"shard_local": 3, "global": 0}   # once a step
+    before = _walk_counts()
+    whole, whole_sums = _run_each(_step(model, mesh),
+                                  _state(model, tx, mesh, rules), batches,
+                                  mesh)
+    assert _walked(before) == {"shard_local": 0, "global": 3}
+    assert local_sums == whole_sums and local_sums[2] > local_sums[0] > 0
+    _same_state(local, whole)
+    start = _state(model, tx)
+    for j in WIDE:
+        table = local.params[f"embedding_{j}"]["embedding"]
+        assert table.sharding.shard_shape(table.shape)[0] == SIZES[j] // shards
+        assert not np.array_equal(np.asarray(table), np.asarray(
+            start.params[f"embedding_{j}"]["embedding"]))
+
+
+def test_only_a_table_split_by_rows_is_walked_locally(monkeypatch):
+    """One mesh, three specs: rows over ``expert`` (walked shard by shard),
+    columns over ``expert`` and not sharded at all (both as before). The
+    step's results do not depend on which."""
+    import jax
+    import optax
+
+    from raydp_tpu.parallel import param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh, _, _ = _placement("data2_expert2")
+    rules = [("embedding_0", ("expert", None)),
+             ("embedding_2", (None, "expert")), ("embedding", ())]
+    model, tx = _model(), optax.sgd(0.5)     # rounds once: see above
+    batches = _batches_of("spread", 2)
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+    before = _walk_counts()
+    told_step = _step(model, mesh, placed=placed)
+    told, told_sums = _run_each(told_step, _state(model, tx, mesh, rules),
+                                batches, mesh)
+    assert _walked(before) == {"shard_local": 1, "global": 2}
+    untold, untold_sums = _run_each(_step(model, mesh),
+                                    _state(model, tx, mesh, rules), batches,
+                                    mesh)
+    assert told_sums == untold_sums
+    _same_state(told, untold)
+    # the lookup and the write-back of one table (sgd keeps no state)
+    text = str(jax.make_jaxpr(told_step)(_state(model, tx), batches[0], (),
+                                         0.0))
+    assert text.count("shard_map") == 2
+    assert "shard_map" not in str(jax.make_jaxpr(_step(model, mesh))(
+        _state(model, tx), batches[0], (), 0.0))
+
+
+@pytest.mark.parametrize("spec,shape,expected", [
+    (("expert", None), (1000, 8), ("expert",)),
+    ((("data", "expert"), None), (1000, 8), ("data", "expert")),
+    ((("expert", "tensor"), None), (1000, 8), ("expert",)),  # tensor is 1
+    (("tensor", None), (1000, 8), None),
+    ((None, "expert"), (1000, 8), None),
+    (("data", "expert"), (1000, 8), None),
+    ((), (1000, 8), None),
+    (("expert", None), (1001, 8), None),
+], ids=["rows", "rows_over_two_axes", "an_axis_of_one_is_none", "axis_of_one",
+        "columns", "rows_and_columns", "replicated", "uneven_rows"])
+def test_row_axes_reads_the_spec(spec, shape, expected):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from raydp_tpu.train import rowwise
+
+    mesh, _, _ = _placement("data2_expert2")
+    assert rowwise.row_axes(NamedSharding(mesh, PartitionSpec(*spec)),
+                            shape) == expected
+    assert rowwise.row_axes(None, shape) is None
+
+
+def _collectives_in_loops(hlo: str):
+    """The collective instructions of the computations a ``while`` runs
+    (its body and condition, and whatever they call)."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m and not line.startswith(" "):
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    todo = [c for lines in comps.values() for line in lines
+            if " while(" in line
+            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", line)]
+    inside = set()
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in inside:
+            inside.add(c)
+            todo += [x for line in comps[c] for x in re.findall(
+                r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)", line)]
+    assert inside
+    return [line.strip() for c in inside for line in comps[c] if re.search(
+        r" (all-reduce|all-gather|reduce-scatter|all-to-all"
+        r"|collective-permute)(-start)?\(", line)]
+
+
+@pytest.mark.parametrize("told", [True, False],
+                         ids=["told", "not_told_for_contrast"])
+def test_compiled_local_walk_holds_no_collective_in_a_loop(told, monkeypatch):
+    """The step compiled for data 2 x expert 4 with tables walked in passes:
+    told the shardings, no collective sits inside a ``while`` (the shards'
+    trip counts differ) and none has a table-sized operand; what crosses
+    chips is batch-sized. Not told, the partitioner sums every pass's rows
+    over ``expert`` inside the loop: the check can see."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.parallel import batch_sharding, param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh, rules, _ = _placement("data2_expert4")
+    model, tx = _model(), optax.adagrad(0.05)
+    state = _state(model, tx, mesh, rules)
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+    batch = jax.device_put(_batches(1)[0], batch_sharding(mesh))
+    hlo = jax.jit(_step(model, mesh, placed=placed if told else None),
+                  donate_argnums=(0, 3)).lower(
+                      state, batch, (), jnp.zeros(())).compile().as_text()
+    found = _ops_on_tables(hlo, B)
+    assert len(found["scatter"]) == 6 and " while(" in hlo
+    if told:
+        assert not _collectives_in_loops(hlo)
+        assert not set(found) - _MAY_HOLD_A_TABLE
+    else:
+        assert _collectives_in_loops(hlo)
+
+
+# ------------------------------- (g) a dense fit's checkpoint, row-wise step
 def test_dense_checkpoint_resumes_under_the_rowwise_step(tmp_path):
     """One tree: what a dense step (here: accumulated) saved restores into the
     state a row-wise step runs on, and the next step from it is the dense
@@ -569,14 +883,16 @@ def test_fit_engages_from_the_model_and_optimizer_alone(session, step_log):
                for line in step_log)
 
 
-def test_counter_is_registered_and_documented():
+@pytest.mark.parametrize("name", ["train_table_updates_total",
+                                  "train_table_walk_total"])
+def test_counter_is_registered_and_documented(name):
     import os
 
     from raydp_tpu import metrics
 
-    m = metrics.METRICS["train_table_updates_total"]
+    m = metrics.METRICS[name]
     assert (m.kind, m.label) == (metrics.COUNTER, "path")
     doc = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "doc", "observability.md")
     with open(doc) as fh:
-        assert "| `train_table_updates_total` | counter |" in fh.read()
+        assert f"| `{name}` | counter |" in fh.read()
