@@ -327,8 +327,24 @@ _ALL_METRICS = [
        "and added here with each epoch's loss: `all` is every slot "
        "(experts a token x tokens x expert layers), `max_expert` the slots "
        "of each layer's fullest expert. max_expert / (all / experts) is the "
-       "load imbalance a dropless layer pays for. doc/training.md.",
+       "load imbalance a dropless layer pays for; `held` the slots routed "
+       "to an expert this chip holds (`experts_held`: one chip's share of an "
+       "expert-parallel layer computes those and no other; equal to `all` "
+       "where every expert is held). doc/training.md.",
        label="kind"),
+    _m("train_attention_layers_total", COUNTER, "1", "training",
+       "Attention layers of a training model, counted once a built train "
+       "step by kind: `window` (a sliding window: a query sees itself and "
+       "the window - 1 keys before it) or `full` (every key up to its own). "
+       "doc/models.md.",
+       label="kind"),
+    _m("flash_blocks_total", COUNTER, "1", "training",
+       "(q block, k block) pairs of the flash-attention kernels, counted "
+       "where a kernel's grid is built (once a built forward kernel, twice "
+       "a built backward pair; heads x pairs): `computed`, `skipped_causal` "
+       "(wholly above the diagonal) and `skipped_window` (wholly behind the "
+       "window: never fetched). ops/flash_attention.py.",
+       label="fate"),
     _m("train_accum_steps", GAUGE, "1", "training",
        "Gradient-accumulation microbatches per optimizer step this fit is "
        "running with (1 = unaccumulated; the RDT_TRAIN_ACCUM_STEPS / "
@@ -478,6 +494,14 @@ _ALL_SPANS = [
        "projections, QK-norm, RoPE and the flash kernels "
        "(`rdt_flash_fwd`, `rdt_flash_bwd_dkdv`, `rdt_flash_bwd_dq`).",
        kind=SCOPE),
+    _s("attn_full", "model",
+       "Under `attn`: the attention itself (the flash kernels or their jnp "
+       "path) of a layer in which a query sees every key up to its own.",
+       kind=SCOPE),
+    _s("attn_window", "model",
+       "Under `attn`: the attention itself of a sliding-window layer (the "
+       "kernels `rdt_flash_win_fwd`, `rdt_flash_win_bwd_dkdv`, "
+       "`rdt_flash_win_bwd_dq`).", kind=SCOPE),
     _s("moe/router", "model",
        "Sparse expert layer (`models/moe.py`): float32 router product, "
        "softmax, top-k, group sizes and both auxiliary losses.", kind=SCOPE),

@@ -27,6 +27,20 @@ With ``num_experts > 0`` every block's feed-forward is the sparse expert
 layer of :mod:`raydp_tpu.models.moe` (``ffn_dim`` is then one expert's
 width), and the model's training loss carries its two auxiliary losses.
 
+What else a published layer may ask for: ``head_dim`` given outright (heads
+whose width is not ``dim / num_heads``), ``num_kv_heads`` (grouped-query: the
+flash kernels read a group's one K/V head, the other paths repeat it),
+``sliding_window`` with ``window_layers`` and ``rope_layers`` (a pattern over
+the layers, repeated: 1 = this layer's attention has the window / applies
+RoPE, 0 = it sees every earlier key / has no position embedding), the expert
+layer's ``expert_activation``, ``normalize_top_k``, ``first_expert`` and
+``experts_held`` (one chip's share of an expert-parallel layer), and
+``router_input="attention"`` (the router reads the attention's normed input,
+not the experts'). ``remat_blocks`` saves each block's input alone and
+recomputes the block in the backward pass. A vocabulary-parallel deployment's
+share is a smaller ``vocab_size``: embedding and head over the rows held,
+ids drawn from them.
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -37,7 +51,7 @@ never exist. Called plainly the model still returns them.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +100,10 @@ class Attention(nn.Module):
     qk_norm: bool = False
     rms_norm_eps: float = 1e-6
     init_std: Optional[float] = None
+    head_dim: Optional[int] = None          # None: dim // num_heads
+    num_kv_heads: Optional[int] = None      # None: num_heads
+    window: Optional[int] = None            # None: every key up to its own
+    rope: bool = True
 
     def _dispatch(self, t: int, head_dim: int) -> str:
         from raydp_tpu.ops.flash_attention import kernel_ineligible
@@ -95,9 +113,17 @@ class Attention(nn.Module):
             return self.attention
         if self.mesh is not None and seq_extent(self.mesh) > 1:
             return "ring"
-        on_kernel = (jax.default_backend() == "tpu"
-                     and kernel_ineligible(t, head_dim) is None)
-        return "flash" if on_kernel else "dense"
+        if jax.default_backend() != "tpu":
+            return "dense"
+        why = kernel_ineligible(t, head_dim, window=self.window)
+        if why is not None and self.window is not None:
+            # a window is set for sequences whose dense [T, T] scores do not
+            # fit: never a quiet switch to them
+            raise ValueError(
+                f"windowed attention (window {self.window}) over {t} "
+                f"positions cannot run the flash kernel on this TPU backend: "
+                f"{why}; pad the sequence, or ask for attention='dense'")
+        return "flash" if why is None else "dense"
 
     @nn.compact
     def __call__(self, x):
@@ -106,30 +132,40 @@ class Attention(nn.Module):
             dense_attention, ring_attention_sharded)
 
         b, t, dim = x.shape
-        head_dim = dim // self.num_heads
+        head_dim = self.head_dim or dim // self.num_heads
+        kv_heads = self.num_kv_heads or self.num_heads
         init = _init(self.init_std, nn.linear.default_kernel_init)
-        dense = lambda name: nn.DenseGeneral(  # noqa: E731
-            (self.num_heads, head_dim), axis=-1, name=name, dtype=self.dtype,
+        dense = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+            (heads, head_dim), axis=-1, name=name, dtype=self.dtype,
             use_bias=False, kernel_init=init)
-        q, k, v = dense("q")(x), dense("k")(x), dense("v")(x)
+        q = dense("q", self.num_heads)(x)
+        k, v = dense("k", kv_heads)(x), dense("v", kv_heads)(x)
         if self.qk_norm:
             # over the whole projection (all heads together), then split
             norm = lambda name, a: RMSNorm(  # noqa: E731
                 self.rms_norm_eps, name=name)(
-                    a.reshape(b, t, dim)).reshape(a.shape)
+                    a.reshape(b, t, -1)).reshape(a.shape)
             q, k = norm("q_norm", q), norm("k_norm", k)
 
-        positions = jnp.arange(t)
-        q = rotary_embedding(q, positions, self.rope_theta)
-        k = rotary_embedding(k, positions, self.rope_theta)
+        if self.rope:
+            positions = jnp.arange(t)
+            q = rotary_embedding(q, positions, self.rope_theta)
+            k = rotary_embedding(k, positions, self.rope_theta)
 
         kind = self._dispatch(t, head_dim)
-        if kind == "ring":
-            out = ring_attention_sharded(q, k, v, self.mesh, causal=True)
-        elif kind == "flash":
-            out = flash_attention_sharded(q, k, v, self.mesh, causal=True)
-        else:
-            out = dense_attention(q, k, v, causal=True)
+        with jax.named_scope(
+                "attn_full" if self.window is None else "attn_window"):
+            if kind == "ring":
+                if self.window or kv_heads != self.num_heads:
+                    raise NotImplementedError(
+                        "ring attention takes no window and no grouped K/V")
+                out = ring_attention_sharded(q, k, v, self.mesh, causal=True)
+            elif kind == "flash":
+                out = flash_attention_sharded(q, k, v, self.mesh, causal=True,
+                                              window=self.window)
+            else:
+                out = dense_attention(q, k, v, causal=True,
+                                      window=self.window)
         return nn.DenseGeneral(dim, axis=(-2, -1), name="o", dtype=self.dtype,
                                use_bias=False, kernel_init=init)(out)
 
@@ -137,7 +173,9 @@ class Attention(nn.Module):
 class Block(nn.Module):
     """One pre-norm block. Dense (``num_experts == 0``): ``x -> x``. Sparse:
     ``x -> (x, aux)``, ``aux`` what :class:`raydp_tpu.models.moe.MoE`
-    returns beside its output."""
+    returns beside its output. ``router_input="attention"``: the router's
+    kernel lies in the block (``router``) and reads the attention's normed
+    input; its logits are handed to the expert layer."""
 
     num_heads: int
     mlp_ratio: int = 4
@@ -151,23 +189,43 @@ class Block(nn.Module):
     num_experts: int = 0
     experts_per_token: int = 0
     init_std: Optional[float] = None
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    window: Optional[int] = None
+    rope: bool = True
+    first_expert: int = 0
+    experts_held: Optional[int] = None
+    expert_activation: str = "silu"
+    normalize_top_k: bool = False
+    router_input: str = "experts"
 
     @nn.compact
     def __call__(self, x):
-        from raydp_tpu.models.moe import MoE
+        from raydp_tpu.models.moe import MoE, router_logits
 
         dim = x.shape[-1]
         eps = self.rms_norm_eps
+        init = _init(self.init_std, nn.linear.default_kernel_init)
+        u = RMSNorm(eps, name="ln1")(x)
+        logits = None
+        if self.num_experts and self.router_input == "attention":
+            router = self.param("router", init, (dim, self.num_experts))
+            with jax.named_scope("moe"), jax.named_scope("router"):
+                logits = router_logits(u.reshape(-1, dim), router)
+        elif self.router_input != "experts":
+            raise ValueError(f"router_input {self.router_input!r}: "
+                             f"'experts' or 'attention'")
         x = x + Attention(self.num_heads, self.attention, self.mesh,
                           self.dtype, self.rope_theta, self.qk_norm, eps,
-                          self.init_std, name="attn")(
-                              RMSNorm(eps, name="ln1")(x))
+                          self.init_std, self.head_dim, self.num_kv_heads,
+                          self.window, self.rope, name="attn")(u)
         h = RMSNorm(eps, name="ln2")(x)
         hidden = self.ffn_dim or self.mlp_ratio * dim
-        init = _init(self.init_std, nn.linear.default_kernel_init)
         if self.num_experts:
             y, aux = MoE(self.num_experts, self.experts_per_token, hidden,
-                         self.dtype, init, name="moe")(h)
+                         self.dtype, init, self.first_expert,
+                         self.experts_held, self.expert_activation,
+                         self.normalize_top_k, name="moe")(h, logits)
             return x + y, aux
         # SwiGLU
         dense = lambda n, name: nn.Dense(  # noqa: E731
@@ -197,6 +255,38 @@ class TransformerLM(nn.Module):
     balance_loss_weight: float = 0.01
     z_loss_weight: float = 0.001
     init_std: Optional[float] = None
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    sliding_window: Optional[int] = None
+    window_layers: Tuple[int, ...] = ()     # () : no layer has the window
+    rope_layers: Tuple[int, ...] = ()       # () : every layer applies RoPE
+    first_expert: int = 0
+    experts_held: Optional[int] = None
+    expert_activation: str = "silu"
+    normalize_top_k: bool = False
+    router_input: str = "experts"
+    remat_blocks: bool = False
+
+    def _windowed(self, layer: int) -> bool:
+        pattern = self.window_layers
+        return bool(self.sliding_window and pattern
+                    and pattern[layer % len(pattern)])
+
+    def _rope(self, layer: int) -> bool:
+        pattern = self.rope_layers
+        return bool(pattern[layer % len(pattern)]) if pattern else True
+
+    @property
+    def attention_layers(self):
+        """How many layers of each kind the model has: what
+        ``train_attention_layers_total`` counts once a built step."""
+        windowed = sum(self._windowed(i) for i in range(self.num_layers))
+        return {"window": windowed, "full": self.num_layers - windowed}
+
+    @property
+    def _share(self) -> bool:
+        return bool(self.num_experts and self.experts_held is not None
+                    and self.experts_held < self.num_experts)
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False, labels=None,
@@ -213,12 +303,17 @@ class TransformerLM(nn.Module):
         x = nn.Embed(self.vocab_size, self.dim, name="embed",
                      dtype=self.dtype, embedding_init=init)(tokens)
         aux = []
+        block = nn.remat(Block) if self.remat_blocks else Block
         for i in range(self.num_layers):
-            x = Block(self.num_heads, self.mlp_ratio, self.attention,
+            x = block(self.num_heads, self.mlp_ratio, self.attention,
                       self.mesh, self.dtype, self.ffn_dim, self.rms_norm_eps,
                       self.rope_theta, self.qk_norm, self.num_experts,
-                      self.experts_per_token, self.init_std,
-                      name=f"block_{i}")(x)
+                      self.experts_per_token, self.init_std, self.head_dim,
+                      self.num_kv_heads,
+                      self.sliding_window if self._windowed(i) else None,
+                      self._rope(i), self.first_expert, self.experts_held,
+                      self.expert_activation, self.normalize_top_k,
+                      self.router_input, name=f"block_{i}")(x)
             if self.num_experts:
                 x, layer_aux = x
                 aux.append(layer_aux)
@@ -239,15 +334,20 @@ class TransformerLM(nn.Module):
         loss = loss + weights.sum() * (
             self.balance_loss_weight * mean("balance")
             + self.z_loss_weight * mean("z"))
-        return loss, jnp.stack([sum(a["slots_max"] for a in aux),
-                                sum(a["slots_all"] for a in aux)])
+        return loss, jnp.stack([sum(a[f"slots_{kind}"] for a in aux)
+                                for kind in self._slot_kinds])
+
+    @property
+    def _slot_kinds(self):
+        return ("max", "all", "held") if self._share else ("max", "all")
 
     @property
     def loss_counters(self):
         """What the second output of :meth:`loss_rows` counts, as (registry
         counter, label) pairs."""
-        return (("moe_slots_total", "max_expert"),
-                ("moe_slots_total", "all")) if self.num_experts else ()
+        labels = {"max": "max_expert", "all": "all", "held": "held"}
+        return tuple(("moe_slots_total", labels[kind])
+                     for kind in self._slot_kinds) if self.num_experts else ()
 
     def loss_rows(self, tokens, labels, weights):
         """The training loss over the rows, under the rows' ``weights`` [B]
@@ -412,6 +512,10 @@ def transformer_param_rules(axis: str = "tensor"):
     the fastest ICI links (raydp_tpu/parallel/mesh.py axis order).
     """
     return [
+        # q over the query heads, k and v over the (with grouped-query
+        # attention fewer) K/V heads: the axis has to divide both. The held
+        # experts' stacked kernels [held, in, out] are the ``expert`` role's
+        # (parallel/roles.py), not a rule's here
         ("attn/q/kernel", (None, axis, None)),
         ("attn/k/kernel", (None, axis, None)),
         ("attn/v/kernel", (None, axis, None)),

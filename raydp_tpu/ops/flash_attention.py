@@ -12,6 +12,19 @@ blocks, dq walking k blocks, both with the causal block skip); elsewhere a
 blockwise ``lax.scan`` computes the same math — memory stays O(T·blk) in both
 directions.
 
+Two options follow a published layer. ``window`` (with ``causal``): a query
+attends to itself and the ``window - 1`` keys before it. The kernels then walk
+only the blocks of the band: the grid's inner dimension holds as many steps as
+a block's band has blocks (five 1024-blocks for a window of 4096, whatever the
+sequence length), its block index is the band's, and the blocks on both edges
+of the band are masked element by element; blocks above the diagonal and
+blocks wholly behind the window are never fetched or computed. Grouped-query
+heads: K and V may hold fewer heads than Q (``H = G * Hk``); a K/V head is
+read by its ``G`` query heads from where it lies, never repeated in HBM, and
+the dK/dV kernel walks a group's query heads in its inner dimension so that
+their sum is formed in VMEM. ``window=None`` with as many K/V heads as query
+heads builds the program this op built before it had either.
+
 Dispatch: on a TPU backend the Pallas kernel runs, and a shape it cannot take
 is an error that says why — never a quiet switch to another path. Off the chip
 a fused jnp path computes the same math (it materializes the [T, T] scores, so
@@ -42,20 +55,111 @@ _NEG_INF = -1e30
 #: the three kernels' names as a device trace shows them (forward, the
 #: backward's dk/dv walk, its dq walk): what a roofline reader sums
 KERNEL_NAMES = ("rdt_flash_fwd", "rdt_flash_bwd_dkdv", "rdt_flash_bwd_dq")
+#: the same three of a call with a window, so that a trace tells a windowed
+#: layer's events from a full one's
+WINDOW_KERNEL_NAMES = ("rdt_flash_win_fwd", "rdt_flash_win_bwd_dkdv",
+                       "rdt_flash_win_bwd_dq")
+
+
+# ---------------------------------------------------------------------------
+# Which blocks a step works on. Without a window the inner grid dimension is
+# the other side's block index itself and the causal skip is a condition in
+# the kernel; with one it counts the steps of the band.
+# ---------------------------------------------------------------------------
+def _k_band(qi, blk_q: int, blk_k: int, window: int):
+    """First and last k block that hold a key some query of q block ``qi``
+    sees (``qi`` a Python or a traced integer)."""
+    most = max if isinstance(qi, int) else jnp.maximum
+    lo = most(qi * blk_q - (window - 1), 0) // blk_k
+    return lo, (qi * blk_q + blk_q - 1) // blk_k
+
+
+def _q_band(ki, blk_q: int, blk_k: int, window: int, num_q: int):
+    """First and last q block that hold a query which sees some key of k
+    block ``ki``."""
+    least = min if isinstance(ki, int) else jnp.minimum
+    hi = least((ki * blk_k + blk_k - 1 + window - 1) // blk_q, num_q - 1)
+    return (ki * blk_k) // blk_q, hi
+
+
+def _band_steps(t: int, blk_q: int, blk_k: int, window: Optional[int]):
+    """(steps of a q block's walk over k blocks, steps of a k block's walk
+    over q blocks): the other side's block count without a window, the
+    widest band's with one."""
+    num_q, num_k = t // blk_q, t // blk_k
+    if window is None:
+        return num_k, num_q
+    k_steps = max(hi - lo + 1 for lo, hi in (
+        _k_band(qi, blk_q, blk_k, window) for qi in range(num_q)))
+    q_steps = max(hi - lo + 1 for lo, hi in (
+        _q_band(ki, blk_q, blk_k, window, num_q) for ki in range(num_k)))
+    return k_steps, q_steps
+
+
+def _k_step(qi, j, *, blk_q: int, blk_k: int, window: Optional[int],
+            steps: int):
+    """Step ``j`` of q block ``qi``'s walk: (the k block it works on, whether
+    it works at all; None = the kernel's causal condition decides). With a
+    window the walk ends on the diagonal block; the steps before the band's
+    first block stay on that block, which is then fetched once."""
+    if window is None:
+        return j, None
+    lo, hi = _k_band(qi, blk_q, blk_k, window)
+    kb = hi - (steps - 1) + j
+    return jnp.maximum(kb, lo), kb >= lo
+
+
+def _q_step(b, ki, j, *, group: int, blk_q: int, blk_k: int,
+            window: Optional[int], steps: int, num_q: int):
+    """Step ``j`` of the dK/dV walk of K/V head ``b``, k block ``ki``: (the
+    query head, the q block, whether it works at all or None). The walk goes
+    through the group's query heads one after another, each over ``steps`` q
+    blocks."""
+    head = b if group == 1 else b * group + j // steps
+    jj = j if group == 1 else j % steps
+    if window is None:
+        return head, jj, None
+    lo, hi = _q_band(ki, blk_q, blk_k, window, num_q)
+    qb = lo + jj
+    return head, jnp.minimum(qb, hi), qb <= hi
+
+
+def _count_blocks(kernels: int, heads: int, t: int, blk_q: int, blk_k: int,
+                  window: Optional[int], causal: bool) -> None:
+    """``flash_blocks_total``: the (q block, k block) pairs of the kernels
+    being built, by what becomes of them."""
+    from raydp_tpu import metrics as rdt_metrics
+
+    num_q, num_k = t // blk_q, t // blk_k
+    above = behind = 0
+    for qi in range(num_q):
+        last = (qi * blk_q + blk_q - 1) // blk_k if causal else num_k - 1
+        first = 0 if window is None else _k_band(qi, blk_q, blk_k,
+                                                 window)[0]
+        above += num_k - 1 - last
+        behind += first
+    total = num_q * num_k
+    for label, n in (("computed", total - above - behind),
+                     ("skipped_causal", above), ("skipped_window", behind)):
+        if n:
+            rdt_metrics.inc("flash_blocks_total", kernels * heads * n, label)
 
 
 # ---------------------------------------------------------------------------
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, blk_q: int, blk_k: int):
+                *, scale: float, causal: bool, blk_q: int, blk_k: int,
+                window: Optional[int] = None, steps: int = 0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    j = pl.program_id(2)
     num_k = pl.num_programs(2)
+    ki, in_band = _k_step(qi, j, blk_q=blk_q, blk_k=blk_k, window=window,
+                          steps=steps)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -74,8 +178,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             preferred_element_type=jnp.float32) * scale     # [blk_q, blk_k]
 
         if causal:
-            s = _mask_causal(s, qi, ki, blk_q, blk_k)
+            s = _mask_causal(s, qi, ki, blk_q, blk_k, window)
 
+        # a row that sees no key of a block on the band's far edge keeps
+        # m = -1e30 and adds p = 1 for each of them; the first block that
+        # holds a key it does see (its own diagonal at the latest) scales
+        # that away with exp(-1e30 - m) = 0
         m_prev = m_scr[:, 0]                                # [blk_q]
         l_prev = l_scr[:, 0]
         m_blk = jnp.max(s, axis=-1)
@@ -90,44 +198,75 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[:, 0] = m_new
         l_scr[:, 0] = l_new
 
-    if causal:
-        # causal block skipping: a k block strictly above the triangle (its
-        # first key after this q block's last query) contributes exactly
-        # zero — skip both matmuls, halving causal FLOPs
-        pl.when(qi * blk_q + (blk_q - 1) >= ki * blk_k)(_body)
-    else:
-        _body()
+    _when_visible(_body, in_band, causal, qi, ki, blk_q, blk_k)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(j == num_k - 1)
     def _finalize():
         l_fin = jnp.maximum(l_scr[:, 0], 1e-30)
         o_ref[0] = (acc_scr[:] / l_fin[:, None]).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[:, 0] + jnp.log(l_fin)
 
 
+def _when_visible(body, in_band, causal: bool, qi, ki, blk_q: int,
+                  blk_k: int):
+    """Run ``body`` where the block pair holds a visible (query, key) pair.
+    With a window the walk itself is the band (``in_band``); without one a k
+    block strictly above the triangle (its first key after this q block's
+    last query) contributes exactly zero: skip both matmuls, halving causal
+    FLOPs."""
+    from jax.experimental import pallas as pl
+
+    if in_band is not None:
+        pl.when(in_band)(body)
+    elif causal:
+        pl.when(qi * blk_q + (blk_q - 1) >= ki * blk_k)(body)
+    else:
+        body()
+
+
+def _maps(group: int, band: dict):
+    """The index maps of a grid ``(q head, q block, step)``: a q-side block,
+    the K/V block of the step (the K/V head is the query head's group), and
+    the ``(1, 1, blk_q)`` block of a per-row float32 array."""
+    def q_map(b, qi, j):
+        return (b, qi, 0)
+
+    def kv_map(b, qi, j):
+        return (b if group == 1 else b // group, _k_step(qi, j, **band)[0], 0)
+
+    def row_map(b, qi, j):
+        return (b, 0, qi)
+
+    return q_map, kv_map, row_map
+
+
 def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
-                blk_k: int, interpret: bool):
-    """q3/k3/v3: [BH, T, D] → (out [BH, T, D], lse [BH, T])."""
+                blk_k: int, interpret: bool, window: Optional[int] = None):
+    """q3: [BH, T, D], k3/v3: [BHk, T, D] → (out [BH, T, D], lse [BH, T])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
-    grid = (bh, t // blk_q, t // blk_k)
+    group = bh // k3.shape[0]
+    k_steps, _ = _band_steps(t, blk_q, blk_k, window)
+    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
+    q_map, kv_map, row_map = _maps(group, band)
+    _count_blocks(1, bh, t, blk_q, blk_k, window, causal)
+    grid = (bh, t // blk_q, k_steps)
     vma = jax.typeof(q3).vma     # inside a shard_map the outputs vary as q does
 
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k),
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, **band),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, blk_q, d), q_map),
+            pl.BlockSpec((1, blk_k, d), kv_map),
+            pl.BlockSpec((1, blk_k, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, blk_q, d), q_map),
             # [BH, 1, T]: trailing block dims (1, blk_q) satisfy TPU tiling
-            pl.BlockSpec((1, 1, blk_q), lambda b, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((1, 1, blk_q), row_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q3.dtype, vma=vma),
@@ -142,21 +281,42 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=KERNEL_NAMES[0],
+        name=_names(window)[0],
     )(q3, k3, v3)
     return out, lse.reshape(bh, t)
+
+
+def _names(window: Optional[int]):
+    return KERNEL_NAMES if window is None else WINDOW_KERNEL_NAMES
 
 
 # ---------------------------------------------------------------------------
 # Fused jnp path: the implementation off the chip (materializes [T, T] scores)
 # ---------------------------------------------------------------------------
-def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool):
+def _visible(t: int, window: Optional[int]):
+    """[T, T] bool: query i sees key j."""
+    mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)
+    return mask
+
+
+def _repeat_kv(q3, k3, v3):
+    """K and V repeated to a head a query head (the jnp paths only: the
+    kernels read a group's one copy)."""
+    group = q3.shape[0] // k3.shape[0]
+    if group == 1:
+        return k3, v3, group
+    return jnp.repeat(k3, group, axis=0), jnp.repeat(v3, group, axis=0), group
+
+
+def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool,
+             window: Optional[int] = None):
+    k3, v3, _ = _repeat_kv(q3, k3, v3)
     s = jnp.einsum("bqd,bkd->bqk", q3.astype(jnp.float32),
                    k3.astype(jnp.float32)) * scale
     if causal:
-        t = q3.shape[1]
-        mask = jnp.tril(jnp.ones((t, t), dtype=bool))
-        s = jnp.where(mask[None], s, _NEG_INF)
+        s = jnp.where(_visible(q3.shape[1], window)[None], s, _NEG_INF)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
     out = jnp.einsum("bqk,bkd->bqd", p, v3.astype(jnp.float32))
@@ -169,15 +329,21 @@ def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool):
 # one accumulates dq walking k blocks — so each output block is written once
 # and all accumulation stays in VMEM scratch.
 # ---------------------------------------------------------------------------
-def _mask_causal(s, qi, ki, blk_q: int, blk_k: int):
-    """Apply the causal mask to a score block (shared by fwd + both bwds)."""
+def _mask_causal(s, qi, ki, blk_q: int, blk_k: int,
+                 window: Optional[int] = None):
+    """Apply the causal mask, and the window's, to a score block (shared by
+    fwd + both bwds)."""
     q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
     k_pos = ki * blk_k + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = keep & (q_pos - k_pos < window)
+    return jnp.where(keep, s, _NEG_INF)
 
 
 def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki,
-                    *, scale: float, causal: bool, blk_q: int, blk_k: int):
+                    *, scale: float, causal: bool, blk_q: int, blk_k: int,
+                    window: Optional[int] = None):
     """Re-form a score block from (q, k, lse) and compute (p, ds) — the flash
     backward identity ds = p ⊙ (do·vᵀ − delta)·scale, shared by the dk/dv and
     dq kernels so forward and backward masking cannot desynchronize."""
@@ -185,7 +351,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale       # [blk_q, blk_k]
     if causal:
-        s = _mask_causal(s, qi, ki, blk_q, blk_k)
+        s = _mask_causal(s, qi, ki, blk_q, blk_k, window)
     p = jnp.exp(s - lse[:, None])                         # true softmax rows
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -196,14 +362,18 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki,
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dk_ref, dv_ref, dk_scr, dv_scr,
-                     *, scale: float, causal: bool, blk_q: int, blk_k: int):
+                     *, scale: float, causal: bool, blk_q: int, blk_k: int,
+                     window: Optional[int] = None, steps: int = 0,
+                     group: int = 1, num_q: int = 0):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    num_q = pl.num_programs(2)
+    j = pl.program_id(2)
+    last = pl.num_programs(2)
+    _, qi, in_band = _q_step(0, ki, j, group=group, blk_q=blk_q, blk_k=blk_k,
+                             window=window, steps=steps, num_q=num_q)
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -212,7 +382,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, do = q_ref[0], do_ref[0]            # [blk_q, D]
         p, ds = _recompute_p_ds(
             q, k_ref[0], v_ref[0], do, lse_ref[0, 0], delta_ref[0, 0],
-            qi, ki, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k)
+            qi, ki, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
+            window=window)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -220,12 +391,9 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(qi * blk_q + (blk_q - 1) >= ki * blk_k)(_body)
-    else:
-        _body()
+    _when_visible(_body, in_band, causal, qi, ki, blk_q, blk_k)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(j == last - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -233,14 +401,17 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_scr,
-                   *, scale: float, causal: bool, blk_q: int, blk_k: int):
+                   *, scale: float, causal: bool, blk_q: int, blk_k: int,
+                   window: Optional[int] = None, steps: int = 0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    j = pl.program_id(2)
     num_k = pl.num_programs(2)
+    ki, in_band = _k_step(qi, j, blk_q=blk_q, blk_k=blk_k, window=window,
+                          steps=steps)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -248,54 +419,73 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         _, ds = _recompute_p_ds(
             q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0, 0], delta_ref[0, 0],
-            qi, ki, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k)
+            qi, ki, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
+            window=window)
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(qi * blk_q + (blk_q - 1) >= ki * blk_k)(_body)
-    else:
-        _body()
+    _when_visible(_body, in_band, causal, qi, ki, blk_q, blk_k)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(j == num_k - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
-                blk_k: int, interpret: bool):
+                blk_k: int, interpret: bool, window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q3, k3, v3, out, lse = res
     bh, t, d = q3.shape
+    bkv = k3.shape[0]
+    group = bh // bkv
     do = g
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, t)
     lse3 = lse.reshape(bh, 1, t)
     num_q, num_k = t // blk_q, t // blk_k
+    k_steps, q_steps = _band_steps(t, blk_q, blk_k, window)
     vma = jax.typeof(q3).vma
+    names = _names(window)
+    _count_blocks(2, bh, t, blk_q, blk_k, window, causal)
+
+    # dK/dV: one K/V head and k block a program row, walking the q blocks of
+    # each of the group's query heads in turn
+    walk = dict(group=group, blk_q=blk_q, blk_k=blk_k, window=window,
+                steps=q_steps, num_q=num_q)
+
+    def q_side(b, ki, j):
+        head, qi, _ = _q_step(b, ki, j, **walk)
+        return (head, qi, 0)
+
+    def q_rows(b, ki, j):
+        head, qi, _ = _q_step(b, ki, j, **walk)
+        return (head, 0, qi)
+
+    def k_side(b, ki, j):
+        return (b, ki, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k),
-        grid=(bh, num_k, num_q),
+                          **walk),
+        grid=(bkv, num_k, group * q_steps),
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, ki, qi: (b, qi, 0)),  # q
-            pl.BlockSpec((1, blk_k, d), lambda b, ki, qi: (b, ki, 0)),  # k
-            pl.BlockSpec((1, blk_k, d), lambda b, ki, qi: (b, ki, 0)),  # v
-            pl.BlockSpec((1, blk_q, d), lambda b, ki, qi: (b, qi, 0)),  # do
-            pl.BlockSpec((1, 1, blk_q), lambda b, ki, qi: (b, 0, qi)),  # lse
-            pl.BlockSpec((1, 1, blk_q), lambda b, ki, qi: (b, 0, qi)),  # delta
+            pl.BlockSpec((1, blk_q, d), q_side),    # q
+            pl.BlockSpec((1, blk_k, d), k_side),    # k
+            pl.BlockSpec((1, blk_k, d), k_side),    # v
+            pl.BlockSpec((1, blk_q, d), q_side),    # do
+            pl.BlockSpec((1, 1, blk_q), q_rows),    # lse
+            pl.BlockSpec((1, 1, blk_q), q_rows),    # delta
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, blk_k, d), k_side),
+            pl.BlockSpec((1, blk_k, d), k_side),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), k3.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t, d), v3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, t, d), k3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, t, d), v3.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
@@ -304,30 +494,31 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=KERNEL_NAMES[1],
+        name=names[1],
     )(q3, k3, v3, do, lse3, delta)
 
+    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
+    q_map, kv_map, row_map = _maps(group, band)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k),
-        grid=(bh, num_q, num_k),
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, **band),
+        grid=(bh, num_q, k_steps),
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, qi, ki: (b, qi, 0)),  # q
-            pl.BlockSpec((1, blk_k, d), lambda b, qi, ki: (b, ki, 0)),  # k
-            pl.BlockSpec((1, blk_k, d), lambda b, qi, ki: (b, ki, 0)),  # v
-            pl.BlockSpec((1, blk_q, d), lambda b, qi, ki: (b, qi, 0)),  # do
-            pl.BlockSpec((1, 1, blk_q), lambda b, qi, ki: (b, 0, qi)),  # lse
-            pl.BlockSpec((1, 1, blk_q), lambda b, qi, ki: (b, 0, qi)),  # delta
+            pl.BlockSpec((1, blk_q, d), q_map),     # q
+            pl.BlockSpec((1, blk_k, d), kv_map),    # k
+            pl.BlockSpec((1, blk_k, d), kv_map),    # v
+            pl.BlockSpec((1, blk_q, d), q_map),     # do
+            pl.BlockSpec((1, 1, blk_q), row_map),   # lse
+            pl.BlockSpec((1, 1, blk_q), row_map),   # delta
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, blk_q, d), q_map),
         ],
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=KERNEL_NAMES[2],
+        name=names[2],
     )(q3, k3, v3, do, lse3, delta)[0]
     return dq, dk, dv
 
@@ -335,8 +526,11 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
 # ---------------------------------------------------------------------------
 # Blockwise backward (flash recompute from LSE), shared by both paths
 # ---------------------------------------------------------------------------
-def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int):
+def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int,
+                   window: Optional[int] = None):
     q3, k3, v3, out, lse = res
+    bkv = k3.shape[0]
+    k3, v3, group = _repeat_kv(q3, k3, v3)
     bh, t, d = q3.shape
     blk = _fit_block(t, blk_k)
     num_k = t // blk
@@ -352,7 +546,10 @@ def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int):
         s = jnp.einsum("bqd,bkd->bqk", qf, k_blk) * scale
         if causal:
             k_pos = j * blk + jnp.arange(blk)
-            s = jnp.where((q_pos[:, None] >= k_pos[None, :])[None], s, _NEG_INF)
+            keep = q_pos[:, None] >= k_pos[None, :]
+            if window is not None:
+                keep = keep & (q_pos[:, None] - k_pos[None, :] < window)
+            s = jnp.where(keep[None], s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])                      # [BH, Tq, blk]
         dv_blk = jnp.einsum("bqk,bqd->bkd", p, do)
         dp = jnp.einsum("bqd,bkd->bqk", do, v_blk)
@@ -365,6 +562,9 @@ def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int):
         step, jnp.zeros_like(qf), jnp.arange(num_k))
     dk = dk_blocks.transpose(1, 0, 2, 3).reshape(bh, t, d)
     dv = dv_blocks.transpose(1, 0, 2, 3).reshape(bh, t, d)
+    if group > 1:       # a K/V head's gradient: the sum over its query heads
+        dk = dk.reshape(bkv, group, t, d).sum(axis=1)
+        dv = dv.reshape(bkv, group, t, d).sum(axis=1)
     return dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
 
 
@@ -381,14 +581,19 @@ def _fit_block(t: int, blk: int) -> int:
 
 
 def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
-                      block_k: int = DEFAULT_BLOCK_K) -> Optional[str]:
+                      block_k: int = DEFAULT_BLOCK_K,
+                      window: Optional[int] = None) -> Optional[str]:
     """Why the compiled Pallas kernel cannot take a [.., T=t, .., D=d] call
     (None when it can). Block dims equal to the full array dim satisfy TPU
     tiling, so d needs no 128 alignment; the q/k blocks must be sublane-
     aligned themselves — ``_fit_block`` caps them at t, which need not be a
     multiple of 8 (t=20 → blk=20) — and the (1, 1, blk_q) LSE blocks put
-    blk_q on the lanes."""
+    blk_q on the lanes. A window need not be a multiple of a block (the
+    band's edges are masked element by element); it has to hold the query
+    itself."""
     blk_q, blk_k = _fit_block(t, block_q), _fit_block(t, block_k)
+    if window is not None and window < 1:
+        return f"window {window} holds no key, not even the query's own"
     if d % 8:
         return f"head_dim {d} is not a multiple of 8"
     if blk_q % 8 or blk_k % 8:
@@ -400,8 +605,8 @@ def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
     return None
 
 
-def _use_pallas(t: int, d: int, blk_q: int, blk_k: int,
-                interpret: bool) -> bool:
+def _use_pallas(t: int, d: int, blk_q: int, blk_k: int, interpret: bool,
+                window: Optional[int] = None) -> bool:
     """Can the Pallas kernel take this call? In interpret mode: whenever the
     blocks divide the sequence. Otherwise whenever the compiled kernel is
     eligible; on a TPU backend an ineligible shape is an error. Whether the
@@ -410,7 +615,7 @@ def _use_pallas(t: int, d: int, blk_q: int, blk_k: int,
     path."""
     if interpret:
         return t % blk_q == 0 and t % blk_k == 0
-    why = kernel_ineligible(t, d, blk_q, blk_k)
+    why = kernel_ineligible(t, d, blk_q, blk_k, window)
     if why is None:
         return True
     if jax.default_backend() == "tpu":
@@ -432,33 +637,37 @@ def _by_platform(pallas_fn, jnp_fn, interpret: bool, *args):
     return lax.platform_dependent(*args, tpu=pallas_fn, default=jnp_fn)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, scale, causal, blk_q, blk_k, interpret):
-    out, _ = _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window):
+    out, _ = _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret,
+                        window)
     return out
 
 
-def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret):
+def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window):
     t, d = q3.shape[1], q3.shape[2]
-    jnp_fn = functools.partial(_fwd_jnp, scale=scale, causal=causal)
-    if _use_pallas(t, d, blk_q, blk_k, interpret):
+    jnp_fn = functools.partial(_fwd_jnp, scale=scale, causal=causal,
+                               window=window)
+    if _use_pallas(t, d, blk_q, blk_k, interpret, window):
         out, lse = _by_platform(
             functools.partial(_fwd_pallas, scale=scale, causal=causal,
-                              blk_q=blk_q, blk_k=blk_k, interpret=interpret),
+                              blk_q=blk_q, blk_k=blk_k, interpret=interpret,
+                              window=window),
             jnp_fn, interpret, q3, k3, v3)
     else:
         out, lse = jnp_fn(q3, k3, v3)
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_bwd(scale, causal, blk_q, blk_k, interpret, res, g):
+def _flash_bwd(scale, causal, blk_q, blk_k, interpret, window, res, g):
     t, d = res[0].shape[1], res[0].shape[2]
     jnp_fn = functools.partial(_bwd_blockwise, scale=scale, causal=causal,
-                               blk_k=blk_k)
-    if _use_pallas(t, d, blk_q, blk_k, interpret):
+                               blk_k=blk_k, window=window)
+    if _use_pallas(t, d, blk_q, blk_k, interpret, window):
         return _by_platform(
             functools.partial(_bwd_pallas, scale=scale, causal=causal,
-                              blk_q=blk_q, blk_k=blk_k, interpret=interpret),
+                              blk_q=blk_q, blk_k=blk_k, interpret=interpret,
+                              window=window),
             jnp_fn, interpret, res, g)
     return jnp_fn(res, g)
 
@@ -470,18 +679,28 @@ def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = False):
-    """Memory-efficient exact attention. q/k/v: [B, T, H, D] → [B, T, H, D]."""
+                    interpret: bool = False,
+                    window: Optional[int] = None):
+    """Memory-efficient exact attention. q: [B, T, H, D]; k, v: [B, T, Hk, D]
+    with ``H`` a multiple of ``Hk`` (query head ``h`` reads K/V head
+    ``h // (H // Hk)``) → [B, T, H, D]. ``window`` (causal only): a query
+    sees itself and the ``window - 1`` keys before it."""
     b, t, h, d = q.shape
+    hk = k.shape[2]
+    if h % hk or v.shape != k.shape:
+        raise ValueError(f"{h} query heads over K {k.shape} / V {v.shape}: "
+                         f"the K/V heads must divide the query heads")
+    if window is not None and not causal:
+        raise ValueError("a window is one-sided: it needs causal=True")
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     blk_q = _fit_block(t, block_q)
     blk_k = _fit_block(t, block_k)
 
     def to3(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, d)
 
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, blk_q, blk_k,
-                  interpret)
+                  interpret, window)
     return out3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 

@@ -181,14 +181,23 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = True,
 
 
 def dense_attention(q, k, v, causal: bool = True,
-                    scale: Optional[float] = None):
-    """Unsharded reference implementation (for tests and single-device use)."""
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
+    """Unsharded reference implementation (for tests and single-device use).
+    ``k`` and ``v`` may hold fewer heads than ``q`` (grouped-query: query
+    head ``h`` reads K/V head ``h // group``); ``window`` (causal only) keeps
+    a query's own key and the ``window - 1`` before it."""
     b, t, h, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    group = h // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     if causal:
         mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

@@ -1,0 +1,555 @@
+"""SmallThinker-style LM (window and full attention mixed on grouped-query
+heads, the router on the attention's input, ReLU-gated experts of which one
+chip holds a share): the flash op, the expert layer's shares, the whole model
+and a fit, against dense masked attention and the plain reference
+(``chipbench/reference/smallthinker-21b-a3b.py``: float32 ``jax.numpy``,
+attention by blocks of queries, every held expert on every token), at small
+sizes on the CPU, seeded random weights. Widths are small here, and only here
+(the fit at the CPU cut keeps them).
+"""
+
+import copy
+import os
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = "smallthinker-21b-a3b"
+
+# 14 query heads on 2 K/V heads (seven a group, as published), four layers in
+# the published pattern, 8 experts of which experts 2-3 are held, 3 a token,
+# 64 of 256 vocabulary rows, 32 positions with a window of 8
+TINY = {"hidden_size": 32, "head_dim": 8, "num_attention_heads": 14,
+        "num_key_value_heads": 2, "moe_ffn_hidden_size": 16,
+        "moe_num_primary_experts": 8, "first_expert": 2, "experts_held": 2,
+        "moe_num_active_primary_experts": 3, "vocab_size": 256,
+        "vocab_rows_held": 64, "max_position_embeddings": 32,
+        "sliding_window_size": 8, "layers": 4, "compared_positions": 8,
+        "compute_dtype": "float32", "attention": "dense", "init_std": 0.3}
+F32_TOL = 2e-5
+
+
+def _files(**changed):
+    from chipbench import manifest
+    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
+    cfg.update(copy.deepcopy(TINY))
+    cfg["input"] = dict(cfg["input"], eos_id=63)
+    cfg.update(changed)
+    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
+            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
+
+
+def _leaves(tree):
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(getattr(k, "key", k)) for k in path): np.asarray(v)
+            for path, v in flat}
+
+
+def _close(got, want, tol=10 * F32_TOL):
+    got, want = _leaves(got), _leaves(want)
+    assert set(got) == set(want)
+    for name, g in got.items():
+        scale = max(np.abs(want[name]).max(), 1e-3)
+        assert np.abs(g - want[name]).max() <= tol * scale, name
+
+
+# ------------------------------------------------- (a) the flash op
+# (T, window, block_q, block_k, query heads, K/V heads)
+FLASH_CASES = {
+    "t_below_the_window": (32, 48, 16, 16, 4, 2),
+    "t_at_the_window": (32, 32, 16, 16, 4, 2),
+    "t_four_windows": (64, 16, 16, 16, 4, 2),
+    "window_no_multiple_of_the_block": (64, 24, 16, 16, 4, 2),
+    "window_odd": (128, 37, 16, 16, 4, 4),
+    "seven_query_heads_a_kv_head": (64, 16, 16, 16, 14, 2),
+    "seven_query_heads_full": (64, None, 16, 16, 7, 1),
+    "k_blocks_wider": (64, 16, 16, 32, 4, 2),
+    "q_blocks_wider": (128, 32, 32, 16, 4, 2),
+    "window_of_one": (64, 1, 16, 16, 4, 2),
+    "as_before": (64, None, 16, 16, 4, 4),
+}
+
+
+def _qkv(t, h, hk, d=16, seed=0):
+    import jax
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (2, t, h, d)),
+            jax.random.normal(ks[1], (2, t, hk, d)),
+            jax.random.normal(ks[2], (2, t, hk, d)),
+            jax.random.normal(ks[3], (2, t, h, d)))
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["jnp_path", "pallas_interpret"])
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_flash_with_window_and_grouped_kv_matches_dense_masked_attention(
+        case, interpret):
+    """Forward and all three gradients; dK and dV are the sums over a
+    group's query heads."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.ops.flash_attention import flash_attention
+    from raydp_tpu.ops.ring_attention import dense_attention
+
+    t, window, blk_q, blk_k, h, hk = FLASH_CASES[case]
+    q, k, v, w = _qkv(t, h, hk)
+    got = jax.value_and_grad(lambda *a: jnp.sum(flash_attention(
+        *a, window=window, block_q=blk_q, block_k=blk_k,
+        interpret=interpret) * w), (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(lambda *a: jnp.sum(dense_attention(
+        *a, window=window) * w), (0, 1, 2))(q, k, v)
+    assert got[1][1].shape == (2, t, hk, 16)
+    for g, x in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, x, rtol=2e-4, atol=2e-4)
+
+
+def test_dense_attention_with_a_window_is_the_masked_softmax():
+    """The dense path itself, against numpy: query i sees keys i-w+1..i of
+    K/V head h // group."""
+    from raydp_tpu.ops.ring_attention import dense_attention
+
+    q, k, v, _ = _qkv(16, 4, 2, d=8, seed=3)
+    got = np.asarray(dense_attention(q, k, v, window=5))
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    for h in range(4):
+        s = q[0, :, h] @ k[0, :, h // 2].T / np.sqrt(8)
+        i, j = np.indices(s.shape)
+        s[(j > i) | (i - j >= 5)] = -np.inf
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ v[0, :, h // 2]
+        np.testing.assert_allclose(got[0, :, h], want, rtol=1e-5, atol=1e-5)
+
+
+def test_no_window_and_equal_heads_is_the_op_as_it_was():
+    """``window=None`` with as many K/V heads as query heads: the forward is
+    bit for bit the fused jnp math the op had before either option (written
+    out here), the kernels built are the ones named before, over the whole
+    square of blocks with the causal skip in the kernel."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.ops import flash_attention as fa
+
+    q, k, v, _ = _qkv(64, 4, 4)
+    got = fa.flash_attention(q, k, v, block_q=16, block_k=16)
+
+    def as_before(q, k, v):
+        to3 = lambda x: x.transpose(0, 2, 1, 3).reshape(8, 64, 16)  # noqa
+        s = jnp.einsum("bqd,bkd->bqk", to3(q), to3(k)) * 0.25
+        s = jnp.where(jnp.tril(jnp.ones((64, 64), bool))[None], s, -1e30)
+        p = jnp.exp(s - jax.nn.logsumexp(s, axis=-1)[..., None])
+        out = jnp.einsum("bqk,bkd->bqd", p, to3(v))
+        return out.reshape(2, 4, 64, 16).transpose(0, 2, 1, 3)
+
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(as_before(q, k, v)))
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: fa.flash_attention(
+        *a, block_q=16, block_k=16, interpret=True).sum(), (0, 1, 2)))(
+            q, k, v))
+    for name in fa.KERNEL_NAMES:
+        assert name in text
+    assert "rdt_flash_win" not in text
+    assert "grid=(8, 4, 4)" in text.replace("grid=(8,4,4)", "grid=(8, 4, 4)")
+    windowed = str(jax.make_jaxpr(lambda *a: fa.flash_attention(
+        *a, block_q=16, block_k=16, interpret=True, window=16))(q, k, v))
+    assert fa.WINDOW_KERNEL_NAMES[0] in windowed
+
+
+def test_the_windowed_kernels_walk_the_band_and_count_its_blocks():
+    """A window of 4096 over 16,384 positions in 1024-blocks: five steps a q
+    block, whatever the sequence length; the counter says what became of the
+    256 block pairs a head."""
+    from raydp_tpu import metrics as registry
+    from raydp_tpu.ops import flash_attention as fa
+
+    assert fa._band_steps(16384, 1024, 1024, 4096) == (5, 5)
+    assert fa._band_steps(16384, 1024, 1024, None) == (16, 16)
+    assert fa._band_steps(64, 16, 16, 24) == (3, 3)
+    assert fa._band_steps(64, 16, 32, 16) == (2, 3)
+    before = dict(registry.snapshot()["counters"].get(
+        "flash_blocks_total", {}))
+    fa._count_blocks(1, 28, 16384, 1024, 1024, 4096, True)
+    after = registry.snapshot()["counters"]["flash_blocks_total"]
+    moved = {k: after[k] - before.get(k, 0) for k in after}
+    assert moved == {"computed": 28 * 70, "skipped_causal": 28 * 120,
+                     "skipped_window": 28 * 66}
+    fa._count_blocks(2, 1, 64, 16, 16, None, True)
+    full = registry.snapshot()["counters"]["flash_blocks_total"]
+    assert full["computed"] - after["computed"] == 2 * 10
+    assert full["skipped_window"] == after["skipped_window"]
+
+
+def test_a_window_needs_causal_and_the_heads_have_to_group():
+    from raydp_tpu.ops.flash_attention import (flash_attention,
+                                               kernel_ineligible)
+    q, k, v, _ = _qkv(32, 4, 3)
+    with pytest.raises(ValueError, match="K/V heads"):
+        flash_attention(q, k, v)
+    q, k, v, _ = _qkv(32, 4, 2)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=8)
+    assert kernel_ineligible(16384, 128, window=4096) is None
+    assert kernel_ineligible(16384, 128, window=1000) is None
+    assert "window 0" in kernel_ineligible(16384, 128, window=0)
+
+
+@pytest.mark.parametrize("window,raises", [(8, True), (None, False)])
+def test_auto_attention_never_falls_back_to_dense_under_a_window_on_the_chip(
+        monkeypatch, window, raises):
+    """On a TPU backend a shape the kernel cannot take raises when a window
+    is set (its dense scores are what the window exists to avoid); without
+    one ``auto`` keeps its dense fallback."""
+    import jax
+    from raydp_tpu.models.transformer import Attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = Attention(num_heads=4, window=window)
+    if raises:
+        with pytest.raises(ValueError, match="windowed attention"):
+            layer._dispatch(20, 8)      # 20 positions: blocks of 20
+        assert layer._dispatch(128, 8) == "flash"
+    else:
+        assert layer._dispatch(20, 8) == "dense"
+
+
+# ------------------------------------- (b) the shares of one expert layer
+def _expert_layer(seed=0):
+    """An uncut layer's seeded weights, tokens and the router's input."""
+    rng = np.random.default_rng(seed)
+    d, f, e, n = 32, 16, 8, 48
+    full = {"experts_gate": rng.normal(0, 0.3, (e, d, f)),
+            "experts_up": rng.normal(0, 0.3, (e, d, f)),
+            "experts_down": rng.normal(0, 0.3, (e, f, d))}
+    return (jax_f32(full), jax_f32(rng.normal(0, 0.3, (d, e))),
+            jax_f32(rng.normal(size=(n, d))), jax_f32(rng.normal(size=(n, d))))
+
+
+def jax_f32(tree):
+    import jax
+    return jax.tree.map(lambda a: np.asarray(a, np.float32), tree)
+
+
+def _share_of(full, first, held):
+    return {k: v[first:first + held] for k, v in full.items()}
+
+
+def _program_share(kernels, router, m, u, first, held):
+    from raydp_tpu.models.moe import MoE, router_logits
+    layer = MoE(8, 3, 16, first_expert=first, experts_held=held,
+                activation="relu", normalize_top_k=True)
+    return layer.apply({"params": kernels}, m, router_logits(u, router))
+
+
+def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """Experts 0-1, 2-3, 4-5, 6-7 of 8, 3 a token: each chip routes over all
+    eight with the weights normalised over all three choices and computes
+    its own experts' part; the four parts sum to the reference's uncut
+    layer, and so do the held slots to all slots."""
+    _, _, reference = _files()
+    full, router, m, u = _expert_layer()
+    cfg = {"moe_num_primary_experts": 8, "moe_num_active_primary_experts": 3,
+           "norm_topk_prob": True, "first_expert": 0, "experts_held": 8}
+    logits = u @ router
+    want = np.asarray(reference.expert_layer(full, m, logits, cfg))
+    parts, held_slots = [], 0.0
+    for first in (0, 2, 4, 6):
+        y, aux = _program_share(_share_of(full, first, 2), router, m, u,
+                                first, 2)
+        one = dict(cfg, first_expert=first, experts_held=2)
+        np.testing.assert_allclose(
+            y, reference.expert_layer(_share_of(full, first, 2), m, logits,
+                                      one), rtol=1e-4, atol=1e-5)
+        parts.append(np.asarray(y))
+        held_slots += float(aux["slots_held"])
+        assert float(aux["slots_all"]) == 3 * 48
+    np.testing.assert_allclose(sum(parts), want, rtol=1e-4, atol=1e-5)
+    assert held_slots == 3 * 48
+    assert np.abs(want).max() > 0.1 and np.abs(parts[0] - want).max() > 0.01
+    # the uncut program layer is the same sum, and counts no share
+    y, aux = _program_share(full, router, m, u, 0, 8)
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+    assert "slots_held" not in aux
+
+
+@pytest.mark.parametrize("first", [0, 2, 4, 6])
+def test_a_shares_gradients_match_the_references(first):
+    """Of the router, the held kernels and both inputs: an absent expert's
+    slots give the router only what the normalisation over all choices
+    gives, and the tokens nothing."""
+    import jax
+    import jax.numpy as jnp
+    _, _, reference = _files()
+    full, router, m, u = _expert_layer(seed=first + 1)
+    kernels = _share_of(full, first, 2)
+    cfg = {"moe_num_primary_experts": 8, "moe_num_active_primary_experts": 3,
+           "norm_topk_prob": True, "first_expert": first, "experts_held": 2}
+    w = np.random.default_rng(9).normal(size=m.shape).astype(np.float32)
+    got = jax.grad(lambda k, r, m, u: jnp.sum(_program_share(
+        k, r, m, u, first, 2)[0] * w), (0, 1, 2, 3))(kernels, router, m, u)
+    want = jax.grad(lambda k, r, m, u: jnp.sum(reference.expert_layer(
+        k, m, u @ r, cfg) * w), (0, 1, 2, 3))(kernels, router, m, u)
+    _close(got, want)
+    assert np.abs(np.asarray(want[1])).max() > 1e-3
+
+
+def test_the_grouped_products_are_given_the_held_groups_alone():
+    """The slots sort with the held experts' first and the three products get
+    the held experts' group sizes: their sum is ``slots_held``, and the rows
+    after it are masked in and out (whatever a grouped product leaves in
+    rows that belong to no group goes nowhere)."""
+    import jax
+    from raydp_tpu.models import moe
+
+    full, router, m, u = _expert_layer()
+    seen = []
+    real = jax.lax.ragged_dot
+
+    def spy(lhs, rhs, sizes, **kw):
+        seen.append((lhs.shape, rhs.shape, np.asarray(sizes)))
+        out = real(lhs, rhs, sizes, **kw)
+        # poison the rows that belong to no group
+        rows = np.arange(lhs.shape[0])[:, None] >= int(np.sum(sizes))
+        return out + np.where(rows, 1e6, 0.0).astype(np.float32)
+
+    y0, aux = _program_share(_share_of(full, 2, 2), router, m, u, 2, 2)
+    jax.lax.ragged_dot = spy
+    try:
+        y1, _ = _program_share(_share_of(full, 2, 2), router, m, u, 2, 2)
+    finally:
+        jax.lax.ragged_dot = real
+    assert moe.jax.lax.ragged_dot is real
+    assert [s[1] for s in seen] == [(2, 32, 16), (2, 32, 16), (2, 16, 32)]
+    for lhs, _, sizes in seen:
+        assert lhs[0] == 3 * 48 and sizes.shape == (2,)
+        assert sizes.sum() == float(aux["slots_held"]) < 3 * 48
+    np.testing.assert_allclose(y1, y0, rtol=1e-5, atol=1e-5)
+
+
+# ----------------------------------------------------- (c) the whole model
+def _tokens(cfg, rows, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, cfg["vocab_rows_held"], (rows, cfg["max_position_embeddings"]),
+        dtype=np.int32)
+
+
+def _params(model, tokens, seed=0):
+    import jax
+    return jax.tree.map(np.array, model.init(
+        jax.random.PRNGKey(seed), tokens[:1])["params"])
+
+
+def test_the_parameter_tree_is_the_published_layers():
+    """Grouped-query projections at the heads' own width, the router in the
+    block (it reads the attention's input), the held experts' kernels, the
+    sliced embedding and head; no bias, no router in the expert layer."""
+    cfg, pipeline, _ = _files()
+    model = pipeline.build_model(cfg)
+    shapes = {k: v.shape for k, v in _leaves(
+        _params(model, _tokens(cfg, 1))).items()}
+    assert {k: v for k, v in shapes.items() if k.startswith("block_1/")} == {
+        "block_1/ln1/scale": (32,), "block_1/ln2/scale": (32,),
+        "block_1/router": (32, 8),
+        "block_1/attn/q/kernel": (32, 14, 8),
+        "block_1/attn/k/kernel": (32, 2, 8),
+        "block_1/attn/v/kernel": (32, 2, 8),
+        "block_1/attn/o/kernel": (14, 8, 32),
+        "block_1/moe/experts_gate": (2, 32, 16),
+        "block_1/moe/experts_up": (2, 32, 16),
+        "block_1/moe/experts_down": (2, 16, 32)}
+    assert shapes["embed/embedding"] == (64, 32)
+    assert shapes["lm_head/kernel"] == (32, 64)
+    assert model.attention_layers == {"window": 3, "full": 1}
+    assert model.loss_counters == (("moe_slots_total", "max_expert"),
+                                   ("moe_slots_total", "all"),
+                                   ("moe_slots_total", "held"))
+
+
+@pytest.mark.parametrize("dtype,attention,tol", [
+    ("float32", "dense", F32_TOL), ("float32", "flash", F32_TOL),
+    # at hidden 32 one flipped near-tied choice of 3 in 8 is a larger share
+    # of a token's output than at the published widths (the chip's check (a)
+    # holds 4 ulps there): 8 ulps here
+    ("bfloat16", "flash", 8 * 2.0 ** -8)])
+def test_forward_logits_match_the_reference(dtype, attention, tol):
+    from chipbench.harness import relative_rms_error
+    cfg, pipeline, reference = _files(compute_dtype=dtype,
+                                      attention=attention)
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 3)
+    params = _params(model, tokens)
+    got = pipeline.compared(model.apply({"params": params}, tokens), cfg)
+    want = reference.forward({"params": params}, tokens, cfg)
+    assert got.shape == want.shape == (3, 8, 64)
+    assert relative_rms_error(got, want) <= tol
+    if dtype == "bfloat16":     # and the tolerance does separate precisions
+        assert relative_rms_error(got, want) > F32_TOL
+
+
+def test_the_reference_by_blocks_of_queries_is_the_reference():
+    """A query block smaller than the sequence changes nothing."""
+    cfg, pipeline, reference = _files()
+    tokens = _tokens(cfg, 2, seed=3)
+    params = _params(pipeline.build_model(cfg), tokens)
+    whole = reference.forward({"params": params}, tokens, cfg)
+    block, reference.QUERY_BLOCK = reference.QUERY_BLOCK, 8
+    try:
+        blocked = reference.forward({"params": params}, tokens, cfg)
+    finally:
+        reference.QUERY_BLOCK = block
+    np.testing.assert_allclose(blocked, whole, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
+def test_loss_rows_and_every_gradient_leaf_match_the_reference(remat):
+    """The model's own loss (fused head over the rows held, both auxiliary
+    losses over all experts) and its gradients, with the blocks' activations
+    kept and with the blocks recomputed; and what it counts."""
+    import jax
+    cfg, pipeline, reference = _files(remat_blocks=remat)
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 4, seed=1)
+    params = _params(model, tokens)
+    w = np.full(4, 0.25, np.float32)
+    (loss, counts), grads = jax.value_and_grad(
+        lambda p: model.apply({"params": p}, tokens, tokens, w,
+                              method=model.loss_rows), has_aux=True)(params)
+    want_loss, want_grads = jax.value_and_grad(reference.loss)(
+        params, tokens, cfg)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
+    ids = np.stack(reference.top_k_ids(params, tokens, cfg))
+    per_expert = np.stack([np.bincount(layer.ravel(), minlength=8)
+                           for layer in ids])
+    assert float(counts[1]) == tokens.size * 3 * 4
+    assert float(counts[0]) == per_expert.max(axis=1).sum()
+    assert float(counts[2]) == per_expert[:, 2:4].sum() < float(counts[1])
+
+
+def test_the_pattern_decides_each_layers_window_and_rope():
+    """Layer 0 of a period: every earlier key and no position embedding (a
+    shuffled prefix changes nothing but through the causal mask); layers 1-3:
+    the window, with RoPE."""
+    from raydp_tpu.models import TransformerLM
+    model = TransformerLM(vocab_size=16, num_layers=6, sliding_window=4,
+                          window_layers=(0, 1, 1, 1), rope_layers=(0, 1, 1, 1))
+    assert [model._windowed(i) for i in range(6)] == [0, 1, 1, 1, 0, 1]
+    assert [model._rope(i) for i in range(6)] == [0, 1, 1, 1, 0, 1]
+    assert model.attention_layers == {"window": 4, "full": 2}
+    plain = TransformerLM(vocab_size=16, num_layers=2)
+    assert plain.attention_layers == {"window": 0, "full": 2}
+    assert [plain._rope(i) for i in range(2)] == [True, True]
+    assert plain.loss_counters == ()
+
+
+# -------------------------------------------------------------- (d) a fit
+def _token_frame(session, tmp_path, cfg, pipeline, rows, seed):
+    import pyarrow.parquet as pq
+    path = str(tmp_path / "tokens")
+    os.makedirs(path)
+    table = pipeline.generate(rows, seed, cfg)
+    for i in range(2):
+        pq.write_table(table.slice(i * rows // 2, rows // 2),
+                       os.path.join(path, f"part-{i}.parquet"))
+    wl = {"seq_len": cfg["max_position_embeddings"]}
+    df, info = pipeline.etl(session.read.parquet(path), cfg, wl)
+    return df.persist(), info
+
+
+def _estimator(cfg, pipeline, info, mesh, **fit):
+    from raydp_tpu.train import FlaxEstimator
+    return FlaxEstimator(
+        model=pipeline.build_model(cfg, mesh), loss=None,
+        optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
+        columns_spec={"tokens": (info["tokens"], np.int32)},
+        batch_preprocessor=lambda b: (b["tokens"], b["tokens"]),
+        shuffle=False, seed=0, **fit)
+
+
+def _moved(before, after, name):
+    return {k: v - before.get(name, {}).get(k, 0)
+            for k, v in after.get(name, {}).items()}
+
+
+def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
+    """The cell's own CPU cut (four layers in the pattern, 8 experts of which
+    2 held, 2 a token, 512 of 2048 vocabulary rows, 256 positions with a
+    window of 64, no width cut) through ``fit_on_frame``: the loss falls, the
+    held slots are some and not all of the slots, a built step counts its
+    layers by kind."""
+    import jax
+    from chipbench import manifest
+    from raydp_tpu import metrics as registry
+    from raydp_tpu.parallel import make_mesh
+
+    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
+    pipeline = manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py")
+    wl = {"seq_len": cfg["max_position_embeddings"], "batch_per_replica": 1}
+    rows = pipeline.cpu_cut(cfg, wl, 1)
+    assert (cfg["hidden_size"], cfg["head_dim"],
+            cfg["moe_ffn_hidden_size"]) == (2560, 128, 768)
+    cfg["compute_dtype"] = "float32"
+    df, info = _token_frame(session, tmp_path, cfg, pipeline, rows, 3)
+    mesh = make_mesh(None, devices=jax.devices()[:1])
+    before = registry.snapshot()["counters"]
+    est = _estimator(cfg, pipeline, info, mesh, num_epochs=3, batch_size=1,
+                     checkpoint_interval=3)
+    history = est.fit_on_frame(df).history
+    losses = [e["train_loss"] for e in history]
+    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
+    after = registry.snapshot()["counters"]
+    slots = _moved(before, after, "moe_slots_total")
+    assert slots["all"] == 3 * rows * 256 * 2 * 4   # epochs, tokens, top-2, layers
+    assert 0 < slots["held"] < slots["all"]
+    assert slots["all"] / 8 <= slots["max_expert"] <= slots["all"]
+    assert _moved(before, after, "train_attention_layers_total") == {
+        "window": 3, "full": 1}
+
+
+def test_an_expert_sharded_fit_of_the_share_gives_the_one_device_losses(
+        session, tmp_path):
+    """``expert`` 2 over two virtual devices: the held experts' stacked
+    kernels split on dim 0 by the role policy, the grouped-query K and V
+    kernels replicated; same losses."""
+    import jax
+    from raydp_tpu.parallel import make_mesh
+
+    cfg, pipeline, _ = _files()
+    df, info = _token_frame(session, tmp_path, cfg, pipeline, 8, 6)
+    losses = {}
+    for name, devices, spec in (("one", 1, None), ("two", 2, {"expert": 2})):
+        mesh = make_mesh(spec, devices=jax.devices()[:devices])
+        est = _estimator(cfg, pipeline, info, mesh, num_epochs=2,
+                         batch_size=4)
+        losses[name] = [e["train_loss"]
+                        for e in est.fit_on_frame(df).history]
+        if name == "two":
+            gate = est.get_state().params["block_0"]["moe"]["experts_gate"]
+            assert gate.sharding.spec[0] == "expert"
+            assert {s.data.shape[0] for s in gate.addressable_shards} == {1}
+    np.testing.assert_allclose(losses["two"], losses["one"], rtol=1e-5)
+
+
+def test_tensor_parallel_rules_name_the_grouped_query_kernels():
+    """``transformer_param_rules``: q over the query heads, k and v over the
+    (fewer) K/V heads, o's rows; the held experts keep the expert role."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from raydp_tpu.models.transformer import transformer_param_rules
+    from raydp_tpu.parallel import make_mesh, param_sharding_rules
+    from raydp_tpu.parallel.roles import classify_param
+
+    cfg, pipeline, _ = _files()
+    model = pipeline.build_model(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), _tokens(cfg, 1))["params"])
+    mesh = make_mesh({"tensor": 2, "data": 1}, devices=jax.devices()[:2])
+    placed = param_sharding_rules(mesh, transformer_param_rules())(shapes)
+    attn = placed["block_1"]["attn"]
+    assert attn["q"]["kernel"].spec == P(None, "tensor", None)
+    assert attn["k"]["kernel"].spec == P(None, "tensor", None)
+    assert attn["v"]["kernel"].spec == P(None, "tensor", None)
+    assert attn["o"]["kernel"].spec == P("tensor", None, None)
+    assert classify_param("params/block_1/moe/experts_gate",
+                          (2, 32, 16)) == "expert"
+    assert classify_param("params/block_1/router", (32, 8)) == "kernel"
