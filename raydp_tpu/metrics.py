@@ -330,7 +330,12 @@ _ALL_METRICS = [
        "load imbalance a dropless layer pays for; `held` the slots routed "
        "to an expert this chip holds (`experts_held`: one chip's share of an "
        "expert-parallel layer computes those and no other; equal to `all` "
-       "where every expert is held). doc/training.md.",
+       "where every expert is held); `moved` the slot rows such a share's "
+       "walk carried between token order and expert order: the held slots "
+       "rounded up to a trip of the walk, layer by layer (`held` <= `moved`; "
+       "`moved` = `all` would mean the share moved every slot, held or "
+       "not). `held` and `moved` are counted only where a share is held. "
+       "doc/training.md.",
        label="kind"),
     _m("train_attention_layers_total", COUNTER, "1", "training",
        "Attention layers of a training model, counted once a built train "

@@ -27,13 +27,41 @@ expert beside all slots (what ``moe_slots_total`` counts).
 (``first_expert``, ``experts_held``: the range ``[first, first + held)`` of
 ``num_experts``), the layer still routes over all of them, with the published
 ``top_k`` and weights normalised over all the chosen, and both auxiliary
-losses over all experts' statistics. Its kernels are ``[held, D, F]``; the
-slots are sorted with the held experts' first, the grouped products are given
-the held experts' group sizes alone, so that a slot of an absent expert is in
-no group, costs no product and adds nothing: the output is the part of the
-layer's result that the held experts give. The shares of all the chips add up
-to the whole layer (``tests/test_swa_moe_lm.py``). Nothing here stands in for
-the absent chips or their exchange. ``slots_held`` counts the slots computed.
+losses over all experts' statistics. Its kernels are ``[held, D, F]`` and its
+output is the part of the layer's result that the held experts give: the
+shares of all the chips add up to the whole layer
+(``tests/test_swa_moe_lm.py``). Nothing here stands in for the absent chips
+or their exchange.
+
+A share moves and touches the held slots' rows and no others. The slots are
+sorted with the held experts' first; how many there are, ``S``, is a run-time
+value with no bound below ``top_k * N`` (dropless: no slot of a held expert
+is dropped, at any routing), so the share walks the first ``S`` positions of
+the sorted order ``c`` at a time, ``ceil(S / c)`` trips of a ``while``, none
+when no slot is held (:func:`_held_share`). A trip gathers its ``c`` token
+rows, gives the three grouped products the held groups' sizes clipped to the
+trip, masks the rows of the last trip after the held slots (whatever a
+grouped product leaves in rows of no group goes no further) and writes its
+``c`` output rows at their place in an expert-order buffer that nothing
+initialises; each token then sums its ``top_k`` slots' rows from that buffer
+(``top_k`` gathers of ``N`` rows, an absent slot reading nothing: the one move
+of ``top_k * N`` rows a pass keeps). The loop has no reverse rule, so the
+backward is written here and walks the same trips: a slot's output gradient
+is its token's row of ``g`` (a gather, token order -> expert order), its
+weight's gradient a row dot product in expert order, the kernels' gradients
+are summed over the trips in float32, and the input's gradient comes back as
+the forward's output does; it keeps the layer's inputs and the routing's small
+arrays and forms the two first products again, so a block that is recomputed
+anyway (``remat_blocks``) has nothing to recompute here. ``c`` follows from the shapes
+(:func:`_chunk_rows`: half the even share ``top_k * N * held / E``).
+``slots_held`` counts the slots computed, ``slots_moved`` the rows the trips
+carried (``ceil(S / c) * c``).
+
+The layer that holds every expert keeps the single-shot path above:
+``top_k * N`` is then the exact number of rows, known when the step is built,
+and one product over all of them is what the chip runs fastest. The two are
+told apart by what the layer is configured with (``experts_held <
+num_experts``), not by an option.
 
 The router's logits can be handed in (a model whose router reads another
 activation than the experts' input computes them itself with
@@ -90,23 +118,198 @@ def _permute_bwd(inverse, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-@jax.custom_vjp
-def _where_rows(x, keep):
-    """Rows of ``x`` where ``keep``, zeros elsewhere, and the same of the
-    gradient: whatever a grouped product leaves in the rows that belong to no
-    group (forward or transposed) goes no further."""
-    return jnp.where(keep[:, None], x, 0)
+def _chunk_rows(slots: int, held: int, experts: int, dtype) -> int:
+    """Rows a trip of the held share's walk carries: half the even share
+    ``slots * held / experts`` (even routing takes two trips, the held
+    experts at one and a half times their share three), rounded up to the
+    dtype's sublane tile (8 rows of 32 bits) so that every trip's rows start
+    on a tile. A trip costs 1-2 ms at the published widths whatever it
+    carries (the kernels' float32 gradients are read and written once a
+    trip), a trip's rows are what the step's temporaries grow with, and the
+    rows of the last trip after the held slots cost next to nothing:
+    doc/long_context.md has the measurements."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return max(tile, -(-slots * held // (experts * 2 * tile)) * tile)
 
 
-def _where_rows_fwd(x, keep):
-    return _where_rows(x, keep), keep
+def _transposed(product, like):
+    """The transpose of a grouped product that is linear in its one
+    argument of shape and dtype ``like``: what autodiff would emit for it."""
+    pull = jax.linear_transpose(
+        product, jax.ShapeDtypeStruct(like.shape, like.dtype))
+    return lambda g: pull(g)[0]
 
 
-def _where_rows_bwd(keep, g):
-    return jnp.where(keep[:, None], g, 0), None
+class _Walk:
+    """The held slots of a sorted order, ``chunk`` positions a trip: what
+    the forward and the backward walk of :func:`_held_share` share. The
+    sorted order holds the held experts' slots first, expert by expert
+    (``sizes``), ``total`` of them; ``trips = ceil(total / chunk)`` is a
+    run-time value, zero when no slot is held."""
+
+    def __init__(self, order, sizes, chunk):
+        self.chunk = chunk
+        slots = order.shape[0]
+        self.rows = -(-slots // chunk) * chunk      # the buffers' rows
+        self.order = jnp.pad(order, (0, self.rows - slots))
+        self.ends = jnp.cumsum(sizes)
+        self.starts = self.ends - sizes
+        self.total = self.ends[-1]
+        self.trips = (self.total + chunk - 1) // chunk
+
+    def trip(self, t):
+        """(first position, the positions' slots ``[chunk]``, which of the
+        positions hold a held slot ``[chunk, 1]``, the held groups' sizes
+        clipped to the trip)."""
+        lo = t * self.chunk
+        slot = jax.lax.dynamic_slice(self.order, (lo,), (self.chunk,))
+        live = (lo + jnp.arange(self.chunk) < self.total)[:, None]
+        part = (jnp.clip(self.ends, lo, lo + self.chunk)
+                - jnp.clip(self.starts, lo, lo + self.chunk))
+        return lo, slot, live, part
+
+    def buffer(self, dtype, *width):
+        """Rows for the trips to write; what no trip wrote is not read."""
+        return jax.lax.empty((self.rows, *width), dtype)
+
+    def run(self, body, buffers):
+        return jax.lax.fori_loop(0, self.trips, body, buffers)
 
 
-_where_rows.defvjp(_where_rows_fwd, _where_rows_bwd)
+def _rows_of(x, index):
+    """``x[index]`` for indices known to lie inside ``x``."""
+    return jnp.asarray(x).at[index].get(mode="promise_in_bounds")
+
+
+def _put(buffer, rows, lo):
+    return jax.lax.dynamic_update_slice_in_dim(buffer, rows, lo, axis=0)
+
+
+def _to_tokens(buffer, inverse, held, top_k, weights=None):
+    """Expert-order rows ``[rows, D]``, of which the first ``held`` are
+    written, -> ``[N, D]`` float32: each token's sum over its ``top_k``
+    slots' rows (times ``weights [N, top_k]``), a slot of no held expert
+    adding nothing. The one gather of ``top_k * N`` rows a pass keeps, as
+    ``top_k`` gathers of ``N`` rows: no ``[N, top_k, D]`` array is laid out
+    (``top_k`` is no multiple of a tile's 8 sublanes)."""
+    index = inverse.reshape(-1, top_k)
+    total = 0.0
+    for j in range(top_k):
+        rows = jnp.where((index[:, j] < held)[:, None],
+                         _rows_of(buffer, index[:, j]), 0)
+        rows = rows.astype(jnp.float32)
+        total = total + (rows if weights is None
+                         else rows * weights[:, j, None])
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _held_share(h, weights, gate, up, down, order, inverse, sizes,
+                top_k: int, activation: str, chunk: int):
+    """What the held experts give each token: ``sum_j weights[n, j] *
+    expert(h[n])`` over the token's slots ``j`` on a held expert, by a walk
+    over the held slots, ``chunk`` of them a trip. A trip gathers its slots'
+    token rows, runs the three grouped products over the held groups' sizes
+    clipped to the trip and writes its rows of the expert-order output;
+    :func:`_to_tokens` brings the output to token order. The backward walks
+    the same trips (a ``while`` with a run-time bound has no reverse rule, so
+    it is written here): a slot's output gradient is its token's row of ``g``
+    (a gather, token order -> expert order) times its weight, its weight's
+    gradient a row dot product in expert order, the kernels' gradients add up
+    over the trips in float32, the input's gradient comes back to token order
+    as the output does. It keeps the inputs alone and forms both first
+    products again: under a recomputed block (``remat_blocks``) the
+    recomputed forward then has nothing to do."""
+    act_fn = ACTIVATIONS[activation]
+    walk = _Walk(order, sizes, chunk)
+    gate, up, down = (w.astype(h.dtype) for w in (gate, up, down))
+
+    def body(t, out_rows):
+        lo, slot, live, part = walk.trip(t)
+        with jax.named_scope("dispatch"):
+            xs = _rows_of(h, slot // top_k)
+        with jax.named_scope("experts"):
+            act = act_fn(jax.lax.ragged_dot(xs, gate, part)) \
+                * jax.lax.ragged_dot(xs, up, part)
+            # whatever a grouped product leaves in the rows of no group
+            # goes no further
+            out = jnp.where(live, jax.lax.ragged_dot(act, down, part), 0)
+        return _put(out_rows, out, lo)
+
+    out = walk.run(body, walk.buffer(h.dtype, h.shape[1]))
+    with jax.named_scope("combine"):
+        return _to_tokens(out, inverse, walk.total, top_k,
+                          weights).astype(h.dtype)
+
+
+def _held_share_fwd(h, weights, gate, up, down, order, inverse, sizes,
+                    top_k, activation, chunk):
+    inputs = (h, weights, gate, up, down, order, inverse, sizes)
+    return _held_share(*inputs, top_k, activation, chunk), inputs
+
+
+def _held_share_bwd(top_k, activation, chunk, residuals, g):
+    h, weights, gate, up, down, order, inverse, sizes = residuals
+    act_fn = ACTIVATIONS[activation]
+    walk = _Walk(order, sizes, chunk)
+    dtype, dim, f = h.dtype, h.shape[1], gate.shape[-1]
+    kernels = tuple(w.astype(dtype) for w in (gate, up, down))
+    flat = weights.reshape(-1)
+    rows_d = jax.ShapeDtypeStruct((chunk, dim), dtype)
+    rows_f = jax.ShapeDtypeStruct((chunk, f), dtype)
+
+    def body(t, carried):
+        d_xs_rows, d_weight_rows, d_kernels = carried
+        lo, slot, live, part = walk.trip(t)
+        with jax.named_scope("dispatch"):
+            token = slot // top_k
+            xs, g_rows = _rows_of(h, token), _rows_of(g, token)
+            weight = _rows_of(flat, slot)[:, None]
+        with jax.named_scope("experts"):
+            act, act_pull = jax.vjp(
+                lambda a, b: act_fn(a) * b,
+                jax.lax.ragged_dot(xs, kernels[0], part),
+                jax.lax.ragged_dot(xs, kernels[1], part))
+            # the slot's output is act @ down and its gradient weight * g:
+            # the weight's own gradient <output, g> is <act, g @ down^T>, so
+            # the weight goes on after that product, and on act for down's
+            d_act = _transposed(lambda a: jax.lax.ragged_dot(
+                a, kernels[2], part), rows_f)(g_rows)
+            d_weight = jnp.where(live[:, 0], jnp.sum(
+                act.astype(jnp.float32) * d_act.astype(jnp.float32),
+                axis=-1), 0)
+            d_gate, d_up = act_pull(
+                (d_act.astype(jnp.float32) * weight).astype(dtype))
+            d_xs = (_transposed(lambda a: jax.lax.ragged_dot(
+                a, kernels[0], part), rows_d)(d_gate)
+                    + _transposed(lambda a: jax.lax.ragged_dot(
+                        a, kernels[1], part), rows_d)(d_up))
+            d_xs = jnp.where(live, d_xs, 0)
+            weighed = (act.astype(jnp.float32) * weight).astype(dtype)
+            # the kernels' gradients add up over the trips in float32
+            d_kernels = tuple(
+                total + _transposed(lambda w, lhs=lhs: jax.lax.ragged_dot(
+                    lhs, w, part), kernel)(rhs).astype(jnp.float32)
+                for total, lhs, rhs, kernel in zip(
+                    d_kernels, (xs, xs, weighed), (d_gate, d_up, g_rows),
+                    kernels))
+        return (_put(d_xs_rows, d_xs, lo), _put(d_weight_rows, d_weight, lo),
+                d_kernels)
+
+    d_xs, d_weight, d_kernels = walk.run(body, (
+        walk.buffer(dtype, dim), walk.buffer(jnp.float32),
+        tuple(jnp.zeros(w.shape, jnp.float32) for w in kernels)))
+    d_kernels = tuple(d.astype(w.dtype)
+                      for d, w in zip(d_kernels, (gate, up, down)))
+    with jax.named_scope("dispatch"):
+        # a token's gradient is the sum over its top_k slots
+        d_h = _to_tokens(d_xs, inverse, walk.total, top_k).astype(dtype)
+    d_weights = jnp.where(inverse < walk.total, _rows_of(d_weight, inverse),
+                          0).reshape(weights.shape)
+    return (d_h, d_weights) + d_kernels + (None, None, None)
+
+
+_held_share.defvjp(_held_share_fwd, _held_share_bwd)
 
 
 def router_logits(h: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
@@ -136,7 +339,8 @@ ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
 class MoE(nn.Module):
     """``x [..., D]`` -> (``y [..., D]``, ``aux``) with ``aux`` a dict of
     float32 scalars: ``balance``, ``z``, ``slots_max``, ``slots_all`` and,
-    where only a share of the experts is held, ``slots_held``."""
+    where only a share of the experts is held, ``slots_held`` and
+    ``slots_moved``."""
 
     num_experts: int
     top_k: int
@@ -188,23 +392,31 @@ class MoE(nn.Module):
                 # groups end where they end
                 slots = (slots - first) % e
                 sizes = sizes[first:first + held]
-                here = jnp.arange(k * n) < jnp.sum(sizes)
-                aux["slots_held"] = jnp.sum(sizes).astype(jnp.float32)
+                chunk = _chunk_rows(k * n, held, e, self.dtype)
+                slots_held = jnp.sum(sizes)
+                aux["slots_held"] = slots_held.astype(jnp.float32)
+                # the rows the walk carries: the held slots, rounded up to
+                # a trip
+                aux["slots_moved"] = (-(-slots_held // chunk)
+                                      * chunk).astype(jnp.float32)
 
         with jax.named_scope("dispatch"):
             order = jnp.argsort(slots, stable=True)
             inverse = jnp.argsort(order)
+        if share:
+            y = _held_share(h.astype(self.dtype), weights, gate, up, down,
+                            order, inverse, sizes, k, self.activation, chunk)
+            return y.reshape(*lead, dim), aux
+
+        # every expert held: top_k * N is the exact number of rows, in one go
+        with jax.named_scope("dispatch"):
             xs = _dispatch(h.astype(self.dtype), order, inverse, k)
-            if share:
-                xs = _where_rows(xs, here)
 
         with jax.named_scope("experts"):
             cast = lambda w: w.astype(self.dtype)  # noqa: E731
             act = act_fn(jax.lax.ragged_dot(xs, cast(gate), sizes)) \
                 * jax.lax.ragged_dot(xs, cast(up), sizes)
             out = jax.lax.ragged_dot(act, cast(down), sizes)    # [k * N, D]
-            if share:
-                out = _where_rows(out, here)
 
         with jax.named_scope("combine"):
             back = _permute(out, inverse, order).reshape(n, k, dim)

@@ -339,14 +339,15 @@ class TransformerLM(nn.Module):
 
     @property
     def _slot_kinds(self):
-        return ("max", "all", "held") if self._share else ("max", "all")
+        return ("max", "all", "held", "moved") if self._share \
+            else ("max", "all")
 
     @property
     def loss_counters(self):
         """What the second output of :meth:`loss_rows` counts, as (registry
         counter, label) pairs."""
-        labels = {"max": "max_expert", "all": "all", "held": "held"}
-        return tuple(("moe_slots_total", labels[kind])
+        labels = {"max": "max_expert"}     # the other kinds label themselves
+        return tuple(("moe_slots_total", labels.get(kind, kind))
                      for kind in self._slot_kinds) if self.num_experts else ()
 
     def loss_rows(self, tokens, labels, weights):

@@ -294,11 +294,14 @@ def test_a_shares_gradients_match_the_references(first):
 
 
 def test_the_grouped_products_are_given_the_held_groups_alone():
-    """The slots sort with the held experts' first and the three products get
-    the held experts' group sizes: their sum is ``slots_held``, and the rows
-    after it are masked in and out (whatever a grouped product leaves in
-    rows that belong to no group goes nowhere)."""
+    """The slots sort with the held experts' first and the walk's trips hand
+    the three products the held experts' group sizes clipped to the trip:
+    fewer rows than all the slots, a trip's sizes sum to its held rows and
+    all trips' to ``slots_held``; the rows of a trip after them are masked
+    (whatever a grouped product leaves in rows that belong to no group goes
+    nowhere)."""
     import jax
+    import jax.numpy as jnp
     from raydp_tpu.models import moe
 
     full, router, m, u = _expert_layer()
@@ -306,24 +309,224 @@ def test_the_grouped_products_are_given_the_held_groups_alone():
     real = jax.lax.ragged_dot
 
     def spy(lhs, rhs, sizes, **kw):
-        seen.append((lhs.shape, rhs.shape, np.asarray(sizes)))
+        jax.debug.callback(lambda s, shapes=(lhs.shape, rhs.shape): seen.append(
+            shapes + (np.asarray(s),)), sizes, ordered=True)
         out = real(lhs, rhs, sizes, **kw)
         # poison the rows that belong to no group
-        rows = np.arange(lhs.shape[0])[:, None] >= int(np.sum(sizes))
-        return out + np.where(rows, 1e6, 0.0).astype(np.float32)
+        rows = jnp.arange(lhs.shape[0])[:, None] >= jnp.sum(sizes)
+        return out + jnp.where(rows, 1e6, 0.0).astype(jnp.float32)
 
     y0, aux = _program_share(_share_of(full, 2, 2), router, m, u, 2, 2)
     jax.lax.ragged_dot = spy
     try:
         y1, _ = _program_share(_share_of(full, 2, 2), router, m, u, 2, 2)
+        jax.effects_barrier()
     finally:
         jax.lax.ragged_dot = real
     assert moe.jax.lax.ragged_dot is real
-    assert [s[1] for s in seen] == [(2, 32, 16), (2, 32, 16), (2, 16, 32)]
-    for lhs, _, sizes in seen:
-        assert lhs[0] == 3 * 48 and sizes.shape == (2,)
-        assert sizes.sum() == float(aux["slots_held"]) < 3 * 48
+    chunk = moe._chunk_rows(3 * 48, 2, 8, np.float32)
+    held = int(aux["slots_held"])
+    trips = -(-held // chunk)
+    assert 1 < trips and trips * chunk == float(aux["slots_moved"]) < 3 * 48
+    assert [s[1] for s in seen] == [(2, 32, 16), (2, 32, 16),
+                                    (2, 16, 32)] * trips
+    for i, (lhs, _, sizes) in enumerate(seen):
+        assert lhs[0] == chunk < 3 * 48 and sizes.shape == (2,)
+        assert sizes.sum() == min(chunk, held - (i // 3) * chunk)
+    assert sum(s[2].sum() for s in seen[::3]) == held < 3 * 48
     np.testing.assert_allclose(y1, y0, rtol=1e-5, atol=1e-5)
+
+
+# the parent's formulation of a share (PR 31): every one of the top_k * N slot
+# rows gathered, masked in and out, multiplied and permuted back, the grouped
+# products given the held groups' sizes. What the walk over the held slots is
+# compared with, and what the layer that holds every expert still runs.
+def _full_size_share(kernels, logits, m, first, held, e=8, k=3,
+                     normalize=True):
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+
+    @jax.custom_vjp
+    def where_rows(x, keep):
+        return jnp.where(keep[:, None], x, 0)
+
+    where_rows.defvjp(lambda x, keep: (where_rows(x, keep), keep),
+                      lambda keep, g: (jnp.where(keep[:, None], g, 0), None))
+    n, dim = m.shape
+    share = held < e
+    _, ids, weights = moe.route(logits, k, normalize)
+    slots = ids.reshape(-1)
+    sizes = jnp.sum(jax.nn.one_hot(slots, e, dtype=jnp.int32), axis=0)
+    if share:
+        slots = (slots - first) % e
+        sizes = sizes[first:first + held]
+        here = jnp.arange(k * n) < jnp.sum(sizes)
+    order = jnp.argsort(slots, stable=True)
+    inverse = jnp.argsort(order)
+    xs = moe._dispatch(m, order, inverse, k)
+    if share:
+        xs = where_rows(xs, here)
+    act = jax.nn.relu(jax.lax.ragged_dot(xs, kernels["experts_gate"], sizes)) \
+        * jax.lax.ragged_dot(xs, kernels["experts_up"], sizes)
+    out = jax.lax.ragged_dot(act, kernels["experts_down"], sizes)
+    if share:
+        out = where_rows(out, here)
+    back = moe._permute(out, inverse, order).reshape(n, k, dim)
+    return jnp.sum(back.astype(jnp.float32) * weights[..., None], axis=1)
+
+
+# experts 2-5 of 8 held, 3 a token over 48 tokens: 144 slots, a trip of the
+# walk carries 40 (half the even share of 72, up to a tile of 8 rows)
+def _routed(routing, seed=0):
+    """Seeded logits [48, 8] that send the slots where ``routing`` says, and
+    the number of slots on the held experts 2-5."""
+    logits = np.random.default_rng(seed).normal(size=(48, 8))
+    held, absent = [2, 3, 4, 5], [0, 1, 6, 7]
+    if routing == "all_on_held_experts":
+        logits[:, held] += 20
+    elif routing == "none_on_held_experts":
+        logits[:, absent] += 20
+    elif routing == "one_past_a_trip":
+        # 41 tokens with one slot on a held expert, 7 with none
+        logits[:, absent[:3]] += 20
+        logits[:41, absent[2]] -= 20
+        logits[np.arange(41), np.asarray(held)[np.arange(41) % 4]] += 20
+    ids = np.argsort(-logits, axis=1)[:, :3]
+    return logits.astype(np.float32), int(np.isin(ids, held).sum())
+
+
+ROUTINGS = {"random": None, "all_on_held_experts": 144,
+            "none_on_held_experts": 0, "one_past_a_trip": 41}
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_walk_over_the_held_slots_drops_none_at_any_routing(routing):
+    """Output and every gradient (held kernels, handed-in logits, the
+    experts' input) against the reference and against the full-size
+    formulation: with every slot on a held expert (the most trips), with none
+    (no trip: zeros, exactly), one slot past a trip's end, and as seeded."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    _, _, reference = _files()
+    full, _, m, _ = _expert_layer(seed=5)
+    kernels = _share_of(full, 2, 4)
+    logits, held = _routed(routing)
+    assert moe._chunk_rows(144, 4, 8, np.float32) == 40
+    if ROUTINGS[routing] is not None:
+        assert held == ROUTINGS[routing]
+    cfg = {"moe_num_primary_experts": 8, "moe_num_active_primary_experts": 3,
+           "norm_topk_prob": True, "first_expert": 2, "experts_held": 4}
+    layer = moe.MoE(8, 3, 16, first_expert=2, experts_held=4,
+                    activation="relu", normalize_top_k=True)
+    w = np.random.default_rng(9).normal(size=m.shape).astype(np.float32)
+
+    def graded(fn):
+        return jax.value_and_grad(
+            lambda k, lg, m: jnp.sum(fn(k, lg, m) * w), (0, 1, 2))(
+                kernels, logits, m)
+
+    y, aux = layer.apply({"params": kernels}, m, logits)
+    assert float(aux["slots_held"]) == held
+    assert float(aux["slots_moved"]) == -(-held // 40) * 40
+    got = graded(lambda k, lg, m: layer.apply({"params": k}, m, lg)[0])
+    for other in (lambda k, lg, m: reference.expert_layer(k, m, lg, cfg),
+                  lambda k, lg, m: _full_size_share(k, lg, m, 2, 4)):
+        np.testing.assert_allclose(y, other(kernels, logits, m),
+                                   rtol=1e-4, atol=1e-5)
+        want = graded(other)
+        _close(got[1], want[1])
+    if held == 0:
+        assert not np.any(np.asarray(y))
+        assert not any(np.any(g) for g in _leaves(got[1]).values())
+    else:
+        assert np.abs(np.asarray(got[1][1])).max() > 1e-4
+
+
+def _primitives_over(jaxpr, rows, seen=None):
+    """Every (primitive, output shape) in a jaxpr and its sub-jaxprs whose
+    output has ``rows`` leading rows or more and a trailing dimension."""
+    import jax
+    seen = [] if seen is None else seen
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if len(shape) >= 2 and np.prod(shape[:-1]) >= rows:
+                seen.append((eqn.primitive.name, tuple(shape)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives_over(sub, rows, seen)
+    return seen
+
+
+def test_a_share_moves_no_full_size_rows_but_the_gather_to_token_order():
+    """The jaxpr of a share's forward and backward: arrays of ``top_k * N``
+    rows of width D or F are the buffers the trips write into (uninitialised,
+    updated a trip's rows at a time, carried by the loops) and nothing else:
+    no gather, no ``where``, no elementwise op and no product over them. Rows
+    come back to token order as ``top_k`` gathers of ``N`` rows a pass, the
+    forward's and its mirror in the backward. The full-size formulation has
+    all of those."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    full, _, m, _ = _expert_layer()
+    kernels, (logits, _) = _share_of(full, 2, 4), _routed("random")
+    layer = moe.MoE(8, 3, 16, first_expert=2, experts_held=4,
+                    activation="relu", normalize_top_k=True)
+
+    def ops(fn, rows):
+        text = jax.make_jaxpr(jax.grad(
+            lambda k, lg, m: jnp.sum(fn(k, lg, m)), (0, 1, 2)))(
+                kernels, logits, m)
+        wide = {}
+        for name, shape in _primitives_over(text.jaxpr, rows):
+            if shape[-1] in (16, 32):           # F and D; not [N, E] routing
+                wide.setdefault(name, []).append(shape)
+        return wide
+
+    walk = ops(lambda k, lg, m: layer.apply({"params": k}, m, lg)[0], 144)
+    assert set(walk) == {"empty", "dynamic_update_slice", "while"}, walk
+    # the forward's output rows and the backward's gradient rows, in expert
+    # order (144 slots in trips of 40: 160 rows); nothing of width F is kept
+    assert sorted(walk["empty"]) == [(160, 32), (160, 32)]
+    by_tokens = ops(lambda k, lg, m: layer.apply({"params": k}, m, lg)[0], 40)
+    assert by_tokens["gather"].count((48, 32)) == 2 * 3
+    assert {s[0] for s in by_tokens["gather"]} == {48, 40}     # N, a trip
+    full_size = ops(lambda k, lg, m: _full_size_share(k, lg, m, 2, 4), 144)
+    assert len(full_size["gather"]) == 4 and len(full_size["select_n"]) >= 4
+    assert {"ragged_dot_general", "max", "mul"} <= set(full_size)
+
+
+@pytest.mark.parametrize("handed_in", [False, True],
+                         ids=["own_router", "logits_handed_in"])
+def test_a_layer_that_holds_every_expert_is_the_layer_as_it_was(handed_in):
+    """``experts_held`` all of them (or not given): the forward and backward
+    jaxpr is the full-size formulation's, text for text."""
+    import jax
+    import jax.numpy as jnp
+    from jax.interpreters import partial_eval as pe
+    from raydp_tpu.models import moe
+    full, router, m, u = _expert_layer()
+
+    def text(fn):
+        # without what only the layer's ``aux`` reads
+        closed = jax.make_jaxpr(jax.grad(
+            lambda k, r, m: jnp.sum(fn(k, r, m)), (0, 1, 2)))(
+                full, router, m)
+        return str(pe.dce_jaxpr(closed.jaxpr, [True] * 5)[0])
+
+    layer = moe.MoE(8, 3, 16, activation="relu", normalize_top_k=True)
+    if handed_in:
+        got = text(lambda k, r, m: layer.apply(
+            {"params": k}, m, moe.router_logits(u, r))[0])
+    else:
+        got = text(lambda k, r, m: layer.apply(
+            {"params": dict(k, router=r)}, m)[0])
+    want = text(lambda k, r, m: _full_size_share(
+        k, moe.router_logits(u if handed_in else m, r), m, 0, 8))
+    assert "while[" not in got and got.count("= ragged_dot_general[") == 9
+    assert got == want
 
 
 # ----------------------------------------------------- (c) the whole model
@@ -362,7 +565,8 @@ def test_the_parameter_tree_is_the_published_layers():
     assert model.attention_layers == {"window": 3, "full": 1}
     assert model.loss_counters == (("moe_slots_total", "max_expert"),
                                    ("moe_slots_total", "all"),
-                                   ("moe_slots_total", "held"))
+                                   ("moe_slots_total", "held"),
+                                   ("moe_slots_total", "moved"))
 
 
 @pytest.mark.parametrize("dtype,attention,tol", [
@@ -424,6 +628,12 @@ def test_loss_rows_and_every_gradient_leaf_match_the_reference(remat):
     assert float(counts[1]) == tokens.size * 3 * 4
     assert float(counts[0]) == per_expert.max(axis=1).sum()
     assert float(counts[2]) == per_expert[:, 2:4].sum() < float(counts[1])
+    # 384 slots a layer, 2 of 8 experts held: trips of 48 rows
+    from raydp_tpu.models import moe
+    assert moe._chunk_rows(384, 2, 8, np.float32) == 48
+    assert float(counts[3]) == sum(
+        -(-held // 48) * 48 for held in per_expert[:, 2:4].sum(axis=1))
+    assert float(counts[2]) <= float(counts[3]) < float(counts[1])
 
 
 def test_the_pattern_decides_each_layers_window_and_rope():
@@ -500,10 +710,43 @@ def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
     after = registry.snapshot()["counters"]
     slots = _moved(before, after, "moe_slots_total")
     assert slots["all"] == 3 * rows * 256 * 2 * 4   # epochs, tokens, top-2, layers
-    assert 0 < slots["held"] < slots["all"]
+    assert 0 < slots["held"] <= slots["moved"] < slots["all"]
+    assert slots["moved"] % 64 == 0     # 512 slots a layer: trips of 64 rows
     assert slots["all"] / 8 <= slots["max_expert"] <= slots["all"]
     assert _moved(before, after, "train_attention_layers_total") == {
         "window": 3, "full": 1}
+
+
+def test_moved_slots_are_counted_where_a_share_is_held_and_only_there(
+        session, tmp_path):
+    """A fit of the share reads ``held <= moved < all`` from the registry
+    (the walk carried the held slots rounded up to its trips, and not every
+    slot); a model that holds every expert still counts two entries."""
+    import jax
+    from raydp_tpu import metrics as registry
+    from raydp_tpu.parallel import make_mesh
+
+    cfg, pipeline, _ = _files()
+    df, info = _token_frame(session, tmp_path, cfg, pipeline, 8, 2)
+    mesh = make_mesh(None, devices=jax.devices()[:1])
+    before = registry.snapshot()["counters"]
+    _estimator(cfg, pipeline, info, mesh, num_epochs=1,
+               batch_size=4).fit_on_frame(df)
+    slots = _moved(before, registry.snapshot()["counters"], "moe_slots_total")
+    assert set(slots) >= {"max_expert", "all", "held", "moved"}
+    assert slots["all"] == 8 * 32 * 3 * 4       # tokens, top-3, layers
+    assert 0 < slots["held"] <= slots["moved"] < slots["all"]
+    assert slots["moved"] % 48 == 0
+    assert slots["moved"] - slots["held"] < 2 * 4 * 48  # steps x layers
+
+    whole = pipeline.build_model(dict(cfg, first_expert=0, experts_held=8))
+    assert whole.loss_counters == (("moe_slots_total", "max_expert"),
+                                   ("moe_slots_total", "all"))
+    tokens = _tokens(cfg, 2)
+    _, counts = whole.apply(
+        {"params": _params(whole, tokens)}, tokens, tokens,
+        np.full(2, 0.5, np.float32), method=whole.loss_rows)
+    assert counts.shape == (2,) and float(counts[1]) == 2 * 32 * 3 * 4
 
 
 def test_an_expert_sharded_fit_of_the_share_gives_the_one_device_losses(
