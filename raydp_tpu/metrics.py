@@ -337,6 +337,14 @@ _ALL_METRICS = [
        "not). `held` and `moved` are counted only where a share is held. "
        "doc/training.md.",
        label="kind"),
+    _m("moe_router_bias_spread", GAUGE, "1", "training",
+       "Sigmoid routing's balancing bias (`routing=\"sigmoid\"`: experts "
+       "picked by score + bias, auxiliary-loss-free balancing): max(bias) - "
+       "min(bias) of the expert layer where it is widest, as the last train "
+       "step of an epoch read it. The bias moves by `bias_update_rate` an "
+       "optimizer step towards the experts short of slots, so the spread "
+       "says how far the router's own scores are from balanced. "
+       "doc/training.md."),
     _m("train_attention_layers_total", COUNTER, "1", "training",
        "Attention layers of a training model, counted once a built train "
        "step by kind: `window` (a sliding window: a query sees itself and "
@@ -507,9 +515,21 @@ _ALL_SPANS = [
        "Under `attn`: the attention itself of a sliding-window layer (the "
        "kernels `rdt_flash_win_fwd`, `rdt_flash_win_bwd_dkdv`, "
        "`rdt_flash_win_bwd_dq`).", kind=SCOPE),
+    _s("attn_gate", "model",
+       "Under `attn`: the attention output times sigmoid of its gate "
+       "projection (`attention_gate`), before the output projection.",
+       kind=SCOPE),
+    _s("mlp", "model",
+       "A transformer block's dense feed-forward (SwiGLU): the three "
+       "products, SiLU and the gate.", kind=SCOPE),
     _s("moe/router", "model",
        "Sparse expert layer (`models/moe.py`): float32 router product, "
-       "softmax, top-k, group sizes and both auxiliary losses.", kind=SCOPE),
+       "softmax or sigmoid (with its balancing bias), top-k, group sizes "
+       "and both auxiliary losses.", kind=SCOPE),
+    _s("moe/shared", "model",
+       "Sparse expert layer: the shared expert every token takes "
+       "(`shared_dim`), its three products, activation and gate.",
+       kind=SCOPE),
     _s("moe/dispatch", "model",
        "Sparse expert layer: the sort of the slots by expert and the gather "
        "of the tokens into that order.", kind=SCOPE),

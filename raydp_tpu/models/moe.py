@@ -66,12 +66,31 @@ num_experts``), not by an option.
 The router's logits can be handed in (a model whose router reads another
 activation than the experts' input computes them itself with
 :func:`router_logits`); the layer then holds no router kernel.
+
+**Sigmoid routing with a balancing bias** (``routing="sigmoid"``; auxiliary-
+loss-free balancing). The scores are ``sigmoid(logits)``, each expert's own.
+The ``top_k`` experts are *picked* by ``score + bias`` and *weighed* by the
+bare scores, renormalised over the chosen (``normalize_top_k``) and scaled
+(``route_scale``). ``bias [E]`` is float32 state that no gradient moves: it
+lies in the variable collection :data:`STATE` (the one
+:class:`raydp_tpu.train.FlaxEstimator` carries, shards and checkpoints beside
+the parameters, with no optimizer moments), a forward pass under which the
+collection is mutable adds the slots each of **all** ``E`` experts was picked
+for to ``counts`` next to it, and :func:`balance_bias`, which the model runs
+once an optimizer step after the gradients are applied, moves the bias by
+``rate * sign(mean(counts) - counts)``, centred, and empties the counts: the
+forward of a step reads the bias the step before left.
+
+**A shared expert** (``shared_dim``): one more gated MLP of that width that
+every token takes, beside the routed ones and unweighted. A share of the
+layer (``experts_held``) holds it whole, so when the chips' shares are added
+up it counts once.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -320,17 +339,57 @@ def router_logits(h: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def route(logits: jnp.ndarray, top_k: int, normalize: bool = False
+def route(logits: jnp.ndarray, top_k: int, normalize: bool = False,
+          kind: str = "softmax", bias: Optional[jnp.ndarray] = None,
+          scale: float = 1.0
           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Float32 router logits ``[N, E]`` -> (probabilities ``[N, E]``, the
-    ``top_k`` expert ids ``[N, top_k]``, their weights: their probabilities
-    or, ``normalize``, those over their sum: the softmax over the chosen
-    logits alone)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, ids = jax.lax.top_k(probs, top_k)
+    """Float32 router logits ``[N, E]`` -> (scores ``[N, E]``, the ``top_k``
+    expert ids ``[N, top_k]``, their weights). ``kind="softmax"``: the scores
+    are the softmax's probabilities, the experts those of the largest, the
+    weights their probabilities or, ``normalize``, those over their sum (the
+    softmax over the chosen logits alone). ``kind="sigmoid"``: the scores are
+    ``sigmoid(logits)``, the experts are picked by ``score + bias`` (``bias
+    [E]``, outside the gradient) and weighed by the bare scores, over their
+    sum where ``normalize``, times ``scale``."""
+    logits = logits.astype(jnp.float32)
+    if kind == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, ids = jax.lax.top_k(probs, top_k)
+        if normalize:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return probs, ids, weights
+    if kind != "sigmoid":
+        raise ValueError(f"routing {kind!r}: 'softmax' or 'sigmoid'")
+    scores = jax.nn.sigmoid(logits)
+    picked_by = scores if bias is None else scores + jax.lax.stop_gradient(
+        bias.astype(jnp.float32))
+    _, ids = jax.lax.top_k(picked_by, top_k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
     if normalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return probs, ids, weights
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return scores, ids, weights * scale
+
+
+# the variable collection the balancing bias lives in: the one the estimator
+# carries through its train step beside the parameters (donated, sharded,
+# checkpointed; no gradient, no weight decay, no optimizer moments)
+STATE = "batch_stats"
+
+
+def balance_bias(state, rate: float):
+    """One update of every expert layer's balancing bias in a ``STATE``
+    collection: ``delta = rate * sign(mean(counts) - counts)``, ``bias +=
+    delta - mean(delta)``, and the counts start again at zero. ``counts`` are
+    the slots each expert was picked for since the last update (every
+    micro-batch of an optimizer step adds its own)."""
+    if not isinstance(state, Mapping):
+        return state
+    if "bias" in state and "counts" in state:
+        counts = state["counts"]
+        delta = rate * jnp.sign(jnp.mean(counts) - counts)
+        return {**state, "bias": state["bias"] + delta - jnp.mean(delta),
+                "counts": jnp.zeros_like(counts)}
+    return type(state)({k: balance_bias(v, rate) for k, v in state.items()})
 
 
 ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
@@ -338,9 +397,9 @@ ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
 
 class MoE(nn.Module):
     """``x [..., D]`` -> (``y [..., D]``, ``aux``) with ``aux`` a dict of
-    float32 scalars: ``balance``, ``z``, ``slots_max``, ``slots_all`` and,
-    where only a share of the experts is held, ``slots_held`` and
-    ``slots_moved``."""
+    float32 scalars: ``balance``, ``z``, ``slots_max``, ``slots_all``; where
+    only a share of the experts is held, ``slots_held`` and ``slots_moved``;
+    under sigmoid routing ``bias_spread``, ``max(bias) - min(bias)``."""
 
     num_experts: int
     top_k: int
@@ -351,6 +410,9 @@ class MoE(nn.Module):
     experts_held: Optional[int] = None      # None: all of them
     activation: str = "silu"
     normalize_top_k: bool = False
+    routing: str = "softmax"                # or "sigmoid", with its bias
+    route_scale: float = 1.0
+    shared_dim: int = 0                     # 0: no shared expert
 
     @nn.compact
     def __call__(self, x, logits=None
@@ -374,11 +436,24 @@ class MoE(nn.Module):
         with jax.named_scope("router"):
             logits = router_logits(h, router) if logits is None \
                 else logits.reshape(n, e)
-            probs, ids, weights = route(logits, k, self.normalize_top_k)
+            bias = None
+            if self.routing == "sigmoid":
+                bias = self.variable(STATE, "bias", jnp.zeros, (e,),
+                                     jnp.float32)
+                counts = self.variable(STATE, "counts", jnp.zeros, (e,),
+                                       jnp.float32)
+            probs, ids, weights = route(
+                logits, k, self.normalize_top_k, self.routing,
+                None if bias is None else bias.value, self.route_scale)
             # read back only by a caller that asks (mutable="intermediates")
             self.sow("intermediates", "top_k_ids", ids)
             slots = ids.reshape(-1)                              # [k * N]
             sizes = jnp.sum(jax.nn.one_hot(slots, e, dtype=jnp.int32), axis=0)
+            if bias is not None and self.is_mutable_collection(STATE) \
+                    and not self.is_initializing():
+                # what balance_bias reads once an optimizer step: all E
+                # experts' slots, whatever is held here
+                counts.value = counts.value + sizes.astype(jnp.float32)
             load = sizes.astype(jnp.float32) / (k * n)
             aux = {
                 "balance": e * jnp.sum(load * jnp.mean(probs, axis=0)),
@@ -387,6 +462,8 @@ class MoE(nn.Module):
                 "slots_max": jnp.max(sizes).astype(jnp.float32),
                 "slots_all": jnp.float32(k * n),
             }
+            if bias is not None:
+                aux["bias_spread"] = jnp.max(bias.value) - jnp.min(bias.value)
             if share:
                 # the held experts' slots sort first, in expert order; the
                 # groups end where they end
@@ -406,7 +483,7 @@ class MoE(nn.Module):
         if share:
             y = _held_share(h.astype(self.dtype), weights, gate, up, down,
                             order, inverse, sizes, k, self.activation, chunk)
-            return y.reshape(*lead, dim), aux
+            return self._with_shared(y, h).reshape(*lead, dim), aux
 
         # every expert held: top_k * N is the exact number of rows, in one go
         with jax.named_scope("dispatch"):
@@ -422,4 +499,20 @@ class MoE(nn.Module):
             back = _permute(out, inverse, order).reshape(n, k, dim)
             y = jnp.sum(back.astype(jnp.float32)
                         * weights[..., None], axis=1).astype(self.dtype)
-        return y.reshape(*lead, dim), aux
+        return self._with_shared(y, h).reshape(*lead, dim), aux
+
+    @nn.nowrap      # no scope of its own: the ops lie under moe/shared
+    def _with_shared(self, y, h):
+        """``y [N, D]`` plus the shared expert's output for the token rows
+        ``h``; ``y`` itself where the layer has none."""
+        if not self.shared_dim:
+            return y
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name,
+            kernel_init=self.kernel_init)
+        with jax.named_scope("shared"):
+            h = h.astype(self.dtype)
+            return y + dense(h.shape[-1], "shared_down")(
+                ACTIVATIONS[self.activation](
+                    dense(self.shared_dim, "shared_gate")(h))
+                * dense(self.shared_dim, "shared_up")(h))

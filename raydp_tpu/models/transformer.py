@@ -41,6 +41,18 @@ recomputes the block in the backward pass. A vocabulary-parallel deployment's
 share is a smaller ``vocab_size``: embedding and head over the rows held,
 ids drawn from them.
 
+And, since the ``afmoe`` family (Trinity): ``qk_norm="head"`` (RMSNorm over
+each head's ``head_dim``, one weight for the query heads and one for the K/V
+heads), ``attention_gate`` (``o(attn * sigmoid(W_g u))``, ``W_g`` to the query
+heads' width), ``sandwich_norms`` (a second norm on each sub-layer's output:
+``x + norm(attn(norm(x)))``, ``x + norm(ffn(norm(x)))``), ``embed_scale`` (the
+embeddings times ``sqrt(dim)``), ``dense_layers`` (that many leading blocks
+keep a dense SwiGLU of width ``dense_ffn_dim`` where the others hold the
+expert layer), the expert layer's ``routing="sigmoid"`` with ``route_scale``
+and the balancing bias that ``bias_update_rate`` moves once an optimizer step
+(:meth:`TransformerLM.after_step`; state outside the parameters, see
+:mod:`raydp_tpu.models.moe`), and ``shared_expert_dim``.
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -97,13 +109,14 @@ class Attention(nn.Module):
     mesh: Any = None
     dtype: Any = jnp.float32
     rope_theta: float = 10000.0
-    qk_norm: bool = False
+    qk_norm: Any = False                    # True: whole projection; "head"
     rms_norm_eps: float = 1e-6
     init_std: Optional[float] = None
     head_dim: Optional[int] = None          # None: dim // num_heads
     num_kv_heads: Optional[int] = None      # None: num_heads
     window: Optional[int] = None            # None: every key up to its own
     rope: bool = True
+    gate: bool = False                      # o(attn * sigmoid(W_g x))
 
     def _dispatch(self, t: int, head_dim: int) -> str:
         from raydp_tpu.ops.flash_attention import kernel_ineligible
@@ -140,7 +153,11 @@ class Attention(nn.Module):
             use_bias=False, kernel_init=init)
         q = dense("q", self.num_heads)(x)
         k, v = dense("k", kv_heads)(x), dense("v", kv_heads)(x)
-        if self.qk_norm:
+        if self.qk_norm == "head":
+            # head by head, over head_dim: one weight for q, one for k
+            q = RMSNorm(self.rms_norm_eps, name="q_norm")(q)
+            k = RMSNorm(self.rms_norm_eps, name="k_norm")(k)
+        elif self.qk_norm:
             # over the whole projection (all heads together), then split
             norm = lambda name, a: RMSNorm(  # noqa: E731
                 self.rms_norm_eps, name=name)(
@@ -166,6 +183,12 @@ class Attention(nn.Module):
             else:
                 out = dense_attention(q, k, v, causal=True,
                                       window=self.window)
+        if self.gate:
+            g = dense("gate", self.num_heads)(x)
+            with jax.named_scope("attn_gate"):
+                # at the activations' width: a float32 gate is one more
+                # [B, T, heads, head_dim] float32 array kept a layer
+                out = out * jax.nn.sigmoid(g)
         return nn.DenseGeneral(dim, axis=(-2, -1), name="o", dtype=self.dtype,
                                use_bias=False, kernel_init=init)(out)
 
@@ -175,7 +198,9 @@ class Block(nn.Module):
     ``x -> (x, aux)``, ``aux`` what :class:`raydp_tpu.models.moe.MoE`
     returns beside its output. ``router_input="attention"``: the router's
     kernel lies in the block (``router``) and reads the attention's normed
-    input; its logits are handed to the expert layer."""
+    input; its logits are handed to the expert layer. ``sandwich_norms``: each
+    sub-layer's output is normed too (``ln1_post``, ``ln2_post``) before it is
+    added to the stream."""
 
     num_heads: int
     mlp_ratio: int = 4
@@ -185,7 +210,7 @@ class Block(nn.Module):
     ffn_dim: Optional[int] = None
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
-    qk_norm: bool = False
+    qk_norm: Any = False
     num_experts: int = 0
     experts_per_token: int = 0
     init_std: Optional[float] = None
@@ -198,6 +223,11 @@ class Block(nn.Module):
     expert_activation: str = "silu"
     normalize_top_k: bool = False
     router_input: str = "experts"
+    attention_gate: bool = False
+    sandwich_norms: bool = False
+    routing: str = "softmax"
+    route_scale: float = 1.0
+    shared_expert_dim: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -215,24 +245,29 @@ class Block(nn.Module):
         elif self.router_input != "experts":
             raise ValueError(f"router_input {self.router_input!r}: "
                              f"'experts' or 'attention'")
-        x = x + Attention(self.num_heads, self.attention, self.mesh,
-                          self.dtype, self.rope_theta, self.qk_norm, eps,
-                          self.init_std, self.head_dim, self.num_kv_heads,
-                          self.window, self.rope, name="attn")(u)
+        post = (lambda name, y: RMSNorm(eps, name=name)(y)) \
+            if self.sandwich_norms else (lambda name, y: y)
+        x = x + post("ln1_post", Attention(
+            self.num_heads, self.attention, self.mesh, self.dtype,
+            self.rope_theta, self.qk_norm, eps, self.init_std, self.head_dim,
+            self.num_kv_heads, self.window, self.rope, self.attention_gate,
+            name="attn")(u))
         h = RMSNorm(eps, name="ln2")(x)
         hidden = self.ffn_dim or self.mlp_ratio * dim
         if self.num_experts:
             y, aux = MoE(self.num_experts, self.experts_per_token, hidden,
                          self.dtype, init, self.first_expert,
                          self.experts_held, self.expert_activation,
-                         self.normalize_top_k, name="moe")(h, logits)
-            return x + y, aux
+                         self.normalize_top_k, self.routing, self.route_scale,
+                         self.shared_expert_dim, name="moe")(h, logits)
+            return x + post("ln2_post", y), aux
         # SwiGLU
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name, kernel_init=init)
-        down = dense(dim, "down")(
-            nn.silu(dense(hidden, "gate")(h)) * dense(hidden, "up")(h))
-        return x + down
+        with jax.named_scope("mlp"):
+            down = dense(dim, "down")(
+                nn.silu(dense(hidden, "gate")(h)) * dense(hidden, "up")(h))
+        return x + post("ln2_post", down)
 
 
 class TransformerLM(nn.Module):
@@ -249,7 +284,7 @@ class TransformerLM(nn.Module):
     ffn_dim: Optional[int] = None
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
-    qk_norm: bool = False
+    qk_norm: Any = False
     num_experts: int = 0
     experts_per_token: int = 0
     balance_loss_weight: float = 0.01
@@ -266,6 +301,15 @@ class TransformerLM(nn.Module):
     normalize_top_k: bool = False
     router_input: str = "experts"
     remat_blocks: bool = False
+    attention_gate: bool = False
+    sandwich_norms: bool = False
+    embed_scale: bool = False
+    dense_layers: int = 0                   # leading blocks that stay dense
+    dense_ffn_dim: Optional[int] = None     # their width; None: ffn_dim's rule
+    routing: str = "softmax"
+    route_scale: float = 1.0
+    shared_expert_dim: int = 0
+    bias_update_rate: float = 0.0           # sigmoid routing's balancing bias
 
     def _windowed(self, layer: int) -> bool:
         pattern = self.window_layers
@@ -288,6 +332,9 @@ class TransformerLM(nn.Module):
         return bool(self.num_experts and self.experts_held is not None
                     and self.experts_held < self.num_experts)
 
+    def _sparse(self, layer: int) -> bool:
+        return bool(self.num_experts) and layer >= self.dense_layers
+
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False, labels=None,
                  weights=None):
@@ -302,19 +349,27 @@ class TransformerLM(nn.Module):
         init = _init(self.init_std, nn.linear.default_embed_init)
         x = nn.Embed(self.vocab_size, self.dim, name="embed",
                      dtype=self.dtype, embedding_init=init)(tokens)
+        if self.embed_scale:
+            x = x * jnp.asarray(np.sqrt(self.dim), x.dtype)
         aux = []
         block = nn.remat(Block) if self.remat_blocks else Block
         for i in range(self.num_layers):
+            sparse = self._sparse(i)
             x = block(self.num_heads, self.mlp_ratio, self.attention,
-                      self.mesh, self.dtype, self.ffn_dim, self.rms_norm_eps,
-                      self.rope_theta, self.qk_norm, self.num_experts,
+                      self.mesh, self.dtype,
+                      self.ffn_dim if sparse or self.dense_ffn_dim is None
+                      else self.dense_ffn_dim, self.rms_norm_eps,
+                      self.rope_theta, self.qk_norm,
+                      self.num_experts if sparse else 0,
                       self.experts_per_token, self.init_std, self.head_dim,
                       self.num_kv_heads,
                       self.sliding_window if self._windowed(i) else None,
                       self._rope(i), self.first_expert, self.experts_held,
                       self.expert_activation, self.normalize_top_k,
-                      self.router_input, name=f"block_{i}")(x)
-            if self.num_experts:
+                      self.router_input, self.attention_gate,
+                      self.sandwich_norms, self.routing, self.route_scale,
+                      self.shared_expert_dim, name=f"block_{i}")(x)
+            if sparse:
                 x, layer_aux = x
                 aux.append(layer_aux)
         x = RMSNorm(self.rms_norm_eps, name="ln_f")(x)
@@ -331,11 +386,15 @@ class TransformerLM(nn.Module):
         if not aux:
             return loss, jnp.zeros((0,), jnp.float32)
         mean = lambda key: sum(a[key] for a in aux) / len(aux)  # noqa: E731
-        loss = loss + weights.sum() * (
-            self.balance_loss_weight * mean("balance")
-            + self.z_loss_weight * mean("z"))
-        return loss, jnp.stack([sum(a[f"slots_{kind}"] for a in aux)
-                                for kind in self._slot_kinds])
+        if self.balance_loss_weight or self.z_loss_weight:
+            loss = loss + weights.sum() * (
+                self.balance_loss_weight * mean("balance")
+                + self.z_loss_weight * mean("z"))
+        counts = [sum(a[f"slots_{kind}"] for a in aux)
+                  for kind in self._slot_kinds]
+        if self.routing == "sigmoid":       # the widest layer's
+            counts.append(jnp.max(jnp.stack([a["bias_spread"] for a in aux])))
+        return loss, jnp.stack(counts)
 
     @property
     def _slot_kinds(self):
@@ -345,18 +404,38 @@ class TransformerLM(nn.Module):
     @property
     def loss_counters(self):
         """What the second output of :meth:`loss_rows` counts, as (registry
-        counter, label) pairs."""
+        metric, label) pairs: counters are summed over an epoch's steps, a
+        gauge keeps the last step's value."""
+        if not self.num_experts:
+            return ()
         labels = {"max": "max_expert"}     # the other kinds label themselves
-        return tuple(("moe_slots_total", labels.get(kind, kind))
-                     for kind in self._slot_kinds) if self.num_experts else ()
+        names = tuple(("moe_slots_total", labels.get(kind, kind))
+                      for kind in self._slot_kinds)
+        if self.routing == "sigmoid":
+            names += (("moe_router_bias_spread", ""),)
+        return names
+
+    def after_step(self, state):
+        """Once an optimizer step, after the gradients are applied (the train
+        step calls it on the collection it carries beside the parameters):
+        every expert layer's balancing bias moves towards the experts that
+        were short of slots in the step's tokens, all micro-batches together
+        (:func:`raydp_tpu.models.moe.balance_bias`). The state itself where
+        the routing has no bias."""
+        from raydp_tpu.models.moe import balance_bias
+
+        if self.routing != "sigmoid" or state is None:
+            return state
+        return balance_bias(state, self.bias_update_rate)
 
     def loss_rows(self, tokens, labels, weights):
         """The training loss over the rows, under the rows' ``weights`` [B]
         (``1 / B`` each, or a pad-and-mask feed's ``mask / sum(mask)``): the
         weighted sum of each sequence's mean next-token cross entropy (head
         fused into the loss, float32: :func:`lm_head_loss`) plus, with
-        experts, ``sum(weights)`` times the weighted load-balancing and router
-        z-losses of the batch (means over the layers); and the counts of
+        experts and a weight on either, ``sum(weights)`` times the weighted
+        load-balancing and router z-losses of the batch (means over the
+        layers; no auxiliary loss where both weights are 0); and the counts of
         :attr:`loss_counters`. The scalar is the loss
         :class:`raydp_tpu.train.FlaxEstimator` differentiates, so no
         ``[B, T, vocab]`` logits exist in its train step, and the weights

@@ -198,7 +198,14 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     differentiates (:func:`_step_loss`). With the weights in hand a model
     can take gradients inside its forward (a language model's fused head
     loss does), and its ``[B, T, vocab]`` logits need never exist; called
-    plainly the model still returns them."""
+    plainly the model still returns them.
+
+    A model with ``after_step(state)`` moves state of its own outside the
+    gradient (``apply_fn.after_step``): the train step hands it the mutable
+    collection it carries once an optimizer step, after the gradients are
+    applied and after every micro-batch's forward has written to it, and
+    carries on what it returns (a language model's routing bias:
+    ``models/transformer.py``)."""
     import jax.numpy as jnp
 
     model_loss = callable(getattr(model, "loss_rows", None))
@@ -233,6 +240,9 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
         return preds.astype(jnp.float32), labels, new_bstats
 
     apply_fn.model_loss = model_loss
+    # state the model moves itself, once an optimizer step (the collection
+    # ``bstats`` carries -> the same, after the step): none for most models
+    apply_fn.after_step = getattr(model, "after_step", None)
     # {"window": n, "full": m} where the model says so (a language model)
     apply_fn.attention_layers = getattr(model, "attention_layers", None) or {}
     if callable(getattr(model, "lookups", None)):
@@ -456,6 +466,16 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     counted: list = []      # the table counters are bumped once a built step
     placed = None if state_shardings is None else (
         state_shardings.params, state_shardings.opt_state)
+    after_step = getattr(apply_fn, "after_step", None)
+
+    def _carry_on(new_state, new_bstats):
+        """The stepped state with the forward's collection, which the model
+        has moved on where it keeps state of its own there."""
+        if new_bstats is None:
+            return new_state
+        if after_step is not None:
+            new_bstats = after_step(new_bstats)
+        return new_state.replace(batch_stats=new_bstats)
 
     def _microbatch_grads(params, bstats, batch, mask, inv=None):
         def _loss(p):
@@ -505,8 +525,7 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         if accum <= 1:
             new_state, (loss_val, (preds, labels, new_bstats)) = _update(
                 state, batch, mask, tables)
-            if new_bstats is not None:
-                new_state = new_state.replace(batch_stats=new_bstats)
+            new_state = _carry_on(new_state, new_bstats)
             new_mstats = tuple(
                 _update_metric(m, s, preds, labels, mask)
                 for m, s in zip(metrics, mstats))
@@ -560,9 +579,7 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         denom = jnp.maximum(r_acc, 1.0)
         grads = jax.tree.map(lambda a, p: (a / denom).astype(p.dtype),
                              g_acc, state.params)
-        new_state = state.apply_gradients(grads=grads)
-        if new_bstats is not None:
-            new_state = new_state.replace(batch_stats=new_bstats)
+        new_state = _carry_on(state.apply_gradients(grads=grads), new_bstats)
         return new_state, loss_sum + l_acc / denom, new_mstats
 
     return train_step

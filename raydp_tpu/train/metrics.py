@@ -100,25 +100,34 @@ class BinaryCrossEntropy(Metric):
 class ModelCounters(Metric):
     """What a model that brings its own loss counts beside it (the second
     output of its ``loss_rows``; ``model.loss_counters`` names each entry as
-    a (registry counter, label) pair): summed inside the jitted step like any
-    metric's statistics and, at the epoch's end, added to the registry's
-    counters. It reports nothing into the epoch's history."""
+    a (registry metric, label) pair): a counter's entry is summed inside the
+    jitted step like any metric's statistics and, at the epoch's end, added
+    to the registry's counter; a gauge's keeps the last step's value and sets
+    the gauge. It reports nothing into the epoch's history."""
 
     name = "model_counters"
 
     def __init__(self, names):
+        from raydp_tpu import metrics as registry
         self.names = tuple(names)
+        self.gauge = np.array([registry.METRICS[name].kind == registry.GAUGE
+                               for name, _ in self.names])
 
     def init(self):
         return np.zeros(len(self.names), np.float32)
 
     def update(self, stats, preds, labels, mask=None):
-        return stats + preds[1]
+        if not self.gauge.any():        # counters alone: the step as it was
+            return stats + preds[1]
+        return jnp.where(self.gauge, preds[1], stats + preds[1])
 
     def compute(self, stats) -> None:
         from raydp_tpu import metrics as registry
-        for (counter, label), value in zip(self.names, stats):
-            registry.inc(counter, float(value), label)
+        for (name, label), gauge, value in zip(self.names, self.gauge, stats):
+            if gauge:
+                registry.set_gauge(name, float(value), label)
+            else:
+                registry.inc(name, float(value), label)
 
 
 def model_counters(model) -> List[Metric]:
