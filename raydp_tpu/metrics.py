@@ -313,6 +313,16 @@ _ALL_METRICS = [
        "step that was not told the state's shardings). doc/training.md, the "
        "row-wise update.",
        label="path"),
+    _m("train_table_sum_total", COUNTER, "1", "training",
+       "Row-wise embedding tables whose looked-up rows are put together by "
+       "a sum over mesh axes (the shard_local ones of "
+       "train_table_walk_total), counted once a built train step, by what "
+       "that sum carries: real_rows (the batch's distinct ids, rounded up "
+       "to a pass of the sum; the fill rows past them stay the zeros they "
+       "are on every shard) or all_rows (all B rows of the view: a batch no "
+       "larger than one pass). Nothing on one chip or where every shard "
+       "walks all of the ids. doc/training.md, the row-wise update.",
+       label="carries"),
     _m("train_head_loss_total", COUNTER, "1", "training",
        "Train steps built round a model that brings its own loss "
        "(`loss_rows`), counted once a built step by how the loss is "

@@ -30,11 +30,16 @@ traced leaf carries none). A table whose rows are split over mesh axes
 (:func:`row_axes`) is then read and written per shard under ``shard_map``:
 ``uniq`` is sorted and a shard holds one contiguous range of rows, so its ids
 are one slice of ``uniq``, and it walks that slice only (:func:`shard_rows`).
-One sum over those axes after the walk puts the looked-up rows together; no
-collective runs inside a pass, since the shards make different numbers of
-them. A table that is not split by rows, or a step that was not told, keeps
-the one walk of all of ``uniq``, which the partitioner then runs on every
-chip that holds a part of the table.
+No collective runs inside a pass of the walk, since the shards make different
+numbers of them. A sum over those axes after the walk puts the looked-up rows
+together, and it carries the real rows only (:func:`sum_real_rows`): ``uniq``
+holds the real ids first, the view's rows past them are zero on every shard,
+and the table's global count is the same number on every shard, so the sum
+runs in passes of :data:`SUM_PASS` rows, as many as hold real ids, a table's
+parameter and accumulators in one all-reduce a pass. A table that is not
+split by rows, or a step that was not told, keeps the one walk of all of
+``uniq``, which the partitioner then runs on every chip that holds a part of
+the table.
 """
 
 from __future__ import annotations
@@ -62,6 +67,11 @@ CHUNK = 256
 #: 1.28 ms against 0.23 ms for one pass over all B ids (143,091 x 32
 #: float32, PERF.md PR 25).
 STAGED_BYTES = 256 << 20
+#: rows a pass of the sum over a table's row axes carries (a parameter's and
+#: its accumulators' in one all-reduce). On four v5e chips, 8192 ids a table
+#: of which ~2300 distinct, ten tables: passes of 512 ran the step in 10.96
+#: ms, of 1024 in 10.98, one sum of all 8192 rows in 12.04 (CHANGES.md PR 34)
+SUM_PASS = 512
 
 Path = Tuple[str, ...]
 
@@ -184,7 +194,7 @@ def tables_to_update(apply_fn, state, batch, accum: int, seen: list,
         because.update(dict.fromkeys(wide, "probe"))
     if not seen:
         seen.append(True)
-        for path in ids:
+        for path, i in ids.items():
             metrics.inc("train_table_updates_total",
                         label="dense" if path in because else "rowwise")
             if path not in because:
@@ -192,6 +202,10 @@ def tables_to_update(apply_fn, state, batch, accum: int, seen: list,
                     leaf_at(placed, path), leaf_at(state.params, path).shape)
                 metrics.inc("train_table_walk_total",
                             label="shard_local" if local else "global")
+                if local:
+                    metrics.inc("train_table_sum_total",
+                                label="real_rows" if i.shape[0] > SUM_PASS
+                                else "all_rows")
         if because:
             why: Dict[str, list] = {}
             for path, reason in because.items():
@@ -241,27 +255,52 @@ def take_rows(tree, idx, placed=None):
     on those is never written). ``placed`` is ``tree``'s shardings where the
     caller knows them: a leaf whose rows are split over mesh axes
     (:func:`row_axes`) is read shard by shard, each shard its own slice of
-    ``uniq``, and one sum over those axes after the walk puts the view
-    together (one shard's rows and the others' zeros: exact)."""
+    ``uniq``, and a sum over those axes of the real rows puts the view
+    together (:func:`sum_real_rows`; one shard's rows and the others' zeros:
+    exact). The leaves that mirror one table, its parameter and its
+    accumulators, are read and summed together."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    leaves, treedef = jax.tree.flatten(tree)
+    rows = treedef.flatten_up_to(idx)
+    shardings = [None] * len(leaves) if placed is None \
+        else treedef.flatten_up_to(placed)
+    split: Dict[tuple, list] = {}   # (a table's Rows, mesh, axes): its leaves
+    for i, (a, r, sharding) in enumerate(zip(leaves, rows, shardings)):
+        if r is WHOLE:
+            continue
+        axes = row_axes(sharding, a.shape)
+        if axes is not None:
+            split.setdefault((id(r), sharding.mesh, axes), []).append(i)
+            continue
+        leaves[i] = r.passes(
+            a, lambda start, ids, out, a=a: lax.dynamic_update_slice_in_dim(
+                out, jnp.take(a, ids, axis=0, mode="clip",
+                              indices_are_sorted=True, unique_indices=True),
+                start, axis=0),
+            jnp.zeros(r.uniq.shape + a.shape[1:], a.dtype))
+    for (_, mesh, axes), members in split.items():
+        views = _take_split([leaves[i] for i in members], rows[members[0]],
+                            mesh, axes)
+        for i, view in zip(members, views):
+            leaves[i] = view
+    return treedef.unflatten(leaves)
+
+
+def _take_split(tables, rows: Rows, mesh, axes):
+    """The views of ``tables``, leaves that looked up the same ``rows`` and
+    whose rows are split over the mesh ``axes``: each shard reads its own
+    slice of ``uniq``, then one sum puts the real rows of all of them
+    together."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    def take(a, rows, sharding=None):
-        if rows is WHOLE:
-            return a
-        axes = row_axes(sharding, a.shape)
-        if axes is None:
-            return rows.passes(
-                a, lambda start, ids, out: lax.dynamic_update_slice_in_dim(
-                    out, jnp.take(a, ids, axis=0, mode="clip",
-                                  indices_are_sorted=True,
-                                  unique_indices=True),
-                    start, axis=0),
-                jnp.zeros(rows.uniq.shape + a.shape[1:], a.dtype))
-
-        def shard(table, uniq):
+    def shard(tables, uniq, count):
+        def walk(table):
             def visit(start, ids, out):
                 own = (ids >= 0) & (ids < table.shape[0])
                 got = jnp.take(table, ids, axis=0, mode="clip",
@@ -271,17 +310,44 @@ def take_rows(tree, idx, placed=None):
                                    got, 0), start, axis=0)
 
             # no collective inside the walk: shards differ in their passes
-            return lax.psum(shard_rows(uniq, table.shape[0], axes).passes(
+            return shard_rows(uniq, table.shape[0], axes).passes(
                 table, visit, lax.pcast(
                     jnp.zeros(uniq.shape + table.shape[1:], table.dtype),
-                    axes, to="varying")), axes)
+                    axes, to="varying"))
 
-        return jax.shard_map(shard, mesh=sharding.mesh,
-                             in_specs=(P(axes), P()), out_specs=P())(
-                                 a, rows.uniq)
+        return sum_real_rows([walk(table) for table in tables], count, axes)
 
-    return jax.tree.map(take, tree, idx, *(() if placed is None
-                                          else (placed,)))
+    return jax.shard_map(shard, mesh=mesh, in_specs=(P(axes), P(), P()),
+                         out_specs=P())(tables, rows.uniq, rows.count)
+
+
+def sum_real_rows(views, count, axes):
+    """Inside a ``shard_map`` over ``axes``: ``views`` ``[B, ...]``, each
+    shard's own rows and zeros elsewhere, summed over the axes in their rows
+    ``[0, count)``, in passes of :data:`SUM_PASS` rows, one ``psum`` of all
+    the views a pass (the last pass pulled back inside ``B``). The rows past
+    the last pass are zero on every shard and stay so, unsummed; a row below
+    it is the sum one ``psum`` of all ``B`` rows would give.
+
+    A collective in a loop, where the walk may hold none: ``count`` is the
+    table's global count, computed from the replicated ``uniq``, so every
+    shard makes the same number of passes."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    b = views[0].shape[0]
+    if b <= SUM_PASS:
+        return lax.psum(views, axes)
+
+    def body(i, out):
+        start = jnp.minimum(i * SUM_PASS, b - SUM_PASS)
+        part = lax.psum([lax.dynamic_slice_in_dim(v, start, SUM_PASS)
+                         for v in views], axes)
+        return [lax.dynamic_update_slice_in_dim(o, p, start, axis=0)
+                for o, p in zip(out, part)]
+
+    return lax.fori_loop(0, (count + SUM_PASS - 1) // SUM_PASS, body,
+                         [jnp.zeros(v.shape, v.dtype) for v in views])
 
 
 def put_rows(tree, view, idx, placed=None):

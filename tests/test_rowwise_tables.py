@@ -115,17 +115,23 @@ def _run(step, state, batches, mesh=None):
     return state, sums[-1]
 
 
-def _table_counts():
+def _counts(name):
     from raydp_tpu import metrics
 
-    return dict(metrics.snapshot()["counters"].get(
-        "train_table_updates_total", {}))
+    return dict(metrics.snapshot()["counters"].get(name, {}))
+
+
+def _since(name, before, labels):
+    after = _counts(name)
+    return {k: after.get(k, 0) - before.get(k, 0) for k in labels}
+
+
+def _table_counts():
+    return _counts("train_table_updates_total")
 
 
 def _counted(before):
-    after = _table_counts()
-    return {k: after.get(k, 0) - before.get(k, 0)
-            for k in ("rowwise", "dense")}
+    return _since("train_table_updates_total", before, ("rowwise", "dense"))
 
 
 @pytest.fixture
@@ -570,16 +576,12 @@ def _batches_of(pattern, shards, n=3, seed=0):
 
 
 def _walk_counts():
-    from raydp_tpu import metrics
-
-    return dict(metrics.snapshot()["counters"].get(
-        "train_table_walk_total", {}))
+    return _counts("train_table_walk_total")
 
 
 def _walked(before):
-    after = _walk_counts()
-    return {k: after.get(k, 0) - before.get(k, 0)
-            for k in ("shard_local", "global")}
+    return _since("train_table_walk_total", before,
+                  ("shard_local", "global"))
 
 
 def _same_state(a, b):
@@ -749,9 +751,8 @@ def test_row_axes_reads_the_spec(spec, shape, expected):
     assert rowwise.row_axes(None, shape) is None
 
 
-def _collectives_in_loops(hlo: str):
-    """The collective instructions of the computations a ``while`` runs
-    (its body and condition, and whatever they call)."""
+def _computations(hlo: str):
+    """``{name: [instruction line, ...]}`` of a compiled program's text."""
     comps, name = {}, None
     for line in hlo.splitlines():
         m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
@@ -762,20 +763,45 @@ def _collectives_in_loops(hlo: str):
             name = None
         elif name:
             comps[name].append(line)
-    todo = [c for lines in comps.values() for line in lines
-            if " while(" in line
-            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", line)]
-    inside = set()
+    return comps
+
+
+def _run_by(comps, roots):
+    """The computations ``roots`` and whatever they call."""
+    todo, inside = list(roots), set()
     while todo:
         c = todo.pop()
         if c in comps and c not in inside:
             inside.add(c)
             todo += [x for line in comps[c] for x in re.findall(
                 r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)", line)]
+    return inside
+
+
+def _loops(comps, what="body|condition"):
+    return [c for lines in comps.values() for line in lines
+            if " while(" in line
+            for c in re.findall(rf"(?:{what})=%?([\w.\-]+)", line)]
+
+
+def _collectives_in_loops(hlo: str):
+    """The collective instructions of the computations a ``while`` runs
+    (its body and condition, and whatever they call)."""
+    comps = _computations(hlo)
+    inside = _run_by(comps, _loops(comps))
     assert inside
     return [line.strip() for c in inside for line in comps[c] if re.search(
         r" (all-reduce|all-gather|reduce-scatter|all-to-all"
         r"|collective-permute)(-start)?\(", line)]
+
+
+def _loop_bodies_with(hlo: str, pattern: str):
+    """The ``while`` bodies that hold (themselves or in what they call) an
+    instruction matching ``pattern``."""
+    comps = _computations(hlo)
+    return {b for b in set(_loops(comps, "body")) if any(
+        re.search(pattern, line) for c in _run_by(comps, [b])
+        for line in comps[c])}
 
 
 @pytest.mark.parametrize("told", [True, False],
@@ -810,6 +836,237 @@ def test_compiled_local_walk_holds_no_collective_in_a_loop(told, monkeypatch):
         assert not set(found) - _MAY_HOLD_A_TABLE
     else:
         assert _collectives_in_loops(hlo)
+
+
+# ------------- (f2) on a mesh: the sum over the row axes carries the real rows
+def _sum_counts():
+    return _counts("train_table_sum_total")
+
+
+def _summed(before):
+    return _since("train_table_sum_total", before, ("real_rows", "all_rows"))
+
+
+def _whole_sum_view(table, uniq, sharding):
+    """The parent's formulation, kept here to compare with: each shard walks
+    its slice of ``uniq`` and ONE ``psum`` carries all ``B`` rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from raydp_tpu.train import rowwise
+
+    axes = rowwise.row_axes(sharding, table.shape)
+
+    def shard(table, uniq):
+        def visit(start, ids, out):
+            own = (ids >= 0) & (ids < table.shape[0])
+            got = jnp.take(table, ids, axis=0, mode="clip")
+            return lax.dynamic_update_slice_in_dim(
+                out, jnp.where(own[:, None], got, 0), start, axis=0)
+
+        return lax.psum(rowwise.shard_rows(uniq, table.shape[0], axes).passes(
+            table, visit, lax.pcast(
+                jnp.zeros(uniq.shape + table.shape[1:], table.dtype), axes,
+                to="varying")), axes)
+
+    return jax.shard_map(shard, mesh=sharding.mesh, in_specs=(P(axes), P()),
+                         out_specs=P())(table, uniq)
+
+
+def _ids_with(count, v, rng, lo=0):
+    """``B`` ids of which exactly ``count`` are distinct, from ``lo`` on."""
+    distinct = lo + rng.permutation(v - lo)[:count]
+    return rng.permutation(np.concatenate(
+        [distinct, rng.choice(distinct, B - count)]))
+
+
+#: case -> (placement, distinct ids of B = 64 at 16 rows a pass of the sum,
+#: whether every id lies in the last shard's range, whether the table is
+#: walked in passes or, under STAGED_BYTES, visited once)
+_SUM_CASES = {
+    "one_real_row": ("data2_expert2", 1, False, True),
+    "one_below_a_pass": ("data2_expert2", 31, False, True),
+    "a_whole_pass": ("data2_expert2", 32, False, True),
+    "one_above_a_pass": ("data2_expert2", 33, False, True),
+    "all_distinct": ("data2_expert2", B, False, True),
+    "one_shard_holds_all": ("data2_expert2", 23, True, True),
+    "one_shard_of_four_holds_all": ("data2_expert4", 40, True, True),
+    "table_under_staged_bytes": ("data2_expert2", 23, False, False),
+    "rows_over_two_axes": ("fsdp2_tensor2", 37, False, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_SUM_CASES))
+def test_bounded_sum_is_the_whole_sum_bit_for_bit(case, monkeypatch):
+    """``take_rows`` told the shardings sums ``ceil(count / 16)`` passes of
+    rows over the row axes. Its view is the view of one ``psum`` over all
+    ``B`` rows (the parent's), of the global walk, and numpy's: the real rows
+    first, zeros after them, for a parameter and its accumulator summed in
+    one call."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from raydp_tpu.train import rowwise
+
+    placement, count, one_shard, in_passes = _SUM_CASES[case]
+    monkeypatch.setattr(rowwise, "SUM_PASS", 16)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    if in_passes:
+        monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    mesh, rules, shards = _placement(placement)
+    sharding = NamedSharding(mesh, PartitionSpec(*rules[0][1]))
+    v = SIZES[0]
+    rng = np.random.default_rng(count)
+    table = rng.standard_normal((v, 8)).astype(np.float32)
+    accum = rng.standard_normal((v, 8)).astype(np.float32)
+    ids = _ids_with(count, v, rng, v // shards * (shards - 1) if one_shard
+                    else 0)
+    assert len(np.unique(ids)) == count
+
+    @jax.jit
+    def views(table, accum, ids):
+        rows, _ = rowwise.unique_rows(ids, v)
+        tree, idx = {"t": table, "a": accum}, {"t": rows, "a": rows}
+        told = rowwise.take_rows(tree, idx, {"t": sharding, "a": sharding})
+        return (told, rowwise.take_rows(tree, idx),
+                {k: _whole_sum_view(x, rows.uniq, sharding)
+                 for k, x in tree.items()})
+
+    placed = [jax.device_put(x, sharding) for x in (table, accum)]
+    text = str(jax.make_jaxpr(views)(*placed, jnp.asarray(ids, jnp.int32)))
+    assert "psum" in text
+    told, whole_walk, whole_sum = views(*placed, jnp.asarray(ids, jnp.int32))
+    uniq = np.unique(ids)
+    for k, x in (("t", table), ("a", accum)):
+        got = np.asarray(told[k])
+        np.testing.assert_array_equal(got[:count], x[uniq])
+        assert not got[count:].any()
+        np.testing.assert_array_equal(got, np.asarray(whole_sum[k]))
+        # the global walk clips a fill id inside its last pass to the
+        # table's last row instead of masking it: equal in the real rows
+        np.testing.assert_array_equal(got[:count],
+                                      np.asarray(whole_walk[k])[:count])
+
+
+@pytest.mark.parametrize("placement", ["data2_expert2", "fsdp2_tensor2"])
+def test_step_with_a_bounded_sum_matches_dense(placement, monkeypatch):
+    """The whole step (``take_rows`` -> ``tx.update`` -> ``put_rows``) with
+    the sum in passes of 16 rows against the dense step, as
+    ``test_rowwise_matches_dense`` asserts it, and against the same step with
+    one whole sum (a pass wider than the batch) to the bit."""
+    import jax
+    import optax
+
+    from raydp_tpu.parallel import param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh, rules, _ = _placement(placement)
+    model, tx = _model(), optax.adagrad(0.05)
+    batches = _batches(6)
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+
+    def run(pass_rows):
+        monkeypatch.setattr(rowwise, "SUM_PASS", pass_rows)
+        before = _sum_counts()
+        out = _run_each(_step(model, mesh, placed=placed),
+                        _state(model, tx, mesh, rules), batches, mesh)
+        return out, _summed(before)
+
+    (bounded, bounded_sums), counted = run(16)
+    assert counted == {"real_rows": 3, "all_rows": 0}
+    (whole, whole_sums), counted = run(256)
+    assert counted == {"real_rows": 0, "all_rows": 3}
+    assert bounded_sums == whole_sums
+    _same_state(bounded, whole)
+    dense, dense_sums = _run_each(_step(_Undeclared(model), mesh),
+                                  _state(model, tx, mesh, rules), batches,
+                                  mesh)
+    assert bounded_sums[-1] == pytest.approx(dense_sums[-1], rel=1e-6)
+    for x, y in zip(jax.tree.leaves((bounded.params, bounded.opt_state)),
+                    jax.tree.leaves((dense.params, dense.opt_state))):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=1e-7)
+
+
+@pytest.mark.parametrize("pass_rows", [16, 256],
+                         ids=["in_passes", "one_whole_sum_for_contrast"])
+def test_compiled_sum_carries_passes_not_the_batch(pass_rows, monkeypatch):
+    """The step compiled for data 2 x expert 4 (a count, not a timing). With
+    the sum in passes: ONE all-reduce a shard-local table, inside a loop of
+    its own, whose operands are the 16 rows a pass of the parameter and of
+    its Adagrad accumulator; no all-reduce anywhere carries a leaf's ``B``
+    rows; and the loops of the walk (they hold the gathers and scatters)
+    hold no collective still. A pass wider than the batch sums all ``B``
+    rows at once and fails the same checks: they can see."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.parallel import batch_sharding, param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    monkeypatch.setattr(rowwise, "SUM_PASS", pass_rows)
+    mesh, rules, _ = _placement("data2_expert4")
+    model, tx = _model(), optax.adagrad(0.05)
+    state = _state(model, tx, mesh, rules)
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+    batch = jax.device_put(_batches(1)[0], batch_sharding(mesh))
+    hlo = jax.jit(_step(model, mesh, placed=placed),
+                  donate_argnums=(0, 3)).lower(
+                      state, batch, (), jnp.zeros(())).compile().as_text()
+    in_loops = _collectives_in_loops(hlo) if pass_rows < B else []
+    # over ``expert``, the row axis: two groups of four of the eight devices
+    over_rows = ("replica_groups={{0,1,2,3},{4,5,6,7}}",
+                 "replica_groups=[2,4]<=[8]")
+    whole = [line for line in hlo.splitlines()
+             if re.search(r" all-reduce(-start)?\(", line)
+             and any(g in line for g in over_rows)
+             and f"f32[{B},8]" in line.split(" all-reduce")[0]]
+    if pass_rows < B:
+        assert len(in_loops) == len(WIDE)
+        for line in in_loops:
+            assert " all-reduce(" in line and over_rows[0] in line
+            assert re.findall(r"f32\[[\d,]*\]", line.split(" all-reduce(")[0]
+                              ) == [f"f32[{pass_rows},8]"] * 2
+        assert not whole
+        summing = _loop_bodies_with(hlo, r" all-reduce(-start)?\(")
+        walking = _loop_bodies_with(hlo, r" (gather|scatter)\(")
+        assert len(summing) == len(WIDE) and len(walking) == 4 * len(WIDE)
+        assert not summing & walking
+    else:
+        assert len(whole) == 1 and whole[0].split(" all-reduce")[0].count(
+            f"f32[{B},8]") == 2 * len(WIDE)
+
+
+@pytest.mark.parametrize("where", ["one_device", "not_told", "told"])
+def test_sum_counter_counts_the_tables_a_sum_puts_together(where,
+                                                           monkeypatch):
+    """``train_table_sum_total``: once a built step for every table whose
+    looked-up rows a sum over mesh axes puts together; nothing on one device
+    or where the step was not told the state's shardings (the global walk)."""
+    import optax
+
+    from raydp_tpu.parallel import param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "SUM_PASS", 16)
+    model, tx = _model(), optax.adagrad(0.05)
+    mesh, rules, _ = (None, None, None) if where == "one_device" \
+        else _placement("data2_expert2")
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx)) \
+        if where == "told" else None
+    before = _sum_counts()
+    _run_each(_step(model, mesh, placed=placed),
+              _state(model, tx, mesh, rules), _batches(2), mesh)
+    assert _summed(before) == {"real_rows": 3 if where == "told" else 0,
+                               "all_rows": 0}
 
 
 # ------------------------------- (g) a dense fit's checkpoint, row-wise step
@@ -883,15 +1140,16 @@ def test_fit_engages_from_the_model_and_optimizer_alone(session, step_log):
                for line in step_log)
 
 
-@pytest.mark.parametrize("name", ["train_table_updates_total",
-                                  "train_table_walk_total"])
-def test_counter_is_registered_and_documented(name):
+@pytest.mark.parametrize("name,label", [
+    ("train_table_updates_total", "path"), ("train_table_walk_total", "path"),
+    ("train_table_sum_total", "carries")])
+def test_counter_is_registered_and_documented(name, label):
     import os
 
     from raydp_tpu import metrics
 
     m = metrics.METRICS[name]
-    assert (m.kind, m.label) == (metrics.COUNTER, "path")
+    assert (m.kind, m.label) == (metrics.COUNTER, label)
     doc = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "doc", "observability.md")
     with open(doc) as fh:
