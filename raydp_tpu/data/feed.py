@@ -662,7 +662,10 @@ class DevicePrefetcher:
     STEP span of that pull (the host stage's is ``feed:decode``; a stage
     whose pull only waits on the stage before it has none). ``count_pulls``
     marks the stage a train loop pulls from: its consumer side counts
-    ``feed_pulls_total{ready|empty}``.
+    ``feed_pulls_total{ready|empty}``. ``stop_span`` names the STEP span of
+    the ``close()`` that ends the iteration, on the consumer's thread (the
+    feed's is ``feed:stop``: the close of the stage the loop pulls from stops
+    and joins the whole chain behind it).
     """
 
     _DONE = object()
@@ -671,13 +674,15 @@ class DevicePrefetcher:
                  pull_key: Optional[str] = None,
                  name: str = "devicefeed-prefetch",
                  pull_span: Optional[str] = None,
-                 count_pulls: bool = False):
+                 count_pulls: bool = False,
+                 stop_span: Optional[str] = None):
         self._src = src
         self._fn = fn
         self._timings = timings
         self._pull_key = pull_key
         self._pull_span = pull_span
         self._count_pulls = count_pulls
+        self._stop_span = stop_span
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._stop = threading.Event()
         # the prefetch thread must trace under the constructing context
@@ -777,7 +782,11 @@ class DevicePrefetcher:
                                 label="empty" if empty else "ready")
                 yield item
         finally:
-            self.close()
+            if self._stop_span is None:
+                self.close()
+            else:
+                with profiler.step(self._stop_span):
+                    self.close()
 
     def _drain(self) -> None:
         try:
@@ -896,11 +905,12 @@ class DeviceFeed:
         the pull wall (Arrow→numpy decode, native staging kernel included)
         accumulates as the ``decode`` phase. With synchronous placement this
         is the stage the train loop pulls from."""
+        pulled_from = self.prefetch_to_device <= 0
         return iter(DevicePrefetcher(
             self.host_iter, depth=self.prefetch, timings=self.timings,
             pull_key="decode", name="devicefeed-host",
-            pull_span="feed:decode",
-            count_pulls=self.prefetch_to_device <= 0))
+            pull_span="feed:decode", count_pulls=pulled_from,
+            stop_span="feed:stop" if pulled_from else None))
 
     def _timed_place(self, batch):
         t0 = time.perf_counter()
@@ -915,11 +925,17 @@ class DeviceFeed:
         inline otherwise. Same values in the same order either way; the
         async stage only moves the placement off the consumer's critical
         path."""
-        if self.prefetch_to_device <= 0:
-            for batch in self._host_batches():
-                yield self._timed_place(batch)
-            return
-        yield from DevicePrefetcher(
-            self._host_batches(), fn=self._timed_place,
-            depth=self.prefetch_to_device, name="devicefeed-device",
-            count_pulls=True)
+        # feed:start: an epoch's chain of stage threads is built anew, and
+        # the consumer waits for its first batch to come all the way through
+        with profiler.step("feed:start"):
+            if self.prefetch_to_device <= 0:
+                batches = map(self._timed_place, self._host_batches())
+            else:
+                batches = iter(DevicePrefetcher(
+                    self._host_batches(), fn=self._timed_place,
+                    depth=self.prefetch_to_device, name="devicefeed-device",
+                    count_pulls=True, stop_span="feed:stop"))
+            first = next(batches, None)
+        if first is not None:
+            yield first
+            yield from batches

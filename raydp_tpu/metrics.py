@@ -502,6 +502,24 @@ _ALL_SPANS = [
     _s("train:epoch_end", "training",
        "Between the last dispatch of an epoch and the end of its callbacks: "
        "loss fetch, report assembly, eval pass, callbacks.", kind=STEP),
+    _s("train:loss_fetch", "training",
+       "Under train:epoch_end, once an epoch: the fetch of the epoch's summed "
+       "loss, where the loop stands until the device has run every program "
+       "of the epoch. It closes before any callback runs.", kind=STEP),
+    _s("train:report", "training",
+       "Under train:epoch_end, once an epoch: the report's assembly, the "
+       "fetch of the train metrics' counters and their compute.", kind=STEP),
+    _s("train:eval", "training",
+       "Under train:epoch_end, once an epoch of a fit with an eval set: the "
+       "eval pass and the fetch of its loss and metrics.", kind=STEP),
+    _s("train:callbacks", "training",
+       "Under train:epoch_end, once an epoch: the estimator's callbacks.",
+       kind=STEP),
+    _s("train:epoch_turn", "training",
+       "From the end of train:epoch_end (for epoch 0, the loop's start) to "
+       "the epoch's first train:feed_wait or, resident, its train:dispatch: "
+       "the phase span's close and open, the save check and a due save, the "
+       "fault probe, the metrics' init, set_epoch, iter(feed).", kind=STEP),
     _s("feed:decode", "feed",
        "One host batch pulled by the feed's host stage (Arrow to numpy, "
        "native staging kernel included).", kind=STEP),
@@ -511,6 +529,14 @@ _ALL_SPANS = [
     _s("feed:put_wait", "feed",
        "A feed stage blocked on its full output queue: the stage is ahead "
        "of its consumer.", kind=STEP),
+    _s("feed:start", "feed",
+       "On the consumer's thread, inside the epoch's first train:feed_wait: "
+       "from DeviceFeed.__iter__ (it builds the epoch's chain of stage "
+       "threads) to the first placed batch handed out.", kind=STEP),
+    _s("feed:stop", "feed",
+       "On the consumer's thread, inside the epoch's last train:feed_wait: "
+       "the close() of the chain (stop, drain, join each stage's thread).",
+       kind=STEP),
     # ---- model: scopes in the device ops' op_name ---------------------------
     _s("attn", "model",
        "A transformer block's attention (`models/transformer.py`): the "

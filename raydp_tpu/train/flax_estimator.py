@@ -21,6 +21,7 @@ Parity map (reference torch/estimator.py):
 
 from __future__ import annotations
 
+import contextlib
 import os
 import tempfile
 import time
@@ -1105,6 +1106,14 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                     history = list(extra["history"])
                 logger.info("resuming from checkpoint step %d", done_epoch)
 
+        # train:epoch_turn crosses the train:epoch phase span's close and
+        # open, so no ``with`` block can hold it: the stack does, and is
+        # closed (a no-op when empty) where the turn ends
+        turn = contextlib.ExitStack()
+        turn.enter_context(step_span("train:epoch_turn"))
+        #: when the device last ran dry for the loop: its start, then the
+        #: return of each epoch's loss fetch (lead_time_s counts from here)
+        t_ready = time.perf_counter()
         while epoch < self.num_epochs:
             try:
                 rule = faults.check("estimator.epoch", key=str(epoch))
@@ -1116,15 +1125,17 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                     mstats = tuple(m.init() for m in train_metrics)
                     loss_sum = np.zeros((), np.float32)
                     steps, samples = 0, 0
-                    t_feed = t_disp = 0.0
+                    t_feed = t_disp = t_pull = 0.0
                     if cache is not None:
                         td = time.perf_counter()
                         ekey = jax.random.fold_in(
                             jax.random.PRNGKey(self.seed), epoch)
+                        turn.close()
                         with step_span("train:dispatch"):
                             state, loss_sum, mstats = _dispatch(
                                 jit_epoch, (state, loss_sum, mstats),
                                 cache.arrays, ekey)
+                            t_handed = time.perf_counter()
                             # dispatch is async: fetch the loss scalar INSIDE
                             # this window so dispatch_time_s carries the
                             # epoch's device time (otherwise the report's sync
@@ -1137,86 +1148,121 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                     else:
                         feed.set_epoch(epoch)
                         it = iter(feed)
-                        while True:
-                            tf = time.perf_counter()
-                            with step_span("train:feed_wait"):
-                                batch = next(it, None)
-                            t_feed += time.perf_counter() - tf
-                            if batch is None:
-                                break
-                            td = time.perf_counter()
+                        turn.close()
+                        # the epoch's first step, apart from the loop below so
+                        # that the loop itself reads nothing more: how long
+                        # the first pull took (the feed's restart) and when
+                        # the first program was handed over
+                        tf = time.perf_counter()
+                        with step_span("train:feed_wait"):
+                            batch = next(it, None)
+                        td = t_handed = time.perf_counter()
+                        t_feed = t_pull = td - tf
+                        if batch is not None:
                             with step_span("train:dispatch"):
                                 state, loss_sum, mstats = _dispatch(
                                     jit_train, state, batch, mstats, loss_sum)
-                            t_disp += time.perf_counter() - td
-                            steps += 1
-                            samples += self.batch_size
+                            t_handed = time.perf_counter()
+                            t_disp = t_handed - td
+                            steps, samples = 1, self.batch_size
+                            while True:
+                                tf = time.perf_counter()
+                                with step_span("train:feed_wait"):
+                                    batch = next(it, None)
+                                t_feed += time.perf_counter() - tf
+                                if batch is None:
+                                    break
+                                td = time.perf_counter()
+                                with step_span("train:dispatch"):
+                                    state, loss_sum, mstats = _dispatch(
+                                        jit_train, state, batch, mstats,
+                                        loss_sum)
+                                t_disp += time.perf_counter() - td
+                                steps += 1
+                                samples += self.batch_size
                     with step_span("train:epoch_end"):
                         # fetch the accumulated loss BEFORE reading the
                         # clock: dispatch is async, so only a host scalar
                         # fetch makes the epoch wall include the device work
-                        ts = time.perf_counter()
-                        train_loss = float(loss_sum) / steps if steps \
-                            else float("nan")
-                        t_sync = time.perf_counter() - ts
+                        with step_span("train:loss_fetch"):
+                            ts = time.perf_counter()
+                            train_loss = float(loss_sum) / steps if steps \
+                                else float("nan")
+                            t_fetched = time.perf_counter()
+                        t_sync = t_fetched - ts
                         dt = time.perf_counter() - t0
                         # registry twin of the epoch report (metrics_report()
                         # sees epoch walls without re-publishing the history
                         # dicts)
                         rdt_metrics.observe("train_epoch_seconds", dt)
-                        # the feed's thread-side phase split (decode, h2d):
-                        # these walls OVERLAP dispatch by design (that
-                        # is the prefetch win), so they attribute the epoch,
-                        # they don't sum to it
-                        pipe = feed.timings.take() if feed is not None else {}
-                        report = {
-                            "epoch": epoch,
-                            "train_loss": train_loss,
-                            "steps": steps,
-                            "samples_per_s": samples / dt if dt > 0 else 0.0,
-                            "epoch_time_s": dt,
-                            "feed_time_s": t_feed,
-                            "decode_time_s": pipe.get("decode", 0.0),
-                            "h2d_time_s": pipe.get("h2d", 0.0),
-                            "dispatch_time_s": t_disp,
-                            "sync_time_s": t_sync,
-                        }
-                        for m, s in zip(train_metrics, mstats):
-                            value = m.compute(jax.tree.map(np.asarray, s))
-                            if value is not None:
-                                report[f"train_{m.name}"] = value
+                        with step_span("train:report"):
+                            # the feed's thread-side phase split (decode,
+                            # h2d): these walls OVERLAP dispatch by design
+                            # (that is the prefetch win), so they attribute
+                            # the epoch, they don't sum to it
+                            pipe = feed.timings.take() if feed is not None \
+                                else {}
+                            report = {
+                                "epoch": epoch,
+                                "train_loss": train_loss,
+                                "steps": steps,
+                                "samples_per_s": samples / dt if dt > 0
+                                else 0.0,
+                                "epoch_time_s": dt,
+                                "feed_time_s": t_feed,
+                                "decode_time_s": pipe.get("decode", 0.0),
+                                "h2d_time_s": pipe.get("h2d", 0.0),
+                                "dispatch_time_s": t_disp,
+                                "sync_time_s": t_sync,
+                                # the device had nothing of this epoch queued
+                                # from the last loss fetch's return (epoch 0:
+                                # the loop's start) until its first program
+                                # was handed over; the first pull, which waits
+                                # for the feed's new chain, is part of that
+                                "lead_time_s": t_handed - t_ready,
+                                "first_pull_time_s": t_pull,
+                            }
+                            t_ready = t_fetched
+                            for m, s in zip(train_metrics, mstats):
+                                value = m.compute(jax.tree.map(np.asarray, s))
+                                if value is not None:
+                                    report[f"train_{m.name}"] = value
 
                         if eval_feed is not None or eval_cache is not None:
-                            estats = tuple(m.init() for m in metrics)
-                            esum = np.zeros((), np.float32)
-                            ecnt = np.zeros((), np.float32)
-                            if eval_cache is not None:
-                                _, estats, esum, ecnt = jit_eval_epoch(
-                                    (state, estats, esum, ecnt),
-                                    eval_cache.arrays,
-                                    jax.random.PRNGKey(0))  # unused: no shuffle
-                                if eval_tail is not None:
-                                    esum, ecnt, estats = jit_eval(
-                                        state, eval_tail, estats, esum, ecnt)
-                            else:
-                                for batch in eval_feed:
-                                    esum, ecnt, estats = jit_eval(
-                                        state, batch, estats, esum, ecnt)
-                            # real rows only: pad rows mask to 0
-                            rows = float(ecnt)
-                            report["eval_loss"] = (float(esum) / rows) \
-                                if rows else float("nan")
-                            for m, s in zip(metrics, estats):
-                                report[f"eval_{m.name}"] = m.compute(
-                                    jax.tree.map(np.asarray, s))
+                            with step_span("train:eval"):
+                                estats = tuple(m.init() for m in metrics)
+                                esum = np.zeros((), np.float32)
+                                ecnt = np.zeros((), np.float32)
+                                if eval_cache is not None:
+                                    _, estats, esum, ecnt = jit_eval_epoch(
+                                        (state, estats, esum, ecnt),
+                                        eval_cache.arrays,
+                                        jax.random.PRNGKey(0))  # no shuffle
+                                    if eval_tail is not None:
+                                        esum, ecnt, estats = jit_eval(
+                                            state, eval_tail, estats, esum,
+                                            ecnt)
+                                else:
+                                    for batch in eval_feed:
+                                        esum, ecnt, estats = jit_eval(
+                                            state, batch, estats, esum, ecnt)
+                                # real rows only: pad rows mask to 0
+                                rows = float(ecnt)
+                                report["eval_loss"] = (float(esum) / rows) \
+                                    if rows else float("nan")
+                                for m, s in zip(metrics, estats):
+                                    report[f"eval_{m.name}"] = m.compute(
+                                        jax.tree.map(np.asarray, s))
 
                         history.append(report)
-                        for cb in self.callbacks:
-                            cb(report)
+                        with step_span("train:callbacks"):
+                            for cb in self.callbacks:
+                                cb(report)
                         logger.info(
                             "epoch %d: %s", epoch,
                             {k: (round(v, 5) if isinstance(v, float) else v)
                              for k, v in report.items()})
+                    turn.enter_context(step_span("train:epoch_turn"))
                     profiler.add_args(epoch_span, steps=steps)
                 if save_epoch_now(epoch, self.checkpoint_interval,
                                   self.num_epochs):
@@ -1227,6 +1273,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:  # noqa: BLE001 - retry path (FailureConfig)
+                turn.close()
                 retries += 1
                 if retries > max_retries:
                     raise
@@ -1267,7 +1314,11 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                         state_sharding)
                     epoch = 0
                     history = []
+                # the retried epoch gets a turn of its own
+                turn.enter_context(step_span("train:epoch_turn"))
+                t_ready = time.perf_counter()
 
+        turn.close()
         return state, history
 
     # ------------------------------------------------------------ partial_fit

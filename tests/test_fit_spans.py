@@ -22,7 +22,13 @@ PHASE_SPANS = ["fit:run", "fit:convert", "fit:shuffle", "fit:feed",
                "train:epoch", "ckpt:save", "ckpt:d2h", "ckpt:import",
                "ckpt:write"]
 STEP_SPANS = ["train:feed_wait", "train:dispatch", "train:epoch_end",
-              "feed:decode", "feed:h2d", "feed:put_wait"]
+              "feed:decode", "feed:h2d", "feed:put_wait",
+              # once an epoch: what the loop does while the device runs dry
+              "train:loss_fetch", "train:report", "train:eval",
+              "train:callbacks", "train:epoch_turn", "feed:start",
+              "feed:stop"]
+EPOCH_END_CHILDREN = ["train:loss_fetch", "train:report", "train:eval",
+                      "train:callbacks"]
 
 
 # ------------------------------------------------------------------ registry
@@ -186,18 +192,33 @@ def test_feed_counts_one_pull_a_batch(session, prefetch_to_device):
     assert sum(pulls.values()) == 10
 
 
-def test_device_trace_carries_the_programs_spans(session, tmp_path,
-                                                 monkeypatch):
-    """``profiler.jax_trace`` is the operator's way to a device trace with the
-    program's spans: the step spans by thread, the phase spans mirrored with
-    the ring's span id."""
+def _traced_fit(tmp_path, cache: str, with_eval: bool) -> dict:
+    """One fit of one epoch (the OS reuses an ended thread's id) under
+    ``profiler.jax_trace``, the operator's way to a device trace with the
+    program's spans. Its callback leaves a mark of its own in the trace.
+    Gives the program's spans by line (name -> [(start, end)]; the mark too),
+    the mirrored spans' ids beside the ring's, and the fit's history."""
+    import jax
     from jax.profiler import ProfileData
-    monkeypatch.setenv("RDT_DEVICE_CACHE", "0")
-    df = _frame(session)
-    est = _estimator(1)     # one epoch: the OS reuses an ended thread's id
-    profiler.clear()
-    with profiler.jax_trace(str(tmp_path)) as log_dir:
-        est.fit_on_frame(df)
+
+    import raydp_tpu
+
+    def callback(report):
+        with jax.profiler.TraceAnnotation("test:callback"):
+            pass
+
+    with pytest.MonkeyPatch.context() as env:
+        env.setenv("RDT_DEVICE_CACHE", cache)
+        session = raydp_tpu.init("pytest", num_executors=2, executor_cores=1,
+                                 executor_memory="512MB")
+        try:
+            df = _frame(session)
+            est = _estimator(1, callbacks=[callback])
+            profiler.clear()
+            with profiler.jax_trace(str(tmp_path)) as log_dir:
+                result = est.fit_on_frame(df, df if with_eval else None)
+        finally:
+            raydp_tpu.stop()
     (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
                                      "*.xplane.pb"))
     lines, sids = [], {}
@@ -207,13 +228,37 @@ def test_device_trace_carries_the_programs_spans(session, tmp_path,
         for line in plane.lines:
             found = {}
             for e in line.events:
-                if e.name in metrics.SPAN_NAMES:
-                    found[e.name] = found.get(e.name, 0) + 1
+                if e.name in metrics.SPAN_NAMES or e.name == "test:callback":
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
                     sid = dict(e.stats).get("sid")
                     if sid:
                         sids[sid] = e.name
             if found:
                 lines.append(found)
+    return {"lines": lines, "sids": sids, "history": result.history,
+            "ring": {s["sid"]: s["name"] for s in profiler.spans()},
+            "loop": next(f for f in lines if "train:dispatch" in f)}
+
+
+@pytest.fixture(scope="module")
+def traced_fit(tmp_path_factory):
+    """A streaming fit, no eval set: the feed's threads are the only ones."""
+    return _traced_fit(tmp_path_factory.mktemp("stream"), "0", False)
+
+
+@pytest.fixture(scope="module")
+def traced_resident_fit(tmp_path_factory):
+    """A resident fit with an eval set: one dispatch an epoch, no feed."""
+    return _traced_fit(tmp_path_factory.mktemp("resident"), "1", True)
+
+
+def test_device_trace_carries_the_programs_spans(traced_fit):
+    """The step spans by thread, the phase spans mirrored with the ring's
+    span id."""
+    sids = traced_fit["sids"]
+    lines = [{name: len(at) for name, at in found.items()}
+             for found in traced_fit["lines"]]
 
     def line_of(name):
         (line,) = [found for found in lines if name in found]
@@ -228,7 +273,81 @@ def test_device_trace_carries_the_programs_spans(session, tmp_path,
     assert decode["feed:decode"] == 9 and h2d["feed:h2d"] == 8
     assert not set(decode) & {"train:dispatch", "feed:h2d"}
     # the phase spans are mirrored on the loop's line, joined by span id
-    ring = {s["sid"]: s["name"] for s in profiler.spans()}
+    ring = traced_fit["ring"]
     assert sids and all(ring[sid] == name for sid, name in sids.items())
     assert {"fit:run", "train:epoch", "ckpt:save"} <= set(sids.values())
     assert {"fit:run", "train:epoch", "ckpt:write"} <= set(loop)
+
+
+def _inside(spans, outer):
+    return all(any(s <= a and b <= e for s, e in outer) for a, b in spans)
+
+
+@pytest.mark.parametrize("name", ["train:loss_fetch", "train:report",
+                                  "train:callbacks", "train:epoch_turn",
+                                  "feed:start", "feed:stop"])
+def test_device_trace_carries_the_once_an_epoch_spans(traced_fit, name):
+    """Each on the loop's own line, once an epoch, where it belongs."""
+    loop = traced_fit["loop"]
+    assert all(name not in found for found in traced_fit["lines"]
+               if found is not loop)
+    pulls = sorted(loop["train:feed_wait"])
+    (end,) = loop["train:epoch_end"]
+    if name == "train:epoch_turn":
+        # the loop's start to the first pull, then the epoch's end to the
+        # loop's end, with the final save in it
+        first, last = sorted(loop[name])
+        assert first[1] <= pulls[0][0] and end[1] <= last[0]
+        assert _inside(loop["ckpt:save"], [last])
+        return
+    (span,) = loop[name]
+    outer = {"feed:start": pulls[0], "feed:stop": pulls[-1]}.get(name, end)
+    assert _inside([span], [outer])
+    assert "train:eval" not in loop     # the fit has no eval set
+
+
+def test_resident_trace_turns_into_the_epochs_one_dispatch(
+        traced_resident_fit):
+    loop = traced_resident_fit["loop"]
+    (dispatch,), (end,) = loop["train:dispatch"], loop["train:epoch_end"]
+    first, last = sorted(loop["train:epoch_turn"])
+    assert first[1] <= dispatch[0] and dispatch[1] <= end[0]
+    assert end[1] <= last[0]
+    assert not {"train:feed_wait", "feed:start", "feed:stop"} & set(loop)
+    # the eval pass is a child of the epoch's end, between report and callbacks
+    (ev,), (report,) = loop["train:eval"], loop["train:report"]
+    (calls,) = loop["train:callbacks"]
+    assert _inside([ev], [end]) and report[1] <= ev[0] and ev[1] <= calls[0]
+    assert "eval_loss" in traced_resident_fit["history"][0]
+
+
+@pytest.mark.parametrize("which", ["traced_fit", "traced_resident_fit"])
+def test_loss_fetch_ends_before_the_first_callback_is_called(request, which):
+    """A profiler session that a callback stops (the benchmark's harness does)
+    still holds the epoch's ``train:loss_fetch``: it has closed by then."""
+    loop = request.getfixturevalue(which)["loop"]
+    (fetch,), (report,) = loop["train:loss_fetch"], loop["train:report"]
+    (mark,), (calls,) = loop["test:callback"], loop["train:callbacks"]
+    assert fetch[1] <= report[0] and report[1] <= mark[0]
+    assert _inside([mark], [calls])
+    assert _inside([fetch, report, calls], loop["train:epoch_end"])
+
+
+@pytest.mark.parametrize("route", ["stream", "resident"])
+def test_history_says_how_long_the_device_had_nothing_queued(
+        session, monkeypatch, route):
+    """``lead_time_s`` (last loss fetch, or the loop's start, to the epoch's
+    first program handed over) holds ``first_pull_time_s`` (the epoch's first
+    ``next()`` on the feed; a resident epoch pulls nothing) in every epoch,
+    traced or not."""
+    monkeypatch.setenv("RDT_DEVICE_CACHE", "0" if route == "stream" else "1")
+    history = _estimator(3).fit_on_frame(_frame(session)).history
+    assert len(history) == 3
+    for entry in history:
+        assert entry["lead_time_s"] >= entry["first_pull_time_s"] >= 0
+        assert entry["lead_time_s"] > 0
+    pulls = [e["first_pull_time_s"] for e in history]
+    assert all(p > 0 for p in pulls) if route == "stream" else pulls == [0.0] * 3
+    # the first pull is one of the epoch's pulls
+    assert all(e["first_pull_time_s"] <= e["feed_time_s"] for e in history
+               if route == "stream")
