@@ -361,6 +361,15 @@ _ALL_METRICS = [
        "the window - 1 keys before it) or `full` (every key up to its own). "
        "doc/models.md.",
        label="kind"),
+    _m("train_attention_forward_total", COUNTER, "1", "training",
+       "Attention layers of a training model, counted once a built train "
+       "step by how often the step runs their forward attention: `once` "
+       "(the block is not recomputed, or `remat_blocks` recomputes it and "
+       "keeps the flash kernel's output and row sums, so the recomputation "
+       "holds no forward kernel) or `twice` (a recomputed block whose "
+       "attention names nothing to keep: `dense`, `ring`). "
+       "doc/long_context.md.",
+       label="times"),
     _m("flash_blocks_total", COUNTER, "1", "training",
        "(q block, k block) pairs of the flash-attention kernels, counted "
        "where a kernel's grid is built (once a built forward kernel, twice "

@@ -36,8 +36,13 @@ RoPE, 0 = it sees every earlier key / has no position embedding), the expert
 layer's ``expert_activation``, ``normalize_top_k``, ``first_expert`` and
 ``experts_held`` (one chip's share of an expert-parallel layer), and
 ``router_input="attention"`` (the router reads the attention's normed input,
-not the experts'). ``remat_blocks`` saves each block's input alone and
-recomputes the block in the backward pass. A vocabulary-parallel deployment's
+not the experts'). ``remat_blocks`` recomputes each block in the backward
+pass from what it saved: the block's input and, where its attention is the
+flash kernel, the kernel's output and row log-sum-exp
+(``flash_attention.RESIDUAL_NAMES``: ``B*T x heads x head_dim`` activations
+and ``B*T x heads`` float32 a layer), so norms, projections, RoPE, gate,
+router and feed-forward run again and the forward kernel does not
+(:attr:`TransformerLM.attention_forward`). A vocabulary-parallel deployment's
 share is a smaller ``vocab_size``: embedding and head over the rows held,
 ids drawn from them.
 
@@ -328,6 +333,20 @@ class TransformerLM(nn.Module):
         return {"window": windowed, "full": self.num_layers - windowed}
 
     @property
+    def attention_forward(self):
+        """How often a train step runs each layer's forward attention: what
+        ``train_attention_forward_total`` counts once a built step. ``once``:
+        the block is not recomputed, or it is and keeps its flash kernel's
+        output and row sums; ``twice``: a recomputed block whose attention
+        names nothing to keep (``dense`` and ``ring``: their ``[T, T]``
+        scores must not be kept). ``auto`` counts as what it picks for a
+        shape the kernel takes."""
+        kind = Attention(self.num_heads, self.attention,
+                         self.mesh)._dispatch(8192, 128)
+        kept = not self.remat_blocks or kind == "flash"
+        return {"once" if kept else "twice": self.num_layers}
+
+    @property
     def _share(self) -> bool:
         return bool(self.num_experts and self.experts_held is not None
                     and self.experts_held < self.num_experts)
@@ -352,7 +371,15 @@ class TransformerLM(nn.Module):
         if self.embed_scale:
             x = x * jnp.asarray(np.sqrt(self.dim), x.dtype)
         aux = []
-        block = nn.remat(Block) if self.remat_blocks else Block
+        block = Block
+        if self.remat_blocks:
+            from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+            # a recomputed block keeps its input and what its flash kernel
+            # made: the recomputation re-forms q, k and v for the backward
+            # kernels and holds no forward kernel
+            block = nn.remat(Block, policy=jax.checkpoint_policies
+                             .save_only_these_names(*RESIDUAL_NAMES))
         for i in range(self.num_layers):
             sparse = self._sparse(i)
             x = block(self.num_heads, self.mlp_ratio, self.attention,

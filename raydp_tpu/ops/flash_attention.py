@@ -656,7 +656,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window):
             jnp_fn, interpret, q3, k3, v3)
     else:
         out, lse = jnp_fn(q3, k3, v3)
-    return out, (q3, k3, v3, out, lse)
+    return _named(q3, k3, v3, out, lse)
 
 
 def _flash_bwd(scale, causal, blk_q, blk_k, interpret, window, res, g):
@@ -724,3 +724,22 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, **kwargs):
     spec = P(batch if len(batch) > 1 else batch[0], None, heads, None)
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec)(q, k, v)
+
+
+# What the backward kernels read beside q, k and v, by the names a
+# ``jax.checkpoint`` policy can keep them under
+# (``save_only_these_names(*RESIDUAL_NAMES)``): a recomputed block then keeps
+# these two and its recomputation holds no forward kernel. Outside a
+# checkpoint, and under a policy that names nothing, a name is the identity.
+# Down here so that no line above moves: the kernels' payloads embed the
+# source lines of their call stack, and a moved line misses the compile cache.
+RESIDUAL_NAMES = ("rdt_flash_out", "rdt_flash_lse")
+
+
+def _named(q3, k3, v3, out, lse):
+    """What ``_flash_fwd`` returns: the output and the residuals, with the
+    kernel's two products under their names in both."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    out, lse = map(checkpoint_name, (out, lse), RESIDUAL_NAMES)
+    return out, (q3, k3, v3, out, lse)

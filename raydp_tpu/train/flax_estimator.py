@@ -246,6 +246,9 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     apply_fn.after_step = getattr(model, "after_step", None)
     # {"window": n, "full": m} where the model says so (a language model)
     apply_fn.attention_layers = getattr(model, "attention_layers", None) or {}
+    # {"once": n} or {"twice": n}: how often a step runs their forward
+    apply_fn.attention_forward = getattr(
+        model, "attention_forward", None) or {}
     if callable(getattr(model, "lookups", None)):
         apply_fn.lookups = lambda batch: model.lookups(split_batch(batch)[0])
     return apply_fn
@@ -461,9 +464,12 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     if getattr(apply_fn, "model_loss", False):
         # once a built step, like the table counter below
         rdt_metrics.inc("train_head_loss_total", label="forward_grad")
-    for kind, layers in getattr(apply_fn, "attention_layers", {}).items():
-        if layers:      # once a built step, by kind of layer
-            rdt_metrics.inc("train_attention_layers_total", layers, kind)
+    for metric, of_model in (
+            ("train_attention_layers_total", "attention_layers"),
+            ("train_attention_forward_total", "attention_forward")):
+        for kind, layers in getattr(apply_fn, of_model, {}).items():
+            if layers:      # once a built step, by kind of layer
+                rdt_metrics.inc(metric, layers, kind)
     counted: list = []      # the table counters are bumped once a built step
     placed = None if state_shardings is None else (
         state_shardings.params, state_shardings.opt_state)

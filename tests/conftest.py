@@ -52,3 +52,20 @@ def session():
                        executor_memory="512MB")
     yield s
     raydp_tpu.stop()
+
+
+@pytest.fixture
+def forward_flash_kernels(monkeypatch):
+    """A model's ``attention="flash"`` runs its Pallas kernels here,
+    interpreted, in blocks of 16 (off the chip the op would take its jnp
+    path); the fixture counts the forward kernels, full and windowed, in a
+    program's jaxpr."""
+    import functools
+    import re
+
+    from raydp_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True, block_q=16, block_k=16))
+    return lambda program: len(re.findall(
+        r"\bname=rdt_flash(?:_win)?_fwd\b", str(program)))

@@ -399,24 +399,35 @@ def _train_step(model, tx, accum):
                                 tuple(m.init() for m in metrics),
                                 np.float32(0))
         return new, float(loss), np.asarray(stats[0])
+    run.step, run.metrics = step, metrics
     return create, run
 
 
-@pytest.mark.parametrize("remat,accum", [(False, 1), (True, 1), (True, 2)],
-                         ids=["kept", "recomputed", "recomputed_accum2"])
+@pytest.mark.parametrize("remat,accum,attention,forward", [
+    (False, 1, "dense", "once"), (True, 1, "dense", "twice"),
+    (True, 2, "dense", "twice"), (False, 1, "flash", "once"),
+    (True, 1, "flash", "once"), (True, 2, "flash", "once")],
+    ids=["kept", "recomputed", "recomputed_accum2", "kept_flash",
+         "recomputed_flash", "recomputed_flash_accum2"])
 def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
-        remat, accum):
+        remat, accum, attention, forward, forward_flash_kernels):
     """The model's own loss (fused head over the rows held, no auxiliary
     loss) and the gradient of every leaf, with seeded biases; then three
     optimizer steps of the estimator's train step: after each, every layer's
     bias is the reference's ``next_bias`` of the slots ALL experts were
     picked for in the step's tokens (both micro-batches together under
     ``accum_steps`` 2: one update a step), the counts are empty again, and
-    the forward of a step used the bias the step before left."""
+    the forward of a step used the bias the step before left. With the flash
+    kernels (interpret mode) a recomputed block keeps the kernel's output and
+    row sums: the built step's program holds one forward kernel a layer and
+    micro-batch and counts its five layers ``once``; recomputed dense
+    attention counts them ``twice``."""
     import jax
     import optax
-    cfg, pipeline, reference = _files(remat_blocks=remat)
+    from raydp_tpu import metrics as registry
+    cfg, pipeline, reference = _files(remat_blocks=remat, attention=attention)
     model = pipeline.build_model(cfg)
+    assert model.attention_forward == {forward: 5}
     tokens = _tokens(cfg, 4, seed=1)
     params, state = _variables(model, tokens, bias_std=0.1)
     w = np.full(4, 0.25, np.float32)
@@ -437,8 +448,19 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
                  for b in state.values())
     assert float(counts[4]) == pytest.approx(spread)
 
+    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
+        "train_attention_forward_total", {}))
+    before = counted()
     create, run = _train_step(model, optax.sgd(0.05), accum)
+    assert {k: v - before.get(k, 0) for k, v in counted().items()
+            if v != before.get(k, 0)} == {forward: 5}
     now = create(params, state)
+    program = jax.make_jaxpr(run.step)(
+        now, {"tokens": tokens}, tuple(m.init() for m in run.metrics),
+        np.float32(0))
+    # a scan over the micro-batches holds its body once
+    assert forward_flash_kernels(program) == (
+        5 if attention == "flash" else 0)
     bias = {name: b["moe"]["bias"] for name, b in state.items()}
     for step in range(3):
         batch = _tokens(cfg, 4, seed=10 + step)
@@ -585,7 +607,9 @@ def test_fit_on_frame_at_the_cpu_cut_learns_counts_and_balances(session,
                    os.path.join(path, "part-0.parquet"))
     df, info = pipeline.etl(session.read.parquet(path), cfg, wl)
     mesh = make_mesh(None, devices=jax.devices()[:1])
-    before = registry.snapshot()["counters"].get("moe_slots_total", {})
+    counters = registry.snapshot()["counters"]
+    before = counters.get("moe_slots_total", {})
+    forward = dict(counters.get("train_attention_forward_total", {}))
     est = FlaxEstimator(
         model=pipeline.build_model(cfg, mesh), loss=None,
         optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
@@ -601,6 +625,11 @@ def test_fit_on_frame_at_the_cpu_cut_learns_counts_and_balances(session,
              for k, v in snapshot["counters"]["moe_slots_total"].items()}
     steps = 2 * rows // 2
     assert slots["all"] == 2 * rows * 256 * 8       # epochs, tokens, top-8, one layer
+    # the cut's two blocks are recomputed and keep their flash op's pair
+    assert (cfg["remat_blocks"], cfg["attention"]) == (True, "flash")
+    after = snapshot["counters"]["train_attention_forward_total"]
+    assert after["once"] - forward.get("once", 0) == 2
+    assert after.get("twice", 0) == forward.get("twice", 0)
     assert 0 < slots["held"] <= slots["moved"] < slots["all"]
     state = est.get_model()["batch_stats"]
     spreads = []

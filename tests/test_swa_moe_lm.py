@@ -604,20 +604,31 @@ def test_the_reference_by_blocks_of_queries_is_the_reference():
     np.testing.assert_allclose(blocked, whole, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
-def test_loss_rows_and_every_gradient_leaf_match_the_reference(remat):
+@pytest.mark.parametrize("remat,attention,forward,kernels", [
+    (False, "dense", "once", 0), (True, "dense", "twice", 0),
+    (False, "flash", "once", 4), (True, "flash", "once", 4)],
+    ids=["kept", "recomputed", "kept_flash", "recomputed_flash"])
+def test_loss_rows_and_every_gradient_leaf_match_the_reference(
+        remat, attention, forward, kernels, forward_flash_kernels):
     """The model's own loss (fused head over the rows held, both auxiliary
     losses over all experts) and its gradients, with the blocks' activations
-    kept and with the blocks recomputed; and what it counts."""
+    kept and with the blocks recomputed; and what it counts. A recomputed
+    block whose attention is the flash kernel keeps the kernel's output and
+    row sums: the gradient's program holds one forward kernel a layer, as
+    when nothing is recomputed."""
     import jax
-    cfg, pipeline, reference = _files(remat_blocks=remat)
+    cfg, pipeline, reference = _files(remat_blocks=remat, attention=attention)
     model = pipeline.build_model(cfg)
+    assert model.attention_forward == {forward: 4}
     tokens = _tokens(cfg, 4, seed=1)
     params = _params(model, tokens)
     w = np.full(4, 0.25, np.float32)
-    (loss, counts), grads = jax.value_and_grad(
+    value_and_grad = jax.value_and_grad(
         lambda p: model.apply({"params": p}, tokens, tokens, w,
-                              method=model.loss_rows), has_aux=True)(params)
+                              method=model.loss_rows), has_aux=True)
+    assert forward_flash_kernels(
+        jax.make_jaxpr(value_and_grad)(params)) == kernels
+    (loss, counts), grads = value_and_grad(params)
     want_loss, want_grads = jax.value_and_grad(reference.loss)(
         params, tokens, cfg)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
@@ -634,6 +645,39 @@ def test_loss_rows_and_every_gradient_leaf_match_the_reference(remat):
     assert float(counts[3]) == sum(
         -(-held // 48) * 48 for held in per_expert[:, 2:4].sum(axis=1))
     assert float(counts[2]) <= float(counts[3]) < float(counts[1])
+
+
+def test_the_kept_pair_survives_the_map_over_a_mesh(forward_flash_kernels):
+    """On four devices the flash op runs under ``shard_map`` (a chip its own
+    rows): the names it gives its output and row sums are honoured inside
+    the map, so a recomputed block still holds one forward kernel a layer,
+    and loss and gradients are the one-device, nothing-recomputed ones."""
+    import jax
+    from raydp_tpu.models import TransformerLM
+    from raydp_tpu.parallel import MeshSpec, make_mesh
+
+    mesh = make_mesh(MeshSpec(data=4), devices=jax.devices()[:4])
+    sizes = dict(vocab_size=64, dim=32, num_heads=4, num_kv_heads=2,
+                 head_dim=8, num_layers=2, sliding_window=16,
+                 window_layers=(0, 1), attention="flash")
+    plain = TransformerLM(**sizes)
+    mapped = TransformerLM(**sizes, mesh=mesh, remat_blocks=True)
+    assert mapped.attention_forward == plain.attention_forward == {"once": 2}
+    tokens = np.random.default_rng(5).integers(0, 64, (4, 32), np.int32)
+    params = _params(plain, tokens)
+    w = np.full(4, 0.25, np.float32)
+
+    def value_and_grad(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p}, tokens, tokens, w,
+                                  method=model.loss_rows)[0]))
+
+    program = str(jax.make_jaxpr(value_and_grad(mapped))(params))
+    assert "shard_map" in program and forward_flash_kernels(program) == 2
+    loss, grads = value_and_grad(mapped)(params)
+    want_loss, want_grads = value_and_grad(plain)(params)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
 
 
 def test_the_pattern_decides_each_layers_window_and_rope():
@@ -715,6 +759,10 @@ def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
     assert slots["all"] / 8 <= slots["max_expert"] <= slots["all"]
     assert _moved(before, after, "train_attention_layers_total") == {
         "window": 3, "full": 1}
+    # the cell's blocks are recomputed and their attention is the flash op
+    assert (cfg["remat_blocks"], cfg["attention"]) == (True, "flash")
+    assert _moved(before, after, "train_attention_forward_total") == {
+        "once": 4}
 
 
 def test_moved_slots_are_counted_where_a_share_is_held_and_only_there(

@@ -69,6 +69,61 @@ def test_flash_pallas_bwd_interpret_matches_dense(causal, blocks):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4)
 
 
+@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel"])
+@pytest.mark.parametrize("policy,forwards", [
+    ("the_names", 1), ("nothing_saveable", 2), ("no_policy", 2)])
+def test_a_checkpoint_that_keeps_the_named_pair_runs_the_forward_once(
+        interpret, policy, forwards, capsys, forward_flash_kernels):
+    """``_flash_fwd`` names what the backward reads beside q, k, v. Under
+    ``save_only_these_names`` of the two a ``jax.checkpoint`` keeps them and
+    its recomputation holds no forward (one a call in the gradient's
+    program); under ``nothing_saveable`` (the estimator's ``remat="full"``)
+    and with no policy the forward still runs twice. The gradients are the
+    un-checkpointed ones either way."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    policies = jax.checkpoint_policies
+    kept = {"the_names": policies.save_only_these_names(*RESIDUAL_NAMES),
+            "nothing_saveable": policies.nothing_saveable,
+            "no_policy": None}[policy]
+    q, k, v = _qkv(t=128, h=4, d=32, seed=3)
+    k, v = k[:, :, :2], v[:, :, :2]       # two query heads a K/V head
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=interpret,
+                              block_q=64, block_k=64, window=96)
+        return jnp.sum(jnp.tanh(out) * q)
+
+    def forward_calls(fn):
+        # the forward kernel by name or, on the jnp path, the row maxima of
+        # its log-sum-exp, which nothing else in these programs takes
+        program = jax.make_jaxpr(grad(fn))(q, k, v)
+        return forward_flash_kernels(program) if interpret \
+            else str(program).count("= reduce_max[")
+
+    grad = lambda f: jax.grad(f, argnums=(0, 1, 2))  # noqa: E731
+    recomputed = jax.checkpoint(loss, policy=kept)
+    assert (forward_calls(loss), forward_calls(recomputed)) == (1, forwards)
+    for got, want in zip(grad(recomputed)(q, k, v), grad(loss)(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+    # what the checkpoint keeps beside its arguments: the kernel's output
+    # ([B*H, T, D]; it is read on after the op, so jax lists it by the
+    # reduce_precision it puts on such a residual) and the row sums by name
+    capsys.readouterr()
+    print_saved_residuals(recomputed, q, k, v)
+    inside = [line for line in capsys.readouterr().out.splitlines()
+              if "from the argument" not in line]
+    if forwards == 1:
+        assert sorted(line.split()[0] for line in inside) == [
+            "f32[8,128,32]", "f32[8,128]"]
+        assert any(f"named '{RESIDUAL_NAMES[1]}'" in line for line in inside)
+    else:
+        assert inside == []
+
+
 def test_explicit_flash_on_a_tpu_backend_raises_where_the_kernel_cannot_run(
         monkeypatch):
     """On the chip an explicit ``flash`` never quietly runs the jnp path
