@@ -25,11 +25,12 @@ The sequence length is the configuration's ``seq_len`` (the cell's workload
 has to repeat it: ``pipelines/trinity-mini.py``); ``max_position_embeddings``
 is the published 131,072 and sizes nothing.
 
-The kernels' functions return ``(operations, bytes)`` of ONE execution of the
-kernels of one layer of a kind over ``sequences`` sequences, both the least
+The flash kernels' functions answer to the one contract every family keeps
+(``trace/executions.py``): ``(operations, bytes)`` of ONE execution of the
+kernels of one layer of ``kind`` over ``sequences`` sequences, both the least
 the algorithm needs: a reader multiplies by the executions it finds in the
-trace (a recomputed block runs its forward kernel a second time, and that
-execution's operations are counted with its seconds), and
+trace (a block that ran its forward kernel a second time would have that
+execution's operations counted with its seconds), and
 ``trace/roofline.share`` divides by the peaks. Bytes are each operand read
 once and each result written once at the activations' width; a K/V head is
 read once a group of query heads, not once a query head.
@@ -112,7 +113,12 @@ def _width(cfg: dict) -> int:
     return 2 if cfg["compute_dtype"] == "bfloat16" else 4
 
 
-def flash_forward(cfg: dict, kind: str, sequences: float):
+def num_experts(cfg: dict) -> int:
+    """The experts a layer's router chooses among, whatever a chip holds."""
+    return cfg["num_experts"]
+
+
+def flash_forward(cfg: dict, wl: dict, kind: str, sequences: float):
     """One execution of the forward attention kernel of ONE layer of
     ``kind`` (``window`` or ``full``) over ``sequences`` sequences: QK^T and
     PV over the layer's visible pairs; reads q and, once a group, k and v;
@@ -125,7 +131,7 @@ def flash_forward(cfg: dict, kind: str, sequences: float):
     return flops, moved
 
 
-def flash_backward(cfg: dict, kind: str, sequences: float):
+def flash_backward(cfg: dict, wl: dict, kind: str, sequences: float):
     """The backward attention kernels (dK/dV and dQ together) of ONE layer
     of ``kind``: the five products the gradient needs over the visible pairs
     (scores again, dP, dV, dK, dQ). The program's two kernels form the scores

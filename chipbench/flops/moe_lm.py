@@ -12,10 +12,14 @@ the flash kernels re-form the scores; that work lowers ``model_flops_util``,
 it does not count towards it. Norms, softmax, RoPE, the sort, gathers and the
 optimizer count zero: they move bytes.
 
-The kernels' functions return ``(operations, bytes)`` for one optimizer step
-of one chip, both the least the algorithm needs: what
-``trace/roofline.share`` divides by the peaks. Bytes are each operand read
-once and each result written once at the activations' width.
+The flash kernels' functions answer to the one contract every family keeps
+(``trace/executions.py``): ``(operations, bytes)`` of ONE execution of the
+kernels of one layer of ``kind`` over ``sequences`` sequences of the
+workload's ``seq_len`` (every layer here is ``full``: causal, no window);
+``expert_gemms`` returns them for one optimizer step of one chip. Both are
+the least the algorithm needs: what ``trace/roofline.share`` divides by the
+peaks. Bytes are each operand read once and each result written once at the
+activations' width.
 """
 
 from __future__ import annotations
@@ -49,27 +53,38 @@ def _width(cfg: dict) -> int:
     return 2 if cfg["compute_dtype"] == "bfloat16" else 4
 
 
-def flash_forward(cfg: dict, sequences: int, seq_len: int):
-    """The forward attention kernel over ``sequences`` sequences in every
-    layer: QK^T and PV over the causal pairs; reads q, k, v, writes the
-    output and a float32 log-sum-exp a row."""
-    d, heads, *_, layers = _shape(cfg)
-    pairs = seq_len * (seq_len + 1) / 2
-    rows = layers * sequences * seq_len
-    flops = layers * sequences * 2 * 2 * d * pairs
+def num_experts(cfg: dict) -> int:
+    """The experts a layer's router chooses among."""
+    return cfg["num_experts"]
+
+
+def _pairs(wl: dict, kind: str) -> float:
+    """Visible (query, key) pairs of one sequence in one layer."""
+    if kind != "full":
+        raise ValueError(f"a moe_lm layer is full causal attention, not {kind!r}")
+    seq_len = int(wl["seq_len"])
+    return seq_len * (seq_len + 1) / 2
+
+
+def flash_forward(cfg: dict, wl: dict, kind: str, sequences: float):
+    """One execution of the forward attention kernel of ONE layer over
+    ``sequences`` sequences: QK^T and PV over the causal pairs; reads q, k,
+    v, writes the output and a float32 log-sum-exp a row."""
+    d, heads, *_ = _shape(cfg)
+    rows = sequences * int(wl["seq_len"])
+    flops = sequences * 2 * 2 * d * _pairs(wl, kind)
     return flops, 4 * rows * d * _width(cfg) + rows * heads * 4
 
 
-def flash_backward(cfg: dict, sequences: int, seq_len: int):
-    """The backward attention kernels: the five products the gradient needs
-    over the causal pairs (scores again, dP, dV, dK, dQ). The program's two
-    kernels form the scores and dP twice (seven products): the two extra are
-    recompute, not counted. Reads q, k, v, the output and its gradient,
-    writes three gradients."""
-    d, heads, *_, layers = _shape(cfg)
-    pairs = seq_len * (seq_len + 1) / 2
-    rows = layers * sequences * seq_len
-    flops = layers * sequences * 5 * 2 * d * pairs
+def flash_backward(cfg: dict, wl: dict, kind: str, sequences: float):
+    """The backward attention kernels (dK/dV and dQ together) of ONE layer:
+    the five products the gradient needs over the causal pairs (scores again,
+    dP, dV, dK, dQ). The program's two kernels form the scores and dP twice
+    (seven products): the two extra are recompute, not counted. Reads q, k,
+    v, the output and its gradient, writes three gradients."""
+    d, heads, *_ = _shape(cfg)
+    rows = sequences * int(wl["seq_len"])
+    flops = sequences * 5 * 2 * d * _pairs(wl, kind)
     return flops, 8 * rows * d * _width(cfg) + 2 * rows * heads * 4
 
 

@@ -17,8 +17,11 @@ the flash kernels' re-formed scores lower ``model_flops_util``, they do not
 count towards it. Norms, softmax, RoPE, the sort, gathers and the optimizer
 count zero: they move bytes.
 
-The kernels' functions return ``(operations, bytes)`` for one optimizer step
-of one chip, both the least the algorithm needs: what
+The flash kernels' functions answer to the one contract every family keeps
+(``trace/executions.py``): ``(operations, bytes)`` of ONE execution of the
+kernels of one layer of ``kind`` (``window`` or ``full``) over ``sequences``
+sequences of the workload's ``seq_len``, both the least the algorithm needs:
+a reader multiplies by the executions it finds in the trace, and
 ``trace/roofline.share`` divides by the peaks. Bytes are each operand read
 once and each result written once at the activations' width; a K/V head is
 read once a group of query heads, not once a query head.
@@ -98,27 +101,36 @@ def _width(cfg: dict) -> int:
     return 2 if cfg["compute_dtype"] == "bfloat16" else 4
 
 
-def gqa_flash_forward(cfg: dict, sequences: float, seq_len: int):
-    """The forward attention kernels over ``sequences`` sequences in every
-    layer held: QK^T and PV over each layer's visible pairs; reads q and, once
-    a group, k and v; writes the output and a float32 log-sum-exp a row."""
+def num_experts(cfg: dict) -> int:
+    """The experts a layer's router chooses among, whatever a chip holds."""
+    return cfg["moe_num_primary_experts"]
+
+
+def flash_forward(cfg: dict, wl: dict, kind: str, sequences: float):
+    """One execution of the forward attention kernel of ONE layer of ``kind``
+    over ``sequences`` sequences: QK^T and PV over the layer's visible pairs;
+    reads q and, once a group, k and v; writes the output and a float32
+    log-sum-exp a row."""
     q, kv = _heads(cfg)
-    rows = cfg["layers"] * sequences * seq_len
-    flops = sequences * 2 * 2 * q * _all_pairs(cfg, seq_len)
+    seq_len = int(wl["seq_len"])
+    rows = sequences * seq_len
+    flops = sequences * 2 * 2 * q * pairs_by_kind(cfg, seq_len)[kind]
     moved = rows * (2 * q + 2 * kv) * _width(cfg) \
         + rows * cfg["num_attention_heads"] * 4
     return flops, moved
 
 
-def gqa_flash_backward(cfg: dict, sequences: float, seq_len: int):
-    """The backward attention kernels: the five products the gradient needs
-    over the visible pairs (scores again, dP, dV, dK, dQ). The program's two
-    kernels form the scores and dP twice (seven products): the two extra are
-    recompute, not counted. Reads q, the output and its gradient and, once a
-    group, k and v; writes dq and, summed over a group, dk and dv."""
+def flash_backward(cfg: dict, wl: dict, kind: str, sequences: float):
+    """The backward attention kernels (dK/dV and dQ together) of ONE layer of
+    ``kind``: the five products the gradient needs over the visible pairs
+    (scores again, dP, dV, dK, dQ). The program's two kernels form the scores
+    and dP twice (seven products): the two extra are recompute, not counted.
+    Reads q, the output and its gradient and, once a group, k and v; writes
+    dq and, summed over a group, dk and dv."""
     q, kv = _heads(cfg)
-    rows = cfg["layers"] * sequences * seq_len
-    flops = sequences * 5 * 2 * q * _all_pairs(cfg, seq_len)
+    seq_len = int(wl["seq_len"])
+    rows = sequences * seq_len
+    flops = sequences * 5 * 2 * q * pairs_by_kind(cfg, seq_len)[kind]
     moved = rows * (4 * q + 4 * kv) * _width(cfg) \
         + 2 * rows * cfg["num_attention_heads"] * 4
     return flops, moved

@@ -349,6 +349,13 @@ def check_correct(cell: Cell, est, session, info, seed, out_dir, window,
     found["lowerings_in_window"] = lowerings_in_window
     found["checks"] = {"reference": ok_a, "loss_falls": ok_b,
                        "loss_band": ok_c, "path": ok_d, "no_compile": ok_e}
+    # each number compared beside its limit: the result line's last key and
+    # the run's last lines on standard error (``run.py``)
+    found["compared"] = {
+        "reference_error": {"value": err, "limit": cell.reference.TOLERANCE},
+        "last_loss": {"value": losses[-1], "limit": losses[0]},
+        "first_window_loss": {"value": losses[0], "limit": band},
+        "lowerings_in_window": {"value": lowerings_in_window, "limit": 0}}
     return all(found["checks"].values()), found
 
 
@@ -451,7 +458,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                   int(s.get("peak_bytes_in_use", 0)) for s in stats)}
     detail = {"cell": cell.name, "seed": seed, "rows": rows,
               "num_epochs": num_epochs, "t_e": t_e, "window_s": window_s,
-              "clock": clock, "found": found,
+              "clock": clock, "found": found, "counters": counters,
               "cache_misses_in_fit": misses_in_fit,
               "cache_misses_total": compiles.cache_misses,
               "lowerings_total": compiles.lowerings,
@@ -478,8 +485,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     # own wall (no cell here is resident, so no reader takes it yet)
     gaps = [ticks[i][0] - ticks[i - 1][0] - history[i]["epoch_time_s"]
             for i in range(2, first)]
-    # what a per-layer reader is given
+    # what a per-layer reader is given: the run's readings, and its cell
+    # (the configuration and the traffic as their files have them, and the
+    # family's count of operations), so that a reader names no configuration
     run = {
+        "cell": cell.name, "cfg": cell.cfg, "wl": cell.wl,
+        "flops": cell.flops,
         "epochs": untraced, "epoch_gaps_s": gaps, "clock": clock,
         "counters": counters, "trace": reduced, "xplane": xplane,
         "chips": len(devices),

@@ -1,9 +1,9 @@
 """Model: attention's share of the device's busy time. Busy seconds of the
 ops whose ``op_name`` lies under an ``attn`` scope (every layer's projections,
-RoPE and flash kernels, windowed and full, forward, recomputed and backward)
-over all busy seconds (``trace/scopes.py`` reads the programs the trace
-stores). It sums ``op_seconds``, whose leaf rule drops a kernel execution
-that holds an async copy's ``-done`` (PERF.md section 3), from both sides."""
+an output gate's among them, QK norms, RoPE, a gate's sigmoid, and the flash
+kernels, windowed and full, forward, recomputed and backward) over all busy
+seconds (``trace/scopes.py`` reads the programs the trace stores). A program
+without the scope says nothing."""
 
 from chipbench.trace import scopes
 

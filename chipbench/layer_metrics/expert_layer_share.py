@@ -1,10 +1,12 @@
-"""Model: the sparse expert layer's share of the device's busy time. Busy
-seconds of the ops whose ``op_name`` lies under a ``moe`` scope (router,
-dispatch, SiLU and gate, combine, forward and backward) and of the grouped
-products themselves (``ragged-dot``: the compiler's kernel keeps no op_name
-of the model's) over all busy seconds; the optimizer's update of the expert
-kernels is outside it (``trace/scopes.py`` reads the programs the trace
-stores)."""
+"""Model: the sparse expert layers' share of the device's busy time, whole or
+the share of them one chip holds. Busy seconds of the ops whose ``op_name``
+lies under a ``moe`` scope (router, the sort and dispatch of the slots, the
+experts' activation and gate, a shared expert, combine; forward, recomputed
+and backward) and of the grouped products themselves (``ragged-dot``: the
+compiler's kernel keeps no op_name of the model's; with a share held they run
+over the held experts' slots alone) over all busy seconds; the optimizer's
+update of the expert kernels is outside it (``trace/scopes.py`` reads the
+programs the trace stores). A program without the scope says nothing."""
 
 from chipbench.trace import scopes
 

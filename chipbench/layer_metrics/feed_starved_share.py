@@ -6,7 +6,7 @@ had to wait for the feed, not that the chip starved: at each epoch's start the
 loop runs tens of steps ahead of the device as fast as the feed delivers, and
 those pulls find the queue empty while the device's own queue is deep (3% on
 one chip, 26% on four, both at 0.8% device idle; PERF.md). Read it beside
-``idle_feed_wait_share``."""
+``idle_host_late_share`` and ``feed_restart_share``."""
 
 
 def read(run):

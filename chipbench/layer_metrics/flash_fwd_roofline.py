@@ -1,24 +1,24 @@
-"""Kernels: the forward flash-attention kernel's share of its roofline. The
-seconds of ``rdt_flash_fwd`` (``raydp_tpu/ops/flash_attention.KERNEL_NAMES``)
-in the traced epochs (its own events, ``trace/kernels.py``) against the least
-a chip could take for the same calls: QK^T and PV over the causal pairs of
-every traced sequence
-(``flops/moe_lm.flash_forward``, with the sizes of the configuration whose
-cells this metric lists) at the peaks of ``peaks.json``. Never clipped."""
+"""Kernels: the forward flash-attention kernels' share of their roofline, over
+all the layers held, windowed and full together. The seconds of
+``rdt_flash_fwd`` (full causal layers) and ``rdt_flash_win_fwd`` (windowed
+ones; ``raydp_tpu/ops/flash_attention``'s ``KERNEL_NAMES`` and
+``WINDOW_KERNEL_NAMES``) in the traced epochs against the least a chip could
+take for the same executions: each kernel instruction of the step's program
+runs once over every traced sequence, so the executions are counted from the
+trace itself, by kind (``trace/executions.py``; a recomputed block that ran
+its forward kernel a second time would count it with its seconds), and one
+execution's work is the cell's family's (``flops/<family>.flash_forward``:
+QK^T and PV over the pairs the layer's mask leaves visible, K and V read
+once a group of query heads) at the peaks of ``peaks.json``. A program
+without these kernels, or a family that counts none, says nothing. Never
+clipped."""
 
-from chipbench.trace import kernels, roofline
+from chipbench.trace import executions, roofline
 
-CONFIG = "olmoe-1b-7b"
-KERNEL = r"^rdt_flash_fwd"
+KINDS = {"window": r"^rdt_flash_win_fwd", "full": r"^rdt_flash_fwd"}
+KERNEL = r"^rdt_flash(_win)?_fwd"
 
 
 def read(run):
-    seconds = kernels.seconds_of(run, KERNEL)
-    sizes = kernels.sizes_of(CONFIG, run) if seconds else None
-    if sizes is None:
-        return None
-    cfg, work = sizes
-    seq_len = cfg["max_position_embeddings"]
-    flops, moved = work.flash_forward(
-        cfg, run["traced_items"] / seq_len / run["chips"], seq_len)
-    return roofline.share(seconds, flops, moved, run["peak"])
+    found = executions.work_of(run, KINDS, "flash_forward", KERNEL)
+    return None if found is None else roofline.share(*found, run["peak"])

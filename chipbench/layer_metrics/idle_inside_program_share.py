@@ -4,9 +4,12 @@ of the chip's ``XLA Modules`` line): the chip waited on itself (a copy, a
 loop's trip count, a collective's partner) while the host had done its part.
 
 One rule cuts every gap (``chipbench/trace/idle_causes.py``): inside a
-program, launch, host late, unmatched; the four sum to 100. Unlike the three
-``idle_*_share`` of ``host_spans.py`` it does not ask where the loop's thread
-stood: a loop parked in the loss fetch stands there for the whole epoch."""
+program, launch, host late, unmatched; the four sum to 100. Unlike the idle
+seconds by loop span of ``host_spans.py`` (the result line's
+``breakdown.idle_gaps``) it does not ask where the loop's thread stood: a
+loop parked in the loss fetch stands there for the whole epoch. A loop's or a
+conditional's own control between its body's ops counts here; a kernel that
+holds a zero-length event is busy (``reduce.leaf_ops``)."""
 
 from chipbench.trace import idle_causes
 

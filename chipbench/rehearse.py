@@ -121,7 +121,7 @@ def rehearse_compile(names) -> int:
             _make_apply(model, takes_train, est._split_batch,
                         est.compute_dtype),
             _resolve_loss(est._loss), [], 1, "none",
-            mb_shardings=(b_sh, None))
+            mb_shardings=(b_sh, None), state_shardings=shardings)
         t0 = time.perf_counter()
         compiled = jax.jit(step, donate_argnums=(0, 3)).lower(
             state, jbatch, (), loss_sum).compile()

@@ -6,8 +6,10 @@ One process; it owns the chip(s); the ETL executors are CPU children that
 ``raydp_tpu.init`` starts. It needs the TPU chips the cell asks for: anywhere
 else it exits non-zero, naming what it found, and no flag changes that. The
 last line of stdout is the result object (``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``); the run's
-detail goes on the line before it and into ``chipbench/out/<cell>/``.
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared``: each number the checks compared beside its limit, which are
+also the last lines of stderr); the run's detail (its ``counters`` among it)
+goes on the line before it and into ``chipbench/out/<cell>/``.
 """
 
 from __future__ import annotations
@@ -70,7 +72,12 @@ def main(argv=None) -> int:
     with open(out, "w") as fh:
         json.dump(detail, fh, indent=1)
     print("detail " + json.dumps(detail), flush=True)
+    # each number the checks compared, beside its limit: the line's last key
+    result["compared"] = detail["found"]["compared"]
     print(json.dumps(result), flush=True)
+    for name, pair in result["compared"].items():
+        print(f"compared {name}: {pair['value']} limit {pair['limit']}",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -153,7 +153,7 @@ def main(argv=None) -> int:
             s[2] for s in spans)) for thread, spans in marked.items()}
         report["modules_vs_dispatch"] = modules_vs_dispatch(data, marked)
         report["ring_offset_us"] = ring_offsets(ring, marked)
-        report["idle_shares"] = host_spans.idle_shares(path)
+        report["idle_seconds"] = host_spans.idle_seconds(path)
     with open(os.path.join(ROOT, "chipbench", "out", args.workload,
                            "detail-trace1.json")) as fh:
         detail = json.load(fh)

@@ -142,20 +142,3 @@ def idle_seconds(xplane_path: Optional[str]) -> Optional[Dict[str, float]]:
                 seconds = None
         _seconds[xplane_path] = seconds
     return _seconds[xplane_path]
-
-
-def idle_shares(xplane_path: Optional[str]) -> Optional[Dict[str, float]]:
-    """The same as percent of all the idle seconds (they sum to 100)."""
-    seconds = idle_seconds(xplane_path)
-    if seconds is None:
-        return None
-    total = sum(seconds.values())
-    return {name: 100.0 * sec / total for name, sec in seconds.items()}
-
-
-def idle_share(xplane_path: Optional[str], span_name: str) -> Optional[float]:
-    """What an ``idle_*_share`` reader returns (it hands over
-    ``run["xplane"]``): the share under one span, 0 where the trace has the
-    loop's spans and no idle time fell under this one."""
-    shares = idle_shares(xplane_path)
-    return None if shares is None else shares.get(span_name, 0.0)

@@ -31,10 +31,8 @@ feed's threads did meanwhile (``feed:decode``, ``feed:h2d``,
 ``feed:put_wait``), and for the gaps inside a program the ten operations
 after which the chip waited longest, with their ``op_name`` scope
 (``trace/scopes.py``), and the seconds that lie UNDER an operation the leaf
-rule does not count because it holds another: a loop's own control, but also
-a kernel or a copy whose event holds a zero-length ``custom-call`` (a
-``ConcatBitcast``, seen on the chip) or a copy's ``-done``
-(``trace/kernels.py``). Those seconds are no stall: the chip was busy.
+rule does not count because it holds another of non-zero length: a loop's or
+a conditional's own control (``reduce.held_ops``).
 """
 
 from __future__ import annotations
@@ -198,7 +196,7 @@ def causes(xplane_path: Optional[str]) -> Optional[dict]:
             every = reducer._events(plane, reducer.OPS_LINE)
             ops = reducer.leaf_ops(every)
             if ops:
-                chips.append((ops, sorted(set(every) - set(ops)),
+                chips.append((ops, reducer.held_ops(every, ops),
                               reducer._events(plane, reducer.MODULES_LINE)))
     idle = host_spans.device_idle(data)     # the same planes, in their order
     if not chips or len(idle) != len(chips):
@@ -234,7 +232,7 @@ def causes(xplane_path: Optional[str]) -> Optional[dict]:
     return {"idle_s": total, "seconds": seconds, "chips": n,
             # of the seconds inside a program: between its operations (a
             # stall), and under an operation that holds another and so is not
-            # counted busy (no stall: the leaf rule's blind spot)
+            # counted busy (a loop's own control between its body's ops)
             "inside_between_ops_s": between,
             "inside_under_held_op_s": seconds[INSIDE] - between,
             "executions_with_a_dispatch": matched // n,

@@ -3,12 +3,12 @@ benchmark reports. Read with nothing but JAX (``jax.profiler.ProfileData``).
 
 A device is a plane named ``/device:TPU:<n>``. Its line ``XLA Ops`` holds one
 event per operation the chip executed (a loop's op spans its body's, so only
-ops that hold no other op count) and ``XLA Modules`` one per program
-execution. Busy time is the union of those op intervals; the traced span of a
-chip is taken between two marks the caller gives (on the trace's own clock)
-or, without them, from the first op's start to the last op's end over all
-chips. Everything here works on intervals and names only, so the same
-reduction serves any program.
+ops that hold no other op of non-zero length count) and ``XLA Modules`` one
+per program execution. Busy time is the union of those op intervals; the
+traced span of a chip is taken between two marks the caller gives (on the
+trace's own clock) or, without them, from the first op's start to the last
+op's end over all chips. Everything here works on intervals and names only,
+so the same reduction serves any program.
 """
 
 from __future__ import annotations
@@ -45,13 +45,19 @@ def _events(plane, line_name: str) -> List[Tuple[float, float, str]]:
 
 
 def leaf_ops(ops):
-    """The ops that hold no other op. ``XLA Ops`` nests: a ``while`` or a
-    ``conditional`` spans the ops of its body, and counting it would call the
-    whole loop busy and hide the gaps between its small ops. Its own time
-    (loop control on the scalar core) then counts as idle inside the program.
+    """The ops that hold no op of non-zero length. ``XLA Ops`` nests: a
+    ``while`` or a ``conditional`` spans the ops of its body, and counting it
+    would call the whole loop busy and hide the gaps between its small ops.
+    Its own time (loop control on the scalar core) then counts as idle inside
+    the program. An event of zero length (on the chip: a ``custom-call`` of
+    target ``ConcatBitcast`` at the start of copies, fusions and kernels) is
+    neither a leaf nor a reason to drop the op that holds it: the chip was
+    running the holder.
     """
     out, stack = [], []         # stack of [op, holds another op]
     for op in sorted(ops, key=lambda o: (o[0], -o[1])):
+        if op[1] <= op[0]:
+            continue
         while stack and stack[-1][0][1] <= op[0]:
             top, holds = stack.pop()
             if not holds:
@@ -62,6 +68,16 @@ def leaf_ops(ops):
     out.extend(op for op, holds in stack if not holds)
     out.sort()
     return out
+
+
+def held_ops(ops, leaves):
+    """What ``leaves = leaf_ops(ops)`` leaves out and the chip still spent
+    time under: the ops of non-zero length that hold another such op (a loop,
+    a conditional, or, were the chip to write one, a kernel round a copy's
+    ``-done``). Look at these by hand in a trace before trusting its busy
+    time: ``python3 -m chipbench.trace.reduce <trace>`` lists them."""
+    leaves = set(leaves)
+    return sorted(op for op in ops if op[1] > op[0] and op not in leaves)
 
 
 def union_seconds(intervals) -> float:
@@ -176,6 +192,30 @@ def describe(xplane_path: str, head: int = 4) -> None:
             print(f"  line {line.name!r}: {len(events)} events, first {names}")
 
 
+def dropped(xplane_path: str) -> List[list]:
+    """``[[name, executions, seconds, seconds of it under no leaf], ...]``
+    of the ops the leaf rule does not count, all chips together, longest
+    first: loops and conditionals should be all of it."""
+    from jax.profiler import ProfileData
+
+    found: Dict[str, list] = {}
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        ops = _events(plane, OPS_LINE)
+        leaves = leaf_ops(ops)
+        starts = [o[0] for o in leaves]
+        for s, e, name in held_ops(ops, leaves):
+            inside = leaves[bisect.bisect_left(starts, s):
+                            bisect.bisect_left(starts, e)]
+            row = found.setdefault(_strip(name), [0, 0.0, 0.0])
+            row[0] += 1
+            row[1] += (e - s) / 1e9
+            row[2] += (e - s) / 1e9 - union_seconds(inside)
+    return [[name, *row] for name, row in sorted(
+        found.items(), key=lambda kv: -kv[1][1])]
+
+
 if __name__ == "__main__":
     import json
     import sys
@@ -184,3 +224,4 @@ if __name__ == "__main__":
     target = target if target.endswith(".pb") else find_xplane(target)
     describe(target)
     print(json.dumps(reduce(target), indent=1))
+    print(json.dumps({"held_ops": dropped(target)}, indent=1))

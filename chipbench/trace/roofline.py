@@ -1,11 +1,12 @@
 """A kernel's share of its roofline, from the trace and the table of peaks.
 
 A reader of one kernel's time is a file under ``layer_metrics/``: it sums the
-kernel's events out of ``run["trace"]["op_seconds"]`` (every op's seconds by
-name) or reads ``run["xplane"]`` itself, takes the operations and bytes the
-algorithm needs for those calls from the configuration's ``flops/<family>.py``
-(functions of the shapes, kept with the benchmark) and ``run["peak"]`` from
-``peaks.json``, and returns ``share(...)`` under the name
+kernel's events out of ``run["trace"]["op_seconds"]`` (every leaf op's seconds
+by name: a kernel holds no op of non-zero length, so these are its own events,
+every execution), takes the operations and bytes the algorithm needs for
+those calls from the cell's ``flops/<family>.py`` (``run["flops"]``: functions
+of ``run["cfg"]`` and ``run["wl"]``, kept with the benchmark) and
+``run["peak"]`` from ``peaks.json``, and returns ``share(...)`` under the name
 ``<kernel>_roofline``, unit ``%``. A share over 100 means the operations or
 bytes are counted too high or the time leaves out part of the work: nothing
 here clips it.

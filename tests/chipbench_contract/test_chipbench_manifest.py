@@ -96,6 +96,30 @@ def test_every_cell_resolves_to_its_files():
         assert callable(cell.flops.train_flops_per_item)
 
 
+def test_one_reader_a_metric_and_one_metric_a_reader():
+    """Every per-layer entry has its reader file and no reader file stands
+    without an entry; every cell reads every list-free metric; and a kernel's
+    work answers to one contract in every family that has the kernel."""
+    m = manifest.load_manifest()
+    names = [x["name"] for x in m["per_layer"]]
+    assert len(names) == len(set(names))
+    files = {f[:-3] for f in os.listdir(os.path.join(
+        REPO, manifest.BENCH_DIR, "layer_metrics")) if f.endswith(".py")}
+    assert files == set(names)
+    list_free = {x["name"] for x in m["per_layer"] if "workloads" not in x}
+    for entry in m["workloads"]:
+        cell = manifest.resolve(m, entry["name"])
+        assert list_free <= set(cell.readers)
+        if "flash_fwd_roofline" in cell.readers:
+            for fn in ("flash_forward", "flash_backward", "num_experts"):
+                assert callable(getattr(cell.flops, fn)), (entry["name"], fn)
+    # no reader names a configuration: it is handed its cell
+    for name in files:
+        with open(os.path.join(REPO, manifest.BENCH_DIR, "layer_metrics",
+                               f"{name}.py")) as fh:
+            assert "CONFIG" not in fh.read(), name
+
+
 def test_a_cell_a_config_and_a_metric_are_added_as_files(tmp_path):
     """Throw-away ones, in a copy: new files and one entry each in
     BENCHMARK.json; no existing file is edited. The second configuration is

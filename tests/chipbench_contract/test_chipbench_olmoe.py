@@ -99,12 +99,17 @@ def test_flops_against_a_hand_count(cell):
     per_token = cell.flops.train_flops_per_item(cell.cfg, cell.wl, {})
     assert per_token == 3 * sum(parts.values())
     assert 1.06e9 < per_token < 1.08e9
-    # a step's kernels: 4 sequences, 16,384 tokens
-    fwd, fwd_bytes = cell.flops.flash_forward(cell.cfg, 4, 4096)
+    # one execution of the one layer's kernels over a step's 4 sequences,
+    # 16,384 tokens (the contract of ``trace/executions.py``); every layer
+    # of this family is full causal attention
+    fwd, fwd_bytes = cell.flops.flash_forward(cell.cfg, cell.wl, "full", 4)
     assert fwd == 4 * 16 * 4 * 128 * 4096 * 4097 / 2
     assert fwd_bytes == 4 * 16384 * 2048 * 2 + 16384 * 16 * 4
-    bwd, _ = cell.flops.flash_backward(cell.cfg, 4, 4096)
+    bwd, _ = cell.flops.flash_backward(cell.cfg, cell.wl, "full", 4)
     assert bwd == 2.5 * fwd
+    with pytest.raises(ValueError, match="full causal"):
+        cell.flops.flash_forward(cell.cfg, cell.wl, "window", 4)
+    assert cell.flops.num_experts(cell.cfg) == 64
     gemm, gemm_bytes = cell.flops.expert_gemms(cell.cfg, 16384)
     assert gemm == 9 * 2 * 131072 * 2048 * 1024
     assert gemm / PEAK["bf16_flops_per_s"] > gemm_bytes / PEAK[
@@ -208,6 +213,8 @@ def _xplane(path, instructions, events=()):
 STEP = "jit(train_step)/jvp(TransformerLM.loss_rows)/TransformerLM/"
 PROGRAM = {
     "rdt_flash_fwd.1": STEP + "block_0/attn/pallas_call",
+    "rdt_flash_bwd_dkdv.1": STEP + "block_0/attn/pallas_call",
+    "rdt_flash_bwd_dq.1": STEP + "block_0/attn/pallas_call",
     "fusion.1": STEP + "block_0/moe/router/dot_general",
     "ragged-dot-none.3": "ragged-dot-none",     # as the chip's compiler names it
     "ragged-dot-metadata": "ragged-dot-metadata",
@@ -221,10 +228,11 @@ STEP_EVENTS = [("rdt_flash_fwd.1", 0, 4000), ("fusion.1", 4000, 1000),
                ("ragged-dot-none.3", 5100, 40000),
                ("ragged-dot-none", 45100, 10000), ("fusion.7", 55100, 9000),
                ("fusion.9", 64100, 90000), ("rdt_flash_bwd_dkdv.1", 154100, 9000),
-               # an asynchronous copy's -done inside the kernel's interval:
-               # reduce.py's leaf rule drops this execution, the reader not
-               ("rdt_flash_bwd_dq.1", 163100, 6000), ("copy-done.4", 165000, 10),
+               # a zero-length custom-call at the kernel's start, as the chip
+               # writes one (target ConcatBitcast): the kernel stays a leaf
+               ("rdt_flash_bwd_dq.1", 163100, 6000), ("custom-call.64", 163100, 0),
                ("fusion.11", 169100, 20000)]
+BUSY = 0.1891               # seconds a step, every op of non-zero length a leaf
 
 
 def _run(cell, tmp_path, steps=2):
@@ -234,8 +242,12 @@ def _run(cell, tmp_path, steps=2):
               for name, start, dur in STEP_EVENTS]
     xplane = _xplane(tmp_path / f"t{steps}.xplane.pb", PROGRAM, events)
     trace = reducer.reduce(xplane)
-    assert "rdt_flash_bwd_dq.1" not in trace["op_seconds"]      # the leaf rule
-    return {"trace": trace, "xplane": xplane, "chips": 1, "peak": PEAK,
+    assert trace["op_seconds"]["rdt_flash_bwd_dq.1"] == pytest.approx(
+        0.006 * steps)
+    assert "custom-call.64" not in trace["op_seconds"]
+    assert trace["busy_s"] == pytest.approx(BUSY * steps)
+    return {"cell": CELL, "cfg": cell.cfg, "wl": cell.wl, "flops": cell.flops,
+            "trace": trace, "xplane": xplane, "chips": 1, "peak": PEAK,
             "traced_items": 16384 * steps,
             "flops_per_item": cell.flops.train_flops_per_item(
                 cell.cfg, cell.wl, {}),
@@ -243,47 +255,53 @@ def _run(cell, tmp_path, steps=2):
                                              "max_expert": 4096.0 * steps}}}
 
 
-# a run of the DLRM cells: a trace with none of the kernels and no program
-# that names a scope, and none of the counters
-OTHER = {"trace": {"op_seconds": {"fusion.114": 0.089, "all-reduce.102": 0.2},
+# a run of a DLRM cell as the harness hands it over: its own configuration
+# and family, a trace with none of the kernels and no program that names a
+# scope, and none of the counters
+DLRM = manifest.resolve(manifest.load_manifest(), "dlrm_criteo_stream")
+OTHER = {"cell": DLRM.name, "cfg": DLRM.cfg, "wl": DLRM.wl,
+         "flops": DLRM.flops,
+         "trace": {"op_seconds": {"fusion.114": 0.089, "all-reduce.102": 0.2},
                    "busy_s": 2.7},
          "xplane": None, "chips": 1, "peak": PEAK, "traced_items": 1 << 20,
          "flops_per_item": 1.4e6,
          "counters": {"train_table_updates_total": {"rowwise": 10}}}
+#: the per-layer metrics that list this cell
+METRICS = ["flash_fwd_roofline", "flash_bwd_roofline", "expert_gemm_roofline",
+           "expert_layer_share", "head_loss_share", "expert_load_imbalance",
+           "attn_share"]
 
 
 @pytest.mark.parametrize("name,want", [
-    # least seconds of the hand count above over the kernel's seconds
+    # least seconds of the hand count above over the kernel's seconds: one
+    # forward instruction, one pair of backward kernels
     ("flash_fwd_roofline",
      100 * (4 * 16 * 4 * 128 * 4096 * 4097 / 2 / 197e12) / 0.004),
     ("flash_bwd_roofline",
      100 * (2.5 * 4 * 16 * 4 * 128 * 4096 * 4097 / 2 / 197e12) / 0.015),
     ("expert_gemm_roofline",
      100 * (9 * 2 * 131072 * 2048 * 1024 / 197e12) / 0.05),
-    # the moe scopes and every ragged-dot op
-    # ... over the busy seconds (a step's ops but the dropped dq execution)
+    # the moe scopes and every ragged-dot op over the busy seconds
     ("expert_layer_share",
-     100 * (0.001 + 0.04 + 0.01 + 0.0001 + 0.009) / (0.1891 - 0.006 + 1e-5)),
-    ("head_loss_share", 100 * 0.09 / (0.1891 - 0.006 + 1e-5)),
+     100 * (0.001 + 0.04 + 0.01 + 0.0001 + 0.009) / BUSY),
+    ("head_loss_share", 100 * 0.09 / BUSY),
     ("expert_load_imbalance", 4096 / (131072 / 64)),
+    # the three flash kernels: this trace names no projection
+    ("attn_share", 100 * (0.004 + 0.009 + 0.006) / BUSY),
 ])
-def test_a_reader_on_a_synthetic_run_and_on_another_configurations(
+def test_a_reader_on_a_synthetic_run_and_on_another_cells(
         cell, tmp_path, name, want):
     reader = cell.readers[name]
     run = _run(cell, tmp_path)
     assert reader.read(run) == pytest.approx(want, rel=1e-6)
     # the same share whatever the number of traced steps
     assert reader.read(_run(cell, tmp_path, steps=5)) == pytest.approx(want)
-    # a reader that counts with this configuration's sizes says nothing of a
-    # run whose operations a token are another configuration's (a second
-    # moe_lm with the same kernels, scopes and counter)
-    if not name.endswith("_share"):
-        foreign = dict(run, flops_per_item=2 * run["flops_per_item"])
-        assert reader.read(foreign) is None
     assert reader.read(OTHER) is None
     assert reader.read(dict(OTHER, trace=None)) is None
     entry = next(m for m in cell.per_layer if m["name"] == name)
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
+    assert sorted(METRICS) == sorted(
+        m["name"] for m in cell.per_layer if "workloads" in m)
     if name.endswith("_roofline"):
         assert entry["unit"] == "%" and want < 100
 

@@ -192,6 +192,70 @@ def test_reduce_on_a_hand_written_trace(tmp_path, reducer):
         pytest.approx(2e-6 / 2)
 
 
+K, Z, C, W, B1, B2 = "kernel.1", "custom-call.64", "copy-done.4", \
+    "while.3", "fusion.1", "fusion.2"
+
+
+@pytest.mark.parametrize("ops,leaves,held", [
+    # a kernel whose event holds a zero-length custom-call at its start (as
+    # the chip writes them: target ConcatBitcast): the kernel is the leaf
+    ([(0, 10, K), (0, 0, Z)], [(0, 10, K)], []),
+    # ... in its middle and at its end, and one that stands alone
+    ([(0, 10, K), (4, 4, Z), (10, 10, Z), (12, 12, Z)], [(0, 10, K)], []),
+    # a real nested op (an asynchronous copy's -done with a length): the
+    # holder goes, what it holds counts
+    ([(0, 10, K), (3, 5, C)], [(3, 5, C)], [(0, 10, K)]),
+    # a loop goes and its body stays, the gap in it too
+    ([(0, 10, W), (0, 4, B1), (6, 10, B2)], [(0, 4, B1), (6, 10, B2)],
+     [(0, 10, W)]),
+    # a loop round a kernel that holds a zero-length event
+    ([(0, 10, W), (1, 9, K), (1, 1, Z), (20, 25, B1)],
+     [(1, 9, K), (20, 25, B1)], [(0, 10, W)]),
+    # a loop in a loop: both go
+    ([(0, 20, W), (2, 12, "while.5"), (2, 6, B1), (8, 12, B2), (14, 20, K)],
+     [(2, 6, B1), (8, 12, B2), (14, 20, K)], [(0, 20, W), (2, 12, "while.5")]),
+], ids=["zero_length_at_the_start", "zero_length_inside_at_the_end_alone",
+        "real_nested_op", "loop", "loop_round_a_kernel", "loop_in_a_loop"])
+def test_a_leaf_holds_no_op_of_non_zero_length(reducer, ops, leaves, held):
+    assert reducer.leaf_ops(ops) == leaves
+    assert reducer.leaf_ops(list(reversed(ops))) == leaves   # any order
+    assert reducer.held_ops(ops, leaves) == held
+    # busy time is the leaves' union; a zero-length event adds nothing
+    assert reducer.union_seconds(reducer.leaf_ops(ops)) == pytest.approx(
+        sum(e - s for s, e, _ in leaves) / 1e9)
+
+
+def test_the_by_hand_listing_names_what_the_leaf_rule_leaves_out(
+        tmp_path, reducer):
+    """``python3 -m chipbench.trace.reduce <trace>``: the ops that hold
+    another, with their executions, their seconds and how much of them lies
+    under no leaf (the loop's own control)."""
+    from jax.profiler import ProfileData
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(HAND_TRACE))
+    # while.3 spans [6,10] and its body [6,8] [7,10] covers it whole
+    assert reducer.dropped(str(path)) == [
+        ["while.3", 1, pytest.approx(4e-6), pytest.approx(0.0)]]
+    # with a zero-length custom-call at the start of every fusion.1 the
+    # reduction is the same to the last digit
+    marked = HAND_TRACE.replace(
+        "events { metadata_id: 1 offset_ps: 0 duration_ps: 2000000 }",
+        "events { metadata_id: 1 offset_ps: 0 duration_ps: 2000000 }\n"
+        "    events { metadata_id: 4 offset_ps: 0 duration_ps: 0 }").replace(
+        "events { metadata_id: 1 offset_ps: 6000000 duration_ps: 2000000 }",
+        "events { metadata_id: 1 offset_ps: 6000000 duration_ps: 2000000 }\n"
+        "    events { metadata_id: 4 offset_ps: 6000000 duration_ps: 0 }"
+    ).replace(
+        'event_metadata { key: 3 value',
+        'event_metadata { key: 4 value { id: 4 name: "custom-call.64" } }\n'
+        '  event_metadata { key: 3 value')
+    assert marked.count("duration_ps: 0 }") == 2
+    other = tmp_path / "marked.xplane.pb"
+    other.write_bytes(ProfileData.text_proto_to_serialized_xspace(marked))
+    assert reducer.reduce(str(other)) == reducer.reduce(str(path))
+    assert reducer.dropped(str(other)) == reducer.dropped(str(path))
+
+
 PEAK = {"bf16_flops_per_s": 100e12, "hbm_bytes_per_s": 1e12}
 
 
@@ -203,7 +267,7 @@ def test_roofline_share_of_an_op_of_the_hand_written_trace(
         tmp_path, reducer, flops, bytes_moved, bound, least_us):
     """``fusion.1`` takes 7 us a chip in the hand-written trace (4 us on chip
     0, 10 on chip 1). The least the made-up chip could take for the made-up
-    work, over that: no metric uses it yet (no cell runs a named kernel)."""
+    work, over that: what every ``<kernel>_roofline`` reader does."""
     from jax.profiler import ProfileData
 
     from chipbench.trace import roofline

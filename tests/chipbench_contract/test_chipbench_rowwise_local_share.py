@@ -38,13 +38,13 @@ def test_reader_that_finds_nothing_says_nothing(counters):
     assert _reader().read({"counters": counters}) is None
 
 
-def test_the_entry_is_the_last_and_lists_the_cells_with_tables():
+def test_the_entry_is_present_once_and_lists_the_cells_with_tables():
+    """Where in ``per_layer`` it stands is a later PR's to change."""
     assert manifest.validate(M) == []
-    entry = M["per_layer"][-1]
+    (entry,) = [m for m in M["per_layer"] if m["name"] == NAME]
     assert entry == {"name": NAME, "unit": "%", "better": "higher",
                      "source": "program_counter", "layer": "model",
                      "moves": "train_throughput", "workloads": CELLS}
-    assert [m["name"] for m in M["per_layer"]].count(NAME) == 1
     assert os.path.isfile(os.path.join(
         manifest.ROOT, manifest.BENCH_DIR, "layer_metrics", f"{NAME}.py"))
 

@@ -92,6 +92,16 @@ def _check_contract(result, cell, trace):
         assert math.isfinite(got["value"])
     if not trace:
         assert all(v["value"] > 0 for v in line["metrics"].values())
+    # each number the checks compared stands beside its limit (``run.py``
+    # prints them as the line's last key and on stderr), and the run's
+    # counters are in its detail
+    assert set(detail["found"]["compared"]) == {
+        "reference_error", "last_loss", "first_window_loss",
+        "lowerings_in_window"}
+    assert all(set(pair) == {"value", "limit"}
+               for pair in detail["found"]["compared"].values())
+    assert detail["counters"] == json.loads(json.dumps(detail["counters"]))
+    assert detail["counters"]
     return line, detail
 
 

@@ -3,8 +3,8 @@ contract: its configuration's widths and the cut written into its file, the
 parameter table to the parameter, the pairs a window leaves visible, its
 operation counts against a hand count, its train step compiled chip-free at
 the published widths, its rehearsal through ``harness.cut_for_cpu``, and each
-of its per-layer readers on a synthetic run (and on a run of another
-configuration, where they say nothing).
+of the per-layer readers that list it on a synthetic run handed the cell (and
+on a DLRM run, where they say nothing).
 """
 
 import copy
@@ -30,6 +30,10 @@ WIDTHS = {"hidden_size": 2560, "head_dim": 128, "num_attention_heads": 28,
           "moe_num_active_primary_experts": 6, "sliding_window_size": 4096,
           "rope_theta": 1500000, "rms_norm_eps": 1e-06}
 T = 16384
+#: the per-layer metrics that list this cell
+METRICS = ["flash_fwd_roofline", "flash_bwd_roofline", "window_attn_share",
+           "attn_share", "expert_layer_share", "held_slot_share",
+           "expert_load_imbalance", "head_loss_share"]
 
 
 @pytest.fixture()
@@ -77,24 +81,34 @@ def test_the_configuration_carries_the_source_whole_and_every_width(cell):
             assert cfg[key] == value, key
 
 
-def test_the_manifest_gained_the_cell_and_its_six_metrics(cell):
+def test_the_manifest_holds_the_cell_and_the_metrics_it_lists(cell):
+    """Present, once, each with its reader, in the cells it lists: no place
+    in ``per_layer`` and no length is asked of the manifest."""
     m = manifest.load_manifest()
     assert manifest.validate(m) == []
     entry = next(w for w in m["workloads"] if w["name"] == CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         CONFIG, "packed_16k_stream", 1)
-    mine = [e for e in m["per_layer"] if e.get("workloads") == [CELL]]
-    assert [e["name"] for e in mine] == [
-        "gqa_flash_fwd_roofline", "gqa_flash_bwd_roofline",
-        "window_attn_share", "attn_share", "held_expert_layer_share",
-        "held_slot_share"]
-    assert all(e["moves"] == "train_throughput" and e["unit"] == "%"
-               for e in mine)
+    mine = [e for e in m["per_layer"] if CELL in e.get("workloads", [])]
+    assert sorted(e["name"] for e in mine) == sorted(METRICS)
+    assert all(e["moves"] == "train_throughput" for e in mine)
+    assert all(e["unit"] == "%" for e in mine
+               if e["name"] != "expert_load_imbalance")
+    # one reader a measurement: the windowed kernels are this cell's alone,
+    # the held share is read by the two cells that hold one, the rest by
+    # every LM cell
+    lists = {e["name"]: e["workloads"] for e in mine}
+    assert lists["window_attn_share"] == [CELL]
+    assert lists["held_slot_share"] == [CELL, "trinity_mini_8k_train"]
+    assert all(lists[n] == ["olmoe_1b7b_train", CELL, "trinity_mini_8k_train"]
+               for n in METRICS if n not in ("window_attn_share",
+                                             "held_slot_share"))
     # every list-free metric is read here too, and no other cell's
     names = {e["name"] for e in cell.per_layer}
     assert {e["name"] for e in m["per_layer"] if "workloads" not in e} < names
-    assert not names & {"flash_fwd_roofline", "expert_layer_share",
-                        "head_loss_share", "rowwise_table_share"}
+    assert not names & {"expert_gemm_roofline", "shared_expert_share",
+                        "rowwise_table_share", "collective_share"}
+    assert set(cell.readers) == names
     assert (cell.wl["rows"], cell.wl["batch_per_replica"], cell.wl["seq_len"],
             cell.wl["residency"], cell.wl["estimator_args"]) == (
                 8, 1, T, "stream", {})
@@ -158,17 +172,23 @@ def test_flops_against_a_hand_count(cell):
     assert per_token == 3 * sum(parts.values())
     # 56.8%: the windowed layers' share of the pairs
     assert round(100 * 3 * windowed / (full + 3 * windowed), 1) == 56.8
-    # a step's kernels: 1 sequence of 16,384 tokens in four layers
-    pairs = 134225920 + 3 * 58722304
-    fwd, fwd_bytes = cell.flops.gqa_flash_forward(cell.cfg, 1, T)
-    assert fwd == 2 * 2 * 3584 * pairs
-    assert fwd_bytes == 4 * T * ((2 * 3584 + 2 * 512) * 2 + 28 * 4)
-    bwd, bwd_bytes = cell.flops.gqa_flash_backward(cell.cfg, 1, T)
-    assert bwd == 2.5 * fwd
-    assert bwd_bytes == 4 * T * ((4 * 3584 + 4 * 512) * 2 + 2 * 28 * 4)
-    for flops, moved in ((fwd, fwd_bytes), (bwd, bwd_bytes)):   # compute-bound
-        assert flops / PEAK["bf16_flops_per_s"] > 10 * moved / PEAK[
-            "hbm_bytes_per_s"]
+    # one execution of one layer's kernels over 1 sequence of 16,384
+    # tokens, by kind (the contract of ``trace/executions.py``)
+    work, cfg, wl = cell.flops, cell.cfg, cell.wl
+    assert work.num_experts(cfg) == 64
+    for kind, pairs in (("full", 134225920), ("window", 58722304)):
+        fwd, fwd_bytes = work.flash_forward(cfg, wl, kind, 1)
+        assert fwd == 2 * 2 * 3584 * pairs
+        assert fwd_bytes == T * ((2 * 3584 + 2 * 512) * 2 + 28 * 4)
+        bwd, bwd_bytes = work.flash_backward(cfg, wl, kind, 1)
+        assert bwd == 2.5 * fwd
+        assert bwd_bytes == T * ((4 * 3584 + 4 * 512) * 2 + 2 * 28 * 4)
+        for flops, moved in ((fwd, fwd_bytes), (bwd, bwd_bytes)):
+            assert flops / PEAK["bf16_flops_per_s"] > 10 * moved / PEAK[
+                "hbm_bytes_per_s"]      # compute-bound
+    # twice the sequences, twice the work
+    assert work.flash_forward(cfg, wl, "full", 2)[0] == 2 * 2 * 2 * 3584 \
+        * 134225920
 
 
 def test_a_batch_is_int32_tokens_drawn_from_the_slice(cell):
@@ -249,9 +269,10 @@ def test_the_rehearsal_through_cut_for_cpu_is_correct(cell, tmp_path):
     got = {k: v["value"] for k, v in result["metrics"].items()}
     assert 0 < got["held_slot_share"] < 100
     # no TPU plane off the chip: the device readers say nothing
-    assert not {"gqa_flash_fwd_roofline", "gqa_flash_bwd_roofline",
-                "window_attn_share", "attn_share",
-                "held_expert_layer_share"} & set(got)
+    assert got["expert_load_imbalance"] >= 1.0
+    assert not {"flash_fwd_roofline", "flash_bwd_roofline",
+                "window_attn_share", "attn_share", "expert_layer_share",
+                "head_loss_share"} & set(got)
 
 
 def test_the_tolerance_separates_bfloat16_from_the_precision_below(cell):
@@ -332,7 +353,7 @@ PROGRAM = {
     "rdt_flash_fwd.1": STEP + "block_0/attn/attn_full/pallas_call",
     "rdt_flash_win_fwd.2": STEP + "block_1/attn/attn_window/pallas_call",
     "rdt_flash_bwd_dkdv.1": STEP + "block_0/attn/attn_full/pallas_call",
-    "rdt_flash_win_bwd_dq.3": STEP + "block_1/attn/attn_window/pallas_call",
+    "rdt_flash_win_bwd_dkdv.3": STEP + "block_1/attn/attn_window/pallas_call",
     "fusion.2": STEP + "block_1/attn/q/dot_general",
     "fusion.1": STEP + "block_0/moe/router/dot_general",
     "ragged-dot-none.3": "ragged-dot-none",     # as the chip's compiler names it
@@ -347,7 +368,7 @@ STEP_EVENTS = [("rdt_flash_fwd.1", 0, 10000),
                ("ragged-dot-none.3", 31000, 20000), ("fusion.7", 51000, 9000),
                ("fusion.9", 60000, 50000),
                ("rdt_flash_bwd_dkdv.1", 110000, 30000),
-               ("rdt_flash_win_bwd_dq.3", 140000, 45000),
+               ("rdt_flash_win_bwd_dkdv.3", 140000, 45000),
                ("fusion.11", 185000, 15000)]
 BUSY = 0.2                  # seconds a step, every op a leaf
 
@@ -358,7 +379,8 @@ def _run(cell, tmp_path, steps=2):
     events = [(name, 250000 * i + start, dur) for i in range(steps)
               for name, start, dur in STEP_EVENTS]
     xplane = _xplane(tmp_path / f"t{steps}.xplane.pb", PROGRAM, events)
-    return {"trace": reducer.reduce(xplane), "xplane": xplane, "chips": 1,
+    return {"cell": CELL, "cfg": cell.cfg, "wl": cell.wl, "flops": cell.flops,
+            "trace": reducer.reduce(xplane), "xplane": xplane, "chips": 1,
             "peak": PEAK, "traced_items": T * steps,
             "flops_per_item": cell.flops.train_flops_per_item(
                 cell.cfg, cell.wl, {}),
@@ -367,51 +389,54 @@ def _run(cell, tmp_path, steps=2):
                 "held": 90000.0 * steps}}}
 
 
-# a run of the OLMoE cell: its kernels, scopes and counter, no windowed
-# kernel and no held share; and one of the DLRM cells: none of either
+# what a run of the OLMoE cell counts (no held share), and a run of a DLRM
+# cell as the harness hands it over: its own configuration and family
 OLMOE = {"counters": {"moe_slots_total": {"all": 131072.0,
-                                          "max_expert": 4096.0}},
-         "chips": 1, "peak": PEAK, "traced_items": T, "flops_per_item": 1.07e9}
-OTHER = {"trace": {"op_seconds": {"fusion.114": 0.089}, "busy_s": 2.7},
+                                          "max_expert": 4096.0}}}
+DLRM = manifest.resolve(manifest.load_manifest(), "dlrm_criteo_stream")
+OTHER = {"cell": DLRM.name, "cfg": DLRM.cfg, "wl": DLRM.wl,
+         "flops": DLRM.flops,
+         "trace": {"op_seconds": {"fusion.114": 0.089}, "busy_s": 2.7},
          "xplane": None, "chips": 1, "peak": PEAK, "traced_items": 1 << 20,
          "flops_per_item": 1.4e6,
          "counters": {"train_table_updates_total": {"rowwise": 10}}}
-PAIRS = 134225920 + 3 * 58722304
+FULL, WINDOW = 134225920, 58722304
 
 
 @pytest.mark.parametrize("name,want", [
-    ("gqa_flash_fwd_roofline",
-     100 * (2 * 2 * 3584 * PAIRS / 197e12) / 0.025),
-    ("gqa_flash_bwd_roofline",
-     100 * (5 * 2 * 3584 * PAIRS / 197e12) / 0.075),
+    # one execution of a full layer's kernels and one of a windowed layer's,
+    # over one sequence each: what the trace holds, not the layers held
+    ("flash_fwd_roofline",
+     100 * (2 * 2 * 3584 * (FULL + WINDOW) / 197e12) / 0.025),
+    ("flash_bwd_roofline",
+     100 * (5 * 2 * 3584 * (FULL + WINDOW) / 197e12) / 0.075),
     ("window_attn_share", 100 * (0.015 + 0.045) / 0.1),
     ("attn_share", 100 * (0.1 + 0.005) / BUSY),
-    ("held_expert_layer_share", 100 * (0.001 + 0.02 + 0.009) / BUSY),
+    ("expert_layer_share", 100 * (0.001 + 0.02 + 0.009) / BUSY),
     ("held_slot_share", 100 * 90000 / 393216),
+    # over all 64 experts the router chooses among, not the 16 held
+    ("expert_load_imbalance", 40000 / (393216 / 64)),
+    ("head_loss_share", 100 * 0.05 / BUSY),
 ])
-def test_a_reader_on_a_synthetic_run_and_on_another_configurations(
+def test_a_reader_on_a_synthetic_run_and_on_another_cells(
         cell, tmp_path, name, want):
     reader = cell.readers[name]
     run = _run(cell, tmp_path)
     assert reader.read(run) == pytest.approx(want, rel=1e-6)
     # the same share whatever the number of traced steps
     assert reader.read(_run(cell, tmp_path, steps=5)) == pytest.approx(want)
-    # a roofline counted with this configuration's sizes says nothing of a
-    # run whose operations a token are another configuration's
     if name.endswith("_roofline"):
-        foreign = dict(run, flops_per_item=2 * run["flops_per_item"])
-        assert reader.read(foreign) is None
         assert want < 100
-    # the parent of this PR under these files: the full-attention kernels,
-    # the moe scopes and two of the three counts, no windowed kernel, no
-    # share; and a DLRM run
-    parent = dict(run, counters=OLMOE["counters"])
-    if name in ("held_slot_share", "held_expert_layer_share"):
-        assert reader.read(parent) is None
+    # a program that holds every expert counts no held share
+    if name == "held_slot_share":
+        assert reader.read(dict(run, counters=OLMOE["counters"])) is None
+    # a DLRM run has none of the kernels, scopes or counters
     assert reader.read(OTHER) is None
     assert reader.read(dict(OTHER, trace=None)) is None
     entry = next(m for m in cell.per_layer if m["name"] == name)
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
+    assert sorted(METRICS) == sorted(
+        m["name"] for m in cell.per_layer if "workloads" in m)
 
 
 def test_a_trace_without_the_windowed_kernel_has_no_window_share(
@@ -419,12 +444,18 @@ def test_a_trace_without_the_windowed_kernel_has_no_window_share(
     from chipbench.trace import reduce as reducer
     events = [e for e in STEP_EVENTS if "_win_" not in e[0]]
     xplane = _xplane(tmp_path / "full.xplane.pb", PROGRAM, events)
-    run = {"trace": reducer.reduce(xplane), "xplane": xplane, "chips": 1,
+    run = {"cell": CELL, "cfg": cell.cfg, "wl": cell.wl, "flops": cell.flops,
+           "trace": reducer.reduce(xplane), "xplane": xplane, "chips": 1,
            "peak": PEAK, "traced_items": T, "counters": {},
            "flops_per_item": 1.07e9}
     assert cell.readers["window_attn_share"].read(run) is None
-    assert cell.readers["gqa_flash_fwd_roofline"].read(run) is None
+    # the full layers' kernels are read alone, one execution of each
+    assert cell.readers["flash_fwd_roofline"].read(run) == pytest.approx(
+        100 * (2 * 2 * 3584 * FULL / 197e12) / 0.01)
+    assert cell.readers["flash_bwd_roofline"].read(run) == pytest.approx(
+        100 * (5 * 2 * 3584 * FULL / 197e12) / 0.03)
     assert cell.readers["held_slot_share"].read(run) is None
+    assert cell.readers["expert_load_imbalance"].read(run) is None
     assert cell.readers["attn_share"].read(run) == pytest.approx(
         100 * 0.045 / 0.14)
 

@@ -3,8 +3,8 @@ configuration's widths and the cut written into its file, the parameter table
 to the parameter (and the bias outside it), the pairs a window leaves
 visible, its operation counts against a hand count, its train step compiled
 chip-free at the published widths, its rehearsal through
-``harness.cut_for_cpu``, and each of its per-layer readers on a synthetic run
-(and on a run of another configuration, where they say nothing).
+``harness.cut_for_cpu``, and each of the per-layer readers that list it on a
+synthetic run handed the cell (and on a DLRM run, where they say nothing).
 """
 
 import copy
@@ -33,10 +33,11 @@ WIDTHS = {"hidden_size": 2048, "head_dim": 128, "num_attention_heads": 32,
           "load_balance_coeff": 0.001}
 T = 8192
 PARAMETERS = 705473792
-METRICS = ["afmoe_expert_layer_share", "shared_expert_share",
-           "afmoe_attn_share", "afmoe_flash_fwd_roofline",
-           "afmoe_flash_bwd_roofline", "afmoe_load_imbalance",
-           "afmoe_held_slot_share"]
+#: the per-layer metrics that list this cell: the device's first, then the
+#: counters'
+METRICS = ["expert_layer_share", "shared_expert_share", "attn_share",
+           "flash_fwd_roofline", "flash_bwd_roofline", "head_loss_share",
+           "expert_load_imbalance", "held_slot_share"]
 
 
 @pytest.fixture()
@@ -95,28 +96,39 @@ def test_the_configuration_carries_the_source_whole_and_every_width(cell):
             assert cfg[key] == value, key
 
 
-def test_the_manifest_gained_the_cell_and_its_seven_metrics(cell):
+def test_the_manifest_holds_the_cell_and_the_metrics_it_lists(cell):
+    """Present, once, each with its reader, in the cells it lists: no place
+    in ``per_layer``, ``workloads`` or ``configs`` and no length is asked of
+    the manifest."""
     m = manifest.load_manifest()
     assert manifest.validate(m) == []
     entry = next(w for w in m["workloads"] if w["name"] == CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         CONFIG, "packed_8k_stream", 1)
-    assert m["workloads"][-1] == entry and m["configs"][-1]["name"] == CONFIG
-    mine = [e for e in m["per_layer"] if e.get("workloads") == [CELL]]
-    assert [e["name"] for e in mine] == METRICS == [
-        e["name"] for e in m["per_layer"][-7:]]
+    assert [c["name"] for c in m["configs"]].count(CONFIG) == 1
+    mine = [e for e in m["per_layer"] if CELL in e.get("workloads", [])]
+    assert sorted(e["name"] for e in mine) == sorted(METRICS)
     assert all(e["moves"] == "train_throughput" for e in mine)
     assert {e["name"]: e["source"] for e in mine} == {
-        **{n: "device_trace" for n in METRICS[:5]},
-        **{n: "program_counter" for n in METRICS[5:]}}
+        **{n: "device_trace" for n in METRICS[:6]},
+        **{n: "program_counter" for n in METRICS[6:]}}
     assert all(e["unit"] == "%" for e in mine
-               if e["name"] != "afmoe_load_imbalance")
+               if e["name"] != "expert_load_imbalance")
+    # one reader a measurement: the shared expert is this cell's alone, the
+    # held share is read by the two cells that hold one, the rest by every
+    # LM cell
+    lists = {e["name"]: e["workloads"] for e in mine}
+    assert lists["shared_expert_share"] == [CELL]
+    assert lists["held_slot_share"] == ["smallthinker_21ba3b_16k_train", CELL]
+    assert all(lists[n] == ["olmoe_1b7b_train",
+                            "smallthinker_21ba3b_16k_train", CELL]
+               for n in METRICS if n not in ("shared_expert_share",
+                                             "held_slot_share"))
     # every list-free metric is read here too, and no other cell's
     names = {e["name"] for e in cell.per_layer}
     assert {e["name"] for e in m["per_layer"] if "workloads" not in e} < names
-    assert not names & {"flash_fwd_roofline", "expert_layer_share",
-                        "gqa_flash_fwd_roofline", "held_slot_share",
-                        "rowwise_table_share"}
+    assert not names & {"expert_gemm_roofline", "window_attn_share",
+                        "rowwise_table_share", "collective_share"}
     assert set(cell.readers) == names
     wl = cell.wl
     assert (wl["rows"], wl["seq_len"], wl["residency"],
@@ -195,14 +207,16 @@ def test_the_pairs_a_window_leaves_visible_and_the_flops(cell):
     assert work.train_flops_per_item(cfg, cell.wl, {}) == 3 * sum(
         parts.values())
     # the sequence length is the configuration's, whatever a caller's
-    # workload says (``trace/kernels.sizes_of`` hands the published 131,072)
+    # workload says
     assert work.train_flops_per_item(cfg, {"seq_len": 131072}, {}) == 3 * sum(
         parts.values())
-    # one execution of one layer's kernels over 2 sequences
-    fwd, fwd_bytes = work.flash_forward(cfg, "full", 2)
+    assert work.num_experts(cfg) == 128
+    # one execution of one layer's kernels over 2 sequences (the contract of
+    # ``trace/executions.py``)
+    fwd, fwd_bytes = work.flash_forward(cfg, cell.wl, "full", 2)
     assert fwd == 2 * 2 * 2 * 4096 * 33558528
     assert fwd_bytes == 2 * T * ((2 * 4096 + 2 * 512) * 2 + 32 * 4)
-    bwd, bwd_bytes = work.flash_backward(cfg, "window", 2)
+    bwd, bwd_bytes = work.flash_backward(cfg, cell.wl, "window", 2)
     assert bwd == 2 * 5 * 2 * 4096 * 14681088
     assert bwd_bytes == 2 * T * ((4 * 4096 + 4 * 512) * 2 + 2 * 32 * 4)
     for flops, moved in ((fwd, fwd_bytes), (bwd, bwd_bytes)):   # compute-bound
@@ -294,9 +308,11 @@ def test_the_rehearsal_through_cut_for_cpu_is_correct(cell, tmp_path):
     assert found["reference_error"] <= cell.reference.TOLERANCE
     assert found["streamed"] and found["lowerings_in_window"] == 0
     got = {k: v["value"] for k, v in result["metrics"].items()}
-    assert 0 < got["afmoe_held_slot_share"] < 100
-    # no TPU plane off the chip: the device readers say nothing; and the
-    # imbalance counts with the published 128 experts, not the cut's 16
+    assert 0 < got["held_slot_share"] < 100
+    # the imbalance counts with the experts of the configuration as it is
+    # run: the cut's 16 here, the published 128 on the chip
+    assert got["expert_load_imbalance"] >= 1.0
+    # no TPU plane off the chip: the device readers say nothing
     assert not set(METRICS[:6]) & set(got)
 
 
@@ -414,7 +430,8 @@ def _run(cell, tmp_path, steps=2):
     events = [(name, 250000 * i + start, dur) for i in range(steps)
               for name, start, dur in STEP_EVENTS]
     xplane = _xplane(tmp_path / f"t{steps}.xplane.pb", PROGRAM, events)
-    return {"trace": reducer.reduce(xplane), "xplane": xplane, "chips": 1,
+    return {"cell": CELL, "cfg": cell.cfg, "wl": cell.wl, "flops": cell.flops,
+            "trace": reducer.reduce(xplane), "xplane": xplane, "chips": 1,
             "peak": PEAK, "traced_items": 2 * T * steps,
             "flops_per_item": cell.flops.train_flops_per_item(
                 cell.cfg, cell.wl, {}),
@@ -423,13 +440,12 @@ def _run(cell, tmp_path, steps=2):
                 "held": 70000.0 * steps, "moved": 73728.0 * steps}}}
 
 
-# a run of the SmallThinker cell (its kernels, scopes and counters: a share
-# held, another configuration's operations a token), and one of a DLRM cell
-SMALLTHINKER = {"counters": {"moe_slots_total": {
-    "all": 393216.0, "max_expert": 40000.0, "held": 90000.0}},
-    "chips": 1, "peak": PEAK, "traced_items": 16384,
-    "flops_per_item": 2.118e9}
-OTHER = {"trace": {"op_seconds": {"fusion.114": 0.089}, "busy_s": 2.7},
+# a run of a DLRM cell as the harness hands it over: its own configuration
+# and family, none of the kernels, scopes or counters
+DLRM = manifest.resolve(manifest.load_manifest(), "dlrm_criteo_stream")
+OTHER = {"cell": DLRM.name, "cfg": DLRM.cfg, "wl": DLRM.wl,
+         "flops": DLRM.flops,
+         "trace": {"op_seconds": {"fusion.114": 0.089}, "busy_s": 2.7},
          "xplane": None, "chips": 1, "peak": PEAK, "traced_items": 1 << 20,
          "flops_per_item": 1.4e6,
          "counters": {"train_table_updates_total": {"rowwise": 10}}}
@@ -437,20 +453,23 @@ FULL, WINDOW = 33558528, 14681088
 
 
 @pytest.mark.parametrize("name,want", [
-    ("afmoe_expert_layer_share", 100 * (0.001 + 0.006 + 0.014 + 0.009) / BUSY),
+    ("expert_layer_share", 100 * (0.001 + 0.006 + 0.014 + 0.009) / BUSY),
     ("shared_expert_share", 100 * 0.006 / BUSY),
-    ("afmoe_attn_share", 100 * (0.1 + 0.005) / BUSY),
-    # two executions of the full layer's forward kernel (one recomputed) and
-    # one of a windowed layer's, over two sequences each
-    ("afmoe_flash_fwd_roofline",
+    ("attn_share", 100 * (0.1 + 0.005) / BUSY),
+    # two executions of the full layer's forward kernel (as a block that
+    # recomputed its kernel would run it) and one of a windowed layer's,
+    # over two sequences each
+    ("flash_fwd_roofline",
      100 * (2 * 2 * 2 * 4096 * (2 * FULL + WINDOW) / 197e12) / 0.025),
     # one full and one windowed layer's pair of kernels
-    ("afmoe_flash_bwd_roofline",
+    ("flash_bwd_roofline",
      100 * (2 * 5 * 2 * 4096 * (FULL + WINDOW) / 197e12) / 0.075),
-    ("afmoe_load_imbalance", 6000 / (524288 / 128)),
-    ("afmoe_held_slot_share", 100 * 70000 / 524288),
+    ("head_loss_share", 100 * 0.04 / BUSY),
+    # over all 128 experts the router chooses among, not the 16 held
+    ("expert_load_imbalance", 6000 / (524288 / 128)),
+    ("held_slot_share", 100 * 70000 / 524288),
 ])
-def test_a_reader_on_a_synthetic_run_and_on_another_configurations(
+def test_a_reader_on_a_synthetic_run_and_on_another_cells(
         cell, tmp_path, name, want):
     reader = cell.readers[name]
     run = _run(cell, tmp_path)
@@ -459,16 +478,12 @@ def test_a_reader_on_a_synthetic_run_and_on_another_configurations(
     assert reader.read(_run(cell, tmp_path, steps=5)) == pytest.approx(want)
     if name.endswith("_roofline"):
         assert want < 100
-    # counted with this configuration's sizes, it says nothing of a run
-    # whose operations a token are another configuration's
-    if name not in ("shared_expert_share", "afmoe_held_slot_share"):
-        foreign = dict(run, flops_per_item=2 * run["flops_per_item"])
-        assert reader.read(foreign) is None
-        assert reader.read(dict(run, **SMALLTHINKER)) is None
     assert reader.read(OTHER) is None
     assert reader.read(dict(OTHER, trace=None)) is None
     entry = next(m for m in cell.per_layer if m["name"] == name)
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
+    assert sorted(METRICS) == sorted(
+        m["name"] for m in cell.per_layer if "workloads" in m)
 
 
 def test_a_program_without_the_scope_or_the_kernels_says_nothing(
@@ -483,12 +498,12 @@ def test_a_program_without_the_scope_or_the_kernels_says_nothing(
     run = dict(_run(cell, tmp_path), trace=reducer.reduce(xplane),
                xplane=xplane)
     assert cell.readers["shared_expert_share"].read(run) is None
-    assert cell.readers["afmoe_flash_fwd_roofline"].read(run) is None
-    assert cell.readers["afmoe_flash_bwd_roofline"].read(run) is None
-    assert cell.readers["afmoe_expert_layer_share"].read(run) is not None
-    assert cell.readers["afmoe_held_slot_share"].read(
+    assert cell.readers["flash_fwd_roofline"].read(run) is None
+    assert cell.readers["flash_bwd_roofline"].read(run) is None
+    assert cell.readers["expert_layer_share"].read(run) is not None
+    assert cell.readers["held_slot_share"].read(
         dict(run, counters={})) is None
-    assert cell.readers["afmoe_load_imbalance"].read(
+    assert cell.readers["expert_load_imbalance"].read(
         dict(run, counters={})) is None
 
 
