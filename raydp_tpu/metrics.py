@@ -370,6 +370,16 @@ _ALL_METRICS = [
        "attention names nothing to keep: `dense`, `ring`). "
        "doc/long_context.md.",
        label="times"),
+    _m("train_sublayer_out_total", COUNTER, "1", "training",
+       "Sub-layer outputs that a second norm reads (`sandwich_norms`: the "
+       "attention's and the feed-forward's, two a block) in a training "
+       "model whose blocks are recomputed (`remat_blocks`), counted once a "
+       "built train step by what the recomputation does with them: `kept` "
+       "(the feed-forward's: no forward walk of the held experts and no "
+       "down projection runs again) or `rebuilt` (the attention's: its "
+       "output projection runs again). Absent where no block is recomputed "
+       "or no norm reads them. doc/long_context.md.",
+       label="outputs"),
     _m("flash_blocks_total", COUNTER, "1", "training",
        "(q block, k block) pairs of the flash-attention kernels, counted "
        "where a kernel's grid is built (once a built forward kernel, twice "

@@ -37,14 +37,14 @@ layer's ``expert_activation``, ``normalize_top_k``, ``first_expert`` and
 ``experts_held`` (one chip's share of an expert-parallel layer), and
 ``router_input="attention"`` (the router reads the attention's normed input,
 not the experts'). ``remat_blocks`` recomputes each block in the backward
-pass from what it saved: the block's input and, where its attention is the
-flash kernel, the kernel's output and row log-sum-exp
-(``flash_attention.RESIDUAL_NAMES``: ``B*T x heads x head_dim`` activations
-and ``B*T x heads`` float32 a layer), so norms, projections, RoPE, gate,
-router and feed-forward run again and the forward kernel does not
-(:attr:`TransformerLM.attention_forward`). A vocabulary-parallel deployment's
-share is a smaller ``vocab_size``: embedding and head over the rows held,
-ids drawn from them.
+pass from what it saved: its input, its flash kernel's output and row sums
+(``flash_attention.RESIDUAL_NAMES``) and, under ``sandwich_norms``, the
+feed-forward sub-layer's output (``SUBLAYER_OUT``, ``B*T x dim``: the second
+norm on it reads it in the backward). So norms, q, k, v, RoPE, gate, ``W_o``
+and router run again; the forward kernel and, where that output is kept, the
+feed-forward's (the held experts') forward walk do not
+(:attr:`TransformerLM.attention_forward`, ``.sublayer_out``). A vocabulary-
+parallel share is a smaller ``vocab_size``, ids drawn from the rows held.
 
 And, since the ``afmoe`` family (Trinity): ``qk_norm="head"`` (RMSNorm over
 each head's ``head_dim``, one weight for the query heads and one for the K/V
@@ -265,14 +265,14 @@ class Block(nn.Module):
                          self.experts_held, self.expert_activation,
                          self.normalize_top_k, self.routing, self.route_scale,
                          self.shared_expert_dim, name="moe")(h, logits)
-            return x + post("ln2_post", y), aux
+            return x + post("ln2_post", _ffn_out(self, y)), aux
         # SwiGLU
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name, kernel_init=init)
         with jax.named_scope("mlp"):
             down = dense(dim, "down")(
                 nn.silu(dense(hidden, "gate")(h)) * dense(hidden, "up")(h))
-        return x + post("ln2_post", down)
+        return x + post("ln2_post", _ffn_out(self, down))
 
 
 class TransformerLM(nn.Module):
@@ -375,11 +375,11 @@ class TransformerLM(nn.Module):
         if self.remat_blocks:
             from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
 
-            # a recomputed block keeps its input and what its flash kernel
-            # made: the recomputation re-forms q, k and v for the backward
-            # kernels and holds no forward kernel
+            # a recomputed block keeps its input, its flash kernel's pair and
+            # its feed-forward's normed output: the backward reads them (end)
+            kept = (*RESIDUAL_NAMES, SUBLAYER_OUT)
             block = nn.remat(Block, policy=jax.checkpoint_policies
-                             .save_only_these_names(*RESIDUAL_NAMES))
+                             .save_only_these_names(*kept))
         for i in range(self.num_layers):
             sparse = self._sparse(i)
             x = block(self.num_heads, self.mlp_ratio, self.attention,
@@ -468,6 +468,18 @@ class TransformerLM(nn.Module):
         ``[B, T, vocab]`` logits exist in its train step, and the weights
         are what lets the head's gradients be taken in its forward scan."""
         return self(tokens, labels=labels, weights=weights)
+
+    @property
+    def sublayer_out(self):
+        """The sub-layer outputs a second norm reads (two a block under
+        ``sandwich_norms``) by what a recomputed block does with them: what
+        ``train_sublayer_out_total`` counts once a built step. ``kept``: the
+        feed-forward's (``SUBLAYER_OUT``); ``rebuilt``: the attention's (its
+        output projection runs again). Nothing where no block is recomputed
+        or no norm reads them."""
+        if not (self.remat_blocks and self.sandwich_norms):
+            return {}
+        return {"kept": self.num_layers, "rebuilt": self.num_layers}
 
 
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -633,3 +645,28 @@ def transformer_param_rules(axis: str = "tensor"):
         ("embed/embedding", (None, axis)),
         ("lm_head/kernel", (None, axis)),
     ]
+
+
+# What a recomputed block keeps beside its flash kernel's pair
+# (``TransformerLM.remat_blocks``): its feed-forward sub-layer's output where a
+# second norm reads it (``Block.sandwich_norms``). The norm's backward reads
+# its input, and only the whole sub-layer can rebuild that: the held experts'
+# walk with its three grouped products and its return to token order and the
+# shared expert's down projection, or a SwiGLU's. Where no norm follows
+# nothing is named: there the backward reads no sub-layer's output. The
+# attention's output, which ``ln1_post`` reads, is NOT named: rebuilding it is
+# one projection (``W_o``), and with it kept too the Trinity cell's step ran
+# slower on the chip (+10.9% over keeping neither against +11.8%) and the TPU
+# compiler reported 2.8 GiB more temporaries (PERF.md, PR 38). Defined at the file's end so that no line of the flash
+# kernels' call stack moves (their payloads embed it: a moved line misses the
+# compile cache).
+SUBLAYER_OUT = "rdt_sublayer_out"
+
+
+def _ffn_out(block, y):
+    """``y``, a block's feed-forward output, named where a norm reads it."""
+    if not block.sandwich_norms:
+        return y
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(y, SUBLAYER_OUT)

@@ -249,6 +249,8 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     # {"once": n} or {"twice": n}: how often a step runs their forward
     apply_fn.attention_forward = getattr(
         model, "attention_forward", None) or {}
+    # {"kept": n, "rebuilt": m}: second norms' inputs in a recomputed block
+    apply_fn.sublayer_out = getattr(model, "sublayer_out", None) or {}
     if callable(getattr(model, "lookups", None)):
         apply_fn.lookups = lambda batch: model.lookups(split_batch(batch)[0])
     return apply_fn
@@ -466,7 +468,8 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         rdt_metrics.inc("train_head_loss_total", label="forward_grad")
     for metric, of_model in (
             ("train_attention_layers_total", "attention_layers"),
-            ("train_attention_forward_total", "attention_forward")):
+            ("train_attention_forward_total", "attention_forward"),
+            ("train_sublayer_out_total", "sublayer_out")):
         for kind, layers in getattr(apply_fn, of_model, {}).items():
             if layers:      # once a built step, by kind of layer
                 rdt_metrics.inc(metric, layers, kind)

@@ -69,3 +69,41 @@ def forward_flash_kernels(monkeypatch):
         fa.flash_attention, interpret=True, block_q=16, block_k=16))
     return lambda program: len(re.findall(
         r"\bname=rdt_flash(?:_win)?_fwd\b", str(program)))
+
+
+@pytest.fixture
+def grouped_products():
+    """Counts the grouped products (``jax.lax.ragged_dot`` and its two
+    transposes: one primitive) in a traced program, through every loop,
+    checkpoint and call it holds. The printed text would not do: it prints
+    a body that several equations share once."""
+    def count(jaxpr):
+        jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+        found = 0
+        for eqn in jaxpr.eqns:
+            found += eqn.primitive.name == "ragged_dot_general"
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                        found += count(inner)
+        return found
+    return count
+
+
+@pytest.fixture
+def policy_without_sublayer_out(monkeypatch):
+    """Calling it leaves ``transformer.SUBLAYER_OUT`` out of every
+    ``save_only_these_names`` policy built from then on: ``remat_blocks`` as
+    it was before a recomputed block kept what its second norm reads."""
+    import jax
+
+    from raydp_tpu.models.transformer import SUBLAYER_OUT
+
+    real = jax.checkpoint_policies.save_only_these_names
+
+    def leave_out():
+        monkeypatch.setattr(
+            jax.checkpoint_policies, "save_only_these_names",
+            lambda *names: real(*(n for n in names if n != SUBLAYER_OUT)))
+    return leave_out

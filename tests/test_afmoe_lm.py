@@ -483,6 +483,65 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
                for n in bias)
 
 
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_a_recomputed_block_keeps_what_its_second_norms_read(
+        attention, forward_flash_kernels, grouped_products,
+        policy_without_sublayer_out):
+    """The two-layer cut (the dense layer, one expert layer). A norm's
+    backward reads its input, so under ``sandwich_norms`` a recomputed block
+    that kept its flash kernel's pair alone had to rebuild both sub-layers'
+    outputs: the held experts' whole forward walk once more. It keeps the
+    feed-forward's (``SUBLAYER_OUT``): the built step holds 11 grouped
+    products an expert layer (3 forward, 2 the backward re-forms, 6 in the
+    backward) as with nothing recomputed, 14 with the name left out of the
+    policy; the step counts two outputs ``kept`` and the attention's two
+    ``rebuilt``; loss and gradients are the unrecomputed model's."""
+    import jax
+    import optax
+    from raydp_tpu import metrics as registry
+
+    def built(remat):
+        cfg, pipeline, _ = _files(layers=2, layers_held=[0, 7],
+                                  remat_blocks=remat, attention=attention)
+        return cfg, pipeline.build_model(cfg)
+
+    cfg, plain = built(False)
+    _, recomputed = built(True)
+    assert (plain.sublayer_out, recomputed.sublayer_out) == (
+        {}, {"kept": 2, "rebuilt": 2})
+    tokens = _tokens(cfg, 4, seed=2)
+    params, state = _variables(plain, tokens, bias_std=0.1)
+    w = np.full(4, 0.25, np.float32)
+
+    def value_and_grad(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
+                                  tokens, w, method=model.loss_rows)[0]))(
+                                      params)
+
+    loss, grads = value_and_grad(recomputed)
+    want_loss, want_grads = value_and_grad(plain)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
+
+    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
+        "train_sublayer_out_total", {}))
+
+    def products(model):
+        before = counted()
+        create, run = _train_step(model, optax.sgd(0.05), 1)
+        moved = {k: v - before.get(k, 0) for k, v in counted().items()
+                 if v != before.get(k, 0)}
+        return grouped_products(jax.make_jaxpr(run.step)(
+            create(params, state), {"tokens": tokens},
+            tuple(m.init() for m in run.metrics), np.float32(0))), moved
+
+    assert products(plain) == (11, {})
+    assert products(recomputed) == (11, {"kept": 2, "rebuilt": 2})
+    policy_without_sublayer_out()
+    assert products(recomputed)[0] == 14
+
+
 def test_the_bias_has_no_gradient_no_decay_and_no_moments():
     """The optimizer's state holds moments for the parameters and nothing
     for the bias; AdamW with decay on the matrices moves every parameter and
@@ -610,6 +669,7 @@ def test_fit_on_frame_at_the_cpu_cut_learns_counts_and_balances(session,
     counters = registry.snapshot()["counters"]
     before = counters.get("moe_slots_total", {})
     forward = dict(counters.get("train_attention_forward_total", {}))
+    outputs = dict(counters.get("train_sublayer_out_total", {}))
     est = FlaxEstimator(
         model=pipeline.build_model(cfg, mesh), loss=None,
         optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
@@ -630,6 +690,10 @@ def test_fit_on_frame_at_the_cpu_cut_learns_counts_and_balances(session,
     after = snapshot["counters"]["train_attention_forward_total"]
     assert after["once"] - forward.get("once", 0) == 2
     assert after.get("twice", 0) == forward.get("twice", 0)
+    # and of the four sub-layer outputs their second norms read, the
+    # feed-forwards'
+    assert snapshot["counters"]["train_sublayer_out_total"] == {
+        kind: outputs.get(kind, 0) + 2 for kind in ("kept", "rebuilt")}
     assert 0 < slots["held"] <= slots["moved"] < slots["all"]
     state = est.get_model()["batch_stats"]
     spreads = []

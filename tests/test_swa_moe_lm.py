@@ -647,6 +647,33 @@ def test_loss_rows_and_every_gradient_leaf_match_the_reference(
     assert float(counts[2]) <= float(counts[3]) < float(counts[1])
 
 
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_a_block_without_second_norms_names_no_sublayer_output(
+        attention, policy_without_sublayer_out):
+    """No norm follows this family's sub-layers, so its backward reads none
+    of their outputs and a recomputed block names none to keep (80 MiB a
+    layer at the cell's size, for nothing): the gradient's lowered program
+    is the same text with and without ``SUBLAYER_OUT`` in the policy, and
+    the model has nothing for ``train_sublayer_out_total`` to count."""
+    import jax
+    cfg, pipeline, _ = _files(remat_blocks=True, attention=attention)
+    model = pipeline.build_model(cfg)
+    assert not model.sandwich_norms and model.sublayer_out == {}
+    tokens = _tokens(cfg, 4, seed=1)
+    params = _params(model, tokens)
+    w = np.full(4, 0.25, np.float32)
+
+    def lowered():
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p}, tokens, tokens, w,
+                                  method=model.loss_rows)[0])).lower(
+                                      params).as_text()
+
+    with_the_name = lowered()
+    policy_without_sublayer_out()
+    assert lowered() == with_the_name
+
+
 def test_the_kept_pair_survives_the_map_over_a_mesh(forward_flash_kernels):
     """On four devices the flash op runs under ``shard_map`` (a chip its own
     rows): the names it gives its output and row sums are honoured inside
@@ -721,8 +748,11 @@ def _estimator(cfg, pipeline, info, mesh, **fit):
 
 
 def _moved(before, after, name):
+    """What a counter gained, by label; a label that gained nothing (another
+    test file's, earlier in this process) is left out."""
     return {k: v - before.get(name, {}).get(k, 0)
-            for k, v in after.get(name, {}).items()}
+            for k, v in after.get(name, {}).items()
+            if v != before.get(name, {}).get(k, 0)}
 
 
 def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
@@ -763,6 +793,8 @@ def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
     assert (cfg["remat_blocks"], cfg["attention"]) == (True, "flash")
     assert _moved(before, after, "train_attention_forward_total") == {
         "once": 4}
+    # and no second norm reads a sub-layer's output
+    assert _moved(before, after, "train_sublayer_out_total") == {}
 
 
 def test_moved_slots_are_counted_where_a_share_is_held_and_only_there(
