@@ -14,7 +14,17 @@ compiler refuses is listed as REFUSED with the compiler's message.
 Needs a TPU (off the chip ``flash_attention`` runs its jnp path and the
 blocks mean nothing).
 
+``--window`` and ``--kv-heads`` give a cell's geometry. ``--edges`` leaves the
+blocks at their default and times instead the kernels' paths by the mask's
+two edges, one line each: as built (interior blocks unmasked, edge blocks
+walked in tiles), the interior unmasked alone, the tiles alone, neither (every
+computed block whole and masked), and the tile walk at the other width. It
+sets the op's module-level rules aside for a measurement (``_tile``, ``_walk``,
+``_TILES_A_SIDE``); the op has no argument for any of them.
+
 Run: python benchmarks/flash_block_sweep.py [--seq-len 8192] [--dim 128]
+     python benchmarks/flash_block_sweep.py --edges --grad --batch 2 \
+         --seq-len 8192 --heads 32 --kv-heads 4 --window 2048   # a Trinity layer
 """
 
 from __future__ import annotations
@@ -36,6 +46,11 @@ def main():
     ap.add_argument("--iters", type=int, default=16)
     ap.add_argument("--grad", action="store_true",
                     help="time fwd+bwd instead of fwd")
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="K/V heads (default: as many as query heads)")
+    ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--edges", action="store_true",
+                    help="time the paths by the mask's edges, not the blocks")
     args = ap.parse_args()
 
     import jax
@@ -43,7 +58,8 @@ def main():
     import numpy as np
     from jax import lax
 
-    from raydp_tpu.ops.flash_attention import flash_attention
+    from raydp_tpu.ops import flash_attention as fa
+    flash_attention = fa.flash_attention
 
     if jax.default_backend() != "tpu":
         raise SystemExit(f"flash_block_sweep needs a TPU, found platform "
@@ -51,9 +67,10 @@ def main():
     B, T, H, D = args.batch, args.seq_len, args.heads, args.dim
     iters = args.iters
     rng = np.random.RandomState(0)
-    mk = lambda: jnp.asarray(  # noqa: E731
-        rng.randn(B, T, H, D).astype(np.float32) * 0.3).astype(jnp.bfloat16)
-    q, k, v = mk(), mk(), mk()
+    Hk = args.kv_heads or H
+    mk = lambda h: jnp.asarray(  # noqa: E731
+        rng.randn(B, T, h, D).astype(np.float32) * 0.3).astype(jnp.bfloat16)
+    q, k, v = mk(H), mk(Hk), mk(Hk)
 
     def rtt_ms() -> float:
         x = jnp.ones((8, 8))
@@ -70,13 +87,19 @@ def main():
         if args.grad:
             def one(x):
                 dq, dk, dv = jax.grad(lambda qq, kk, vv: flash_attention(
-                    qq, kk, vv, causal=True, block_q=bq, block_k=bk)
+                    qq, kk, vv, causal=True, block_q=bq, block_k=bk,
+                    window=args.window)
                     .astype(jnp.float32).sum(), argnums=(0, 1, 2))(x, k, v)
-                return (dq + dk + dv).astype(x.dtype)
+                # a K/V head's gradients enter as one number each: they are
+                # a group's, not a query head's shape
+                kept = dk.astype(jnp.float32).mean() + dv.astype(
+                    jnp.float32).mean()
+                return (dq + kept.astype(dq.dtype)).astype(x.dtype)
         else:
             def one(x):
                 return flash_attention(x, k, v, causal=True,
-                                       block_q=bq, block_k=bk)
+                                       block_q=bq, block_k=bk,
+                                       window=args.window)
 
         @jax.jit
         def chained(x):
@@ -85,9 +108,12 @@ def main():
             return out.astype(jnp.float32).sum()
 
         float(chained(q))                    # compile + warm
-        t0 = time.perf_counter()
-        float(chained(q))
-        wall = (time.perf_counter() - t0) * 1e3
+        walls = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            float(chained(q))
+            walls.append((time.perf_counter() - t0) * 1e3)
+        wall = min(walls)
         per_iter = (wall - rtt) / iters
         if per_iter <= 0:
             raise RuntimeError(
@@ -95,10 +121,13 @@ def main():
                 f"{rtt:.1f} ms) — raise --iters or --seq-len")
         return per_iter
 
+    what = "fwd+bwd" if args.grad else "fwd"
+    if args.edges:
+        return edges(fa, timed, what, args)
+
     results, refused = [], []
     grid = [(128, 128), (128, 256), (256, 256), (256, 512), (512, 512),
             (512, 1024), (1024, 1024)]
-    what = "fwd+bwd" if args.grad else "fwd"
     for bq, bk in grid:
         if bq > T or bk > T:
             continue
@@ -121,6 +150,40 @@ def main():
     print(f"best: blk_q={best[1]} blk_k={best[2]} ({best[0]:.1f} us/{what}, "
           f"~{tflops:.1f} TFLOP/s) at B={B} T={T} H={H} D={D} on "
           f"{jax.devices()[0].device_kind}; refused: {refused or 'none'}")
+
+
+def edges(fa, timed, what, args):
+    """The kernels' paths by the mask's edges at the default blocks, each
+    timed under the op's rules set aside for it."""
+    import jax
+
+    if not hasattr(fa, "_tile"):       # a checkout from before the paths
+        us = timed(fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K) * 1e3
+        print(f"every block whole and masked  {us:9.1f} us/{what}")
+        return
+    tile, walk, a_side = fa._tile, fa._walk, fa._TILES_A_SIDE
+
+    def masked_interior(edge, *rest):
+        return walk("whole" if edge is None else edge, *rest)
+
+    other = 4 if a_side == 2 else 2
+    for name, rules in (
+            ("as built: interior unmasked, edges in tiles", {}),
+            ("interior unmasked, edges whole", {"_tile": lambda *a: None}),
+            ("interior masked, edges in tiles", {"_walk": masked_interior}),
+            ("every block whole and masked",
+             {"_tile": lambda *a: None, "_walk": masked_interior}),
+            (f"as built at {other} tiles a side", {"_TILES_A_SIDE": other})):
+        for rule, value in rules.items():
+            setattr(fa, rule, value)
+        jax.clear_caches()
+        try:
+            us = timed(fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K) * 1e3
+        finally:
+            fa._tile, fa._walk, fa._TILES_A_SIDE = tile, walk, a_side
+        print(f"{name:46s} {us:9.1f} us/{what}  (B={args.batch} "
+              f"T={args.seq_len} H={args.heads}/{args.kv_heads or args.heads}"
+              f" D={args.dim} window={args.window})", flush=True)
 
 
 if __name__ == "__main__":

@@ -387,6 +387,19 @@ _ALL_METRICS = [
        "(wholly above the diagonal) and `skipped_window` (wholly behind the "
        "window: never fetched). ops/flash_attention.py.",
        label="fate"),
+    _m("flash_tiles_total", COUNTER, "1", "training",
+       "Tiles (half a block a side) of the `computed` block pairs "
+       "of `flash_blocks_total`, counted with them, by what a kernel step "
+       "does with them: `unmasked` (every tile of a block no edge of the "
+       "mask crosses, and the tiles of an edge block that lie wholly inside "
+       "the mask: no iota, compare or select), `masked` (the causal "
+       "diagonal or the window's far side crosses the tile: masked element "
+       "by element), `skipped` (a tile of an edge block with no visible "
+       "pair: neither product nor softmax work) and `whole_edge` (the tiles "
+       "of an edge block computed whole and masked: q and k blocks that "
+       "differ, a window that is no multiple of the block, a block under "
+       "two tiles of 128 lanes). ops/flash_attention.py.",
+       label="fate"),
     _m("train_accum_steps", GAUGE, "1", "training",
        "Gradient-accumulation microbatches per optimizer step this fit is "
        "running with (1 = unaccumulated; the RDT_TRAIN_ACCUM_STEPS / "

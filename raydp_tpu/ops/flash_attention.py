@@ -16,14 +16,22 @@ Two options follow a published layer. ``window`` (with ``causal``): a query
 attends to itself and the ``window - 1`` keys before it. The kernels then walk
 only the blocks of the band: the grid's inner dimension holds as many steps as
 a block's band has blocks (five 1024-blocks for a window of 4096, whatever the
-sequence length), its block index is the band's, and the blocks on both edges
-of the band are masked element by element; blocks above the diagonal and
+sequence length), its block index is the band's; blocks above the diagonal and
 blocks wholly behind the window are never fetched or computed. Grouped-query
 heads: K and V may hold fewer heads than Q (``H = G * Hk``); a K/V head is
 read by its ``G`` query heads from where it lies, never repeated in HBM, and
 the dK/dV kernel walks a group's query heads in its inner dimension so that
-their sum is formed in VMEM. ``window=None`` with as many K/V heads as query
-heads builds the program this op built before it had either.
+their sum is formed in VMEM.
+
+The mask has two edges, the causal diagonal and the window's far side, and
+every kernel step sorts its block pair by them (``_by_edges``): a block no edge
+crosses is computed whole with no mask at all; a block an edge crosses is
+walked in tiles (``_tile``: half a block wide), of which those with no
+visible pair run nothing, the ones the edge crosses are masked element by
+element and the rest are not. Where the geometry is not static (the q and k
+blocks differ, the window is no multiple of a block, a block too small for
+two tiles of 128 lanes) an edge block is computed whole and masked.
+``flash_tiles_total`` counts the tiles by what becomes of them.
 
 Dispatch: on a TPU backend the Pallas kernel runs, and a shape it cannot take
 is an error that says why — never a quiet switch to another path. Off the chip
@@ -127,22 +135,39 @@ def _q_step(b, ki, j, *, group: int, blk_q: int, blk_k: int,
 def _count_blocks(kernels: int, heads: int, t: int, blk_q: int, blk_k: int,
                   window: Optional[int], causal: bool) -> None:
     """``flash_blocks_total``: the (q block, k block) pairs of the kernels
-    being built, by what becomes of them."""
+    being built, by what becomes of them; and ``flash_tiles_total``: the
+    computed pairs' tiles (``_TILES_A_SIDE`` a side), by what a step does
+    with them."""
     from raydp_tpu import metrics as rdt_metrics
 
     num_q, num_k = t // blk_q, t // blk_k
     above = behind = 0
+    tiled = _tile(blk_q, blk_k, window) is not None
+    a_block = _TILES_A_SIDE ** 2
+    off_edge = (a_block - _TILES_A_SIDE) // 2   # each side of a crossed block
+    tiles = dict.fromkeys(("unmasked", "masked", "skipped", "whole_edge"), 0)
     for qi in range(num_q):
         last = (qi * blk_q + blk_q - 1) // blk_k if causal else num_k - 1
         first = 0 if window is None else _k_band(qi, blk_q, blk_k,
                                                  window)[0]
         above += num_k - 1 - last
         behind += first
-    total = num_q * num_k
-    for label, n in (("computed", total - above - behind),
-                     ("skipped_causal", above), ("skipped_window", behind)):
-        if n:
-            rdt_metrics.inc("flash_blocks_total", kernels * heads * n, label)
+        for ki in range(first, last + 1):
+            if not causal or _interior(qi, ki, blk_q, blk_k, window):
+                tiles["unmasked"] += a_block
+            elif not tiled:
+                tiles["whole_edge"] += a_block
+            else:       # a triangle of tiles: the crossed ones its diagonal
+                tiles["masked"] += _TILES_A_SIDE
+                tiles["unmasked"] += off_edge
+                tiles["skipped"] += off_edge
+    blocks = {"computed": num_q * num_k - above - behind,
+              "skipped_causal": above, "skipped_window": behind}
+    for name, fates in (("flash_blocks_total", blocks),
+                        ("flash_tiles_total", tiles)):
+        for label, n in fates.items():
+            if n:
+                rdt_metrics.inc(name, kernels * heads * n, label)
 
 
 # ---------------------------------------------------------------------------
@@ -165,63 +190,51 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _body():
-        q = q_ref[0]                            # [blk_q, D], native dtype
-        k = k_ref[0]                            # [blk_k, D]
-        v = v_ref[0]                            # [blk_k, D]
-
+    def _rows(rows, pieces):
+        """One online-softmax update of the q rows ``rows`` by the keys of
+        ``pieces``: (k rows, the pairs to keep or None for all)."""
+        q = q_ref[0, rows]                      # [rows, D], native dtype
         # native-dtype MXU matmul (bf16 x bf16 -> f32); upcasting inputs to
         # f32 first would cost ~4x MXU throughput for no accuracy gain over
         # the f32 accumulator
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [blk_q, blk_k]
-
-        if causal:
-            s = _mask_causal(s, qi, ki, blk_q, blk_k, window)
+        scores = [_masked(lax.mul(lax.dot_general(
+            q, k_ref[0, cols], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), scale), keep)
+            for cols, keep in pieces]                       # [rows, cols]
 
         # a row that sees no key of a block on the band's far edge keeps
         # m = -1e30 and adds p = 1 for each of them; the first block that
         # holds a key it does see (its own diagonal at the latest) scales
         # that away with exp(-1e30 - m) = 0
-        m_prev = m_scr[:, 0]                                # [blk_q]
-        l_prev = l_scr[:, 0]
-        m_blk = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new[:, None])
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_prev * correction + jnp.sum(p, axis=-1)
-        acc_scr[:] = (acc_scr[:] * correction[:, None]
-                      + jax.lax.dot_general(
-                          p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                          preferred_element_type=jnp.float32))
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = l_new
+        m_prev = m_scr[rows, 0]                             # [rows]
+        m_new = m_prev
+        for s in scores:
+            m_new = lax.max(m_new, lax.reduce_max(s, (1,)))
+        correction = lax.exp(lax.sub(m_prev, m_new))
+        l_new = lax.mul(l_scr[rows, 0], correction)
+        acc = lax.mul(acc_scr[rows], _col(correction))
+        for s, (cols, _) in zip(scores, pieces):
+            p = lax.exp(lax.sub(s, _col(m_new)))
+            l_new = lax.add(l_new, lax.reduce_sum(p, (1,)))
+            v = v_ref[0, cols]                              # [cols, D]
+            acc = lax.add(acc, lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        acc_scr[rows] = acc
+        m_scr[rows, 0] = m_new
+        l_scr[rows, 0] = l_new
 
-    _when_visible(_body, in_band, causal, qi, ki, blk_q, blk_k)
+    def _body(edge):
+        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
+            _rows(rows, pieces)
+
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
 
     @pl.when(j == num_k - 1)
     def _finalize():
         l_fin = jnp.maximum(l_scr[:, 0], 1e-30)
         o_ref[0] = (acc_scr[:] / l_fin[:, None]).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[:, 0] + jnp.log(l_fin)
-
-
-def _when_visible(body, in_band, causal: bool, qi, ki, blk_q: int,
-                  blk_k: int):
-    """Run ``body`` where the block pair holds a visible (query, key) pair.
-    With a window the walk itself is the band (``in_band``); without one a k
-    block strictly above the triangle (its first key after this q block's
-    last query) contributes exactly zero: skip both matmuls, halving causal
-    FLOPs."""
-    from jax.experimental import pallas as pl
-
-    if in_band is not None:
-        pl.when(in_band)(body)
-    elif causal:
-        pl.when(qi * blk_q + (blk_q - 1) >= ki * blk_k)(body)
-    else:
-        body()
 
 
 def _maps(group: int, band: dict):
@@ -329,34 +342,46 @@ def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool,
 # one accumulates dq walking k blocks — so each output block is written once
 # and all accumulation stays in VMEM scratch.
 # ---------------------------------------------------------------------------
-def _mask_causal(s, qi, ki, blk_q: int, blk_k: int,
+def _keep_causal(qi, ki, blk_q: int, blk_k: int,
                  window: Optional[int] = None):
-    """Apply the causal mask, and the window's, to a score block (shared by
-    fwd + both bwds)."""
+    """[blk_q, blk_k] bool: the pairs of a block that the causal mask, and
+    the window's, keep (shared by fwd + both bwds)."""
     q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
     k_pos = ki * blk_k + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
     keep = q_pos >= k_pos
     if window is not None:
         keep = keep & (q_pos - k_pos < window)
-    return jnp.where(keep, s, _NEG_INF)
+    return keep
 
 
-def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki,
-                    *, scale: float, causal: bool, blk_q: int, blk_k: int,
-                    window: Optional[int] = None):
-    """Re-form a score block from (q, k, lse) and compute (p, ds) — the flash
-    backward identity ds = p ⊙ (do·vᵀ − delta)·scale, shared by the dk/dv and
-    dq kernels so forward and backward masking cannot desynchronize."""
-    s = jax.lax.dot_general(
+def _masked(s, keep):
+    """The scores ``s`` with the pairs ``keep`` drops (None: none) at -1e30.
+    Written, as the kernels' other elementwise math is, in ``lax`` ops: a
+    ``jnp`` wrapper is a jitted function traced anew at every call, and a
+    train step traces each kernel body several times (PERF.md, PR 39)."""
+    if keep is None:
+        return s
+    return lax.select(keep, s, lax.full_like(s, _NEG_INF))
+
+
+def _col(rows):
+    """A per-row vector as a column, to broadcast over a row's keys."""
+    return lax.broadcast_in_dim(rows, (rows.shape[0], 1), (0,))
+
+
+def _recompute_p_ds(q, k, v, do, lse, delta, keep, *, scale: float):
+    """Re-form the scores of (q rows, k rows) from (q, k, lse) and compute
+    (p, ds) — the flash backward identity ds = p ⊙ (do·vᵀ − delta)·scale,
+    shared by the dk/dv and dq kernels so forward and backward masking cannot
+    desynchronize (``keep``: the pairs the mask keeps, None for all)."""
+    s = _masked(lax.mul(lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # [blk_q, blk_k]
-    if causal:
-        s = _mask_causal(s, qi, ki, blk_q, blk_k, window)
-    p = jnp.exp(s - lse[:, None])                         # true softmax rows
-    dp = jax.lax.dot_general(
+        preferred_element_type=jnp.float32), scale), keep)  # [rows, cols]
+    p = lax.exp(lax.sub(s, _col(lse)))                    # true softmax rows
+    dp = lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = lax.mul(lax.mul(p, lax.sub(dp, _col(delta))), scale)
     return p, ds
 
 
@@ -378,20 +403,25 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _body():
-        q, do = q_ref[0], do_ref[0]            # [blk_q, D]
-        p, ds = _recompute_p_ds(
-            q, k_ref[0], v_ref[0], do, lse_ref[0, 0], delta_ref[0, 0],
-            qi, ki, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-            window=window)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _body(edge):
+        for cols, pieces in _walk(edge, False, qi, ki, blk_q, blk_k, window):
+            k, v = k_ref[0, cols], v_ref[0, cols]           # [cols, D]
+            dk, dv = dk_scr[cols], dv_scr[cols]
+            for rows, keep in pieces:
+                q, do = q_ref[0, rows], do_ref[0, rows]     # [rows, D]
+                p, ds = _recompute_p_ds(
+                    q, k, v, do, lse_ref[0, 0, rows], delta_ref[0, 0, rows],
+                    keep, scale=scale)
+                dv = lax.add(dv, lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                dk = lax.add(dk, lax.dot_general(
+                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            dk_scr[cols] = dk
+            dv_scr[cols] = dv
 
-    _when_visible(_body, in_band, causal, qi, ki, blk_q, blk_k)
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
 
     @pl.when(j == last - 1)
     def _finalize():
@@ -415,17 +445,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _body():
-        k = k_ref[0]
-        _, ds = _recompute_p_ds(
-            q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0, 0], delta_ref[0, 0],
-            qi, ki, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-            window=window)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _body(edge):
+        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
+            dq = dq_scr[rows]
+            for cols, keep in pieces:
+                k = k_ref[0, cols]
+                _, ds = _recompute_p_ds(
+                    q_ref[0, rows], k, v_ref[0, cols], do_ref[0, rows],
+                    lse_ref[0, 0, rows], delta_ref[0, 0, rows], keep,
+                    scale=scale)
+                dq = lax.add(dq, lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            dq_scr[rows] = dq
 
-    _when_visible(_body, in_band, causal, qi, ki, blk_q, blk_k)
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
 
     @pl.when(j == num_k - 1)
     def _finalize():
@@ -589,8 +623,8 @@ def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
     aligned themselves — ``_fit_block`` caps them at t, which need not be a
     multiple of 8 (t=20 → blk=20) — and the (1, 1, blk_q) LSE blocks put
     blk_q on the lanes. A window need not be a multiple of a block (the
-    band's edges are masked element by element); it has to hold the query
-    itself."""
+    band's edge blocks are then masked whole, element by element); it has to
+    hold the query itself."""
     blk_q, blk_k = _fit_block(t, block_q), _fit_block(t, block_k)
     if window is not None and window < 1:
         return f"window {window} holds no key, not even the query's own"
@@ -743,3 +777,98 @@ def _named(q3, k3, v3, out, lse):
 
     out, lse = map(checkpoint_name, (out, lse), RESIDUAL_NAMES)
     return out, (q3, k3, v3, out, lse)
+
+
+# ---------------------------------------------------------------------------
+# The mask's two edges. A block pair that holds a visible pair is *interior*
+# (no edge crosses it: no mask) or an *edge* block: the causal diagonal
+# crosses it, or the window's far side does. Down here for the reason above.
+# ---------------------------------------------------------------------------
+#: tiles a side of an edge block's walk: 1024-blocks in tiles of 512, which
+#: compute three tiles of four where tiles of 256 compute ten of sixteen and
+#: were the slower in every geometry swept (PERF.md, PR 39: a tile's products
+#: stream its few rows against each latched key tile)
+_TILES_A_SIDE = 2
+
+
+def _tile(blk_q: int, blk_k: int, window: Optional[int]) -> Optional[int]:
+    """The width of the tiles an edge block is walked in, or None where it
+    is computed whole and masked: the q and k blocks differ or the window is
+    no multiple of them (the edges are then no triangles of whole tiles), or
+    a tile would be no multiple of 128 lanes."""
+    if blk_q != blk_k or (window is not None and window % blk_k):
+        return None
+    ts = blk_q // _TILES_A_SIDE
+    return ts if ts and ts % 128 == 0 else None
+
+
+def _interior(qi, ki, blk_q: int, blk_k: int, window: Optional[int]):
+    """Whether no edge crosses block pair (``qi``, ``ki``) of the band:
+    every key at or before every query and, under a window, every pair
+    inside it (Python or traced integers)."""
+    inside = ki * blk_k + blk_k - 1 <= qi * blk_q
+    if window is not None:
+        inside = inside & (qi * blk_q + blk_q - 1 - ki * blk_k < window)
+    return inside
+
+
+def _by_edges(body, in_band, causal: bool, qi, ki, blk_q: int, blk_k: int,
+              window: Optional[int]):
+    """Run ``body(edge)`` on the path block pair (``qi``, ``ki``) takes.
+    A pair with no visible (query, key) pair runs nothing: with a window the
+    walk itself is the band (``in_band``); without one a k block strictly
+    above the triangle (its first key after this q block's last query)
+    contributes exactly zero. An interior pair runs ``body(None)``: no mask.
+    An edge pair runs ``body("diagonal")`` or ``body("far")``, a walk in
+    tiles, or ``body("whole")`` where ``_tile`` has none."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        return body(None)
+    visible = (in_band if in_band is not None
+               else qi * blk_q + (blk_q - 1) >= ki * blk_k)
+    interior = visible & _interior(qi, ki, blk_q, blk_k, window)
+    pl.when(interior)(functools.partial(body, None))
+    if _tile(blk_q, blk_k, window) is None:
+        pl.when(visible & ~interior)(functools.partial(body, "whole"))
+        return
+    pl.when(visible & (ki == qi))(functools.partial(body, "diagonal"))
+    if window is not None:
+        pl.when(visible & (ki == qi - window // blk_k))(
+            functools.partial(body, "far"))
+
+
+def _walk(edge: Optional[str], by_rows: bool, qi, ki, blk_q: int, blk_k: int,
+          window: Optional[int]):
+    """How a kernel step covers its block: a list of (slice of the walked
+    side, [(slice of the other side, the pairs to keep or None for all)]),
+    the walked side being the q rows (``by_rows``: forward and dq, whose
+    state is a row's) or the k rows (dk/dv). An interior block is one piece
+    with no mask and a whole edge block one piece with the block's; a tiled
+    edge block gives every tile of the walked side the crossing tile,
+    masked, and the other side's tiles that lie wholly inside the mask
+    (before it under the diagonal seen by rows, after it seen by columns;
+    the far edge the other way round), in one piece; the tiles beyond the
+    edge appear nowhere."""
+    whole = slice(None)
+    if edge is None:
+        return [(whole, [(whole, None)])]
+    if edge == "whole":
+        return [(whole, [(whole, _keep_causal(qi, ki, blk_q, blk_k,
+                                              window))])]
+    ts = _tile(blk_q, blk_k, window)
+    row = lax.broadcasted_iota(jnp.int32, (ts, ts), 0)
+    col = lax.broadcasted_iota(jnp.int32, (ts, ts), 1)
+    # within a crossed tile the diagonal keeps key <= query; the far edge,
+    # `window` keys back, keeps the keys strictly after the query's own place
+    keep = lax.ge(row, col) if edge == "diagonal" else lax.gt(col, row)
+    before = (edge == "diagonal") == by_rows
+    steps = []
+    for a in range(_TILES_A_SIDE):
+        crossed = slice(a * ts, (a + 1) * ts)
+        inside = slice(0, a * ts) if before else slice((a + 1) * ts, blk_q)
+        pieces = [(crossed, keep)]
+        if inside.stop > inside.start:
+            pieces.append((inside, None))
+        steps.append((crossed, pieces))
+    return steps

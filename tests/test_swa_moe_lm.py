@@ -105,6 +105,47 @@ def test_flash_with_window_and_grouped_kv_matches_dense_masked_attention(
         np.testing.assert_allclose(g, x, rtol=2e-4, atol=2e-4)
 
 
+# (T, window, block_q, block_k): sizes at which a q block has a far-edge, an
+# interior and a diagonal block, and (the first three) an edge block is walked
+# in tiles of 256 or 128; interpret mode has no lane rule, so the head is 16
+# wide
+EDGE_CASES = {
+    "full_causal": (1024, None, 512, 512),
+    "window_a_multiple_of_the_block": (2048, 1024, 512, 512),
+    "window_of_two_small_blocks": (1024, 512, 256, 256),
+    "window_no_multiple_whole_edges": (1024, 1000, 256, 256),
+    "q_and_k_blocks_differ_whole_edges": (1024, 512, 512, 256),
+}
+
+
+@pytest.mark.parametrize("heads", [(2, 2), (4, 1)],
+                         ids=["a_kv_head_a_query_head", "groups_of_four"])
+@pytest.mark.parametrize("case", list(EDGE_CASES))
+def test_the_kernels_follow_the_masks_two_edges(case, heads):
+    """Forward and the three gradients against dense masked attention where
+    the kernels sort their block pairs by the mask's edges: interior blocks
+    take no mask, an edge block computes only the tiles a query can see (or,
+    where the geometry is not static, is masked whole)."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.ops import flash_attention as fa
+    from raydp_tpu.ops.ring_attention import dense_attention
+
+    t, window, blk_q, blk_k = EDGE_CASES[case]
+    assert (fa._tile(blk_q, blk_k, window) is None) == ("whole" in case)
+    h, hk = heads
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, w = (jax.random.normal(k, (1, t, h, 16)) for k in ks[:2])
+    k, v = (jax.random.normal(k, (1, t, hk, 16)) for k in ks[2:])
+    got = jax.value_and_grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, window=window, block_q=blk_q, block_k=blk_k,
+        interpret=True) * w), (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(lambda *a: jnp.sum(dense_attention(
+        *a, window=window) * w), (0, 1, 2))(q, k, v)
+    for g, x in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, x, rtol=2e-4, atol=2e-4)
+
+
 def test_dense_attention_with_a_window_is_the_masked_softmax():
     """The dense path itself, against numpy: query i sees keys i-w+1..i of
     K/V head h // group."""
@@ -178,6 +219,93 @@ def test_the_windowed_kernels_walk_the_band_and_count_its_blocks():
     full = registry.snapshot()["counters"]["flash_blocks_total"]
     assert full["computed"] - after["computed"] == 2 * 10
     assert full["skipped_window"] == after["skipped_window"]
+
+
+def _counted(name, call):
+    """What counter ``name`` gains from ``call()``, by label."""
+    from raydp_tpu import metrics as registry
+    before = registry.snapshot()["counters"]
+    call()
+    return _moved(before, registry.snapshot()["counters"], name)
+
+
+# a head's tiles at the three cells' geometries (1024-blocks in tiles of 512,
+# four a block): (T, window) -> (interior blocks, edge blocks) a head
+CELL_GEOMETRIES = {
+    "trinity_window": ((8192, 2048), (7, 14)),          # 17.5 blocks' worth
+    "trinity_full": ((8192, None), (28, 8)),            # 34
+    "smallthinker_window": ((16384, 4096), (42, 28)),   # 63
+    "smallthinker_full": ((16384, None), (120, 16)),    # 132
+    "olmoe_full": ((4096, None), (6, 4)),               # 9
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_GEOMETRIES))
+def test_the_tiles_of_the_cells_geometries_by_what_becomes_of_them(cell):
+    """An interior block is four unmasked tiles; an edge block a triangle:
+    two crossed by the edge, one inside the mask, one never computed. No
+    edge block of a cell stays whole, and the block counter reads what it
+    read."""
+    from raydp_tpu.ops import flash_attention as fa
+
+    (t, window), (interior, edge) = CELL_GEOMETRIES[cell]
+    assert fa._tile(1024, 1024, window) == 1024 // fa._TILES_A_SIDE == 512
+    tiles = _counted("flash_tiles_total", lambda: fa._count_blocks(
+        1, 3, t, 1024, 1024, window, True))
+    assert tiles == {"unmasked": 3 * (4 * interior + edge),
+                     "masked": 3 * 2 * edge, "skipped": 3 * edge}
+    blocks = _counted("flash_blocks_total", lambda: fa._count_blocks(
+        2, 1, t, 1024, 1024, window, True))
+    assert blocks["computed"] == 2 * (interior + edge)
+    if cell == "trinity_window":
+        assert (tiles["unmasked"] + tiles["masked"]) / (3 * 4) == 17.5
+
+
+@pytest.mark.parametrize("geometry,fates", [
+    ((1024, 256, 256, 1000), {"unmasked": 4 * 5, "whole_edge": 4 * 5}),
+    ((1024, 512, 256, None), {"unmasked": 4 * 2, "whole_edge": 4 * 4}),
+    ((256, 128, 128, None), {"unmasked": 4, "whole_edge": 4 * 2}),
+    ((1024, 256, 256, 512), {"unmasked": 4 * 3 + 6, "masked": 2 * 6,
+                             "skipped": 6}),
+], ids=["window_no_multiple", "blocks_differ", "block_of_one_tile",
+        "tiles_of_128"])
+def test_the_tiles_where_an_edge_block_stays_whole_or_a_tile_is_small(
+        geometry, fates):
+    from raydp_tpu.ops import flash_attention as fa
+
+    t, blk_q, blk_k, window = geometry
+    assert _counted("flash_tiles_total", lambda: fa._count_blocks(
+        1, 1, t, blk_q, blk_k, window, True)) == fates
+    # without a mask there is no edge: every tile of every block
+    assert _counted("flash_tiles_total", lambda: fa._count_blocks(
+        1, 1, t, blk_q, blk_k, None, False)) == {
+            "unmasked": 4 * (t // blk_q) * (t // blk_k)}
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "windowed"])
+def test_one_pallas_call_a_kernel_under_the_pinned_names_and_grids(window):
+    """The edges' paths live inside the three kernels: a call's jaxpr holds
+    one ``pallas_call`` a kernel, named as a trace's readers expect, over
+    the grids ``(bh, T/blk_q, k_steps)`` and ``(bkv, T/blk_k, group *
+    q_steps)``."""
+    import re
+
+    import jax
+    from raydp_tpu.ops import flash_attention as fa
+
+    q, k, v, _ = _qkv(1024, 4, 2)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: fa.flash_attention(
+        *a, block_q=256, block_k=256, interpret=True, window=window).sum(),
+        (0, 1, 2)))(q, k, v))
+    names = re.findall(r"name=(rdt_flash(?:_win)?_(?:fwd|bwd_\w+))", text)
+    assert sorted(names) == sorted(fa._names(window))
+    assert text.count("pallas_call[") == 3
+    k_steps, q_steps = fa._band_steps(1024, 256, 256, window)
+    assert (k_steps, q_steps) == ((4, 4) if window is None else (3, 3))
+    grids = [tuple(int(n) for n in g.split(","))
+             for g in re.findall(r"grid=\(([\d, ]+)\)", text)]
+    assert sorted(grids) == sorted([(8, 4, k_steps), (4, 4, 2 * q_steps),
+                                    (8, 4, k_steps)])
 
 
 def test_a_window_needs_causal_and_the_heads_have_to_group():
