@@ -22,6 +22,9 @@ computed block whole and masked), and the tile walk at the other width. It
 sets the op's module-level rules aside for a measurement (``_tile``, ``_walk``,
 ``_TILES_A_SIDE``); the op has no argument for any of them.
 
+``--v-dim`` gives v and the output a width of their own (latent attention:
+``--dim 192 --v-dim 128``); ``--blocks`` names the shapes to time.
+
 Run: python benchmarks/flash_block_sweep.py [--seq-len 8192] [--dim 128]
      python benchmarks/flash_block_sweep.py --edges --grad --batch 2 \
          --seq-len 8192 --heads 32 --kv-heads 4 --window 2048   # a Trinity layer
@@ -42,6 +45,10 @@ def main():
     ap.add_argument("--seq-len", type=int, default=8192)
     ap.add_argument("--heads", type=int, default=8)
     ap.add_argument("--dim", type=int, default=128, help="head dim")
+    ap.add_argument("--v-dim", type=int, default=None,
+                    help="width of v and the output (default: --dim)")
+    ap.add_argument("--blocks", default=None,
+                    help="the shapes to time, e.g. 1024x1024,512x1024")
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--iters", type=int, default=16)
     ap.add_argument("--grad", action="store_true",
@@ -68,9 +75,10 @@ def main():
     iters = args.iters
     rng = np.random.RandomState(0)
     Hk = args.kv_heads or H
-    mk = lambda h: jnp.asarray(  # noqa: E731
-        rng.randn(B, T, h, D).astype(np.float32) * 0.3).astype(jnp.bfloat16)
-    q, k, v = mk(H), mk(Hk), mk(Hk)
+    Dv = args.v_dim or D
+    mk = lambda h, d=D: jnp.asarray(  # noqa: E731
+        rng.randn(B, T, h, d).astype(np.float32) * 0.3).astype(jnp.bfloat16)
+    q, k, v = mk(H), mk(Hk), mk(Hk, Dv)
 
     def rtt_ms() -> float:
         x = jnp.ones((8, 8))
@@ -97,9 +105,12 @@ def main():
                 return (dq + kept.astype(dq.dtype)).astype(x.dtype)
         else:
             def one(x):
-                return flash_attention(x, k, v, causal=True,
-                                       block_q=bq, block_k=bk,
-                                       window=args.window)
+                out = flash_attention(x, k, v, causal=True, block_q=bq,
+                                      block_k=bk, window=args.window)
+                # fed back as q: a narrower output keeps q's other columns
+                # (one more copy of q an application)
+                return out if Dv == D else jnp.concatenate(
+                    [out, x[..., Dv:]], axis=-1)
 
         @jax.jit
         def chained(x):
@@ -128,6 +139,9 @@ def main():
     results, refused = [], []
     grid = [(128, 128), (128, 256), (256, 256), (256, 512), (512, 512),
             (512, 1024), (1024, 1024)]
+    if args.blocks:
+        grid = [tuple(int(n) for n in shape.split("x"))
+                for shape in args.blocks.split(",")]
     for bq, bk in grid:
         if bq > T or bk > T:
             continue
@@ -144,8 +158,9 @@ def main():
     if not results:
         raise SystemExit("the compiler refused every configuration")
     best = min(results)
-    # causal flash fwd FLOPs: 2 matmuls x B*H*(T^2/2)*D x 2
-    flops = 4.0 * B * H * (T * T / 2) * D * (3.5 if args.grad else 1.0)
+    # causal flash fwd FLOPs: QK^T at D and PV at Dv, B*H*(T^2/2) pairs, x 2
+    flops = 2.0 * B * H * (T * T / 2) * (D + Dv) * (
+        3.5 if args.grad else 1.0)
     tflops = flops / (best[0] * 1e-6) / 1e12
     print(f"best: blk_q={best[1]} blk_k={best[2]} ({best[0]:.1f} us/{what}, "
           f"~{tflops:.1f} TFLOP/s) at B={B} T={T} H={H} D={D} on "

@@ -358,7 +358,9 @@ _ALL_METRICS = [
     _m("train_attention_layers_total", COUNTER, "1", "training",
        "Attention layers of a training model, counted once a built train "
        "step by kind: `window` (a sliding window: a query sees itself and "
-       "the window - 1 keys before it) or `full` (every key up to its own). "
+       "the window - 1 keys before it) or `full` (every key up to its own); "
+       "a latent-attention layer (keys and values from one low-rank latent "
+       "a token) counts under its kernel's kind and under `latent` too. "
        "doc/models.md.",
        label="kind"),
     _m("train_attention_forward_total", COUNTER, "1", "training",
@@ -583,6 +585,14 @@ _ALL_SPANS = [
        "Under `attn`: the attention itself of a sliding-window layer (the "
        "kernels `rdt_flash_win_fwd`, `rdt_flash_win_bwd_dkdv`, "
        "`rdt_flash_win_bwd_dq`).", kind=SCOPE),
+    _s("attn/latent", "model",
+       "latent attention's K/V path inside an `attn` scope: the "
+       "down-projection to the K/V latent and the one rotary key all heads "
+       "share, the latent's norm, the up-projection to every head's keys "
+       "and values, RoPE on the two rotary parts, and the broadcast and "
+       "concatenation that lay the rotary key into every head's key; "
+       "forward, recomputed and backward. The query and output projections "
+       "and the flash kernels lie outside it.", kind=SCOPE),
     _s("attn_gate", "model",
        "Under `attn`: the attention output times sigmoid of its gate "
        "projection (`attention_gate`), before the output projection.",

@@ -58,6 +58,13 @@ and the balancing bias that ``bias_update_rate`` moves once an optimizer step
 (:meth:`TransformerLM.after_step`; state outside the parameters, see
 :mod:`raydp_tpu.models.moe`), and ``shared_expert_dim``.
 
+And, since the ``deepseek_v3`` family (latent attention): ``kv_lora_rank``
+with ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` makes every
+block's attention a :class:`LatentAttention` (keys and values come from one
+low-rank latent a token and one rotary key all heads share; keys are wider
+than values), ``q_lora_rank`` gives the queries a latent of their own, and
+``rope_interleave`` rotates dimension pairs ``(2i, 2i + 1)``.
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -77,13 +84,20 @@ from flax import linen as nn
 
 
 def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray,
-                     base: float = 10000.0) -> jnp.ndarray:
-    """Apply RoPE. x: [B, T, H, D]; positions: [T] global token positions."""
+                     base: float = 10000.0,
+                     interleaved: bool = False) -> jnp.ndarray:
+    """Apply RoPE. x: [B, T, H, D]; positions: [T] global token positions.
+    Pair ``i`` of the ``D / 2`` rotated pairs is dimensions ``(i, i + D/2)``
+    or, ``interleaved``, ``(2i, 2i + 1)``; a pair keeps its places."""
     d_half = x.shape[-1] // 2
     freqs = 1.0 / (base ** (np.arange(0, d_half) / d_half))
     angles = positions[:, None] * freqs[None, :]            # [T, D/2]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :d_half], x[..., d_half:]
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
@@ -123,7 +137,7 @@ class Attention(nn.Module):
     rope: bool = True
     gate: bool = False                      # o(attn * sigmoid(W_g x))
 
-    def _dispatch(self, t: int, head_dim: int) -> str:
+    def _dispatch(self, t: int, head_dim: int, d_v=None) -> str:
         from raydp_tpu.ops.flash_attention import kernel_ineligible
         from raydp_tpu.parallel.mesh import seq_extent
 
@@ -133,7 +147,7 @@ class Attention(nn.Module):
             return "ring"
         if jax.default_backend() != "tpu":
             return "dense"
-        why = kernel_ineligible(t, head_dim, window=self.window)
+        why = kernel_ineligible(t, head_dim, window=self.window, d_v=d_v)
         if why is not None and self.window is not None:
             # a window is set for sequences whose dense [T, T] scores do not
             # fit: never a quiet switch to them
@@ -205,7 +219,8 @@ class Block(nn.Module):
     kernel lies in the block (``router``) and reads the attention's normed
     input; its logits are handed to the expert layer. ``sandwich_norms``: each
     sub-layer's output is normed too (``ln1_post``, ``ln2_post``) before it is
-    added to the stream."""
+    added to the stream. ``kv_lora_rank``: the attention is a
+    :class:`LatentAttention` of the four widths given with it."""
 
     num_heads: int
     mlp_ratio: int = 4
@@ -233,6 +248,12 @@ class Block(nn.Module):
     routing: str = "softmax"
     route_scale: float = 1.0
     shared_expert_dim: int = 0
+    kv_lora_rank: Optional[int] = None      # None: Attention as it is
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_interleave: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -252,11 +273,7 @@ class Block(nn.Module):
                              f"'experts' or 'attention'")
         post = (lambda name, y: RMSNorm(eps, name=name)(y)) \
             if self.sandwich_norms else (lambda name, y: y)
-        x = x + post("ln1_post", Attention(
-            self.num_heads, self.attention, self.mesh, self.dtype,
-            self.rope_theta, self.qk_norm, eps, self.init_std, self.head_dim,
-            self.num_kv_heads, self.window, self.rope, self.attention_gate,
-            name="attn")(u))
+        x = x + post("ln1_post", _attention(self)(u))
         h = RMSNorm(eps, name="ln2")(x)
         hidden = self.ffn_dim or self.mlp_ratio * dim
         if self.num_experts:
@@ -315,6 +332,12 @@ class TransformerLM(nn.Module):
     route_scale: float = 1.0
     shared_expert_dim: int = 0
     bias_update_rate: float = 0.0           # sigmoid routing's balancing bias
+    kv_lora_rank: Optional[int] = None      # latent attention: the K/V latent
+    q_lora_rank: Optional[int] = None       # and, where given, the queries'
+    qk_nope_head_dim: Optional[int] = None  # a key's part without position,
+    qk_rope_head_dim: Optional[int] = None  # its rotary part (all heads' one)
+    v_head_dim: Optional[int] = None        # and a value's width
+    rope_interleave: bool = False           # RoPE over pairs (2i, 2i + 1)
 
     def _windowed(self, layer: int) -> bool:
         pattern = self.window_layers
@@ -328,9 +351,13 @@ class TransformerLM(nn.Module):
     @property
     def attention_layers(self):
         """How many layers of each kind the model has: what
-        ``train_attention_layers_total`` counts once a built step."""
+        ``train_attention_layers_total`` counts once a built step. A latent
+        layer counts under its kernel's kind and under ``latent``."""
         windowed = sum(self._windowed(i) for i in range(self.num_layers))
-        return {"window": windowed, "full": self.num_layers - windowed}
+        kinds = {"window": windowed, "full": self.num_layers - windowed}
+        if self.kv_lora_rank is not None:
+            kinds["latent"] = self.num_layers
+        return kinds
 
     @property
     def attention_forward(self):
@@ -341,8 +368,12 @@ class TransformerLM(nn.Module):
         names nothing to keep (``dense`` and ``ring``: their ``[T, T]``
         scores must not be kept). ``auto`` counts as what it picks for a
         shape the kernel takes."""
+        d_qk, d_v = self.head_dim or self.dim // self.num_heads, None
+        if self.kv_lora_rank is not None:
+            d_qk, d_v = (self.qk_nope_head_dim + self.qk_rope_head_dim,
+                         self.v_head_dim)
         kind = Attention(self.num_heads, self.attention,
-                         self.mesh)._dispatch(8192, 128)
+                         self.mesh)._dispatch(8192, d_qk, d_v)
         kept = not self.remat_blocks or kind == "flash"
         return {"once" if kept else "twice": self.num_layers}
 
@@ -395,7 +426,10 @@ class TransformerLM(nn.Module):
                       self.expert_activation, self.normalize_top_k,
                       self.router_input, self.attention_gate,
                       self.sandwich_norms, self.routing, self.route_scale,
-                      self.shared_expert_dim, name=f"block_{i}")(x)
+                      self.shared_expert_dim, self.kv_lora_rank,
+                      self.q_lora_rank, self.qk_nope_head_dim,
+                      self.qk_rope_head_dim, self.v_head_dim,
+                      self.rope_interleave, name=f"block_{i}")(x)
             if sparse:
                 x, layer_aux = x
                 aux.append(layer_aux)
@@ -639,6 +673,11 @@ def transformer_param_rules(axis: str = "tensor"):
         ("attn/k/kernel", (None, axis, None)),
         ("attn/v/kernel", (None, axis, None)),
         ("attn/o/kernel", (axis, None, None)),
+        # latent attention: the two up-projections over the heads; the
+        # down-projections (q_a, kv_a) and the latents' norms match no rule
+        # and stay replicated
+        ("attn/q_b/kernel", (None, axis, None)),
+        ("attn/kv_b/kernel", (None, axis, None)),
         ("gate/kernel", (None, axis)),
         ("up/kernel", (None, axis)),
         ("down/kernel", (axis, None)),
@@ -670,3 +709,106 @@ def _ffn_out(block, y):
     from jax.ad_checkpoint import checkpoint_name
 
     return checkpoint_name(y, SUBLAYER_OUT)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (the ``deepseek_v3`` family's). A token's
+    keys and values come from ONE latent of ``kv_lora_rank`` and one rotary
+    key of ``qk_rope_head_dim`` that all heads share::
+
+        q = W_q u                     [H, nope + rope]   (or W_qb norm(W_qa u))
+        c, k_rope = split(W_kva u)    [rank], [rope]
+        k_nope, v = split(W_kvb norm(c))   [H, nope], [H, v_head_dim]
+        q_rope, k_rope = RoPE(q_rope), RoPE(k_rope)
+        k = [k_nope, k_rope for every head]
+        out = W_o softmax(q k^T / sqrt(nope + rope) + causal) v
+
+    The keys are ``nope + rope`` wide and the values ``v_head_dim``: the
+    flash kernels take the two widths as they are, nothing is padded. ``k`` is
+    built in HBM by broadcasting ``k_rope`` over the heads (the scope
+    ``latent`` holds that, both projections, the norm and RoPE, so a trace
+    prices it: ``latent_kv_share``). Defined at the file's end for the reason
+    ``SUBLAYER_OUT`` is."""
+
+    num_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    q_lora_rank: Optional[int] = None       # None: one full-rank W_q
+    attention: str = "auto"
+    mesh: Any = None
+    dtype: Any = jnp.float32
+    rope_theta: float = 10000.0
+    rope_interleave: bool = False
+    rms_norm_eps: float = 1e-6
+    init_std: Optional[float] = None
+
+    window = None                           # every key up to a query's own
+    _dispatch = Attention._dispatch
+
+    @nn.compact
+    def __call__(self, x):
+        from raydp_tpu.ops.flash_attention import flash_attention_sharded
+        from raydp_tpu.ops.ring_attention import dense_attention
+
+        b, t, dim = x.shape
+        heads, nope, rope = (self.num_heads, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim)
+        eps = self.rms_norm_eps
+        init = _init(self.init_std, nn.linear.default_kernel_init)
+        dense = lambda name, features: nn.DenseGeneral(  # noqa: E731
+            features, axis=-1, name=name, dtype=self.dtype, use_bias=False,
+            kernel_init=init)
+        if self.q_lora_rank is None:
+            q = dense("q", (heads, nope + rope))(x)
+        else:
+            q = dense("q_b", (heads, nope + rope))(RMSNorm(
+                eps, name="q_a_norm")(dense("q_a", self.q_lora_rank)(x)))
+        with jax.named_scope("latent"):
+            down = dense("kv_a", self.kv_lora_rank + rope)(x)
+            latent = RMSNorm(eps, name="kv_norm")(
+                down[..., :self.kv_lora_rank])
+            kv = dense("kv_b", (heads, nope + self.v_head_dim))(latent)
+            positions = jnp.arange(t)
+            turn = lambda a: rotary_embedding(  # noqa: E731
+                a, positions, self.rope_theta, self.rope_interleave)
+            k_rope = turn(down[..., None, self.kv_lora_rank:])  # [B, T, 1, r]
+            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                k_rope, (b, t, heads, rope))], axis=-1)
+            v = kv[..., nope:]
+
+        kind = self._dispatch(t, nope + rope, self.v_head_dim)
+        with jax.named_scope("attn_full"):
+            if kind == "ring":
+                raise NotImplementedError(
+                    "latent attention takes no seq axis: ring attention "
+                    "rotates keys and values of one width")
+            if kind == "flash":
+                out = flash_attention_sharded(q, k, v, self.mesh, causal=True)
+            else:
+                out = dense_attention(q, k, v, causal=True)
+        return nn.DenseGeneral(dim, axis=(-2, -1), name="o", dtype=self.dtype,
+                               use_bias=False, kernel_init=init)(out)
+
+
+def _attention(block):
+    """A block's attention sub-layer, ``attn``: :class:`Attention` or, where
+    the block states a K/V latent, :class:`LatentAttention`."""
+    if block.kv_lora_rank is None:
+        return Attention(
+            block.num_heads, block.attention, block.mesh, block.dtype,
+            block.rope_theta, block.qk_norm, block.rms_norm_eps,
+            block.init_std, block.head_dim, block.num_kv_heads, block.window,
+            block.rope, block.attention_gate, name="attn")
+    if (block.window or not block.rope or block.attention_gate
+            or block.qk_norm or block.num_kv_heads):
+        raise ValueError("latent attention has no window, no layer without "
+                         "RoPE, no gate, no QK norm and no grouped K/V")
+    return LatentAttention(
+        block.num_heads, block.kv_lora_rank, block.qk_nope_head_dim,
+        block.qk_rope_head_dim, block.v_head_dim, block.q_lora_rank,
+        block.attention, block.mesh, block.dtype, block.rope_theta,
+        block.rope_interleave, block.rms_norm_eps, block.init_std,
+        name="attn")

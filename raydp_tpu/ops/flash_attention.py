@@ -12,16 +12,16 @@ blocks, dq walking k blocks, both with the causal block skip); elsewhere a
 blockwise ``lax.scan`` computes the same math — memory stays O(T·blk) in both
 directions.
 
-Two options follow a published layer. ``window`` (with ``causal``): a query
+Three options follow a published layer. ``window`` (with ``causal``): a query
 attends to itself and the ``window - 1`` keys before it. The kernels then walk
 only the blocks of the band: the grid's inner dimension holds as many steps as
-a block's band has blocks (five 1024-blocks for a window of 4096, whatever the
-sequence length), its block index is the band's; blocks above the diagonal and
-blocks wholly behind the window are never fetched or computed. Grouped-query
-heads: K and V may hold fewer heads than Q (``H = G * Hk``); a K/V head is
-read by its ``G`` query heads from where it lies, never repeated in HBM, and
-the dK/dV kernel walks a group's query heads in its inner dimension so that
-their sum is formed in VMEM.
+a block's band has blocks (five 1024-blocks for a window of 4096 at any
+length); blocks above the diagonal and blocks wholly behind the window are
+never fetched or computed. Grouped-query heads: K and V may hold fewer heads
+than Q (``H = G * Hk``); a K/V head is read by its ``G`` query heads from
+where it lies, and the dK/dV kernel sums a group's query heads in VMEM. Two
+widths: V, the output and its cotangent may be ``Dv`` wide beside Q and K of
+``D`` (latent attention: 128 beside 192), every block at its own width.
 
 The mask has two edges, the causal diagonal and the window's far side, and
 every kernel step sorts its block pair by them (``_by_edges``): a block no edge
@@ -255,11 +255,11 @@ def _maps(group: int, band: dict):
 
 def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
                 blk_k: int, interpret: bool, window: Optional[int] = None):
-    """q3: [BH, T, D], k3/v3: [BHk, T, D] → (out [BH, T, D], lse [BH, T])."""
+    """q3 [BH,T,D], k3 [BHk,T,D], v3 [BHk,T,Dv] → (out [BH,T,Dv], lse [BH,T])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, t, d = q3.shape
+    (bh, t, d), d_v = q3.shape, v3.shape[2]
     group = bh // k3.shape[0]
     k_steps, _ = _band_steps(t, blk_q, blk_k, window)
     band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
@@ -274,21 +274,21 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), q_map),
             pl.BlockSpec((1, blk_k, d), kv_map),
-            pl.BlockSpec((1, blk_k, d), kv_map),
+            pl.BlockSpec((1, blk_k, d_v), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), q_map),
+            pl.BlockSpec((1, blk_q, d_v), q_map),
             # [BH, 1, T]: trailing block dims (1, blk_q) satisfy TPU tiling
             pl.BlockSpec((1, 1, blk_q), row_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, d_v), q3.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 128), jnp.float32),   # m (lane-padded)
             pltpu.VMEM((blk_q, 128), jnp.float32),   # l
-            pltpu.VMEM((blk_q, d), jnp.float32),     # acc
+            pltpu.VMEM((blk_q, d_v), jnp.float32),   # acc
         ],
         # bh and q blocks are independent; only the k walk carries state
         compiler_params=pltpu.CompilerParams(
@@ -472,7 +472,7 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
     from jax.experimental.pallas import tpu as pltpu
 
     q3, k3, v3, out, lse = res
-    bh, t, d = q3.shape
+    (bh, t, d), d_v = q3.shape, v3.shape[2]
     bkv = k3.shape[0]
     group = bh // bkv
     do = g
@@ -508,22 +508,22 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), q_side),    # q
             pl.BlockSpec((1, blk_k, d), k_side),    # k
-            pl.BlockSpec((1, blk_k, d), k_side),    # v
-            pl.BlockSpec((1, blk_q, d), q_side),    # do
+            pl.BlockSpec((1, blk_k, d_v), k_side),  # v
+            pl.BlockSpec((1, blk_q, d_v), q_side),  # do
             pl.BlockSpec((1, 1, blk_q), q_rows),    # lse
             pl.BlockSpec((1, 1, blk_q), q_rows),    # delta
         ],
         out_specs=[
             pl.BlockSpec((1, blk_k, d), k_side),
-            pl.BlockSpec((1, blk_k, d), k_side),
+            pl.BlockSpec((1, blk_k, d_v), k_side),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, t, d), k3.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bkv, t, d), v3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, t, d_v), v3.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
-            pltpu.VMEM((blk_k, d), jnp.float32),
+            pltpu.VMEM((blk_k, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -539,8 +539,8 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), q_map),     # q
             pl.BlockSpec((1, blk_k, d), kv_map),    # k
-            pl.BlockSpec((1, blk_k, d), kv_map),    # v
-            pl.BlockSpec((1, blk_q, d), q_map),     # do
+            pl.BlockSpec((1, blk_k, d_v), kv_map),  # v
+            pl.BlockSpec((1, blk_q, d_v), q_map),   # do
             pl.BlockSpec((1, 1, blk_q), row_map),   # lse
             pl.BlockSpec((1, 1, blk_q), row_map),   # delta
         ],
@@ -565,7 +565,7 @@ def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int,
     q3, k3, v3, out, lse = res
     bkv = k3.shape[0]
     k3, v3, group = _repeat_kv(q3, k3, v3)
-    bh, t, d = q3.shape
+    (bh, t, d), d_v = q3.shape, v3.shape[2]
     blk = _fit_block(t, blk_k)
     num_k = t // blk
 
@@ -595,10 +595,10 @@ def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int,
     dq, (dk_blocks, dv_blocks) = lax.scan(
         step, jnp.zeros_like(qf), jnp.arange(num_k))
     dk = dk_blocks.transpose(1, 0, 2, 3).reshape(bh, t, d)
-    dv = dv_blocks.transpose(1, 0, 2, 3).reshape(bh, t, d)
+    dv = dv_blocks.transpose(1, 0, 2, 3).reshape(bh, t, d_v)
     if group > 1:       # a K/V head's gradient: the sum over its query heads
         dk = dk.reshape(bkv, group, t, d).sum(axis=1)
-        dv = dv.reshape(bkv, group, t, d).sum(axis=1)
+        dv = dv.reshape(bkv, group, t, d_v).sum(axis=1)
     return dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
 
 
@@ -616,20 +616,20 @@ def _fit_block(t: int, blk: int) -> int:
 
 def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
                       block_k: int = DEFAULT_BLOCK_K,
-                      window: Optional[int] = None) -> Optional[str]:
+                      window: Optional[int] = None,
+                      d_v: Optional[int] = None) -> Optional[str]:
     """Why the compiled Pallas kernel cannot take a [.., T=t, .., D=d] call
-    (None when it can). Block dims equal to the full array dim satisfy TPU
-    tiling, so d needs no 128 alignment; the q/k blocks must be sublane-
-    aligned themselves — ``_fit_block`` caps them at t, which need not be a
-    multiple of 8 (t=20 → blk=20) — and the (1, 1, blk_q) LSE blocks put
-    blk_q on the lanes. A window need not be a multiple of a block (the
-    band's edge blocks are then masked whole, element by element); it has to
-    hold the query itself."""
+    with values of width ``d_v`` (None: ``d``; None back when it can). Block
+    dims equal to the full array dim satisfy TPU tiling, so neither width
+    needs 128 alignment; the q/k blocks must be sublane-aligned themselves
+    (``_fit_block`` caps them at t: t=20 → blk=20) and the (1, 1, blk_q) LSE
+    blocks put blk_q on the lanes. A window need not be a multiple of a block
+    (edge blocks are then masked whole); it has to hold the query itself."""
     blk_q, blk_k = _fit_block(t, block_q), _fit_block(t, block_k)
     if window is not None and window < 1:
         return f"window {window} holds no key, not even the query's own"
-    if d % 8:
-        return f"head_dim {d} is not a multiple of 8"
+    if d % 8 or (d_v or d) % 8:
+        return f"head_dim {d} (values {d_v or d}) is not a multiple of 8"
     if blk_q % 8 or blk_k % 8:
         return (f"sequence length {t} only divides into blocks "
                 f"({blk_q}, {blk_k}) that are not multiples of 8")
@@ -640,7 +640,7 @@ def kernel_ineligible(t: int, d: int, block_q: int = DEFAULT_BLOCK_Q,
 
 
 def _use_pallas(t: int, d: int, blk_q: int, blk_k: int, interpret: bool,
-                window: Optional[int] = None) -> bool:
+                window: Optional[int] = None, d_v=None) -> bool:
     """Can the Pallas kernel take this call? In interpret mode: whenever the
     blocks divide the sequence. Otherwise whenever the compiled kernel is
     eligible; on a TPU backend an ineligible shape is an error. Whether the
@@ -649,7 +649,7 @@ def _use_pallas(t: int, d: int, blk_q: int, blk_k: int, interpret: bool,
     path."""
     if interpret:
         return t % blk_q == 0 and t % blk_k == 0
-    why = kernel_ineligible(t, d, blk_q, blk_k, window)
+    why = kernel_ineligible(t, d, blk_q, blk_k, window, d_v)
     if why is None:
         return True
     if jax.default_backend() == "tpu":
@@ -682,7 +682,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window):
     t, d = q3.shape[1], q3.shape[2]
     jnp_fn = functools.partial(_fwd_jnp, scale=scale, causal=causal,
                                window=window)
-    if _use_pallas(t, d, blk_q, blk_k, interpret, window):
+    if _use_pallas(t, d, blk_q, blk_k, interpret, window, v3.shape[2]):
         out, lse = _by_platform(
             functools.partial(_fwd_pallas, scale=scale, causal=causal,
                               blk_q=blk_q, blk_k=blk_k, interpret=interpret,
@@ -697,7 +697,7 @@ def _flash_bwd(scale, causal, blk_q, blk_k, interpret, window, res, g):
     t, d = res[0].shape[1], res[0].shape[2]
     jnp_fn = functools.partial(_bwd_blockwise, scale=scale, causal=causal,
                                blk_k=blk_k, window=window)
-    if _use_pallas(t, d, blk_q, blk_k, interpret, window):
+    if _use_pallas(t, d, blk_q, blk_k, interpret, window, res[2].shape[2]):
         return _by_platform(
             functools.partial(_bwd_pallas, scale=scale, causal=causal,
                               blk_q=blk_q, blk_k=blk_k, interpret=interpret,
@@ -715,15 +715,15 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: int = DEFAULT_BLOCK_K,
                     interpret: bool = False,
                     window: Optional[int] = None):
-    """Memory-efficient exact attention. q: [B, T, H, D]; k, v: [B, T, Hk, D]
-    with ``H`` a multiple of ``Hk`` (query head ``h`` reads K/V head
-    ``h // (H // Hk)``) → [B, T, H, D]. ``window`` (causal only): a query
-    sees itself and the ``window - 1`` keys before it."""
+    """Memory-efficient exact attention. q: [B, T, H, D]; k: [B, T, Hk, D];
+    v: [B, T, Hk, Dv], ``H`` a multiple of ``Hk`` (query head ``h`` reads K/V
+    head ``h // (H // Hk)``) → [B, T, H, Dv]; the default scale is D^-1/2.
+    ``window`` (causal only): a query sees itself and ``window - 1`` keys."""
     b, t, h, d = q.shape
     hk = k.shape[2]
-    if h % hk or v.shape != k.shape:
-        raise ValueError(f"{h} query heads over K {k.shape} / V {v.shape}: "
-                         f"the K/V heads must divide the query heads")
+    if h % hk or v.shape[:3] != k.shape[:3] or k.shape[3] != d:
+        raise ValueError(f"q {q.shape} over K {k.shape} / V {v.shape}: K/V "
+                         f"heads divide the query heads, keys are q's width")
     if window is not None and not causal:
         raise ValueError("a window is one-sided: it needs causal=True")
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -731,11 +731,11 @@ def flash_attention(q, k, v, causal: bool = True,
     blk_k = _fit_block(t, block_k)
 
     def to3(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, x.shape[3])
 
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, blk_q, blk_k,
                   interpret, window)
-    return out3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out3.reshape(b, h, t, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def flash_attention_sharded(q, k, v, mesh, causal: bool = True, **kwargs):

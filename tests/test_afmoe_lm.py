@@ -11,6 +11,7 @@ the CPU cut keeps them).
 """
 
 import copy
+import functools
 import os
 
 import numpy as np
@@ -124,18 +125,18 @@ def test_softmax_routing_is_the_routing_as_it_was():
 
 
 # ------------------------------------- (b) the shares of one expert layer
-def _expert_layer(seed=0):
+def _expert_layer(seed=0, shared_dim=16):
     """An uncut layer's seeded weights (router, 16 experts, the shared
-    expert), a bias that changes picks, and tokens."""
+    expert of width ``shared_dim``), a bias that changes picks, and tokens."""
     rng = np.random.default_rng(seed)
-    d, f, e, n = 32, 16, 16, 48
+    d, f, e, n, s = 32, 16, 16, 48, shared_dim
     full = {"router": rng.normal(0, 0.3, (d, e)),
             "experts_gate": rng.normal(0, 0.3, (e, d, f)),
             "experts_up": rng.normal(0, 0.3, (e, d, f)),
             "experts_down": rng.normal(0, 0.3, (e, f, d)),
-            "shared_gate": {"kernel": rng.normal(0, 0.3, (d, f))},
-            "shared_up": {"kernel": rng.normal(0, 0.3, (d, f))},
-            "shared_down": {"kernel": rng.normal(0, 0.3, (f, d))}}
+            "shared_gate": {"kernel": rng.normal(0, 0.3, (d, s))},
+            "shared_up": {"kernel": rng.normal(0, 0.3, (d, s))},
+            "shared_down": {"kernel": rng.normal(0, 0.3, (s, d))}}
     return (_f32(full), _f32(rng.normal(0, 0.2, (e,))),
             _f32(rng.normal(size=(n, d))))
 
@@ -151,11 +152,20 @@ def _share_of(full, first, held, shared):
             if shared or not k.startswith("shared_")}
 
 
-def _program_layer(params, bias, m, first, held, shared, mutable=False):
+# a second family's expert layer of the same kind, in its own file's keys
+# (kanana-2-30b-a3b: 6 a token, two shared experts as one MLP, times 2.448)
+KANANA_LAYER_CFG = {"n_routed_experts": 16, "num_experts_per_tok": 6,
+                    "norm_topk_prob": True, "routed_scaling_factor": 2.448,
+                    "n_shared_experts": 2, "first_expert": 0,
+                    "experts_held": 16}
+
+
+def _program_layer(params, bias, m, first, held, shared, mutable=False,
+                   top_k=4, scale=2.826, shared_dim=16):
     from raydp_tpu.models.moe import STATE, MoE
-    layer = MoE(16, 4, 16, first_expert=first, experts_held=held,
-                normalize_top_k=True, routing="sigmoid", route_scale=2.826,
-                shared_dim=16 if shared else 0)
+    layer = MoE(16, top_k, 16, first_expert=first, experts_held=held,
+                normalize_top_k=True, routing="sigmoid", route_scale=scale,
+                shared_dim=shared_dim if shared else 0)
     variables = {"params": params, STATE: {
         "bias": bias, "counts": np.zeros(16, np.float32)}}
     if mutable:
@@ -163,48 +173,58 @@ def _program_layer(params, bias, m, first, held, shared, mutable=False):
     return layer.apply(variables, m)
 
 
-def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
-    """Experts 0-1, 2-3, ... 14-15 of 16, 4 a token: each chip routes over
-    all sixteen (by score + bias, the weights over all four choices) and
-    computes its own experts' part; the eight routed parts and the shared
-    expert, counted once, sum to the reference's uncut layer, and the held
-    slots to all slots. A share that holds the shared expert carries it
-    whole."""
-    _, _, reference = _files()
-    full, bias, m = _expert_layer()
-    want = np.asarray(reference.expert_layer(full, m, bias, LAYER_CFG))
+@pytest.mark.parametrize("config,layer_cfg,none_shared", [
+    (CONFIG, LAYER_CFG, "num_shared_experts"),
+    ("kanana-2-30b-a3b", KANANA_LAYER_CFG, "n_shared_experts")])
+def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer(
+        config, layer_cfg, none_shared):
+    """Experts 0-1, 2-3, ... 14-15 of 16, 4 a token (6 in the second family,
+    beside a shared MLP two experts wide): each chip routes over all sixteen
+    (by score + bias, the weights over all the choices) and computes its own
+    experts' part; the eight routed parts and the shared expert, counted
+    once, sum to the family's own reference's uncut layer, and the held slots
+    to all slots. A share that holds the shared expert carries it whole."""
+    from chipbench import manifest
+    reference = manifest.load_module(ROOT, "reference", f"{config}.py")
+    top_k = layer_cfg["num_experts_per_tok"]
+    sizes = {"top_k": top_k, "scale": layer_cfg.get(
+        "route_scale", layer_cfg.get("routed_scaling_factor")),
+        "shared_dim": 16 * layer_cfg[none_shared]}
+    layer_of = functools.partial(_program_layer, **sizes)
+    full, bias, m = _expert_layer(shared_dim=sizes["shared_dim"])
+    want = np.asarray(reference.expert_layer(full, m, bias, layer_cfg))
     shared = np.asarray(reference.expert_layer(
         _share_of(full, 0, 0, True), m, bias,
-        dict(LAYER_CFG, experts_held=0)))
+        dict(layer_cfg, experts_held=0)))
     parts, held_slots = [], 0.0
     for first in range(0, 16, 2):
-        y, aux = _program_layer(_share_of(full, first, 2, False), bias, m,
-                                first, 2, shared=False)
-        one = dict(LAYER_CFG, first_expert=first, experts_held=2,
-                   num_shared_experts=0)
+        y, aux = layer_of(_share_of(full, first, 2, False), bias, m, first,
+                          2, shared=False)
+        one = dict(layer_cfg, first_expert=first, experts_held=2,
+                   **{none_shared: 0})
         np.testing.assert_allclose(
             y, reference.expert_layer(_share_of(full, first, 2, False), m,
                                       bias, one), rtol=1e-4, atol=1e-5)
-        with_shared, _ = _program_layer(_share_of(full, first, 2, True),
-                                        bias, m, first, 2, shared=True)
+        with_shared, _ = layer_of(_share_of(full, first, 2, True), bias, m,
+                                  first, 2, shared=True)
         np.testing.assert_allclose(with_shared, np.asarray(y) + shared,
                                    rtol=1e-4, atol=1e-5)
         parts.append(np.asarray(y))
         held_slots += float(aux["slots_held"])
-        assert float(aux["slots_all"]) == 4 * 48
+        assert float(aux["slots_all"]) == top_k * 48
         assert float(aux["bias_spread"]) == pytest.approx(
             bias.max() - bias.min())
     np.testing.assert_allclose(sum(parts) + shared, want, rtol=1e-4,
                                atol=1e-5)
-    assert held_slots == 4 * 48
+    assert held_slots == top_k * 48
     assert np.abs(shared).max() > 0.1 and np.abs(sum(parts)).max() > 0.1
     # the uncut program layer is the same sum, and counts no share
-    y, aux = _program_layer(full, bias, m, 0, 16, shared=True)
+    y, aux = layer_of(full, bias, m, 0, 16, shared=True)
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     assert "slots_held" not in aux
     # the bias moved picks: without it the layer is another
     assert np.abs(np.asarray(reference.expert_layer(
-        full, m, np.zeros(16, np.float32), LAYER_CFG)) - want).max() > 0.01
+        full, m, np.zeros(16, np.float32), layer_cfg)) - want).max() > 0.01
 
 
 @pytest.mark.parametrize("first,held", [(0, 2), (6, 2), (14, 2), (0, 16)])
@@ -238,24 +258,29 @@ def test_a_shares_gradients_and_counts_match_the_references(first, held):
 
 
 # ------------------------------------------------ (c) the flash op's sizes
+@pytest.mark.parametrize("widths", [(16, 16), (192, 128)],
+                         ids=["one_width", "keys_192_values_128"])
 @pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel"])
 @pytest.mark.parametrize("t,window", [(64, 16), (64, None), (32, 48)])
 def test_flash_with_a_group_of_eight_matches_dense_masked_attention(
-        t, window, interpret):
+        t, window, interpret, widths):
     """8 query heads a K/V head (Trinity's group; SmallThinker's is seven)
     through the op's jnp path and its kernels in interpret mode, with a
     window a quarter of the sequence (as 2048 is of 8,192), without one, and
-    with one longer than the sequence: forward and all three gradients."""
+    with one longer than the sequence: forward and all three gradients; at
+    one width, and with keys of 192 beside values of 128 (latent attention's
+    widths, which its own model runs with neither a group nor a window)."""
     import jax
     import jax.numpy as jnp
     from raydp_tpu.ops.flash_attention import flash_attention
     from raydp_tpu.ops.ring_attention import dense_attention
 
     rng = np.random.default_rng(t + (window or 0))
-    q = jnp.asarray(rng.normal(size=(1, t, 16, 16)), jnp.float32)
-    k, v = (jnp.asarray(rng.normal(size=(1, t, 2, 16)), jnp.float32)
-            for _ in range(2))
-    w = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+    d, d_v = widths
+    q = jnp.asarray(rng.normal(size=(1, t, 16, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, t, 2, n)), jnp.float32)
+            for n in widths)
+    w = jnp.asarray(rng.normal(size=(1, t, 16, d_v)), jnp.float32)
     flash = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, window=window, block_q=16, block_k=16,
         interpret=interpret)

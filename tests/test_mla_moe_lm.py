@@ -1,0 +1,428 @@
+"""Latent-attention sparse-expert LM (``deepseek_v3``: kanana-2-30b-a3b): the
+flash op at two widths (keys of 192 beside values of 128), RoPE over
+interleaved pairs, ``LatentAttention`` alone, the parameter tree, the whole
+model's logits, loss, gradients and balancing bias through the estimator's
+train step, a recomputed latent block, and the older families' programs left
+as they were; against the plain reference
+(``chipbench/reference/kanana-2-30b-a3b.py``: float32 ``jax.numpy``, scores as
+the sum of two products, the rotation written out pair by pair), at small
+sizes on the CPU, seeded random weights. Widths are small here, and only
+here (``tests/chipbench_contract/test_chipbench_kanana_2.py`` keeps them).
+"""
+
+import copy
+import hashlib
+import os
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = "kanana-2-30b-a3b"
+
+# 4 heads of 16 + 8 beside 16 over a K/V latent of 24, the dense layer and
+# two expert layers, 16 experts of which experts 2-3 are held, 6 a token (as
+# published), two shared experts, 64 of 512 vocabulary rows, 32 positions
+TINY = {"hidden_size": 32, "num_attention_heads": 4, "num_key_value_heads": 4,
+        "kv_lora_rank": 24, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+        "qk_head_dim": 24, "head_dim": 8, "v_head_dim": 16,
+        "intermediate_size": 48, "moe_intermediate_size": 16,
+        "n_routed_experts": 16, "first_expert": 2, "experts_held": 2,
+        "vocab_size": 512, "vocab_rows_held": 64, "seq_len": 32,
+        "layers": 3, "layers_held": [0, 1, 2],
+        "compared_positions": 8, "compute_dtype": "float32",
+        "attention": "dense", "init_std": 0.3, "remat_blocks": False}
+F32_TOL = 2e-5
+
+
+def _files(**changed):
+    from chipbench import manifest
+    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
+    cfg.update(copy.deepcopy(TINY))
+    cfg["input"] = dict(cfg["input"], eos_id=63)
+    cfg.update(changed)
+    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
+            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
+
+
+def _leaves(tree):
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
+                     for k in path): np.asarray(v) for path, v in flat}
+
+
+def _close(got, want, tol=10 * F32_TOL):
+    got, want = _leaves(got), _leaves(want)
+    assert set(got) == set(want)
+    for name, g in got.items():
+        scale = max(np.abs(want[name]).max(), 1e-3)
+        assert np.abs(g - want[name]).max() <= tol * scale, name
+
+
+def _tokens(cfg, rows, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, cfg["vocab_rows_held"], (rows, cfg["seq_len"]), dtype=np.int32)
+
+
+def _variables(model, tokens, seed=0, bias_std=0.0):
+    """Seeded parameters and, ``bias_std``, seeded non-zero biases."""
+    import jax
+    from raydp_tpu.models.moe import STATE
+    v = jax.tree.map(np.array, model.init(jax.random.PRNGKey(seed),
+                                          tokens[:1]))
+    rng = np.random.default_rng(seed)
+    for block in v[STATE].values():
+        block["moe"]["bias"] = rng.normal(
+            0, bias_std, block["moe"]["bias"].shape).astype(np.float32)
+    return v["params"], v[STATE]
+
+
+def _train_step(model, tx, accum=1):
+    """The estimator's own train step round the model (not yet jitted), a
+    state for it, and its metrics."""
+    from flax.training import train_state
+    from raydp_tpu.train.flax_estimator import _make_apply, _make_train_step
+    from raydp_tpu.train.metrics import model_counters
+
+    class State(train_state.TrainState):
+        batch_stats: object = None
+
+    apply_fn = _make_apply(model, False, lambda b: (b["tokens"], b["tokens"]),
+                           None)
+    metrics = model_counters(model)
+    step = _make_train_step(apply_fn, None, metrics, accum, "none")
+
+    def create(params, state):
+        return State.create(apply_fn=model.apply, params=params, tx=tx,
+                            batch_stats=state)
+
+    def arguments(state, tokens):
+        return (state, {"tokens": tokens}, tuple(m.init() for m in metrics),
+                np.float32(0))
+    return step, create, arguments
+
+
+# --------------------------------------------- (b) RoPE over interleaved pairs
+def test_interleaved_rope_turns_pairs_2i_and_2i_plus_1():
+    """Against the rotation written out pair by pair; and the scores of
+    rotated queries against rotated keys do not change when the pairs of q
+    and k alike are first moved to the half-split layout and turned there
+    (what the family's own code does)."""
+    import jax.numpy as jnp
+    from raydp_tpu.models.transformer import rotary_embedding
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(2, 12, 3, 8)).astype(np.float32)
+    positions = jnp.arange(12)
+    got = np.asarray(rotary_embedding(jnp.asarray(x), positions, 1e4,
+                                      interleaved=True))
+    want = np.zeros_like(x)
+    for p in range(12):
+        for i in range(4):
+            angle = p * 1e4 ** (-i / 4)
+            a, b = x[:, p, :, 2 * i], x[:, p, :, 2 * i + 1]
+            want[:, p, :, 2 * i] = a * np.cos(angle) - b * np.sin(angle)
+            want[:, p, :, 2 * i + 1] = a * np.sin(angle) + b * np.cos(angle)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # off by default: the halves, as before
+    halves = np.asarray(rotary_embedding(jnp.asarray(x), positions, 1e4))
+    assert np.abs(halves - got).max() > 0.1
+    k = rng.normal(size=(2, 12, 3, 8)).astype(np.float32)
+    perm = np.r_[0:8:2, 1:8:2]          # interleaved -> half-split
+    turn = lambda a, inter: np.asarray(rotary_embedding(  # noqa: E731
+        jnp.asarray(a), positions, 1e4, interleaved=inter))
+    scores = np.einsum("bqhd,bkhd->bhqk", got, turn(k, True))
+    moved = np.einsum("bqhd,bkhd->bhqk", turn(x[..., perm], False),
+                      turn(k[..., perm], False))
+    np.testing.assert_allclose(moved, scores, rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------------------ (c) the sub-layer alone
+@pytest.mark.parametrize("q_rank", [None, 12], ids=["full_q", "q_latent"])
+@pytest.mark.parametrize("dtype,attention,tol", [
+    ("float32", "dense", 10 * F32_TOL), ("bfloat16", "flash", 0.03)])
+def test_latent_attention_matches_the_references(
+        dtype, attention, tol, q_rank, forward_flash_kernels):
+    import jax
+    import jax.numpy as jnp
+    from chipbench.harness import relative_rms_error
+    from raydp_tpu.models.transformer import LatentAttention
+
+    cfg, _, reference = _files(q_lora_rank=q_rank)
+    layer = LatentAttention(
+        4, 24, 16, 8, 16, q_rank, attention, None, jnp.dtype(dtype),
+        float(cfg["rope_theta"]), True, cfg["rms_norm_eps"], 0.3)
+    u = np.random.default_rng(1).normal(size=(2, 32, 32)).astype(np.float32)
+    variables = layer.init(jax.random.PRNGKey(1), u)
+    params = jax.tree.map(np.asarray, variables["params"])
+    params["kv_norm"]["scale"] = np.random.default_rng(2).uniform(
+        0.5, 1.5, 24).astype(np.float32)
+    names = {"kv_a", "kv_norm", "kv_b", "o"} | (
+        {"q"} if q_rank is None else {"q_a", "q_a_norm", "q_b"})
+    assert set(params) == names
+    got = layer.apply({"params": params}, jnp.asarray(u, jnp.dtype(dtype)))
+    assert got.dtype == jnp.dtype(dtype) and got.shape == u.shape
+    want = reference.latent_attention(params, u, cfg)
+    assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
+    # the latent's norm and the one shared rotary key are in the result
+    plain = dict(params, kv_norm={"scale": np.ones(24, np.float32)})
+    assert relative_rms_error(
+        reference.latent_attention(plain, u, cfg), want) > 0.01
+
+
+def test_latent_attention_takes_no_seq_axis_and_no_window():
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import TransformerLM
+    from raydp_tpu.models.transformer import LatentAttention
+    from raydp_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"seq": 2}, devices=jax.devices()[:2])
+    u = jnp.zeros((1, 8, 16))
+    layer = LatentAttention(2, 8, 8, 8, 8, mesh=mesh)
+    with pytest.raises(NotImplementedError, match="seq axis"):
+        layer.init(jax.random.PRNGKey(0), u)
+    windowed = TransformerLM(
+        vocab_size=16, dim=16, num_heads=2, num_layers=1, kv_lora_rank=8,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+        sliding_window=4, window_layers=(1,))
+    with pytest.raises(ValueError, match="no window"):
+        windowed.init(jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+
+
+# ----------------------------------------------------- (d) the whole model
+def test_the_parameter_tree_is_the_published_layers():
+    """Names and shapes at the tiny widths; and at the PUBLISHED widths, by
+    ``jax.eval_shape`` (nothing is allocated), a layer's attention is
+    26,345,984 parameters and its two norms 4,096."""
+    import jax
+    cfg, pipeline, _ = _files()
+    model = pipeline.build_model(cfg)
+    params, state = _variables(model, _tokens(cfg, 1))
+    shapes = {k: v.shape for k, v in _leaves(params).items()}
+    attn = {"attn/q/kernel": (32, 4, 24), "attn/kv_a/kernel": (32, 32),
+            "attn/kv_norm/scale": (24,), "attn/kv_b/kernel": (24, 4, 32),
+            "attn/o/kernel": (4, 16, 32), "ln1/scale": (32,),
+            "ln2/scale": (32,)}
+    want = {f"block_0/{k}": v for k, v in attn.items()}
+    want.update({"block_0/gate/kernel": (32, 48),
+                 "block_0/up/kernel": (32, 48),
+                 "block_0/down/kernel": (48, 32)})
+    assert {k: v for k, v in shapes.items() if k.startswith("block_0/")} \
+        == want
+    want = {f"block_2/{k}": v for k, v in attn.items()}
+    want.update({"block_2/moe/router": (32, 16),
+                 "block_2/moe/experts_gate": (2, 32, 16),
+                 "block_2/moe/experts_up": (2, 32, 16),
+                 "block_2/moe/experts_down": (2, 16, 32),
+                 # two shared experts as one MLP of width 2 x 16
+                 "block_2/moe/shared_gate/kernel": (32, 32),
+                 "block_2/moe/shared_up/kernel": (32, 32),
+                 "block_2/moe/shared_down/kernel": (32, 32)})
+    assert {k: v for k, v in shapes.items() if k.startswith("block_2/")} \
+        == want
+    assert {k: v.shape for k, v in _leaves(state).items()} == {
+        f"block_{i}/moe/{name}": (16,) for i in (1, 2)
+        for name in ("bias", "counts")}
+    assert model.attention_layers == {"window": 0, "full": 3, "latent": 3}
+    assert [model._sparse(i) for i in range(3)] == [0, 1, 1]
+
+    from chipbench import manifest
+    published = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
+    big = pipeline.build_model(dict(published, layers=2, layers_held=[0, 1],
+                                    vocab_rows_held=8))
+    tree = jax.eval_shape(lambda: big.init(
+        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)))["params"]
+    count = lambda t: sum(int(np.prod(x.shape))  # noqa: E731
+                          for x in jax.tree.leaves(t))
+    block = tree["block_1"]
+    assert count(block["attn"]) == 26345984
+    assert count(block["ln1"]) + count(block["ln2"]) == 4096
+    assert block["attn"]["q"]["kernel"].shape == (2048, 32, 192)
+    assert block["attn"]["kv_a"]["kernel"].shape == (2048, 576)
+    assert block["attn"]["kv_b"]["kernel"].shape == (512, 32, 256)
+    assert block["attn"]["o"]["kernel"].shape == (32, 128, 2048)
+    assert count(block["moe"]) == 262144 + 9437184 + 16 * 4718592
+    assert count(tree["block_0"]) == 64098816
+
+
+@pytest.mark.parametrize("dtype,attention,tol", [
+    ("float32", "dense", 10 * F32_TOL), ("float32", "flash", 10 * F32_TOL),
+    ("bfloat16", "flash", 0.1)])
+def test_forward_logits_match_the_reference(dtype, attention, tol,
+                                            forward_flash_kernels):
+    """What check (a) compares, with biases that move picks: float32 to
+    rounding on the dense path and through the kernels (interpreted, keys of
+    24 beside values of 16); bfloat16 inside what near-tied picks cost."""
+    from chipbench.harness import relative_rms_error
+    cfg, pipeline, reference = _files(compute_dtype=dtype,
+                                      attention=attention)
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 2, seed=5)
+    params, state = _variables(model, tokens, bias_std=0.1)
+    variables = {"params": params, "batch_stats": state}
+    got = pipeline.compared(model.apply(variables, tokens), cfg)
+    want = reference.forward(variables, tokens, cfg)
+    assert got.shape == want.shape == (2, 8, 64)
+    assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
+    # the biases matter to the outputs compared
+    zero = reference.forward({"params": params}, tokens, cfg)
+    assert relative_rms_error(zero, want) > 100 * F32_TOL
+
+
+@pytest.mark.parametrize("remat,attention,forward", [
+    (False, "dense", "once"), (True, "dense", "twice"),
+    (True, "flash", "once")],
+    ids=["kept", "recomputed", "recomputed_flash"])
+def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
+        remat, attention, forward, forward_flash_kernels):
+    """The model's own loss (fused head over the rows held, no auxiliary
+    loss) and the gradient of every leaf, with seeded biases; then three
+    optimizer steps of the estimator's train step: after each, every expert
+    layer's bias is the reference's ``next_bias`` of the slots ALL experts
+    were picked for in the step's tokens, and the counts are empty again."""
+    import jax
+    import optax
+    cfg, pipeline, reference = _files(remat_blocks=remat, attention=attention)
+    model = pipeline.build_model(cfg)
+    assert model.attention_forward == {forward: 3}
+    tokens = _tokens(cfg, 4, seed=1)
+    params, state = _variables(model, tokens, bias_std=0.1)
+    w = np.full(4, 0.25, np.float32)
+    (loss, counts), grads = jax.value_and_grad(
+        lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
+                              tokens, w, method=model.loss_rows),
+        has_aux=True)(params)
+    want_loss, want_grads = jax.jit(jax.value_and_grad(
+        lambda p, t: reference.loss(p, state, t, cfg)))(params, tokens)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
+    counts_of = jax.jit(lambda p, st, t: reference.slot_counts(p, st, t, cfg))
+    picked = np.stack(counts_of(params, state, tokens))
+    assert float(counts[1]) == tokens.size * 6 * 2      # top-6, two layers
+    assert float(counts[0]) == picked.max(axis=1).sum()
+    assert float(counts[2]) == picked[:, 2:4].sum() < float(counts[1])
+
+    step, create, arguments = _train_step(model, optax.sgd(0.05))
+    run = jax.jit(step)
+    now = create(params, state)
+    bias = {name: b["moe"]["bias"] for name, b in state.items()}
+    for i in range(3):
+        batch = _tokens(cfg, 4, seed=10 + i)
+        before = jax.tree.map(np.asarray, (now.params, now.batch_stats))
+        now, _, _ = run(*arguments(now, batch))
+        for (name, b), c in zip(sorted(bias.items()),
+                                counts_of(*before, batch)):
+            assert float(np.sum(c)) == batch.size * 6
+            bias[name] = np.asarray(reference.next_bias(b, c, cfg))
+            got = now.batch_stats[name]["moe"]
+            np.testing.assert_allclose(got["bias"], bias[name], rtol=0,
+                                       atol=1e-7)
+            assert not np.any(np.asarray(got["counts"]))
+    assert any(np.abs(bias[n] - state[n]["moe"]["bias"]).max() > 1e-3
+               for n in bias)
+
+
+# --------------------------------------------- (g) a recomputed latent block
+def test_a_recomputed_latent_block_keeps_its_kernels_pair(
+        forward_flash_kernels):
+    """Loss and gradients are the unrecomputed model's; the built step holds
+    ONE forward kernel a layer (q, k and v are formed again from the normed
+    input, the kernel's output and row sums are kept), its operands keys of
+    24 beside values of 16 with nothing padded, and counts its layers
+    ``once`` and as ``latent``."""
+    import jax
+    import optax
+    from raydp_tpu import metrics as registry
+
+    def built(remat):
+        cfg, pipeline, _ = _files(remat_blocks=remat, attention="flash")
+        return cfg, pipeline.build_model(cfg)
+
+    cfg, plain = built(False)
+    _, recomputed = built(True)
+    tokens = _tokens(cfg, 2, seed=2)
+    params, state = _variables(plain, tokens, bias_std=0.1)
+    w = np.full(2, 0.5, np.float32)
+
+    def value_and_grad(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
+                                  tokens, w, method=model.loss_rows)[0]))(
+                                      params)
+
+    loss, grads = value_and_grad(recomputed)
+    want_loss, want_grads = value_and_grad(plain)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
+
+    counted = lambda name: dict(  # noqa: E731
+        registry.snapshot()["counters"].get(name, {}))
+    names = ("train_attention_layers_total", "train_attention_forward_total")
+    before = [counted(n) for n in names]
+    step, create, arguments = _train_step(recomputed, optax.sgd(0.05))
+    moved = [{k: v - b.get(k, 0) for k, v in counted(n).items()
+              if v != b.get(k, 0)} for n, b in zip(names, before)]
+    assert moved == [{"full": 3, "latent": 3}, {"once": 3}]
+    program = str(jax.make_jaxpr(step)(*arguments(create(params, state),
+                                                  tokens)))
+    assert forward_flash_kernels(program) == 3
+    # q and k [B * H, T, 24], v [B * H, T, 16] -> the output at 16
+    assert "f32[8,32,24]" in program and "f32[8,32,16]" in program
+    assert "f32[8,32,32]" not in program
+
+
+# ------------------------------------------------------- (h) older models
+# sha256 of the estimator's train step as jax lowers it (the StableHLO text,
+# no source locations; the flash kernels interpreted in blocks of 16, so
+# their bodies are in it) for the three older families' CPU cuts, computed
+# on the commit before latent attention (74af897) with ``_step_text``. A PR
+# that means to change one of these programs replaces its line.
+PARENT_STEP = {
+    "olmoe-1b-7b":
+        "fc12cbdf792919544024982de7e9d345a78459791d3896cc61eac78be79ff455",
+    "smallthinker-21b-a3b":
+        "aefcfbb2077debbcbb85e8fc68073132dda201b3ab37a84faba744a89dab4de6",
+    "trinity-mini":
+        "a5b10012347c358426747cfe0c06271ce5c5217cbbd0bc9851e9e8cc47b50fb3",
+}
+
+
+def _step_text(config, cell):
+    import jax
+    import optax
+    from chipbench import manifest
+    cfg = manifest.load_json(ROOT, "configs", f"{config}.json")
+    pipeline = manifest.load_module(ROOT, "pipelines", f"{config}.py")
+    wl = manifest.load_json(ROOT, "workloads", f"{cell}.json")
+    pipeline.cpu_cut(cfg, wl, 1)
+    model = pipeline.build_model(cfg)
+    tokens = np.zeros((1, wl["seq_len"]), np.int32)
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
+                                               tokens[:, :8]))
+    step, create, arguments = _train_step(model, optax.sgd(0.05))
+    state = jax.eval_shape(lambda: create(
+        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes["params"]),
+        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype),
+                     shapes.get("batch_stats"))))
+    return (model, jax.jit(step).lower(*arguments(state, tokens)).as_text(),
+            shapes["params"]["block_0"]["attn"])
+
+
+@pytest.mark.parametrize("config,cell", [
+    ("olmoe-1b-7b", "olmoe_1b7b_train"),
+    ("smallthinker-21b-a3b", "smallthinker_21ba3b_16k_train"),
+    ("trinity-mini", "trinity_mini_8k_train")])
+def test_an_older_familys_step_is_the_parents_text(config, cell,
+                                                   forward_flash_kernels):
+    """Every latent option is off by default, the blocks build ``Attention``
+    as it was, and a flash call with one width lowers to the text it lowered
+    to before the kernels took two."""
+    model, text, attn = _step_text(config, cell)
+    assert (model.kv_lora_rank, model.q_lora_rank, model.qk_nope_head_dim,
+            model.qk_rope_head_dim, model.v_head_dim,
+            model.rope_interleave) == (None,) * 5 + (False,)
+    assert "latent" not in model.attention_layers
+    assert {"q", "k", "v", "o"} <= set(attn) and "kv_a" not in attn
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]

@@ -56,7 +56,8 @@ def _close(got, want, tol=10 * F32_TOL):
 
 
 # ------------------------------------------------- (a) the flash op
-# (T, window, block_q, block_k, query heads, K/V heads)
+# (T, window, block_q, block_k, query heads, K/V heads[, keys' width,
+# values' width: 16 and 16 where not given])
 FLASH_CASES = {
     "t_below_the_window": (32, 48, 16, 16, 4, 2),
     "t_at_the_window": (32, 32, 16, 16, 4, 2),
@@ -69,16 +70,21 @@ FLASH_CASES = {
     "q_blocks_wider": (128, 32, 32, 16, 4, 2),
     "window_of_one": (64, 1, 16, 16, 4, 2),
     "as_before": (64, None, 16, 16, 4, 4),
+    # latent attention's two widths: keys and queries of 192, values of 128
+    "keys_192_values_128": (64, None, 16, 16, 4, 4, 192, 128),
+    "keys_192_values_128_window": (64, 16, 16, 16, 4, 4, 192, 128),
+    "keys_192_values_128_group": (64, None, 16, 16, 4, 2, 192, 128),
+    "keys_192_values_128_window_group": (64, 24, 16, 32, 6, 2, 192, 128),
 }
 
 
-def _qkv(t, h, hk, d=16, seed=0):
+def _qkv(t, h, hk, d=16, seed=0, d_v=None):
     import jax
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     return (jax.random.normal(ks[0], (2, t, h, d)),
             jax.random.normal(ks[1], (2, t, hk, d)),
-            jax.random.normal(ks[2], (2, t, hk, d)),
-            jax.random.normal(ks[3], (2, t, h, d)))
+            jax.random.normal(ks[2], (2, t, hk, d_v or d)),
+            jax.random.normal(ks[3], (2, t, h, d_v or d)))
 
 
 @pytest.mark.parametrize("interpret", [False, True],
@@ -87,20 +93,22 @@ def _qkv(t, h, hk, d=16, seed=0):
 def test_flash_with_window_and_grouped_kv_matches_dense_masked_attention(
         case, interpret):
     """Forward and all three gradients; dK and dV are the sums over a
-    group's query heads."""
+    group's query heads, each at its own width."""
     import jax
     import jax.numpy as jnp
     from raydp_tpu.ops.flash_attention import flash_attention
     from raydp_tpu.ops.ring_attention import dense_attention
 
-    t, window, blk_q, blk_k, h, hk = FLASH_CASES[case]
-    q, k, v, w = _qkv(t, h, hk)
+    t, window, blk_q, blk_k, h, hk, d, d_v = (FLASH_CASES[case]
+                                              + (16, 16))[:8]
+    q, k, v, w = _qkv(t, h, hk, d, d_v=d_v)
     got = jax.value_and_grad(lambda *a: jnp.sum(flash_attention(
         *a, window=window, block_q=blk_q, block_k=blk_k,
         interpret=interpret) * w), (0, 1, 2))(q, k, v)
     want = jax.value_and_grad(lambda *a: jnp.sum(dense_attention(
         *a, window=window) * w), (0, 1, 2))(q, k, v)
-    assert got[1][1].shape == (2, t, hk, 16)
+    assert got[1][1].shape == (2, t, hk, d)
+    assert got[1][2].shape == (2, t, hk, d_v)
     for g, x in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, x, rtol=2e-4, atol=2e-4)
 
@@ -111,6 +119,8 @@ def test_flash_with_window_and_grouped_kv_matches_dense_masked_attention(
 # wide
 EDGE_CASES = {
     "full_causal": (1024, None, 512, 512),
+    "full_causal_keys_24_values_16": (1024, None, 512, 512, 24),
+    "window_keys_24_values_16": (2048, 1024, 512, 512, 24),
     "window_a_multiple_of_the_block": (2048, 1024, 512, 512),
     "window_of_two_small_blocks": (1024, 512, 256, 256),
     "window_no_multiple_whole_edges": (1024, 1000, 256, 256),
@@ -131,12 +141,14 @@ def test_the_kernels_follow_the_masks_two_edges(case, heads):
     from raydp_tpu.ops import flash_attention as fa
     from raydp_tpu.ops.ring_attention import dense_attention
 
-    t, window, blk_q, blk_k = EDGE_CASES[case]
+    t, window, blk_q, blk_k, d = (EDGE_CASES[case] + (16,))[:5]
     assert (fa._tile(blk_q, blk_k, window) is None) == ("whole" in case)
     h, hk = heads
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    q, w = (jax.random.normal(k, (1, t, h, 16)) for k in ks[:2])
-    k, v = (jax.random.normal(k, (1, t, hk, 16)) for k in ks[2:])
+    q, k = (jax.random.normal(key, (1, t, n, d))
+            for key, n in zip(ks[:2], heads))
+    w, v = (jax.random.normal(key, (1, t, n, 16))
+            for key, n in zip(ks[2:], heads))
     got = jax.value_and_grad(lambda *a: jnp.sum(fa.flash_attention(
         *a, window=window, block_q=blk_q, block_k=blk_k,
         interpret=True) * w), (0, 1, 2))(q, k, v)
