@@ -25,9 +25,19 @@ sets the op's module-level rules aside for a measurement (``_tile``, ``_walk``,
 ``--v-dim`` gives v and the output a width of their own (latent attention:
 ``--dim 192 --v-dim 128``); ``--blocks`` names the shapes to time.
 
+``--backward`` times a layer's backward alone at the default blocks
+(``_bwd_pallas`` on the residuals of one forward, chained on its cotangent),
+one line each for the one kernel that holds a K/V head's dk and dv in VMEM and
+for the pair of kernels, whichever of them the shape rule
+(``_fused_backward_fits``) would take: the rule is set aside for a
+measurement, the op has no argument for it. A form the compiler refuses is a
+line that says so.
+
 Run: python benchmarks/flash_block_sweep.py [--seq-len 8192] [--dim 128]
      python benchmarks/flash_block_sweep.py --edges --grad --batch 2 \
          --seq-len 8192 --heads 32 --kv-heads 4 --window 2048   # a Trinity layer
+     python benchmarks/flash_block_sweep.py --backward --seq-len 16384 \
+         --heads 32 --dim 192 --v-dim 128                       # a kanana-2 layer
 """
 
 from __future__ import annotations
@@ -58,6 +68,8 @@ def main():
     ap.add_argument("--window", type=int, default=None)
     ap.add_argument("--edges", action="store_true",
                     help="time the paths by the mask's edges, not the blocks")
+    ap.add_argument("--backward", action="store_true",
+                    help="time the backward alone: one kernel, and the pair")
     args = ap.parse_args()
 
     import jax
@@ -118,20 +130,10 @@ def main():
                            length=iters)[0]
             return out.astype(jnp.float32).sum()
 
-        float(chained(q))                    # compile + warm
-        walls = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            float(chained(q))
-            walls.append((time.perf_counter() - t0) * 1e3)
-        wall = min(walls)
-        per_iter = (wall - rtt) / iters
-        if per_iter <= 0:
-            raise RuntimeError(
-                f"measurement below timing noise (wall {wall:.1f} ms <= RTT "
-                f"{rtt:.1f} ms) — raise --iters or --seq-len")
-        return per_iter
+        return per_iter_ms(lambda: chained(q), rtt, iters)
 
+    if args.backward:
+        return backward(fa, (q, k, v), rtt, args)
     what = "fwd+bwd" if args.grad else "fwd"
     if args.edges:
         return edges(fa, timed, what, args)
@@ -167,6 +169,25 @@ def main():
           f"{jax.devices()[0].device_kind}; refused: {refused or 'none'}")
 
 
+def per_iter_ms(chain, rtt: float, iters: int) -> float:
+    """Milliseconds an application of ``chain()``, which runs ``iters`` of
+    them and returns a scalar: the first call compiles and warms, the least
+    wall of three more, less one round trip, is the measurement."""
+    float(chain())
+    walls = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        float(chain())
+        walls.append((time.perf_counter() - t0) * 1e3)
+    wall = min(walls)
+    per_iter = (wall - rtt) / iters
+    if per_iter <= 0:
+        raise RuntimeError(
+            f"measurement below timing noise (wall {wall:.1f} ms <= RTT "
+            f"{rtt:.1f} ms) — raise --iters or --seq-len")
+    return per_iter
+
+
 def edges(fa, timed, what, args):
     """The kernels' paths by the mask's edges at the default blocks, each
     timed under the op's rules set aside for it."""
@@ -199,6 +220,55 @@ def edges(fa, timed, what, args):
         print(f"{name:46s} {us:9.1f} us/{what}  (B={args.batch} "
               f"T={args.seq_len} H={args.heads}/{args.kv_heads or args.heads}"
               f" D={args.dim} window={args.window})", flush=True)
+
+
+def backward(fa, qkv, rtt, args):
+    """A layer's backward alone, as one kernel and as the pair."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    b, t, h, d = qkv[0].shape
+    to3 = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        b * x.shape[2], t, x.shape[3])
+    q3, k3, v3 = map(to3, qkv)
+    rules = dict(scale=d ** -0.5, causal=True, blk_q=fa.DEFAULT_BLOCK_Q,
+                 blk_k=fa.DEFAULT_BLOCK_K, interpret=False,
+                 window=args.window)
+    res = (q3, k3, v3) + tuple(jax.jit(
+        lambda *a: fa._fwd_pallas(*a, **rules))(q3, k3, v3))
+    fits = fa._fused_backward_fits
+    held = fa._fused_resident_bytes(t, d, v3.shape[2], k3.dtype)
+
+    def one(res, g):
+        dq, dk, dv = fa._bwd_pallas(res, g, **rules)
+        # every gradient enters the next cotangent as one number
+        kept = sum(x.astype(jnp.float32).mean() for x in (dq, dk, dv))
+        return g + (kept * 1e-3).astype(g.dtype)
+
+    @jax.jit
+    def chained(res, g):
+        out = lax.scan(lambda c, _: (one(res, c), ()), g, None,
+                       length=args.iters)[0]
+        return out.astype(jnp.float32).sum()
+
+    g = jnp.ones_like(res[3])
+    for name, rule in (("one kernel (dk, dv held in VMEM)", lambda *a: True),
+                       ("the pair of kernels", lambda *a: False)):
+        fa._fused_backward_fits = rule
+        jax.clear_caches()
+        try:
+            ms = per_iter_ms(lambda: chained(res, g), rtt, args.iters)
+            said = f"{ms:9.3f} ms/bwd"
+        except jax.errors.JaxRuntimeError as e:   # the compiler said no
+            said = f"REFUSED ({str(e)[:400]})"
+        finally:
+            fa._fused_backward_fits = fits
+        print(f"{name:34s} {said}  (B={b} T={t} H={h}/{k3.shape[0] // b} "
+              f"D={d}/{v3.shape[2]} window={args.window}; a head holds "
+              f"{held / 2**20:.0f} MiB, the rule takes "
+              f"{'one' if fits(t, d, v3.shape[2], k3.dtype) else 'the pair'})",
+              flush=True)
 
 
 if __name__ == "__main__":

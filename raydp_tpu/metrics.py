@@ -382,10 +382,19 @@ _ALL_METRICS = [
        "output projection runs again). Absent where no block is recomputed "
        "or no norm reads them. doc/long_context.md.",
        label="outputs"),
+    _m("flash_backward_total", COUNTER, "1", "training",
+       "Backward passes of the flash-attention kernels, counted where one "
+       "is built (a layer call each), by what it is made of: `fused` (one "
+       "kernel: the scores and dP formed once a block pair, a K/V head's "
+       "dk and dv held in VMEM over its walk) or `split` (two kernels, the "
+       "scores and dP formed in each: a sequence whose head's gradients do "
+       "not fit, `FUSED_BWD_RESIDENT_BYTES`). ops/flash_attention.py.",
+       label="kernels"),
     _m("flash_blocks_total", COUNTER, "1", "training",
        "(q block, k block) pairs of the flash-attention kernels, counted "
-       "where a kernel's grid is built (once a built forward kernel, twice "
-       "a built backward pair; heads x pairs): `computed`, `skipped_causal` "
+       "where a kernel's grid is built (once a built forward kernel, once "
+       "a built one-kernel backward, twice a built backward pair; heads x "
+       "pairs): `computed`, `skipped_causal` "
        "(wholly above the diagonal) and `skipped_window` (wholly behind the "
        "window: never fetched). ops/flash_attention.py.",
        label="fate"),
@@ -575,7 +584,8 @@ _ALL_SPANS = [
     _s("attn", "model",
        "A transformer block's attention (`models/transformer.py`): the "
        "projections, QK-norm, RoPE and the flash kernels "
-       "(`rdt_flash_fwd`, `rdt_flash_bwd_dkdv`, `rdt_flash_bwd_dq`).",
+       "(`rdt_flash_fwd`, `rdt_flash_bwd_dkdv_dq`; `rdt_flash_bwd_dkdv` and "
+       "`rdt_flash_bwd_dq` where the backward takes two).",
        kind=SCOPE),
     _s("attn_full", "model",
        "Under `attn`: the attention itself (the flash kernels or their jnp "
@@ -583,8 +593,9 @@ _ALL_SPANS = [
        kind=SCOPE),
     _s("attn_window", "model",
        "Under `attn`: the attention itself of a sliding-window layer (the "
-       "kernels `rdt_flash_win_fwd`, `rdt_flash_win_bwd_dkdv`, "
-       "`rdt_flash_win_bwd_dq`).", kind=SCOPE),
+       "kernels `rdt_flash_win_fwd`, `rdt_flash_win_bwd_dkdv_dq`; "
+       "`rdt_flash_win_bwd_dkdv` and `rdt_flash_win_bwd_dq` where the "
+       "backward takes two).", kind=SCOPE),
     _s("attn/latent", "model",
        "latent attention's K/V path inside an `attn` scope: the "
        "down-projection to the K/V latent and the one rotary key all heads "

@@ -7,10 +7,15 @@ lives in VMEM scratch and carries across k steps — the [T, T] score matrix
 never exists, each program touches one ``[blk_q, D] × [blk_k, D]`` tile pair on
 the MXU. The kernel also emits the log-sum-exp per query row, which makes the
 backward pass a pure recompute: ``custom_vjp`` re-forms each score block from
-(Q, K, LSE). On TPU the backward is two Pallas kernels (dk/dv walking q
-blocks, dq walking k blocks, both with the causal block skip); elsewhere a
-blockwise ``lax.scan`` computes the same math — memory stays O(T·blk) in both
-directions.
+(Q, K, LSE). On TPU the backward is one Pallas kernel (``_bwd_fused_kernel``):
+it walks the visible block pairs once, q blocks outermost, forms the scores
+and dP once a pair and adds to all three gradients from them — dq in a q
+block's scratch, dk and dv into a whole K/V head's float32 accumulators, which
+stay in VMEM over the head's walk and are written once. A sequence too long
+for a head's gradients to be held there (``FUSED_BWD_RESIDENT_BYTES``) takes
+two kernels instead (dk/dv walking q blocks, dq walking k blocks: the scores
+and dP formed twice); elsewhere a blockwise ``lax.scan`` computes the same
+math — HBM stays O(T·blk) in both directions.
 
 Three options follow a published layer. ``window`` (with ``causal``): a query
 attends to itself and the ``window - 1`` keys before it. The kernels then walk
@@ -19,7 +24,7 @@ a block's band has blocks (five 1024-blocks for a window of 4096 at any
 length); blocks above the diagonal and blocks wholly behind the window are
 never fetched or computed. Grouped-query heads: K and V may hold fewer heads
 than Q (``H = G * Hk``); a K/V head is read by its ``G`` query heads from
-where it lies, and the dK/dV kernel sums a group's query heads in VMEM. Two
+where it lies, and the backward sums a group's query heads in VMEM. Two
 widths: V, the output and its cotangent may be ``Dv`` wide beside Q and K of
 ``D`` (latent attention: 128 beside 192), every block at its own width.
 
@@ -47,6 +52,7 @@ the sequence-sharded path uses :mod:`raydp_tpu.ops.ring_attention` instead.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -60,13 +66,28 @@ from jax import lax
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 _NEG_INF = -1e30
-#: the three kernels' names as a device trace shows them (forward, the
-#: backward's dk/dv walk, its dq walk): what a roofline reader sums
-KERNEL_NAMES = ("rdt_flash_fwd", "rdt_flash_bwd_dkdv", "rdt_flash_bwd_dq")
-#: the same three of a call with a window, so that a trace tells a windowed
+#: the kernels' names as a device trace shows them (forward; the split
+#: backward's dk/dv walk and its dq walk; the one-kernel backward): what a
+#: roofline reader sums. A layer's backward is the last, or the two before it
+#: where a K/V head's gradients do not fit (``_fused_backward_fits``)
+KERNEL_NAMES = ("rdt_flash_fwd", "rdt_flash_bwd_dkdv", "rdt_flash_bwd_dq",
+                "rdt_flash_bwd_dkdv_dq")
+#: the same four of a call with a window, so that a trace tells a windowed
 #: layer's events from a full one's
 WINDOW_KERNEL_NAMES = ("rdt_flash_win_fwd", "rdt_flash_win_bwd_dkdv",
-                       "rdt_flash_win_bwd_dq")
+                       "rdt_flash_win_bwd_dq", "rdt_flash_win_bwd_dkdv_dq")
+#: what the one-kernel backward may hold in VMEM for a K/V head's dk and dv:
+#: float32 accumulators [T, D] and [T, Dv] and the two out blocks the
+#: pipeline keeps of each, T * (D + Dv) * (4 + 2 * itemsize) bytes with each
+#: width rounded up to 128 lanes. A v5e has 128 MiB; a step's own blocks and
+#: its [blk_q, blk_k] float32 temporaries take under the 16 MiB scoped
+#: default (the split kernels compile inside it), and ``_VMEM_WORKING_BYTES``
+#: is what the call asks for on top of the held bytes. In bfloat16: 16,384
+#: positions at 192/128 hold 48 MiB, at 128/128 32 MiB; 32,768 at 128/128
+#: hold 64 MiB; 32,768 at 192/128 (96 MiB) and 65,536 at 128/128 (128 MiB)
+#: take the two kernels.
+FUSED_BWD_RESIDENT_BYTES = 80 << 20
+_VMEM_WORKING_BYTES = 32 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -338,9 +359,11 @@ def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool,
 
 # ---------------------------------------------------------------------------
 # Pallas backward kernels: recompute p from (q, k, lse), causal block skip.
-# Split in the standard way — one kernel accumulates dk/dv walking q blocks,
-# one accumulates dq walking k blocks — so each output block is written once
-# and all accumulation stays in VMEM scratch.
+# One kernel walks the pairs once and holds a K/V head's dk and dv in VMEM
+# (`_bwd_fused_kernel`). The two before it split the work in the standard
+# way — one accumulates dk/dv walking q blocks, one dq walking k blocks — so
+# each output block is written once from a block's scratch: the path of a
+# sequence whose head does not fit.
 # ---------------------------------------------------------------------------
 def _keep_causal(qi, ki, blk_q: int, blk_k: int,
                  window: Optional[int] = None):
@@ -466,24 +489,196 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
+def _held(ki, cols: slice, blk_k: int):
+    """Where the k rows ``cols`` of k block ``ki`` lie in a K/V head's held
+    gradients."""
+    from jax.experimental import pallas as pl
+
+    start, stop, _ = cols.indices(blk_k)
+    return pl.ds(pl.multiple_of(ki * blk_k + start, math.gcd(blk_k, start)),
+                 stop - start)
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                      *, scale: float, causal: bool, blk_q: int, blk_k: int,
+                      window: Optional[int] = None, steps: int = 0):
+    """Grid ``(K/V head, query head of its group, q block, step)``: dq is a q
+    block's walk as in ``_bwd_dq_kernel``; dk and dv of the whole K/V head
+    gather every pair's share in ``dk_scr`` / ``dv_scr`` ([T, D], [T, Dv])
+    from the head's first step to its last."""
+    from jax.experimental import pallas as pl
+
+    g, qi, j = (pl.program_id(axis) for axis in (1, 2, 3))
+    last_g, last_qi, last_j = (pl.num_programs(axis) - 1
+                               for axis in (1, 2, 3))
+    ki, in_band = _k_step(qi, j, blk_q=blk_q, blk_k=blk_k, window=window,
+                          steps=steps)
+
+    def _k_blocks(fn):
+        """``fn`` on each k block's rows of the held gradients in turn: a
+        head's are tens of MiB, a block of them a value the size of a step's
+        own."""
+        def one(i, _):
+            fn(pl.ds(pl.multiple_of(i * blk_k, blk_k), blk_k))
+        lax.fori_loop(0, dk_scr.shape[0] // blk_k, one, None)
+
+    @pl.when((g == 0) & (qi == 0) & (j == 0))
+    def _init_head():
+        def zero(at):
+            dk_scr[at] = jnp.zeros((blk_k, dk_scr.shape[1]), jnp.float32)
+            dv_scr[at] = jnp.zeros((blk_k, dv_scr.shape[1]), jnp.float32)
+        _k_blocks(zero)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def _body(edge):
+        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
+            q, do = q_ref[0, rows], do_ref[0, rows]         # [rows, D]
+            dq = dq_scr[rows]
+            for cols, keep in pieces:
+                k = k_ref[0, cols]                          # [cols, D]
+                p, ds = _recompute_p_ds(
+                    q, k, v_ref[0, cols], do, lse_ref[0, 0, rows],
+                    delta_ref[0, 0, rows], keep, scale=scale)
+                ds = ds.astype(k.dtype)
+                dq = lax.add(dq, lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                at = _held(ki, cols, blk_k)
+                dv_scr[at] = lax.add(dv_scr[at], lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                dk_scr[at] = lax.add(dk_scr[at], lax.dot_general(
+                    ds, q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            dq_scr[rows] = dq
+
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
+
+    @pl.when(j == last_j)
+    def _finalize():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when((g == last_g) & (qi == last_qi) & (j == last_j))
+    def _finalize_head():
+        def store(at):
+            dk_ref[0, at] = dk_scr[at].astype(dk_ref.dtype)
+            dv_ref[0, at] = dv_scr[at].astype(dv_ref.dtype)
+        _k_blocks(store)
+
+
+def _fused_resident_bytes(t: int, d: int, d_v: int, dtype) -> int:
+    """What ``_bwd_fused_kernel`` holds in VMEM for one K/V head's dk and dv
+    (the arithmetic of ``FUSED_BWD_RESIDENT_BYTES``; VMEM lays a row out in
+    whole tiles of 128 lanes, so a width of 192 takes 256)."""
+    lanes = sum(-(-width // 128) * 128 for width in (d, d_v))
+    return t * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _fused_backward_fits(t: int, d: int, d_v: int, dtype) -> bool:
+    """The shape rule: one backward kernel where a K/V head's gradients fit
+    the budget, the pair of kernels where they do not."""
+    return _fused_resident_bytes(t, d, d_v, dtype) <= FUSED_BWD_RESIDENT_BYTES
+
+
 def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
                 blk_k: int, interpret: bool, window: Optional[int] = None):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    from raydp_tpu import metrics as rdt_metrics
 
     q3, k3, v3, out, lse = res
     (bh, t, d), d_v = q3.shape, v3.shape[2]
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(bh, 1, t)
+    fused = _fused_backward_fits(t, d, d_v, k3.dtype)
+    rdt_metrics.inc("flash_backward_total", label="fused" if fused
+                    else "split")
+    _count_blocks(1 if fused else 2, bh, t, blk_q, blk_k, window, causal)
+    return (_bwd_fused if fused else _bwd_split)(
+        q3, k3, v3, g, lse.reshape(bh, 1, t), delta, scale=scale,
+        causal=causal, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
+        window=window)
+
+
+def _bwd_fused(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
+               blk_q: int, blk_k: int, interpret: bool,
+               window: Optional[int]):
+    """dq, dk, dv from one kernel: see ``_bwd_fused_kernel``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (bh, t, d), d_v = q3.shape, v3.shape[2]
     bkv = k3.shape[0]
     group = bh // bkv
-    do = g
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, t)
-    lse3 = lse.reshape(bh, 1, t)
+    k_steps, _ = _band_steps(t, blk_q, blk_k, window)
+    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
+    vma = jax.typeof(q3).vma
+
+    def of_head(index_map):
+        """A map of ``_maps``' grid on this one, which splits the query head
+        into (its K/V head, its place in the group)."""
+        return lambda h, g, qi, j: index_map(h * group + g, qi, j)
+
+    q_map, kv_map, row_map = map(of_head, _maps(group, band))
+
+    def held(h, g, qi, j):     # one block a K/V head: it stays over the walk
+        return (h, 0, 0)
+
+    return tuple(pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
+                          **band),
+        grid=(bkv, group, t // blk_q, k_steps),
+        in_specs=[
+            pl.BlockSpec((1, blk_q, d), q_map),     # q
+            pl.BlockSpec((1, blk_k, d), kv_map),    # k
+            pl.BlockSpec((1, blk_k, d_v), kv_map),  # v
+            pl.BlockSpec((1, blk_q, d_v), q_map),   # do
+            pl.BlockSpec((1, 1, blk_q), row_map),   # lse
+            pl.BlockSpec((1, 1, blk_q), row_map),   # delta
+        ],
+        out_specs=[
+            pl.BlockSpec((1, blk_q, d), q_map),
+            pl.BlockSpec((1, t, d), held),
+            pl.BlockSpec((1, t, d_v), held),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), q3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, t, d), k3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, t, d_v), v3.dtype, vma=vma),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((blk_q, d), jnp.float32),
+            pltpu.VMEM((t, d), jnp.float32),
+            pltpu.VMEM((t, d_v), jnp.float32),
+        ],
+        # a K/V head's gradients gather over every other axis
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_fused_resident_bytes(t, d, d_v, k3.dtype)
+            + _VMEM_WORKING_BYTES),
+        interpret=interpret,
+        name=_names(window)[3],
+    )(q3, k3, v3, do, lse3, delta))
+
+
+def _bwd_split(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
+               blk_q: int, blk_k: int, interpret: bool,
+               window: Optional[int]):
+    """dq, dk, dv from two kernels, each output block in its own scratch:
+    what a sequence too long for ``_bwd_fused`` takes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (bh, t, d), d_v = q3.shape, v3.shape[2]
+    bkv = k3.shape[0]
+    group = bh // bkv
     num_q, num_k = t // blk_q, t // blk_k
     k_steps, q_steps = _band_steps(t, blk_q, blk_k, window)
     vma = jax.typeof(q3).vma
     names = _names(window)
-    _count_blocks(2, bh, t, blk_q, blk_k, window, causal)
 
     # dK/dV: one K/V head and k block a program row, walking the q blocks of
     # each of the group's query heads in turn

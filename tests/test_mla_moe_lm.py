@@ -376,16 +376,24 @@ def test_a_recomputed_latent_block_keeps_its_kernels_pair(
 # ------------------------------------------------------- (h) older models
 # sha256 of the estimator's train step as jax lowers it (the StableHLO text,
 # no source locations; the flash kernels interpreted in blocks of 16, so
-# their bodies are in it) for the three older families' CPU cuts, computed
-# on the commit before latent attention (74af897) with ``_step_text``. A PR
-# that means to change one of these programs replaces its line.
+# their bodies are in it) for the three older families' CPU cuts: with the
+# backward as the pair of kernels, computed on the commit before latent
+# attention (74af897) with ``_step_text``; with the backward as one kernel,
+# on the commit that built it (PR 43). A PR that means to change one of
+# these programs replaces its line.
 PARENT_STEP = {
-    "olmoe-1b-7b":
+    ("olmoe-1b-7b", "split"):
         "fc12cbdf792919544024982de7e9d345a78459791d3896cc61eac78be79ff455",
-    "smallthinker-21b-a3b":
+    ("smallthinker-21b-a3b", "split"):
         "aefcfbb2077debbcbb85e8fc68073132dda201b3ab37a84faba744a89dab4de6",
-    "trinity-mini":
+    ("trinity-mini", "split"):
         "a5b10012347c358426747cfe0c06271ce5c5217cbbd0bc9851e9e8cc47b50fb3",
+    ("olmoe-1b-7b", "fused"):
+        "8f37bf58d0cd1236e4ebe06e3aaa2d7ba11414a3ba5e485f65347853ae8aadec",
+    ("smallthinker-21b-a3b", "fused"):
+        "334a99fefdde8fb0c485fa731e5d00ab9572d7631705a0f8e023a43b0af28a7f",
+    ("trinity-mini", "fused"):
+        "73d28ba5f226a0200e7d2179d16396efc5a99e7141eb8bb5b43c9af010c3acc4",
 }
 
 
@@ -414,15 +422,23 @@ def _step_text(config, cell):
     ("olmoe-1b-7b", "olmoe_1b7b_train"),
     ("smallthinker-21b-a3b", "smallthinker_21ba3b_16k_train"),
     ("trinity-mini", "trinity_mini_8k_train")])
-def test_an_older_familys_step_is_the_parents_text(config, cell,
-                                                   forward_flash_kernels):
+@pytest.mark.parametrize("backward", ["fused", "split"])
+def test_an_older_familys_step_is_the_parents_text(config, cell, backward,
+                                                   forward_flash_kernels,
+                                                   monkeypatch):
     """Every latent option is off by default, the blocks build ``Attention``
     as it was, and a flash call with one width lowers to the text it lowered
-    to before the kernels took two."""
+    to before the kernels took two; where the backward takes its pair of
+    kernels (the shape rule set aside), to the text of before the one kernel:
+    the pair is as it was."""
+    from raydp_tpu.ops import flash_attention as fa
+    if backward == "split":
+        monkeypatch.setattr(fa, "_fused_backward_fits", lambda *a: False)
     model, text, attn = _step_text(config, cell)
     assert (model.kv_lora_rank, model.q_lora_rank, model.qk_nope_head_dim,
             model.qk_rope_head_dim, model.v_head_dim,
             model.rope_interleave) == (None,) * 5 + (False,)
     assert "latent" not in model.attention_layers
     assert {"q", "k", "v", "o"} <= set(attn) and "kv_a" not in attn
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[
+        config, backward]

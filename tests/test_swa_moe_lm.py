@@ -10,6 +10,7 @@ sizes on the CPU, seeded random weights. Widths are small here, and only here
 
 import copy
 import os
+import re
 
 import numpy as np
 import pytest
@@ -200,10 +201,15 @@ def test_no_window_and_equal_heads_is_the_op_as_it_was():
     text = str(jax.make_jaxpr(jax.grad(lambda *a: fa.flash_attention(
         *a, block_q=16, block_k=16, interpret=True).sum(), (0, 1, 2)))(
             q, k, v))
-    for name in fa.KERNEL_NAMES:
-        assert name in text
+    # the forward and the one backward kernel; the pair's names are the
+    # fallback's (``test_one_pallas_call_a_kernel_...``)
+    fwd, dkdv, dq, fused = fa.KERNEL_NAMES
+    assert set(re.findall(r"name=(rdt_flash_(?:fwd|bwd)\w*)", text)) == {
+        fwd, fused}
+    assert fused == dkdv + "_dq" and f"name={dq}" not in text
     assert "rdt_flash_win" not in text
-    assert "grid=(8, 4, 4)" in text.replace("grid=(8,4,4)", "grid=(8, 4, 4)")
+    grids = re.findall(r"grid=\(([\d, ]+)\)", text.replace(", ", ","))
+    assert sorted(grids) == ["8,1,4,4", "8,4,4"]
     windowed = str(jax.make_jaxpr(lambda *a: fa.flash_attention(
         *a, block_q=16, block_k=16, interpret=True, window=16))(q, k, v))
     assert fa.WINDOW_KERNEL_NAMES[0] in windowed
@@ -231,6 +237,43 @@ def test_the_windowed_kernels_walk_the_band_and_count_its_blocks():
     full = registry.snapshot()["counters"]["flash_blocks_total"]
     assert full["computed"] - after["computed"] == 2 * 10
     assert full["skipped_window"] == after["skipped_window"]
+
+
+# (T, window, blocks, query heads, K/V heads, causal) -> a head's computed
+# block pairs
+BACKWARD_COUNTS = {
+    "full_causal": ((64, None, 16, 4, 4, True), 10),
+    "group_of_four_window": ((64, 32, 16, 4, 1, True), 9),
+    "window_no_multiple": ((64, 24, 16, 2, 2, True), 9),
+    "one_block": ((64, None, 64, 2, 1, True), 1),
+    "not_causal": ((64, None, 16, 2, 2, False), 16),
+}
+
+
+@pytest.mark.parametrize("kernels", ["fused", "split"])
+@pytest.mark.parametrize("case", list(BACKWARD_COUNTS))
+def test_a_backward_counts_itself_and_its_block_pairs_once_a_kernel(
+        case, kernels, monkeypatch):
+    """``flash_backward_total`` says which backward a layer call built;
+    ``flash_blocks_total`` gains a head's computed pairs once from the one
+    kernel and twice from the pair (and once from the forward)."""
+    import jax
+    from raydp_tpu.ops import flash_attention as fa
+
+    (t, window, blk, h, hk, causal), pairs = BACKWARD_COUNTS[case]
+    if kernels == "split":
+        monkeypatch.setattr(fa, "_fused_backward_fits", lambda *a: False)
+    q, k, v, _ = _qkv(t, h, hk)
+
+    def build():
+        jax.make_jaxpr(jax.grad(lambda *a: fa.flash_attention(
+            *a, causal=causal, window=window, block_q=blk, block_k=blk,
+            interpret=True).sum(), (0, 1, 2)))(q, k, v)
+
+    assert _counted("flash_backward_total", build) == {kernels: 1}
+    times = 1 + (1 if kernels == "fused" else 2)    # the forward's, then
+    assert _counted("flash_blocks_total", build)["computed"] == (
+        times * 2 * h * pairs)
 
 
 def _counted(name, call):
@@ -294,30 +337,131 @@ def test_the_tiles_where_an_edge_block_stays_whole_or_a_tile_is_small(
             "unmasked": 4 * (t // blk_q) * (t // blk_k)}
 
 
+@pytest.mark.parametrize("kernels", ["fused", "split"])
 @pytest.mark.parametrize("window", [None, 512], ids=["full", "windowed"])
-def test_one_pallas_call_a_kernel_under_the_pinned_names_and_grids(window):
-    """The edges' paths live inside the three kernels: a call's jaxpr holds
-    one ``pallas_call`` a kernel, named as a trace's readers expect, over
-    the grids ``(bh, T/blk_q, k_steps)`` and ``(bkv, T/blk_k, group *
-    q_steps)``."""
-    import re
-
+def test_one_pallas_call_a_kernel_under_the_pinned_names_and_grids(
+        window, kernels, monkeypatch):
+    """The edges' paths live inside the kernels: a call's jaxpr holds one
+    ``pallas_call`` a kernel, named as a trace's readers expect. The backward
+    is ONE kernel ``..._bwd_dkdv_dq`` over ``(bkv, group, T/blk_q, k_steps)``
+    and none named ``..._bwd_dq``; where a head's gradients do not fit, the
+    pair over ``(bkv, T/blk_k, group * q_steps)`` and ``(bh, T/blk_q,
+    k_steps)``."""
     import jax
     from raydp_tpu.ops import flash_attention as fa
 
+    if kernels == "split":
+        monkeypatch.setattr(fa, "_fused_backward_fits", lambda *a: False)
     q, k, v, _ = _qkv(1024, 4, 2)
     text = str(jax.make_jaxpr(jax.grad(lambda *a: fa.flash_attention(
         *a, block_q=256, block_k=256, interpret=True, window=window).sum(),
         (0, 1, 2)))(q, k, v))
     names = re.findall(r"name=(rdt_flash(?:_win)?_(?:fwd|bwd_\w+))", text)
-    assert sorted(names) == sorted(fa._names(window))
-    assert text.count("pallas_call[") == 3
+    fwd, dkdv, dq, fused = fa._names(window)
     k_steps, q_steps = fa._band_steps(1024, 256, 256, window)
     assert (k_steps, q_steps) == ((4, 4) if window is None else (3, 3))
     grids = [tuple(int(n) for n in g.split(","))
              for g in re.findall(r"grid=\(([\d, ]+)\)", text)]
-    assert sorted(grids) == sorted([(8, 4, k_steps), (4, 4, 2 * q_steps),
-                                    (8, 4, k_steps)])
+    if kernels == "fused":
+        assert sorted(names) == sorted([fwd, fused])
+        assert re.match(r"^rdt_flash(_win)?_bwd_dkdv", fused)  # a layer each
+        assert sorted(grids) == sorted([(8, 4, k_steps), (4, 2, 4, k_steps)])
+    else:
+        assert sorted(names) == sorted([fwd, dkdv, dq])
+        assert sorted(grids) == sorted([(8, 4, k_steps), (4, 4, 2 * q_steps),
+                                        (8, 4, k_steps)])
+    assert text.count("pallas_call[") == len(names)
+
+
+# (T, window, block, query heads, K/V heads, keys' width, values' width,
+# causal)
+FUSED_CASES = {
+    "a_kv_head_a_query_head": (64, None, 16, 4, 4, 16, 16, True),
+    "group_of_four": (64, None, 16, 4, 1, 16, 16, True),
+    "keys_192_values_128": (64, None, 16, 2, 2, 192, 128, True),
+    "keys_192_values_128_group_window": (64, 32, 16, 4, 1, 192, 128, True),
+    "window_a_multiple_of_the_block": (64, 32, 16, 4, 2, 16, 16, True),
+    "window_no_multiple_of_the_block": (64, 24, 16, 4, 1, 16, 16, True),
+    "window_in_tiles": (1024, 512, 256, 4, 1, 16, 16, True),
+    "full_causal_in_tiles": (1024, None, 512, 2, 1, 24, 16, True),
+    "t_of_one_block": (64, None, 64, 4, 1, 16, 16, True),
+    "not_causal": (64, None, 16, 4, 2, 16, 16, False),
+    "not_causal_group_of_four": (64, None, 16, 4, 1, 24, 16, False),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSED_CASES))
+def test_the_one_kernel_backward_gives_the_pairs_and_the_blockwise_gradients(
+        case, monkeypatch):
+    """dq, dk and dv of the one kernel against the pair of kernels (the same
+    block products, dk and dv summed in another order) and against the jnp
+    path's blockwise backward, to float32 accumulation."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.ops import flash_attention as fa
+
+    t, window, blk, h, hk, d, d_v, causal = FUSED_CASES[case]
+    q, k, v, w = _qkv(t, h, hk, d, d_v=d_v)
+
+    def grads(**how):
+        return jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+            *a, causal=causal, window=window, block_q=blk, block_k=blk,
+            **how) * w), (0, 1, 2))(q, k, v)
+
+    fused = _counted("flash_backward_total", lambda: grads(interpret=True))
+    assert fused == {"fused": 1}
+    one = grads(interpret=True)
+    blockwise = grads()
+    monkeypatch.setattr(fa, "_fused_backward_fits", lambda *a: False)
+    pair = grads(interpret=True)
+    for got, same, want in zip(one, pair, blockwise):
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(got, same, rtol=0, atol=2e-6 * scale)
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-5 * scale)
+
+
+# what the shape rule reads: (T, keys' width, values' width) -> one kernel?
+HELD_CASES = {
+    "olmoe_4k": ((4096, 128, 128), True),
+    "trinity_8k": ((8192, 128, 128), True),
+    "smallthinker_16k": ((16384, 128, 128), True),
+    "kanana2_16k_192_128": ((16384, 192, 128), True),
+    "32k_at_128": ((32768, 128, 128), True),
+    "32k_at_192_128": ((32768, 192, 128), False),
+    "64k_at_128": ((65536, 128, 128), False),
+}
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_a_head_too_long_to_hold_takes_the_two_kernels(case):
+    """The shape alone decides: a K/V head's float32 accumulators and out
+    blocks (each width in whole tiles of 128 lanes) inside the budget take
+    the one kernel, and the call asks the compiler for them plus the working
+    room; a longer sequence still lowers the pair, under the default limit."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.ops import flash_attention as fa
+
+    (t, d, d_v), fits = HELD_CASES[case]
+    lanes = -(-d // 128) * 128 + d_v
+    held = fa._fused_resident_bytes(t, d, d_v, jnp.bfloat16)
+    assert held == t * lanes * (4 + 2 * 2)
+    assert fa._fused_backward_fits(t, d, d_v, jnp.bfloat16) is fits
+    assert (held <= fa.FUSED_BWD_RESIDENT_BYTES) is fits
+    assert fa.FUSED_BWD_RESIDENT_BYTES + fa._VMEM_WORKING_BYTES < 128 << 20
+    mk = lambda heads, width: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, t, heads, width), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: fa.flash_attention(
+        *a, interpret=True).astype(jnp.float32).sum(), (0, 1, 2)))(
+            mk(2, d), mk(1, d), mk(1, d_v)))
+    names = set(re.findall(r"name=(rdt_flash_bwd_\w+)", text))
+    fwd, dkdv, dq, fused = fa.KERNEL_NAMES
+    assert names == ({fused} if fits else {dkdv, dq})
+    limits = re.findall(r"vmem_limit_bytes=(\d+)", text)
+    if fits:
+        assert [int(n) for n in limits] == [held + fa._VMEM_WORKING_BYTES]
+    else:
+        assert not limits
 
 
 def test_a_window_needs_causal_and_the_heads_have_to_group():
