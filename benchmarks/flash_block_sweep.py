@@ -33,11 +33,20 @@ for the pair of kernels, whichever of them the shape rule
 measurement, the op has no argument for it. A form the compiler refuses is a
 line that says so.
 
+``--forward`` times a layer's forward alone at the default blocks, one line
+for each unit of an unmasked block's online-softmax update: the whole block
+(one product for all its scores, then the softmax, then PV), the chunk of q
+rows the op takes (``_ROW_CHUNK``: a chunk's QK^T is issued before the
+softmax and PV of the chunk before it), and chunks of 128, 256 and 512 rows.
+The constant is set aside for a measurement; the op has no argument for it.
+
 Run: python benchmarks/flash_block_sweep.py [--seq-len 8192] [--dim 128]
      python benchmarks/flash_block_sweep.py --edges --grad --batch 2 \
          --seq-len 8192 --heads 32 --kv-heads 4 --window 2048   # a Trinity layer
      python benchmarks/flash_block_sweep.py --backward --seq-len 16384 \
          --heads 32 --dim 192 --v-dim 128                       # a kanana-2 layer
+     python benchmarks/flash_block_sweep.py --forward --seq-len 16384 \
+         --heads 28 --kv-heads 4 --window 4096          # a SmallThinker layer
 """
 
 from __future__ import annotations
@@ -70,6 +79,8 @@ def main():
                     help="time the paths by the mask's edges, not the blocks")
     ap.add_argument("--backward", action="store_true",
                     help="time the backward alone: one kernel, and the pair")
+    ap.add_argument("--forward", action="store_true",
+                    help="time the forward's update whole and by row chunks")
     args = ap.parse_args()
 
     import jax
@@ -137,6 +148,8 @@ def main():
     what = "fwd+bwd" if args.grad else "fwd"
     if args.edges:
         return edges(fa, timed, what, args)
+    if args.forward:
+        return forward(fa, timed, args)
 
     results, refused = [], []
     grid = [(128, 128), (128, 256), (256, 256), (256, 512), (512, 512),
@@ -220,6 +233,31 @@ def edges(fa, timed, what, args):
         print(f"{name:46s} {us:9.1f} us/{what}  (B={args.batch} "
               f"T={args.seq_len} H={args.heads}/{args.kv_heads or args.heads}"
               f" D={args.dim} window={args.window})", flush=True)
+
+
+def forward(fa, timed, args):
+    """A layer's forward at the default blocks by the unit of a block's
+    online-softmax update: the whole block, the chunk the op takes, and each
+    other chunk of q rows."""
+    import jax
+
+    built = fa._ROW_CHUNK
+    for name, rows in [("the whole block", fa.DEFAULT_BLOCK_Q),
+                       (f"as built: chunks of {built} rows", built)] + [
+            (f"chunks of {c} rows", c) for c in (128, 256, 512) if c != built]:
+        fa._ROW_CHUNK = rows
+        jax.clear_caches()
+        try:
+            ms = timed(fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
+            said = f"{ms:9.3f} ms/fwd"
+        except jax.errors.JaxRuntimeError as e:   # the compiler said no
+            said = f"REFUSED ({str(e)[:400]})"
+        finally:
+            fa._ROW_CHUNK = built
+        print(f"{name:32s} {said}  (B={args.batch} T={args.seq_len} "
+              f"H={args.heads}/{args.kv_heads or args.heads} "
+              f"D={args.dim}/{args.v_dim or args.dim} window={args.window})",
+              flush=True)
 
 
 def backward(fa, qkv, rtt, args):

@@ -390,6 +390,15 @@ _ALL_METRICS = [
        "scores and dP formed in each: a sequence whose head's gradients do "
        "not fit, `FUSED_BWD_RESIDENT_BYTES`). ops/flash_attention.py.",
        label="kernels"),
+    _m("flash_forward_total", COUNTER, "1", "training",
+       "Forward passes of the flash-attention kernels, counted where one "
+       "is built (a layer call each), by the unit of a block's "
+       "online-softmax update: `chunked` (the q rows of a block no edge "
+       "of the mask crosses a chunk at a time, a chunk's QK^T issued "
+       "before the softmax and PV of the chunk before it; `_ROW_CHUNK` "
+       "rows) or `whole` (a block too small for two chunks is one piece). "
+       "ops/flash_attention.py.",
+       label="update"),
     _m("flash_blocks_total", COUNTER, "1", "training",
        "(q block, k block) pairs of the flash-attention kernels, counted "
        "where a kernel's grid is built (once a built forward kernel, once "

@@ -5,7 +5,11 @@ Forward is a Pallas kernel (``_fwd_kernel``): the grid is
 online-softmax state (running max ``m``, normalizer ``l``, accumulator ``acc``)
 lives in VMEM scratch and carries across k steps — the [T, T] score matrix
 never exists, each program touches one ``[blk_q, D] × [blk_k, D]`` tile pair on
-the MXU. The kernel also emits the log-sum-exp per query row, which makes the
+the MXU. A step updates a block no edge of the mask crosses a chunk of its q
+rows at a time (``_ROW_CHUNK``: 256; a block too small for two is one piece):
+a chunk's QK^T is issued before the softmax and PV of the chunk before it, so
+a product and the vector passes that do not wait for it stand side by side.
+The kernel also emits the log-sum-exp per query row, which makes the
 backward pass a pure recompute: ``custom_vjp`` re-forms each score block from
 (Q, K, LSE). On TPU the backward is one Pallas kernel (``_bwd_fused_kernel``):
 it walks the visible block pairs once, q blocks outermost, forms the scores
@@ -211,18 +215,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _rows(rows, pieces):
-        """One online-softmax update of the q rows ``rows`` by the keys of
-        ``pieces``: (k rows, the pairs to keep or None for all)."""
+    def _scores(rows, pieces):
+        """The scores of the q rows ``rows`` against the keys of ``pieces``:
+        (k rows, the pairs to keep or None for all), a list by piece."""
         q = q_ref[0, rows]                      # [rows, D], native dtype
         # native-dtype MXU matmul (bf16 x bf16 -> f32); upcasting inputs to
         # f32 first would cost ~4x MXU throughput for no accuracy gain over
         # the f32 accumulator
-        scores = [_masked(lax.mul(lax.dot_general(
+        return [_masked(lax.mul(lax.dot_general(
             q, k_ref[0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32), scale), keep)
             for cols, keep in pieces]                       # [rows, cols]
 
+    def _update(rows, pieces, scores):
+        """The online-softmax state of the q rows ``rows`` moved on by their
+        ``scores`` against ``pieces``."""
         # a row that sees no key of a block on the band's far edge keeps
         # m = -1e30 and adds p = 1 for each of them; the first block that
         # holds a key it does see (its own diagonal at the latest) scales
@@ -244,6 +251,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[rows] = acc
         m_scr[rows, 0] = m_new
         l_scr[rows, 0] = l_new
+
+    def _rows(rows, pieces):
+        """One online-softmax update of the q rows ``rows`` by the keys of
+        ``pieces``, where no mask lies on them a chunk of rows at a time
+        (``_row_chunks``; a row's state is its own). A chunk's scores are
+        formed before the softmax of the chunk before it: the compiler keeps
+        close to the order it is given, and so one chunk's QK^T on the MXU
+        stands beside the other's vector passes, which do not wait for it."""
+        chunks = _row_chunks(rows, pieces, blk_q)
+        ahead = _scores(*chunks[0])
+        for (chunk, kept), after in zip(chunks, chunks[1:] + [None]):
+            scores, ahead = ahead, after and _scores(*after)
+            _update(chunk, kept, scores)
 
     def _body(edge):
         for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
@@ -280,12 +300,16 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from raydp_tpu import metrics as rdt_metrics
+
     (bh, t, d), d_v = q3.shape, v3.shape[2]
     group = bh // k3.shape[0]
     k_steps, _ = _band_steps(t, blk_q, blk_k, window)
     band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
     q_map, kv_map, row_map = _maps(group, band)
     _count_blocks(1, bh, t, blk_q, blk_k, window, causal)
+    rdt_metrics.inc("flash_forward_total", label="chunked" if _row_chunk(
+        blk_q) < blk_q else "whole")
     grid = (bh, t // blk_q, k_steps)
     vma = jax.typeof(q3).vma     # inside a shard_map the outputs vary as q does
 
@@ -1067,3 +1091,38 @@ def _walk(edge: Optional[str], by_rows: bool, qi, ki, blk_q: int, blk_k: int,
             pieces.append((inside, None))
         steps.append((crossed, pieces))
     return steps
+
+
+# ---------------------------------------------------------------------------
+# The forward's unit of work inside a block. Down here for the reason above.
+# ---------------------------------------------------------------------------
+#: q rows a chunk of the forward's online-softmax update. Kernel-alone on a
+#: v5e (PERF.md, PR 45) 256 rows were the fastest of 128 / 256 / 512 in five
+#: geometries of six (128 at keys of 192 beside values of 128): fewer rows
+#: latch a key tile for too few of them, more leave a block too few chunks to
+#: stand beside one another. And every chunk is more of a kernel body for
+#: Python to trace and lower in every run: chunks of 128 everywhere cost a
+#: 16,384-token step of six layers 11 s of its warm set-up.
+_ROW_CHUNK = 256
+
+
+def _row_chunk(n: int) -> int:
+    """The rows of a chunk where the forward updates ``n`` q rows; ``n``
+    itself (one piece) where they hold fewer than two chunks or no whole
+    number of them: small blocks."""
+    c = _ROW_CHUNK
+    return c if n >= 2 * c and n % c == 0 else n
+
+
+def _row_chunks(rows: slice, pieces, blk_q: int):
+    """``(rows, pieces)`` of a forward step cut into chunks of rows: a list
+    of (slice of the q rows, the pieces). A slice with a mask on it (an edge
+    block's tile, half a block already, or an edge block kept whole) stays
+    one piece."""
+    start, stop, _ = rows.indices(blk_q)
+    n = stop - start
+    c = _row_chunk(n)
+    if c == n or any(keep is not None for _, keep in pieces):
+        return [(rows, pieces)]
+    return [(slice(start + at, start + at + c), pieces)
+            for at in range(0, n, c)]

@@ -464,6 +464,110 @@ def test_a_head_too_long_to_hold_takes_the_two_kernels(case):
         assert not limits
 
 
+# the forward's chunked update: FUSED_CASES' geometries and the rows of a
+# chunk the test gives the op (a block holds two to four chunks)
+CHUNKED_ROWS = {case: 8 for case in FUSED_CASES}
+CHUNKED_ROWS.update(window_in_tiles=64, full_causal_in_tiles=128,
+                    t_of_one_block=16)
+
+
+@pytest.mark.parametrize("case", list(FUSED_CASES))
+def test_the_chunked_forward_is_the_whole_block_forward(case, monkeypatch):
+    """Output and ``lse`` of the forward with an unmasked block's rows
+    updated a chunk at a time against the whole-block body (the constant set
+    aside) and against the jnp path: a row's state is its own, so a chunk
+    changes no sum."""
+    import jax.numpy as jnp
+    from raydp_tpu.ops import flash_attention as fa
+
+    t, window, blk, h, hk, d, d_v, causal = FUSED_CASES[case]
+    q3, k3, v3 = (x.transpose(0, 2, 1, 3).reshape(-1, t, x.shape[3])
+                  for x in _qkv(t, h, hk, d, d_v=d_v)[:3])
+    how = dict(scale=d ** -0.5, causal=causal, window=window)
+
+    def kernel(rows):
+        monkeypatch.setattr(fa, "_ROW_CHUNK", rows)
+        return fa._fwd_pallas(q3, k3, v3, blk_q=blk, blk_k=blk,
+                              interpret=True, **how)
+
+    got, whole = [], []
+    assert _counted("flash_forward_total", lambda: got.extend(kernel(
+        CHUNKED_ROWS[case]))) == {"chunked": 1}
+    assert fa._row_chunk(blk) == CHUNKED_ROWS[case] < blk
+    assert _counted("flash_forward_total",
+                    lambda: whole.extend(kernel(blk))) == {"whole": 1}
+    scale = float(jnp.abs(v3).max())
+    for (out, lse), tol in ((whole, 2e-6), (fa._fwd_jnp(q3, k3, v3, **how),
+                                            2e-5)):
+        np.testing.assert_allclose(got[0], out, rtol=0, atol=tol * scale)
+        np.testing.assert_allclose(got[1], lse, rtol=2e-6, atol=tol)
+
+
+# rows of the slice a forward step updates -> rows a chunk
+ROW_CHUNKS = {
+    "the_four_cells_block": (1024, 256),
+    "two_chunks_exactly": (512, 256),
+    "a_block_of_one_chunk_and_a_half": (384, 384),
+    "a_block_under_two_chunks": (256, 256),
+    "the_cpu_cuts_block_of_16": (16, 16),
+    "rows_no_multiple_of_a_chunk": (640, 640),
+}
+
+
+@pytest.mark.parametrize("case", list(ROW_CHUNKS))
+def test_the_rows_of_a_chunk_follow_from_the_rows_of_the_slice(case):
+    """The shape alone decides: 256 rows a chunk at every width of the four
+    cells (128/128 and 192/128), the slice in one piece where it holds fewer
+    than two chunks or no whole number of them, and wherever a mask lies on
+    it: an edge block's tiles, an edge block kept whole."""
+    import jax.numpy as jnp
+    from raydp_tpu.ops import flash_attention as fa
+
+    n, rows = ROW_CHUNKS[case]
+    free, kept = [(slice(None), None)], jnp.ones((n, n), bool)
+    assert fa._row_chunk(n) == rows
+    chunks = fa._row_chunks(slice(None), free, n)
+    assert [c.indices(n)[:2] for c, _ in chunks] == [
+        (at, at + rows) for at in range(0, n, rows)]
+    assert all(pieces is free for _, pieces in chunks)
+    masked = [(slice(None), kept)] + free
+    assert fa._row_chunks(slice(0, n), masked, n) == [(slice(0, n), masked)]
+
+
+@pytest.mark.parametrize("rows,update", [(None, "whole"), (128, "chunked")])
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "windowed"])
+def test_a_chunked_forward_is_one_call_and_counts_what_a_whole_one_counts(
+        window, rows, update, monkeypatch):
+    """The chunks live inside the kernel: one ``rdt_flash(_win)?_fwd`` call a
+    layer over the same grid, the same block pairs and tiles counted, and
+    ``flash_forward_total`` says which body it lowered."""
+    import jax
+    from raydp_tpu.ops import flash_attention as fa
+
+    if rows is not None:
+        monkeypatch.setattr(fa, "_ROW_CHUNK", rows)
+    q, k, v, _ = _qkv(1024, 4, 2)
+
+    def build():
+        return str(jax.make_jaxpr(lambda *a: fa.flash_attention(
+            *a, block_q=256, block_k=256, interpret=True, window=window))(
+                q, k, v))
+
+    text = build()
+    assert re.findall(r"name=(rdt_flash(?:_win)?_(?:fwd|bwd)\w*)",
+                      text) == [fa._names(window)[0]]
+    assert text.count("pallas_call[") == 1
+    k_steps, _ = fa._band_steps(1024, 256, 256, window)
+    assert re.findall(r"grid=\(([\d, ]+)\)", text) == [f"8, 4, {k_steps}"]
+    assert _counted("flash_forward_total", build) == {update: 1}
+    pairs = 10 if window is None else 9      # of a head's 16
+    assert _counted("flash_blocks_total", build)["computed"] == 8 * pairs
+    tiles = _counted("flash_tiles_total", build)
+    assert tiles == ({"unmasked": 8 * 28, "masked": 8 * 8, "skipped": 8 * 4}
+                     if window is None else
+                     {"unmasked": 8 * 18, "masked": 8 * 12, "skipped": 8 * 6})
+
+
 def test_a_window_needs_causal_and_the_heads_have_to_group():
     from raydp_tpu.ops.flash_attention import (flash_attention,
                                                kernel_ineligible)
