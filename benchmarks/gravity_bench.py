@@ -459,7 +459,6 @@ def main():
     grav = run_gravity_config(args.smoke)
     record = {
         "bench": "gravity_bench",
-        # headline + PERF_CLAIMS handle (tests/test_perf_claims)
         "metric": "warm_readiness_speedup",
         "value": warm["readiness_speedup"],
         "smoke": args.smoke,

@@ -530,8 +530,7 @@ def main():
                     help="run ONLY the activation-residency config (accum × "
                          "remat × seq); a full run merges configs.activation "
                          "into the existing MESH.json record so the "
-                         "memory/overlap numbers (and their PERF_CLAIMS) "
-                         "stay as measured")
+                         "memory/overlap numbers stay as measured")
     ap.add_argument("--pipeline", action="store_true",
                     help="run ONLY the pipeline-parallel config (stage-"
                          "stacked estimator placement vs unstaged); a full "
@@ -564,7 +563,6 @@ def main():
         configs = merged
     record = {
         "bench": "mesh_bench",
-        # the headline number + PERF_CLAIMS handle (tests/test_perf_claims)
         "metric": "fsdp_state_bytes_reduction",
         "value": (configs["memory"]["replicated_over_sharded"]
                   if "memory" in configs

@@ -522,7 +522,6 @@ def main():
         ooc = run_outofcore_config(args.smoke)
         record = {
             "bench": "scale_bench",
-            # headline + PERF_CLAIMS handle (tests/test_perf_claims)
             "metric": "outofcore_store_bytes_over_budget",
             "value": ooc["store_bytes_over_budget"],
             "smoke": args.smoke,

@@ -305,7 +305,6 @@ def main():
     }
     record = {
         "bench": "stream_bench",
-        # the headline number + PERF_CLAIMS handle (tests/test_perf_claims)
         "metric": "stream_sustained_rows_per_s",
         "value": configs["sustained"]["rows_per_s"],
         "smoke": args.smoke,

@@ -112,7 +112,7 @@ def main():
               f"category sizes: min={min(cat_sizes)} max={max(cat_sizes)}")
 
         if args.scale == "full":
-            # reference dims (pytorch_dlrm.ipynb / BASELINE.md)
+            # reference dims (pytorch_dlrm.ipynb)
             model_kw = dict(embedding_dim=32, bottom_mlp=(512, 128, 32),
                             top_mlp=(1024, 1024, 512, 256, 1))
         else:
@@ -123,6 +123,9 @@ def main():
         n_dev = len(jax.devices())
         expert = 2 if n_dev % 2 == 0 else 1
         mesh = make_mesh(MeshSpec(expert=expert))
+        # a table shards over ``expert`` only if the extent divides its rows:
+        # pad each up to a multiple (ids never reach a pad row)
+        cat_sizes = [-(-n // expert) * expert for n in cat_sizes]
         import jax.numpy as jnp
         est = FlaxEstimator(
             model=DLRM(categorical_sizes=cat_sizes, num_dense=NUM_DENSE,

@@ -17,7 +17,6 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-import time
 
 import numpy as np
 
@@ -40,13 +39,14 @@ def main():
     args = p.parse_args()
 
     import jax
-    import jax.numpy as jnp
     import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    import pyarrow as pa
 
+    import raydp_tpu
     from raydp_tpu.models import TransformerLM, lm_loss, \
         transformer_param_rules
-    from raydp_tpu.parallel import MeshSpec, make_mesh, shard_params
+    from raydp_tpu.parallel import MeshSpec, make_mesh
+    from raydp_tpu.train import FlaxEstimator
 
     n_dev = len(jax.devices())
     tp = args.tensor_parallel
@@ -58,45 +58,35 @@ def main():
                               tensor=tp))
     print(f"devices={n_dev} mesh={dict(mesh.shape)}")
 
-    model = TransformerLM(vocab_size=args.vocab, dim=args.dim,
-                          num_heads=args.heads, num_layers=args.layers,
-                          attention="ring" if seq_par > 1 else "auto",
-                          mesh=mesh)
-
     rng = np.random.RandomState(0)
     start = rng.randint(0, args.vocab, size=(args.batch, 1))
-    tokens = jnp.asarray((start + np.arange(args.seq_len)[None]) % args.vocab,
-                         dtype=jnp.int32)
-    tokens = jax.device_put(tokens, NamedSharding(mesh, P("data", "seq")))
+    tokens = ((start + np.arange(args.seq_len)[None]) % args.vocab
+              ).astype(np.int32)
 
-    variables = model.init(jax.random.PRNGKey(0), tokens)
-    tx = optax.adamw(3e-4)
-    params = variables["params"]
-    opt_state = tx.init(params)
-    if tp > 1:
-        # Megatron split: q/k/v + gate/up column-parallel, o/down row-parallel
-        rules = transformer_param_rules("tensor")
-        params = shard_params(params, mesh, rules)
-        opt_state = shard_params(opt_state, mesh, rules)
-
-    @jax.jit
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_loss(model.apply({"params": p}, batch), batch)
-        )(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
-
-    with mesh:
-        t0 = time.perf_counter()
-        for i in range(args.steps):
-            params, opt_state, loss = step(params, opt_state, tokens)
-            if i % 5 == 0 or i == args.steps - 1:
-                print(f"step {i}: loss {float(loss):.4f}")
-        dt = time.perf_counter() - t0
-    toks = args.batch * args.seq_len * args.steps
-    print(f"{toks / dt:.0f} tokens/s over {n_dev} devices "
-          f"(seq_parallel={seq_par}, T={args.seq_len})")
+    session = raydp_tpu.init("longcontext", num_executors=1, executor_cores=1,
+                             executor_memory="1GB")
+    try:
+        # one batch of token rows as a list column; the feed places each row's
+        # positions over the mesh's seq axis, and an epoch is one step
+        df = session.createDataFrame(pa.table({
+            "tokens": pa.FixedSizeListArray.from_arrays(tokens.ravel(),
+                                                        args.seq_len)}))
+        est = FlaxEstimator(
+            model=TransformerLM(vocab_size=args.vocab, dim=args.dim,
+                                num_heads=args.heads, num_layers=args.layers,
+                                attention="ring" if seq_par > 1 else "auto",
+                                mesh=mesh),
+            optimizer=optax.adamw(3e-4), loss=lm_loss, mesh=mesh,
+            # Megatron split: q/k/v + gate/up column-parallel, o/down row-parallel
+            param_rules=transformer_param_rules("tensor") if tp > 1 else None,
+            columns_spec={"tokens": ("tokens", np.int32)},
+            batch_preprocessor=lambda batch: (batch["tokens"], batch["tokens"]),
+            batch_size=args.batch, num_epochs=args.steps, shuffle=False)
+        for report in est.fit_on_frame(df).history:
+            if report["epoch"] % 5 == 0 or report["epoch"] == args.steps - 1:
+                print(f"step {report['epoch']}: loss {report['train_loss']:.4f}")
+    finally:
+        raydp_tpu.stop()
 
 
 if __name__ == "__main__":

@@ -521,6 +521,12 @@ class DeviceEpochCache:
         steps_per_epoch = n_rows // B
 
         def epoch_fn(carry, data, key):
+            if not steps_per_epoch:
+                # fewer rows than one batch (an evaluation set; ``eligible``
+                # keeps such a training set off this path): the body's slice
+                # of B rows would not trace, and the caller's tail call
+                # serves every row
+                return carry
             perm = jax.random.permutation(key, n_rows) if shuffle else None
 
             def body(carry, s):

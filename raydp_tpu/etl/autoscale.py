@@ -137,8 +137,8 @@ class PoolAutoscaler:
             return  # session not started (or already torn down)
         pool = engine.pool
         # AQE store-budget feed: re-derive per-host budgets from the stage
-        # ledger's measured bytes (no-op when RDT_STORE_AQE_BUDGET is off,
-        # the ledger is empty, or the measurement has not changed); getattr:
+        # ledger's measured bytes (no-op when the ledger is empty or the
+        # measurement has not changed); getattr:
         # unit harnesses drive the controller against bare engine stubs
         derive = getattr(engine, "derive_store_budgets", None)
         if derive is not None:

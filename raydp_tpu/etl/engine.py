@@ -1768,11 +1768,9 @@ class Engine:
         """Feed the stage ledger's measured bytes to the store's budget
         plane (``ObjectStoreServer.derive_budgets``): per-host budgets
         re-derive from what stages actually moved instead of only the
-        static ``ENV_STORE_*`` numbers. Gated by ``RDT_STORE_AQE_BUDGET``;
-        skips the RPC when the measured figure has not changed; never
-        raises (a failed derivation leaves the static budgets standing)."""
-        if not bool(knobs.get("RDT_STORE_AQE_BUDGET")):
-            return None
+        static ``ENV_STORE_*`` numbers. Skips the RPC when the measured
+        figure has not changed; never raises (a failed derivation leaves
+        the static budgets standing)."""
         measured = self.measured_stage_bytes()
         if measured <= 0 or measured == self._last_budget_measured:
             return None
@@ -1791,8 +1789,6 @@ class Engine:
         refs to unpin when the stage completes. Advisory and best-effort:
         a store that cannot take hints changes nothing. Deliberately NOT
         a metadata RPC (the data-plane counters stay comparable)."""
-        if not bool(knobs.get("RDT_STORE_STAGE_HINTS")):
-            return []
         seen: Dict[str, ObjectRef] = {}
         for t in tasks:
             for oid in T.task_input_ids(t):

@@ -195,16 +195,6 @@ _ALL = [
        "real host instead of returning no preference. 0 restores the "
        "holder-only ranking; 1 scores remote copies like local ones "
        "(distance-blind)."),
-    _k("RDT_STORE_STAGE_HINTS", "bool", True, PER_ACTION, "etl",
-       "Stage-aware eviction: each stage pins its input blobs in the "
-       "store for its duration and demotes them to evict-first when it "
-       "completes, so LRU only breaks ties among blobs no stage is "
-       "reading. 0 restores pure-LRU spill order."),
-    _k("RDT_STORE_AQE_BUDGET", "bool", True, PER_ACTION, "etl",
-       "Re-derive per-host store budgets from the AQE plane's measured "
-       "stage bytes (clamped to the statically configured capacity), so "
-       "cold bytes spill ahead of demand when the measured working set is "
-       "smaller than the static budget. 0 keeps static budgets only."),
     _k("RDT_STORE_BUDGET_HEADROOM", "float", 1.5, PER_ACTION, "etl",
        "Multiplier on the measured per-stage bytes when deriving store "
        "budgets (derived = min(static capacity, measured x headroom))."),
@@ -227,11 +217,6 @@ _ALL = [
        "device residency."),
     _k("RDT_STAGE_THREADS", "int", 1, PER_ACTION, "training",
        "Column fan-out threads of the native staging core (host decode)."),
-    _k("RDT_TRAIN_SHARD_ROLES", "bool", True, PER_ACTION, "training",
-       "Role-driven parameter sharding (embeddings over fsdp×tensor, "
-       "kernels over fsdp/tensor by dimension, biases replicated) for "
-       "leaves no param_rules entry matches; 0 restores the legacy "
-       "largest-divisible-dim fsdp fallback."),
     _k("RDT_TRAIN_PAD_TAIL", "bool", True, PER_ACTION, "training",
        "Pad-and-mask the ragged final batch under a >1 data extent (or a "
        ">1 stage extent — the pipelined forward reshapes every batch into "
@@ -358,12 +343,6 @@ _ALL = [
     _k("RDT_STREAM_MAX_PARTITIONS", "int", 0, PER_ACTION, "stream",
        "Partitions each micro-batch epoch is split into before its engine "
        "action (0 = auto: min(executors, rows))."),
-    _k("RDT_STREAM_ROLLOUT", "bool", False, PER_ACTION, "stream",
-       "Ship partial_fit exports through a guarded rollout (canary ramp "
-       "+ auto-rollback, doc/serving.md) instead of an immediate "
-       "hot_swap. The partial_fit rollout= argument overrides; rollouts "
-       "block on serving traffic, so the default stays the atomic "
-       "swap."),
     # ---- runtime ------------------------------------------------------------
     _k("RDT_LOG_LEVEL", "str", "INFO", PROCESS_START, "runtime",
        "Log level of spawned processes (node agents, SPMD rank workers)."),

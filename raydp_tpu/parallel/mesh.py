@@ -141,40 +141,22 @@ def param_sharding_rules(mesh, rules: Optional[List[Tuple[str, Tuple]]] = None):
     ``rules`` is an ordered list of ``(substring, spec_tuple)``; the first
     matching substring of the parameter path wins. Leaves no rule matches go
     to the role policy (:mod:`raydp_tpu.parallel.roles` — embeddings over
-    fsdp×tensor, kernels over fsdp/tensor by dimension, biases replicated;
-    opt out with ``RDT_TRAIN_SHARD_ROLES=0``), whose fallback-of-last-resort
-    matches the legacy behavior: replicated (pure DP, the reference's only
-    strategy), or fsdp sharding on the largest divisible dim when an ``fsdp``
-    axis is present.
+    fsdp×tensor, kernels over fsdp/tensor by dimension, biases replicated),
+    which is total: what it cannot shard it replicates (pure DP, the
+    reference's only strategy).
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from raydp_tpu import knobs
     from raydp_tpu.parallel.roles import role_partition_spec
-
-    fsdp = mesh.shape.get("fsdp", 1) > 1
-    use_roles = bool(knobs.get("RDT_TRAIN_SHARD_ROLES"))
 
     def spec_for(path: str, leaf) -> NamedSharding:
         if rules:
             for pat, spec in rules:
                 if pat in path:
                     return NamedSharding(mesh, PartitionSpec(*spec))
-        if use_roles:
-            return NamedSharding(mesh, role_partition_spec(
-                mesh, path, tuple(getattr(leaf, "shape", ()))))
-        if fsdp and hasattr(leaf, "ndim") and leaf.ndim >= 1:
-            dims = getattr(leaf, "shape", ())
-            if dims:
-                # shard the largest dim divisible by the fsdp axis
-                order = sorted(range(len(dims)), key=lambda i: -dims[i])
-                for i in order:
-                    if dims[i] % mesh.shape["fsdp"] == 0 and dims[i] > 1:
-                        spec = [None] * len(dims)
-                        spec[i] = "fsdp"
-                        return NamedSharding(mesh, PartitionSpec(*spec))
-        return NamedSharding(mesh, PartitionSpec())
+        return NamedSharding(mesh, role_partition_spec(
+            mesh, path, tuple(getattr(leaf, "shape", ()))))
 
     def shardings_of(tree):
         flat, treedef = jax.tree_util.tree_flatten_with_path(tree)

@@ -25,9 +25,9 @@ parameter's spec for free: optax moment trees (adam ``mu``/``nu``) mirror the
 parameter paths and shapes, so the same classification fires — the FSDP
 memory win covers the Adam moments, not just the weights.
 
-``param_sharding_rules`` consults this policy (behind ``RDT_TRAIN_SHARD_ROLES``)
-whenever no explicit rule matches, so ``mesh_spec=dict(fsdp=..., tensor=...)``
-alone yields a fully sharded train state.
+``param_sharding_rules`` consults this policy whenever no explicit rule
+matches, so ``mesh_spec=dict(fsdp=..., tensor=...)`` alone yields a fully
+sharded train state.
 """
 
 from __future__ import annotations

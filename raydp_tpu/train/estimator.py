@@ -57,7 +57,7 @@ class EstimatorInterface(ABC):
                     export_every: Optional[int] = None,
                     export_dir: Optional[str] = None,
                     serving=None,
-                    rollout: Optional[bool] = None,
+                    rollout: bool = False,
                     timeout_s: Optional[float] = None
                     ) -> OnlineTrainingResult:
         """Online training over a continuous pipeline (doc/streaming.md).
@@ -83,10 +83,9 @@ class EstimatorInterface(ABC):
         :class:`~raydp_tpu.serve.ServingSession`) is attached — shipped
         into it under live traffic, tagged with the source epoch id:
         either an immediate atomic :meth:`hot_swap`, or, with
-        ``rollout=True`` (default ``RDT_STREAM_ROLLOUT``), a GUARDED
-        rollout — canary weight, ramp, per-version health judgment,
-        auto-promote or auto-rollback (doc/serving.md "Guarded
-        rollouts"). A rolled-back export does NOT stop training: the
+        ``rollout=True``, a GUARDED rollout — canary weight, ramp,
+        per-version health judgment, auto-promote or auto-rollback
+        (doc/serving.md "Guarded rollouts"). A rolled-back export does NOT stop training: the
         outcome lands in ``result.rollouts`` and the next epoch trains
         on — shipping a bad epoch to 100% of traffic is the failure mode
         the guard exists for, a bad epoch itself is routine.
@@ -96,8 +95,6 @@ class EstimatorInterface(ABC):
 
         if export_every is None:
             export_every = int(knobs.get("RDT_STREAM_EXPORT_EVERY"))
-        if rollout is None:
-            rollout = bool(knobs.get("RDT_STREAM_ROLLOUT"))
         if export_every and export_dir is None:
             export_dir = tempfile.mkdtemp(prefix="rdt-online-")
         result = OnlineTrainingResult()

@@ -916,7 +916,14 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # ---- init params from one host batch's shapes ----
         with profiler.trace("fit:feed", "training", what="first_batch"):
             first = cache.init_row if cache is not None \
-                else next(iter(feed.host_iter))
+                else next(iter(feed.host_iter), None)
+        if first is None:
+            rows = sum(feed.host_iter.dataset.block_sizes())
+            raise ValueError(
+                f"the training set has {rows} rows and yields no batch of "
+                f"{self.batch_size} (drop_last={self.drop_last}): no step "
+                f"would run. Lower batch_size, or pass drop_last=False to "
+                f"train on a ragged batch.")
         with profiler.trace("fit:init", "training"):
             inputs0, _ = self._split_batch(
                 {k: jnp.asarray(v[:1]) for k, v in first.items()})

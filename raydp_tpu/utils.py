@@ -135,7 +135,7 @@ COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 def compile_cache_dir() -> str:
     """Directory of JAX's persistent compile cache for an entry point at the
-    root of the checkout (``chip_smoke.py``, ``bench.py``). Call it BEFORE
+    root of the checkout (``chipbench/run.py``). Call it BEFORE
     importing jax; children inherit the variable. Where the environment
     already names a directory that one is used and nothing is set here;
     otherwise ``<checkout>/.jax_cache`` — a fixed path, because the path is

@@ -134,3 +134,20 @@ def test_add_worker_failure_stops_cluster(runtime):
         cluster.add_worker({"CPU": 1.0})
     assert stopped == [True]
     _wait_gone(runtime, "flaky-master")
+
+
+def test_executors_are_held_to_the_cpu_whatever_the_driver_names(
+        monkeypatch, request):
+    """One process owns a chip: the driver that calls ``fit``. An executor is
+    spawned with ``JAX_PLATFORMS=cpu`` however the driver's environment reads
+    (the chip machine's says ``tpu,cpu``), so nothing living in one — a task,
+    a serving replica — can open the chip."""
+    import os
+
+    from raydp_tpu.etl.expressions import col, udf
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    session = request.getfixturevalue("session")    # started under that name
+    seen = udf("string")(lambda v: os.environ.get("JAX_PLATFORMS"))
+    out = session.range(64).withColumn("platform", seen(col("id"))).to_pandas()
+    assert set(out["platform"]) == {"cpu"}

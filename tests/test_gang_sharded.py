@@ -275,6 +275,28 @@ def test_optimizer_state_inherits_param_specs():
     assert any(tuple(s.spec) for s in p_leaves)
 
 
+def test_a_leaf_no_rule_names_is_placed_by_its_role():
+    """``param_rules`` win where they match; every other leaf — a kernel, a
+    bias a legacy largest-dim rule would have cut over fsdp, a step counter
+    with no shape — takes the role policy's spec. There is one fallback."""
+    from jax.sharding import PartitionSpec as P
+
+    from raydp_tpu.parallel import make_mesh, param_sharding_rules
+    from raydp_tpu.parallel.roles import role_partition_spec
+
+    mesh = make_mesh(dict(fsdp=4, tensor=2))
+    tree = {"step": np.zeros((), np.int32),
+            "params": {"Dense_0": {"kernel": np.zeros((16, 8), np.float32),
+                                   "bias": np.zeros((8,), np.float32)},
+                       "embed": {"embedding": np.zeros((32, 8), np.float32)}}}
+    sh = param_sharding_rules(mesh, [("embed", (None, "tensor"))])(tree)
+    assert sh["params"]["embed"]["embedding"].spec == P(None, "tensor")
+    assert sh["params"]["Dense_0"]["kernel"].spec == P("fsdp", "tensor") \
+        == role_partition_spec(mesh, "params/Dense_0/kernel", (16, 8))
+    assert sh["params"]["Dense_0"]["bias"].spec == P()
+    assert sh["step"].spec == P()
+
+
 def test_mesh_equivalence_matrix(session):
     """dp / fsdp / fsdp×tp from mesh_spec alone (no param_rules): per-epoch
     losses match the single-device run — sharding changes the layout, not

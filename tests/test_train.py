@@ -240,6 +240,47 @@ def test_device_cache_parity_and_fallback(session, monkeypatch):
     assert any(r["feed_time_s"] > 0.0 for r in capped.history)
 
 
+def _short_set_estimator(**kw):
+    import optax
+
+    return FlaxEstimator(
+        model=MLP(features=(8,)), optimizer=optax.adam(1e-2), loss="mse",
+        feature_columns=["x1", "x2"], label_column="y", batch_size=64,
+        num_epochs=1, shuffle=False, seed=0, metrics=["mae"], **kw)
+
+
+def test_resident_eval_set_under_one_batch_is_the_tail(session, monkeypatch):
+    """An evaluation set of fewer rows than one batch rides beside a resident
+    training set: its scan has no step to trace and the tail call serves
+    every row — the streaming pass's numbers."""
+    from raydp_tpu.data import from_frame
+
+    ds = from_frame(_linear_df(session, n=640))
+    eval_ds = from_frame(_linear_df(session, n=40))
+    monkeypatch.delenv("RDT_DEVICE_CACHE_MB", raising=False)
+    reports = {}
+    for cache in ("1", "0"):
+        monkeypatch.setenv("RDT_DEVICE_CACHE", cache)
+        reports[cache] = _short_set_estimator().fit(ds, eval_ds).history[-1]
+    assert reports["1"]["feed_time_s"] == 0.0 < reports["0"]["feed_time_s"]
+    for key in ("eval_loss", "eval_mae"):
+        np.testing.assert_allclose(reports["1"][key], reports["0"][key],
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_training_set_under_one_batch_is_refused_by_name(session):
+    """drop_last leaves such a set no step: the fit says so, with both
+    numbers, before a program is traced; drop_last=False trains on it."""
+    from raydp_tpu.data import from_frame
+
+    ds = from_frame(_linear_df(session, n=40))
+    with pytest.raises(ValueError, match=r"40 rows.*batch of 64"):
+        _short_set_estimator().fit(ds)
+    result = _short_set_estimator(drop_last=False).fit(ds)
+    assert [r["steps"] for r in result.history] == [1]
+    assert np.isfinite(result.history[-1]["train_loss"])
+
+
 def test_device_cache_shuffled_training_converges(session, monkeypatch):
     """With shuffle=True the resident path shuffles via an on-device
     permutation per epoch: training must still converge on the linear task

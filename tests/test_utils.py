@@ -1,8 +1,13 @@
 """Unit tests for utils (parity: reference test_spark_utils.py)."""
 
+import os
+import subprocess
+
 import pytest
 
 from raydp_tpu.utils import divide_blocks, memory_string, parse_memory_size
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_parse_memory_size():
@@ -64,3 +69,32 @@ def test_divide_blocks_shuffle_deterministic():
 def test_divide_blocks_not_enough():
     with pytest.raises(ValueError):
         divide_blocks([5], 2)
+
+
+def test_compile_cache_dir_env_wins_else_fixed_path_in_the_checkout(
+        monkeypatch, tmp_path):
+    from raydp_tpu.utils import COMPILE_CACHE_ENV, compile_cache_dir
+
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv(COMPILE_CACHE_ENV, placed)
+    assert compile_cache_dir() == placed
+    assert os.environ[COMPILE_CACHE_ENV] == placed
+    assert not os.path.exists(placed)       # nothing is set or made in code
+
+    monkeypatch.delenv(COMPILE_CACHE_ENV)
+    want = os.path.join(REPO, ".jax_cache")
+    # the same path on every call, in every process: never temp/pid/time
+    assert compile_cache_dir() == want == os.environ[COMPILE_CACHE_ENV]
+    monkeypatch.delenv(COMPILE_CACHE_ENV)
+    assert compile_cache_dir() == want and os.path.isdir(want)
+
+
+def test_compile_cache_is_configured_in_exactly_one_place():
+    """``JAX_COMPILATION_CACHE_DIR`` / ``jax_compilation_cache_dir`` is set by
+    the one helper; a second setter would move the cache under some runs."""
+    hits = subprocess.run(
+        ["git", "grep", "-l", "-i", "-e", "jax_compilation_cache_dir", "--",
+         "*.py", ":!tests/"], cwd=REPO, capture_output=True, text=True)
+    if hits.returncode not in (0, 1):
+        pytest.skip("not a git checkout")
+    assert hits.stdout.split() == ["raydp_tpu/utils.py"]
