@@ -382,6 +382,20 @@ _ALL_METRICS = [
        "output projection runs again). Absent where no block is recomputed "
        "or no norm reads them. doc/long_context.md.",
        label="outputs"),
+    _m("train_ssm_layers_total", COUNTER, "1", "training",
+       "State-space layers (a Mamba-2 mixer alone in its layer) of a "
+       "training model, counted once a built train step by what a "
+       "recomputed layer does with its scan: `plain` (the layer is not "
+       "recomputed) or `rescanned` (`remat_blocks`: the forward scan kernel "
+       "runs again in the backward pass and hands the backward kernel the "
+       "chunks' states; nothing of the scan is kept). doc/long_context.md.",
+       label="scan"),
+    _m("ssd_chunks_total", COUNTER, "1", "training",
+       "Chunks the state-space scan kernels walk, counted where a kernel's "
+       "grid is built (sequences x groups x chunks), by pass: `forward` "
+       "(once a built forward kernel, a recomputed layer's second one "
+       "included) or `backward`. ops/ssd_scan.py.",
+       label="pass"),
     _m("flash_backward_total", COUNTER, "1", "training",
        "Backward passes of the flash-attention kernels, counted where one "
        "is built (a layer call each), by what it is made of: `fused` (one "
@@ -613,6 +627,27 @@ _ALL_SPANS = [
        "concatenation that lay the rotary key into every head's key; "
        "forward, recomputed and backward. The query and output projections "
        "and the flash kernels lie outside it.", kind=SCOPE),
+    _s("ssm", "model",
+       "every op of a state-space layer's Mamba-2 mixer (module name `ssm`): "
+       "its two projections, the convolution, the scan and the gated norm; "
+       "forward, recomputed and backward.", kind=SCOPE),
+    _s("ssm/in_proj", "model",
+       "a state-space mixer's input projection to the gate, `xBC` and `dt`.",
+       kind=SCOPE),
+    _s("ssm/conv", "model",
+       "a state-space mixer's depthwise causal convolution over `xBC` (four "
+       "shifted multiply-adds and a bias) and the SiLU after it.",
+       kind=SCOPE),
+    _s("ssm/scan", "model",
+       "a state-space mixer's chunked scan: the kernels `rdt_ssd_fwd` and "
+       "`rdt_ssd_bwd` and, outside them, `dt`'s softplus, the decays' "
+       "cumulative sums in a chunk, the packing of `dt` for the kernels and "
+       "the split of `xBC`.", kind=SCOPE),
+    _s("ssm/norm", "model",
+       "a state-space mixer's gate (`y * silu(z)`) and the RMSNorm over each "
+       "group of channels after it.", kind=SCOPE),
+    _s("ssm/out_proj", "model",
+       "a state-space mixer's output projection.", kind=SCOPE),
     _s("attn_gate", "model",
        "Under `attn`: the attention output times sigmoid of its gate "
        "projection (`attention_gate`), before the output projection.",

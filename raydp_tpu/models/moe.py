@@ -85,11 +85,19 @@ forward of a step reads the bias the step before left.
 every token takes, beside the routed ones and unweighted. A share of the
 layer (``experts_held``) holds it whole, so when the chips' shares are added
 up it counts once.
+
+**Experts of two matrices** (``gated=False``): ``W_down act(W_up h)`` with no
+gate kernel (``experts_gate``, ``shared_gate`` do not exist), ``activation``
+``relu2`` giving ``relu(.)^2``. Both paths and the shared expert take the one
+first product where gated experts take two (:func:`_hidden`), so the held
+share's walk is two grouped products a trip forward and its hand-written
+backward drops the gate's three.
 """
 
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax
@@ -239,17 +247,16 @@ def _held_share(h, weights, gate, up, down, order, inverse, sizes,
     as the output does. It keeps the inputs alone and forms both first
     products again: under a recomputed block (``remat_blocks``) the
     recomputed forward then has nothing to do."""
-    act_fn = ACTIVATIONS[activation]
     walk = _Walk(order, sizes, chunk)
-    gate, up, down = (w.astype(h.dtype) for w in (gate, up, down))
+    firsts, down = _kernels(h.dtype, gate, up, down)
 
     def body(t, out_rows):
         lo, slot, live, part = walk.trip(t)
         with jax.named_scope("dispatch"):
             xs = _rows_of(h, slot // top_k)
         with jax.named_scope("experts"):
-            act = act_fn(jax.lax.ragged_dot(xs, gate, part)) \
-                * jax.lax.ragged_dot(xs, up, part)
+            act = _hidden(activation, (jax.lax.ragged_dot(xs, w, part)
+                                       for w in firsts))
             # whatever a grouped product leaves in the rows of no group
             # goes no further
             out = jnp.where(live, jax.lax.ragged_dot(act, down, part), 0)
@@ -269,10 +276,10 @@ def _held_share_fwd(h, weights, gate, up, down, order, inverse, sizes,
 
 def _held_share_bwd(top_k, activation, chunk, residuals, g):
     h, weights, gate, up, down, order, inverse, sizes = residuals
-    act_fn = ACTIVATIONS[activation]
     walk = _Walk(order, sizes, chunk)
-    dtype, dim, f = h.dtype, h.shape[1], gate.shape[-1]
-    kernels = tuple(w.astype(dtype) for w in (gate, up, down))
+    dtype, dim, f = h.dtype, h.shape[1], up.shape[-1]
+    firsts, last = _kernels(dtype, gate, up, down)
+    kernels = firsts + (last,)
     flat = weights.reshape(-1)
     rows_d = jax.ShapeDtypeStruct((chunk, dim), dtype)
     rows_f = jax.ShapeDtypeStruct((chunk, f), dtype)
@@ -286,23 +293,21 @@ def _held_share_bwd(top_k, activation, chunk, residuals, g):
             weight = _rows_of(flat, slot)[:, None]
         with jax.named_scope("experts"):
             act, act_pull = jax.vjp(
-                lambda a, b: act_fn(a) * b,
-                jax.lax.ragged_dot(xs, kernels[0], part),
-                jax.lax.ragged_dot(xs, kernels[1], part))
+                lambda *products: _hidden(activation, products),
+                *(jax.lax.ragged_dot(xs, w, part) for w in firsts))
             # the slot's output is act @ down and its gradient weight * g:
             # the weight's own gradient <output, g> is <act, g @ down^T>, so
             # the weight goes on after that product, and on act for down's
             d_act = _transposed(lambda a: jax.lax.ragged_dot(
-                a, kernels[2], part), rows_f)(g_rows)
+                a, last, part), rows_f)(g_rows)
             d_weight = jnp.where(live[:, 0], jnp.sum(
                 act.astype(jnp.float32) * d_act.astype(jnp.float32),
                 axis=-1), 0)
-            d_gate, d_up = act_pull(
+            d_firsts = act_pull(
                 (d_act.astype(jnp.float32) * weight).astype(dtype))
-            d_xs = (_transposed(lambda a: jax.lax.ragged_dot(
-                a, kernels[0], part), rows_d)(d_gate)
-                    + _transposed(lambda a: jax.lax.ragged_dot(
-                        a, kernels[1], part), rows_d)(d_up))
+            d_xs = functools.reduce(operator.add, (
+                _transposed(lambda a, w=w: jax.lax.ragged_dot(a, w, part),
+                            rows_d)(d) for w, d in zip(firsts, d_firsts)))
             d_xs = jnp.where(live, d_xs, 0)
             weighed = (act.astype(jnp.float32) * weight).astype(dtype)
             # the kernels' gradients add up over the trips in float32
@@ -310,16 +315,18 @@ def _held_share_bwd(top_k, activation, chunk, residuals, g):
                 total + _transposed(lambda w, lhs=lhs: jax.lax.ragged_dot(
                     lhs, w, part), kernel)(rhs).astype(jnp.float32)
                 for total, lhs, rhs, kernel in zip(
-                    d_kernels, (xs, xs, weighed), (d_gate, d_up, g_rows),
-                    kernels))
+                    d_kernels, (xs,) * len(firsts) + (weighed,),
+                    (*d_firsts, g_rows), kernels))
         return (_put(d_xs_rows, d_xs, lo), _put(d_weight_rows, d_weight, lo),
                 d_kernels)
 
     d_xs, d_weight, d_kernels = walk.run(body, (
         walk.buffer(dtype, dim), walk.buffer(jnp.float32),
         tuple(jnp.zeros(w.shape, jnp.float32) for w in kernels)))
-    d_kernels = tuple(d.astype(w.dtype)
-                      for d, w in zip(d_kernels, (gate, up, down)))
+    d_kernels = tuple(d.astype(w.dtype) for d, w in zip(
+        d_kernels, (up, down) if gate is None else (gate, up, down)))
+    if gate is None:
+        d_kernels = (None,) + d_kernels
     with jax.named_scope("dispatch"):
         # a token's gradient is the sum over its top_k slots
         d_h = _to_tokens(d_xs, inverse, walk.total, top_k).astype(dtype)
@@ -392,7 +399,27 @@ def balance_bias(state, rate: float):
     return type(state)({k: balance_bias(v, rate) for k, v in state.items()})
 
 
-ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu,
+               "relu2": lambda x: jnp.square(nn.relu(x))}
+
+
+def _hidden(activation: str, products):
+    """What an expert makes of its first products, taken one at a time (an
+    iterator's are formed as they are asked for, the activation between
+    them): gated (two of them), ``act(gate) * up``; of two matrices (one),
+    ``act(up)``."""
+    products = iter(products)
+    hidden = ACTIVATIONS[activation](next(products))
+    for up in products:
+        hidden = hidden * up
+    return hidden
+
+
+def _kernels(dtype, gate, up, down):
+    """(the first products' kernels, the last's) at the activations' dtype:
+    gate and up, or, for experts of two matrices (``gate`` None), up alone."""
+    firsts = (up,) if gate is None else (gate, up)
+    return tuple(w.astype(dtype) for w in firsts), down.astype(dtype)
 
 
 class MoE(nn.Module):
@@ -413,6 +440,7 @@ class MoE(nn.Module):
     routing: str = "softmax"                # or "sigmoid", with its bias
     route_scale: float = 1.0
     shared_dim: int = 0                     # 0: no shared expert
+    gated: bool = True                      # False: experts of two matrices
 
     @nn.compact
     def __call__(self, x, logits=None
@@ -424,12 +452,12 @@ class MoE(nn.Module):
         if not 0 <= first <= first + held <= e or held < 1:
             raise ValueError(f"experts [{first}, {first + held}) of {e}")
         share = held < e
-        act_fn = ACTIVATIONS[self.activation]
         h = x.reshape(-1, dim)
         n = h.shape[0]
         if logits is None:
             router = self.param("router", self.kernel_init, (dim, e))
-        gate = self.param("experts_gate", self.kernel_init, (held, dim, f))
+        gate = self.param("experts_gate", self.kernel_init,
+                          (held, dim, f)) if self.gated else None
         up = self.param("experts_up", self.kernel_init, (held, dim, f))
         down = self.param("experts_down", self.kernel_init, (held, f, dim))
 
@@ -491,8 +519,9 @@ class MoE(nn.Module):
 
         with jax.named_scope("experts"):
             cast = lambda w: w.astype(self.dtype)  # noqa: E731
-            act = act_fn(jax.lax.ragged_dot(xs, cast(gate), sizes)) \
-                * jax.lax.ragged_dot(xs, cast(up), sizes)
+            act = _hidden(self.activation, (
+                jax.lax.ragged_dot(xs, cast(w), sizes)
+                for w in ((gate, up) if self.gated else (up,))))
             out = jax.lax.ragged_dot(act, cast(down), sizes)    # [k * N, D]
 
         with jax.named_scope("combine"):
@@ -512,7 +541,8 @@ class MoE(nn.Module):
             kernel_init=self.kernel_init)
         with jax.named_scope("shared"):
             h = h.astype(self.dtype)
-            return y + dense(h.shape[-1], "shared_down")(
-                ACTIVATIONS[self.activation](
-                    dense(self.shared_dim, "shared_gate")(h))
-                * dense(self.shared_dim, "shared_up")(h))
+            firsts = ("shared_gate", "shared_up") if self.gated \
+                else ("shared_up",)
+            return y + dense(h.shape[-1], "shared_down")(_hidden(
+                self.activation, (dense(self.shared_dim, name)(h)
+                                  for name in firsts)))

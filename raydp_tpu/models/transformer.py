@@ -65,6 +65,20 @@ low-rank latent a token and one rotary key all heads share; keys are wider
 than values), ``q_lora_rank`` gives the queries a latent of their own, and
 ``rope_interleave`` rotates dimension pairs ``(2i, 2i + 1)``.
 
+And, since the ``nemotron_h`` family (state-space hybrids): ``layer_kinds``,
+one letter a layer, makes a layer ONE sub-layer, ``x + mixer(RMSNorm(x))``:
+``M`` a :class:`Mamba2Mixer` (module ``ssm``; its sizes are the one field
+``ssm``, an :class:`SSMSpec`), ``*`` attention alone (``attn``: the window,
+RoPE and grouped K/V of the fields above), ``E`` the expert layer alone
+(``moe``), ``B`` the pair of the older families (attention, then a
+feed-forward part, two norms), which is also what every layer is where
+``layer_kinds`` is empty. ``expert_gated=False`` gives experts (and a shared
+expert) of TWO matrices, ``W_down act(W_up h)``, with ``expert_activation``
+``relu2`` for ``relu(.)^2``. A recomputed state-space layer keeps nothing of
+its scan: the forward scan kernel runs again in the backward pass, where it
+hands the backward kernel the chunks' states (:attr:`TransformerLM.ssm_layers`;
+doc/long_context.md has the chip's numbers).
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -74,6 +88,7 @@ never exist. Called plainly the model still returns them.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Optional, Tuple
 
@@ -254,6 +269,7 @@ class Block(nn.Module):
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
     rope_interleave: bool = False
+    expert_gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -281,7 +297,8 @@ class Block(nn.Module):
                          self.dtype, init, self.first_expert,
                          self.experts_held, self.expert_activation,
                          self.normalize_top_k, self.routing, self.route_scale,
-                         self.shared_expert_dim, name="moe")(h, logits)
+                         self.shared_expert_dim, self.expert_gated,
+                         name="moe")(h, logits)
             return x + post("ln2_post", _ffn_out(self, y)), aux
         # SwiGLU
         dense = lambda n, name: nn.Dense(  # noqa: E731
@@ -338,6 +355,22 @@ class TransformerLM(nn.Module):
     qk_rope_head_dim: Optional[int] = None  # its rotary part (all heads' one)
     v_head_dim: Optional[int] = None        # and a value's width
     rope_interleave: bool = False           # RoPE over pairs (2i, 2i + 1)
+    layer_kinds: str = ""                   # a letter a layer; "": all "B"
+    ssm: Any = None                         # the "M" layers' SSMSpec
+    expert_gated: bool = True               # False: experts of two matrices
+
+    def _kind(self, layer: int) -> str:
+        """``B`` the pair (attention, then a feed-forward part), or the one
+        sub-layer the layer is: ``M`` state-space mixer, ``*`` attention,
+        ``E`` experts."""
+        kinds = self.layer_kinds or "B" * self.num_layers
+        if len(kinds) != self.num_layers or set(kinds) - set("BM*E"):
+            raise ValueError(f"layer_kinds {kinds!r}: {self.num_layers} "
+                             f"letters of 'B', 'M', '*', 'E'")
+        return kinds[layer]
+
+    def _layers_of(self, kinds: str):
+        return [i for i in range(self.num_layers) if self._kind(i) in kinds]
 
     def _windowed(self, layer: int) -> bool:
         pattern = self.window_layers
@@ -353,11 +386,19 @@ class TransformerLM(nn.Module):
         """How many layers of each kind the model has: what
         ``train_attention_layers_total`` counts once a built step. A latent
         layer counts under its kernel's kind and under ``latent``."""
-        windowed = sum(self._windowed(i) for i in range(self.num_layers))
-        kinds = {"window": windowed, "full": self.num_layers - windowed}
+        layers = self._layers_of("B*")
+        windowed = sum(self._windowed(i) for i in layers)
+        kinds = {"window": windowed, "full": len(layers) - windowed}
         if self.kv_lora_rank is not None:
-            kinds["latent"] = self.num_layers
+            kinds["latent"] = len(layers)
         return kinds
+
+    @property
+    def ssm_layers(self):
+        """The state-space layers by what a recomputed one does with its
+        scan: what ``train_ssm_layers_total`` counts once a built step."""
+        return {"rescanned" if self.remat_blocks else "plain":
+                len(self._layers_of("M"))}
 
     @property
     def attention_forward(self):
@@ -375,7 +416,7 @@ class TransformerLM(nn.Module):
         kind = Attention(self.num_heads, self.attention,
                          self.mesh)._dispatch(8192, d_qk, d_v)
         kept = not self.remat_blocks or kind == "flash"
-        return {"once" if kept else "twice": self.num_layers}
+        return {"once" if kept else "twice": len(self._layers_of("B*"))}
 
     @property
     def _share(self) -> bool:
@@ -383,7 +424,9 @@ class TransformerLM(nn.Module):
                     and self.experts_held < self.num_experts)
 
     def _sparse(self, layer: int) -> bool:
-        return bool(self.num_experts) and layer >= self.dense_layers
+        kind = self._kind(layer)
+        return bool(self.num_experts) and (
+            kind == "E" or (kind == "B" and layer >= self.dense_layers))
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False, labels=None,
@@ -413,6 +456,13 @@ class TransformerLM(nn.Module):
                              .save_only_these_names(*kept))
         for i in range(self.num_layers):
             sparse = self._sparse(i)
+            if self._kind(i) != "B":
+                x = _one_sublayer(self, i, kept if self.remat_blocks
+                                  else None)(x)
+                if sparse:
+                    x, layer_aux = x
+                    aux.append(layer_aux)
+                continue
             x = block(self.num_heads, self.mlp_ratio, self.attention,
                       self.mesh, self.dtype,
                       self.ffn_dim if sparse or self.dense_ffn_dim is None
@@ -429,7 +479,8 @@ class TransformerLM(nn.Module):
                       self.shared_expert_dim, self.kv_lora_rank,
                       self.q_lora_rank, self.qk_nope_head_dim,
                       self.qk_rope_head_dim, self.v_head_dim,
-                      self.rope_interleave, name=f"block_{i}")(x)
+                      self.rope_interleave, self.expert_gated,
+                      name=f"block_{i}")(x)
             if sparse:
                 x, layer_aux = x
                 aux.append(layer_aux)
@@ -513,7 +564,8 @@ class TransformerLM(nn.Module):
         or no norm reads them."""
         if not (self.remat_blocks and self.sandwich_norms):
             return {}
-        return {"kept": self.num_layers, "rebuilt": self.num_layers}
+        pairs = len(self._layers_of("B"))
+        return {"kept": pairs, "rebuilt": pairs}
 
 
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -678,6 +730,8 @@ def transformer_param_rules(axis: str = "tensor"):
         # and stay replicated
         ("attn/q_b/kernel", (None, axis, None)),
         ("attn/kv_b/kernel", (None, axis, None)),
+        # a state-space mixer (ssm/in_proj, conv, out_proj, ...) matches no
+        # rule: its heads and groups stay whole on every device
         ("gate/kernel", (None, axis)),
         ("up/kernel", (None, axis)),
         ("down/kernel", (axis, None)),
@@ -812,3 +866,173 @@ def _attention(block):
         block.attention, block.mesh, block.dtype, block.rope_theta,
         block.rope_interleave, block.rms_norm_eps, block.init_std,
         name="attn")
+
+
+# ---------------------------------------------------------------------------
+# Layers of ONE sub-layer and the state-space mixer (the ``nemotron_h``
+# family). Down here for the reason ``SUBLAYER_OUT`` is.
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class SSMSpec:
+    """The sizes of a Mamba-2 mixer, as one field of a model: ``num_heads``
+    heads of ``head_dim`` channels (the inner width is their product, whatever
+    the model's ``dim``), ``n_groups`` groups of heads that share ``B`` and
+    ``C``, a state of ``state_size``, a causal convolution of ``conv_kernel``
+    taps, a scan in chunks of ``chunk_size``, and the limits ``dt``'s bias is
+    initialised between."""
+
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    state_size: int
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+
+def causal_conv(x, kernel, bias):
+    """A depthwise causal convolution as shifted multiply-adds, float32:
+    ``y_t = bias + sum_j kernel[j] * x_{t - (K - 1) + j}`` with zeros before
+    the sequence. ``x [B, T, C]``, ``kernel [K, C]``, ``bias [C]``."""
+    taps, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = bias.astype(jnp.float32)
+    for j in range(taps):
+        y = y + kernel[j].astype(jnp.float32) * padded[:, j:j + t].astype(
+            jnp.float32)
+    return y
+
+
+def _dt_bias_init(spec: SSMSpec):
+    """The inverse softplus of ``dt`` drawn log-uniformly between the spec's
+    limits, floored."""
+    def init(key, shape, dtype=jnp.float32):
+        lo, hi = np.log(spec.dt_min), np.log(spec.dt_max)
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape) * (hi - lo)
+                                 + lo), spec.dt_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 state-space mixer on a normed input ``u [B, T, D]``::
+
+        z, xBC, dt = split(W_in u)        [inner], [inner + 2 G N], [H]
+        xBC = silu(conv(xBC))             depthwise, causal, K taps, a bias
+        x, B, C = split(xBC)              [H, P], [G, N], [G, N]
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)          float32
+        y = scan(x, dt, A, B, C, D)       :func:`raydp_tpu.ops.ssd_scan`
+        y = RMSNorm_by_group(y * silu(z)) * weight
+        out = W_out y
+
+    ``dt``, ``A``, ``D`` and the scan's decays are float32 whatever ``dtype``
+    is. The state is carried through the whole sequence (a packed row's
+    documents are not told apart, as attention attends across them). Each
+    part lies under a scope of its own (``in_proj``, ``conv``, ``scan``,
+    ``norm``, ``out_proj``) so that a trace prices it. Over a mesh the scan
+    is mapped over the batch; heads and groups are not split over ``tensor``,
+    and a ``seq`` axis raises."""
+
+    spec: SSMSpec
+    dtype: Any = jnp.float32
+    rms_norm_eps: float = 1e-6
+    init_std: Optional[float] = None
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, u):
+        from raydp_tpu.ops.ssd_scan import ssd_scan_sharded
+        from raydp_tpu.parallel.mesh import seq_extent
+
+        if self.mesh is not None and seq_extent(self.mesh) > 1:
+            raise NotImplementedError(
+                "a state-space layer takes no seq axis: its state passes "
+                "from a position to the next")
+        s, f32 = self.spec, jnp.float32
+        b, t, dim = u.shape
+        inner, bc = s.num_heads * s.head_dim, s.n_groups * s.state_size
+        init = _init(self.init_std, nn.linear.default_kernel_init)
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name, kernel_init=init)
+        proj = dense(2 * inner + 2 * bc + s.num_heads, "in_proj")(u)
+        z, xbc, dt = jnp.split(proj, (inner, 2 * inner + 2 * bc), axis=-1)
+        kernel = self.param("conv", init, (s.conv_kernel, inner + 2 * bc))
+        bias = self.param("conv_bias", nn.initializers.zeros,
+                          (inner + 2 * bc,))
+        dt_bias = self.param("dt_bias", _dt_bias_init(s), (s.num_heads,))
+        a_log = self.param("A_log", lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
+            (s.num_heads,))
+        skip = self.param("D", nn.initializers.ones, (s.num_heads,))
+        weight = self.param("norm", nn.initializers.ones, (inner,))
+        with jax.named_scope("conv"):
+            xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(self.dtype)
+        with jax.named_scope("scan"):
+            x, b_in, c_in = jnp.split(xbc, (inner, inner + bc), axis=-1)
+            y = ssd_scan_sharded(
+                x.reshape(b, t, s.num_heads, s.head_dim),
+                jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)),
+                -jnp.exp(a_log.astype(f32)),
+                b_in.reshape(b, t, s.n_groups, s.state_size),
+                c_in.reshape(b, t, s.n_groups, s.state_size),
+                skip.astype(f32), self.mesh, chunk=s.chunk_size)
+        with jax.named_scope("norm"):
+            gated = (y.reshape(b, t, s.n_groups, -1).astype(f32)
+                     * nn.silu(z.astype(f32)).reshape(b, t, s.n_groups, -1))
+            var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+            y = ((gated * jax.lax.rsqrt(var + self.rms_norm_eps)).reshape(
+                b, t, inner) * weight).astype(self.dtype)
+        return dense(dim, "out_proj")(y)
+
+
+class Layer(nn.Module):
+    """One pre-norm layer of ONE sub-layer: ``x + mixer(RMSNorm(x))``.
+    ``mixer`` builds the sub-layer's module (called here, so the module lies
+    under this layer by its own name: ``ssm``, ``attn`` or ``moe``); an
+    expert layer's ``aux`` is handed on beside ``x``."""
+
+    mixer: Any
+    rms_norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        out = self.mixer()(RMSNorm(self.rms_norm_eps, name="norm")(x))
+        if isinstance(out, tuple):
+            return x + out[0], out[1]
+        return x + out
+
+
+def _one_sublayer(model, i: int, kept):
+    """Layer ``i`` of a model whose ``layer_kinds`` makes it one sub-layer;
+    recomputed in the backward pass where ``kept`` names what it keeps."""
+    kind = model._kind(i)
+    if kind == "M":
+        if model.ssm is None:
+            raise ValueError("an 'M' layer needs the model's ssm=SSMSpec(..)")
+        mixer = functools.partial(
+            Mamba2Mixer, model.ssm, model.dtype, model.rms_norm_eps,
+            model.init_std, model.mesh, name="ssm")
+    elif kind == "*":
+        mixer = functools.partial(
+            Attention, model.num_heads, model.attention, model.mesh,
+            model.dtype, model.rope_theta, model.qk_norm, model.rms_norm_eps,
+            model.init_std, model.head_dim, model.num_kv_heads,
+            model.sliding_window if model._windowed(i) else None,
+            model._rope(i), model.attention_gate, name="attn")
+    else:
+        from raydp_tpu.models.moe import MoE
+
+        if not model.num_experts:
+            raise ValueError("an 'E' layer needs num_experts")
+        mixer = functools.partial(
+            MoE, model.num_experts, model.experts_per_token,
+            model.ffn_dim or model.mlp_ratio * model.dim, model.dtype,
+            _init(model.init_std, nn.linear.default_kernel_init),
+            model.first_expert, model.experts_held, model.expert_activation,
+            model.normalize_top_k, model.routing, model.route_scale,
+            model.shared_expert_dim, model.expert_gated, name="moe")
+    layer = Layer if kept is None else nn.remat(
+        Layer, policy=jax.checkpoint_policies.save_only_these_names(*kept))
+    return layer(mixer, model.rms_norm_eps, name=f"block_{i}")

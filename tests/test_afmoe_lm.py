@@ -125,9 +125,10 @@ def test_softmax_routing_is_the_routing_as_it_was():
 
 
 # ------------------------------------- (b) the shares of one expert layer
-def _expert_layer(seed=0, shared_dim=16):
+def _expert_layer(seed=0, shared_dim=16, gated=True):
     """An uncut layer's seeded weights (router, 16 experts, the shared
-    expert of width ``shared_dim``), a bias that changes picks, and tokens."""
+    expert of width ``shared_dim``; without a gate's kernels where the
+    experts are of two matrices), a bias that changes picks, and tokens."""
     rng = np.random.default_rng(seed)
     d, f, e, n, s = 32, 16, 16, 48, shared_dim
     full = {"router": rng.normal(0, 0.3, (d, e)),
@@ -137,6 +138,8 @@ def _expert_layer(seed=0, shared_dim=16):
             "shared_gate": {"kernel": rng.normal(0, 0.3, (d, s))},
             "shared_up": {"kernel": rng.normal(0, 0.3, (d, s))},
             "shared_down": {"kernel": rng.normal(0, 0.3, (s, d))}}
+    if not gated:
+        del full["experts_gate"], full["shared_gate"]
     return (_f32(full), _f32(rng.normal(0, 0.2, (e,))),
             _f32(rng.normal(size=(n, d))))
 
@@ -160,12 +163,23 @@ KANANA_LAYER_CFG = {"n_routed_experts": 16, "num_experts_per_tok": 6,
                     "experts_held": 16}
 
 
+# a third family's: experts of TWO matrices with relu(.)^2, 6 a token, one
+# shared expert twice an expert's width, times 2.5 (nemotron-3-nano-30b-a3b)
+NEMOTRON_LAYER_CFG = {"n_routed_experts": 16, "num_experts_per_tok": 6,
+                      "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+                      "n_shared_experts": 1, "first_expert": 0,
+                      "experts_held": 16}
+NEMOTRON = {"gated": False, "activation": "relu2"}
+
+
 def _program_layer(params, bias, m, first, held, shared, mutable=False,
-                   top_k=4, scale=2.826, shared_dim=16):
+                   top_k=4, scale=2.826, shared_dim=16, gated=True,
+                   activation="silu"):
     from raydp_tpu.models.moe import STATE, MoE
     layer = MoE(16, top_k, 16, first_expert=first, experts_held=held,
                 normalize_top_k=True, routing="sigmoid", route_scale=scale,
-                shared_dim=shared_dim if shared else 0)
+                shared_dim=shared_dim if shared else 0, gated=gated,
+                activation=activation)
     variables = {"params": params, STATE: {
         "bias": bias, "counts": np.zeros(16, np.float32)}}
     if mutable:
@@ -173,40 +187,46 @@ def _program_layer(params, bias, m, first, held, shared, mutable=False,
     return layer.apply(variables, m)
 
 
-@pytest.mark.parametrize("config,layer_cfg,none_shared", [
-    (CONFIG, LAYER_CFG, "num_shared_experts"),
-    ("kanana-2-30b-a3b", KANANA_LAYER_CFG, "n_shared_experts")])
+@pytest.mark.parametrize("config,layer_cfg,none_shared,form,held", [
+    (CONFIG, LAYER_CFG, "num_shared_experts", {}, 2),
+    ("kanana-2-30b-a3b", KANANA_LAYER_CFG, "n_shared_experts", {}, 2),
+    ("nemotron-3-nano-30b-a3b", NEMOTRON_LAYER_CFG, "n_shared_experts",
+     NEMOTRON, 1)], ids=["trinity", "kanana", "nemotron_sixteen_shares"])
 def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer(
-        config, layer_cfg, none_shared):
+        config, layer_cfg, none_shared, form, held):
     """Experts 0-1, 2-3, ... 14-15 of 16, 4 a token (6 in the second family,
-    beside a shared MLP two experts wide): each chip routes over all sixteen
-    (by score + bias, the weights over all the choices) and computes its own
-    experts' part; the eight routed parts and the shared expert, counted
-    once, sum to the family's own reference's uncut layer, and the held slots
-    to all slots. A share that holds the shared expert carries it whole."""
+    beside a shared MLP two experts wide; in the third SIXTEEN shares of one
+    expert each, 6 a token, experts of two matrices with ``relu(.)^2``): each
+    chip routes over all sixteen (by score + bias, the weights over all the
+    choices) and computes its own experts' part; the routed parts and the
+    shared expert, counted once, sum to the family's own reference's uncut
+    layer, and the held slots to all slots. A share that holds the shared
+    expert carries it whole."""
     from chipbench import manifest
     reference = manifest.load_module(ROOT, "reference", f"{config}.py")
     top_k = layer_cfg["num_experts_per_tok"]
     sizes = {"top_k": top_k, "scale": layer_cfg.get(
         "route_scale", layer_cfg.get("routed_scaling_factor")),
-        "shared_dim": 16 * layer_cfg[none_shared]}
-    layer_of = functools.partial(_program_layer, **sizes)
-    full, bias, m = _expert_layer(shared_dim=sizes["shared_dim"])
+        "shared_dim": 16 * layer_cfg[none_shared] * (1 if form == {} else 2)}
+    layer_of = functools.partial(_program_layer, **sizes, **form)
+    full, bias, m = _expert_layer(shared_dim=sizes["shared_dim"],
+                                  gated=form.get("gated", True))
+    assert ("experts_gate" in full) == form.get("gated", True)
     want = np.asarray(reference.expert_layer(full, m, bias, layer_cfg))
     shared = np.asarray(reference.expert_layer(
         _share_of(full, 0, 0, True), m, bias,
         dict(layer_cfg, experts_held=0)))
     parts, held_slots = [], 0.0
-    for first in range(0, 16, 2):
-        y, aux = layer_of(_share_of(full, first, 2, False), bias, m, first,
-                          2, shared=False)
-        one = dict(layer_cfg, first_expert=first, experts_held=2,
+    for first in range(0, 16, held):
+        y, aux = layer_of(_share_of(full, first, held, False), bias, m,
+                          first, held, shared=False)
+        one = dict(layer_cfg, first_expert=first, experts_held=held,
                    **{none_shared: 0})
         np.testing.assert_allclose(
-            y, reference.expert_layer(_share_of(full, first, 2, False), m,
+            y, reference.expert_layer(_share_of(full, first, held, False), m,
                                       bias, one), rtol=1e-4, atol=1e-5)
-        with_shared, _ = layer_of(_share_of(full, first, 2, True), bias, m,
-                                  first, 2, shared=True)
+        with_shared, _ = layer_of(_share_of(full, first, held, True), bias,
+                                  m, first, held, shared=True)
         np.testing.assert_allclose(with_shared, np.asarray(y) + shared,
                                    rtol=1e-4, atol=1e-5)
         parts.append(np.asarray(y))
@@ -227,20 +247,31 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer(
         full, m, np.zeros(16, np.float32), layer_cfg)) - want).max() > 0.01
 
 
+@pytest.mark.parametrize("form", ["gated", "two_matrices"])
 @pytest.mark.parametrize("first,held", [(0, 2), (6, 2), (14, 2), (0, 16)])
-def test_a_shares_gradients_and_counts_match_the_references(first, held):
+def test_a_shares_gradients_and_counts_match_the_references(first, held,
+                                                            form):
     """Of the router, the held kernels, the shared expert and the input; the
     bias gets none. Under a mutable collection the forward adds the slots of
     ALL sixteen experts to the counts and leaves the bias alone; called
-    plainly it writes nothing."""
+    plainly it writes nothing. With gated experts (the walk's three grouped
+    products a trip) and with experts of two matrices and ``relu(.)^2`` (two
+    a trip), each against its family's dense reference: the held share's
+    walk where two are held, the single-shot path where all sixteen are."""
     import jax
     import jax.numpy as jnp
+    from chipbench import manifest
     from raydp_tpu.models.moe import STATE
-    _, _, reference = _files()
-    full, bias, m = _expert_layer(seed=first + 1)
+    gated = form == "gated"
+    reference = _files()[2] if gated else manifest.load_module(
+        ROOT, "reference", "nemotron-3-nano-30b-a3b.py")
+    sizes = {} if gated else dict(NEMOTRON, top_k=6, scale=2.5)
+    full, bias, m = _expert_layer(seed=first + 1, gated=gated)
     params = _share_of(full, first, held, True)
-    cfg = dict(LAYER_CFG, first_expert=first, experts_held=held)
+    cfg = dict(LAYER_CFG if gated else NEMOTRON_LAYER_CFG,
+               first_expert=first, experts_held=held)
     w = np.random.default_rng(9).normal(size=m.shape).astype(np.float32)
+    _program_layer = functools.partial(globals()["_program_layer"], **sizes)
     got = jax.grad(lambda p, b, m: jnp.sum(_program_layer(
         p, b, m, first, held, True)[0] * w), (0, 1, 2))(params, bias, m)
     want = jax.grad(lambda p, m: jnp.sum(reference.expert_layer(
@@ -258,13 +289,16 @@ def test_a_shares_gradients_and_counts_match_the_references(first, held):
 
 
 # ------------------------------------------------ (c) the flash op's sizes
+@pytest.mark.parametrize("heads", [16, 32],
+                         ids=["group_of_8", "group_of_16"])
 @pytest.mark.parametrize("widths", [(16, 16), (192, 128)],
                          ids=["one_width", "keys_192_values_128"])
 @pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel"])
 @pytest.mark.parametrize("t,window", [(64, 16), (64, None), (32, 48)])
 def test_flash_with_a_group_of_eight_matches_dense_masked_attention(
-        t, window, interpret, widths):
-    """8 query heads a K/V head (Trinity's group; SmallThinker's is seven)
+        t, window, interpret, widths, heads):
+    """8 query heads a K/V head (Trinity's group; SmallThinker's is seven),
+    and 16 (Nemotron-3-Nano's 32 on 2; no rotation is applied here or there),
     through the op's jnp path and its kernels in interpret mode, with a
     window a quarter of the sequence (as 2048 is of 8,192), without one, and
     with one longer than the sequence: forward and all three gradients; at
@@ -277,10 +311,10 @@ def test_flash_with_a_group_of_eight_matches_dense_masked_attention(
 
     rng = np.random.default_rng(t + (window or 0))
     d, d_v = widths
-    q = jnp.asarray(rng.normal(size=(1, t, 16, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(1, t, heads, d)), jnp.float32)
     k, v = (jnp.asarray(rng.normal(size=(1, t, 2, n)), jnp.float32)
             for n in widths)
-    w = jnp.asarray(rng.normal(size=(1, t, 16, d_v)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(1, t, heads, d_v)), jnp.float32)
     flash = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, window=window, block_q=16, block_k=16,
         interpret=interpret)

@@ -1,0 +1,604 @@
+"""State-space / attention / sparse-expert hybrid LM (``nemotron_h``:
+nemotron-3-nano-30b-a3b): the chunked scan (its ``jax.numpy`` path and its two
+Pallas kernels, interpreted) against the recurrence itself, the causal
+convolution, ``Mamba2Mixer`` alone, the parameter tree, the whole model's
+logits, loss, gradients and balancing bias through the estimator's train
+step, a recomputed state-space layer, and the older families' programs left
+as they were; against the plain reference
+(``chipbench/reference/nemotron-3-nano-30b-a3b.py``: float32 ``jax.numpy``,
+the recurrence one position a step), at small sizes on the CPU, seeded random
+weights. Widths are small here, and only here
+(``tests/chipbench_contract/test_chipbench_nemotron_3_nano.py`` keeps them).
+"""
+
+import copy
+import functools
+import hashlib
+import os
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = "nemotron-3-nano-30b-a3b"
+
+# 4 state-space heads of 8 in 2 groups, a state of 16, chunks of 8, 4 taps;
+# 4 query heads on 2 K/V heads of 8; 16 experts of width 16 of which expert 2
+# is held, 6 a token (as published), a shared expert of 24; the nine-letter
+# pattern; 64 of 512 vocabulary rows, 32 positions (four chunks)
+TINY = {"hidden_size": 32, "mamba_num_heads": 4, "mamba_head_dim": 8,
+        "n_groups": 2, "ssm_state_size": 16, "chunk_size": 8,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8,
+        "moe_intermediate_size": 16, "intermediate_size": 16,
+        "moe_shared_expert_intermediate_size": 24,
+        "n_routed_experts": 16, "first_expert": 2, "experts_held": 1,
+        "vocab_size": 512, "vocab_rows_held": 64, "seq_len": 32,
+        "compared_positions": 8, "compute_dtype": "float32",
+        "attention": "dense", "init_std": 0.3, "remat_blocks": False}
+F32_TOL = 2e-5
+
+
+def _files(**changed):
+    from chipbench import manifest
+    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
+    cfg.update(copy.deepcopy(TINY))
+    cfg["input"] = dict(cfg["input"], eos_id=63)
+    cfg.update(changed)
+    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
+            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
+
+
+def _leaves(tree):
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
+                     for k in path): np.asarray(v) for path, v in flat}
+
+
+def _close(got, want, tol=10 * F32_TOL):
+    got, want = _leaves(got), _leaves(want)
+    assert set(got) == set(want)
+    for name, g in got.items():
+        scale = max(np.abs(want[name]).max(), 1e-3)
+        assert np.abs(g - want[name]).max() <= tol * scale, name
+
+
+def _tokens(cfg, rows, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, cfg["vocab_rows_held"], (rows, cfg["seq_len"]), dtype=np.int32)
+
+
+def _variables(model, tokens, seed=0, bias_std=0.0):
+    """Seeded parameters (the state-space layers' 1-D ones moved off their
+    round initial values) and, ``bias_std``, seeded non-zero biases."""
+    import jax
+    from raydp_tpu.models.moe import STATE
+    v = jax.tree.map(np.array, model.init(jax.random.PRNGKey(seed),
+                                          tokens[:1]))
+    rng = np.random.default_rng(seed)
+    for block in v[STATE].values():
+        block["moe"]["bias"] = rng.normal(
+            0, bias_std, block["moe"]["bias"].shape).astype(np.float32)
+    for block in v["params"].values():
+        for name in ("D", "norm", "conv_bias") if "ssm" in block else ():
+            leaf = block["ssm"][name]
+            block["ssm"][name] = (leaf + rng.normal(
+                0, 0.2, leaf.shape)).astype(np.float32)
+    return v["params"], v[STATE]
+
+
+def _train_step(model, tx, accum=1):
+    """The estimator's own train step round the model (not yet jitted), a
+    state for it, and its metrics."""
+    from flax.training import train_state
+    from raydp_tpu.train.flax_estimator import _make_apply, _make_train_step
+    from raydp_tpu.train.metrics import model_counters
+
+    class State(train_state.TrainState):
+        batch_stats: object = None
+
+    apply_fn = _make_apply(model, False, lambda b: (b["tokens"], b["tokens"]),
+                           None)
+    metrics = model_counters(model)
+    step = _make_train_step(apply_fn, None, metrics, accum, "none")
+
+    def create(params, state):
+        return State.create(apply_fn=model.apply, params=params, tx=tx,
+                            batch_stats=state)
+
+    def arguments(state, tokens):
+        return (state, {"tokens": tokens}, tuple(m.init() for m in metrics),
+                np.float32(0))
+    return step, create, arguments
+
+
+@pytest.fixture
+def scan_kernels(monkeypatch):
+    """A state-space layer's scan runs its two Pallas kernels here,
+    interpreted (off the chip the op would take its jnp path); the fixture
+    counts a kernel's calls in a traced program, through every loop,
+    checkpoint and call it holds."""
+    from raydp_tpu.ops import ssd_scan as ssd
+
+    monkeypatch.setattr(ssd, "ssd_scan", functools.partial(
+        ssd.ssd_scan, interpret=True))
+
+    def count(jaxpr, name):
+        jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+        found = 0
+        for eqn in jaxpr.eqns:
+            found += (eqn.primitive.name == "pallas_call"
+                      and name in str(eqn.params.get("name", "")))
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                        found += count(inner, name)
+        return found
+    return count
+
+
+# ------------------------------------------------------- (a) the scan alone
+def _scan_inputs(b, t, h, p, g, n, seed=0):
+    r = np.random.default_rng(seed)
+    f = lambda *shape: r.normal(size=shape).astype(np.float32)  # noqa: E731
+    return (f(b, t, h, p), (0.5 * np.log1p(np.exp(f(b, t, h)))),
+            -np.exp(r.uniform(0, 1.5, h)).astype(np.float32),
+            f(b, t, g, n), f(b, t, g, n), f(h))
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernels"])
+@pytest.mark.parametrize("b,t,h,p,g,n", [
+    (1, 8, 2, 4, 2, 8), (1, 32, 4, 4, 1, 8), (2, 24, 4, 4, 2, 8),
+    (1, 20, 2, 4, 1, 8)],
+    ids=["one_chunk", "four_chunks_one_group", "batch_of_two",
+         "no_whole_chunks"])
+def test_the_chunked_scan_is_the_recurrence(b, t, h, p, g, n, path):
+    """Values and every gradient (x, dt, A, B, C, D) of the chunked form, in
+    ``jax.numpy`` and through the two kernels interpreted, against the
+    reference's recurrence, one position a step: at one chunk, several
+    chunks, heads that share a group, a batch of two; a sequence that is no
+    whole number of chunks takes the ``jax.numpy`` path either way."""
+    import jax
+    import jax.numpy as jnp
+    from chipbench import manifest
+    from raydp_tpu.ops.ssd_scan import ssd_scan
+
+    reference = manifest.load_module(ROOT, "reference", f"{CONFIG}.py")
+    args = tuple(map(jnp.asarray, _scan_inputs(b, t, h, p, g, n)))
+    g_y = jnp.asarray(np.random.default_rng(1).normal(
+        size=(b, t, h, p)).astype(np.float32))
+    ours = lambda *a: ssd_scan(  # noqa: E731
+        *a, chunk=8, interpret=path == "kernels")
+    got = ours(*args)
+    want = reference.recurrence(*args)
+    assert got.shape == want.shape == (b, t, h, p)
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) <= F32_TOL * scale
+    grads = jax.grad(lambda *a: jnp.sum(ours(*a) * g_y),
+                     argnums=range(6))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(reference.recurrence(*a) * g_y),
+                     argnums=range(6))(*args)
+    for name, got, want in zip("x dt A B C D".split(), grads, wants):
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        assert float(jnp.abs(got - want).max()) <= 10 * F32_TOL * max(
+            float(jnp.abs(want).max()), 1.0), name
+
+
+def test_the_scan_refuses_shapes_that_do_not_belong_together():
+    import jax.numpy as jnp
+    from raydp_tpu.ops.ssd_scan import kernel_ineligible, ssd_scan
+
+    x, dt, a, b, c, d = map(jnp.asarray, _scan_inputs(1, 8, 4, 4, 3, 8))
+    with pytest.raises(ValueError, match="groups that divide the heads"):
+        ssd_scan(x, dt, a, b, c, d)
+    # the compiled kernels take the published shape and say why not another
+    assert kernel_ineligible(16384, 128, 8, 64, 128) is None
+    assert "whole number of chunks" in kernel_ineligible(100, 128, 8, 64, 128)
+    assert "multiples of 128" in kernel_ineligible(256, 64, 8, 64, 128)
+
+
+# ------------------------------------------------- (b) the causal convolution
+def test_the_convolution_is_a_sum_over_shifted_copies_and_looks_at_no_later():
+    import jax.numpy as jnp
+    from chipbench import manifest
+    from raydp_tpu.models.transformer import causal_conv
+
+    reference = manifest.load_module(ROOT, "reference", f"{CONFIG}.py")
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(2, 12, 6)).astype(np.float32)
+    taps = rng.normal(size=(4, 6)).astype(np.float32)
+    bias = rng.normal(size=(6,)).astype(np.float32)
+    got = np.asarray(causal_conv(jnp.asarray(x), taps, bias))
+    np.testing.assert_allclose(got, reference.convolution(
+        jnp.asarray(x), taps, bias), rtol=1e-6, atol=1e-6)
+    # written out: y_t = b + sum_j w_j x_{t-3+j}
+    want = np.zeros_like(x) + bias
+    for t in range(12):
+        for j in range(4):
+            if t - 3 + j >= 0:
+                want[:, t] += taps[j] * x[:, t - 3 + j]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # position t reads nothing after t
+    later = x.copy()
+    later[:, 7:] += 1.0
+    moved = np.asarray(causal_conv(jnp.asarray(later), taps, bias))
+    np.testing.assert_array_equal(moved[:, :7], got[:, :7])
+    assert np.abs(moved[:, 7] - got[:, 7]).max() > 0.01
+
+
+# ------------------------------------------------- (c) the sub-layer alone
+@pytest.mark.parametrize("dtype,kernels,tol", [
+    ("float32", False, 10 * F32_TOL), ("float32", True, 10 * F32_TOL),
+    ("bfloat16", True, 0.03)], ids=["f32_jnp", "f32_kernels", "bf16_kernels"])
+def test_the_mixer_matches_the_references(dtype, kernels, tol, request):
+    import jax
+    import jax.numpy as jnp
+    from chipbench.harness import relative_rms_error
+
+    cfg, pipeline, reference = _files()
+    if kernels:
+        request.getfixturevalue("scan_kernels")
+    from raydp_tpu.models.transformer import Mamba2Mixer
+    spec = pipeline.build_model(cfg).ssm
+    layer = Mamba2Mixer(spec, jnp.dtype(dtype), cfg["layer_norm_epsilon"],
+                        0.3)
+    u = np.random.default_rng(1).normal(size=(2, 32, 32)).astype(np.float32)
+    params = jax.tree.map(np.asarray, layer.init(jax.random.PRNGKey(1),
+                                                 u)["params"])
+    assert set(params) == {"in_proj", "conv", "conv_bias", "dt_bias", "A_log",
+                           "D", "norm", "out_proj"}
+    rng = np.random.default_rng(2)
+    for name in ("conv_bias", "D", "norm"):
+        params[name] = params[name] + rng.normal(
+            0, 0.3, params[name].shape).astype(np.float32)
+    got = layer.apply({"params": params}, jnp.asarray(u, jnp.dtype(dtype)))
+    assert got.dtype == jnp.dtype(dtype) and got.shape == u.shape
+    want = reference.mixer(params, u, cfg)
+    assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
+    # the skip D, the gated norm's weight and the convolution's bias are in
+    # the result
+    for name in ("D", "norm", "conv_bias"):
+        other = dict(params, **{name: np.ones_like(params[name])})
+        assert relative_rms_error(reference.mixer(other, u, cfg),
+                                  want) > 0.01, name
+    # dt_bias and A_log start where the configuration says, in float32
+    dt = np.log1p(np.exp(params["dt_bias"]))
+    assert params["dt_bias"].dtype == np.float32
+    assert 1e-4 <= dt.min() and dt.max() <= 0.1 + 1e-6
+    a = np.exp(params["A_log"])
+    assert 1.0 <= a.min() and a.max() <= 16.0
+
+
+def test_a_state_space_layer_takes_no_seq_axis():
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models.transformer import Mamba2Mixer, SSMSpec
+    from raydp_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"seq": 2}, devices=jax.devices()[:2])
+    layer = Mamba2Mixer(SSMSpec(2, 4, 1, 8, chunk_size=8), mesh=mesh)
+    with pytest.raises(NotImplementedError, match="seq axis"):
+        layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 16)))
+
+
+def test_the_scan_is_mapped_over_a_meshs_batch():
+    """Over ``data`` the scan of each device's rows is the whole scan's."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.ops.ssd_scan import ssd_scan, ssd_scan_sharded
+    from raydp_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    args = tuple(map(jnp.asarray, _scan_inputs(2, 16, 4, 4, 2, 8)))
+    got = jax.jit(lambda *a: ssd_scan_sharded(*a, mesh, chunk=8))(*args)
+    np.testing.assert_allclose(got, ssd_scan(*args, chunk=8), rtol=1e-5,
+                               atol=1e-5)
+
+
+# ----------------------------------------------------- (d) the whole model
+def test_the_parameter_tree_is_the_published_layers():
+    """Names and shapes at the tiny widths; and at the PUBLISHED widths, by
+    ``jax.eval_shape`` (nothing is allocated), a state-space layer is
+    38,742,208 parameters, the attention layer 23,396,352, an expert layer as
+    held 100,122,624, each with a norm of 2,688."""
+    import jax
+    cfg, pipeline, _ = _files()
+    model = pipeline.build_model(cfg)
+    params, state = _variables(model, _tokens(cfg, 1))
+    shapes = {k: v.shape for k, v in _leaves(params).items()}
+    ssm = {"ssm/in_proj/kernel": (32, 2 * 32 + 2 * 32 + 4),
+           "ssm/conv": (4, 32 + 2 * 32), "ssm/conv_bias": (96,),
+           "ssm/dt_bias": (4,), "ssm/A_log": (4,), "ssm/D": (4,),
+           "ssm/norm": (32,), "ssm/out_proj/kernel": (32, 32),
+           "norm/scale": (32,)}
+    assert {k: v for k, v in shapes.items() if k.startswith("block_0/")} \
+        == {f"block_0/{k}": v for k, v in ssm.items()}
+    assert {k: v for k, v in shapes.items() if k.startswith("block_1/")} == {
+        "block_1/moe/router": (32, 16),
+        "block_1/moe/experts_up": (1, 32, 16),      # two matrices: no gate
+        "block_1/moe/experts_down": (1, 16, 32),
+        "block_1/moe/shared_up/kernel": (32, 24),
+        "block_1/moe/shared_down/kernel": (24, 32),
+        "block_1/norm/scale": (32,)}
+    assert {k: v for k, v in shapes.items() if k.startswith("block_5/")} == {
+        "block_5/attn/q/kernel": (32, 4, 8), "block_5/attn/k/kernel":
+        (32, 2, 8), "block_5/attn/v/kernel": (32, 2, 8),
+        "block_5/attn/o/kernel": (4, 8, 32), "block_5/norm/scale": (32,)}
+    assert {k: v.shape for k, v in _leaves(state).items()} == {
+        f"block_{i}/moe/{name}": (16,) for i in (1, 3, 6, 8)
+        for name in ("bias", "counts")}
+    assert model.layer_kinds == "MEMEM*EME"
+    assert model.attention_layers == {"window": 0, "full": 1}
+    assert model.attention_forward == {"twice": 1} or not model.remat_blocks
+    assert model.ssm_layers == {"plain": 4}
+    assert [model._sparse(i) for i in range(9)] == [0, 1, 0, 1, 0, 0, 1, 0, 1]
+
+    from chipbench import manifest
+    published = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
+    big = pipeline.build_model(dict(published, vocab_rows_held=8))
+    tree = jax.eval_shape(lambda: big.init(
+        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)))["params"]
+    count = lambda t: sum(int(np.prod(x.shape))  # noqa: E731
+                          for x in jax.tree.leaves(t))
+    for i, letter in enumerate("MEMEM*EME"):
+        block = tree[f"block_{i}"]
+        assert count(block["norm"]) == 2688
+        assert count(block) - 2688 == {"M": 38742208, "*": 23396352,
+                                       "E": 100122624}[letter]
+    block = tree["block_0"]["ssm"]
+    assert block["in_proj"]["kernel"].shape == (2688, 10304)
+    assert block["conv"].shape == (4, 6144)
+    assert block["norm"].shape == (4096,)
+    assert block["out_proj"]["kernel"].shape == (4096, 2688)
+    assert tree["block_5"]["attn"]["k"]["kernel"].shape == (2688, 2, 128)
+    assert tree["block_1"]["moe"]["experts_up"].shape == (8, 2688, 1856)
+    assert tree["block_1"]["moe"]["shared_up"]["kernel"].shape == (2688, 3712)
+
+
+def test_layer_kinds_say_what_a_layer_is_or_raise():
+    import jax
+    from raydp_tpu.models import TransformerLM
+    from raydp_tpu.models.transformer import SSMSpec
+
+    tokens = np.zeros((1, 8), np.int32)
+    make = lambda **kw: TransformerLM(  # noqa: E731
+        vocab_size=16, dim=16, num_heads=2, attention="dense", **kw)
+    with pytest.raises(ValueError, match="3 letters"):
+        make(num_layers=3, layer_kinds="M*").init(jax.random.PRNGKey(0),
+                                                  tokens)
+    with pytest.raises(ValueError, match="ssm=SSMSpec"):
+        make(num_layers=1, layer_kinds="M").init(jax.random.PRNGKey(0),
+                                                 tokens)
+    with pytest.raises(ValueError, match="num_experts"):
+        make(num_layers=1, layer_kinds="E").init(jax.random.PRNGKey(0),
+                                                 tokens)
+    # a pair beside one-sub-layer layers; the default is all pairs
+    mixed = make(num_layers=3, layer_kinds="BM*",
+                 ssm=SSMSpec(2, 4, 1, 8, chunk_size=8))
+    params = mixed.init(jax.random.PRNGKey(0), tokens)["params"]
+    assert set(params["block_0"]) == {"ln1", "attn", "ln2", "gate", "up",
+                                      "down"}
+    assert set(params["block_1"]) == {"norm", "ssm"}
+    assert set(params["block_2"]) == {"norm", "attn"}
+    assert mixed.attention_layers == {"window": 0, "full": 2}
+    assert make(num_layers=2).attention_layers == {"window": 0, "full": 2}
+    assert make(num_layers=2).ssm_layers == {"plain": 0}
+
+
+@pytest.mark.parametrize("dtype,kernels,tol", [
+    ("float32", False, 10 * F32_TOL), ("float32", True, 10 * F32_TOL),
+    ("bfloat16", True, 0.1)], ids=["f32_jnp", "f32_kernels", "bf16_kernels"])
+def test_forward_logits_match_the_reference(dtype, kernels, tol, request):
+    """What check (a) compares, with biases that move picks: float32 to
+    rounding on the ``jax.numpy`` path and through the scan and flash kernels
+    (interpreted); bfloat16 inside what near-tied picks cost."""
+    from chipbench.harness import relative_rms_error
+    if kernels:
+        request.getfixturevalue("scan_kernels")
+        request.getfixturevalue("forward_flash_kernels")
+    cfg, pipeline, reference = _files(
+        compute_dtype=dtype, attention="flash" if kernels else "dense")
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 2, seed=5)
+    params, state = _variables(model, tokens, bias_std=0.1)
+    variables = {"params": params, "batch_stats": state}
+    got = pipeline.compared(model.apply(variables, tokens), cfg)
+    want = reference.forward(variables, tokens, cfg)
+    assert got.shape == want.shape == (2, 8, 64)
+    assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
+    # the biases matter to the outputs compared
+    zero = reference.forward({"params": params}, tokens, cfg)
+    assert relative_rms_error(zero, want) > 100 * F32_TOL
+
+
+@pytest.mark.parametrize("remat,kernels", [
+    (False, False), (True, False), (True, True)],
+    ids=["kept", "recomputed", "recomputed_kernels"])
+def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
+        remat, kernels, request):
+    """The model's own loss (fused head over the rows held, no auxiliary
+    loss) and the gradient of every leaf of the nine-letter pattern, with
+    seeded biases; then three optimizer steps of the estimator's train step:
+    after each, every expert layer's bias is the reference's ``next_bias`` of
+    the slots ALL experts were picked for in the step's tokens, and the
+    counts are empty again."""
+    import jax
+    import optax
+    if kernels:
+        request.getfixturevalue("scan_kernels")
+        request.getfixturevalue("forward_flash_kernels")
+    cfg, pipeline, reference = _files(
+        remat_blocks=remat, attention="flash" if kernels else "dense")
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 4, seed=1)
+    params, state = _variables(model, tokens, bias_std=0.1)
+    w = np.full(4, 0.25, np.float32)
+    (loss, counts), grads = jax.value_and_grad(
+        lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
+                              tokens, w, method=model.loss_rows),
+        has_aux=True)(params)
+    want_loss, want_grads = jax.jit(jax.value_and_grad(
+        lambda p, t: reference.loss(p, state, t, cfg)))(params, tokens)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
+    counts_of = jax.jit(lambda p, st, t: reference.slot_counts(p, st, t, cfg))
+    picked = np.stack(counts_of(params, state, tokens))
+    assert float(counts[1]) == tokens.size * 6 * 4      # top-6, four layers
+    assert float(counts[0]) == picked.max(axis=1).sum()
+    assert float(counts[2]) == picked[:, 2:3].sum() < float(counts[1])
+
+    step, create, arguments = _train_step(model, optax.sgd(0.05))
+    run = jax.jit(step)
+    now = create(params, state)
+    bias = {name: b["moe"]["bias"] for name, b in state.items()}
+    for i in range(3):
+        batch = _tokens(cfg, 4, seed=10 + i)
+        before = jax.tree.map(np.asarray, (now.params, now.batch_stats))
+        now, _, _ = run(*arguments(now, batch))
+        for (name, b), c in zip(sorted(bias.items()),
+                                counts_of(*before, batch)):
+            assert float(np.sum(c)) == batch.size * 6
+            bias[name] = np.asarray(reference.next_bias(b, c, cfg))
+            got = now.batch_stats[name]["moe"]
+            np.testing.assert_allclose(got["bias"], bias[name], rtol=0,
+                                       atol=1e-7)
+            assert not np.any(np.asarray(got["counts"]))
+    assert any(np.abs(bias[n] - state[n]["moe"]["bias"]).max() > 1e-3
+               for n in bias)
+
+
+# ------------------------------------- (h) a recomputed state-space layer
+def test_a_recomputed_state_space_layer_scans_again(scan_kernels,
+                                                    forward_flash_kernels):
+    """Loss and gradients are the unrecomputed model's; the built step holds
+    the forward scan kernel TWICE a state-space layer (the recomputation runs
+    it again and hands the backward kernel the chunks' states: nothing of the
+    scan is kept) and the backward kernel once, two grouped products an
+    expert trip forward, and counts its layers ``rescanned``."""
+    import jax
+    import optax
+    from raydp_tpu import metrics as registry
+
+    def built(remat):
+        cfg, pipeline, _ = _files(remat_blocks=remat, attention="flash")
+        return cfg, pipeline.build_model(cfg)
+
+    cfg, plain = built(False)
+    _, recomputed = built(True)
+    tokens = _tokens(cfg, 2, seed=2)
+    params, state = _variables(plain, tokens, bias_std=0.1)
+    w = np.full(2, 0.5, np.float32)
+
+    def value_and_grad(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
+                                  tokens, w, method=model.loss_rows)[0]))(
+                                      params)
+
+    loss, grads = value_and_grad(recomputed)
+    want_loss, want_grads = value_and_grad(plain)
+    assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
+    _close(grads, want_grads)
+
+    counted = lambda name: dict(  # noqa: E731
+        registry.snapshot()["counters"].get(name, {}))
+    names = ("train_ssm_layers_total", "train_attention_layers_total",
+             "ssd_chunks_total")
+    before = [counted(n) for n in names]
+    step, create, arguments = _train_step(recomputed, optax.sgd(0.05))
+    program = jax.make_jaxpr(step)(*arguments(create(params, state), tokens))
+    moved = [{k: v - b.get(k, 0) for k, v in counted(n).items()
+              if v != b.get(k, 0)} for n, b in zip(names, before)]
+    assert moved[:2] == [{"rescanned": 4}, {"full": 1}]
+    # sequences x groups x chunks a built kernel: 2 x 2 x 4
+    assert moved[2]["backward"] == 4 * 16 and moved[2]["forward"] >= 8 * 16
+    assert scan_kernels(program, "rdt_ssd_fwd") == 8
+    assert scan_kernels(program, "rdt_ssd_bwd") == 4
+    assert forward_flash_kernels(str(program)) == 1
+    step, create, arguments = _train_step(plain, optax.sgd(0.05))
+    program = jax.make_jaxpr(step)(*arguments(create(params, state), tokens))
+    assert scan_kernels(program, "rdt_ssd_fwd") == 4
+    assert scan_kernels(program, "rdt_ssd_bwd") == 4
+
+
+def test_an_expert_trip_forward_is_two_grouped_products(grouped_products):
+    """The held share's walk of experts of two matrices: two grouped products
+    a trip forward (up, down) where gated experts take three, and fewer in
+    the backward's trip too."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models.moe import MoE
+
+    def products(gated):
+        layer = MoE(16, 6, 16, jnp.float32, first_expert=2, experts_held=1,
+                    activation="relu2", normalize_top_k=True,
+                    routing="sigmoid", gated=gated)
+        x = jnp.ones((8, 32))
+        variables = layer.init(jax.random.PRNGKey(0), x)
+        forward = jax.make_jaxpr(lambda v: layer.apply(v, x)[0])(variables)
+        both = jax.make_jaxpr(jax.grad(lambda v: jnp.sum(layer.apply(
+            v, x)[0])))(variables)
+        return grouped_products(forward), grouped_products(both)
+
+    assert products(False)[0] == 2 and products(True)[0] == 3
+    assert products(False)[1] < products(True)[1]
+
+
+# ------------------------------------------------------- (i) older models
+# sha256 of the estimator's train step as jax lowers it (the StableHLO text,
+# no source locations; every op on its ``jax.numpy`` path) for the four older
+# families' CPU cuts, computed on the commit before this family (6e8d15d)
+# with ``_step_text``. A PR that means to change one of these programs
+# replaces its line.
+PARENT_STEP = {
+    "olmoe-1b-7b":
+        "7116221b2cffe66cee500a7f382bd80cd42ca76514520e3b205d118bb54d4d84",
+    "smallthinker-21b-a3b":
+        "b99d14d9db45a0e37eab3ecf7371976f77704f5a16fed54a871e0d8ffe2ab42a",
+    "trinity-mini":
+        "f025738ce9d12cdf712f0f3ccd0b1f429471430c7400ae420f61dbd3b8d5e698",
+    "kanana-2-30b-a3b":
+        "4a89659b2364d9de464b8b90da2cae401375f066d284ab2ce6d7ce904272ea4a",
+}
+
+
+def _step_text(config, cell):
+    import jax
+    import optax
+    from chipbench import manifest
+    cfg = manifest.load_json(ROOT, "configs", f"{config}.json")
+    pipeline = manifest.load_module(ROOT, "pipelines", f"{config}.py")
+    wl = manifest.load_json(ROOT, "workloads", f"{cell}.json")
+    pipeline.cpu_cut(cfg, wl, 1)
+    model = pipeline.build_model(cfg)
+    tokens = np.zeros((1, wl["seq_len"]), np.int32)
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
+                                               tokens[:, :8]))
+    step, create, arguments = _train_step(model, optax.sgd(0.05))
+    state = jax.eval_shape(lambda: create(
+        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes["params"]),
+        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype),
+                     shapes.get("batch_stats"))))
+    return (model, jax.jit(step).lower(*arguments(state, tokens)).as_text(),
+            shapes["params"])
+
+
+@pytest.mark.parametrize("config,cell", [
+    ("olmoe-1b-7b", "olmoe_1b7b_train"),
+    ("smallthinker-21b-a3b", "smallthinker_21ba3b_16k_train"),
+    ("trinity-mini", "trinity_mini_8k_train"),
+    ("kanana-2-30b-a3b", "kanana2_30ba3b_16k_train")])
+def test_an_older_familys_step_is_the_parents_text(config, cell):
+    """The default layer kind is the pair and experts are gated by default:
+    the blocks build what they built, the expert layer's walk takes its three
+    grouped products in the order it took them, and the lowered step is the
+    text it was."""
+    model, text, params = _step_text(config, cell)
+    assert (model.layer_kinds, model.ssm, model.expert_gated) == (
+        "", None, True)
+    assert not any(model.ssm_layers.values())
+    block = params[f"block_{model.num_layers - 1}"]
+    assert {"ln1", "attn", "ln2", "moe"} <= set(block)
+    assert "experts_gate" in block["moe"] and "norm" not in block
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]
