@@ -24,11 +24,25 @@ time.
 axis last and walked in order; a step loads one chunk of one group: ``x``
 ``[Q, heads x P]`` (the group's heads side by side on the lanes, as the
 projection lays them out: nothing is transposed in HBM), ``B`` and ``C``
-``[Q, N]``, and the group's ``l`` and ``dt`` both as rows and as columns. It
-forms ``C B^T`` once for the group and ``C S_prev`` and the state's update as
-ONE product each over all the group's heads, then a head at a time the masked
-decay ``exp(l_t - l_s)`` (float32), the intra-chunk product and the output.
-The group's state ``[N, heads x P]`` lies in VMEM in float32 through the walk.
+``[Q, N]``, and the group's ``l`` and ``dt`` both as rows ``[2 hg, Q]`` and as
+columns ``[Q, 2 hg]``. The group's state ``[N, heads x P]`` lies in VMEM in
+float32 through the walk. A step does a group's work once a group and only a
+head's own a head:
+
+- *once a group*: ``C B^T``; from the columns, the factors of all its heads
+  at once ``[Q, hg]`` (``exp(l_Q - l_s) dt_s``); the state's update as ONE
+  product over all the heads.
+- *once a piece* of the group's lanes (a 128-lane tile: two heads of 64;
+  :func:`_tiles`), every lane of the piece at once: ``C S_prev``; the output
+  ``y = within + exp(l_t) C S_prev + D x`` and ``x`` weighed for the state's
+  update, each stored once, whole tiles. A ``[Q, hg]`` factor reaches its
+  heads' lanes exactly (:func:`_over_lanes`).
+- *a head*: its masked decay ``exp(l_t - l_s)`` ``[Q, Q]`` (float32, of the
+  difference), the intra-chunk product that takes it (against the piece's
+  ``x``: the head keeps its own lanes of the result, so no operand is cut
+  inside a tile) and ``exp(l_t)`` on the piece's lanes, from the same lane
+  broadcast of ``l_t``.
+
 Differentiated, it also writes the state each chunk STARTS from (float32:
 ``T / Q`` of them a head), which the backward walk reads.
 
@@ -39,14 +53,30 @@ One walk gives ``dx``, ``dB``, ``dC`` (a group's heads summed in float32),
 dy_t . x_s`` and ``W[t, s] = (C_t . B_s) exp(l_t - l_s)``, the explicit
 ``d dt_s = sum_t W E + exp(l_Q - l_s) <dS, B_s (x) x_s>`` and ``dl_t`` (what
 ``l_t`` moves: its row of ``W dt E``, minus its column, the carried state's
-term and the next state's). ``l`` is a cumulative sum of ``dt A``, so outside
-the kernel ``d(dt A)`` is ``dl``'s reverse cumulative sum in the chunk, ``d
-dt`` adds ``A`` times it and ``dA`` sums ``dt`` times it: three small
-``[B, T, H]`` float32 passes.
+term and the next state's). The same three levels:
 
-Decays (``l``, every ``exp``) are float32; the products' operands are the
-activations' dtype and accumulate in float32; a state is rounded to the
-activations' dtype only as a product's operand.
+- *once a group*: ``C B^T``, the factors ``[Q, hg]``; at the end the two
+  per-position sums of all its heads put together ``[Q, 2 hg]`` and written
+  once, the heads' ``[Q, Q]`` column sums collected ``[hg, Q]`` and written
+  once, ``dB``, ``dC`` and the state's gradient as products over all heads.
+- *once a piece*: ``C S_prev`` and ``B dS_end``; ``dx``, ``dy`` decayed to
+  the chunk's start and ``x`` weighed to its end, ``dD``'s sum, each stored
+  once, whole tiles; a head's sums over its own lanes (``dy . C S_prev``,
+  ``B dS . x``, ``<dS, S>``) land in its column of ``[Q, hg]``
+  (:func:`_head_sums`).
+- *a head*: its decay, ``E`` (``dy`` zeroed off the head's lanes against the
+  piece's ``x``), ``Z = decay dt E`` summed over heads, the two sums over
+  ``[Q, Q]`` (``sum_t W E`` and ``sum_s (C B^T) Z``) and ``(W dt)^T dy``.
+
+``l`` is a cumulative sum of ``dt A``, so outside the kernel ``d(dt A)`` is
+``dl``'s reverse cumulative sum in the chunk, ``d dt`` adds ``A`` times it and
+``dA`` sums ``dt`` times it: three small ``[B, T, H]`` float32 passes.
+
+Decays (``l``, every ``exp``) are float32, and a decay between two
+positions is ``exp`` of the DIFFERENCE ``l_t - l_s`` (late in training a
+chunk's ``l`` reaches -200: ``exp(-l_s)`` alone is no float32); the products'
+operands are the activations' dtype and accumulate in float32; a state is
+rounded to the activations' dtype only as a product's operand.
 
 The kernels take ``T`` a multiple of the chunk (and lane-sized blocks: the
 chunk and the state multiples of 128, a group's heads x width too). Anything
@@ -176,14 +206,72 @@ def _visible(chunk: int):
             >= lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
 
 
-def _of_head(rows, cols, head: int, hg: int):
-    """A head's ``l`` and ``dt`` out of its group's packed blocks: as columns
-    ``[Q, 1]`` (a position a row) and as rows ``[1, Q]``, and ``l`` at the
-    chunk's last position ``[1, 1]``."""
+def _tiles(hg: int, p: int):
+    """A group's ``hg x P`` lanes in pieces of whole heads, ``[(heads,
+    lanes)]``: 128-lane tiles where the heads fill them (two heads of 64, a
+    head of 128 or 256), else the whole width in one piece (the tests'
+    widths). What a group does once it does a piece at a time, every lane
+    of the piece at once: a piece's float32 arrays are 16 vregs, the whole
+    width's 64 (all the vregs there are)."""
+    heads = max(1, 128 // p)
+    if hg % heads or (heads * p) % 128:
+        heads = hg
+    return [(range(first, first + heads),
+             slice(first * p, (first + heads) * p))
+            for first in range(0, hg, heads)]
+
+
+def _own_lanes(heads, p: int):
+    """``[1, piece]`` a head: which of a piece's lanes are its own (None:
+    all of them)."""
+    if len(heads) == 1:
+        return [None]
+    lane = lax.broadcasted_iota(jnp.int32, (1, len(heads) * p), 1)
+    return [(lane >= k * p) & (lane < (k + 1) * p) for k in range(len(heads))]
+
+
+def _over_lanes(per_head, heads, p: int):
+    """``[R, hg]`` float32 -> ``[R, piece]``: each of the piece's heads'
+    numbers on its own ``P`` lanes, exactly, on the MXU: the numbers as
+    three bfloat16 pieces whose sum they are (8 + 8 + 8 bits), each piece's
+    one-pass product with the 0/1 ``[hg, piece]`` matrix (one term a sum, so
+    the product is the piece), the three added in float32. (A lane broadcast
+    a head and a select is as exact and books the XLU, which the backward
+    needs for its lane sums; ``Precision.HIGHEST`` is six passes:
+    ``benchmarks/ssd_scan_sweep.py`` times the three.)"""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hg, width = per_head.shape[1], len(heads) * p
+    lane = lax.broadcasted_iota(jnp.int32, (hg, width), 1)
+    first = (lax.broadcasted_iota(jnp.int32, (hg, width), 0) - heads[0]) * p
+    spread = ((lane >= first) & (lane < first + p)).astype(bf16)
+    high = per_head.astype(bf16)
+    rest = per_head - high.astype(f32)
+    middle = rest.astype(bf16)
+    low = (rest - middle.astype(f32)).astype(bf16)
+    return (_nn(high, spread) + _nn(middle, spread)) + _nn(low, spread)
+
+
+def _head_sums(piece, heads, p: int, into):
+    """``[R, piece]`` float32 summed over each head's own lanes, into that
+    head's column of ``into [R, hg]``."""
+    column = lax.broadcasted_iota(jnp.int32, (1, into.shape[1]), 1)
+    for head, mine in zip(heads, _own_lanes(heads, p)):
+        kept = piece if mine is None else jnp.where(mine, piece, 0.0)
+        into = jnp.where(column == head,
+                         jnp.sum(kept, axis=1, keepdims=True), into)
+    return into
+
+
+def _of_head(rows, l, head: int, hg: int, visible, width: int):
+    """What differs by head: its masked decay ``exp(l_t - l_s)`` ``[Q, Q]``
+    (float32, of the difference), its ``dt`` as a row ``[1, Q]``, and the
+    decay to ``t`` from the chunk's start ``exp(l_t)`` on ``width`` lanes
+    (from the lane broadcast of ``l_t`` the first already makes, where the
+    two widths are one)."""
     l_row, dt_row = rows[head:head + 1], rows[hg + head:hg + head + 1]
-    l_col, dt_col = (cols[:, head:head + 1],
-                     cols[:, hg + head:hg + head + 1])
-    return l_col, dt_col, l_row, dt_row, l_row[:, -1:]
+    l_col = l[:, head:head + 1]
+    return (jnp.exp(jnp.where(visible, l_col - l_row, _MASKED)), dt_row,
+            jnp.exp(jnp.broadcast_to(l_col, (l.shape[0], width))))
 
 
 def _fwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, ends_ref,
@@ -202,23 +290,31 @@ def _fwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, ends_ref,
 
     if emit_states:
         states_ref[0, 0, 0] = state[:]      # what this chunk starts from
-    x, bm, cm = x_ref[0], b_ref[0], c_ref[0]
+    bm, cm = b_ref[0], c_ref[0]
     rows, cols = rows_ref[0, 0], cols_ref[0, 0]
-    dtype = x.dtype
-    visible = _visible(chunk)
+    dtype, f32 = x_ref.dtype, jnp.float32
+    # once a group: its heads' per-position factors [Q, hg], and C B^T
+    l, dt = cols[:, :hg], cols[:, hg:]
+    to_end_dt = jnp.exp(l[chunk - 1:] - l) * dt
     cb = _nt(cm, bm)                                        # [t, s] float32
-    carried = _nn(cm, state[:].astype(dtype))               # C S_prev, all heads
-    for head in range(hg):
-        lanes = slice(head * p, (head + 1) * p)
-        l_col, dt_col, l_row, dt_row, l_last = _of_head(rows, cols, head, hg)
-        decay = jnp.exp(jnp.where(visible, l_col - l_row, _MASKED))
-        xh = x[:, lanes]
-        y = _nn((cb * decay * dt_row).astype(dtype), xh)
-        y += jnp.exp(l_col) * carried[:, lanes]
-        y += d_ref[:, lanes] * xh
-        y_ref[0, :, lanes] = y.astype(dtype)
-        weighed[:, lanes] = (xh * (jnp.exp(l_last - l_col)
-                                   * dt_col)).astype(dtype)
+    visible = _visible(chunk)
+    for heads, lanes in _tiles(hg, p):
+        x = x_ref[0, :, lanes]
+        xf = x.astype(f32)
+        carried = _nn(cm, state[:, lanes].astype(dtype))    # C S_prev
+        within = to_t = None
+        for head, mine in zip(heads, _own_lanes(heads, p)):
+            # a head: its decay, and the one product that takes it
+            decay, dt_row, reached = _of_head(rows, l, head, hg, visible,
+                                              xf.shape[1])
+            y = _nn((cb * decay * dt_row).astype(dtype), x)
+            within = y if within is None else jnp.where(mine, y, within)
+            to_t = reached if to_t is None else jnp.where(mine, reached, to_t)
+        # once a piece, all its lanes at once
+        y_ref[0, :, lanes] = (within + to_t * carried
+                              + d_ref[:, lanes] * xf).astype(dtype)
+        weighed[:, lanes] = (xf * _over_lanes(to_end_dt, heads, p)
+                             ).astype(dtype)
     state[:] = state[:] * ends_ref[0, 0, 0] + _tn(bm, weighed[:])
 
 
@@ -233,50 +329,70 @@ def _bwd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, d_ref, ends_ref,
         d_state[:] = jnp.zeros_like(d_state)
         dd_ref[...] = jnp.zeros_like(dd_ref)
 
-    x, bm, cm, dy = x_ref[0], b_ref[0], c_ref[0], dy_ref[0]
+    bm, cm = b_ref[0], c_ref[0]
     rows, cols = rows_ref[0, 0], cols_ref[0, 0]
-    dtype, f32 = x.dtype, jnp.float32
-    s0, ds1 = states_ref[0, 0, 0], d_state[:]               # [N, heads x P]
-    s0c, ds1c = s0.astype(dtype), ds1.astype(dtype)
-    visible = _visible(chunk)
-    last_row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    dtype, f32 = x_ref.dtype, jnp.float32
+    # once a group: its heads' per-position factors [Q, hg], and C B^T
+    l, dt = cols[:, :hg], cols[:, hg:]
+    l_last = l[chunk - 1:]
+    to_end = jnp.exp(l_last - l)
+    to_end_dt = to_end * dt
     cb = _nt(cm, bm)                                        # [t, s]
-    carried = _nn(cm, s0c)                                  # C S_prev
-    from_next = _nn(bm, ds1c)                               # B dS_end
+    visible = _visible(chunk)
+    column = lax.broadcasted_iota(jnp.int32, (1, hg), 1)
+    row = lax.broadcasted_iota(jnp.int32, (hg, 1), 0)
     z_sum = jnp.zeros((chunk, chunk), f32)
-    for head in range(hg):
-        lanes = slice(head * p, (head + 1) * p)
-        l_col, dt_col, l_row, dt_row, l_last = _of_head(rows, cols, head, hg)
-        decay = jnp.exp(jnp.where(visible, l_col - l_row, _MASKED))
-        xh, dyh = x[:, lanes], dy[:, lanes]
-        xf, dyf = xh.astype(f32), dyh.astype(f32)
-        e = _nt(dyh, xh)                                    # dy_t . x_s
-        z = decay * dt_row * e
-        z_sum += z
-        w = cb * decay
-        by_row = jnp.sum(w * e, axis=0, keepdims=True)      # [1, s]
-        moved = jnp.sum(cb * z, axis=1, keepdims=True)      # [t, 1]
-        to_t, to_end = jnp.exp(l_col), jnp.exp(l_last - l_col)
-        moved += to_t * jnp.sum(dyf * carried[:, lanes], axis=1,
-                                keepdims=True)
-        q = to_end * jnp.sum(from_next[:, lanes] * xf, axis=1, keepdims=True)
-        tail = jnp.sum(dt_col * q, axis=0, keepdims=True) + jnp.exp(
-            l_last) * jnp.sum(jnp.sum(ds1[:, lanes] * s0[:, lanes], axis=1,
-                                      keepdims=True), axis=0, keepdims=True)
-        moved += jnp.where(last_row, tail, 0.0)
-        dx = dt_col * _tn(w.astype(dtype), dyh)
-        dx += (to_end * dt_col) * from_next[:, lanes]
-        dx += d_ref[:, lanes] * dyf
-        dx_ref[0, :, lanes] = dx.astype(dtype)
-        by_row_ref[0, 0, head:head + 1, :] = by_row
-        by_col_ref[0, 0, :, head:head + 1] = moved
-        by_col_ref[0, 0, :, hg + head:hg + head + 1] = q
+    by_row = jnp.zeros((hg, chunk), f32)
+    moved, carried_sums, q, kept = (
+        jnp.zeros((chunk, hg), f32), jnp.zeros((chunk, hg), f32),
+        jnp.zeros((chunk, hg), f32), jnp.zeros((1, hg), f32))
+    for heads, lanes in _tiles(hg, p):
+        x, dy = x_ref[0, :, lanes], dy_ref[0, :, lanes]
+        xf, dyf = x.astype(f32), dy.astype(f32)
+        s0, ds1 = states_ref[0, 0, 0, :, lanes], d_state[:, lanes]
+        carried = _nn(cm, s0.astype(dtype))                 # C S_prev
+        from_next = _nn(bm, ds1.astype(dtype))              # B dS_end
+        within = to_t = None
+        for head, mine in zip(heads, _own_lanes(heads, p)):
+            # a head: its decay, the two products and the two sums over
+            # [Q, Q] that take it
+            decay, dt_row, reached = _of_head(rows, l, head, hg, visible,
+                                              xf.shape[1])
+            dyh = dy if mine is None else jnp.where(mine, dyf, 0.0).astype(
+                dtype)                                      # zero off its lanes
+            e = _nt(dyh, x)                                 # dy_t . x_s
+            reach = decay * dt_row
+            z = reach * e
+            z_sum += z
+            by_row = jnp.where(row == head, jnp.sum(
+                cb * decay * e, axis=0, keepdims=True), by_row)
+            moved = jnp.where(column == head, jnp.sum(
+                cb * z, axis=1, keepdims=True), moved)
+            dx = _tn((cb * reach).astype(dtype), dyh)       # 0 off its lanes
+            within = dx if within is None else within + dx
+            to_t = reached if to_t is None else jnp.where(mine, reached, to_t)
+        # once a piece, all its lanes at once
+        to_end_dt_over = _over_lanes(to_end_dt, heads, p)
+        dx_ref[0, :, lanes] = (within + to_end_dt_over * from_next
+                               + d_ref[:, lanes] * dyf).astype(dtype)
         dy_decayed[:, lanes] = (dyf * to_t).astype(dtype)
-        weighed[:, lanes] = (xf * (to_end * dt_col)).astype(dtype)
+        weighed[:, lanes] = (xf * to_end_dt_over).astype(dtype)
         dd_ref[0, :, lanes] += jnp.sum(dyf * xf, axis=0, keepdims=True)
+        carried_sums = _head_sums(dyf * carried, heads, p, carried_sums)
+        q = _head_sums(from_next * xf, heads, p, q)
+        kept = _head_sums(jnp.sum(ds1 * s0, axis=0, keepdims=True), heads, p,
+                          kept)
+    # once a group: the two per-position sums of all its heads, [Q, hg] each
+    q *= to_end
+    tail = jnp.sum(dt * q, axis=0, keepdims=True) + jnp.exp(l_last) * kept
+    last_row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    moved += jnp.exp(l) * carried_sums + jnp.where(last_row, tail, 0.0)
+    by_row_ref[0, 0] = by_row
+    by_col_ref[0, 0] = jnp.concatenate([moved, q], axis=1)
+    s0c, ds1 = states_ref[0, 0, 0].astype(dtype), d_state[:]
     zc = z_sum.astype(dtype)
     dc_ref[0] = (_nn(zc, bm) + _nt(dy_decayed[:], s0c)).astype(dtype)
-    db_ref[0] = (_tn(zc, cm) + _nt(weighed[:], ds1c)).astype(dtype)
+    db_ref[0] = (_tn(zc, cm) + _nt(weighed[:], ds1.astype(dtype))).astype(dtype)
     d_state[:] = ds1 * ends_ref[0, 0, 0] + _tn(cm, dy_decayed[:])
 
 

@@ -139,41 +139,64 @@ def scan_kernels(monkeypatch):
 
 
 # ------------------------------------------------------- (a) the scan alone
-def _scan_inputs(b, t, h, p, g, n, seed=0):
+def _scan_inputs(b, t, h, p, g, n, seed=0, steep=False):
+    """``steep``: ``dt A`` sums to about -200 to -400 inside a chunk of 128
+    (``dt`` 0.1 to 0.2, ``A`` -16: a layer late in training), where
+    ``exp(l_t)`` alone would be no float32."""
     r = np.random.default_rng(seed)
     f = lambda *shape: r.normal(size=shape).astype(np.float32)  # noqa: E731
-    return (f(b, t, h, p), (0.5 * np.log1p(np.exp(f(b, t, h)))),
-            -np.exp(r.uniform(0, 1.5, h)).astype(np.float32),
-            f(b, t, g, n), f(b, t, g, n), f(h))
+    x = f(b, t, h, p)
+    if steep:
+        dt, a = (r.uniform(0.1, 0.2, (b, t, h)).astype(np.float32),
+                 np.full(h, -16.0, np.float32))
+    else:
+        dt, a = (0.5 * np.log1p(np.exp(f(b, t, h))),
+                 -np.exp(r.uniform(0, 1.5, h)).astype(np.float32))
+    return x, dt, a, f(b, t, g, n), f(b, t, g, n), f(h)
 
 
 @pytest.mark.parametrize("path", ["jnp", "kernels"])
-@pytest.mark.parametrize("b,t,h,p,g,n", [
-    (1, 8, 2, 4, 2, 8), (1, 32, 4, 4, 1, 8), (2, 24, 4, 4, 2, 8),
-    (1, 20, 2, 4, 1, 8)],
+@pytest.mark.parametrize("b,t,h,p,g,n,chunk,steep", [
+    (1, 8, 2, 4, 2, 8, 8, False), (1, 32, 4, 4, 1, 8, 8, False),
+    (2, 24, 4, 4, 2, 8, 8, False), (1, 20, 2, 4, 1, 8, 8, False),
+    (1, 16, 8, 4, 1, 8, 8, False), (1, 16, 3, 4, 1, 8, 8, False),
+    (1, 16, 2, 16, 1, 8, 8, False), (1, 32, 4, 4, 2, 8, 8, False),
+    (1, 16, 4, 64, 1, 8, 8, False), (1, 16, 2, 128, 2, 8, 8, False),
+    (1, 256, 2, 4, 1, 8, 128, True)],
     ids=["one_chunk", "four_chunks_one_group", "batch_of_two",
-         "no_whole_chunks"])
-def test_the_chunked_scan_is_the_recurrence(b, t, h, p, g, n, path):
+         "no_whole_chunks", "eight_heads_a_group", "three_heads_a_group",
+         "heads_wider_than_the_state", "two_groups_four_chunks",
+         "two_lane_tiles_of_two_heads", "a_head_a_lane_tile",
+         "steep_decays"])
+def test_the_chunked_scan_is_the_recurrence(b, t, h, p, g, n, chunk, steep,
+                                            path):
     """Values and every gradient (x, dt, A, B, C, D) of the chunked form, in
     ``jax.numpy`` and through the two kernels interpreted, against the
     reference's recurrence, one position a step: at one chunk, several
-    chunks, heads that share a group, a batch of two; a sequence that is no
-    whole number of chunks takes the ``jax.numpy`` path either way."""
+    chunks, heads that share a group (two, three: no power of two, eight: the
+    published group), a batch of two, heads wider than the state, a group
+    whose lanes are two 128-lane tiles of two heads and one whose heads are a
+    tile each (what a group does once it does a tile at a time), and decays
+    so steep that only ``exp`` of a DIFFERENCE ``l_t - l_s`` is a float32; a
+    sequence that is no whole number of chunks takes the ``jax.numpy`` path
+    either way."""
     import jax
     import jax.numpy as jnp
     from chipbench import manifest
     from raydp_tpu.ops.ssd_scan import ssd_scan
 
     reference = manifest.load_module(ROOT, "reference", f"{CONFIG}.py")
-    args = tuple(map(jnp.asarray, _scan_inputs(b, t, h, p, g, n)))
+    args = tuple(map(jnp.asarray, _scan_inputs(b, t, h, p, g, n,
+                                               steep=steep)))
     g_y = jnp.asarray(np.random.default_rng(1).normal(
         size=(b, t, h, p)).astype(np.float32))
     ours = lambda *a: ssd_scan(  # noqa: E731
-        *a, chunk=8, interpret=path == "kernels")
+        *a, chunk=chunk, interpret=path == "kernels")
     got = ours(*args)
     want = reference.recurrence(*args)
     assert got.shape == want.shape == (b, t, h, p)
     scale = float(jnp.abs(want).max())
+    assert bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) <= F32_TOL * scale
     grads = jax.grad(lambda *a: jnp.sum(ours(*a) * g_y),
                      argnums=range(6))(*args)
@@ -181,6 +204,7 @@ def test_the_chunked_scan_is_the_recurrence(b, t, h, p, g, n, path):
                      argnums=range(6))(*args)
     for name, got, want in zip("x dt A B C D".split(), grads, wants):
         assert got.shape == want.shape and got.dtype == want.dtype, name
+        assert bool(jnp.isfinite(got).all()), name
         assert float(jnp.abs(got - want).max()) <= 10 * F32_TOL * max(
             float(jnp.abs(want).max()), 1.0), name
 
@@ -196,6 +220,35 @@ def test_the_scan_refuses_shapes_that_do_not_belong_together():
     assert kernel_ineligible(16384, 128, 8, 64, 128) is None
     assert "whole number of chunks" in kernel_ineligible(100, 128, 8, 64, 128)
     assert "multiples of 128" in kernel_ineligible(256, 64, 8, 64, 128)
+
+
+def test_the_kernel_sweep_runs_interpreted(capsys):
+    """``benchmarks/ssd_scan_sweep.py`` (the two kernels alone; on the chip it
+    times them, and no cell runs it) end to end at a toy shape through the
+    interpreter: every kept form of a group's work gives the forward the
+    float32 ``jax.numpy`` form gives, with finite gradients, and the file
+    another checkout would be timed from is loaded beside it."""
+    import importlib.util
+
+    path = os.path.join(ROOT, "benchmarks", "ssd_scan_sweep.py")
+    spec = importlib.util.spec_from_file_location("ssd_scan_sweep", path)
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    toy = ["--interpret", "--seq-len", "16", "--heads", "6", "--head-dim",
+           "4", "--groups", "2", "--state", "8", "--chunk", "8", "--iters",
+           "1", "--dtype", "float32"]
+    beside = os.path.join(ROOT, "raydp_tpu", "ops", "ssd_scan.py")
+    out = sweep.main(toy + ["--forms", "--beside", beside])
+    assert len(out["forms"]) == len(sweep.FORMS) == 6
+    for name, read in [("beside", out["beside"]), *out["forms"].items()]:
+        assert read["forward_rel_rms"] < F32_TOL, name
+        assert read["gradients_finite"], name
+        assert read["backward"][1] is None      # no device, no kernel time
+        np.testing.assert_allclose(read["digests"], out["beside"]["digests"],
+                                   rtol=1e-5)
+    assert "not measured" in capsys.readouterr().out
+    with pytest.raises(SystemExit, match="needs a TPU"):
+        sweep.main(toy[1:])
 
 
 # ------------------------------------------------- (b) the causal convolution
