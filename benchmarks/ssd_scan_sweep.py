@@ -1,4 +1,4 @@
-"""The state-space scan kernels alone, on the chip.
+"""The state-space scan kernels, and the kernels round them, alone on the chip.
 
 Times ``rdt_ssd_fwd`` (with and without the chunk states written) and
 ``rdt_ssd_bwd`` of ``raydp_tpu/ops/ssd_scan.py`` at one layer's shape — by
@@ -39,11 +39,24 @@ values): forms that lay the factors out exactly print the same digits.
 The form before them all (a head at a time: ``[Q, 1]`` columns, 64-lane
 slices) is the parent's file: ``--beside``.
 
+``--glue`` times what stands round the scan in place of it
+(``raydp_tpu/ops/ssm_glue.py``: the causal convolution with its SiLU and the
+gated grouped norm, two kernels each) at the same layer's shape (``xBC [1,
+16384, 6144]``, ``y`` and ``z`` ``[1, 16384, 4096]``), the ``jax.numpy`` forms
+XLA runs beside the kernels in one process: each op's own ms from a trace
+(the kernels' events; every op of a ``jax.numpy`` call), the bytes a pass has
+to move at 819 GB/s, and a layer's forward + recomputed forward + backward
+both ways (the gate a stage was built against: the kernels' at most half
+XLA's). ``--rows``, ``--lanes`` and ``--walk`` set the module's tile rules
+aside for a measurement, ``--approx-sigmoid`` the sigmoid's exact division (a
+form tried: faster, and not the same arithmetic).
+
 Needs a TPU; ``--interpret`` runs the kernels through the Pallas interpreter
 instead (any platform, toy shapes: the tier-1 smoke test), where a time means
 nothing.
 
 Run: python benchmarks/ssd_scan_sweep.py [--forms] [--beside <ssd_scan.py>]
+     python benchmarks/ssd_scan_sweep.py --glue [--rows R --lanes L --walk W]
 """
 
 from __future__ import annotations
@@ -78,9 +91,11 @@ def _inputs(args):
     return (x, dt, a, bm, cm, d), dy
 
 
-def _kernel_ms(trace_dir: str, kernel: str):
-    """Milliseconds an execution of the device events named after
-    ``kernel`` in the trace under ``trace_dir`` (None: no device plane)."""
+def _kernel_ms(trace_dir: str, kernel: str, iters: int):
+    """Milliseconds an execution (of ``iters``) of the device events named
+    after ``kernel`` in the trace under ``trace_dir``, every event of a call
+    that runs the kernel several times summed (``""``: every op of the
+    call; None: no device plane)."""
     import jax
 
     found = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
@@ -92,7 +107,7 @@ def _kernel_ms(trace_dir: str, kernel: str):
              if plane.name.startswith("/device:TPU:")
              for line in plane.lines if line.name == "XLA Ops"
              for e in line.events if e.name.lstrip("%").startswith(kernel)]
-    return sum(spent) / len(spent) / 1e6 if spent else None
+    return sum(spent) / iters / 1e6 if spent else None
 
 
 def _timed(name: str, kernel: str, fn, operands, iters: int, traced: bool):
@@ -112,9 +127,10 @@ def _timed(name: str, kernel: str, fn, operands, iters: int, traced: bool):
         wall = 1e3 * (time.perf_counter() - t0) / iters
         if traced:
             jax.profiler.stop_trace()
-        on_device = _kernel_ms(trace_dir, kernel) if traced else None
+        on_device = _kernel_ms(trace_dir, kernel, iters) if traced else None
     said = "not measured" if on_device is None else f"{on_device:8.3f} ms"
-    print(f"  {name:28s} kernel {said}   call {wall:8.3f} ms", flush=True)
+    print(f"  {name:28s} {'kernel' if kernel else 'ops   '} {said}   call "
+          f"{wall:8.3f} ms", flush=True)
     return out, wall, on_device
 
 
@@ -154,6 +170,94 @@ def measure(ssd, label: str, args) -> dict:
           f"{out['forward_rel_rms']:.3e}; gradients finite: "
           f"{out['gradients_finite']}\n  digests (y, dx, d dt, dA, dB, dC, dD): "
           + " ".join(f"{v:.9g}" for v in out["digests"]), flush=True)
+    return out
+
+
+HBM_BYTES_A_SECOND = 819e9      # chipbench/peaks.json, TPU v5 lite
+
+
+def glue(sg, args) -> dict:
+    """The two stages of ``sg`` (``raydp_tpu/ops/ssm_glue.py``) round the
+    scan at ``args``' shape, kernels alone beside their ``jax.numpy`` forms
+    in one process: a stage's forward and its backward as the kernels run
+    them (each op's own ms from a trace: the kernels' events, and every
+    op's of the call) and as XLA runs the ``jax.numpy`` form (forward; forward
+    and backward by autodiff: every op's ms), the bytes a pass has to move
+    at 819 GB/s, and a layer's forward + recomputed forward + backward both
+    ways (the gate: the kernels' at most half XLA's)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, t = args.batch, args.seq_len
+    inner, bc = args.heads * args.head_dim, args.groups * args.state
+    widths, channels = (inner, bc, bc), inner + 2 * bc
+    dtype, f32 = jnp.dtype(args.dtype), jnp.float32
+    r = np.random.default_rng(0)
+    normal = lambda *shape: jnp.asarray(r.normal(size=shape), dtype)  # noqa: E731
+    xbc, kernel, bias = (normal(b, t, channels),
+                         jnp.asarray(0.5 * r.normal(size=(4, channels)), f32),
+                         jnp.asarray(0.3 * r.normal(size=channels), f32))
+    grads = tuple(normal(b, t, w) for w in widths)
+    y, z, dout = normal(b, t, inner), normal(b, t, inner), normal(b, t, inner)
+    weight = jnp.asarray(1 + 0.3 * r.normal(size=inner), f32)
+    rules = dict(tile=sg._row_tile(t, args.rows or sg.ROW_TILE),
+                 interpret=args.interpret)
+    conv = dict(offset=0, widths=widths)
+    norm = dict(groups=args.groups, eps=1e-5, offset=0)
+    traced, size = not args.interpret, dtype.itemsize
+    nbytes = lambda columns: columns * b * t * size  # noqa: E731
+    stages = {
+        "conv": dict(
+            operands=(xbc, kernel, bias), grads=grads,
+            forward=lambda *a: sg._conv_fwd_pallas(*a, **conv, **rules),
+            backward=lambda *a: sg._conv_bwd_pallas(*a, **conv, **rules),
+            jnp=lambda *a: sg._conv_jnp(*a, **conv),
+            bytes=(nbytes(2 * channels), nbytes(3 * channels))),
+        "norm": dict(
+            operands=(y, z, weight), grads=dout,
+            forward=lambda *a: sg._norm_fwd_pallas(*a, **norm, **rules),
+            backward=lambda *a: sg._norm_bwd_pallas(*a, **norm, **rules),
+            jnp=lambda *a: sg._norm_jnp(*a, **norm),
+            bytes=(nbytes(3 * inner), nbytes(5 * inner)))}
+    out = {}
+    for stage, of in stages.items():
+        operands, g = of["operands"], of["grads"]
+        both = lambda *a: jax.vjp(of["jnp"], *a[:3])[1](a[3])  # noqa: E731
+        print(f"{stage} (tiles: {rules['tile']} rows, {sg.LANE_TILE} lanes, "
+              f"walked {sg.CONV_WALK} / {sg.NORM_WALK} rows at a time):",
+              flush=True)
+        read = {}
+        got, *read["forward"] = _timed(
+            "forward, kernels", f"rdt_ssm_{stage}_fwd", jax.jit(of["forward"]),
+            operands, args.iters, traced)
+        d_got, *read["backward"] = _timed(
+            "backward, kernels", f"rdt_ssm_{stage}_bwd",
+            jax.jit(of["backward"]), operands + (g,), args.iters, traced)
+        want, *read["forward_jnp"] = _timed(
+            "forward, jax.numpy", "", jax.jit(of["jnp"]), operands,
+            args.iters, traced)
+        d_want, *read["both_jnp"] = _timed(
+            "forward + backward, jax.numpy", "", jax.jit(both),
+            operands + (g,), args.iters, traced)
+        flat = lambda v: [a.astype(f32) for a in jax.tree.leaves(v)]  # noqa: E731
+        read["worst"] = max(
+            float(jnp.abs(a - w).max() / jnp.maximum(jnp.abs(w).max(), 1e-6))
+            for a, w in zip(flat((got, d_got)), flat((want, d_want))))
+        floors = [1e3 * n / HBM_BYTES_A_SECOND for n in of["bytes"]]
+        print(f"  against the jax.numpy form, value and gradients: worst "
+              f"|difference| / max {read['worst']:.3e}; bytes at 819 GB/s: "
+              f"forward {floors[0]:.3f} ms, backward {floors[1]:.3f} ms",
+              flush=True)
+        if traced:
+            ours = 2 * read["forward"][1] + read["backward"][1]
+            xla = read["forward_jnp"][1] + read["both_jnp"][1]
+            read["layer_ms"] = (ours, xla)
+            print(f"  a layer (forward + forward + backward): kernels "
+                  f"{ours:.3f} ms, jax.numpy {xla:.3f} ms: "
+                  f"{100 * ours / xla:.1f}% (the gate: at most 50%)",
+                  flush=True)
+        out[stage] = read
     return out
 
 
@@ -276,6 +380,19 @@ def main(argv=None) -> dict:
                     help="another checkout's ssd_scan.py, timed first")
     ap.add_argument("--forms", action="store_true",
                     help="time each kept form of a group's work")
+    ap.add_argument("--glue", action="store_true",
+                    help="the convolution and the gated norm round the scan "
+                         "(ops/ssm_glue.py) beside their jax.numpy forms, "
+                         "and not the scan")
+    ap.add_argument("--rows", type=int, default=0,
+                    help="--glue: rows a grid step (default: the op's)")
+    ap.add_argument("--lanes", type=int, default=0,
+                    help="--glue: the convolution's lanes a grid step")
+    ap.add_argument("--walk", type=int, default=0,
+                    help="--glue: rows a kernel works on at a time")
+    ap.add_argument("--approx-sigmoid", action="store_true",
+                    help="--glue: the sigmoid's division as the EUP's "
+                         "approximate reciprocal (a form tried)")
     args = ap.parse_args(argv)
 
     import jax
@@ -286,6 +403,29 @@ def main(argv=None) -> dict:
         raise SystemExit(f"ssd_scan_sweep needs a TPU, found platform "
                          f"{jax.default_backend()!r} (--interpret runs the "
                          f"kernels interpreted)")
+    if args.glue:
+        from raydp_tpu.ops import ssm_glue
+
+        print(f"B={args.batch} T={args.seq_len} channels "
+              f"{args.heads * args.head_dim} + 2 x {args.groups * args.state} "
+              f"{args.dtype} on {jax.devices()[0].device_kind}"
+              + (" (interpreted)" if args.interpret else ""), flush=True)
+        # the module's tile rules set aside for a measurement, as --forms does
+        rules = {"LANE_TILE": args.lanes, "CONV_WALK": args.walk,
+                 "NORM_WALK": args.walk}
+        if args.approx_sigmoid:
+            from jax.experimental import pallas as pl
+
+            rules["_sigmoid"] = lambda x: pl.reciprocal(
+                1.0 + jax.numpy.exp(-x), approx=True)
+        built = {rule: getattr(ssm_glue, rule) for rule in rules}
+        for rule, value in rules.items():
+            setattr(ssm_glue, rule, value or built[rule])
+        try:
+            return {"glue": glue(ssm_glue, args)}
+        finally:
+            for rule, value in built.items():
+                setattr(ssm_glue, rule, value)
     why_not = ssd_scan.kernel_ineligible(
         args.seq_len, args.chunk, args.heads // args.groups, args.head_dim,
         args.state)

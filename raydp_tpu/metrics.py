@@ -396,6 +396,15 @@ _ALL_METRICS = [
        "(once a built forward kernel, a recomputed layer's second one "
        "included) or `backward`. ops/ssd_scan.py.",
        label="pass"),
+    _m("ssm_glue_total", COUNTER, "1", "training",
+       "Stages a state-space mixer runs round its scan (the causal "
+       "convolution with its SiLU; the gated grouped RMSNorm), counted "
+       "once a built layer call a stage by the path the call's shapes "
+       "take: `kernel` (one Pallas pass over HBM each way; where the "
+       "program is lowered for anything but a TPU the `jax.numpy` form "
+       "runs in its place) or `jnp` (a shape the kernels do not take: "
+       "`ssm_glue.kernel_ineligible` says why). ops/ssm_glue.py.",
+       label="path"),
     _m("flash_backward_total", COUNTER, "1", "training",
        "Backward passes of the flash-attention kernels, counted where one "
        "is built (a layer call each), by what it is made of: `fused` (one "

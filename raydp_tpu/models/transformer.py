@@ -97,6 +97,9 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from raydp_tpu.ops import ssm_glue
+from raydp_tpu.ops.ssm_glue import causal_conv  # noqa: F401  (its home now)
+
 
 def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray,
                      base: float = 10000.0,
@@ -892,19 +895,6 @@ class SSMSpec:
     dt_floor: float = 1e-4
 
 
-def causal_conv(x, kernel, bias):
-    """A depthwise causal convolution as shifted multiply-adds, float32:
-    ``y_t = bias + sum_j kernel[j] * x_{t - (K - 1) + j}`` with zeros before
-    the sequence. ``x [B, T, C]``, ``kernel [K, C]``, ``bias [C]``."""
-    taps, t = kernel.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    y = bias.astype(jnp.float32)
-    for j in range(taps):
-        y = y + kernel[j].astype(jnp.float32) * padded[:, j:j + t].astype(
-            jnp.float32)
-    return y
-
-
 def _dt_bias_init(spec: SSMSpec):
     """The inverse softplus of ``dt`` drawn log-uniformly between the spec's
     limits, floored."""
@@ -931,9 +921,17 @@ class Mamba2Mixer(nn.Module):
     is. The state is carried through the whole sequence (a packed row's
     documents are not told apart, as attention attends across them). Each
     part lies under a scope of its own (``in_proj``, ``conv``, ``scan``,
-    ``norm``, ``out_proj``) so that a trace prices it. Over a mesh the scan
-    is mapped over the batch; heads and groups are not split over ``tensor``,
-    and a ``seq`` axis raises."""
+    ``norm``, ``out_proj``) so that a trace prices it. Where the program is
+    lowered for a TPU and the shapes allow (:mod:`raydp_tpu.ops.ssm_glue`,
+    :mod:`raydp_tpu.ops.ssd_scan`: whole row tiles and chunks, widths of
+    whole 128-lane tiles) the convolution and the gated norm are one Pallas
+    pass over HBM each way (``rdt_ssm_conv_fwd|bwd`` under ``conv``, reading
+    ``xBC`` out of ``W_in u`` by block index and writing ``x``, ``B``, ``C``
+    as three arrays; ``rdt_ssm_norm_fwd|bwd`` under ``norm``, reading ``z``
+    likewise) round the scan's two kernels; on every other platform and for
+    every other shape all three are their ``jax.numpy`` forms. Over a mesh
+    the three are mapped over the batch; heads and groups are not split over
+    ``tensor``, and a ``seq`` axis raises."""
 
     spec: SSMSpec
     dtype: Any = jnp.float32
@@ -957,7 +955,8 @@ class Mamba2Mixer(nn.Module):
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name, kernel_init=init)
         proj = dense(2 * inner + 2 * bc + s.num_heads, "in_proj")(u)
-        z, xbc, dt = jnp.split(proj, (inner, 2 * inner + 2 * bc), axis=-1)
+        # z, xBC, dt side by side: the two stages read their columns of it
+        dt = proj[..., 2 * inner + 2 * bc:]
         kernel = self.param("conv", init, (s.conv_kernel, inner + 2 * bc))
         bias = self.param("conv_bias", nn.initializers.zeros,
                           (inner + 2 * bc,))
@@ -968,9 +967,9 @@ class Mamba2Mixer(nn.Module):
         skip = self.param("D", nn.initializers.ones, (s.num_heads,))
         weight = self.param("norm", nn.initializers.ones, (inner,))
         with jax.named_scope("conv"):
-            xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(self.dtype)
+            x, b_in, c_in = ssm_glue.conv_silu_sharded(
+                proj, kernel, bias, (inner, bc, bc), self.mesh, offset=inner)
         with jax.named_scope("scan"):
-            x, b_in, c_in = jnp.split(xbc, (inner, inner + bc), axis=-1)
             y = ssd_scan_sharded(
                 x.reshape(b, t, s.num_heads, s.head_dim),
                 jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)),
@@ -979,11 +978,9 @@ class Mamba2Mixer(nn.Module):
                 c_in.reshape(b, t, s.n_groups, s.state_size),
                 skip.astype(f32), self.mesh, chunk=s.chunk_size)
         with jax.named_scope("norm"):
-            gated = (y.reshape(b, t, s.n_groups, -1).astype(f32)
-                     * nn.silu(z.astype(f32)).reshape(b, t, s.n_groups, -1))
-            var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
-            y = ((gated * jax.lax.rsqrt(var + self.rms_norm_eps)).reshape(
-                b, t, inner) * weight).astype(self.dtype)
+            y = ssm_glue.gated_norm_sharded(
+                y.reshape(b, t, inner), proj, weight, s.n_groups,
+                self.rms_norm_eps, self.mesh)
         return dense(dim, "out_proj")(y)
 
 

@@ -112,30 +112,49 @@ def _train_step(model, tx, accum=1):
     return step, create, arguments
 
 
+def _kernels_of(jaxpr, found=None, outer=""):
+    """(name, scopes) of every Pallas kernel of a traced program, through
+    every loop, checkpoint and call it holds (a jitted call's own program
+    names its scopes from the call on: ``outer`` carries the caller's)."""
+    found = [] if found is None else found
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        scopes = f"{outer}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            found.append((str(eqn.params.get("name", "")), scopes))
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    _kernels_of(inner, found, scopes)
+    return found
+
+
 @pytest.fixture
 def scan_kernels(monkeypatch):
     """A state-space layer's scan runs its two Pallas kernels here,
     interpreted (off the chip the op would take its jnp path); the fixture
-    counts a kernel's calls in a traced program, through every loop,
-    checkpoint and call it holds."""
+    counts a kernel's calls in a traced program."""
     from raydp_tpu.ops import ssd_scan as ssd
 
     monkeypatch.setattr(ssd, "ssd_scan", functools.partial(
         ssd.ssd_scan, interpret=True))
+    return lambda jaxpr, name: sum(
+        name in kernel for kernel, _ in _kernels_of(jaxpr))
 
-    def count(jaxpr, name):
-        jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
-        found = 0
-        for eqn in jaxpr.eqns:
-            found += (eqn.primitive.name == "pallas_call"
-                      and name in str(eqn.params.get("name", "")))
-            for value in eqn.params.values():
-                for inner in value if isinstance(value, (tuple, list)) \
-                        else (value,):
-                    if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
-                        found += count(inner, name)
-        return found
-    return count
+
+@pytest.fixture
+def glue_kernels(monkeypatch):
+    """A state-space layer's convolution and gated norm run their four
+    Pallas kernels here, interpreted, in row tiles of 16 (off the chip the
+    ops would take their jnp path); the fixture lists the kernels of a
+    traced program with the scopes they lie under."""
+    from raydp_tpu.ops import ssm_glue
+
+    for op in ("conv_silu", "gated_norm"):
+        monkeypatch.setattr(ssm_glue, op, functools.partial(
+            getattr(ssm_glue, op), rows=16, interpret=True))
+    return _kernels_of
 
 
 # ------------------------------------------------------- (a) the scan alone
@@ -249,6 +268,35 @@ def test_the_kernel_sweep_runs_interpreted(capsys):
     assert "not measured" in capsys.readouterr().out
     with pytest.raises(SystemExit, match="needs a TPU"):
         sweep.main(toy[1:])
+
+
+def test_the_glue_sweep_runs_interpreted(capsys):
+    """``benchmarks/ssd_scan_sweep.py --glue`` (the convolution's and the
+    gated norm's kernels alone beside their ``jax.numpy`` forms; on the chip
+    it times each op and prints the gate) end to end at a toy shape through
+    the interpreter: both stages' values and gradients are the ``jax.numpy``
+    forms', with the module's tile rules set aside and put back."""
+    import importlib.util
+
+    from raydp_tpu.ops import ssm_glue
+
+    path = os.path.join(ROOT, "benchmarks", "ssd_scan_sweep.py")
+    spec = importlib.util.spec_from_file_location("ssd_scan_sweep", path)
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    rules = (ssm_glue.LANE_TILE, ssm_glue.CONV_WALK, ssm_glue.NORM_WALK)
+    out = sweep.main(["--glue", "--interpret", "--seq-len", "64", "--heads",
+                      "4", "--head-dim", "64", "--groups", "2", "--state",
+                      "64", "--rows", "32", "--lanes", "128", "--walk", "16",
+                      "--iters", "1", "--dtype", "float32"])["glue"]
+    assert rules == (ssm_glue.LANE_TILE, ssm_glue.CONV_WALK,
+                     ssm_glue.NORM_WALK)
+    assert set(out) == {"conv", "norm"}
+    for stage, read in out.items():
+        assert read["worst"] < F32_TOL, stage
+        assert read["backward"][1] is None and "layer_ms" not in read
+    said = capsys.readouterr().out
+    assert "not measured" in said and "bytes at 819 GB/s" in said
 
 
 # ------------------------------------------------- (b) the causal convolution
@@ -573,6 +621,105 @@ def test_a_recomputed_state_space_layer_scans_again(scan_kernels,
     program = jax.make_jaxpr(step)(*arguments(create(params, state), tokens))
     assert scan_kernels(program, "rdt_ssd_fwd") == 4
     assert scan_kernels(program, "rdt_ssd_bwd") == 4
+
+
+# widths the four kernels round the scan take: 4 heads of 64 in 2 groups (a
+# norm group of 128 lanes), a state of 64 (B and C of 128), chunks of 16
+LANE_WIDE = {"mamba_num_heads": 4, "mamba_head_dim": 64, "n_groups": 2,
+             "ssm_state_size": 64, "chunk_size": 16}
+
+
+def test_the_mixer_on_its_kernels_is_the_mixer_on_jax_numpy(scan_kernels,
+                                                            glue_kernels):
+    """With the convolution's, the scan's and the norm's kernels interpreted
+    the mixer gives what it gives on the ``jax.numpy`` path, outputs and
+    every parameter's gradient; differentiated, its program names the four
+    kernels round the scan under the scopes they replace (``conv``: one a
+    width, forward and backward; ``norm``) and the scan's two under ``scan``,
+    and each stage counts itself ``kernel`` once a layer call."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu import metrics as registry
+    from raydp_tpu.models.transformer import Mamba2Mixer
+    from raydp_tpu.ops import ssd_scan, ssm_glue
+
+    cfg, pipeline, _ = _files(**LANE_WIDE)
+    layer = Mamba2Mixer(pipeline.build_model(cfg).ssm, jnp.float32,
+                        cfg["layer_norm_epsilon"], 0.3)
+    u = jnp.asarray(np.random.default_rng(1).normal(size=(2, 32, 32)),
+                    jnp.float32)
+    params = jax.tree.map(np.asarray, layer.init(jax.random.PRNGKey(1),
+                                                 u)["params"])
+    rng = np.random.default_rng(2)
+    for name in ("conv_bias", "D", "norm"):
+        params[name] = params[name] + rng.normal(
+            0, 0.3, params[name].shape).astype(np.float32)
+    g = jnp.asarray(rng.normal(size=u.shape), jnp.float32)
+    both = jax.value_and_grad(lambda p, u: jnp.sum(
+        layer.apply({"params": p}, u) * g), argnums=(0, 1), has_aux=False)
+    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
+        "ssm_glue_total", {}))
+    before = counted()
+    program = jax.make_jaxpr(both)(params, u)
+    assert counted().get("kernel", 0) - before.get("kernel", 0) == 2
+    assert counted().get("jnp", 0) == before.get("jnp", 0)
+    found = glue_kernels(program)
+    under = lambda name: sorted(  # noqa: E731
+        scope for kernel, scopes in found if kernel == name
+        for scope in {"conv", "scan", "norm"} & set(scopes.split("/")))
+    assert under("rdt_ssm_conv_fwd") == under("rdt_ssm_conv_bwd") == [
+        "conv"] * 3
+    assert under("rdt_ssm_norm_fwd") == under("rdt_ssm_norm_bwd") == ["norm"]
+    assert under("rdt_ssd_fwd") == under("rdt_ssd_bwd") == ["scan"]
+    assert {k for k, _ in found} == {*ssm_glue.KERNEL_NAMES,
+                                     *ssd_scan.KERNEL_NAMES}
+    value, grads = both(params, u)
+    with pytest.MonkeyPatch.context() as plain:
+        # what a lowering for the CPU picks: every op's jax.numpy form
+        for module in (ssm_glue, ssd_scan):
+            plain.setattr(module, "_by_platform", lambda kernel_fn, jnp_fn,
+                          interpret, *args: jnp_fn(*args))
+        jax.clear_caches()      # an op's traced rules are kept by its arguments
+        assert not glue_kernels(jax.make_jaxpr(both)(params, u))
+        want_value, want_grads = both(params, u)
+    jax.clear_caches()
+    assert abs(float(value) - float(want_value)) <= 10 * F32_TOL * abs(
+        float(want_value))
+    _close(grads, want_grads)
+
+
+@pytest.mark.parametrize("widths,path", [({}, "jnp"), (LANE_WIDE, "kernel")],
+                         ids=["narrow_widths", "lane_wide"])
+def test_a_built_step_counts_its_glue_stages(widths, path, scan_kernels,
+                                             glue_kernels):
+    """``ssm_glue_total``: two stages a state-space layer of a built step
+    (four layers: eight), ``jnp`` at the tests' narrow widths (no kernel of
+    theirs in the program) and ``kernel`` at widths of whole lane tiles (a
+    recomputed layer: its convolution's and its norm's forward kernels run
+    twice, as its scan's does, the backward ones once)."""
+    import jax
+    import optax
+    from raydp_tpu import metrics as registry
+
+    cfg, pipeline, _ = _files(remat_blocks=True, **widths)
+    model = pipeline.build_model(cfg)
+    tokens = _tokens(cfg, 2, seed=2)
+    params, state = _variables(model, tokens, bias_std=0.1)
+    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
+        "ssm_glue_total", {}))
+    step, create, arguments = _train_step(model, optax.sgd(0.05))
+    built = create(params, state)
+    before = counted()
+    program = jax.make_jaxpr(step)(*arguments(built, tokens))
+    moved = {k: v - before.get(k, 0) for k, v in counted().items()
+             if v != before.get(k, 0)}
+    assert moved == {path: 8}
+    names = [kernel for kernel, _ in glue_kernels(program)]
+    per_layer = {"rdt_ssm_conv_fwd": 6, "rdt_ssm_conv_bwd": 3,
+                 "rdt_ssm_norm_fwd": 2, "rdt_ssm_norm_bwd": 1}
+    for kernel, calls in per_layer.items():
+        assert names.count(kernel) == (4 * calls if path == "kernel" else 0)
+    assert names.count("rdt_ssd_fwd") == 8 and names.count("rdt_ssd_bwd") == 4
 
 
 def test_an_expert_trip_forward_is_two_grouped_products(grouped_products):
