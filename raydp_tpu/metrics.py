@@ -358,7 +358,9 @@ _ALL_METRICS = [
     _m("train_attention_layers_total", COUNTER, "1", "training",
        "Attention layers of a training model, counted once a built train "
        "step by kind: `window` (a sliding window: a query sees itself and "
-       "the window - 1 keys before it) or `full` (every key up to its own); "
+       "the window - 1 keys before it), `blockdiff` (the block-diffusion "
+       "mask over a clean and a noised copy of each row) or `full` (every "
+       "key up to its own); "
        "a latent-attention layer (keys and values from one low-rank latent "
        "a token) counts under its kernel's kind and under `latent` too. "
        "doc/models.md.",
@@ -427,8 +429,10 @@ _ALL_METRICS = [
        "where a kernel's grid is built (once a built forward kernel, once "
        "a built one-kernel backward, twice a built backward pair; heads x "
        "pairs): `computed`, `skipped_causal` "
-       "(wholly above the diagonal) and `skipped_window` (wholly behind the "
-       "window: never fetched). ops/flash_attention.py.",
+       "(wholly above the diagonal), `skipped_window` (wholly behind the "
+       "window: never fetched) and `skipped_blockdiff` (no visible pair "
+       "under the block-diffusion mask: 176 of 256 pairs of 1024-blocks at "
+       "8,192 tokens a row). ops/flash_attention.py.",
        label="fate"),
     _m("flash_tiles_total", COUNTER, "1", "training",
        "Tiles (half a block a side) of the `computed` block pairs "
@@ -443,6 +447,21 @@ _ALL_METRICS = [
        "differ, a window that is no multiple of the block, a block under "
        "two tiles of 128 lanes). ops/flash_attention.py.",
        label="fate"),
+    _m("flash_mask_total", COUNTER, "1", "training",
+       "Calls of the flash-attention op, counted where one is traced (a "
+       "layer call each), by the mask it was given: `causal` (every key up "
+       "to a query's own), `window` (and no further back than the window), "
+       "`blockdiff` (the block-diffusion mask over a clean and a noised "
+       "copy of a row: `blockdiff_visible`) or `none`. "
+       "ops/flash_attention.py.",
+       label="mask"),
+    _m("train_diffusion_tokens_total", COUNTER, "1", "training",
+       "Tokens a block-diffusion language model trained on, summed on the "
+       "device inside the train step and added here with each epoch's "
+       "loss: `all` every token of every row, `masked` those the step's "
+       "noise replaced by the mask id (the only ones with a loss term; "
+       "about half under t ~ U(0, 1]). models/transformer.py.",
+       label="tokens"),
     _m("train_accum_steps", GAUGE, "1", "training",
        "Gradient-accumulation microbatches per optimizer step this fit is "
        "running with (1 = unaccumulated; the RDT_TRAIN_ACCUM_STEPS / "
@@ -628,6 +647,16 @@ _ALL_SPANS = [
        "kernels `rdt_flash_win_fwd`, `rdt_flash_win_bwd_dkdv_dq`; "
        "`rdt_flash_win_bwd_dkdv` and `rdt_flash_win_bwd_dq` where the "
        "backward takes two).", kind=SCOPE),
+    _s("attn_blockdiff", "model",
+       "Under `attn`: the attention itself of a layer under the "
+       "block-diffusion mask (the kernels `rdt_flash_bd_fwd`, "
+       "`rdt_flash_bd_bwd_dkdv_dq`; `rdt_flash_bd_bwd_dkdv` and "
+       "`rdt_flash_bd_bwd_dq` where the backward takes two).", kind=SCOPE),
+    _s("diffusion", "model",
+       "A block-diffusion language model's noise: the draw of one t a block "
+       "and one Bernoulli a token, the masked copy of the row, laying out "
+       "`[clean ; noised]` and the per-position weights of the loss "
+       "(`models/transformer.py`).", kind=SCOPE),
     _s("attn/latent", "model",
        "latent attention's K/V path inside an `attn` scope: the "
        "down-projection to the K/V latent and the one rotary key all heads "

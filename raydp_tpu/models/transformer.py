@@ -79,6 +79,18 @@ its scan: the forward scan kernel runs again in the backward pass, where it
 hands the backward kernel the chunks' states (:attr:`TransformerLM.ssm_layers`;
 doc/long_context.md has the chip's numbers).
 
+And, since the ``sdar_moe`` family (block diffusion): ``diffusion``, a
+:class:`BlockDiffusionSpec`, changes the OBJECTIVE and leaves the layers
+alone: the model lays out ``[row ; noised row]`` (2T positions, position ids
+0..T-1 twice), noises the copy itself (:func:`block_diffusion_noise`, under
+the scope ``diffusion``; the key is the ``diffusion`` stream a train step
+hands it, :attr:`TransformerLM.rng_streams`, or the spec's fixed one), runs
+every attention under the flash kernels' ``blockdiff`` mask, and its logits
+and its loss are the noised half's: position ``i`` against token ``i`` under
+the weight ``masked_i / t`` (:func:`lm_head_loss`'s ``position_weights``).
+``embed_init_std`` gives the embedding's rows a std of their own beside
+``init_std`` (doc/training.md says when that matters).
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -154,6 +166,7 @@ class Attention(nn.Module):
     window: Optional[int] = None            # None: every key up to its own
     rope: bool = True
     gate: bool = False                      # o(attn * sigmoid(W_g x))
+    blockdiff: Optional[int] = None         # the block-diffusion mask's Bd
 
     def _dispatch(self, t: int, head_dim: int, d_v=None) -> str:
         from raydp_tpu.ops.flash_attention import kernel_ineligible
@@ -203,23 +216,29 @@ class Attention(nn.Module):
 
         if self.rope:
             positions = jnp.arange(t)
+            if self.blockdiff is not None:
+                positions = _copies_positions(t)
             q = rotary_embedding(q, positions, self.rope_theta)
             k = rotary_embedding(k, positions, self.rope_theta)
 
         kind = self._dispatch(t, head_dim)
-        with jax.named_scope(
-                "attn_full" if self.window is None else "attn_window"):
+        mask = {"window": self.window}
+        scope = "attn_full" if self.window is None else "attn_window"
+        if self.blockdiff is not None:
+            mask, scope = {"blockdiff": self.blockdiff}, "attn_blockdiff"
+        with jax.named_scope(scope):
             if kind == "ring":
-                if self.window or kv_heads != self.num_heads:
+                if (self.window or self.blockdiff
+                        or kv_heads != self.num_heads):
                     raise NotImplementedError(
-                        "ring attention takes no window and no grouped K/V")
+                        "ring attention takes no window, no block-diffusion "
+                        "mask and no grouped K/V")
                 out = ring_attention_sharded(q, k, v, self.mesh, causal=True)
             elif kind == "flash":
                 out = flash_attention_sharded(q, k, v, self.mesh, causal=True,
-                                              window=self.window)
+                                              **mask)
             else:
-                out = dense_attention(q, k, v, causal=True,
-                                      window=self.window)
+                out = dense_attention(q, k, v, causal=True, **mask)
         if self.gate:
             g = dense("gate", self.num_heads)(x)
             with jax.named_scope("attn_gate"):
@@ -273,6 +292,7 @@ class Block(nn.Module):
     v_head_dim: Optional[int] = None
     rope_interleave: bool = False
     expert_gated: bool = True
+    blockdiff: Optional[int] = None         # Attention's, handed on
 
     @nn.compact
     def __call__(self, x):
@@ -361,6 +381,9 @@ class TransformerLM(nn.Module):
     layer_kinds: str = ""                   # a letter a layer; "": all "B"
     ssm: Any = None                         # the "M" layers' SSMSpec
     expert_gated: bool = True               # False: experts of two matrices
+    diffusion: Any = None                   # a BlockDiffusionSpec: the
+    # model trains the block-diffusion objective, not next-token prediction
+    embed_init_std: Optional[float] = None  # None: the embedding's is init_std
 
     def _kind(self, layer: int) -> str:
         """``B`` the pair (attention, then a feed-forward part), or the one
@@ -392,6 +415,8 @@ class TransformerLM(nn.Module):
         layers = self._layers_of("B*")
         windowed = sum(self._windowed(i) for i in layers)
         kinds = {"window": windowed, "full": len(layers) - windowed}
+        if self.diffusion is not None:
+            kinds = {"blockdiff": len(layers)}
         if self.kv_lora_rank is not None:
             kinds["latent"] = len(layers)
         return kinds
@@ -422,6 +447,17 @@ class TransformerLM(nn.Module):
         return {"once" if kept else "twice": len(self._layers_of("B*"))}
 
     @property
+    def _blockdiff(self) -> Optional[int]:
+        return None if self.diffusion is None else self.diffusion.block
+
+    @property
+    def rng_streams(self):
+        """The random streams a train step has to hand the model (flax's
+        ``rngs=``): ``diffusion`` where the objective draws noise, else
+        none, and a step built round the model is then the step it was."""
+        return () if self.diffusion is None else ("diffusion",)
+
+    @property
     def _share(self) -> bool:
         return bool(self.num_experts and self.experts_held is not None
                     and self.experts_held < self.num_experts)
@@ -441,8 +477,17 @@ class TransformerLM(nn.Module):
         vocab and T=8192 those logits are ~2 GB per direction of pure HBM
         traffic, the single largest non-kernel cost in the train step.
         ``labels`` [B, T] (the tokens themselves) and ``weights`` [B] yield
-        what :meth:`loss_rows` returns."""
-        init = _init(self.init_std, nn.linear.default_embed_init)
+        what :meth:`loss_rows` returns. With ``diffusion`` the model runs
+        ``[tokens ; noised copy]``, 2T positions, and its logits and its loss
+        are those of the noised half (:func:`_noised_row`)."""
+        noise = None
+        if self.diffusion is not None:
+            if self.sliding_window or self.kv_lora_rank is not None:
+                raise ValueError("a block-diffusion model takes no window "
+                                 "and no latent attention")
+            tokens, noise = _noised_row(self, tokens)
+        init = _init(self.init_std if self.embed_init_std is None
+                     else self.embed_init_std, nn.linear.default_embed_init)
         x = nn.Embed(self.vocab_size, self.dim, name="embed",
                      dtype=self.dtype, embedding_init=init)(tokens)
         if self.embed_scale:
@@ -483,7 +528,7 @@ class TransformerLM(nn.Module):
                       self.q_lora_rank, self.qk_nope_head_dim,
                       self.qk_rope_head_dim, self.v_head_dim,
                       self.rope_interleave, self.expert_gated,
-                      name=f"block_{i}")(x)
+                      self._blockdiff, name=f"block_{i}")(x)
             if sparse:
                 x, layer_aux = x
                 aux.append(layer_aux)
@@ -492,14 +537,24 @@ class TransformerLM(nn.Module):
                         name="lm_head", kernel_init=_init(
                             self.init_std, nn.linear.default_kernel_init))
         if labels is None and not return_hidden:
+            if noise is not None:       # the noised half's, [B, T, vocab]
+                x = x[:, x.shape[1] // 2:]
             return head(x).astype(jnp.float32)
         head(x[:, :1])      # registers the kernel (result DCE'd); the head
         if labels is None:  # itself is applied chunk-wise by the fused loss
             return x
+        if noise is not None:
+            return _diffusion_loss(self, x, head.variables["params"][
+                "kernel"], labels, weights, noise, aux)
         loss, _ = lm_head_loss(x, head.variables["params"]["kernel"], labels,
                                weights, chunk=max(128, 2048 // x.shape[0]))
         if not aux:
             return loss, jnp.zeros((0,), jnp.float32)
+        return self._with_aux(loss, weights, aux)
+
+    def _with_aux(self, loss, weights, aux):
+        """The head's loss plus the expert layers' auxiliary losses, and the
+        expert layers' counts."""
         mean = lambda key: sum(a[key] for a in aux) / len(aux)  # noqa: E731
         if self.balance_loss_weight or self.z_loss_weight:
             loss = loss + weights.sum() * (
@@ -521,14 +576,17 @@ class TransformerLM(nn.Module):
         """What the second output of :meth:`loss_rows` counts, as (registry
         metric, label) pairs: counters are summed over an epoch's steps, a
         gauge keeps the last step's value."""
+        noise = () if self.diffusion is None else tuple(
+            ("train_diffusion_tokens_total", kind)
+            for kind in ("masked", "all"))
         if not self.num_experts:
-            return ()
+            return noise
         labels = {"max": "max_expert"}     # the other kinds label themselves
         names = tuple(("moe_slots_total", labels.get(kind, kind))
                       for kind in self._slot_kinds)
         if self.routing == "sigmoid":
             names += (("moe_router_bias_spread", ""),)
-        return names
+        return names + noise
 
     def after_step(self, state):
         """Once an optimizer step, after the gradients are applied (the train
@@ -579,25 +637,34 @@ def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
         logits[:, :-1], tokens[:, 1:]).mean()
 
 
-def _head_chunks(hidden, tokens, chunk):
+def _head_chunks(hidden, tokens, chunk, position_weights=None):
     """Positions 0..T-2 predict tokens 1..T-1: both cut into ``[N, B, C, ...]``
     chunks of ``C <= chunk`` positions (zero-padded to a whole number of
-    chunks), with the ``[N, 1, C]`` mask of the real positions."""
+    chunks), with the ``[N, 1, C]`` mask of the real positions. With
+    ``position_weights`` [B, T]: position ``i`` predicts token ``i`` (no
+    shift, all T), and in the mask's place stand the weights, ``[N, B, C]``
+    (the padding's are zero)."""
     B, T, D = hidden.shape
     n = T - 1
     x, y = hidden[:, :-1], tokens[:, 1:]
+    if position_weights is not None:
+        n, x, y = T, hidden, tokens
     chunk = min(chunk, n)
     pad = (-n) % chunk
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         y = jnp.pad(y, ((0, 0), (0, pad)))
-    mask = (jnp.arange(n + pad) < n).astype(jnp.float32)
     cut = lambda a: a.reshape(  # noqa: E731
         (a.shape[0], (n + pad) // chunk, chunk) + a.shape[2:]).swapaxes(0, 1)
+    if position_weights is not None:
+        return cut(x), cut(y), cut(jnp.pad(
+            position_weights.astype(jnp.float32), ((0, 0), (0, pad))))
+    mask = (jnp.arange(n + pad) < n).astype(jnp.float32)
     return cut(x), cut(y), cut(mask[None])
 
 
-def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads):
+def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
+               position_weights=None):
     """One scan over the chunks of :func:`_head_chunks`. A chunk's logits
     (``[B, C, V]`` float32: operands in the activations' dtype, float32
     accumulation) exist once, inside the scan's body; from them come the
@@ -608,12 +675,14 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads):
     the kernel's, with the operand and accumulation types of the products
     autodiff transposes out of the forward one. Returns ``(rows [B], d hidden
     [B, T, D] in hidden's dtype, d kernel [D, V] float32)``, the last two
-    ``None`` without gradients."""
+    ``None`` without gradients. ``position_weights`` [B, T]: a row is
+    ``sum_i w_i CE(logits_i, token_i) / T``, same position, all T of them
+    (:func:`_head_chunks`)."""
     from jax import lax
 
     B, T, D = hidden.shape
-    n = T - 1
-    xs, ys, ms = _head_chunks(hidden, tokens, chunk)
+    n = T - 1 if position_weights is None else T
+    xs, ys, ms = _head_chunks(hidden, tokens, chunk, position_weights)
     k = kernel.astype(hidden.dtype)      # cast once, not once a chunk
     vocab = lax.broadcasted_iota(jnp.int32, (1, 1, k.shape[1]), 2)
     scale = weights.astype(jnp.float32)[:, None] / n            # [B, 1]
@@ -646,17 +715,21 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads):
     if not with_grads:
         return rows, None, None
     dx = dxs.swapaxes(0, 1).reshape(B, -1, D)[:, :n]
+    if position_weights is not None:
+        return rows, dx, dk
     return rows, jnp.pad(dx, ((0, 0), (0, 1), (0, 0))), dk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _head_loss(hidden, kernel, tokens, weights, chunk):
-    rows, _, _ = _head_scan(hidden, kernel, tokens, weights, chunk, False)
+def _head_loss(hidden, kernel, tokens, weights, chunk, position_weights):
+    rows, _, _ = _head_scan(hidden, kernel, tokens, weights, chunk, False,
+                            position_weights)
     return jnp.sum(weights * rows), rows
 
 
-def _head_loss_fwd(hidden, kernel, tokens, weights, chunk):
-    rows, dh, dk = _head_scan(hidden, kernel, tokens, weights, chunk, True)
+def _head_loss_fwd(hidden, kernel, tokens, weights, chunk, position_weights):
+    rows, dh, dk = _head_scan(hidden, kernel, tokens, weights, chunk, True,
+                              position_weights)
     return (jnp.sum(weights * rows), rows), (dh, dk.astype(kernel.dtype),
                                              rows)
 
@@ -665,8 +738,9 @@ def _head_loss_bwd(chunk, residuals, cotangents):
     dh, dk, rows = residuals
     g, _ = cotangents           # the rows are reported, not differentiated
     with jax.named_scope("lm_head_loss"):
+        # (the position weights are the step's noise: no gradient)
         return ((g * dh).astype(dh.dtype), (g * dk).astype(dk.dtype), None,
-                g * rows)
+                g * rows, None)
 
 
 _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
@@ -674,7 +748,7 @@ _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
                  tokens: jnp.ndarray, weights: jnp.ndarray,
-                 chunk: int = 1024):
+                 chunk: int = 1024, position_weights=None):
     """Next-token cross entropy with the lm_head FUSED into the loss:
     ``(sum(weights * rows), rows)``, ``rows`` ``[B]`` float32 the mean
     cross entropy of each sequence (reported: no gradient flows from them).
@@ -694,9 +768,15 @@ def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
     ``hidden`` [B, T, D] from ``model(tokens, return_hidden=True)``;
     ``lm_head_kernel`` [D, V] = ``params["lm_head"]["kernel"]``; ``weights``
     [B] float32: ``1 / B`` each makes the sum the mean loss.
+
+    ``position_weights`` [B, T] float32 (off by default) makes it the
+    cross entropy at the SAME position under a weight a position: a row is
+    ``sum_i w_i CE(logits_i, tokens_i) / T`` over all T positions (a
+    masked-token objective: ``w_i`` zero where a token carries no loss).
     """
     with jax.named_scope("lm_head_loss"):
-        return _head_loss(hidden, lm_head_kernel, tokens, weights, chunk)
+        return _head_loss(hidden, lm_head_kernel, tokens, weights, chunk,
+                          position_weights)
 
 
 def lm_loss_fused(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
@@ -858,11 +938,12 @@ def _attention(block):
             block.num_heads, block.attention, block.mesh, block.dtype,
             block.rope_theta, block.qk_norm, block.rms_norm_eps,
             block.init_std, block.head_dim, block.num_kv_heads, block.window,
-            block.rope, block.attention_gate, name="attn")
+            block.rope, block.attention_gate, block.blockdiff, name="attn")
     if (block.window or not block.rope or block.attention_gate
-            or block.qk_norm or block.num_kv_heads):
+            or block.qk_norm or block.num_kv_heads or block.blockdiff):
         raise ValueError("latent attention has no window, no layer without "
-                         "RoPE, no gate, no QK norm and no grouped K/V")
+                         "RoPE, no gate, no QK norm, no grouped K/V and no "
+                         "block-diffusion mask")
     return LatentAttention(
         block.num_heads, block.kv_lora_rank, block.qk_nope_head_dim,
         block.qk_rope_head_dim, block.v_head_dim, block.q_lora_rank,
@@ -1017,7 +1098,8 @@ def _one_sublayer(model, i: int, kept):
             model.dtype, model.rope_theta, model.qk_norm, model.rms_norm_eps,
             model.init_std, model.head_dim, model.num_kv_heads,
             model.sliding_window if model._windowed(i) else None,
-            model._rope(i), model.attention_gate, name="attn")
+            model._rope(i), model.attention_gate, model._blockdiff,
+            name="attn")
     else:
         from raydp_tpu.models.moe import MoE
 
@@ -1033,3 +1115,81 @@ def _one_sublayer(model, i: int, kept):
     layer = Layer if kept is None else nn.remat(
         Layer, policy=jax.checkpoint_policies.save_only_these_names(*kept))
     return layer(mixer, model.rms_norm_eps, name=f"block_{i}")
+
+
+# ---------------------------------------------------------------------------
+# Block-diffusion training (the ``sdar`` family). Down here for the reason
+# ``SUBLAYER_OUT`` is.
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionSpec:
+    """The block-diffusion objective (arXiv:2503.09573), as one field of a
+    model: a row is cut into blocks of ``block`` tokens; each block draws
+    ``t ~ U(t_min, 1]`` and each of its tokens becomes ``mask_id`` with
+    probability ``t`` (linear schedule, absorbing state); the model sees
+    ``[row ; noised row]`` under the mask of
+    :func:`raydp_tpu.ops.flash_attention.blockdiff_visible` and its output at
+    a masked position predicts the token that stood there, under the weight
+    ``1 / t``. ``eval_seed``: the key a call without the ``diffusion`` stream
+    noises with, so that a plain call is a function of its inputs."""
+
+    block: int
+    mask_id: int
+    t_min: float = 1e-3
+    eval_seed: int = 0
+
+
+def block_diffusion_noise(key, tokens, spec: BlockDiffusionSpec):
+    """THE sampler: ``tokens`` [B, T] -> (the noised copy [B, T], ``t`` a
+    block [B, T / block] float32, which tokens were masked [B, T] bool)."""
+    b, t = tokens.shape
+    if t % spec.block:
+        raise ValueError(f"a row of {t} tokens is no whole number of blocks "
+                         f"of {spec.block}")
+    n = t // spec.block
+    key_t, key_mask = jax.random.split(key)
+    u = jax.random.uniform(key_t, (b, n))
+    level = 1.0 - u * (1.0 - spec.t_min)            # u in [0, 1): (t_min, 1]
+    masked = jax.random.uniform(key_mask, (b, t)) < jnp.repeat(
+        level, spec.block, axis=1)
+    return (jnp.where(masked, jnp.asarray(spec.mask_id, tokens.dtype),
+                      tokens), level, masked)
+
+
+def _copies_positions(t: int):
+    """The position ids of ``[row ; noised row]``: 0..T/2-1 twice."""
+    with jax.named_scope("diffusion"):
+        return jnp.tile(jnp.arange(t // 2), 2)
+
+
+def _noised_row(model, tokens):
+    """``tokens`` [B, T] -> (``[tokens ; noised copy]`` [B, 2T], (the weight
+    a position of the loss, ``masked / t`` [B, T] float32; how many tokens
+    were masked)). The key is the ``diffusion`` stream's where the caller
+    hands one (a train step does, a new one each optimizer step), else the
+    spec's fixed one."""
+    spec = model.diffusion
+    with jax.named_scope("diffusion"):
+        key = model.make_rng("diffusion") if model.has_rng("diffusion") \
+            else jax.random.PRNGKey(spec.eval_seed)
+        noised, level, masked = block_diffusion_noise(key, tokens, spec)
+        weight = masked / jnp.repeat(level, spec.block, axis=1)
+        return jnp.concatenate([tokens, noised], axis=1), (
+            weight.astype(jnp.float32), jnp.sum(masked, dtype=jnp.float32))
+
+
+def _diffusion_loss(model, x, kernel, labels, weights, noise, aux):
+    """What :meth:`TransformerLM.loss_rows` returns for ``x`` [B, 2T, D], the
+    normed hidden states of ``[row ; noised row]``: the head and the loss run
+    over the noised half alone, position ``i`` against token ``i`` under
+    ``masked_i / t``; the counts end in the tokens masked and all tokens."""
+    weight, masked = noise
+    half = x.shape[1] // 2
+    loss, _ = lm_head_loss(x[:, half:], kernel, labels, weights,
+                           chunk=max(128, 2048 // x.shape[0]),
+                           position_weights=weight)
+    counts = jnp.stack([masked, jnp.float32(labels.size)])
+    if not aux:
+        return loss, counts
+    loss, slots = model._with_aux(loss, weights, aux)
+    return loss, jnp.concatenate([slots, counts])

@@ -42,6 +42,12 @@ blocks differ, the window is no multiple of a block, a block too small for
 two tiles of 128 lanes) an edge block is computed whole and masked.
 ``flash_tiles_total`` counts the tiles by what becomes of them.
 
+A third mask, ``blockdiff`` (block-diffusion training): the row is a clean and
+a noised copy of ``T / 2`` tokens in blocks of ``Bd``, and the mask has three
+regions with an edge each (the section at the file's end): the same kernels
+under names of their own, walking only the block pairs that hold a visible
+pair (80 of 256 at 8,192 tokens in 1024-blocks).
+
 Dispatch: on a TPU backend the Pallas kernel runs, and a shape it cannot take
 is an error that says why — never a quiet switch to another path. Off the chip
 a fused jnp path computes the same math (it materializes the [T, T] scores, so
@@ -80,6 +86,9 @@ KERNEL_NAMES = ("rdt_flash_fwd", "rdt_flash_bwd_dkdv", "rdt_flash_bwd_dq",
 #: layer's events from a full one's
 WINDOW_KERNEL_NAMES = ("rdt_flash_win_fwd", "rdt_flash_win_bwd_dkdv",
                        "rdt_flash_win_bwd_dq", "rdt_flash_win_bwd_dkdv_dq")
+#: and of a call under the block-diffusion mask (``blockdiff``)
+BLOCKDIFF_KERNEL_NAMES = ("rdt_flash_bd_fwd", "rdt_flash_bd_bwd_dkdv",
+                          "rdt_flash_bd_bwd_dq", "rdt_flash_bd_bwd_dkdv_dq")
 #: what the one-kernel backward may hold in VMEM for a K/V head's dk and dv:
 #: float32 accumulators [T, D] and [T, Dv] and the two out blocks the
 #: pipeline keeps of each, T * (D + Dv) * (4 + 2 * itemsize) bytes with each
@@ -115,11 +124,15 @@ def _q_band(ki, blk_q: int, blk_k: int, window: int, num_q: int):
     return (ki * blk_k) // blk_q, hi
 
 
-def _band_steps(t: int, blk_q: int, blk_k: int, window: Optional[int]):
+def _band_steps(t: int, blk_q: int, blk_k: int, window: Optional[int],
+                bd: Optional[int] = None):
     """(steps of a q block's walk over k blocks, steps of a k block's walk
     over q blocks): the other side's block count without a window, the
-    widest band's with one."""
+    widest band's with one; under the block-diffusion mask the longest of
+    ``_bd_k_step``'s walks (a k block's walk is every q block's there)."""
     num_q, num_k = t // blk_q, t // blk_k
+    if bd is not None and _bd_compact(t // 2, blk_q, blk_k, bd):
+        return num_k // 2 + 1, num_q
     if window is None:
         return num_k, num_q
     k_steps = max(hi - lo + 1 for lo, hi in (
@@ -130,11 +143,13 @@ def _band_steps(t: int, blk_q: int, blk_k: int, window: Optional[int]):
 
 
 def _k_step(qi, j, *, blk_q: int, blk_k: int, window: Optional[int],
-            steps: int):
+            steps: int, bd: Optional[int] = None, half: int = 0):
     """Step ``j`` of q block ``qi``'s walk: (the k block it works on, whether
     it works at all; None = the kernel's causal condition decides). With a
     window the walk ends on the diagonal block; the steps before the band's
     first block stay on that block, which is then fetched once."""
+    if bd is not None:
+        return _bd_k_step(qi, j, blk_q, blk_k, bd, half)
     if window is None:
         return j, None
     lo, hi = _k_band(qi, blk_q, blk_k, window)
@@ -143,7 +158,8 @@ def _k_step(qi, j, *, blk_q: int, blk_k: int, window: Optional[int],
 
 
 def _q_step(b, ki, j, *, group: int, blk_q: int, blk_k: int,
-            window: Optional[int], steps: int, num_q: int):
+            window: Optional[int], steps: int, num_q: int,
+            bd: Optional[int] = None, half: int = 0):
     """Step ``j`` of the dK/dV walk of K/V head ``b``, k block ``ki``: (the
     query head, the q block, whether it works at all or None). The walk goes
     through the group's query heads one after another, each over ``steps`` q
@@ -158,13 +174,14 @@ def _q_step(b, ki, j, *, group: int, blk_q: int, blk_k: int,
 
 
 def _count_blocks(kernels: int, heads: int, t: int, blk_q: int, blk_k: int,
-                  window: Optional[int], causal: bool) -> None:
+                  window: Optional[int], causal: bool,
+                  bd: Optional[int] = None) -> None:
     """``flash_blocks_total``: the (q block, k block) pairs of the kernels
     being built, by what becomes of them; and ``flash_tiles_total``: the
     computed pairs' tiles (``_TILES_A_SIDE`` a side), by what a step does
     with them."""
-    from raydp_tpu import metrics as rdt_metrics
-
+    if bd is not None:
+        return _bd_count_blocks(kernels, heads, t, blk_q, blk_k, bd)
     num_q, num_k = t // blk_q, t // blk_k
     above = behind = 0
     tiled = _tile(blk_q, blk_k, window) is not None
@@ -188,11 +205,18 @@ def _count_blocks(kernels: int, heads: int, t: int, blk_q: int, blk_k: int,
                 tiles["skipped"] += off_edge
     blocks = {"computed": num_q * num_k - above - behind,
               "skipped_causal": above, "skipped_window": behind}
+    _count_fates(kernels * heads, blocks, tiles)
+
+
+def _count_fates(times: int, blocks: dict, tiles: dict) -> None:
+    """Add a kernel's block pairs and tiles, by fate, ``times`` over."""
+    from raydp_tpu import metrics as rdt_metrics
+
     for name, fates in (("flash_blocks_total", blocks),
                         ("flash_tiles_total", tiles)):
         for label, n in fates.items():
             if n:
-                rdt_metrics.inc(name, kernels * heads * n, label)
+                rdt_metrics.inc(name, times * n, label)
 
 
 # ---------------------------------------------------------------------------
@@ -200,14 +224,15 @@ def _count_blocks(kernels: int, heads: int, t: int, blk_q: int, blk_k: int,
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale: float, causal: bool, blk_q: int, blk_k: int,
-                window: Optional[int] = None, steps: int = 0):
+                window: Optional[int] = None, steps: int = 0,
+                bd: Optional[int] = None, half: int = 0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     j = pl.program_id(2)
     num_k = pl.num_programs(2)
     ki, in_band = _k_step(qi, j, blk_q=blk_q, blk_k=blk_k, window=window,
-                          steps=steps)
+                          steps=steps, bd=bd, half=half)
 
     @pl.when(j == 0)
     def _init():
@@ -266,10 +291,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             _update(chunk, kept, scores)
 
     def _body(edge):
-        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
+        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window,
+                                  bd, half):
             _rows(rows, pieces)
 
-    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window, bd,
+              half)
 
     @pl.when(j == num_k - 1)
     def _finalize():
@@ -295,7 +322,8 @@ def _maps(group: int, band: dict):
 
 
 def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
-                blk_k: int, interpret: bool, window: Optional[int] = None):
+                blk_k: int, interpret: bool, window: Optional[int] = None,
+                bd: Optional[int] = None):
     """q3 [BH,T,D], k3 [BHk,T,D], v3 [BHk,T,Dv] → (out [BH,T,Dv], lse [BH,T])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -304,10 +332,10 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
 
     (bh, t, d), d_v = q3.shape, v3.shape[2]
     group = bh // k3.shape[0]
-    k_steps, _ = _band_steps(t, blk_q, blk_k, window)
-    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
+    k_steps, _ = _band_steps(t, blk_q, blk_k, window, bd)
+    band = _band(blk_q, blk_k, window, k_steps, bd, t)
     q_map, kv_map, row_map = _maps(group, band)
-    _count_blocks(1, bh, t, blk_q, blk_k, window, causal)
+    _count_blocks(1, bh, t, blk_q, blk_k, window, causal, bd)
     rdt_metrics.inc("flash_forward_total", label="chunked" if _row_chunk(
         blk_q) < blk_q else "whole")
     grid = (bh, t // blk_q, k_steps)
@@ -339,13 +367,25 @@ def _fwd_pallas(q3, k3, v3, *, scale: float, causal: bool, blk_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=_names(window)[0],
+        name=_names(window, bd)[0],
     )(q3, k3, v3)
     return out, lse.reshape(bh, t)
 
 
-def _names(window: Optional[int]):
+def _names(window: Optional[int], bd: Optional[int] = None):
+    if bd is not None:
+        return BLOCKDIFF_KERNEL_NAMES
     return KERNEL_NAMES if window is None else WINDOW_KERNEL_NAMES
+
+
+def _band(blk_q: int, blk_k: int, window: Optional[int], steps: int,
+          bd: Optional[int], t: int) -> dict:
+    """What a kernel and its index maps are told of the walk; the two keys
+    of the block-diffusion mask only where a call has it."""
+    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=steps)
+    if bd is not None:
+        band.update(bd=bd, half=t // 2)
+    return band
 
 
 # ---------------------------------------------------------------------------
@@ -369,11 +409,15 @@ def _repeat_kv(q3, k3, v3):
 
 
 def _fwd_jnp(q3, k3, v3, *, scale: float, causal: bool,
-             window: Optional[int] = None):
+             window: Optional[int] = None, bd: Optional[int] = None):
     k3, v3, _ = _repeat_kv(q3, k3, v3)
     s = jnp.einsum("bqd,bkd->bqk", q3.astype(jnp.float32),
                    k3.astype(jnp.float32)) * scale
-    if causal:
+    if bd is not None:
+        at = jnp.arange(q3.shape[1])
+        s = jnp.where(blockdiff_visible(at[:, None], at[None, :], bd,
+                                        q3.shape[1] // 2)[None], s, _NEG_INF)
+    elif causal:
         s = jnp.where(_visible(q3.shape[1], window)[None], s, _NEG_INF)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
@@ -436,7 +480,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dk_ref, dv_ref, dk_scr, dv_scr,
                      *, scale: float, causal: bool, blk_q: int, blk_k: int,
                      window: Optional[int] = None, steps: int = 0,
-                     group: int = 1, num_q: int = 0):
+                     group: int = 1, num_q: int = 0,
+                     bd: Optional[int] = None, half: int = 0):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -451,7 +496,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _body(edge):
-        for cols, pieces in _walk(edge, False, qi, ki, blk_q, blk_k, window):
+        for cols, pieces in _walk(edge, False, qi, ki, blk_q, blk_k, window,
+                                  bd, half):
             k, v = k_ref[0, cols], v_ref[0, cols]           # [cols, D]
             dk, dv = dk_scr[cols], dv_scr[cols]
             for rows, keep in pieces:
@@ -468,7 +514,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_scr[cols] = dk
             dv_scr[cols] = dv
 
-    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window, bd,
+              half)
 
     @pl.when(j == last - 1)
     def _finalize():
@@ -479,21 +526,23 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_scr,
                    *, scale: float, causal: bool, blk_q: int, blk_k: int,
-                   window: Optional[int] = None, steps: int = 0):
+                   window: Optional[int] = None, steps: int = 0,
+                   bd: Optional[int] = None, half: int = 0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     j = pl.program_id(2)
     num_k = pl.num_programs(2)
     ki, in_band = _k_step(qi, j, blk_q=blk_q, blk_k=blk_k, window=window,
-                          steps=steps)
+                          steps=steps, bd=bd, half=half)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _body(edge):
-        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
+        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window,
+                                  bd, half):
             dq = dq_scr[rows]
             for cols, keep in pieces:
                 k = k_ref[0, cols]
@@ -506,7 +555,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     preferred_element_type=jnp.float32))
             dq_scr[rows] = dq
 
-    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window, bd,
+              half)
 
     @pl.when(j == num_k - 1)
     def _finalize():
@@ -526,7 +576,8 @@ def _held(ki, cols: slice, blk_k: int):
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
                       *, scale: float, causal: bool, blk_q: int, blk_k: int,
-                      window: Optional[int] = None, steps: int = 0):
+                      window: Optional[int] = None, steps: int = 0,
+                      bd: Optional[int] = None, half: int = 0):
     """Grid ``(K/V head, query head of its group, q block, step)``: dq is a q
     block's walk as in ``_bwd_dq_kernel``; dk and dv of the whole K/V head
     gather every pair's share in ``dk_scr`` / ``dv_scr`` ([T, D], [T, Dv])
@@ -537,7 +588,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     last_g, last_qi, last_j = (pl.num_programs(axis) - 1
                                for axis in (1, 2, 3))
     ki, in_band = _k_step(qi, j, blk_q=blk_q, blk_k=blk_k, window=window,
-                          steps=steps)
+                          steps=steps, bd=bd, half=half)
 
     def _k_blocks(fn):
         """``fn`` on each k block's rows of the held gradients in turn: a
@@ -559,7 +610,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _body(edge):
-        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window):
+        for rows, pieces in _walk(edge, True, qi, ki, blk_q, blk_k, window,
+                                  bd, half):
             q, do = q_ref[0, rows], do_ref[0, rows]         # [rows, D]
             dq = dq_scr[rows]
             for cols, keep in pieces:
@@ -580,7 +632,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     preferred_element_type=jnp.float32))
             dq_scr[rows] = dq
 
-    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window)
+    _by_edges(_body, in_band, causal, qi, ki, blk_q, blk_k, window, bd,
+              half)
 
     @pl.when(j == last_j)
     def _finalize():
@@ -609,7 +662,8 @@ def _fused_backward_fits(t: int, d: int, d_v: int, dtype) -> bool:
 
 
 def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
-                blk_k: int, interpret: bool, window: Optional[int] = None):
+                blk_k: int, interpret: bool, window: Optional[int] = None,
+                bd: Optional[int] = None):
     from raydp_tpu import metrics as rdt_metrics
 
     q3, k3, v3, out, lse = res
@@ -619,16 +673,16 @@ def _bwd_pallas(res, g, *, scale: float, causal: bool, blk_q: int,
     fused = _fused_backward_fits(t, d, d_v, k3.dtype)
     rdt_metrics.inc("flash_backward_total", label="fused" if fused
                     else "split")
-    _count_blocks(1 if fused else 2, bh, t, blk_q, blk_k, window, causal)
+    _count_blocks(1 if fused else 2, bh, t, blk_q, blk_k, window, causal, bd)
     return (_bwd_fused if fused else _bwd_split)(
         q3, k3, v3, g, lse.reshape(bh, 1, t), delta, scale=scale,
         causal=causal, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-        window=window)
+        window=window, bd=bd)
 
 
 def _bwd_fused(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
                blk_q: int, blk_k: int, interpret: bool,
-               window: Optional[int]):
+               window: Optional[int], bd: Optional[int] = None):
     """dq, dk, dv from one kernel: see ``_bwd_fused_kernel``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -636,8 +690,8 @@ def _bwd_fused(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
     (bh, t, d), d_v = q3.shape, v3.shape[2]
     bkv = k3.shape[0]
     group = bh // bkv
-    k_steps, _ = _band_steps(t, blk_q, blk_k, window)
-    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
+    k_steps, _ = _band_steps(t, blk_q, blk_k, window, bd)
+    band = _band(blk_q, blk_k, window, k_steps, bd, t)
     vma = jax.typeof(q3).vma
 
     def of_head(index_map):
@@ -684,13 +738,13 @@ def _bwd_fused(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
             vmem_limit_bytes=_fused_resident_bytes(t, d, d_v, k3.dtype)
             + _VMEM_WORKING_BYTES),
         interpret=interpret,
-        name=_names(window)[3],
+        name=_names(window, bd)[3],
     )(q3, k3, v3, do, lse3, delta))
 
 
 def _bwd_split(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
                blk_q: int, blk_k: int, interpret: bool,
-               window: Optional[int]):
+               window: Optional[int], bd: Optional[int] = None):
     """dq, dk, dv from two kernels, each output block in its own scratch:
     what a sequence too long for ``_bwd_fused`` takes."""
     from jax.experimental import pallas as pl
@@ -700,14 +754,14 @@ def _bwd_split(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
     bkv = k3.shape[0]
     group = bh // bkv
     num_q, num_k = t // blk_q, t // blk_k
-    k_steps, q_steps = _band_steps(t, blk_q, blk_k, window)
+    k_steps, q_steps = _band_steps(t, blk_q, blk_k, window, bd)
     vma = jax.typeof(q3).vma
-    names = _names(window)
+    names = _names(window, bd)
 
     # dK/dV: one K/V head and k block a program row, walking the q blocks of
     # each of the group's query heads in turn
-    walk = dict(group=group, blk_q=blk_q, blk_k=blk_k, window=window,
-                steps=q_steps, num_q=num_q)
+    walk = dict(_band(blk_q, blk_k, window, q_steps, bd, t), group=group,
+                num_q=num_q)
 
     def q_side(b, ki, j):
         head, qi, _ = _q_step(b, ki, j, **walk)
@@ -750,7 +804,7 @@ def _bwd_split(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
         name=names[1],
     )(q3, k3, v3, do, lse3, delta)
 
-    band = dict(blk_q=blk_q, blk_k=blk_k, window=window, steps=k_steps)
+    band = _band(blk_q, blk_k, window, k_steps, bd, t)
     q_map, kv_map, row_map = _maps(group, band)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, **band),
@@ -780,7 +834,7 @@ def _bwd_split(q3, k3, v3, do, lse3, delta, *, scale: float, causal: bool,
 # Blockwise backward (flash recompute from LSE), shared by both paths
 # ---------------------------------------------------------------------------
 def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None, bd: Optional[int] = None):
     q3, k3, v3, out, lse = res
     bkv = k3.shape[0]
     k3, v3, group = _repeat_kv(q3, k3, v3)
@@ -797,7 +851,11 @@ def _bwd_blockwise(res, g, *, scale: float, causal: bool, blk_k: int,
         k_blk = lax.dynamic_slice_in_dim(k3, j * blk, blk, 1).astype(jnp.float32)
         v_blk = lax.dynamic_slice_in_dim(v3, j * blk, blk, 1).astype(jnp.float32)
         s = jnp.einsum("bqd,bkd->bqk", qf, k_blk) * scale
-        if causal:
+        if bd is not None:
+            k_pos = j * blk + jnp.arange(blk)
+            s = jnp.where(blockdiff_visible(q_pos[:, None], k_pos[None, :],
+                                            bd, t // 2)[None], s, _NEG_INF)
+        elif causal:
             k_pos = j * blk + jnp.arange(blk)
             keep = q_pos[:, None] >= k_pos[None, :]
             if window is not None:
@@ -890,37 +948,38 @@ def _by_platform(pallas_fn, jnp_fn, interpret: bool, *args):
     return lax.platform_dependent(*args, tpu=pallas_fn, default=jnp_fn)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window, bd):
     out, _ = _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret,
-                        window)
+                        window, bd)
     return out
 
 
-def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window):
+def _flash_fwd(q3, k3, v3, scale, causal, blk_q, blk_k, interpret, window,
+               bd):
     t, d = q3.shape[1], q3.shape[2]
     jnp_fn = functools.partial(_fwd_jnp, scale=scale, causal=causal,
-                               window=window)
+                               window=window, bd=bd)
     if _use_pallas(t, d, blk_q, blk_k, interpret, window, v3.shape[2]):
         out, lse = _by_platform(
             functools.partial(_fwd_pallas, scale=scale, causal=causal,
                               blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-                              window=window),
+                              window=window, bd=bd),
             jnp_fn, interpret, q3, k3, v3)
     else:
         out, lse = jnp_fn(q3, k3, v3)
     return _named(q3, k3, v3, out, lse)
 
 
-def _flash_bwd(scale, causal, blk_q, blk_k, interpret, window, res, g):
+def _flash_bwd(scale, causal, blk_q, blk_k, interpret, window, bd, res, g):
     t, d = res[0].shape[1], res[0].shape[2]
     jnp_fn = functools.partial(_bwd_blockwise, scale=scale, causal=causal,
-                               blk_k=blk_k, window=window)
+                               blk_k=blk_k, window=window, bd=bd)
     if _use_pallas(t, d, blk_q, blk_k, interpret, window, res[2].shape[2]):
         return _by_platform(
             functools.partial(_bwd_pallas, scale=scale, causal=causal,
                               blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-                              window=window),
+                              window=window, bd=bd),
             jnp_fn, interpret, res, g)
     return jnp_fn(res, g)
 
@@ -933,11 +992,15 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
                     interpret: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    blockdiff: Optional[int] = None):
     """Memory-efficient exact attention. q: [B, T, H, D]; k: [B, T, Hk, D];
     v: [B, T, Hk, Dv], ``H`` a multiple of ``Hk`` (query head ``h`` reads K/V
     head ``h // (H // Hk)``) → [B, T, H, Dv]; the default scale is D^-1/2.
-    ``window`` (causal only): a query sees itself and ``window - 1`` keys."""
+    ``window`` (causal only): a query sees itself and ``window - 1`` keys.
+    ``blockdiff`` (causal only, no window): the row is a clean copy and a
+    noised copy of ``T / 2`` tokens in blocks of ``blockdiff``
+    (:func:`blockdiff_visible`)."""
     b, t, h, d = q.shape
     hk = k.shape[2]
     if h % hk or v.shape[:3] != k.shape[:3] or k.shape[3] != d:
@@ -945,6 +1008,9 @@ def flash_attention(q, k, v, causal: bool = True,
                          f"heads divide the query heads, keys are q's width")
     if window is not None and not causal:
         raise ValueError("a window is one-sided: it needs causal=True")
+    if blockdiff is not None:
+        _check_blockdiff(t, blockdiff, causal, window)
+    _count_mask(causal, window, blockdiff)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     blk_q = _fit_block(t, block_q)
     blk_k = _fit_block(t, block_k)
@@ -953,7 +1019,7 @@ def flash_attention(q, k, v, causal: bool = True,
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, x.shape[3])
 
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, blk_q, blk_k,
-                  interpret, window)
+                  interpret, window, blockdiff)
     return out3.reshape(b, h, t, v.shape[3]).transpose(0, 2, 1, 3)
 
 
@@ -1032,7 +1098,7 @@ def _interior(qi, ki, blk_q: int, blk_k: int, window: Optional[int]):
 
 
 def _by_edges(body, in_band, causal: bool, qi, ki, blk_q: int, blk_k: int,
-              window: Optional[int]):
+              window: Optional[int], bd: Optional[int] = None, half: int = 0):
     """Run ``body(edge)`` on the path block pair (``qi``, ``ki``) takes.
     A pair with no visible (query, key) pair runs nothing: with a window the
     walk itself is the band (``in_band``); without one a k block strictly
@@ -1042,6 +1108,8 @@ def _by_edges(body, in_band, causal: bool, qi, ki, blk_q: int, blk_k: int,
     tiles, or ``body("whole")`` where ``_tile`` has none."""
     from jax.experimental import pallas as pl
 
+    if bd is not None:
+        return _bd_by_edges(body, in_band, qi, ki, blk_q, blk_k, bd, half)
     if not causal:
         return body(None)
     visible = (in_band if in_band is not None
@@ -1058,7 +1126,7 @@ def _by_edges(body, in_band, causal: bool, qi, ki, blk_q: int, blk_k: int,
 
 
 def _walk(edge: Optional[str], by_rows: bool, qi, ki, blk_q: int, blk_k: int,
-          window: Optional[int]):
+          window: Optional[int], bd: Optional[int] = None, half: int = 0):
     """How a kernel step covers its block: a list of (slice of the walked
     side, [(slice of the other side, the pairs to keep or None for all)]),
     the walked side being the q rows (``by_rows``: forward and dq, whose
@@ -1072,22 +1140,31 @@ def _walk(edge: Optional[str], by_rows: bool, qi, ki, blk_q: int, blk_k: int,
     whole = slice(None)
     if edge is None:
         return [(whole, [(whole, None)])]
+    if edge == "whole" and bd is not None:
+        return [(whole, [(whole, _bd_keep_block(qi, ki, blk_q, blk_k, bd,
+                                                half))])]
     if edge == "whole":
         return [(whole, [(whole, _keep_causal(qi, ki, blk_q, blk_k,
                                               window))])]
     ts = _tile(blk_q, blk_k, window)
     row = lax.broadcasted_iota(jnp.int32, (ts, ts), 0)
     col = lax.broadcasted_iota(jnp.int32, (ts, ts), 1)
+    if bd is not None:
+        # a tile holds whole diffusion blocks: a place's block from its
+        # place in the tile
+        row, col = _bd_block_of(row, bd), _bd_block_of(col, bd)
     # within a crossed tile the diagonal keeps key <= query; the far edge,
     # `window` keys back, keeps the keys strictly after the query's own place
-    keep = lax.ge(row, col) if edge == "diagonal" else lax.gt(col, row)
-    before = (edge == "diagonal") == by_rows
+    keep = {"diagonal": lax.ge, "far": lambda r, c: lax.gt(c, r),
+            "blocks_upto": lax.ge, "blocks_before": lax.gt,
+            "own_block": lax.eq}[edge](row, col)
+    before = (edge != "far") == by_rows
     steps = []
     for a in range(_TILES_A_SIDE):
         crossed = slice(a * ts, (a + 1) * ts)
         inside = slice(0, a * ts) if before else slice((a + 1) * ts, blk_q)
         pieces = [(crossed, keep)]
-        if inside.stop > inside.start:
+        if inside.stop > inside.start and edge != "own_block":
             pieces.append((inside, None))
         steps.append((crossed, pieces))
     return steps
@@ -1126,3 +1203,174 @@ def _row_chunks(rows: slice, pieces, blk_q: int):
         return [(rows, pieces)]
     return [(slice(start + at, start + at + c), pieces)
             for at in range(0, n, c)]
+
+
+# ---------------------------------------------------------------------------
+# The block-diffusion mask (``blockdiff=Bd``). A row of T positions is a clean
+# copy of L = T / 2 tokens and, after it, a noised copy of the same tokens,
+# both in blocks of Bd tokens (block b holds tokens b * Bd .. b * Bd + Bd - 1).
+# A clean query sees the clean keys of its own and of earlier blocks and no
+# noised key; a noised query sees the clean keys of strictly earlier blocks
+# and the noised keys of its own block, before and after it. Three regions,
+# each with an edge of its own kind: ``blocks_upto`` (the causal diagonal
+# moved up to the block's end), ``blocks_before`` (moved down to its start)
+# and ``own_block`` (a band Bd wide on the noised half's diagonal). Down here
+# for the reason ``RESIDUAL_NAMES`` is.
+# ---------------------------------------------------------------------------
+def _bd_block_of(at, bd: int):
+    """The diffusion block of the places ``at`` (none negative) in a half."""
+    if bd & (bd - 1) == 0:
+        return lax.shift_right_logical(at, jnp.full_like(at, bd.bit_length()
+                                                         - 1))
+    return lax.div(at, jnp.full_like(at, bd))
+
+
+def blockdiff_visible(q_pos, k_pos, bd: int, half: int):
+    """bool, broadcast of ``q_pos`` against ``k_pos`` (int32 positions in the
+    row of ``2 * half``): query sees key under the block-diffusion mask."""
+    q_noised, k_noised = q_pos >= half, k_pos >= half
+    qb = _bd_block_of(jnp.where(q_noised, q_pos - half, q_pos), bd)
+    kb = _bd_block_of(jnp.where(k_noised, k_pos - half, k_pos), bd)
+    return jnp.where(k_noised, q_noised & (qb == kb),
+                     jnp.where(q_noised, kb < qb, kb <= qb))
+
+
+def _check_blockdiff(t: int, bd: int, causal: bool, window) -> None:
+    if not causal or window is not None:
+        raise ValueError("the block-diffusion mask takes causal=True and no "
+                         "window")
+    if bd < 1 or t % 2 or (t // 2) % bd:
+        raise ValueError(f"a row of {t} positions is no clean and noised "
+                         f"copy of whole blocks of {bd} tokens")
+
+
+def _bd_compact(half: int, blk_q: int, blk_k: int, bd: int) -> bool:
+    """Whether the kernels walk only the visible block pairs and sort them by
+    block index alone: square blocks, each half a whole number of them, and
+    no diffusion block across a tile's border (a block's, where it has no
+    tiles). Elsewhere every pair is looked at (``_bd_visible``) and a visible
+    one computed whole and masked."""
+    unit = _tile(blk_q, blk_k, None) or blk_q
+    return blk_q == blk_k and half % blk_q == 0 and unit % bd == 0
+
+
+def _bd_k_step(qi, j, blk_q: int, blk_k: int, bd: int, half: int):
+    """Step ``j`` of q block ``qi``'s walk (``_k_step``). Compact: a clean q
+    block walks the clean k blocks up to its own; a noised one the clean k
+    blocks up to its place in its half, then its own; the steps left over
+    stay on the last block (fetched once) and do nothing."""
+    if not _bd_compact(half, blk_q, blk_k, bd):
+        return j, None
+    nh = half // blk_k
+    noised = qi >= nh
+    last_clean = jnp.where(noised, qi - nh, qi)
+    ki = jnp.where(noised & (j > last_clean), qi, jnp.minimum(j, last_clean))
+    return ki, j <= last_clean + noised.astype(jnp.int32)
+
+
+def _bd_sort(qi, ki, nh: int):
+    """Compact geometry: whether block pair (``qi``, ``ki``) is interior, or
+    cut by ``blocks_upto``, ``blocks_before``, ``own_block`` (at most one
+    holds; none: no visible pair). Python or traced integers."""
+    clean_q, noised_q, place = qi < nh, qi >= nh, qi - nh
+    interior = (ki < nh) & ((clean_q & (ki < qi)) | (noised_q & (ki < place)))
+    return (interior, clean_q & (ki == qi), noised_q & (ki == place),
+            noised_q & (ki == qi))
+
+
+def _bd_visible(qi, ki, blk_q: int, blk_k: int, bd: int, half: int):
+    """Any geometry: whether block pair (``qi``, ``ki``) may hold a visible
+    pair, from the blocks' first and last places in each half (Python or
+    traced integers)."""
+    traced = not (isinstance(qi, int) and isinstance(ki, int))
+    most, least = (jnp.maximum, jnp.minimum) if traced else (max, min)
+    q0, q1 = qi * blk_q, qi * blk_q + blk_q - 1
+    k0, k1 = ki * blk_k, ki * blk_k + blk_k - 1
+    clean_q, noised_q, clean_k, noised_k = (q0 < half, q1 >= half, k0 < half,
+                                            k1 >= half)
+    # the last clean query's block, the noised queries' first and last, the
+    # first clean key's, the noised keys' first and last
+    cq = least(q1, half - 1) // bd
+    nq0, nq1 = (most(q0, half) - half) // bd, most(q1 - half, 0) // bd
+    ck = k0 // bd
+    nk0, nk1 = (most(k0, half) - half) // bd, most(k1 - half, 0) // bd
+    return ((clean_q & clean_k & (ck <= cq))
+            | (noised_q & clean_k & (ck < nq1))
+            | (noised_q & noised_k & (nk0 <= nq1) & (nk1 >= nq0)))
+
+
+def _bd_keep_block(qi, ki, blk_q: int, blk_k: int, bd: int, half: int):
+    """[blk_q, blk_k] bool: the pairs of a block the mask keeps."""
+    q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+    k_pos = ki * blk_k + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+    return blockdiff_visible(q_pos, k_pos, bd, half)
+
+
+_BD_EDGES = ("blocks_upto", "blocks_before", "own_block")
+
+
+def _bd_by_edges(body, in_band, qi, ki, blk_q: int, blk_k: int, bd: int,
+                 half: int):
+    """``_by_edges`` under the block-diffusion mask. ``in_band``: whether the
+    walk's step works at all (None: every pair is a step, as in the dK/dV
+    kernel, and the pair's place decides)."""
+    from jax.experimental import pallas as pl
+
+    if not _bd_compact(half, blk_q, blk_k, bd):
+        pl.when(_bd_visible(qi, ki, blk_q, blk_k, bd, half))(
+            functools.partial(body, "whole"))
+        return
+    interior, *edges = _bd_sort(qi, ki, half // blk_k)
+    works = True if in_band is None else in_band
+    pl.when(works & interior)(functools.partial(body, None))
+    if _tile(blk_q, blk_k, None) is None:
+        pl.when(works & (edges[0] | edges[1] | edges[2]))(
+            functools.partial(body, "whole"))
+        return
+    for edge, crossed in zip(_BD_EDGES, edges):
+        pl.when(works & crossed)(functools.partial(body, edge))
+
+
+def _bd_count_blocks(kernels: int, heads: int, t: int, blk_q: int,
+                     blk_k: int, bd: int) -> None:
+    """``_count_blocks`` under the block-diffusion mask, pair by pair as
+    ``_bd_by_edges`` sorts them."""
+    half = t // 2
+    compact = _bd_compact(half, blk_q, blk_k, bd)
+    tiled = compact and _tile(blk_q, blk_k, None) is not None
+    a_block = _TILES_A_SIDE ** 2
+    off_edge = (a_block - _TILES_A_SIDE) // 2
+    tiles = dict.fromkeys(("unmasked", "masked", "skipped", "whole_edge"), 0)
+    computed = 0
+    for qi in range(t // blk_q):
+        for ki in range(t // blk_k):
+            if not compact:
+                interior, edges = False, [_bd_visible(qi, ki, blk_q, blk_k,
+                                                      bd, half)]
+            else:
+                interior, *edges = _bd_sort(qi, ki, half // blk_k)
+            if not (interior or any(edges)):
+                continue
+            computed += 1
+            if interior:
+                tiles["unmasked"] += a_block
+            elif not tiled:
+                tiles["whole_edge"] += a_block
+            else:       # a triangle of tiles, or the diagonal's tiles alone
+                own = edges[2]
+                tiles["masked"] += _TILES_A_SIDE
+                tiles["unmasked"] += 0 if own else off_edge
+                tiles["skipped"] += (a_block - _TILES_A_SIDE) if own \
+                    else off_edge
+    _count_fates(kernels * heads, {
+        "computed": computed,
+        "skipped_blockdiff": (t // blk_q) * (t // blk_k) - computed}, tiles)
+
+
+def _count_mask(causal: bool, window, blockdiff) -> None:
+    """``flash_mask_total``: one more traced call of the op, by its mask."""
+    from raydp_tpu import metrics as rdt_metrics
+
+    rdt_metrics.inc("flash_mask_total", label=(
+        "blockdiff" if blockdiff is not None else "window"
+        if window is not None else "causal" if causal else "none"))

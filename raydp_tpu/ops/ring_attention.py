@@ -182,11 +182,14 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = True,
 
 def dense_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    blockdiff: Optional[int] = None):
     """Unsharded reference implementation (for tests and single-device use).
     ``k`` and ``v`` may hold fewer heads than ``q`` (grouped-query: query
     head ``h`` reads K/V head ``h // group``); ``window`` (causal only) keeps
-    a query's own key and the ``window - 1`` before it."""
+    a query's own key and the ``window - 1`` before it; ``blockdiff`` is the
+    block-diffusion mask of :func:`raydp_tpu.ops.flash_attention
+    .blockdiff_visible` over a clean and a noised copy of ``T / 2`` tokens."""
     b, t, h, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     group = h // k.shape[2]
@@ -194,7 +197,14 @@ def dense_attention(q, k, v, causal: bool = True,
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
-    if causal:
+    if blockdiff is not None:
+        from raydp_tpu.ops.flash_attention import blockdiff_visible
+
+        at = jnp.arange(t)
+        scores = jnp.where(blockdiff_visible(
+            at[:, None], at[None, :], blockdiff, t // 2)[None, None], scores,
+            -jnp.inf)
+    elif causal:
         mask = jnp.tril(jnp.ones((t, t), dtype=bool))
         if window is not None:
             mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)

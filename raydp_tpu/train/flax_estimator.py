@@ -206,12 +206,21 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     collection it carries once an optimizer step, after the gradients are
     applied and after every micro-batch's forward has written to it, and
     carries on what it returns (a language model's routing bias:
-    ``models/transformer.py``)."""
+    ``models/transformer.py``).
+
+    A model that names random streams (``rng_streams``: a block-diffusion
+    language model's ``diffusion``) is handed them as flax's ``rngs=``, each
+    folded from the ``rng`` the train step passes (a new key each optimizer
+    step: :func:`_make_train_step`); without ``rng`` (evaluation, ``predict``)
+    and for every other model the forward is called as it was."""
+    import jax
     import jax.numpy as jnp
 
     model_loss = callable(getattr(model, "loss_rows", None))
+    streams = tuple(getattr(model, "rng_streams", None) or ())
 
-    def apply_fn(params, bstats, batch, train: bool, rows=None, mask=None):
+    def apply_fn(params, bstats, batch, train: bool, rows=None, mask=None,
+                 rng=None):
         inputs, labels = split_batch(batch)
         inputs = _cast_floating(inputs, compute_dtype)
         variables = {"params": params}
@@ -219,6 +228,9 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
         kwargs = {"train": train} if takes_train else {}
         if rows is not None:
             kwargs["rows"] = rows
+        if streams and rng is not None:
+            kwargs["rngs"] = {name: jax.random.fold_in(rng, i)
+                              for i, name in enumerate(streams)}
         if model_loss:
             args = (inputs, labels, _mean_weights(mask, labels.shape[0]))
             kwargs["method"] = model.loss_rows
@@ -241,6 +253,7 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
         return preds.astype(jnp.float32), labels, new_bstats
 
     apply_fn.model_loss = model_loss
+    apply_fn.rng_streams = streams
     # state the model moves itself, once an optimizer step (the collection
     # ``bstats`` carries -> the same, after the step): none for most models
     apply_fn.after_step = getattr(model, "after_step", None)
@@ -426,7 +439,7 @@ def _make_pipeline_apply(model: "PipelineModel", split_batch, compute_dtype,
 
 
 def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
-                     mb_shardings=None, state_shardings=None):
+                     mb_shardings=None, state_shardings=None, seed: int = 0):
     """Build the jitted train-step body shared by ``fit`` and
     ``partial_fit``: one optimizer update from one global batch.
 
@@ -455,6 +468,12 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     no spec of its own). With them a row-wise table whose rows are split over
     mesh axes is read and written shard by shard (``train/rowwise.py``); not
     told, every table is walked as one.
+
+    ``seed`` — the fit's: where the model names random streams
+    (``apply_fn.rng_streams``) the step folds its key from the seed and the
+    optimizer step the state counts (and the micro-batch), on the device, so
+    a row met in two epochs draws anew and nothing more rides the feed. A
+    model that names none gets no key and its step is the step it was.
     """
     import jax
     import jax.numpy as jnp
@@ -480,6 +499,12 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     placed = None if state_shardings is None else (
         state_shardings.params, state_shardings.opt_state)
     after_step = getattr(apply_fn, "after_step", None)
+    streams = getattr(apply_fn, "rng_streams", ())
+
+    def _step_key(state):
+        if not streams:
+            return None
+        return jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
 
     def _carry_on(new_state, new_bstats):
         """The stepped state with the forward's collection, which the model
@@ -490,12 +515,14 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
             new_bstats = after_step(new_bstats)
         return new_state.replace(batch_stats=new_bstats)
 
-    def _microbatch_grads(params, bstats, batch, mask, inv=None):
+    def _microbatch_grads(params, bstats, batch, mask, inv=None, rng=None):
         def _loss(p):
             # with ``inv``, p is a row view: a row-wise table's leaf holds
             # its uniq rows, and uniq[inv] are the rows the batch looked up
             given = {"rows": {path: rowwise.leaf_at(p, path)[i]
                               for path, i in inv.items()}} if inv else {}
+            if rng is not None:
+                given["rng"] = rng
             preds, labels, new_bstats = apply_fn(p, bstats, batch, train=True,
                                                  mask=mask, **given)
             lv = loss_fn(preds, labels, mask=mask) if mask is not None \
@@ -505,14 +532,14 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         fwd = apply_remat(_loss, remat_mode)
         return jax.value_and_grad(fwd, has_aux=True)(params)
 
-    def _update(state, batch, mask, tables):
+    def _update(state, batch, mask, tables, rng=None):
         """One optimizer update from one batch. The declared ``tables`` (if
         any) are differentiated and updated in the rows the batch looked up
         (``train/rowwise.py``): the user's ``tx.update`` runs once, on the
         row view of params and opt_state."""
         if not tables:
             out, grads = _microbatch_grads(state.params, state.batch_stats,
-                                           batch, mask)
+                                           batch, mask, rng=rng)
             return state.apply_gradients(grads=grads), out
         uniq, inv = {}, {}
         for path, ids in tables.items():
@@ -522,7 +549,7 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         idx = rowwise.index_trees(state.tx, *whole, uniq)
         view_params, view_opt = rowwise.take_rows(whole, idx, placed)
         out, grads = _microbatch_grads(view_params, state.batch_stats, batch,
-                                       mask, inv)
+                                       mask, inv, rng)
         new_view = state.replace(
             params=view_params, opt_state=view_opt).apply_gradients(
                 grads=grads)
@@ -535,9 +562,10 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
         tables = rowwise.tables_to_update(
             apply_fn, state, batch, accum, counted,
             getattr(state_shardings, "params", None))
+        key = _step_key(state)
         if accum <= 1:
             new_state, (loss_val, (preds, labels, new_bstats)) = _update(
-                state, batch, mask, tables)
+                state, batch, mask, tables, key)
             new_state = _carry_on(new_state, new_bstats)
             new_mstats = tuple(
                 _update_metric(m, s, preds, labels, mask)
@@ -574,7 +602,8 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
                 if mb_mask is not None:
                     mb_mask = lax.with_sharding_constraint(mb_mask, b_sh)
             (lv, (preds, labels, new_bstats)), g = _microbatch_grads(
-                state.params, bstats, mb, mb_mask)
+                state.params, bstats, mb, mb_mask, rng=None if key is None
+                else jax.random.fold_in(key, xs[-1]))
             rows = jnp.sum(mb_mask) if mb_mask is not None \
                 else jnp.float32(labels.shape[0])
             g_acc = jax.tree.map(
@@ -586,6 +615,8 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
             return (g_acc, l_acc, r_acc, new_bstats, ms), ()
 
         xs = (micro,) if micro_mask is None else (micro, micro_mask)
+        if key is not None:     # a micro-batch's place in the step
+            xs += (jnp.arange(accum),)
         carry0 = (g0, jnp.float32(0), jnp.float32(0), state.batch_stats, ms0)
         (g_acc, l_acc, r_acc, new_bstats, new_mstats), _ = lax.scan(
             body, carry0, xs)
@@ -988,7 +1019,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         train_step = _make_train_step(_apply, loss_fn, train_metrics,
                                       step_accum, step_remat,
                                       mb_shardings=(b_sharding, seq_sharding),
-                                      state_shardings=state_sharding)
+                                      state_shardings=state_sharding,
+                                      seed=self.seed)
 
         # publish the compiled step's peak temp (activation) bytes when the
         # activation plane is engaged — the residency number accumulation/
@@ -1451,7 +1483,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             mb_shardings=(batch_sharding(mesh),
                           batch_sharding(mesh, seq=True)
                           if self._use_seq(mesh) else None),
-            state_shardings=state_sharding)
+            state_shardings=state_sharding, seed=self.seed)
 
         dp_total = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
         # the ragged micro-batch tail under a >1 data extent (or a >1 stage
