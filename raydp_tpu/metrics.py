@@ -553,8 +553,8 @@ _ALL_SPANS = [
        "DeviceFeed construction (args: route=resident|stream), and the first "
        "host batch the state is shaped from (args: what=first_batch)."),
     _s("fit:init", "training",
-       "Eager state initialisation: model.init, optimizer state creation "
-       "and the sharding rules, before placement."),
+       "State initialisation: model.init as one jitted program, optimizer "
+       "state creation and the sharding rules, before placement."),
     _s("train:place", "training",
        "Sharded placement of the train state onto the mesh (host → device "
        "under each leaf's PartitionSpec; covers the initial FSDP/TP scatter "

@@ -66,6 +66,18 @@ def _takes_train(model) -> bool:
         return False
 
 
+def _init_variables(model, rng, inputs0):
+    """``model.init`` as ONE jitted program, for every path that builds a
+    state from nothing (a fresh fit, the rebuild after a failure with no
+    checkpoint, the online fit): an eager init dispatches the samplers op by
+    op, and the two forms differ in the parameters' last bit, so all three
+    take this one."""
+    import jax
+
+    kwargs = {"train": False} if _takes_train(model) else {}
+    return jax.jit(lambda key, x: model.init(key, x, **kwargs))(rng, inputs0)
+
+
 def _cast_floating(inputs, dtype):
     """Cast the floating leaves of a batch pytree to the compute dtype —
     THE cast policy, shared by the train loop and predict."""
@@ -963,8 +975,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                 {k: jnp.asarray(v[:1]) for k, v in first.items()})
             rng = jax.random.PRNGKey(self.seed)
             takes_train = _takes_train(model)
-            init_kwargs = {"train": False} if takes_train else {}
-            variables = model.init(rng, inputs0, **init_kwargs)
+            variables = _init_variables(model, rng, inputs0)
             batch_stats = variables.get("batch_stats")
 
             class _State(train_state.TrainState):
@@ -1357,7 +1368,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                     # first interval save): the failed state's buffers may
                     # already be donated away — rebuild from scratch like a
                     # fresh fit (the keras twin's no-checkpoint branch)
-                    variables = model.init(rng, inputs0, **init_kwargs)
+                    variables = _init_variables(model, rng, inputs0)
                     state = self._place_state(
                         _State.create(apply_fn=model.apply,
                                       params=variables["params"], tx=tx,
@@ -1458,8 +1469,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             {k: jnp.asarray(v[:1]) for k, v in first.items()})
         rng = jax.random.PRNGKey(self.seed)
         takes_train = _takes_train(model)
-        init_kwargs = {"train": False} if takes_train else {}
-        variables = model.init(rng, inputs0, **init_kwargs)
+        variables = _init_variables(model, rng, inputs0)
 
         class _State(train_state.TrainState):
             batch_stats: Any = None

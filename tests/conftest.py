@@ -17,6 +17,70 @@ os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
 
 import pytest  # noqa: E402
 
+# asserts in the LM families' shared helpers read like a test's own
+pytest.register_assert_rewrite("tests.lm_testing")
+
+# Under ``--dist loadfile`` a file is one unit of work, and xdist hands the
+# units out by their count of tests: a long file of few tests starts late and
+# the run waits for it (``test_chipbench_run.py``: 26 tests, on one worker
+# 1,174 s of the parent's 1,618 s). Here the units leave by the seconds
+# measured for their files, longest first (the unlisted after them, in
+# xdist's order), and a file
+# of ``SPLIT_BY_TEST`` a TEST at a time: it may stand there if it has no
+# module- or class-scoped fixture and its tests share no state, and is worth
+# it only above about a sixth of the run's wall (a split file pays its
+# imports and traced programs once a worker). The seconds are a file's tests
+# summed in a whole run of the driver's command (six workers on eight busy
+# cores, PR 51's sandbox): list a new file that takes over ~200 s.
+FILE_SECONDS = {
+    "tests/chipbench_contract/test_chipbench_run.py": 1568,
+    "tests/test_swa_moe_lm.py": 707,
+    "tests/test_afmoe_lm.py": 637,
+    "tests/chipbench_contract/test_chipbench_host_spans.py": 594,
+    "tests/test_ssm_moe_lm.py": 462,
+    "tests/test_rowwise_tables.py": 322,
+    "tests/test_examples.py": 270,
+    "tests/test_chaos.py": 265,
+    "tests/chipbench_contract/test_chipbench_trinity_mini.py": 248,
+    "tests/chipbench_contract/test_chipbench_kanana_2.py": 243,
+    "tests/chipbench_contract/test_chipbench_smallthinker.py": 228,
+    "tests/chipbench_contract/test_chipbench_nemotron_3_nano.py": 180,
+}
+SPLIT_BY_TEST = {
+    "tests/chipbench_contract/test_chipbench_run.py",
+    "tests/chipbench_contract/test_chipbench_host_spans.py",
+}
+
+
+def split_scope(nodeid):
+    """The unit of work a test belongs to: itself in a file of
+    ``SPLIT_BY_TEST``, its file otherwise."""
+    path = nodeid.split("::", 1)[0]
+    return nodeid if path in SPLIT_BY_TEST else path
+
+
+def file_seconds(scope):
+    return FILE_SECONDS.get(scope.split("::", 1)[0], 0)
+
+
+@pytest.hookimpl(optionalhook=True)     # no such hook under ``-p no:xdist``
+def pytest_xdist_make_scheduler(config, log):
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler.loadfile import LoadFileScheduling
+
+    class LongestFileFirst(LoadFileScheduling):
+        def _split_scope(self, nodeid):
+            return split_scope(nodeid)
+
+        def _assign_work_unit(self, node):
+            # the first of the longest: ties keep xdist's order
+            self.workqueue.move_to_end(
+                max(self.workqueue, key=file_seconds), last=False)
+            super()._assign_work_unit(node)
+
+    return LongestFileFirst(config, log)
+
 
 @pytest.fixture
 def runtime():

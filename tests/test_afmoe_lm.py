@@ -10,14 +10,18 @@ CPU, seeded random weights. Widths are small here, and only here (the fit at
 the CPU cut keeps them).
 """
 
-import copy
 import functools
 import os
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests import lm_testing
+from tests.lm_testing import (F32_TOL, ROOT, close as _close,
+                              leaves as _leaves, tokens as _tokens,
+                              train_step as _train_step,
+                              variables as _variables)
+
 CONFIG = "trinity-mini"
 
 # 8 query heads on 1 K/V head (eight a group, as published), the dense layer
@@ -30,32 +34,7 @@ TINY = {"hidden_size": 32, "head_dim": 8, "num_attention_heads": 8,
         "vocab_rows_held": 64, "seq_len": 32, "sliding_window": 8,
         "compared_positions": 8, "compute_dtype": "float32",
         "attention": "dense", "init_std": 0.3, "remat_blocks": False}
-F32_TOL = 2e-5
-
-
-def _files(**changed):
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
-    cfg.update(copy.deepcopy(TINY))
-    cfg["input"] = dict(cfg["input"], eos_id=63)
-    cfg.update(changed)
-    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
-            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
-
-
-def _leaves(tree):
-    import jax
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
-                     for k in path): np.asarray(v) for path, v in flat}
-
-
-def _close(got, want, tol=10 * F32_TOL):
-    got, want = _leaves(got), _leaves(want)
-    assert set(got) == set(want)
-    for name, g in got.items():
-        scale = max(np.abs(want[name]).max(), 1e-3)
-        assert np.abs(g - want[name]).max() <= tol * scale, name
+_files = functools.partial(lm_testing.files, CONFIG, TINY)
 
 
 def _f32(tree):
@@ -272,10 +251,10 @@ def test_a_shares_gradients_and_counts_match_the_references(first, held,
                first_expert=first, experts_held=held)
     w = np.random.default_rng(9).normal(size=m.shape).astype(np.float32)
     _program_layer = functools.partial(globals()["_program_layer"], **sizes)
-    got = jax.grad(lambda p, b, m: jnp.sum(_program_layer(
-        p, b, m, first, held, True)[0] * w), (0, 1, 2))(params, bias, m)
-    want = jax.grad(lambda p, m: jnp.sum(reference.expert_layer(
-        p, m, bias, cfg) * w), (0, 1))(params, m)
+    got = jax.jit(jax.grad(lambda p, b, m: jnp.sum(_program_layer(
+        p, b, m, first, held, True)[0] * w), (0, 1, 2)))(params, bias, m)
+    want = jax.jit(jax.grad(lambda p, m: jnp.sum(reference.expert_layer(
+        p, m, bias, cfg) * w), (0, 1)))(params, m)
     _close((got[0], got[2]), want)
     assert not np.any(np.asarray(got[1]))
     assert np.abs(np.asarray(want[0]["router"])).max() > 1e-3
@@ -329,24 +308,6 @@ def test_flash_with_a_group_of_eight_matches_dense_masked_attention(
 
 
 # ----------------------------------------------------- (d) the whole model
-def _tokens(cfg, rows, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, cfg["vocab_rows_held"], (rows, cfg["seq_len"]), dtype=np.int32)
-
-
-def _variables(model, tokens, seed=0, bias_std=0.0):
-    """Seeded parameters and, ``bias_std``, seeded non-zero biases."""
-    import jax
-    from raydp_tpu.models.moe import STATE
-    v = jax.tree.map(np.array, model.init(jax.random.PRNGKey(seed),
-                                          tokens[:1]))
-    rng = np.random.default_rng(seed)
-    for block in v[STATE].values():
-        block["moe"]["bias"] = rng.normal(
-            0, bias_std, block["moe"]["bias"].shape).astype(np.float32)
-    return v["params"], v[STATE]
-
-
 def test_the_parameter_tree_is_the_published_layers():
     """Layer 0 dense (SwiGLU of the dense width), then four expert layers
     with router, held experts and the shared expert; the gate and a norm a
@@ -404,62 +365,37 @@ def test_forward_logits_match_the_reference(dtype, attention, tol):
     cost under four norms a block (every sub-layer's output is normed to
     unit size, so a flipped expert is not small beside the stream)."""
     from chipbench.harness import relative_rms_error
-    cfg, pipeline, reference = _files(compute_dtype=dtype,
+    cfg, pipeline, _ = _files(compute_dtype=dtype,
                                       attention=attention)
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 2, seed=5)
     params, state = _variables(model, tokens, bias_std=0.1)
     variables = {"params": params, "batch_stats": state}
-    got = pipeline.compared(model.apply(variables, tokens), cfg)
-    want = reference.forward(variables, tokens, cfg)
+    got = pipeline.compared(lm_testing.logits(model, variables, tokens), cfg)
+    forward = lm_testing.reference_program(CONFIG, cfg, "forward")
+    want = forward(variables, tokens)
     assert got.shape == want.shape == (2, 8, 64)
     assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
     # the biases matter to the outputs compared
-    zero = reference.forward({"params": params}, tokens, cfg)
+    zero = forward({"params": params}, tokens)
     assert relative_rms_error(zero, want) > 100 * F32_TOL
 
 
 def test_the_reference_by_blocks_of_queries_is_the_reference():
+    import jax
     cfg, pipeline, reference = _files()
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 1, seed=2)
     params, _ = _variables(model, tokens)
-    whole = reference.forward({"params": params}, tokens, cfg)
+    forward = lambda: jax.jit(lambda p: reference.forward(  # noqa: E731
+        {"params": p}, tokens, cfg))(params)
+    whole = forward()
     block, reference.QUERY_BLOCK = reference.QUERY_BLOCK, 8
     try:
-        blocked = reference.forward({"params": params}, tokens, cfg)
+        blocked = forward()
     finally:
         reference.QUERY_BLOCK = block
     np.testing.assert_allclose(blocked, whole, rtol=1e-5, atol=1e-5)
-
-
-def _train_step(model, tx, accum):
-    """The estimator's own jitted train step round the model, and a state
-    for it."""
-    import jax
-    from flax.training import train_state
-    from raydp_tpu.train.flax_estimator import _make_apply, _make_train_step
-    from raydp_tpu.train.metrics import model_counters
-
-    class State(train_state.TrainState):
-        batch_stats: object = None
-
-    apply_fn = _make_apply(model, False, lambda b: (b["tokens"], b["tokens"]),
-                           None)
-    metrics = model_counters(model)
-    step = jax.jit(_make_train_step(apply_fn, None, metrics, accum, "none"))
-
-    def create(params, state):
-        return State.create(apply_fn=model.apply, params=params, tx=tx,
-                            batch_stats=state)
-
-    def run(state, tokens):
-        new, loss, stats = step(state, {"tokens": tokens},
-                                tuple(m.init() for m in metrics),
-                                np.float32(0))
-        return new, float(loss), np.asarray(stats[0])
-    run.step, run.metrics = step, metrics
-    return create, run
 
 
 @pytest.mark.parametrize("remat,accum,attention,forward", [
@@ -483,22 +419,19 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
     attention counts them ``twice``."""
     import jax
     import optax
-    from raydp_tpu import metrics as registry
     cfg, pipeline, reference = _files(remat_blocks=remat, attention=attention)
     model = pipeline.build_model(cfg)
     assert model.attention_forward == {forward: 5}
     tokens = _tokens(cfg, 4, seed=1)
     params, state = _variables(model, tokens, bias_std=0.1)
     w = np.full(4, 0.25, np.float32)
-    (loss, counts), grads = jax.value_and_grad(
-        lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
-                              tokens, w, method=model.loss_rows),
-        has_aux=True)(params)
-    want_loss, want_grads = jax.jit(jax.value_and_grad(
-        lambda p, t: reference.loss(p, state, t, cfg)))(params, tokens)
+    (loss, counts), grads = lm_testing.loss_and_grads(model, params, state,
+                                                      tokens, w)
+    want_loss, want_grads = lm_testing.reference_program(
+        CONFIG, cfg, "loss", grad=True)(params, state, tokens)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
-    counts_of = jax.jit(lambda p, st, t: reference.slot_counts(p, st, t, cfg))
+    counts_of = lm_testing.reference_program(CONFIG, cfg, "slot_counts")
     picked = np.stack(counts_of(params, state, tokens))
     assert float(counts[1]) == tokens.size * 4 * 4      # top-4, four layers
     assert float(counts[0]) == picked.max(axis=1).sum()
@@ -507,24 +440,21 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
                  for b in state.values())
     assert float(counts[4]) == pytest.approx(spread)
 
-    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
-        "train_attention_forward_total", {}))
-    before = counted()
-    create, run = _train_step(model, optax.sgd(0.05), accum)
-    assert {k: v - before.get(k, 0) for k, v in counted().items()
-            if v != before.get(k, 0)} == {forward: 5}
+    before = lm_testing.counters()
+    step, create, arguments = _train_step(model, optax.sgd(0.05), accum)
+    assert lm_testing.moved(before, "train_attention_forward_total") == {
+        forward: 5}
     now = create(params, state)
-    program = jax.make_jaxpr(run.step)(
-        now, {"tokens": tokens}, tuple(m.init() for m in run.metrics),
-        np.float32(0))
+    traced = jax.jit(step).trace(*arguments(now, tokens))
     # a scan over the micro-batches holds its body once
-    assert forward_flash_kernels(program) == (
+    assert forward_flash_kernels(traced.jaxpr) == (
         5 if attention == "flash" else 0)
+    run = traced.lower().compile()      # the one trace, run three times
     bias = {name: b["moe"]["bias"] for name, b in state.items()}
-    for step in range(3):
-        batch = _tokens(cfg, 4, seed=10 + step)
+    for i in range(3):
+        batch = _tokens(cfg, 4, seed=10 + i)
         before = jax.tree.map(np.asarray, (now.params, now.batch_stats))
-        now, _, stats = run(now, batch)
+        now, _, stats = run(*arguments(now, batch))
         want_counts = counts_of(*before, batch)
         for (name, b), c in zip(sorted(bias.items()), want_counts):
             assert float(np.sum(c)) == batch.size * 4
@@ -537,7 +467,7 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
             moved = np.asarray(got["bias"]) - before[1][name]["moe"]["bias"]
             assert abs(moved.sum()) < 1e-6
             assert np.abs(moved).max() <= 2 * cfg["load_balance_coeff"]
-        assert stats[1] == batch.size * 4 * 4
+        assert stats[0][1] == batch.size * 4 * 4
     assert any(np.abs(bias[n] - state[n]["moe"]["bias"]).max() > 1e-3
                for n in bias)
 
@@ -557,7 +487,6 @@ def test_a_recomputed_block_keeps_what_its_second_norms_read(
     ``rebuilt``; loss and gradients are the unrecomputed model's."""
     import jax
     import optax
-    from raydp_tpu import metrics as registry
 
     def built(remat):
         cfg, pipeline, _ = _files(layers=2, layers_held=[0, 7],
@@ -571,29 +500,19 @@ def test_a_recomputed_block_keeps_what_its_second_norms_read(
     tokens = _tokens(cfg, 4, seed=2)
     params, state = _variables(plain, tokens, bias_std=0.1)
     w = np.full(4, 0.25, np.float32)
-
-    def value_and_grad(model):
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
-                                  tokens, w, method=model.loss_rows)[0]))(
-                                      params)
-
-    loss, grads = value_and_grad(recomputed)
-    want_loss, want_grads = value_and_grad(plain)
+    (loss, _), grads = lm_testing.loss_and_grads(recomputed, params, state,
+                                                 tokens, w)
+    (want_loss, _), want_grads = lm_testing.loss_and_grads(
+        plain, params, state, tokens, w)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
 
-    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
-        "train_sublayer_out_total", {}))
-
     def products(model):
-        before = counted()
-        create, run = _train_step(model, optax.sgd(0.05), 1)
-        moved = {k: v - before.get(k, 0) for k, v in counted().items()
-                 if v != before.get(k, 0)}
-        return grouped_products(jax.make_jaxpr(run.step)(
-            create(params, state), {"tokens": tokens},
-            tuple(m.init() for m in run.metrics), np.float32(0))), moved
+        before = lm_testing.counters()
+        step, create, arguments = _train_step(model, optax.sgd(0.05))
+        moved = lm_testing.moved(before, "train_sublayer_out_total")
+        return grouped_products(jax.make_jaxpr(jax.jit(step))(
+            *arguments(create(params, state), tokens))), moved
 
     assert products(plain) == (11, {})
     assert products(recomputed) == (11, {"kept": 2, "rebuilt": 2})
@@ -606,19 +525,20 @@ def test_the_bias_has_no_gradient_no_decay_and_no_moments():
     for the bias; AdamW with decay on the matrices moves every parameter and
     the bias moves by the balancing step alone, whatever the learning rate."""
     import jax
-    cfg, pipeline, reference = _files()
+    cfg, pipeline, _ = _files()
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 4, seed=3)
     params, state = _variables(model, tokens, bias_std=0.1)
-    create, run = _train_step(model, pipeline.build_optimizer(
+    step, create, arguments = _train_step(model, pipeline.build_optimizer(
         dict(cfg, optimizer=dict(cfg["optimizer"], warmup_steps=1,
-                                 learning_rate=0.1))), 1)
+                                 learning_rate=0.1))))
+    run = jax.jit(step)
     now = create(params, state)
     n_params = sum(v.size for v in _leaves(params).values())
     moments = [v for v in jax.tree.leaves(now.opt_state) if np.ndim(v) > 0]
     assert sum(v.size for v in moments) == 2 * n_params     # mu and nu
-    now, _, _ = run(now, tokens)        # the rate is 0 at the first step
-    now, _, _ = run(now, tokens)
+    now, _, _ = run(*arguments(now, tokens))    # the first step's rate is 0
+    now, _, _ = run(*arguments(now, tokens))
     for name, leaf in _leaves(now.params).items():
         assert np.abs(leaf - _leaves(params)[name]).max() > 1e-4, name
     # decay on matrices only: with no gradient at all, a matrix shrinks and
@@ -647,22 +567,23 @@ def test_the_bias_survives_save_restore_and_one_more_step_bit_for_bit(
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 4, seed=4)
     params, state = _variables(model, tokens)
-    create, run = _train_step(model, optax.adam(1e-2), 2)
+    step, create, arguments = _train_step(model, optax.adam(1e-2), 2)
+    run = jax.jit(step)
     now = create(params, state)
-    for step in range(2):
-        now, _, _ = run(now, _tokens(cfg, 4, seed=20 + step))
+    for i in range(2):
+        now, _, _ = run(*arguments(now, _tokens(cfg, 4, seed=20 + i)))
     assert any(np.any(b["moe"]["bias"]) for b in now.batch_stats.values())
     ckpt.save(str(tmp_path), now, 2)
-    restored, step = ckpt.restore(str(tmp_path), now)
-    assert step == 2
+    restored, at = ckpt.restore(str(tmp_path), now)
+    assert at == 2
     for name, leaf in _leaves(restored.batch_stats).items():
         np.testing.assert_array_equal(leaf, _leaves(now.batch_stats)[name])
     more = _tokens(cfg, 4, seed=30)
-    a, loss_a, _ = run(now, more)
-    b, loss_b, _ = run(now.replace(
+    a, loss_a, _ = run(*arguments(now, more))
+    b, loss_b, _ = run(*arguments(now.replace(
         params=restored.params, opt_state=restored.opt_state,
-        batch_stats=restored.batch_stats, step=restored.step), more)
-    assert loss_a == loss_b
+        batch_stats=restored.batch_stats, step=restored.step), more))
+    assert float(loss_a) == float(loss_b)
     for got, want in ((b.params, a.params), (b.batch_stats, a.batch_stats),
                       (b.opt_state, a.opt_state)):
         got, want = _leaves(got), _leaves(want)
@@ -681,7 +602,7 @@ def test_older_models_have_no_state_and_the_options_default_off():
                           num_experts=4, experts_per_token=2, ffn_dim=8,
                           qk_norm=True)
     tokens = np.zeros((1, 8), np.int32)
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     assert set(variables) == {"params"}
     assert set(variables["params"]["block_0"]) == {"ln1", "ln2", "attn",
                                                    "moe"}
@@ -709,7 +630,6 @@ def test_fit_on_frame_at_the_cpu_cut_learns_counts_and_balances(session,
     from chipbench import manifest
     from raydp_tpu import metrics as registry
     from raydp_tpu.parallel import make_mesh
-    from raydp_tpu.train import FlaxEstimator
 
     cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
     pipeline = manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py")
@@ -729,13 +649,9 @@ def test_fit_on_frame_at_the_cpu_cut_learns_counts_and_balances(session,
     before = counters.get("moe_slots_total", {})
     forward = dict(counters.get("train_attention_forward_total", {}))
     outputs = dict(counters.get("train_sublayer_out_total", {}))
-    est = FlaxEstimator(
-        model=pipeline.build_model(cfg, mesh), loss=None,
-        optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
-        columns_spec={"tokens": (info["tokens"], np.int32)},
-        batch_preprocessor=lambda b: (b["tokens"], b["tokens"]),
-        shuffle=False, seed=0, num_epochs=2, batch_size=2, accum_steps=2,
-        checkpoint_interval=2)
+    est = lm_testing.estimator(cfg, pipeline, info, mesh, num_epochs=2,
+                               batch_size=2, accum_steps=2,
+                               checkpoint_interval=2)
     history = est.fit_on_frame(df.persist()).history
     losses = [e["train_loss"] for e in history]
     assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]

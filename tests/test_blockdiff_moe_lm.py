@@ -10,13 +10,18 @@ step's key, and the older families' lowered steps.
 from __future__ import annotations
 
 import copy
+import functools
 import hashlib
 import os
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests import lm_testing
+from tests.lm_testing import (F32_TOL, ROOT, close as _close,
+                              leaves as _leaves, step_text as _step_text,
+                              tokens as _tokens, train_step as _train_step)
+
 CONFIG = "sdar-30b-a3b-chat"
 
 # 8 query heads on 1 K/V head (eight a group, as published), two layers, 16
@@ -28,42 +33,8 @@ TINY = {"hidden_size": 32, "head_dim": 8, "num_attention_heads": 8,
         "num_experts_per_tok": 4, "vocab_size": 512, "vocab_rows_held": 64,
         "layers": 2, "seq_len": 32, "compared_positions": 8,
         "compute_dtype": "float32", "attention": "dense", "init_std": 0.3,
-        "remat_blocks": False}
-F32_TOL = 2e-5
-
-
-def _files(**changed):
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
-    cfg.update(copy.deepcopy(TINY))
-    cfg["input"] = dict(cfg["input"], eos_id=63)
-    cfg["diffusion"] = dict(cfg["diffusion"], mask_id=62)
-    for key in [k for k in changed if k in cfg["diffusion"]]:
-        cfg["diffusion"][key] = changed.pop(key)
-    cfg.update(changed)
-    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
-            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
-
-
-def _leaves(tree):
-    import jax
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
-                     for k in path): np.asarray(v) for path, v in flat}
-
-
-def _close(got, want, tol=10 * F32_TOL):
-    got, want = _leaves(got), _leaves(want)
-    assert set(got) == set(want)
-    for name, g in got.items():
-        scale = max(np.abs(want[name]).max(), 1e-3)
-        assert np.abs(g - want[name]).max() <= tol * scale, name
-
-
-def _tokens(cfg, rows, seed=0):
-    table = _files()[1].generate(rows, seed, cfg)
-    col = table["tokens"].combine_chunks()
-    return col.flatten().to_numpy().reshape(rows, cfg["seq_len"])
+        "remat_blocks": False, "diffusion": {"mask_id": 62}}
+_files = functools.partial(lm_testing.files, CONFIG, TINY)
 
 
 def _noised(cfg, pipeline, tokens):
@@ -76,13 +47,10 @@ def _noised(cfg, pipeline, tokens):
     return np.asarray(noised), np.asarray(level), np.asarray(masked)
 
 
-def _variables(model, tokens, seed=0):
+def _moved_norms(model, tokens, seed=0):
     """Seeded weights; norms' weights moved off 1 so that they count."""
-    import jax
-    variables = jax.tree.map(np.array, jax.jit(model.init)(
-        jax.random.PRNGKey(seed), tokens[:, :8]))
+    params, _ = lm_testing.variables(model, tokens[:, :8], seed)
     rng = np.random.default_rng(seed)
-    params = variables["params"]
     for name, block in params.items():
         for norm in ([block] if name == "ln_f" else
                      [block[k] for k in ("ln1", "ln2")] + [
@@ -90,32 +58,7 @@ def _variables(model, tokens, seed=0):
                      if name.startswith("block_") else []):
             norm["scale"] = (1 + rng.normal(0, 0.2, norm["scale"].shape)
                              ).astype(np.float32)
-    return variables
-
-
-def _train_step(model, tx, accum=1, seed=0):
-    """The estimator's own train step round the model (not yet jitted), a
-    state for it, and its metrics."""
-    from flax.training import train_state
-    from raydp_tpu.train.flax_estimator import _make_apply, _make_train_step
-    from raydp_tpu.train.metrics import model_counters
-
-    class State(train_state.TrainState):
-        batch_stats: object = None
-
-    apply_fn = _make_apply(model, False, lambda b: (b["tokens"], b["tokens"]),
-                           None)
-    metrics = model_counters(model)
-    step = _make_train_step(apply_fn, None, metrics, accum, "none", seed=seed)
-
-    def create(params, state=None):
-        return State.create(apply_fn=model.apply, params=params, tx=tx,
-                            batch_stats=state)
-
-    def arguments(state, tokens):
-        return (state, {"tokens": tokens}, tuple(m.init() for m in metrics),
-                np.float32(0))
-    return step, create, arguments
+    return {"params": params}
 
 
 # ------------------------------------------------------------ (a) the mask
@@ -330,7 +273,7 @@ def test_the_parameter_tree_is_the_published_layers():
 
     cfg, pipeline, _ = _files()
     model = pipeline.build_model(cfg)
-    params = _variables(model, _tokens(cfg, 1))["params"]
+    params = _moved_norms(model, _tokens(cfg, 1, pipeline=pipeline))["params"]
     shapes = {k: v.shape for k, v in _leaves(params).items()}
     assert {k: v for k, v in shapes.items() if k.startswith("block_1/")} == {
         "block_1/ln1/scale": (32,), "block_1/ln2/scale": (32,),
@@ -380,8 +323,8 @@ def test_forward_logits_match_the_reference(dtype, attention, tol):
     cfg, pipeline, reference = _files(compute_dtype=dtype,
                                       attention=attention)
     model = pipeline.build_model(cfg)
-    tokens = _tokens(cfg, 3)
-    variables = _variables(model, tokens)
+    tokens = _tokens(cfg, 3, pipeline=pipeline)
+    variables = _moved_norms(model, tokens)
     noised, level, masked = _noised(cfg, pipeline, tokens)
     assert 0.2 < masked.mean() < 0.8 and (noised[masked] == 62).all()
     logits = np.asarray(jax.jit(model.apply)(variables, tokens))
@@ -414,8 +357,8 @@ def test_a_planted_fault_reads_outside_the_tolerance():
     spec.loader.exec_module(control)
     cfg, pipeline, reference = _files()
     model = pipeline.build_model(cfg)
-    tokens = _tokens(cfg, 3)
-    variables = _variables(model, tokens)
+    tokens = _tokens(cfg, 3, pipeline=pipeline)
+    variables = _moved_norms(model, tokens)
     noised, level, _ = _noised(cfg, pipeline, tokens)
     got = np.asarray(pipeline.compared(
         jax.jit(model.apply)(variables, tokens), cfg))
@@ -450,8 +393,8 @@ def test_the_loss_and_its_gradients_match_the_reference(remat, attention):
     cfg, pipeline, reference = _files(remat_blocks=remat,
                                       attention=attention)
     model = pipeline.build_model(cfg)
-    tokens = _tokens(cfg, 2)
-    variables = _variables(model, tokens)
+    tokens = _tokens(cfg, 2, pipeline=pipeline)
+    variables = _moved_norms(model, tokens)
     noised, level, masked = _noised(cfg, pipeline, tokens)
     weights = jnp.full((2,), 0.5, jnp.float32)
 
@@ -480,9 +423,9 @@ def test_the_clean_half_does_not_see_the_noise_and_with_blocks_of_one_is_causal(
     import jax
 
     cfg, pipeline, _ = _files()
-    tokens = _tokens(cfg, 2)
+    tokens = _tokens(cfg, 2, pipeline=pipeline)
     model = pipeline.build_model(cfg)
-    variables = _variables(model, tokens)
+    variables = _moved_norms(model, tokens)
     noisy = jax.jit(lambda key: model.apply(
         variables, tokens, return_hidden=True, rngs={"diffusion": key}))
     hidden = [np.asarray(noisy(jax.random.PRNGKey(seed))) for seed in (1, 2)]
@@ -490,7 +433,7 @@ def test_the_clean_half_does_not_see_the_noise_and_with_blocks_of_one_is_causal(
     np.testing.assert_allclose(hidden[0][:, :32], hidden[1][:, :32],
                                rtol=1e-5, atol=1e-6)
     assert np.abs(hidden[0][:, 32:] - hidden[1][:, 32:]).max() > 0.1
-    ones = pipeline.build_model(_files(block_length=1)[0])
+    ones = pipeline.build_model(_files(diffusion={"block_length": 1})[0])
     hidden_of = lambda m: np.asarray(jax.jit(lambda: m.apply(  # noqa: E731
         variables, tokens, return_hidden=True))())
     causal = hidden_of(ones.clone(diffusion=None))
@@ -558,8 +501,8 @@ def test_a_train_step_draws_its_noise_from_the_seed_and_the_step():
 
     cfg, pipeline, _ = _files(layers=1)
     model = pipeline.build_model(cfg)
-    tokens = np.concatenate([_tokens(cfg, 2)] * 2)
-    params = _variables(model, tokens)["params"]
+    tokens = np.concatenate([_tokens(cfg, 2, pipeline=pipeline)] * 2)
+    params = _moved_norms(model, tokens)["params"]
 
     def run(seed, accum=1):
         """(loss, masked, all) of step 0, of step 0 again, and of step 1."""
@@ -616,26 +559,6 @@ PARENT_STEP = {
 }
 
 
-def _step_text(config, cell):
-    import jax
-    import optax
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{config}.json")
-    pipeline = manifest.load_module(ROOT, "pipelines", f"{config}.py")
-    wl = manifest.load_json(ROOT, "workloads", f"{cell}.json")
-    pipeline.cpu_cut(cfg, wl, 1)
-    model = pipeline.build_model(cfg)
-    tokens = np.zeros((1, wl["seq_len"]), np.int32)
-    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
-                                               tokens[:, :8]))
-    step, create, arguments = _train_step(model, optax.sgd(0.05))
-    state = jax.eval_shape(lambda: create(
-        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes["params"]),
-        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype),
-                     shapes.get("batch_stats"))))
-    return model, jax.jit(step).lower(*arguments(state, tokens)).as_text()
-
-
 @pytest.mark.parametrize("config,cell", [
     ("nemotron-3-nano-30b-a3b", "nemotron3_nano_30ba3b_16k_train")])
 def test_an_older_familys_step_is_the_parents_text(config, cell):
@@ -643,7 +566,7 @@ def test_an_older_familys_step_is_the_parents_text(config, cell):
     default: a model without ``diffusion`` names no stream, its attention
     takes the mask it took, its head loss shifts the labels, and the lowered
     step is the text it was."""
-    model, text = _step_text(config, cell)
+    model, text, _ = _step_text(config, cell)
     assert model.diffusion is None and model.rng_streams == ()
     assert "blockdiff" not in model.attention_layers
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]
@@ -652,7 +575,7 @@ def test_an_older_familys_step_is_the_parents_text(config, cell):
 def test_the_new_familys_step_holds_the_noise_and_the_new_mask():
     """And the new family's CPU-cut step is another program: it draws random
     bits, and its attention layers count under the new mask."""
-    model, text = _step_text(CONFIG, "sdar_30ba3b_8k_blockdiff_train")
+    model, text, _ = _step_text(CONFIG, "sdar_30ba3b_8k_blockdiff_train")
     assert model.rng_streams == ("diffusion",)
     assert model.attention_layers == {"blockdiff": 1}
     assert "diffusion" in text or "threefry" in text or "rng" in text

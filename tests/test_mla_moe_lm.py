@@ -10,14 +10,18 @@ sizes on the CPU, seeded random weights. Widths are small here, and only
 here (``tests/chipbench_contract/test_chipbench_kanana_2.py`` keeps them).
 """
 
-import copy
+import functools
 import hashlib
-import os
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests import lm_testing
+from tests.lm_testing import (F32_TOL, ROOT, close as _close,
+                              leaves as _leaves, step_text as _step_text,
+                              tokens as _tokens, train_step as _train_step,
+                              variables as _variables)
+
 CONFIG = "kanana-2-30b-a3b"
 
 # 4 heads of 16 + 8 beside 16 over a K/V latent of 24, the dense layer and
@@ -32,75 +36,7 @@ TINY = {"hidden_size": 32, "num_attention_heads": 4, "num_key_value_heads": 4,
         "layers": 3, "layers_held": [0, 1, 2],
         "compared_positions": 8, "compute_dtype": "float32",
         "attention": "dense", "init_std": 0.3, "remat_blocks": False}
-F32_TOL = 2e-5
-
-
-def _files(**changed):
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
-    cfg.update(copy.deepcopy(TINY))
-    cfg["input"] = dict(cfg["input"], eos_id=63)
-    cfg.update(changed)
-    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
-            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
-
-
-def _leaves(tree):
-    import jax
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
-                     for k in path): np.asarray(v) for path, v in flat}
-
-
-def _close(got, want, tol=10 * F32_TOL):
-    got, want = _leaves(got), _leaves(want)
-    assert set(got) == set(want)
-    for name, g in got.items():
-        scale = max(np.abs(want[name]).max(), 1e-3)
-        assert np.abs(g - want[name]).max() <= tol * scale, name
-
-
-def _tokens(cfg, rows, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, cfg["vocab_rows_held"], (rows, cfg["seq_len"]), dtype=np.int32)
-
-
-def _variables(model, tokens, seed=0, bias_std=0.0):
-    """Seeded parameters and, ``bias_std``, seeded non-zero biases."""
-    import jax
-    from raydp_tpu.models.moe import STATE
-    v = jax.tree.map(np.array, model.init(jax.random.PRNGKey(seed),
-                                          tokens[:1]))
-    rng = np.random.default_rng(seed)
-    for block in v[STATE].values():
-        block["moe"]["bias"] = rng.normal(
-            0, bias_std, block["moe"]["bias"].shape).astype(np.float32)
-    return v["params"], v[STATE]
-
-
-def _train_step(model, tx, accum=1):
-    """The estimator's own train step round the model (not yet jitted), a
-    state for it, and its metrics."""
-    from flax.training import train_state
-    from raydp_tpu.train.flax_estimator import _make_apply, _make_train_step
-    from raydp_tpu.train.metrics import model_counters
-
-    class State(train_state.TrainState):
-        batch_stats: object = None
-
-    apply_fn = _make_apply(model, False, lambda b: (b["tokens"], b["tokens"]),
-                           None)
-    metrics = model_counters(model)
-    step = _make_train_step(apply_fn, None, metrics, accum, "none")
-
-    def create(params, state):
-        return State.create(apply_fn=model.apply, params=params, tx=tx,
-                            batch_stats=state)
-
-    def arguments(state, tokens):
-        return (state, {"tokens": tokens}, tuple(m.init() for m in metrics),
-                np.float32(0))
-    return step, create, arguments
+_files = functools.partial(lm_testing.files, CONFIG, TINY)
 
 
 # --------------------------------------------- (b) RoPE over interleaved pairs
@@ -154,21 +90,22 @@ def test_latent_attention_matches_the_references(
         4, 24, 16, 8, 16, q_rank, attention, None, jnp.dtype(dtype),
         float(cfg["rope_theta"]), True, cfg["rms_norm_eps"], 0.3)
     u = np.random.default_rng(1).normal(size=(2, 32, 32)).astype(np.float32)
-    variables = layer.init(jax.random.PRNGKey(1), u)
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(1), u)
     params = jax.tree.map(np.asarray, variables["params"])
     params["kv_norm"]["scale"] = np.random.default_rng(2).uniform(
         0.5, 1.5, 24).astype(np.float32)
     names = {"kv_a", "kv_norm", "kv_b", "o"} | (
         {"q"} if q_rank is None else {"q_a", "q_a_norm", "q_b"})
     assert set(params) == names
-    got = layer.apply({"params": params}, jnp.asarray(u, jnp.dtype(dtype)))
+    got = jax.jit(layer.apply)({"params": params},
+                               jnp.asarray(u, jnp.dtype(dtype)))
     assert got.dtype == jnp.dtype(dtype) and got.shape == u.shape
-    want = reference.latent_attention(params, u, cfg)
+    latent = jax.jit(lambda p: reference.latent_attention(p, u, cfg))
+    want = latent(params)
     assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
     # the latent's norm and the one shared rotary key are in the result
     plain = dict(params, kv_norm={"scale": np.ones(24, np.float32)})
-    assert relative_rms_error(
-        reference.latent_attention(plain, u, cfg), want) > 0.01
+    assert relative_rms_error(latent(plain), want) > 0.01
 
 
 def test_latent_attention_takes_no_seq_axis_and_no_window():
@@ -256,18 +193,19 @@ def test_forward_logits_match_the_reference(dtype, attention, tol,
     rounding on the dense path and through the kernels (interpreted, keys of
     24 beside values of 16); bfloat16 inside what near-tied picks cost."""
     from chipbench.harness import relative_rms_error
-    cfg, pipeline, reference = _files(compute_dtype=dtype,
+    cfg, pipeline, _ = _files(compute_dtype=dtype,
                                       attention=attention)
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 2, seed=5)
     params, state = _variables(model, tokens, bias_std=0.1)
     variables = {"params": params, "batch_stats": state}
-    got = pipeline.compared(model.apply(variables, tokens), cfg)
-    want = reference.forward(variables, tokens, cfg)
+    got = pipeline.compared(lm_testing.logits(model, variables, tokens), cfg)
+    forward = lm_testing.reference_program(CONFIG, cfg, "forward")
+    want = forward(variables, tokens)
     assert got.shape == want.shape == (2, 8, 64)
     assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
     # the biases matter to the outputs compared
-    zero = reference.forward({"params": params}, tokens, cfg)
+    zero = forward({"params": params}, tokens)
     assert relative_rms_error(zero, want) > 100 * F32_TOL
 
 
@@ -290,15 +228,13 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
     tokens = _tokens(cfg, 4, seed=1)
     params, state = _variables(model, tokens, bias_std=0.1)
     w = np.full(4, 0.25, np.float32)
-    (loss, counts), grads = jax.value_and_grad(
-        lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
-                              tokens, w, method=model.loss_rows),
-        has_aux=True)(params)
-    want_loss, want_grads = jax.jit(jax.value_and_grad(
-        lambda p, t: reference.loss(p, state, t, cfg)))(params, tokens)
+    (loss, counts), grads = lm_testing.loss_and_grads(model, params, state,
+                                                      tokens, w)
+    want_loss, want_grads = lm_testing.reference_program(
+        CONFIG, cfg, "loss", grad=True)(params, state, tokens)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
-    counts_of = jax.jit(lambda p, st, t: reference.slot_counts(p, st, t, cfg))
+    counts_of = lm_testing.reference_program(CONFIG, cfg, "slot_counts")
     picked = np.stack(counts_of(params, state, tokens))
     assert float(counts[1]) == tokens.size * 6 * 2      # top-6, two layers
     assert float(counts[0]) == picked.max(axis=1).sum()
@@ -334,7 +270,6 @@ def test_a_recomputed_latent_block_keeps_its_kernels_pair(
     ``once`` and as ``latent``."""
     import jax
     import optax
-    from raydp_tpu import metrics as registry
 
     def built(remat):
         cfg, pipeline, _ = _files(remat_blocks=remat, attention="flash")
@@ -345,26 +280,18 @@ def test_a_recomputed_latent_block_keeps_its_kernels_pair(
     tokens = _tokens(cfg, 2, seed=2)
     params, state = _variables(plain, tokens, bias_std=0.1)
     w = np.full(2, 0.5, np.float32)
-
-    def value_and_grad(model):
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
-                                  tokens, w, method=model.loss_rows)[0]))(
-                                      params)
-
-    loss, grads = value_and_grad(recomputed)
-    want_loss, want_grads = value_and_grad(plain)
+    (loss, _), grads = lm_testing.loss_and_grads(recomputed, params, state,
+                                                 tokens, w)
+    (want_loss, _), want_grads = lm_testing.loss_and_grads(
+        plain, params, state, tokens, w)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
 
-    counted = lambda name: dict(  # noqa: E731
-        registry.snapshot()["counters"].get(name, {}))
-    names = ("train_attention_layers_total", "train_attention_forward_total")
-    before = [counted(n) for n in names]
+    before = lm_testing.counters()
     step, create, arguments = _train_step(recomputed, optax.sgd(0.05))
-    moved = [{k: v - b.get(k, 0) for k, v in counted(n).items()
-              if v != b.get(k, 0)} for n, b in zip(names, before)]
-    assert moved == [{"full": 3, "latent": 3}, {"once": 3}]
+    assert [lm_testing.moved(before, name) for name in (
+        "train_attention_layers_total", "train_attention_forward_total")] \
+        == [{"full": 3, "latent": 3}, {"once": 3}]
     program = str(jax.make_jaxpr(step)(*arguments(create(params, state),
                                                   tokens)))
     assert forward_flash_kernels(program) == 3
@@ -397,27 +324,6 @@ PARENT_STEP = {
 }
 
 
-def _step_text(config, cell):
-    import jax
-    import optax
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{config}.json")
-    pipeline = manifest.load_module(ROOT, "pipelines", f"{config}.py")
-    wl = manifest.load_json(ROOT, "workloads", f"{cell}.json")
-    pipeline.cpu_cut(cfg, wl, 1)
-    model = pipeline.build_model(cfg)
-    tokens = np.zeros((1, wl["seq_len"]), np.int32)
-    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
-                                               tokens[:, :8]))
-    step, create, arguments = _train_step(model, optax.sgd(0.05))
-    state = jax.eval_shape(lambda: create(
-        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes["params"]),
-        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype),
-                     shapes.get("batch_stats"))))
-    return (model, jax.jit(step).lower(*arguments(state, tokens)).as_text(),
-            shapes["params"]["block_0"]["attn"])
-
-
 @pytest.mark.parametrize("config,cell", [
     ("olmoe-1b-7b", "olmoe_1b7b_train"),
     ("smallthinker-21b-a3b", "smallthinker_21ba3b_16k_train"),
@@ -434,7 +340,8 @@ def test_an_older_familys_step_is_the_parents_text(config, cell, backward,
     from raydp_tpu.ops import flash_attention as fa
     if backward == "split":
         monkeypatch.setattr(fa, "_fused_backward_fits", lambda *a: False)
-    model, text, attn = _step_text(config, cell)
+    model, text, params = _step_text(config, cell)
+    attn = params["block_0"]["attn"]
     assert (model.kv_lora_rank, model.q_lora_rank, model.qk_nope_head_dim,
             model.qk_rope_head_dim, model.v_head_dim,
             model.rope_interleave) == (None,) * 5 + (False,)

@@ -4,13 +4,18 @@ against the plain reference (``chipbench/reference/olmoe-1b-7b.py``: float32
 on the CPU, seeded random weights. Widths are small here, and only here.
 """
 
-import copy
-import os
+import functools
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests import lm_testing
+from tests.lm_testing import (F32_TOL, close as _close,
+                              estimator as _estimator, leaves as _leaves,
+                              token_frame as _token_frame, tokens as _tokens,
+                              variables as _variables)
+
+CONFIG = "olmoe-1b-7b"
 
 TINY = {"hidden_size": 32, "num_attention_heads": 4, "intermediate_size": 16,
         "num_experts": 8, "num_experts_per_tok": 2, "vocab_size": 64,
@@ -18,58 +23,26 @@ TINY = {"hidden_size": 32, "num_attention_heads": 4, "intermediate_size": 16,
         "compute_dtype": "float32", "attention": "dense", "init_std": 0.3}
 
 
-def _files():
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", "olmoe-1b-7b.json")
-    cfg.update(copy.deepcopy(TINY))
-    cfg["input"] = dict(cfg["input"], eos_id=63)
-    return (cfg, manifest.load_module(ROOT, "pipelines", "olmoe-1b-7b.py"),
-            manifest.load_module(ROOT, "reference", "olmoe-1b-7b.py"))
+_files = functools.partial(lm_testing.files, CONFIG, TINY)
 
 
-def _tokens(cfg, rows, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (rows, cfg["max_position_embeddings"]),
-        dtype=np.int32)
-
-
-def _params(model, tokens, routing, seed=0):
-    """Seeded weights. ``skewed``: a constant feature in the embedding and a
-    router row that reads it, so that expert 0 is in every token's top-2 and
-    experts 5-7 are in nobody's: one expert holds half of all slots, three
-    groups are empty."""
-    import jax
-    params = jax.tree.map(np.array, model.init(
-        jax.random.PRNGKey(seed), tokens[:1])["params"])
-    if routing == "skewed":
-        params["embed"]["embedding"][:, 0] = 25.0
-        for name in (n for n in params if n.startswith("block_")):
-            router = params[name]["moe"]["router"]
-            router[0] = [6.0, 0, 0, 0, 0, -6.0, -6.0, -6.0]
+def _skewed(params):
+    """A constant feature in the embedding and a router row that reads it,
+    so that expert 0 is in every token's top-2 and experts 5-7 are in
+    nobody's: one expert holds half of all slots, three groups are empty."""
+    params["embed"]["embedding"][:, 0] = 25.0
+    for name in (n for n in params if n.startswith("block_")):
+        router = params[name]["moe"]["router"]
+        router[0] = [6.0, 0, 0, 0, 0, -6.0, -6.0, -6.0]
     return params
 
 
-def _leaves(tree):
-    import jax
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", k)) for k in path): np.asarray(v)
-            for path, v in flat}
+def _mean_weights(tokens):
+    """The mean's weights: what the train step hands the model's loss
+    without a mask."""
+    return np.full(len(tokens), 1.0 / len(tokens), np.float32)
 
 
-def _system_loss(model, params, tokens, weights=None):
-    """The model's own loss under the rows' weights (the mean's by default:
-    what the train step hands it without a mask)."""
-    if weights is None:
-        weights = np.full(len(tokens), 1.0 / len(tokens), np.float32)
-    return model.apply({"params": params}, tokens, tokens, weights,
-                       method=model.loss_rows)
-
-
-# float32 against float32-highest: what is left is summation order (a sorted
-# grouped product against a dense masked one). A bfloat16 router moves
-# near-tied top-k choices and a bfloat16 loss rounds at 2**-8: either fails
-# these by orders of magnitude.
-F32_TOL = 2e-5
 # bfloat16 activations, float32 router and loss: 4 ulps of bfloat16 on the
 # relative RMS error of the logits (the chip's check (a) and its TOLERANCE)
 BF16_TOL = 4 * 2.0 ** -8
@@ -79,13 +52,14 @@ BF16_TOL = 4 * 2.0 ** -8
                                        ("bfloat16", BF16_TOL)])
 def test_forward_logits_match_the_reference(dtype, tol):
     from chipbench.harness import relative_rms_error
-    cfg, pipeline, reference = _files()
-    cfg["compute_dtype"] = dtype
+    cfg, pipeline, _ = _files(compute_dtype=dtype)
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 3)
-    params = _params(model, tokens, "uniform")
-    got = pipeline.compared(model.apply({"params": params}, tokens), cfg)
-    want = reference.forward({"params": params}, tokens, cfg)
+    params, _ = _variables(model, tokens)
+    got = pipeline.compared(lm_testing.logits(model, {"params": params},
+                                              tokens), cfg)
+    want = lm_testing.reference_program(CONFIG, cfg, "forward")(
+        {"params": params}, tokens)
     assert got.shape == want.shape == (3, 4, cfg["vocab_size"])
     assert relative_rms_error(got, want) <= tol
     if dtype == "bfloat16":     # and the tolerance does separate precisions
@@ -98,26 +72,25 @@ def test_loss_and_every_gradient_leaf_match_the_reference(routing):
     half of all slots and three hold none, and every slot still contributes
     (the gradients of the full experts, the empty experts' zeros and the
     router's all match a reference that computes every expert densely)."""
-    import jax
-    cfg, pipeline, reference = _files()
+    cfg, pipeline, _ = _files()
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 4, seed=1)
-    params = _params(model, tokens, routing)
+    params, _ = _variables(model, tokens)
+    if routing == "skewed":
+        _skewed(params)
 
-    (loss, counts), grads = jax.value_and_grad(
-        lambda p: _system_loss(model, p, tokens), has_aux=True)(params)
-    want_loss, want_grads = jax.value_and_grad(reference.loss)(
-        params, tokens, cfg)
+    (loss, counts), grads = lm_testing.loss_and_grads(
+        model, params, None, tokens, _mean_weights(tokens))
+    want_loss, want_grads = lm_testing.reference_program(
+        CONFIG, cfg, "loss", grad=True)(params, tokens)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
-    got, want = _leaves(grads), _leaves(want_grads)
-    assert set(got) == set(want)
-    for name, g in got.items():
-        scale = max(np.abs(want[name]).max(), 1e-3)
-        assert np.abs(g - want[name]).max() <= 10 * F32_TOL * scale, name
+    _close(grads, want_grads)
+    got = _leaves(grads)
 
     slots = tokens.size * cfg["num_experts_per_tok"] * cfg["layers"]
     assert float(counts[1]) == slots
-    ids = np.stack(reference.top_k_ids(params, tokens, cfg))
+    ids = np.stack(lm_testing.reference_program(CONFIG, cfg, "top_k_ids")(
+        params, tokens))
     per_expert = np.stack([np.bincount(layer.ravel(), minlength=8)
                            for layer in ids])
     assert float(counts[0]) == per_expert.max(axis=1).sum()
@@ -147,9 +120,11 @@ def test_the_fused_loss_is_lm_loss_on_materialised_logits():
     cfg, pipeline, _ = _files()
     model = pipeline.build_model(_dense(cfg))
     tokens = _tokens(cfg, 3, seed=2)
-    params = _params(model, tokens, "uniform")
-    loss, counts = _system_loss(model, params, tokens)
-    want = lm_loss(model.apply({"params": params}, tokens), tokens)
+    params, _ = _variables(model, tokens)
+    (loss, counts), _ = lm_testing.loss_and_grads(
+        model, params, None, tokens, _mean_weights(tokens))
+    want = lm_loss(lm_testing.logits(model, {"params": params}, tokens),
+                   tokens)
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
     assert counts.shape == (0,) and model.loss_counters == ()
 
@@ -168,7 +143,7 @@ def test_the_model_loss_weighs_rows_and_a_masked_row_carries_no_gradient(
     cfg, pipeline, reference = _files()
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 4, seed=3)
-    params = _params(model, tokens, "uniform")
+    params, _ = _variables(model, tokens)
     w = np.asarray(weights, np.float32)
 
     def want_fn(p):
@@ -180,59 +155,33 @@ def test_the_model_loss_weighs_rows_and_a_masked_row_carries_no_gradient(
         aux = reference.loss(p, tokens, cfg) - reference.loss(p, tokens, dense)
         return sum(wi * r for wi, r in zip(w, rows)) + w.sum() * aux
 
-    (loss, _), grads = jax.value_and_grad(
-        lambda p: _system_loss(model, p, tokens, w), has_aux=True)(params)
-    want, want_grads = jax.value_and_grad(want_fn)(params)
+    (loss, _), grads = lm_testing.loss_and_grads(model, params, None,
+                                                 tokens, w)
+    want, want_grads = jax.jit(jax.value_and_grad(want_fn))(params)
     assert abs(float(loss) - float(want)) <= F32_TOL * max(float(want), 1.0)
-    got, want_leaves = _leaves(grads), _leaves(want_grads)
-    for name, g in got.items():
-        scale = max(np.abs(want_leaves[name]).max(), 1e-3)
-        assert np.abs(g - want_leaves[name]).max() <= 10 * F32_TOL * scale, name
+    _close(grads, want_grads)
+    got = _leaves(grads)
     if not w.any():
         assert float(loss) == 0.0
         assert all(not g.any() for g in got.values())
 
 
 def test_a_masked_rows_tokens_do_not_reach_a_dense_models_gradients():
-    import jax
     cfg, pipeline, _ = _files()
     model = pipeline.build_model(_dense(cfg))
     tokens = _tokens(cfg, 4, seed=4)
-    params = _params(model, tokens, "uniform")
+    params, _ = _variables(model, tokens)
     w = np.asarray([0.5, 0.5, 0.0, 0.0], np.float32)
     other = tokens.copy()
     other[2:] = _tokens(cfg, 2, seed=9)
-    grad = jax.grad(lambda p, t: _system_loss(model, p, t, w)[0])
-    a, b = _leaves(grad(params, tokens)), _leaves(grad(params, other))
-    halves = _leaves(jax.grad(lambda p: _system_loss(
-        model, p, tokens[:2])[0])(params))
+    program = lm_testing.loss_program(model)
+    grad = lambda t, w: _leaves(program(params, None, t, w)[1])  # noqa: E731
+    a, b = grad(tokens, w), grad(other, w)
+    halves = grad(tokens[:2], _mean_weights(tokens[:2]))
     for name in a:
         np.testing.assert_array_equal(a[name], b[name], err_msg=name)
         scale = max(np.abs(halves[name]).max(), 1e-3)
         assert np.abs(a[name] - halves[name]).max() <= 10 * F32_TOL * scale
-
-
-def _token_frame(session, tmp_path, cfg, pipeline, rows, seed):
-    import pyarrow.parquet as pq
-    path = str(tmp_path / "tokens")
-    os.makedirs(path)
-    table = pipeline.generate(rows, seed, cfg)
-    for i in range(2):
-        pq.write_table(table.slice(i * rows // 2, rows // 2),
-                       os.path.join(path, f"part-{i}.parquet"))
-    wl = {"seq_len": cfg["max_position_embeddings"]}
-    df, info = pipeline.etl(session.read.parquet(path), cfg, wl)
-    return df.persist(), info, table
-
-
-def _estimator(cfg, pipeline, info, mesh, **fit):
-    from raydp_tpu.train import FlaxEstimator
-    return FlaxEstimator(
-        model=pipeline.build_model(cfg, mesh), loss=None,
-        optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
-        columns_spec={"tokens": (info["tokens"], np.int32)},
-        batch_preprocessor=lambda b: (b["tokens"], b["tokens"]),
-        shuffle=False, batch_size=4, seed=0, **fit)
 
 
 @pytest.mark.parametrize("accum", [1, 2])
@@ -249,27 +198,26 @@ def test_fit_on_frame_reproduces_an_optax_loop_over_the_reference(
     from raydp_tpu import metrics as registry
     from raydp_tpu.parallel import make_mesh
 
-    cfg, pipeline, reference = _files()
+    cfg, pipeline, _ = _files()
     df, info, table = _token_frame(session, tmp_path, cfg, pipeline, 8, 5)
     mesh = make_mesh(None, devices=jax.devices()[:1])
     before = registry.snapshot()["counters"]
-    est = _estimator(cfg, pipeline, info, mesh, num_epochs=2,
+    est = _estimator(cfg, pipeline, info, mesh, num_epochs=2, batch_size=4,
                      accum_steps=accum)
     history = est.fit_on_frame(df).history
 
     tokens = pipeline.reference_inputs(table, info)
     assert tokens.shape == (8, 16) and tokens.dtype == np.int32
     tx = pipeline.build_optimizer(cfg)
-    params = jax.tree.map(np.asarray, est._build_model().init(
-        jax.random.PRNGKey(0), tokens[:1])["params"])
+    params, _ = _variables(est._build_model(), tokens)  # as the fit's are
     opt_state = tx.init(params)
-    grad = jax.value_and_grad(reference.loss)
+    grad = lm_testing.reference_program(CONFIG, cfg, "loss", grad=True)
     want = []
     for _ in range(2):
         losses = []
         for at in (0, 4):
             halves = np.split(tokens[at:at + 4], accum)
-            pairs = [grad(params, h, cfg) for h in halves]
+            pairs = [grad(params, h) for h in halves]
             losses.append(np.mean([float(v) for v, _ in pairs]))
             g = jax.tree.map(lambda *gs: sum(gs) / accum,
                              *[g for _, g in pairs])
@@ -312,7 +260,8 @@ def test_an_expert_sharded_fit_gives_the_single_device_losses(
     losses = {}
     for name, devices, spec in (("one", 1, None), ("four", 4, {"expert": 4})):
         mesh = make_mesh(spec, devices=jax.devices()[:devices])
-        est = _estimator(cfg, pipeline, info, mesh, num_epochs=2)
+        est = _estimator(cfg, pipeline, info, mesh, num_epochs=2,
+                         batch_size=4)
         losses[name] = [e["train_loss"]
                         for e in est.fit_on_frame(df).history]
         if name == "four":
@@ -378,7 +327,7 @@ def test_a_pad_and_mask_tail_fit_is_the_mean_over_real_rows(
     df, info, table = _token_frame(session, tmp_path, cfg, pipeline, 6, 7)
     mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
     before = _steps_counted()
-    est = _estimator(cfg, pipeline, info, mesh, num_epochs=2,
+    est = _estimator(cfg, pipeline, info, mesh, num_epochs=2, batch_size=4,
                      accum_steps=accum, drop_last=False)
     history = est.fit_on_frame(df, evaluate_df=df).history
     assert _steps_counted() == before + 1
@@ -387,11 +336,10 @@ def test_a_pad_and_mask_tail_fit_is_the_mean_over_real_rows(
     tokens = pipeline.reference_inputs(table, info)
     model = est._build_model()
     tx = pipeline.build_optimizer(cfg)
-    params = jax.tree.map(np.asarray, model.init(
-        jax.random.PRNGKey(0), tokens[:1])["params"])
+    params, _ = _variables(model, tokens)               # as the fit's are
     opt_state = tx.init(params)
-    grad = jax.value_and_grad(
-        lambda p, t: _plain_rows(model, p, t).mean())
+    plain = jax.jit(lambda p, t: _plain_rows(model, p, t).mean())
+    grad = jax.jit(jax.value_and_grad(plain))
     want, want_eval = [], []
     for _ in range(2):
         losses = []
@@ -401,7 +349,7 @@ def test_a_pad_and_mask_tail_fit_is_the_mean_over_real_rows(
             updates, opt_state = tx.update(g, opt_state, params)
             params = optax.apply_updates(params, updates)
         want.append(np.mean(losses))
-        want_eval.append(float(_plain_rows(model, params, tokens).mean()))
+        want_eval.append(float(plain(params, tokens)))
     np.testing.assert_allclose([e["train_loss"] for e in history], want,
                                rtol=2e-4)
     np.testing.assert_allclose([e["eval_loss"] for e in history], want_eval,

@@ -63,8 +63,9 @@ def test_ring_chunked_grad_matches_dense():
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v) ** 2)
 
-    g_ring = jax.grad(loss_ring)(q, k, v)
-    g_dense = jax.grad(loss_dense)(q, k, v)
+    # one program each: an eager ``shard_map`` runs op by op on every device
+    g_ring = jax.jit(jax.grad(loss_ring))(q, k, v)
+    g_dense = jax.jit(jax.grad(loss_dense))(q, k, v)
     np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_dense),
                                atol=5e-4, rtol=5e-4)
 
@@ -79,7 +80,7 @@ def test_ring_grad_flows():
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v) ** 2)
 
-    g_ring = jax.grad(loss_ring)(q, k, v)
-    g_dense = jax.grad(loss_dense)(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring))(q, k, v)
+    g_dense = jax.jit(jax.grad(loss_dense))(q, k, v)
     np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_dense),
                                atol=5e-4, rtol=5e-4)

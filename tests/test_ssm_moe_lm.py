@@ -11,7 +11,6 @@ weights. Widths are small here, and only here
 (``tests/chipbench_contract/test_chipbench_nemotron_3_nano.py`` keeps them).
 """
 
-import copy
 import functools
 import hashlib
 import os
@@ -19,7 +18,12 @@ import os
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests import lm_testing
+from tests.lm_testing import (F32_TOL, ROOT, close as _close,
+                              leaves as _leaves, step_text as _step_text,
+                              tokens as _tokens, train_step as _train_step,
+                              variables as _variables)
+
 CONFIG = "nemotron-3-nano-30b-a3b"
 
 # 4 state-space heads of 8 in 2 groups, a state of 16, chunks of 8, 4 taps;
@@ -35,81 +39,7 @@ TINY = {"hidden_size": 32, "mamba_num_heads": 4, "mamba_head_dim": 8,
         "vocab_size": 512, "vocab_rows_held": 64, "seq_len": 32,
         "compared_positions": 8, "compute_dtype": "float32",
         "attention": "dense", "init_std": 0.3, "remat_blocks": False}
-F32_TOL = 2e-5
-
-
-def _files(**changed):
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
-    cfg.update(copy.deepcopy(TINY))
-    cfg["input"] = dict(cfg["input"], eos_id=63)
-    cfg.update(changed)
-    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
-            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
-
-
-def _leaves(tree):
-    import jax
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
-                     for k in path): np.asarray(v) for path, v in flat}
-
-
-def _close(got, want, tol=10 * F32_TOL):
-    got, want = _leaves(got), _leaves(want)
-    assert set(got) == set(want)
-    for name, g in got.items():
-        scale = max(np.abs(want[name]).max(), 1e-3)
-        assert np.abs(g - want[name]).max() <= tol * scale, name
-
-
-def _tokens(cfg, rows, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, cfg["vocab_rows_held"], (rows, cfg["seq_len"]), dtype=np.int32)
-
-
-def _variables(model, tokens, seed=0, bias_std=0.0):
-    """Seeded parameters (the state-space layers' 1-D ones moved off their
-    round initial values) and, ``bias_std``, seeded non-zero biases."""
-    import jax
-    from raydp_tpu.models.moe import STATE
-    v = jax.tree.map(np.array, model.init(jax.random.PRNGKey(seed),
-                                          tokens[:1]))
-    rng = np.random.default_rng(seed)
-    for block in v[STATE].values():
-        block["moe"]["bias"] = rng.normal(
-            0, bias_std, block["moe"]["bias"].shape).astype(np.float32)
-    for block in v["params"].values():
-        for name in ("D", "norm", "conv_bias") if "ssm" in block else ():
-            leaf = block["ssm"][name]
-            block["ssm"][name] = (leaf + rng.normal(
-                0, 0.2, leaf.shape)).astype(np.float32)
-    return v["params"], v[STATE]
-
-
-def _train_step(model, tx, accum=1):
-    """The estimator's own train step round the model (not yet jitted), a
-    state for it, and its metrics."""
-    from flax.training import train_state
-    from raydp_tpu.train.flax_estimator import _make_apply, _make_train_step
-    from raydp_tpu.train.metrics import model_counters
-
-    class State(train_state.TrainState):
-        batch_stats: object = None
-
-    apply_fn = _make_apply(model, False, lambda b: (b["tokens"], b["tokens"]),
-                           None)
-    metrics = model_counters(model)
-    step = _make_train_step(apply_fn, None, metrics, accum, "none")
-
-    def create(params, state):
-        return State.create(apply_fn=model.apply, params=params, tx=tx,
-                            batch_stats=state)
-
-    def arguments(state, tokens):
-        return (state, {"tokens": tokens}, tuple(m.init() for m in metrics),
-                np.float32(0))
-    return step, create, arguments
+_files = functools.partial(lm_testing.files, CONFIG, TINY)
 
 
 def _kernels_of(jaxpr, found=None, outer=""):
@@ -174,6 +104,19 @@ def _scan_inputs(b, t, h, p, g, n, seed=0, steep=False):
     return x, dt, a, f(b, t, g, n), f(b, t, g, n), f(h)
 
 
+@functools.lru_cache(maxsize=None)
+def _recurrence():
+    """The reference's recurrence and its six gradients under a cotangent
+    (the first argument), jitted: once a worker, for both paths of a shape."""
+    import jax
+    import jax.numpy as jnp
+    from chipbench import manifest
+    recurrence = manifest.load_module(ROOT, "reference",
+                                      f"{CONFIG}.py").recurrence
+    return jax.jit(recurrence), jax.jit(jax.grad(
+        lambda g_y, *a: jnp.sum(recurrence(*a) * g_y), argnums=range(1, 7)))
+
+
 @pytest.mark.parametrize("path", ["jnp", "kernels"])
 @pytest.mark.parametrize("b,t,h,p,g,n,chunk,steep", [
     (1, 8, 2, 4, 2, 8, 8, False), (1, 32, 4, 4, 1, 8, 8, False),
@@ -201,26 +144,24 @@ def test_the_chunked_scan_is_the_recurrence(b, t, h, p, g, n, chunk, steep,
     either way."""
     import jax
     import jax.numpy as jnp
-    from chipbench import manifest
     from raydp_tpu.ops.ssd_scan import ssd_scan
 
-    reference = manifest.load_module(ROOT, "reference", f"{CONFIG}.py")
+    recurrence, recurrence_grads = _recurrence()
     args = tuple(map(jnp.asarray, _scan_inputs(b, t, h, p, g, n,
                                                steep=steep)))
     g_y = jnp.asarray(np.random.default_rng(1).normal(
         size=(b, t, h, p)).astype(np.float32))
     ours = lambda *a: ssd_scan(  # noqa: E731
         *a, chunk=chunk, interpret=path == "kernels")
-    got = ours(*args)
-    want = reference.recurrence(*args)
+    got = jax.jit(ours)(*args)
+    want = recurrence(*args)
     assert got.shape == want.shape == (b, t, h, p)
     scale = float(jnp.abs(want).max())
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) <= F32_TOL * scale
-    grads = jax.grad(lambda *a: jnp.sum(ours(*a) * g_y),
-                     argnums=range(6))(*args)
-    wants = jax.grad(lambda *a: jnp.sum(reference.recurrence(*a) * g_y),
-                     argnums=range(6))(*args)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(ours(*a) * g_y),
+                             argnums=range(6)))(*args)
+    wants = recurrence_grads(g_y, *args)
     for name, got, want in zip("x dt A B C D".split(), grads, wants):
         assert got.shape == want.shape and got.dtype == want.dtype, name
         assert bool(jnp.isfinite(got).all()), name
@@ -345,24 +286,25 @@ def test_the_mixer_matches_the_references(dtype, kernels, tol, request):
     layer = Mamba2Mixer(spec, jnp.dtype(dtype), cfg["layer_norm_epsilon"],
                         0.3)
     u = np.random.default_rng(1).normal(size=(2, 32, 32)).astype(np.float32)
-    params = jax.tree.map(np.asarray, layer.init(jax.random.PRNGKey(1),
-                                                 u)["params"])
+    params = jax.tree.map(np.asarray, jax.jit(layer.init)(
+        jax.random.PRNGKey(1), u)["params"])
     assert set(params) == {"in_proj", "conv", "conv_bias", "dt_bias", "A_log",
                            "D", "norm", "out_proj"}
     rng = np.random.default_rng(2)
     for name in ("conv_bias", "D", "norm"):
         params[name] = params[name] + rng.normal(
             0, 0.3, params[name].shape).astype(np.float32)
-    got = layer.apply({"params": params}, jnp.asarray(u, jnp.dtype(dtype)))
+    got = jax.jit(layer.apply)({"params": params},
+                               jnp.asarray(u, jnp.dtype(dtype)))
     assert got.dtype == jnp.dtype(dtype) and got.shape == u.shape
-    want = reference.mixer(params, u, cfg)
+    mixer = jax.jit(lambda p: reference.mixer(p, u, cfg))
+    want = mixer(params)
     assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
     # the skip D, the gated norm's weight and the convolution's bias are in
     # the result
     for name in ("D", "norm", "conv_bias"):
         other = dict(params, **{name: np.ones_like(params[name])})
-        assert relative_rms_error(reference.mixer(other, u, cfg),
-                                  want) > 0.01, name
+        assert relative_rms_error(mixer(other), want) > 0.01, name
     # dt_bias and A_log start where the configuration says, in float32
     dt = np.log1p(np.exp(params["dt_bias"]))
     assert params["dt_bias"].dtype == np.float32
@@ -498,18 +440,19 @@ def test_forward_logits_match_the_reference(dtype, kernels, tol, request):
     if kernels:
         request.getfixturevalue("scan_kernels")
         request.getfixturevalue("forward_flash_kernels")
-    cfg, pipeline, reference = _files(
+    cfg, pipeline, _ = _files(
         compute_dtype=dtype, attention="flash" if kernels else "dense")
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 2, seed=5)
     params, state = _variables(model, tokens, bias_std=0.1)
     variables = {"params": params, "batch_stats": state}
-    got = pipeline.compared(model.apply(variables, tokens), cfg)
-    want = reference.forward(variables, tokens, cfg)
+    got = pipeline.compared(lm_testing.logits(model, variables, tokens), cfg)
+    forward = lm_testing.reference_program(CONFIG, cfg, "forward")
+    want = forward(variables, tokens)
     assert got.shape == want.shape == (2, 8, 64)
     assert relative_rms_error(np.asarray(got, np.float32), want) <= tol
     # the biases matter to the outputs compared
-    zero = reference.forward({"params": params}, tokens, cfg)
+    zero = forward({"params": params}, tokens)
     assert relative_rms_error(zero, want) > 100 * F32_TOL
 
 
@@ -535,15 +478,13 @@ def test_loss_gradients_and_the_bias_after_three_steps_match_the_reference(
     tokens = _tokens(cfg, 4, seed=1)
     params, state = _variables(model, tokens, bias_std=0.1)
     w = np.full(4, 0.25, np.float32)
-    (loss, counts), grads = jax.value_and_grad(
-        lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
-                              tokens, w, method=model.loss_rows),
-        has_aux=True)(params)
-    want_loss, want_grads = jax.jit(jax.value_and_grad(
-        lambda p, t: reference.loss(p, state, t, cfg)))(params, tokens)
+    (loss, counts), grads = lm_testing.loss_and_grads(model, params, state,
+                                                      tokens, w)
+    want_loss, want_grads = lm_testing.reference_program(
+        CONFIG, cfg, "loss", grad=True)(params, state, tokens)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
-    counts_of = jax.jit(lambda p, st, t: reference.slot_counts(p, st, t, cfg))
+    counts_of = lm_testing.reference_program(CONFIG, cfg, "slot_counts")
     picked = np.stack(counts_of(params, state, tokens))
     assert float(counts[1]) == tokens.size * 6 * 4      # top-6, four layers
     assert float(counts[0]) == picked.max(axis=1).sum()
@@ -579,7 +520,6 @@ def test_a_recomputed_state_space_layer_scans_again(scan_kernels,
     expert trip forward, and counts its layers ``rescanned``."""
     import jax
     import optax
-    from raydp_tpu import metrics as registry
 
     def built(remat):
         cfg, pipeline, _ = _files(remat_blocks=remat, attention="flash")
@@ -590,27 +530,19 @@ def test_a_recomputed_state_space_layer_scans_again(scan_kernels,
     tokens = _tokens(cfg, 2, seed=2)
     params, state = _variables(plain, tokens, bias_std=0.1)
     w = np.full(2, 0.5, np.float32)
-
-    def value_and_grad(model):
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.apply({"params": p, "batch_stats": state}, tokens,
-                                  tokens, w, method=model.loss_rows)[0]))(
-                                      params)
-
-    loss, grads = value_and_grad(recomputed)
-    want_loss, want_grads = value_and_grad(plain)
+    (loss, _), grads = lm_testing.loss_and_grads(recomputed, params, state,
+                                                 tokens, w)
+    (want_loss, _), want_grads = lm_testing.loss_and_grads(
+        plain, params, state, tokens, w)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
 
-    counted = lambda name: dict(  # noqa: E731
-        registry.snapshot()["counters"].get(name, {}))
-    names = ("train_ssm_layers_total", "train_attention_layers_total",
-             "ssd_chunks_total")
-    before = [counted(n) for n in names]
+    before = lm_testing.counters()
     step, create, arguments = _train_step(recomputed, optax.sgd(0.05))
     program = jax.make_jaxpr(step)(*arguments(create(params, state), tokens))
-    moved = [{k: v - b.get(k, 0) for k, v in counted(n).items()
-              if v != b.get(k, 0)} for n, b in zip(names, before)]
+    moved = [lm_testing.moved(before, name) for name in (
+        "train_ssm_layers_total", "train_attention_layers_total",
+        "ssd_chunks_total")]
     assert moved[:2] == [{"rescanned": 4}, {"full": 1}]
     # sequences x groups x chunks a built kernel: 2 x 2 x 4
     assert moved[2]["backward"] == 4 * 16 and moved[2]["forward"] >= 8 * 16
@@ -639,7 +571,6 @@ def test_the_mixer_on_its_kernels_is_the_mixer_on_jax_numpy(scan_kernels,
     and each stage counts itself ``kernel`` once a layer call."""
     import jax
     import jax.numpy as jnp
-    from raydp_tpu import metrics as registry
     from raydp_tpu.models.transformer import Mamba2Mixer
     from raydp_tpu.ops import ssd_scan, ssm_glue
 
@@ -655,14 +586,11 @@ def test_the_mixer_on_its_kernels_is_the_mixer_on_jax_numpy(scan_kernels,
         params[name] = params[name] + rng.normal(
             0, 0.3, params[name].shape).astype(np.float32)
     g = jnp.asarray(rng.normal(size=u.shape), jnp.float32)
-    both = jax.value_and_grad(lambda p, u: jnp.sum(
-        layer.apply({"params": p}, u) * g), argnums=(0, 1), has_aux=False)
-    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
-        "ssm_glue_total", {}))
-    before = counted()
+    both = jax.jit(jax.value_and_grad(lambda p, u: jnp.sum(
+        layer.apply({"params": p}, u) * g), argnums=(0, 1), has_aux=False))
+    before = lm_testing.counters()
     program = jax.make_jaxpr(both)(params, u)
-    assert counted().get("kernel", 0) - before.get("kernel", 0) == 2
-    assert counted().get("jnp", 0) == before.get("jnp", 0)
+    assert lm_testing.moved(before, "ssm_glue_total") == {"kernel": 2}
     found = glue_kernels(program)
     under = lambda name: sorted(  # noqa: E731
         scope for kernel, scopes in found if kernel == name
@@ -699,21 +627,16 @@ def test_a_built_step_counts_its_glue_stages(widths, path, scan_kernels,
     twice, as its scan's does, the backward ones once)."""
     import jax
     import optax
-    from raydp_tpu import metrics as registry
 
     cfg, pipeline, _ = _files(remat_blocks=True, **widths)
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 2, seed=2)
     params, state = _variables(model, tokens, bias_std=0.1)
-    counted = lambda: dict(registry.snapshot()["counters"].get(  # noqa: E731
-        "ssm_glue_total", {}))
     step, create, arguments = _train_step(model, optax.sgd(0.05))
     built = create(params, state)
-    before = counted()
+    before = lm_testing.counters()
     program = jax.make_jaxpr(step)(*arguments(built, tokens))
-    moved = {k: v - before.get(k, 0) for k, v in counted().items()
-             if v != before.get(k, 0)}
-    assert moved == {path: 8}
+    assert lm_testing.moved(before, "ssm_glue_total") == {path: 8}
     names = [kernel for kernel, _ in glue_kernels(program)]
     per_layer = {"rdt_ssm_conv_fwd": 6, "rdt_ssm_conv_bwd": 3,
                  "rdt_ssm_norm_fwd": 2, "rdt_ssm_norm_bwd": 1}
@@ -761,27 +684,6 @@ PARENT_STEP = {
     "kanana-2-30b-a3b":
         "4a89659b2364d9de464b8b90da2cae401375f066d284ab2ce6d7ce904272ea4a",
 }
-
-
-def _step_text(config, cell):
-    import jax
-    import optax
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{config}.json")
-    pipeline = manifest.load_module(ROOT, "pipelines", f"{config}.py")
-    wl = manifest.load_json(ROOT, "workloads", f"{cell}.json")
-    pipeline.cpu_cut(cfg, wl, 1)
-    model = pipeline.build_model(cfg)
-    tokens = np.zeros((1, wl["seq_len"]), np.int32)
-    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
-                                               tokens[:, :8]))
-    step, create, arguments = _train_step(model, optax.sgd(0.05))
-    state = jax.eval_shape(lambda: create(
-        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes["params"]),
-        jax.tree.map(lambda s: np.zeros(s.shape, s.dtype),
-                     shapes.get("batch_stats"))))
-    return (model, jax.jit(step).lower(*arguments(state, tokens)).as_text(),
-            shapes["params"])
 
 
 @pytest.mark.parametrize("config,cell", [

@@ -8,14 +8,18 @@ sizes on the CPU, seeded random weights. Widths are small here, and only here
 (the fit at the CPU cut keeps them).
 """
 
-import copy
-import os
+import functools
 import re
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests import lm_testing
+from tests.lm_testing import (F32_TOL, ROOT, close as _close,
+                              estimator as _estimator, leaves as _leaves,
+                              token_frame as _token_frame, tokens as _tokens,
+                              variables as _variables)
+
 CONFIG = "smallthinker-21b-a3b"
 
 # 14 query heads on 2 K/V heads (seven a group, as published), four layers in
@@ -28,32 +32,7 @@ TINY = {"hidden_size": 32, "head_dim": 8, "num_attention_heads": 14,
         "vocab_rows_held": 64, "max_position_embeddings": 32,
         "sliding_window_size": 8, "layers": 4, "compared_positions": 8,
         "compute_dtype": "float32", "attention": "dense", "init_std": 0.3}
-F32_TOL = 2e-5
-
-
-def _files(**changed):
-    from chipbench import manifest
-    cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
-    cfg.update(copy.deepcopy(TINY))
-    cfg["input"] = dict(cfg["input"], eos_id=63)
-    cfg.update(changed)
-    return (cfg, manifest.load_module(ROOT, "pipelines", f"{CONFIG}.py"),
-            manifest.load_module(ROOT, "reference", f"{CONFIG}.py"))
-
-
-def _leaves(tree):
-    import jax
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", k)) for k in path): np.asarray(v)
-            for path, v in flat}
-
-
-def _close(got, want, tol=10 * F32_TOL):
-    got, want = _leaves(got), _leaves(want)
-    assert set(got) == set(want)
-    for name, g in got.items():
-        scale = max(np.abs(want[name]).max(), 1e-3)
-        assert np.abs(g - want[name]).max() <= tol * scale, name
+_files = functools.partial(lm_testing.files, CONFIG, TINY)
 
 
 # ------------------------------------------------- (a) the flash op
@@ -278,10 +257,9 @@ def test_a_backward_counts_itself_and_its_block_pairs_once_a_kernel(
 
 def _counted(name, call):
     """What counter ``name`` gains from ``call()``, by label."""
-    from raydp_tpu import metrics as registry
-    before = registry.snapshot()["counters"]
+    before = lm_testing.counters()
     call()
-    return _moved(before, registry.snapshot()["counters"], name)
+    return lm_testing.moved(before, name)
 
 
 # a head's tiles at the three cells' geometries (1024-blocks in tiles of 512,
@@ -918,18 +896,6 @@ def test_a_layer_that_holds_every_expert_is_the_layer_as_it_was(handed_in):
 
 
 # ----------------------------------------------------- (c) the whole model
-def _tokens(cfg, rows, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, cfg["vocab_rows_held"], (rows, cfg["max_position_embeddings"]),
-        dtype=np.int32)
-
-
-def _params(model, tokens, seed=0):
-    import jax
-    return jax.tree.map(np.array, model.init(
-        jax.random.PRNGKey(seed), tokens[:1])["params"])
-
-
 def test_the_parameter_tree_is_the_published_layers():
     """Grouped-query projections at the heads' own width, the router in the
     block (it reads the attention's input), the held experts' kernels, the
@@ -937,7 +903,7 @@ def test_the_parameter_tree_is_the_published_layers():
     cfg, pipeline, _ = _files()
     model = pipeline.build_model(cfg)
     shapes = {k: v.shape for k, v in _leaves(
-        _params(model, _tokens(cfg, 1))).items()}
+        _variables(model, _tokens(cfg, 1))[0]).items()}
     assert {k: v for k, v in shapes.items() if k.startswith("block_1/")} == {
         "block_1/ln1/scale": (32,), "block_1/ln2/scale": (32,),
         "block_1/router": (32, 8),
@@ -965,13 +931,15 @@ def test_the_parameter_tree_is_the_published_layers():
     ("bfloat16", "flash", 8 * 2.0 ** -8)])
 def test_forward_logits_match_the_reference(dtype, attention, tol):
     from chipbench.harness import relative_rms_error
-    cfg, pipeline, reference = _files(compute_dtype=dtype,
+    cfg, pipeline, _ = _files(compute_dtype=dtype,
                                       attention=attention)
     model = pipeline.build_model(cfg)
     tokens = _tokens(cfg, 3)
-    params = _params(model, tokens)
-    got = pipeline.compared(model.apply({"params": params}, tokens), cfg)
-    want = reference.forward({"params": params}, tokens, cfg)
+    params, _ = _variables(model, tokens)
+    got = pipeline.compared(lm_testing.logits(model, {"params": params},
+                                              tokens), cfg)
+    want = lm_testing.reference_program(CONFIG, cfg, "forward")(
+        {"params": params}, tokens)
     assert got.shape == want.shape == (3, 8, 64)
     assert relative_rms_error(got, want) <= tol
     if dtype == "bfloat16":     # and the tolerance does separate precisions
@@ -980,13 +948,16 @@ def test_forward_logits_match_the_reference(dtype, attention, tol):
 
 def test_the_reference_by_blocks_of_queries_is_the_reference():
     """A query block smaller than the sequence changes nothing."""
+    import jax
     cfg, pipeline, reference = _files()
     tokens = _tokens(cfg, 2, seed=3)
-    params = _params(pipeline.build_model(cfg), tokens)
-    whole = reference.forward({"params": params}, tokens, cfg)
+    params, _ = _variables(pipeline.build_model(cfg), tokens)
+    forward = lambda: jax.jit(lambda p: reference.forward(  # noqa: E731
+        {"params": p}, tokens, cfg))(params)
+    whole = forward()
     block, reference.QUERY_BLOCK = reference.QUERY_BLOCK, 8
     try:
-        blocked = reference.forward({"params": params}, tokens, cfg)
+        blocked = forward()
     finally:
         reference.QUERY_BLOCK = block
     np.testing.assert_allclose(blocked, whole, rtol=1e-5, atol=1e-5)
@@ -1005,23 +976,22 @@ def test_loss_rows_and_every_gradient_leaf_match_the_reference(
     row sums: the gradient's program holds one forward kernel a layer, as
     when nothing is recomputed."""
     import jax
-    cfg, pipeline, reference = _files(remat_blocks=remat, attention=attention)
+    cfg, pipeline, _ = _files(remat_blocks=remat, attention=attention)
     model = pipeline.build_model(cfg)
     assert model.attention_forward == {forward: 4}
     tokens = _tokens(cfg, 4, seed=1)
-    params = _params(model, tokens)
+    params, _ = _variables(model, tokens)
     w = np.full(4, 0.25, np.float32)
-    value_and_grad = jax.value_and_grad(
-        lambda p: model.apply({"params": p}, tokens, tokens, w,
-                              method=model.loss_rows), has_aux=True)
-    assert forward_flash_kernels(
-        jax.make_jaxpr(value_and_grad)(params)) == kernels
-    (loss, counts), grads = value_and_grad(params)
-    want_loss, want_grads = jax.value_and_grad(reference.loss)(
-        params, tokens, cfg)
+    value_and_grad = lm_testing.loss_program(model)
+    assert forward_flash_kernels(jax.make_jaxpr(value_and_grad)(
+        params, None, tokens, w)) == kernels
+    (loss, counts), grads = value_and_grad(params, None, tokens, w)
+    want_loss, want_grads = lm_testing.reference_program(
+        CONFIG, cfg, "loss", grad=True)(params, tokens)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
-    ids = np.stack(reference.top_k_ids(params, tokens, cfg))
+    ids = np.stack(lm_testing.reference_program(CONFIG, cfg, "top_k_ids")(
+        params, tokens))
     per_expert = np.stack([np.bincount(layer.ravel(), minlength=8)
                            for layer in ids])
     assert float(counts[1]) == tokens.size * 3 * 4
@@ -1043,20 +1013,14 @@ def test_a_block_without_second_norms_names_no_sublayer_output(
     layer at the cell's size, for nothing): the gradient's lowered program
     is the same text with and without ``SUBLAYER_OUT`` in the policy, and
     the model has nothing for ``train_sublayer_out_total`` to count."""
-    import jax
     cfg, pipeline, _ = _files(remat_blocks=True, attention=attention)
     model = pipeline.build_model(cfg)
     assert not model.sandwich_norms and model.sublayer_out == {}
     tokens = _tokens(cfg, 4, seed=1)
-    params = _params(model, tokens)
+    params, _ = _variables(model, tokens)
     w = np.full(4, 0.25, np.float32)
-
-    def lowered():
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.apply({"params": p}, tokens, tokens, w,
-                                  method=model.loss_rows)[0])).lower(
-                                      params).as_text()
-
+    lowered = lambda: lm_testing.loss_program(model).lower(  # noqa: E731
+        params, None, tokens, w).as_text()
     with_the_name = lowered()
     policy_without_sublayer_out()
     assert lowered() == with_the_name
@@ -1079,18 +1043,14 @@ def test_the_kept_pair_survives_the_map_over_a_mesh(forward_flash_kernels):
     mapped = TransformerLM(**sizes, mesh=mesh, remat_blocks=True)
     assert mapped.attention_forward == plain.attention_forward == {"once": 2}
     tokens = np.random.default_rng(5).integers(0, 64, (4, 32), np.int32)
-    params = _params(plain, tokens)
+    params, _ = _variables(plain, tokens)
     w = np.full(4, 0.25, np.float32)
-
-    def value_and_grad(model):
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.apply({"params": p}, tokens, tokens, w,
-                                  method=model.loss_rows)[0]))
-
-    program = str(jax.make_jaxpr(value_and_grad(mapped))(params))
+    of_mapped = lm_testing.loss_program(mapped)
+    program = str(jax.make_jaxpr(of_mapped)(params, None, tokens, w))
     assert "shard_map" in program and forward_flash_kernels(program) == 2
-    loss, grads = value_and_grad(mapped)(params)
-    want_loss, want_grads = value_and_grad(plain)(params)
+    (loss, _), grads = of_mapped(params, None, tokens, w)
+    (want_loss, _), want_grads = lm_testing.loss_and_grads(
+        plain, params, None, tokens, w)
     assert abs(float(loss) - float(want_loss)) <= F32_TOL * float(want_loss)
     _close(grads, want_grads)
 
@@ -1112,37 +1072,6 @@ def test_the_pattern_decides_each_layers_window_and_rope():
 
 
 # -------------------------------------------------------------- (d) a fit
-def _token_frame(session, tmp_path, cfg, pipeline, rows, seed):
-    import pyarrow.parquet as pq
-    path = str(tmp_path / "tokens")
-    os.makedirs(path)
-    table = pipeline.generate(rows, seed, cfg)
-    for i in range(2):
-        pq.write_table(table.slice(i * rows // 2, rows // 2),
-                       os.path.join(path, f"part-{i}.parquet"))
-    wl = {"seq_len": cfg["max_position_embeddings"]}
-    df, info = pipeline.etl(session.read.parquet(path), cfg, wl)
-    return df.persist(), info
-
-
-def _estimator(cfg, pipeline, info, mesh, **fit):
-    from raydp_tpu.train import FlaxEstimator
-    return FlaxEstimator(
-        model=pipeline.build_model(cfg, mesh), loss=None,
-        optimizer=pipeline.build_optimizer(cfg), mesh=mesh,
-        columns_spec={"tokens": (info["tokens"], np.int32)},
-        batch_preprocessor=lambda b: (b["tokens"], b["tokens"]),
-        shuffle=False, seed=0, **fit)
-
-
-def _moved(before, after, name):
-    """What a counter gained, by label; a label that gained nothing (another
-    test file's, earlier in this process) is left out."""
-    return {k: v - before.get(name, {}).get(k, 0)
-            for k, v in after.get(name, {}).items()
-            if v != before.get(name, {}).get(k, 0)}
-
-
 def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
     """The cell's own CPU cut (four layers in the pattern, 8 experts of which
     2 held, 2 a token, 512 of 2048 vocabulary rows, 256 positions with a
@@ -1151,7 +1080,6 @@ def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
     layers by kind."""
     import jax
     from chipbench import manifest
-    from raydp_tpu import metrics as registry
     from raydp_tpu.parallel import make_mesh
 
     cfg = manifest.load_json(ROOT, "configs", f"{CONFIG}.json")
@@ -1161,28 +1089,27 @@ def test_fit_on_frame_at_the_cpu_cut_learns_and_counts(session, tmp_path):
     assert (cfg["hidden_size"], cfg["head_dim"],
             cfg["moe_ffn_hidden_size"]) == (2560, 128, 768)
     cfg["compute_dtype"] = "float32"
-    df, info = _token_frame(session, tmp_path, cfg, pipeline, rows, 3)
+    df, info, _ = _token_frame(session, tmp_path, cfg, pipeline, rows, 3)
     mesh = make_mesh(None, devices=jax.devices()[:1])
-    before = registry.snapshot()["counters"]
+    before = lm_testing.counters()
     est = _estimator(cfg, pipeline, info, mesh, num_epochs=3, batch_size=1,
                      checkpoint_interval=3)
     history = est.fit_on_frame(df).history
     losses = [e["train_loss"] for e in history]
     assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
-    after = registry.snapshot()["counters"]
-    slots = _moved(before, after, "moe_slots_total")
+    slots = lm_testing.moved(before, "moe_slots_total")
     assert slots["all"] == 3 * rows * 256 * 2 * 4   # epochs, tokens, top-2, layers
     assert 0 < slots["held"] <= slots["moved"] < slots["all"]
     assert slots["moved"] % 64 == 0     # 512 slots a layer: trips of 64 rows
     assert slots["all"] / 8 <= slots["max_expert"] <= slots["all"]
-    assert _moved(before, after, "train_attention_layers_total") == {
+    assert lm_testing.moved(before, "train_attention_layers_total") == {
         "window": 3, "full": 1}
     # the cell's blocks are recomputed and their attention is the flash op
     assert (cfg["remat_blocks"], cfg["attention"]) == (True, "flash")
-    assert _moved(before, after, "train_attention_forward_total") == {
+    assert lm_testing.moved(before, "train_attention_forward_total") == {
         "once": 4}
     # and no second norm reads a sub-layer's output
-    assert _moved(before, after, "train_sublayer_out_total") == {}
+    assert lm_testing.moved(before, "train_sublayer_out_total") == {}
 
 
 def test_moved_slots_are_counted_where_a_share_is_held_and_only_there(
@@ -1191,16 +1118,15 @@ def test_moved_slots_are_counted_where_a_share_is_held_and_only_there(
     (the walk carried the held slots rounded up to its trips, and not every
     slot); a model that holds every expert still counts two entries."""
     import jax
-    from raydp_tpu import metrics as registry
     from raydp_tpu.parallel import make_mesh
 
     cfg, pipeline, _ = _files()
-    df, info = _token_frame(session, tmp_path, cfg, pipeline, 8, 2)
+    df, info, _ = _token_frame(session, tmp_path, cfg, pipeline, 8, 2)
     mesh = make_mesh(None, devices=jax.devices()[:1])
-    before = registry.snapshot()["counters"]
+    before = lm_testing.counters()
     _estimator(cfg, pipeline, info, mesh, num_epochs=1,
                batch_size=4).fit_on_frame(df)
-    slots = _moved(before, registry.snapshot()["counters"], "moe_slots_total")
+    slots = lm_testing.moved(before, "moe_slots_total")
     assert set(slots) >= {"max_expert", "all", "held", "moved"}
     assert slots["all"] == 8 * 32 * 3 * 4       # tokens, top-3, layers
     assert 0 < slots["held"] <= slots["moved"] < slots["all"]
@@ -1211,9 +1137,9 @@ def test_moved_slots_are_counted_where_a_share_is_held_and_only_there(
     assert whole.loss_counters == (("moe_slots_total", "max_expert"),
                                    ("moe_slots_total", "all"))
     tokens = _tokens(cfg, 2)
-    _, counts = whole.apply(
-        {"params": _params(whole, tokens)}, tokens, tokens,
-        np.full(2, 0.5, np.float32), method=whole.loss_rows)
+    (_, counts), _ = lm_testing.loss_and_grads(
+        whole, _variables(whole, tokens)[0], None, tokens,
+        np.full(2, 0.5, np.float32))
     assert counts.shape == (2,) and float(counts[1]) == 2 * 32 * 3 * 4
 
 
@@ -1226,7 +1152,7 @@ def test_an_expert_sharded_fit_of_the_share_gives_the_one_device_losses(
     from raydp_tpu.parallel import make_mesh
 
     cfg, pipeline, _ = _files()
-    df, info = _token_frame(session, tmp_path, cfg, pipeline, 8, 6)
+    df, info, _ = _token_frame(session, tmp_path, cfg, pipeline, 8, 6)
     losses = {}
     for name, devices, spec in (("one", 1, None), ("two", 2, {"expert": 2})):
         mesh = make_mesh(spec, devices=jax.devices()[:devices])

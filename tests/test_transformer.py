@@ -339,7 +339,7 @@ def test_lm_loss_fused_matches_materialized():
                           num_layers=2, attention="dense")
     rng = np.random.RandomState(0)
     tokens = jnp.asarray(rng.randint(0, vocab, size=(B, T)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)["params"]
     assert "lm_head" in params  # registered on the plain init path
 
     def loss_mat(p):
@@ -349,8 +349,8 @@ def test_lm_loss_fused_matches_materialized():
         hidden = model.apply({"params": p}, tokens, return_hidden=True)
         return lm_loss_fused(hidden, p["lm_head"]["kernel"], tokens, chunk=16)
 
-    v1, g1 = jax.value_and_grad(loss_mat)(params)
-    v2, g2 = jax.value_and_grad(loss_fused)(params)
+    v1, g1 = jax.jit(jax.value_and_grad(loss_mat))(params)
+    v2, g2 = jax.jit(jax.value_and_grad(loss_fused))(params)
     np.testing.assert_allclose(float(v1), float(v2), rtol=1e-5)
     flat1 = jax.tree_util.tree_leaves_with_path(g1)
     g2_by_path = dict(jax.tree_util.tree_leaves_with_path(g2))
