@@ -462,6 +462,18 @@ _ALL_METRICS = [
        "noise replaced by the mask id (the only ones with a loss term; "
        "about half under t ~ U(0, 1]). models/transformer.py.",
        label="tokens"),
+    _m("jit_lowerings_total", COUNTER, "1", "training",
+       "Programs jax lowered in this process (jaxpr to MLIR: every program "
+       "jax compiles, or loads from the persistent compile cache, is lowered "
+       "first), counted by profiler.watch_jit_builds from the first fit on. "
+       "After warm-up it should stand still: a serving replica or a long fit "
+       "that lowers again is rebuilding a program (a new shape, a changed "
+       "argument type)."),
+    _m("jit_compiles_total", COUNTER, "1", "training",
+       "Backend compiles jax asked for in this process, by what the "
+       "persistent compile cache did: `hit` (loaded), `miss` (compiled and "
+       "written) or `off` (compiled with no cache, or too small an entry to "
+       "be kept). Counted with jit_lowerings_total.", label="cache"),
     _m("train_accum_steps", GAUGE, "1", "training",
        "Gradient-accumulation microbatches per optimizer step this fit is "
        "running with (1 = unaccumulated; the RDT_TRAIN_ACCUM_STEPS / "
@@ -560,20 +572,25 @@ _ALL_SPANS = [
        "under each leaf's PartitionSpec; covers the initial FSDP/TP scatter "
        "or replication)."),
     _s("train:accum", "training",
-       "Compilation + activation-residency analysis of the accumulated "
-       "train step (the lax.scan over microbatches; covers the "
-       "memory_analysis read behind train_activation_bytes_per_process)."),
+       "Before the first dispatch of a fit whose step accumulates over "
+       "microbatches or recomputes its blocks (accum > 1 or remat): the "
+       "step's lower().compile() and the memory_analysis read behind "
+       "train_activation_bytes_per_process; the build's jit:* spans are its "
+       "children."),
     _s("train:pipeline", "training",
        "Compilation + activation-residency analysis of the pipelined "
        "(stage-stacked shard_map GPipe) train step — the train:accum twin "
        "for stage>1 fits."),
     _s("train:first_dispatch", "training",
        "The fit's first call of its jitted step program (train step or "
-       "resident epoch): trace, lower, compile or compile-cache load — the "
-       "synchronous part of the first call."),
+       "resident epoch), the synchronous part of it: the program's FIRST "
+       "build (its jit:trace, jit:lower and jit:compile children). A later "
+       "call that builds the program again (other argument types) is a "
+       "jit:* child of its train:epoch."),
     _s("train:epoch", "training",
        "One epoch of the train loop, loop top to after the callbacks (args: "
-       "epoch, steps); epoch 0 holds train:first_dispatch."),
+       "epoch, steps); epoch 0 holds train:first_dispatch and every other "
+       "build of the step (jit:*)."),
     _s("ckpt:save", "training",
        "One checkpoint write (args: step, bytes); children ckpt:import, "
        "ckpt:d2h and ckpt:write."),
@@ -585,6 +602,19 @@ _ALL_SPANS = [
     _s("ckpt:write", "training",
        "Host state to disk: the orbax (or sharded npz) write, the extra.json "
        "sidecar and retention pruning."),
+    # ---- what built a program (profiler.watch_jit_builds) -------------------
+    _s("jit:trace", "training",
+       "One function traced to a jaxpr (jax's jaxpr_trace_duration; args: "
+       "fun), recorded after the fact under the span active on its thread, "
+       "at or over profiler.JIT_SPAN_FLOOR_S. A function traced inside "
+       "another's trace nests in time, not by parent: read unions."),
+    _s("jit:lower", "training",
+       "One jaxpr lowered to an MLIR module (jaxpr_to_mlir_module_duration; "
+       "args: fun): Pallas kernel bodies are lowered to Mosaic here, in "
+       "every run, cache hit or miss."),
+    _s("jit:compile", "training",
+       "One backend compile or the persistent compile cache's load in its "
+       "place (backend_compile_duration; args: fun, cache=hit|miss|off)."),
     # ---- training: step spans (device trace only) ---------------------------
     _s("train:feed_wait", "training",
        "The train loop in next() on the feed: it has no batch to dispatch.",

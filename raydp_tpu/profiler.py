@@ -33,6 +33,12 @@ per-process lanes:
   too (tagged with their ``sid``), so the ``.xplane.pb`` holds every program
   span of the traced stretch beside ``XLA Ops``, and a ring-only span is
   placed on that clock by the offset of a mirrored one.
+- :func:`watch_jit_builds` — what built a program, and when: one listener on
+  ``jax.monitoring`` turns every trace, lowering and compile (or compile-cache
+  load) that jax reports at or over ``JIT_SPAN_FLOOR_S`` into a phase span
+  ``jit:trace`` / ``jit:lower`` / ``jit:compile`` under whatever span is
+  active on the calling thread. They are recorded after the fact
+  (:func:`record_span`), so they are ring-only spans and never annotations.
 - :func:`jax_trace` — the operator's way to such a trace: wraps
   ``jax.profiler`` around a few epochs; the result opens in Perfetto or
   TensorBoard with the ``train:*`` / ``feed:*`` rows above the device's.
@@ -240,6 +246,21 @@ def close_span(span: Dict[str, Any], **args) -> None:
     _append(rec)
 
 
+def record_span(name: str, start_s: float, end_s: float,
+                category: str = "app", **args) -> None:
+    """Record a span AFTER the fact, from its start and end as ``time.time()``
+    read them (the ring's clock): a child of the span active on the calling
+    thread, like any other. It was never open, so it is never mirrored into a
+    device trace (the module docstring's rule for a ring-only span places
+    it there). No-op when disabled."""
+    if not _enabled:
+        return
+    span = open_span(name, category, **args)
+    span["ts"] = int(start_s * 1e6)
+    span["dur"] = max(0, int(end_s * 1e6) - span["ts"])
+    _append(span)
+
+
 @contextlib.contextmanager
 def trace(name: str, category: str = "app", **args):
     """Record a wall-clock span around the body (no-op when disabled).
@@ -287,6 +308,72 @@ def export_spans() -> Dict[str, Any]:
     return {"spans": spans(), "threads": thread_names(),
             "dropped": spans_dropped(), "clock_ns": time.time_ns(),
             "pid": os.getpid()}
+
+
+# ---- what built a program, and when ------------------------------------------
+#: jax's own build events (``jax.monitoring``) and the phase span each leaves.
+#: ``backend_compile_duration`` wraps the persistent cache's lookup, so a load
+#: from the cache is a ``jit:compile`` span too (args: ``cache=hit``)
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit:lower",
+    "/jax/core/compile/backend_compile_duration": "jit:compile",
+}
+_JIT_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+#: a build event shorter than this leaves no span: every ``jnp`` function
+#: traced inside an outer trace fires an event of its own (some 300 a fit of
+#: a two-layer MLP, 90 of them ``add``), and the ring is for what costs set-up
+JIT_SPAN_FLOOR_S = 0.005
+_jit_cache = threading.local()  # .outcome: the compile under way hit | missed
+_jit_watched = False            # guarded-by: _lock
+
+
+def _on_jit_span(event: str, start_s: float, end_s: float, **kw) -> None:
+    name = _JIT_SPANS.get(event)
+    if name is None or not _enabled:
+        return
+    args = {"fun": kw.get("fun_name", "")}
+    if name == "jit:lower":
+        metrics.inc("jit_lowerings_total")
+    elif name == "jit:compile":
+        args["cache"] = getattr(_jit_cache, "outcome", "off")
+        _jit_cache.outcome = "off"
+        metrics.inc("jit_compiles_total", label=args["cache"])
+    if end_s - start_s >= JIT_SPAN_FLOOR_S:
+        record_span(name, start_s, end_s, "jit", **args)
+
+
+def _on_jit_event(event: str, **_kw) -> None:
+    outcome = _JIT_CACHE_EVENTS.get(event)
+    if outcome is not None and _enabled:
+        # fired on the compiling thread, inside its backend_compile_duration
+        _jit_cache.outcome = outcome
+
+
+def watch_jit_builds() -> None:
+    """Listen to jax's build events from now on (idempotent; a no-op in a
+    process that has not loaded jax, which this module never does itself).
+    Called where a process that runs jax programs starts its work
+    (``FlaxEstimator.fit``). Every trace, lowering and compile, or
+    compile-cache load, at or over ``JIT_SPAN_FLOOR_S`` then leaves a
+    ``jit:*`` span (args ``fun``, on a compile ``cache=hit|miss|off``) under
+    the span active on its thread; every lowering and compile, however short,
+    counts in ``jit_lowerings_total`` / ``jit_compiles_total``. Nested
+    traces overlap their parents: read a union of intervals, never a sum.
+    :func:`set_enabled` silences both at one check an event."""
+    global _jit_watched
+    if _jit_watched or "jax" not in sys.modules:
+        return
+    with _lock:
+        if _jit_watched:
+            return
+        _jit_watched = True
+    import jax.monitoring
+    jax.monitoring.register_event_time_span_listener(_on_jit_span)
+    jax.monitoring.register_event_listener(_on_jit_event)
 
 
 # the flight recorder wants every fired fault as an event; faults.py is a

@@ -6,7 +6,8 @@ are fresh.
 Five checks:
 
 1. **Span names + classes** — a literal first argument of
-   ``profiler.trace(...)`` / ``profiler.open_span(...)`` must be a
+   ``profiler.trace(...)`` / ``profiler.open_span(...)`` /
+   ``profiler.record_span(...)`` must be a
    registered PHASE span name (or fall under a registered dynamic family
    prefix like ``task:``), and one of ``profiler.step(...)`` a registered
    STEP span name: a per-batch span in the ring would flood it, a per-fit
@@ -38,7 +39,8 @@ RULE = "telemetry-registry"
 
 _METRIC_FUNCS = {"inc": "counter", "set_gauge": "gauge",
                  "observe": "histogram"}
-_SPAN_FUNCS = {"trace": "phase", "open_span": "phase", "step": "step"}
+_SPAN_FUNCS = {"trace": "phase", "open_span": "phase",
+               "record_span": "phase", "step": "step"}
 _REGEN = "python -m raydp_tpu.metrics --write-docs"
 
 

@@ -858,6 +858,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             ) -> TrainingResult:
         from raydp_tpu.data.feed import DeviceEpochCache, DeviceFeed
 
+        profiler.watch_jit_builds()
         mesh = self._build_mesh()
         columns = self._columns()
         ckpt_dir = self.checkpoint_dir or tempfile.mkdtemp(prefix="rdt-ckpt-")
@@ -1087,9 +1088,11 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
 
         def _dispatch(fn, *args):
             """Call the fit's step program (the resident epoch scan or the
-            streaming step). Its first call (trace, lower, compile or
-            compile-cache load, all synchronous) is a span, and is where an
-            engaged activation plane publishes the step's temp bytes."""
+            streaming step). Its first call (the program's first build:
+            trace, lower, compile or compile-cache load, all synchronous) is
+            a span, and is where an engaged activation plane publishes the
+            step's temp bytes. A later call whose argument types differ
+            builds the program again, as ``jit:*`` spans under its epoch."""
             if not first_dispatch[0]:
                 return fn(*args)
             first_dispatch[0] = False

@@ -135,7 +135,10 @@ def test_fit_on_frame_leaves_one_root_with_its_phases(session, monkeypatch):
     # (none overlaps another) is no longer than the root
     phases = ["fit:convert", "fit:shuffle", "fit:feed", "fit:init",
               "train:place", "train:epoch", "ckpt:save"]
-    kids = [s for s in ring if s.get("par") == run["sid"]]
+    # (an eager op that jax compiles between two of them leaves a jit:* child
+    # of the root too: tests/test_build_spans.py)
+    kids = [s for s in ring if s.get("par") == run["sid"]
+            and not s["name"].startswith("jit:")]
     assert {s["name"] for s in kids} == set(phases)
     assert all(s["tr"] == run["tr"] for s in kids)
     end = run["ts"] + run["dur"]
@@ -273,8 +276,10 @@ def test_device_trace_carries_the_programs_spans(traced_fit):
     assert decode["feed:decode"] == 9 and h2d["feed:h2d"] == 8
     assert not set(decode) & {"train:dispatch", "feed:h2d"}
     # the phase spans are mirrored on the loop's line, joined by span id
+    # (a jit:* span is recorded after the fact: in the ring, never mirrored)
     ring = traced_fit["ring"]
     assert sids and all(ring[sid] == name for sid, name in sids.items())
+    assert not any(name.startswith("jit:") for name in sids.values())
     assert {"fit:run", "train:epoch", "ckpt:save"} <= set(sids.values())
     assert {"fit:run", "train:epoch", "ckpt:write"} <= set(loop)
 
