@@ -583,14 +583,15 @@ _ALL_SPANS = [
        "for stage>1 fits."),
     _s("train:first_dispatch", "training",
        "The fit's first call of its jitted step program (train step or "
-       "resident epoch), the synchronous part of it: the program's FIRST "
-       "build (its jit:trace, jit:lower and jit:compile children). A later "
-       "call that builds the program again (other argument types) is a "
-       "jit:* child of its train:epoch."),
+       "resident epoch), the synchronous part of it: the step's one build "
+       "(its jit:trace, jit:lower and jit:compile children; every epoch's "
+       "accumulators have the types the step returns). A later call that "
+       "built the program again (other argument types) would be a jit:* "
+       "child of its train:epoch."),
     _s("train:epoch", "training",
        "One epoch of the train loop, loop top to after the callbacks (args: "
        "epoch, steps); epoch 0 holds train:first_dispatch and every other "
-       "build of the step (jit:*)."),
+       "build (jit:*): the eval step's, the epoch's zeros'."),
     _s("ckpt:save", "training",
        "One checkpoint write (args: step, bytes); children ckpt:import, "
        "ckpt:d2h and ckpt:write."),

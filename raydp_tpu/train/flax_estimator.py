@@ -641,6 +641,29 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     return train_step
 
 
+def _epoch_zeros(mesh, metrics, sums: int = 1):
+    """The program that makes an epoch's starting accumulators, ``(sums,
+    mstats)``: ``sums`` f32 scalar zeros and every metric's ``init()``, each
+    leaf strong-typed (``Metric.init`` gives Python floats) and replicated on
+    ``mesh``: the types the step returns them with, so the step's first call
+    of a fit and every later one present ``jax.jit`` one signature and the
+    step is built once. Call it anew each epoch (the step donates the loss
+    sum, the resident scan its whole carry). A jitted program and not a
+    ``device_put``: every process of a gang dispatches it at the same place
+    in the loop and none waits for another."""
+    import jax
+    import jax.numpy as jnp
+
+    from raydp_tpu.parallel.mesh import replicated
+
+    def zeros():
+        return (tuple(jnp.zeros((), jnp.float32) for _ in range(sums)),
+                tuple(jax.tree.map(lambda x: jnp.array(np.asarray(x)),
+                                   m.init()) for m in metrics))
+
+    return jax.jit(zeros, out_shardings=replicated(mesh))
+
+
 class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
     def __init__(
         self,
@@ -1024,6 +1047,10 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         # same order — a rank that is one step behind deadlocks the gang. With
         # in-jit accumulation the only host reads are float() of replicated
         # scalars at epoch end (also one fewer host sync single-process).
+        # An epoch's zeros come from a program too (``_epoch_zeros``), typed
+        # as the step returns them: replicated on the fit's mesh, strong f32.
+        # A host zero names no mesh, and the call that took one would be a
+        # signature, and a build of the step, of its own.
         loss_fn = _step_loss(_apply, loss_fn)      # eval_step's, below
         # what the model's own loss counts rides the train metrics' slot:
         # summed inside the step, fetched with the epoch's loss
@@ -1082,6 +1109,8 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
 
         jit_train = jax.jit(train_step, donate_argnums=(0, 3))
         jit_eval = jax.jit(eval_step, donate_argnums=(3, 4))
+        train_zeros = _epoch_zeros(mesh, train_metrics)
+        eval_zeros = _epoch_zeros(mesh, metrics, sums=2)
 
         step_span = profiler.step
         first_dispatch = [True]
@@ -1092,7 +1121,9 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             trace, lower, compile or compile-cache load, all synchronous) is
             a span, and is where an engaged activation plane publishes the
             step's temp bytes. A later call whose argument types differ
-            builds the program again, as ``jit:*`` spans under its epoch."""
+            builds the program again, as ``jit:*`` spans under its epoch: the
+            loop gives it none, since every epoch starts from
+            ``_epoch_zeros``'s accumulators, typed as the program's own."""
             if not first_dispatch[0]:
                 return fn(*args)
             first_dispatch[0] = False
@@ -1187,8 +1218,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                 with profiler.trace("train:epoch", "training",
                                     epoch=epoch) as epoch_span:
                     t0 = time.perf_counter()
-                    mstats = tuple(m.init() for m in train_metrics)
-                    loss_sum = np.zeros((), np.float32)
+                    (loss_sum,), mstats = train_zeros()
                     steps, samples = 0, 0
                     t_feed = t_disp = t_pull = 0.0
                     if cache is not None:
@@ -1295,9 +1325,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
 
                         if eval_feed is not None or eval_cache is not None:
                             with step_span("train:eval"):
-                                estats = tuple(m.init() for m in metrics)
-                                esum = np.zeros((), np.float32)
-                                ecnt = np.zeros((), np.float32)
+                                (esum, ecnt), estats = eval_zeros()
                                 if eval_cache is not None:
                                     _, estats, esum, ecnt = jit_eval_epoch(
                                         (state, estats, esum, ecnt),
@@ -1416,8 +1444,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                           prefetch_to_device=self.prefetch_to_device,
                           seq=o.get("seq", False))
         t0 = _time.perf_counter()
-        mstats = tuple(m.init() for m in self._metrics)
-        loss_sum = np.zeros((), np.float32)
+        (loss_sum,), mstats = o["zeros"]()
         steps = 0
         for batch in feed:
             o["state"], loss_sum, mstats = o["jit_train"](
@@ -1511,6 +1538,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             "columns": columns,
             "state": state,
             "jit_train": jax.jit(train_step, donate_argnums=(0, 3)),
+            "zeros": _epoch_zeros(mesh, metrics),
             "drop_last": (dp_total > 1 or n_stages > 1) and not pad_tail,
             "pad_tail": pad_tail,
             "seq": self._use_seq(mesh),

@@ -1,11 +1,12 @@
 """What built a program, and when: jax's build events as phase spans in the
 ring (``profiler.watch_jit_builds``), the two counters beside them, and a
-fit's epoch 0 read by them - the step program built twice shows as two."""
+fit's epoch 0 read by them - the step program is built once a fit."""
 
 import subprocess
 import sys
 import time
 
+import numpy as np
 import pytest
 from test_fit_spans import REPO, _by_name, _estimator, _frame
 
@@ -163,16 +164,16 @@ class _Events:
         return sum(e == event and d >= floor for e, d in self.heard)
 
 
-def _fit(num_epochs, **kw):
-    """One streaming fit; gives the ring, the jit counters and what an
-    independent listener heard meanwhile."""
+def _ring_of(train, resident=False):
+    """``train(frame)`` in a session of its own; gives what it returned, the
+    ring, the jit counters and what an independent listener heard meanwhile."""
     import jax.monitoring as mon
 
     import raydp_tpu
 
     events = _Events()
     with pytest.MonkeyPatch.context() as env:
-        env.setenv("RDT_DEVICE_CACHE", "0")
+        env.setenv("RDT_DEVICE_CACHE", "1" if resident else "0")
         session = raydp_tpu.init("pytest", num_executors=2, executor_cores=1,
                                  executor_memory="512MB")
         try:
@@ -181,7 +182,7 @@ def _fit(num_epochs, **kw):
             metrics.reset()
             mon.register_event_time_span_listener(events)
             try:
-                _estimator(num_epochs, **kw).fit_on_frame(df)
+                result = train(df)
             finally:
                 mon.unregister_event_time_span_listener(events)
             counters = _counters()
@@ -189,18 +190,56 @@ def _fit(num_epochs, **kw):
             raydp_tpu.stop()
     ring = profiler.spans()
     return {"ring": ring, "by_sid": {s["sid"]: s for s in ring},
-            "names": _by_name(ring), "counters": counters, "events": events}
+            "names": _by_name(ring), "counters": counters, "events": events,
+            "history": result.history}
+
+
+def _fit(num_epochs, resident=False, evaluate=False, **kw):
+    """One fit, streaming unless ``resident``, evaluated on its own rows if
+    asked."""
+    return _ring_of(lambda df: _estimator(num_epochs, **kw).fit_on_frame(
+        df, df if evaluate else None), resident)
 
 
 @pytest.fixture(scope="module")
 def fit():
-    return _fit(3)
+    """With a user's metric (``Metric.init`` gives weak-typed Python floats)
+    and an eval pass: both step programs in one fit."""
+    return _fit(3, evaluate=True, metrics=["mae"])
 
 
 @pytest.fixture(scope="module")
 def accumulating_fit():
     """Its step is compiled before the first call, under ``train:accum``."""
     return _fit(1, accum_steps=2)
+
+
+@pytest.fixture(scope="module")
+def resident_fit():
+    """The whole epoch, and the whole eval pass, as one scan program each."""
+    return _fit(3, resident=True, evaluate=True, metrics=["mae"])
+
+
+def _reg_table(epoch, rows=128):
+    import pyarrow as pa
+    x = np.random.RandomState(epoch).random_sample((rows, 2))
+    return pa.table({"x1": x[:, 0], "x2": x[:, 1],
+                     "y": x @ np.array([2.0, -3.0]) + 1.0})
+
+
+@pytest.fixture(scope="module")
+def online_fit():
+    """Two ``partial_fit`` epochs of two steps each."""
+    from raydp_tpu import stream
+
+    def train(_):
+        pipe = stream.read_stream(
+            stream.SyntheticSource(_reg_table, max_epochs=2))
+        try:
+            return _estimator(1, metrics=["mae"]).partial_fit(pipe)
+        finally:
+            pipe.close()
+    return _ring_of(train)
 
 
 def _ancestors(fit, span):
@@ -232,23 +271,87 @@ def test_every_build_of_a_fit_is_a_span_inside_its_parent(fit):
         n for n in fit["names"] if n.startswith(metrics.SPAN_PREFIXES)}
 
 
-def test_the_step_is_built_twice_and_the_second_build_shows(fit):
-    """The first build under ``train:first_dispatch``, the second (the call
-    that takes the step's own outputs) under epoch 0's ``train:epoch``."""
+def _built(fit, fun):
+    """The ring's builds of the program ``fun``, in the order they started."""
+    return sorted((s for s in _builds(fit) if fun in s["args"]["fun"]),
+                  key=lambda s: s["ts"])
+
+
+@pytest.mark.parametrize("which,under", [
+    ("fit", "train:first_dispatch"), ("accumulating_fit", "train:accum")])
+def test_the_step_is_built_once_under_its_first_build(request, which, under):
+    """Traced, lowered and compiled once a fit: the first call takes
+    accumulators of the types the step returns, so the call that takes the
+    step's own outputs finds the program built. Nothing named ``train_step``
+    is built under epoch 0 outside that one span."""
+    fit = request.getfixturevalue(which)
     epoch0 = fit["names"]["train:epoch"][0]
     (first,) = fit["names"]["train:first_dispatch"]
-    assert epoch0["args"]["epoch"] == "0" and first["par"] == epoch0["sid"]
-    step = [s for s in _builds(fit) if "train_step" in s["args"]["fun"]]
-    step.sort(key=lambda s: s["ts"])
-    assert [s["name"] for s in step] == list(KINDS) * 2
-    assert [s["par"] for s in step] == [first["sid"]] * 3 + [epoch0["sid"]] * 3
+    (build,) = fit["names"][under]
+    assert epoch0["args"]["epoch"] == "0"
+    assert first["par"] == build["par"] == epoch0["sid"]
+    step = _built(fit, "train_step")
+    assert [s["name"] for s in step] == list(KINDS)
+    assert [s["par"] for s in step] == [build["sid"]] * 3
     assert all(a["ts"] + a["dur"] <= b["ts"] + 1 for a, b in zip(step, step[1:]))
-    # every build under epoch 0 hangs from one of the two
-    under = [s for s in _builds(fit) if epoch0 in list(_ancestors(fit, s))]
-    assert {s["par"] for s in under} <= {first["sid"], epoch0["sid"]}
 
 
-def test_no_epoch_after_the_first_builds_anything(fit):
+#: by fixture: the program that runs an epoch's steps, and the eval pass's
+#: (None: the fit has none)
+PROGRAMS = {"fit": ("train_step", "eval_step"),
+            "accumulating_fit": ("train_step", None),
+            "resident_fit": ("epoch_fn", "epoch_fn"),
+            "online_fit": ("train_step", None)}
+
+
+@pytest.mark.parametrize("which", list(PROGRAMS))
+def test_every_step_program_of_a_fit_is_lowered_once(request, which):
+    """Over three epochs (two of ``partial_fit``): the streaming step with a
+    user's weak-typed metric, the eval step (its second batch once built it
+    again), the resident path's two scans (one name: two lowerings), the
+    online step."""
+    fit = request.getfixturevalue(which)
+    train, evaluate = PROGRAMS[which]
+    assert len(fit["history"]) == {"accumulating_fit": 1,
+                                   "online_fit": 2}.get(which, 3)
+    assert all(h["steps"] >= 2 for h in fit["history"])
+    want = {train: 1}
+    if evaluate:
+        assert all("eval_loss" in h for h in fit["history"])
+        want[evaluate] = want.get(evaluate, 0) + 1
+    for fun, times in want.items():
+        lowered = [s for s in _built(fit, fun) if s["name"] == "jit:lower"]
+        assert len(lowered) == times, [s["args"] for s in lowered]
+    # and the program that makes an epoch's zeros once for each of the two
+    zeros = [s for s in _built(fit, "zeros") if s["name"] == "jit:compile"]
+    assert len(zeros) <= 1 + bool(evaluate)
+
+
+#: what the parent of the one-build change printed for the same fits
+LOSSES = {
+    "fit": ([1.0157625675201416, 0.7355257868766785, 0.5607577562332153],
+            [0.8337425589561462, 0.636257529258728, 0.464870810508728],
+            [0.8178235292434692, 0.7093298435211182, 0.6268231272697449]),
+    "resident_fit": (
+        [1.0214362144470215, 0.7455786466598511, 0.567087709903717],
+        [0.8363513946533203, 0.6378688812255859, 0.47131332755088806],
+        [0.8227283358573914, 0.71086186170578, 0.6299079060554504]),
+}
+
+
+@pytest.mark.parametrize("which", list(LOSSES))
+def test_the_epochs_losses_are_the_two_build_fits(request, which):
+    """Zeros of another type are the same zeros: the sums the step threads
+    through, the eval pass's and a metric's come out as they did."""
+    history = request.getfixturevalue(which)["history"]
+    got = [[h[key] for h in history]
+           for key in ("train_loss", "eval_loss", "train_mae")]
+    np.testing.assert_allclose(got, LOSSES[which], rtol=1e-5)
+
+
+@pytest.mark.parametrize("which", ["fit", "resident_fit"])
+def test_no_epoch_after_the_first_builds_anything(request, which):
+    fit = request.getfixturevalue(which)
     later = {s["sid"] for s in fit["names"]["train:epoch"]
              if s["args"]["epoch"] != "0"}
     assert len(later) == 2
@@ -294,7 +397,7 @@ def test_an_accumulating_fit_builds_its_step_under_train_accum(
 
 @pytest.mark.parametrize("which", ["fit", "accumulating_fit"])
 def test_the_benchmarks_readers_read_a_real_ring(request, monkeypatch, which):
-    """``chipbench/trace/build_spans.py`` on the ring of a fit: two builds of
+    """``chipbench/trace/build_spans.py`` on the ring of a fit: one build of
     the step, and the four parts cover epoch 0."""
     from chipbench.trace import build_spans, fit_spans
     fit = request.getfixturevalue(which)
@@ -302,7 +405,7 @@ def test_the_benchmarks_readers_read_a_real_ring(request, monkeypatch, which):
     epoch0 = fit["names"]["train:epoch"][0]["dur"] / 1e6
     parts = [build_spans.kind_s(kind) for kind in KINDS]
     assert all(p > 0 for p in parts)
-    assert build_spans.step_builds() == 2
+    assert build_spans.step_builds() == 1
     assert 0 < build_spans.run_s() < epoch0
     assert sum(parts) + build_spans.run_s() >= epoch0 - 1e-6
     # nothing here has a compile cache: every compile says so
