@@ -13,54 +13,94 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
+# XLA:CPU's "machine feature ... not supported" on every executable it loads
+# from the cache is an error-level line of 3 kB: silenced with the rest
 os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
 
+import functools  # noqa: E402
+import re  # noqa: E402
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
+
 import pytest  # noqa: E402
+
+# jax's name for its cache directory, as raydp_tpu.utils.COMPILE_CACHE_ENV
+# has it (``test_lm_testing`` holds the two equal): importing the package
+# here could import jax before the variables set above and in
+# ``pytest_configure`` are read
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def run_compile_cache(environ=os.environ):
+    """ONE compile cache a run: the xdist controller (or the lone process)
+    makes a new directory under the run's temporary one and names it in the
+    environment, where the workers, the executors and every subprocess find
+    it; a worker makes none. Never the checkout's ``.jax_cache`` nor a
+    directory an earlier run filled: a run's seconds do not depend on what
+    ran before it. Returns the directory where this process made it."""
+    if "PYTEST_XDIST_WORKER" in environ:
+        return None
+    made = tempfile.mkdtemp(prefix="rdt-compile-cache-")
+    environ[COMPILE_CACHE_ENV] = made
+    # what took under half a second to compile costs more as a file than it
+    # saves (measured: CHANGES.md, PR 54)
+    environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
+    return made
+
+
+_cache_made = None
+
+
+def pytest_configure(config):
+    # not at import: ``from tests import conftest`` is a second copy of this
+    # module. Still before jax is imported (by a test file, at collection)
+    # and before the controller starts its workers (at the session's start)
+    global _cache_made
+    _cache_made = run_compile_cache()
+
+
+def pytest_unconfigure(config):
+    if _cache_made:
+        shutil.rmtree(_cache_made, ignore_errors=True)
+
 
 # asserts in the LM families' shared helpers read like a test's own
 pytest.register_assert_rewrite("tests.lm_testing")
 
-# Under ``--dist loadfile`` a file is one unit of work, and xdist hands the
-# units out by their count of tests: a long file of few tests starts late and
-# the run waits for it (``test_chipbench_run.py``: 26 tests, on one worker
-# 1,174 s of the parent's 1,618 s). Here the units leave by the seconds
-# measured for their files, longest first (the unlisted after them, in
-# xdist's order), and a file
-# of ``SPLIT_BY_TEST`` a TEST at a time: it may stand there if it has no
-# module- or class-scoped fixture and its tests share no state, and is worth
-# it only above about a sixth of the run's wall (a split file pays its
-# imports and traced programs once a worker). The seconds are a file's tests
-# summed in a whole run of the driver's command (six workers on eight busy
-# cores, PR 51's sandbox): list a new file that takes over ~200 s.
-FILE_SECONDS = {
-    "tests/chipbench_contract/test_chipbench_run.py": 1568,
-    "tests/test_swa_moe_lm.py": 707,
-    "tests/test_afmoe_lm.py": 637,
-    "tests/chipbench_contract/test_chipbench_host_spans.py": 594,
-    "tests/test_ssm_moe_lm.py": 462,
-    "tests/test_rowwise_tables.py": 322,
-    "tests/test_examples.py": 270,
-    "tests/test_chaos.py": 265,
-    "tests/chipbench_contract/test_chipbench_trinity_mini.py": 248,
-    "tests/chipbench_contract/test_chipbench_kanana_2.py": 243,
-    "tests/chipbench_contract/test_chipbench_smallthinker.py": 228,
-    "tests/chipbench_contract/test_chipbench_nemotron_3_nano.py": 180,
-}
-SPLIT_BY_TEST = {
-    "tests/chipbench_contract/test_chipbench_run.py",
-    "tests/chipbench_contract/test_chipbench_host_spans.py",
-}
+# Under ``--dist loadfile`` a file is one unit of work and xdist hands the
+# units out by their count of tests, most first. The files of
+# ``tests/chipbench_contract/`` hold the longest single tests of the run (a
+# cell's rehearsal, a chip-free compile: one to four minutes each under six
+# workers) in files of few tests, which as whole units would start late and
+# the run would wait for them: their tests leave one at a time instead, after
+# the whole files (a unit of one test sorts last), where they fill the
+# workers evenly to the end. There is no table of seconds to keep. A file
+# may be split if its tests share nothing a worker would build again: no
+# fixture wider than a test. That is read from the file's own source (the
+# controller schedules node ids and holds no fixtures): a wide fixture that an
+# import or a ``conftest.py`` of that directory brought in would not be seen,
+# and the directory has neither.
+SPLIT_DIR = "tests/chipbench_contract/"
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_WIDER_THAN_A_TEST = re.compile(
+    r"scope\s*=\s*[\"'](module|class|package|session)"
+    r"|def (setup|teardown)_(module|class)\b")
+
+
+@functools.lru_cache(maxsize=None)
+def split_by_test(path):
+    """Whether the tests of the collected file ``path`` leave one at a time."""
+    if not path.startswith(SPLIT_DIR):
+        return False
+    with open(os.path.join(_ROOT, path)) as f:
+        return not _WIDER_THAN_A_TEST.search(f.read())
 
 
 def split_scope(nodeid):
-    """The unit of work a test belongs to: itself in a file of
-    ``SPLIT_BY_TEST``, its file otherwise."""
+    """The unit of work a test belongs to: itself in a file that
+    ``split_by_test``, its file otherwise."""
     path = nodeid.split("::", 1)[0]
-    return nodeid if path in SPLIT_BY_TEST else path
-
-
-def file_seconds(scope):
-    return FILE_SECONDS.get(scope.split("::", 1)[0], 0)
+    return nodeid if split_by_test(path) else path
 
 
 @pytest.hookimpl(optionalhook=True)     # no such hook under ``-p no:xdist``
@@ -69,21 +109,68 @@ def pytest_xdist_make_scheduler(config, log):
         return None
     from xdist.scheduler.loadfile import LoadFileScheduling
 
-    class LongestFileFirst(LoadFileScheduling):
+    class ContractTestsOneAtATime(LoadFileScheduling):
         def _split_scope(self, nodeid):
             return split_scope(nodeid)
 
-        def _assign_work_unit(self, node):
-            # the first of the longest: ties keep xdist's order
-            self.workqueue.move_to_end(
-                max(self.workqueue, key=file_seconds), last=False)
-            super()._assign_work_unit(node)
+    return ContractTestsOneAtATime(config, log)
 
-    return LongestFileFirst(config, log)
+
+class _ModuleSession:
+    """The 2-executor ETL session that the tests of one file share, started
+    at the first ``shared_session`` of the file and again after a test that
+    took ``no_session``."""
+    running = None
+
+    def start(self):
+        if self.running is None:
+            self.running = _start_session()
+        return self.running
+
+    def stop(self):
+        if self.running is not None:
+            import raydp_tpu
+            self.running = None
+            raydp_tpu.stop()
+
+
+def _start_session():
+    import raydp_tpu
+    return raydp_tpu.init("pytest", num_executors=2, executor_cores=1,
+                          executor_memory="512MB")
+
+
+@pytest.fixture(scope="module")
+def _module_session():
+    shared = _ModuleSession()
+    yield shared
+    shared.stop()
 
 
 @pytest.fixture
-def runtime():
+def shared_session(_module_session):
+    """The file's one session, for a test that only reads through it. What
+    the test persisted is released at its end, so the file's last test sees
+    the store its first saw. A test keeps ``session`` if it kills or
+    restarts an actor, resizes or stops the session, must start its
+    executors under an environment of its own, or asserts on executor-side
+    counters or store contents that another test of the file moves."""
+    s = _module_session.start()
+    yield s
+    for frame_id in s.cached_frames():
+        s.release_cached(frame_id)
+
+
+@pytest.fixture
+def no_session(_module_session):
+    """A process holds one session (or runtime) at a time: the file's shared
+    one is stopped, for a test that starts its own in its body; the next
+    ``shared_session`` starts it again."""
+    _module_session.stop()
+
+
+@pytest.fixture
+def runtime(no_session):
     """A bare actor runtime (no ETL session), torn down after the test."""
     from raydp_tpu.runtime import init_runtime, shutdown_runtime
 
@@ -93,7 +180,7 @@ def runtime():
 
 
 @pytest.fixture
-def runtime_3nodes():
+def runtime_3nodes(no_session):
     """Three virtual nodes for placement/fault tests
     (parity: test_spark_cluster.py:90-110 heterogeneous virtual nodes)."""
     from raydp_tpu.runtime import init_runtime, shutdown_runtime
@@ -108,13 +195,12 @@ def runtime_3nodes():
 
 
 @pytest.fixture
-def session():
-    """A 2-executor ETL session (parity: conftest.py spark_on_ray_2_executors)."""
+def session(no_session):
+    """A 2-executor ETL session of the test's own (parity: conftest.py
+    spark_on_ray_2_executors)."""
     import raydp_tpu
 
-    s = raydp_tpu.init("pytest", num_executors=2, executor_cores=1,
-                       executor_memory="512MB")
-    yield s
+    yield _start_session()
     raydp_tpu.stop()
 
 

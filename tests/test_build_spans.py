@@ -11,6 +11,7 @@ import pytest
 from test_fit_spans import REPO, _by_name, _estimator, _frame
 
 from raydp_tpu import metrics, profiler
+from raydp_tpu.utils import COMPILE_CACHE_ENV
 
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -18,6 +19,32 @@ COMPILE = "/jax/core/compile/backend_compile_duration"
 HIT = "/jax/compilation_cache/cache_hits"
 MISS = "/jax/compilation_cache/cache_misses"
 KINDS = ("jit:trace", "jit:lower", "jit:compile")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """This file tells a compile from a cache's load by what jax reports (a
+    compile's span says ``cache: off``, a fit's ``cache_hit_share`` is 0): the
+    run's compile cache (``conftest.run_compile_cache``) is off while its
+    tests run, here and in every process they start."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    patch = pytest.MonkeyPatch()
+    patch.delenv(COMPILE_CACHE_ENV, raising=False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    patch.undo()
+
+
+def test_this_files_tests_run_without_the_runs_compile_cache():
+    import os
+
+    import jax
+    assert COMPILE_CACHE_ENV not in os.environ
+    assert not jax.config.jax_enable_compilation_cache
 
 
 # ------------------------------------------------------------------ registry

@@ -19,8 +19,8 @@ def _make_df(session, n=1000, parts=4):
         "x", col("id") * 2).withColumn("y", col("id") % 7)
 
 
-def test_from_frame_eager(session):
-    ds = from_frame(_make_df(session))
+def test_from_frame_eager(shared_session):
+    ds = from_frame(_make_df(shared_session))
     assert ds.count() == 1000
     assert ds.num_blocks() == 4
     assert set(ds.schema.names) == {"id", "x", "y"}
@@ -28,8 +28,8 @@ def test_from_frame_eager(session):
     assert table.num_rows == 1000
 
 
-def test_from_frame_recoverable_and_release(session):
-    ds = from_frame_recoverable(_make_df(session))
+def test_from_frame_recoverable_and_release(shared_session):
+    ds = from_frame_recoverable(_make_df(shared_session))
     assert ds.count() == 1000
     assert ds.num_blocks() == 4
     # all blocks fetched through the executor data plane into the store
@@ -37,7 +37,7 @@ def test_from_frame_recoverable_and_release(session):
     assert t0.num_rows > 0
     ds.release()
     assert ds.num_blocks() == 0
-    assert session.cached_frames() == []
+    assert shared_session.cached_frames() == []
 
 
 def test_recoverable_survives_executor_crash(session):
@@ -72,7 +72,7 @@ def test_to_frame_roundtrip(session):
     assert len(session.master.holders()) == 1
 
 
-def test_dataset_ownership_survives_stop():
+def test_dataset_ownership_survives_stop(no_session):
     """parity: stop_spark(cleanup_data=False) keeps converted data alive
     (context.py:152-162, dataset.py:137-158, tests/test_from_spark.py)."""
     session = raydp_tpu.init("own-test", num_executors=2, executor_cores=1,
@@ -89,14 +89,14 @@ def test_dataset_ownership_survives_stop():
         raydp_tpu.stop(cleanup_data=True)
 
 
-def test_random_shuffle_distributed(session, monkeypatch):
+def test_random_shuffle_distributed(shared_session, monkeypatch):
     """random_shuffle runs on the executors: the driver must move only refs
     (VERDICT r3 Weak #3 — the old path pulled every block through the
     driver), the result is a uniform permutation of the same rows, and a
     fixed seed is deterministic (lineage-safe)."""
     from raydp_tpu.runtime.object_store import get_client
 
-    ds = from_frame(_make_df(session))
+    ds = from_frame(_make_df(shared_session))
     client = get_client()
     real_get = client.get
 
@@ -122,16 +122,16 @@ def test_random_shuffle_distributed(session, monkeypatch):
     assert other != again
 
 
-def test_split_shards_balanced(session):
-    ds = from_frame(_make_df(session, n=1003, parts=4))
+def test_split_shards_balanced(shared_session):
+    ds = from_frame(_make_df(shared_session, n=1003, parts=4))
     plans = ds.split_shards(world_size=3)
     sizes = [sum(n for _, _, n in plan) for plan in plans]
     assert len(set(sizes)) == 1  # every rank equal (SPMD requirement)
     assert sizes[0] == -(-1003 // 3)
 
 
-def test_host_batch_iterator(session):
-    ds = from_frame(_make_df(session, n=1000, parts=4))
+def test_host_batch_iterator(shared_session):
+    ds = from_frame(_make_df(shared_session, n=1000, parts=4))
     it = HostBatchIterator(
         ds, batch_size=128,
         columns={"feat": (["x", "y"], np.float32), "label": ("id", np.float32)},
@@ -144,13 +144,13 @@ def test_host_batch_iterator(session):
         assert b["label"].shape == (128,)
 
 
-def test_device_feed_sharded(session):
+def test_device_feed_sharded(shared_session):
     import jax
     from jax.sharding import Mesh
 
     devices = np.array(jax.devices()[:8]).reshape(8)
     mesh = Mesh(devices, ("data",))
-    ds = from_frame(_make_df(session, n=2048, parts=4))
+    ds = from_frame(_make_df(shared_session, n=2048, parts=4))
     feed = DeviceFeed(
         ds, batch_size=256,
         columns={"feat": (["x", "y"], np.float32), "label": ("id", np.float32)},
@@ -165,8 +165,8 @@ def test_device_feed_sharded(session):
     assert n == 2048 // 256
 
 
-def test_shard_spec_feed(session):
-    ds = from_frame(_make_df(session, n=600, parts=3))
+def test_shard_spec_feed(shared_session):
+    ds = from_frame(_make_df(shared_session, n=600, parts=3))
     plans = ds.split_shards(2)
     it = HostBatchIterator(
         ds, batch_size=100, columns={"label": ("id", np.int64)},
@@ -175,12 +175,12 @@ def test_shard_spec_feed(session):
     assert rows == 300
 
 
-def test_split_shards_more_ranks_than_blocks(session):
+def test_split_shards_more_ranks_than_blocks(shared_session):
     """More gang workers than dataset blocks: the shard plan wraps around
     (ranks re-read block prefixes) so every rank still gets the same sample
     count — the reference covers this via its sequential-model test with
     num_workers > partitions (test_torch_sequential.py:23-54)."""
-    df = _make_df(session, n=1000, parts=2)
+    df = _make_df(shared_session, n=1000, parts=2)
     ds = from_frame(df)
     assert ds.num_blocks() == 2
     plans = ds.split_shards(world_size=5)
@@ -194,7 +194,7 @@ def test_split_shards_more_ranks_than_blocks(session):
             assert off + length <= ds.block_sizes()[block_idx]
 
 
-def test_to_torch_dataset_bridge(session):
+def test_to_torch_dataset_bridge(shared_session):
     """The torch bridge (reference TorchMLDataset parity,
     torch_ml_dataset.py:30-67): batched (features, label) CPU tensors over
     the native host feed, len() in batches, shard selection for DDP ranks."""
@@ -202,7 +202,7 @@ def test_to_torch_dataset_bridge(session):
 
     from raydp_tpu.data import to_torch_dataset
 
-    ds = from_frame(_make_df(session, n=500, parts=2))
+    ds = from_frame(_make_df(shared_session, n=500, parts=2))
     tds = to_torch_dataset(ds, feature_columns=["x", "y"], label_column="id",
                            batch_size=100, label_dtype=np.int64)
     assert len(tds) == 5
@@ -246,7 +246,7 @@ def test_to_torch_dataset_bridge(session):
     assert sorted(ids) == list(range(500))
 
 
-def test_to_tf_dataset_bridge(session):
+def test_to_tf_dataset_bridge(shared_session):
     """The tf.data bridge (reference to_tf parity, tf/estimator.py:179-199):
     batched (features, label) tensors, ragged tail declared in the
     signature."""
@@ -254,7 +254,7 @@ def test_to_tf_dataset_bridge(session):
 
     from raydp_tpu.data import to_tf_dataset
 
-    ds = from_frame(_make_df(session, n=250, parts=2))
+    ds = from_frame(_make_df(shared_session, n=250, parts=2))
     tfds = to_tf_dataset(ds, feature_columns=["x", "y"], label_column="id",
                          batch_size=100, label_dtype=np.int64)
     batches = list(tfds)

@@ -36,7 +36,7 @@ def test_dlrm_model_shapes():
     assert variables["params"]["embedding_0"]["embedding"].shape == (40, 8)
 
 
-def test_dlrm_fit_sharded_embeddings(session):
+def test_dlrm_fit_sharded_embeddings(shared_session):
     import optax
 
     from raydp_tpu.models import DLRM, criteo_batch_preprocessor, dlrm_param_rules
@@ -44,7 +44,7 @@ def test_dlrm_fit_sharded_embeddings(session):
     from raydp_tpu.train import FlaxEstimator
 
     mesh = make_mesh(MeshSpec(data=2, expert=4))
-    df = _criteo_like(session)
+    df = _criteo_like(shared_session)
     features = [f"_c{i}" for i in range(1, NUM_DENSE + 1 + len(CAT_SIZES))]
 
     est = FlaxEstimator(
@@ -98,7 +98,7 @@ def test_dlrm_fit_sharded_embeddings(session):
 
 
 @pytest.mark.slow
-def test_predict_synthesizes_nonstandard_label_key(session):
+def test_predict_synthesizes_nonstandard_label_key(shared_session):
     """ADVICE r5 #1: a columns_spec may key its label entry anything (the
     batch_preprocessor consumes arbitrary keys) — predict() must synthesize
     zeros for ANY spec entry whose columns the inference frame lacks, not
@@ -113,7 +113,7 @@ def test_predict_synthesizes_nonstandard_label_key(session):
     rng = np.random.RandomState(0)
     pdf = pd.DataFrame({"x1": rng.rand(n), "x2": rng.rand(n),
                         "target": rng.rand(n)})
-    df = session.createDataFrame(pdf, num_partitions=2)
+    df = shared_session.createDataFrame(pdf, num_partitions=2)
 
     est = FlaxEstimator(
         model=MLP(features=(8,), use_batch_norm=False),

@@ -86,7 +86,7 @@ def _linear_df(session, n=1344):
 
 
 @pytest.mark.slow
-def test_flax_prefetch_to_device_parity(session, monkeypatch):
+def test_flax_prefetch_to_device_parity(shared_session, monkeypatch):
     """prefetch_to_device=2 must be BIT-IDENTICAL to =0 (same seed, same
     shuffle): the async stage only overlaps placement with compute."""
     import optax
@@ -96,7 +96,7 @@ def test_flax_prefetch_to_device_parity(session, monkeypatch):
     from raydp_tpu.train import FlaxEstimator
 
     monkeypatch.setenv("RDT_DEVICE_CACHE", "0")  # pin the streaming feed
-    ds = from_frame(_linear_df(session))
+    ds = from_frame(_linear_df(shared_session))
 
     def run(p2d):
         est = FlaxEstimator(
@@ -122,7 +122,7 @@ def test_flax_prefetch_to_device_parity(session, monkeypatch):
 
 
 @pytest.mark.slow
-def test_keras_prefetch_to_device_parity(session, monkeypatch):
+def test_keras_prefetch_to_device_parity(shared_session, monkeypatch):
     """The keras twin of the parity contract, over the jitted stateless
     loop."""
     import os
@@ -134,7 +134,7 @@ def test_keras_prefetch_to_device_parity(session, monkeypatch):
     from raydp_tpu.train import KerasEstimator
 
     monkeypatch.setenv("RDT_DEVICE_CACHE", "0")
-    ds = from_frame(_linear_df(session, n=448))
+    ds = from_frame(_linear_df(shared_session, n=448))
 
     def run(p2d):
         model = keras.Sequential([
@@ -156,7 +156,7 @@ def test_keras_prefetch_to_device_parity(session, monkeypatch):
 
 
 @pytest.mark.slow
-def test_timing_split_surfaced_in_reports(session, monkeypatch):
+def test_timing_split_surfaced_in_reports(shared_session, monkeypatch):
     """Streaming epochs report a positive decode/h2d split; the
     device-resident path reports zeros (nothing streamed)."""
     import optax
@@ -165,7 +165,7 @@ def test_timing_split_surfaced_in_reports(session, monkeypatch):
     from raydp_tpu.models import MLP
     from raydp_tpu.train import FlaxEstimator
 
-    ds = from_frame(_linear_df(session))
+    ds = from_frame(_linear_df(shared_session))
 
     def run():
         est = FlaxEstimator(
@@ -188,13 +188,13 @@ def test_timing_split_surfaced_in_reports(session, monkeypatch):
         assert r["h2d_time_s"] == 0.0
 
 
-def test_device_feed_prefetch_knob_env_default(session, monkeypatch):
+def test_device_feed_prefetch_knob_env_default(shared_session, monkeypatch):
     """prefetch_to_device falls back to RDT_PREFETCH_TO_DEVICE (default 2);
     an explicit argument wins."""
     from raydp_tpu.data import from_frame
     from raydp_tpu.data.feed import DeviceFeed
 
-    ds = from_frame(_linear_df(session, n=256))
+    ds = from_frame(_linear_df(shared_session, n=256))
     cols = {"features": (["x1", "x2"], np.float32),
             "label": ("y", np.float32)}
     assert DeviceFeed(ds, 64, cols).prefetch_to_device == 2

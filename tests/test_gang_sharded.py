@@ -96,13 +96,13 @@ def test_gang_iterator_explicit_row_range():
         np.testing.assert_array_equal(batches[0]["x"], rows[:16])
 
 
-def test_gang_fsdp_params_sharded_across_processes(session, tmp_path):
+def test_gang_fsdp_params_sharded_across_processes(shared_session, tmp_path):
     """fsdp=16 over 2 processes × 8 devices: every weight matrix is sharded
     across the process boundary; losses must still match the single-process
     run (SPMD sharding changes nothing about the math)."""
     from raydp_tpu.data.dataset import from_frame
 
-    df = _linear_df(session)
+    df = _linear_df(shared_session)
     ds = from_frame(df)
 
     single = _mlp_estimator(ckpt_dir=str(tmp_path / "single"))
@@ -122,13 +122,13 @@ def test_gang_fsdp_params_sharded_across_processes(session, tmp_path):
     np.testing.assert_allclose(k2, k1, rtol=1e-3, atol=1e-4)
 
 
-def test_gang_sharded_checkpoint_resume(session, tmp_path):
+def test_gang_sharded_checkpoint_resume(shared_session, tmp_path):
     """A second gang over the same checkpoint dir resumes from the sharded
     multi-writer checkpoint instead of retraining."""
     from raydp_tpu.data.dataset import from_frame
     import raydp_tpu.train.checkpoint as ckpt
 
-    df = _linear_df(session, n=1024)
+    df = _linear_df(shared_session, n=1024)
     ds = from_frame(df)
     ckpt_dir = str(tmp_path / "ck")
 
@@ -154,7 +154,7 @@ def test_gang_sharded_checkpoint_resume(session, tmp_path):
     assert ckpt.restore_extra(ckpt_dir)["history"]
 
 
-def test_gang_expert_sharded_dlrm(session, tmp_path):
+def test_gang_expert_sharded_dlrm(shared_session, tmp_path):
     """expert=16 (data axis size 1) over 2 processes: embedding tables sharded
     across the process boundary, batch REPLICATED on every process — the
     row-range derivation must feed the full global batch from each rank."""
@@ -171,7 +171,7 @@ def test_gang_expert_sharded_dlrm(session, tmp_path):
         data[f"d{i}"] = rng.random_sample(n)
     for j, vocab in enumerate(CAT_SIZES):
         data[f"c{j}"] = rng.randint(0, vocab, n)
-    df = session.createDataFrame(pd.DataFrame(data), num_partitions=4)
+    df = shared_session.createDataFrame(pd.DataFrame(data), num_partitions=4)
     ds = from_frame(df)
     features = [f"d{i}" for i in range(NUM_DENSE)] + \
         [f"c{j}" for j in range(len(CAT_SIZES))]
@@ -297,13 +297,13 @@ def test_a_leaf_no_rule_names_is_placed_by_its_role():
     assert sh["step"].spec == P()
 
 
-def test_mesh_equivalence_matrix(session):
+def test_mesh_equivalence_matrix(shared_session):
     """dp / fsdp / fsdp×tp from mesh_spec alone (no param_rules): per-epoch
     losses match the single-device run — sharding changes the layout, not
     the math. Also the dict-valued mesh_spec path."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session))
+    ds = from_frame(_linear_df(shared_session))
     base = _mlp_estimator(mesh=_single_device_mesh())
     losses0 = [h["train_loss"] for h in base.fit(ds).history]
 
@@ -322,7 +322,7 @@ def test_mesh_equivalence_matrix(session):
     assert k.sharding.spec == P("fsdp", "tensor")
 
 
-def test_train_ragged_tail_pad_parity(session):
+def test_train_ragged_tail_pad_parity(shared_session):
     """drop_last=False with a 28-row tail (1500 = 23×64 + 28): under an
     8-way data extent the tail pads-and-masks to a full batch — same step
     count and same per-epoch losses as the single-device run that consumes
@@ -330,7 +330,7 @@ def test_train_ragged_tail_pad_parity(session):
     place the tail (28 rows do not divide over 8 devices)."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session, n=1500))
+    ds = from_frame(_linear_df(shared_session, n=1500))
 
     base = _mlp_estimator(mesh=_single_device_mesh(), drop_last=False)
     r0 = base.fit(ds)
@@ -344,7 +344,7 @@ def test_train_ragged_tail_pad_parity(session):
         [h["train_loss"] for h in r0.history], rtol=5e-4)
 
 
-def test_eval_ragged_tail_pad_parity(session, monkeypatch):
+def test_eval_ragged_tail_pad_parity(shared_session, monkeypatch):
     """The eval tail (300 = 4×64 + 44) is padded-and-masked instead of
     dropped under a >1 data extent, on BOTH eval paths: the device-resident
     scan (tail padded in-jit) and the streaming feed (tail padded on the
@@ -352,8 +352,8 @@ def test_eval_ragged_tail_pad_parity(session, monkeypatch):
     mask keeps padded rows out of the loss AND the row count."""
     from raydp_tpu.data.dataset import from_frame
 
-    train = from_frame(_linear_df(session, n=1024))
-    ev = from_frame(_linear_df(session, n=300, parts=2))
+    train = from_frame(_linear_df(shared_session, n=1024))
+    ev = from_frame(_linear_df(shared_session, n=300, parts=2))
 
     base = _mlp_estimator(mesh=_single_device_mesh(), metrics=["mae"])
     e0 = base.fit(train, ev).history[-1]
@@ -372,13 +372,13 @@ def test_eval_ragged_tail_pad_parity(session, monkeypatch):
     np.testing.assert_allclose(e2["eval_mae"], e0["eval_mae"], rtol=5e-4)
 
 
-def test_pad_tail_knob_restores_drop(session, monkeypatch):
+def test_pad_tail_knob_restores_drop(shared_session, monkeypatch):
     """RDT_TRAIN_PAD_TAIL=0 is the escape hatch back to the pre-PR-16 drop:
     a 40-row online epoch under fsdp=8 (batch 64) then yields no step at
     all, where padding turns it into one masked step."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session, n=40, parts=2))
+    ds = from_frame(_linear_df(shared_session, n=40, parts=2))
 
     est = _mlp_estimator(mesh_spec=MeshSpec(fsdp=8))
     r1 = est._partial_fit_epoch(ds, 0)
@@ -391,7 +391,7 @@ def test_pad_tail_knob_restores_drop(session, monkeypatch):
     assert r2["steps"] == 0
 
 
-def test_checkpoint_roundtrip_across_mesh_shapes(session, tmp_path):
+def test_checkpoint_roundtrip_across_mesh_shapes(shared_session, tmp_path):
     """Train under fsdp=2, restore the checkpoint into a dp-only mesh:
     restore_placed reassembles full values under the NEW shardings — a
     topology change between save and restore is routine (autoscale)."""
@@ -401,7 +401,7 @@ def test_checkpoint_roundtrip_across_mesh_shapes(session, tmp_path):
     from raydp_tpu.parallel import make_mesh, param_sharding_rules
     from raydp_tpu.train import checkpoint as ckpt
 
-    ds = from_frame(_linear_df(session, n=1024))
+    ds = from_frame(_linear_df(shared_session, n=1024))
     ckpt_dir = str(tmp_path / "ck")
     est = _mlp_estimator(mesh_spec=dict(fsdp=2), num_epochs=2,
                          ckpt_dir=ckpt_dir)
@@ -422,7 +422,8 @@ def test_checkpoint_roundtrip_across_mesh_shapes(session, tmp_path):
     assert k.sharding.spec == P()
 
 
-def test_sharded_export_serve_bitwise_matches_predict(session, tmp_path):
+def test_sharded_export_serve_bitwise_matches_predict(shared_session,
+                                                      tmp_path):
     """export_serving off an fsdp×tp-trained state → load_servable →
     predict_table is bit-identical to the estimator's own predict: the
     export gathered exactly the trained weights."""
@@ -435,7 +436,7 @@ def test_sharded_export_serve_bitwise_matches_predict(session, tmp_path):
     x = rng.random_sample((512, 2))
     y = x @ np.array([2.0, -3.0]) + 1.0
     pdf = pd.DataFrame({"x1": x[:, 0], "x2": x[:, 1], "y": y})
-    df = session.createDataFrame(pdf, num_partitions=2)
+    df = shared_session.createDataFrame(pdf, num_partitions=2)
     ds = from_frame(df)
 
     est = _mlp_estimator(mesh_spec=dict(fsdp=4, tensor=2), num_epochs=2)
@@ -454,13 +455,13 @@ def test_sharded_export_serve_bitwise_matches_predict(session, tmp_path):
 # parity contract against the unaccumulated / unsharded run.
 
 
-def test_accum_parity_across_meshes(session):
+def test_accum_parity_across_meshes(shared_session):
     """accum=4 reproduces the accum=1 per-epoch loss trajectory on dp,
     fsdp and fsdp×tp meshes: row-weighted microbatch accumulation is the
     same math as the full-batch step, whatever the param layout."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session))
+    ds = from_frame(_linear_df(shared_session))
     losses0 = [h["train_loss"]
                for h in _mlp_estimator(mesh_spec=MeshSpec()).fit(ds).history]
 
@@ -479,7 +480,7 @@ def test_accum_parity_across_meshes(session):
     assert snap["train_activation_bytes_per_process"][""] > 0
 
 
-def test_accum_knob_matches_constructor(session, monkeypatch):
+def test_accum_knob_matches_constructor(shared_session, monkeypatch):
     """RDT_TRAIN_ACCUM_STEPS=4 builds the identical step program as
     accum_steps=4 — same losses bitwise — and an accum that does not
     divide the batch fails loudly, not by silently truncating rows."""
@@ -487,7 +488,7 @@ def test_accum_knob_matches_constructor(session, monkeypatch):
 
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session, n=1024))
+    ds = from_frame(_linear_df(shared_session, n=1024))
     r1 = _mlp_estimator(mesh_spec=MeshSpec(), accum_steps=4).fit(ds)
     monkeypatch.setenv("RDT_TRAIN_ACCUM_STEPS", "4")
     r2 = _mlp_estimator(mesh_spec=MeshSpec()).fit(ds)
@@ -500,14 +501,14 @@ def test_accum_knob_matches_constructor(session, monkeypatch):
         _mlp_estimator(mesh_spec=MeshSpec(), accum_steps=5).fit(ds)
 
 
-def test_remat_modes_identical_losses(session):
+def test_remat_modes_identical_losses(shared_session):
     """jax.checkpoint placement (none/dots/full) recomputes, never
     approximates: loss trajectories agree to float-summation noise (the
     recompute can re-associate reductions, nothing more) across remat
     modes, with accumulation and an fsdp mesh engaged."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session, n=1024))
+    ds = from_frame(_linear_df(shared_session, n=1024))
     ref = _mlp_estimator(mesh_spec=MeshSpec(fsdp=8), accum_steps=4,
                          remat="none").fit(ds)
     for mode in ("dots", "full"):
@@ -519,13 +520,13 @@ def test_remat_modes_identical_losses(session):
             err_msg=f"remat={mode} changed the math")
 
 
-def test_seq_sharded_parity(session):
+def test_seq_sharded_parity(shared_session):
     """data=4 × seq=2: feature dims shard over the seq axis on top of the
     batch dim — a pure layout change, so per-epoch losses match the
     seq-less dp run and per-row predictions agree tightly."""
     from raydp_tpu.data.dataset import from_frame
 
-    df = _linear_df(session)
+    df = _linear_df(shared_session)
     ds = from_frame(df)
     base = _mlp_estimator(mesh_spec=MeshSpec())
     r0 = base.fit(ds)
@@ -541,12 +542,12 @@ def test_seq_sharded_parity(session):
                                rtol=1e-4, atol=1e-6)
 
 
-def test_seq_sharded_with_accum_and_remat(session):
+def test_seq_sharded_with_accum_and_remat(shared_session):
     """The full activation plane at once — accum=4 × remat=full ×
     data=4/seq=2 — still lands the plain single-mesh trajectory."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session))
+    ds = from_frame(_linear_df(shared_session))
     losses0 = [h["train_loss"]
                for h in _mlp_estimator(mesh_spec=MeshSpec()).fit(ds).history]
     r = _mlp_estimator(mesh_spec=dict(data=4, seq=2), accum_steps=4,
@@ -555,13 +556,13 @@ def test_seq_sharded_with_accum_and_remat(session):
         [h["train_loss"] for h in r.history], losses0, rtol=5e-4)
 
 
-def test_accum_ragged_tail_partial_fit(session):
+def test_accum_ragged_tail_partial_fit(shared_session):
     """40 rows, batch 64, accum=4 under fsdp=8: the padded tail splits
     into microbatches where the LAST is all padding — its rows-weight is
     zero, so the masked online step still matches the unaccumulated one."""
     from raydp_tpu.data.dataset import from_frame
 
-    ds = from_frame(_linear_df(session, n=40, parts=2))
+    ds = from_frame(_linear_df(shared_session, n=40, parts=2))
 
     plain = _mlp_estimator(mesh_spec=MeshSpec(fsdp=8))._partial_fit_epoch(
         ds, 0)
@@ -572,7 +573,7 @@ def test_accum_ragged_tail_partial_fit(session):
                                rtol=5e-4)
 
 
-def test_accum_checkpoint_roundtrip(session, tmp_path):
+def test_accum_checkpoint_roundtrip(shared_session, tmp_path):
     """Accumulation holds no state across optimizer steps: a checkpoint
     written by an accum=4 fit restores bit-identically to the live state,
     and a longer accum=4 run resumes from it epoch-for-epoch."""
@@ -582,7 +583,7 @@ def test_accum_checkpoint_roundtrip(session, tmp_path):
     from raydp_tpu.parallel import param_sharding_rules
     from raydp_tpu.train import checkpoint as ckpt
 
-    ds = from_frame(_linear_df(session, n=1024))
+    ds = from_frame(_linear_df(shared_session, n=1024))
     ckpt_dir = str(tmp_path / "ck")
     est = _mlp_estimator(mesh_spec=MeshSpec(fsdp=8), num_epochs=2,
                          ckpt_dir=ckpt_dir, accum_steps=4)

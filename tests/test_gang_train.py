@@ -44,10 +44,10 @@ def _estimator(num_epochs=3, callbacks=None, ckpt_dir=None):
     )
 
 
-def test_gang_losses_match_single_process(session, tmp_path):
+def test_gang_losses_match_single_process(shared_session, tmp_path):
     from raydp_tpu.data.dataset import from_frame
 
-    df = _linear_df(session)
+    df = _linear_df(shared_session)
     train_df, test_df = df.randomSplit([0.75, 0.25], seed=1)
     train_ds, test_ds = from_frame(train_df), from_frame(test_df)
 
@@ -70,7 +70,7 @@ def test_gang_losses_match_single_process(session, tmp_path):
     np.testing.assert_allclose(k2, k1, rtol=1e-4, atol=1e-5)
 
 
-def test_gang_rank_failure_restarts_from_checkpoint(session, tmp_path):
+def test_gang_rank_failure_restarts_from_checkpoint(shared_session, tmp_path):
     from raydp_tpu.data.dataset import from_frame
 
     flag = str(tmp_path / "crashed-once")
@@ -84,7 +84,7 @@ def test_gang_rank_failure_restarts_from_checkpoint(session, tmp_path):
             open(flag, "w").close()
             os._exit(1)
 
-    df = _linear_df(session, n=1024)
+    df = _linear_df(shared_session, n=1024)
     ds = from_frame(df)
     est = _estimator(num_epochs=4, callbacks=[crash_once],
                      ckpt_dir=str(tmp_path / "ck"))

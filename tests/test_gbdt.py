@@ -53,13 +53,13 @@ def test_unsupported_objective():
                  objective="rank:pairwise")
 
 
-def test_estimator_fit_on_frame(session):
+def test_estimator_fit_on_frame(shared_session):
     from raydp_tpu.train import GBDTEstimator
 
     rng = np.random.RandomState(3)
     x = rng.rand(600, 3).astype(np.float32)
     y = (x[:, 0] * 4 + x[:, 1] + 0.01 * rng.randn(600)).astype(np.float32)
-    df = session.createDataFrame(
+    df = shared_session.createDataFrame(
         pd.DataFrame({"f0": x[:, 0], "f1": x[:, 1], "f2": x[:, 2], "y": y}),
         num_partitions=2)
     train_df, eval_df = df.randomSplit([0.8, 0.2], seed=0)
@@ -167,7 +167,7 @@ def test_instance_weights_shift_the_fit():
                                rtol=1e-4, atol=1e-5)
 
 
-def test_estimator_multiclass_early_stop(session):
+def test_estimator_multiclass_early_stop(shared_session):
     from raydp_tpu.train import GBDTEstimator
 
     rng = np.random.RandomState(11)
@@ -176,7 +176,7 @@ def test_estimator_multiclass_early_stop(session):
     label = (X[:, 0] * 3).astype(np.int64).clip(0, 2)
     pdf = pd.DataFrame({f"f{i}": X[:, i] for i in range(4)})
     pdf["y"] = label.astype(np.float64)
-    df = session.createDataFrame(pdf, num_partitions=3)
+    df = shared_session.createDataFrame(pdf, num_partitions=3)
     train_df, eval_df = df.randomSplit([0.8, 0.2], seed=0)
 
     est = GBDTEstimator(

@@ -40,8 +40,8 @@ def _estimator(**kw):
     return KerasEstimator(**defaults)
 
 
-def test_fit_on_frame_object_store(session):
-    df = _make_frame(session)
+def test_fit_on_frame_object_store(shared_session):
+    df = _make_frame(shared_session)
     train_df, eval_df = df.randomSplit([0.8, 0.2], seed=1)
     est = _estimator()
     result = est.fit_on_frame(train_df, eval_df)
@@ -53,17 +53,17 @@ def test_fit_on_frame_object_store(session):
     assert preds.shape == (1, 1)
 
 
-def test_fit_on_frame_parquet_spill(session, tmp_path):
-    df = _make_frame(session)
+def test_fit_on_frame_parquet_spill(shared_session, tmp_path):
+    df = _make_frame(shared_session)
     est = _estimator(num_epochs=2)
     result = est.fit_on_frame(df, fs_directory=str(tmp_path))
     assert len(result.history) == 2
 
 
-def test_model_builder_and_spec_roundtrip(session):
+def test_model_builder_and_spec_roundtrip(shared_session):
     """The estimator stores a serialized spec, so the original model object is
     never mutated (parity: tf/estimator.py:96-149)."""
-    df = _make_frame(session, n=256)
+    df = _make_frame(shared_session, n=256)
     est = _estimator(model=None, model_builder=_model, num_epochs=2)
     result = est.fit_on_frame(df)
     assert result.history
@@ -72,12 +72,12 @@ def test_model_builder_and_spec_roundtrip(session):
     assert result2.history
 
 
-def test_data_parallel_over_virtual_mesh(session):
+def test_data_parallel_over_virtual_mesh(shared_session):
     """batch 64 over the 8 virtual CPU devices; DataParallel shards it 8×."""
     import jax
 
     assert len(jax.devices()) == 8
-    df = _make_frame(session)
+    df = _make_frame(shared_session)
     est = _estimator(num_epochs=4, data_parallel=True)
     result = est.fit_on_frame(df)
     # the model must actually learn, not merely not diverge
@@ -94,13 +94,13 @@ def test_requires_model():
         KerasEstimator(feature_columns=["a"], label_column="y")
 
 
-def test_keras_fit_gang_matches_single_process(session, tmp_path):
+def test_keras_fit_gang_matches_single_process(shared_session, tmp_path):
     """The gang path is a real peer of the Flax gang: 2 ranks under one
     global jax.distributed mesh must reproduce the single-process losses
     (same seed, same global batches) and leave a chief model.keras."""
     from raydp_tpu.data.dataset import from_frame
 
-    df = _make_frame(session, n=1024)
+    df = _make_frame(shared_session, n=1024)
     train_df, eval_df = df.randomSplit([0.8, 0.2], seed=1)
     train_ds, eval_ds = from_frame(train_df), from_frame(eval_df)
 
@@ -124,14 +124,14 @@ def test_keras_fit_gang_matches_single_process(session, tmp_path):
     assert preds.shape == (1, 1)
 
 
-def test_keras_device_cache_parity(session, monkeypatch):
+def test_keras_device_cache_parity(shared_session, monkeypatch):
     """The device-resident epoch path must walk exactly the streaming feed's
     update sequence at shuffle=False (mirrors the FlaxEstimator resident
     parity test, on the keras stateless loop)."""
     from raydp_tpu.data import from_frame
 
-    df = _make_frame(session, n=448)
-    eval_ds = from_frame(_make_frame(session, n=200, seed=1))
+    df = _make_frame(shared_session, n=448)
+    eval_ds = from_frame(_make_frame(shared_session, n=200, seed=1))
     monkeypatch.setenv("RDT_DEVICE_CACHE", "1")
     monkeypatch.delenv("RDT_DEVICE_CACHE_MB", raising=False)
 
@@ -154,7 +154,8 @@ def test_keras_device_cache_parity(session, monkeypatch):
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_fit_kwargs_path_interval_checkpoint(session, tmp_path, monkeypatch):
+def test_fit_kwargs_path_interval_checkpoint(shared_session, tmp_path,
+                                             monkeypatch):
     """Custom fit_kwargs route through stock model.fit; the
     checkpoint_interval knob must hold there too (reference parity path,
     tf/estimator.py:171-210). A save spy pins the cadence — existence of the
@@ -172,7 +173,7 @@ def test_fit_kwargs_path_interval_checkpoint(session, tmp_path, monkeypatch):
 
     monkeypatch.setattr(keras.Model, "save", spy)
 
-    df = _make_frame(session, n=256)
+    df = _make_frame(shared_session, n=256)
     ck = tmp_path / "ck"
     est = _estimator(num_epochs=3, fit_kwargs={"class_weight": None},
                      checkpoint_dir=str(ck), checkpoint_interval=5)
@@ -184,7 +185,7 @@ def test_fit_kwargs_path_interval_checkpoint(session, tmp_path, monkeypatch):
 
 
 @pytest.mark.slow
-def test_keras_predict_matches_manual_apply(session):
+def test_keras_predict_matches_manual_apply(shared_session):
     """predict() covers the full row count (ragged tail included) and agrees
     numerically with a manual get_model() + stateless_call apply — the flax
     twin's evidence standard (tests/test_train.py::test_estimator_predict)
@@ -193,7 +194,8 @@ def test_keras_predict_matches_manual_apply(session):
 
     from raydp_tpu.data import from_frame
 
-    df = _make_frame(session, n=300)  # 300 % 64 != 0: exercises the tail
+    # 300 % 64 != 0: exercises the tail
+    df = _make_frame(shared_session, n=300)
     ds = from_frame(df)
     est = _estimator(num_epochs=2)
     est.fit(ds)
@@ -220,13 +222,13 @@ def test_keras_predict_matches_manual_apply(session):
 
 
 @pytest.mark.slow
-def test_keras_predict_labelless_frame(session):
+def test_keras_predict_labelless_frame(shared_session):
     """The normal inference frame has NO label column: predict() only
     decodes feature columns, so it must work unchanged and return the same
     predictions as on the labeled frame."""
     from raydp_tpu.data import from_frame
 
-    df = _make_frame(session, n=256)
+    df = _make_frame(shared_session, n=256)
     est = _estimator(num_epochs=2)
     est.fit(from_frame(df))
 
@@ -240,7 +242,7 @@ def test_keras_predict_labelless_frame(session):
         fresh.predict(from_frame(df))
 
 
-def test_keras_batchnorm_resident(session):
+def test_keras_batchnorm_resident(shared_session):
     """BatchNorm (non-trainable running stats) threads through the resident
     epoch scan's carry — the bench's NYCTaxi-shaped keras model depends on
     it."""
@@ -254,7 +256,7 @@ def test_keras_batchnorm_resident(session):
             keras.layers.Dense(1),
         ])
 
-    df = _make_frame(session, n=448)
+    df = _make_frame(shared_session, n=448)
     est = _estimator(model=None, model_builder=build, num_epochs=3)
     result = est.fit_on_frame(df)
     assert all(r["feed_time_s"] == 0.0 for r in result.history)

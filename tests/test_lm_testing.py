@@ -1,9 +1,8 @@
 """The LM family tests' shared helpers (``tests/lm_testing.py``) and the
-scheduler's list of files that go to the workers a test at a time
-(``tests/conftest.py``)."""
+harness of a run (``tests/conftest.py``): its one compile cache, the session
+a file's tests share, and which files go to the workers a test at a time."""
 
 import os
-import re
 import types
 
 import numpy as np
@@ -88,21 +87,88 @@ def test_a_references_program_is_built_once_whatever_the_programs_options():
         "olmoe-1b-7b", dict(cfg, layers=1), "loss", grad=True) is not a
 
 
-@pytest.mark.parametrize("path", sorted(conftest.SPLIT_BY_TEST))
-def test_a_file_split_by_test_exists_and_shares_no_fixture(path):
-    """What lets a file's tests run in any process: no fixture wider than a
-    test (and no ``setup_module`` / ``setup_class``)."""
-    with open(os.path.join(ROOT, path)) as f:
-        source = f.read()
-    assert not re.search(r"scope\s*=\s*[\"'](module|class|package|session)",
-                         source)
-    assert not re.search(r"def (setup|teardown)_(module|class)\b", source)
-    assert path in conftest.FILE_SECONDS
+def test_a_run_has_one_compile_cache_and_a_worker_makes_none(
+        tmp_path, monkeypatch):
+    """The controller (or the lone process) makes the run's directory, new
+    and empty, under the run's temporary directory and never the checkout's
+    ``.jax_cache``, whatever the environment named before; the workers it
+    starts inherit the name and make nothing; it is gone at the end."""
+    from raydp_tpu.utils import COMPILE_CACHE_ENV
+    assert conftest.COMPILE_CACHE_ENV == COMPILE_CACHE_ENV
+    monkeypatch.setattr(conftest.tempfile, "tempdir", str(tmp_path))
+    controller = {COMPILE_CACHE_ENV: os.path.join(ROOT, ".jax_cache")}
+    made = conftest.run_compile_cache(controller)
+    assert controller[COMPILE_CACHE_ENV] == made
+    assert os.path.dirname(made) == str(tmp_path) and os.listdir(made) == []
+    workers = [dict(controller, PYTEST_XDIST_WORKER=f"gw{i}")
+               for i in range(2)]
+    for worker in workers:
+        before = dict(worker)
+        assert conftest.run_compile_cache(worker) is None
+        assert worker == before and worker[COMPILE_CACHE_ENV] == made
+    assert os.listdir(tmp_path) == [os.path.basename(made)]
+    # a second run of the same checkout: another directory, empty
+    again = conftest.run_compile_cache({})
+    assert again != made and os.listdir(again) == []
+    monkeypatch.setattr(conftest, "_cache_made", made)
+    conftest.pytest_unconfigure(None)
+    assert not os.path.exists(made)
+    # this very run: not the checkout's, and the one its workers share
+    here = os.environ[COMPILE_CACHE_ENV]
+    assert os.path.isdir(here) and ".jax_cache" not in here
 
 
-def test_every_file_the_scheduler_lists_exists():
-    assert all(os.path.exists(os.path.join(ROOT, path))
-               for path in conftest.FILE_SECONDS)
+class _Session:
+    """What ``shared_session`` asks of a session."""
+
+    def __init__(self):
+        self.frames = []
+
+    def cached_frames(self):
+        return list(self.frames)
+
+    def release_cached(self, frame_id):
+        self.frames.remove(frame_id)
+
+
+def test_a_files_tests_share_one_session_and_leave_it_as_they_found_it(
+        monkeypatch):
+    """One session for two tests of a module, what the first persisted gone
+    for the second; a test that needs the process's one session for itself
+    stops it and the next test starts another; the module's end stops it, and
+    the next module starts its own."""
+    import raydp_tpu
+    started, stopped = [], []
+    monkeypatch.setattr(conftest, "_start_session", lambda: (
+        started.append(_Session()), started[-1])[1])
+    monkeypatch.setattr(raydp_tpu, "stop", lambda: stopped.append(1))
+    shared_session = conftest.shared_session.__wrapped__
+    module = conftest._module_session.__wrapped__()
+    holder = module.send(None)
+    assert started == []                    # nothing before the first asks
+
+    first = shared_session(holder)
+    session = first.send(None)
+    session.frames += ["f1", "f2"]          # what a test persists
+    with pytest.raises(StopIteration):
+        first.send(None)
+    second = shared_session(holder)
+    assert second.send(None) is session and session.cached_frames() == []
+    with pytest.raises(StopIteration):
+        second.send(None)
+    assert len(started) == 1 and stopped == []
+
+    conftest.no_session.__wrapped__(holder)     # a test with its own
+    assert stopped == [1]
+    conftest.no_session.__wrapped__(holder)     # and one more: nothing to stop
+    assert stopped == [1]
+    third = shared_session(holder)
+    assert third.send(None) is started[1] is not session
+    with pytest.raises(StopIteration):
+        module.send(None)
+    assert stopped == [1, 1]
+    other = conftest._module_session.__wrapped__()
+    assert shared_session(other.send(None)).send(None) is started[2]
 
 
 class _Node:
@@ -116,30 +182,38 @@ class _Node:
         self.sent.append(list(indices))
 
 
-def test_the_scheduler_splits_listed_files_and_hands_out_the_longest_first():
-    """A listed file's tests are a unit of work each and any other file is
-    one; the units leave by their files' measured seconds, the unlisted
-    after them in xdist's own order (by their count of tests); under another
-    ``--dist`` the hook leaves the choice to xdist."""
-    split = sorted(conftest.SPLIT_BY_TEST)[0]
-    longest = max(conftest.FILE_SECONDS, key=conftest.FILE_SECONDS.get)
-    assert longest in conftest.SPLIT_BY_TEST
-    whole = "tests/test_swa_moe_lm.py"
-    assert whole in conftest.FILE_SECONDS
-    assert len({conftest.split_scope(f"{split}::test_a[{i}]")
-                for i in range(3)}) == 3
-    assert conftest.split_scope(
-        "tests/test_etl.py::TestFrame::test_b") == "tests/test_etl.py"
+SPLIT = "tests/chipbench_contract/test_chipbench_run.py"
+SHARED = "tests/chipbench_contract/test_chipbench_pieces.py"
 
+
+@pytest.mark.parametrize("path,singly", [
+    (SPLIT, True), (SHARED, False),                 # a module-scoped fixture
+    ("tests/test_swa_moe_lm.py", False)])           # programs kept a worker
+def test_a_contract_file_without_a_shared_fixture_leaves_a_test_at_a_time(
+        path, singly):
+    assert conftest.split_by_test(path) is singly
+    units = {conftest.split_scope(f"{path}::test_a[{i}]") for i in range(3)}
+    assert len(units) == (3 if singly else 1)
+    assert conftest.split_scope(f"{path}::TestX::test_b") == (
+        f"{path}::TestX::test_b" if singly else path)
+
+
+def test_the_scheduler_sends_whole_files_by_count_then_contract_tests_singly():
+    """No table of seconds: the units leave in xdist's own order, most tests
+    first, so the single tests of the contract files follow the whole files
+    as they were collected; under another ``--dist`` the hook leaves the
+    choice to xdist."""
+    assert not hasattr(conftest, "FILE_SECONDS")
     option = {"dist": "loadfile", "tx": ["2*popen"]}
     config = types.SimpleNamespace(
         getvalue=option.get, option=types.SimpleNamespace(
             loadscopereorder=True))
     scheduler = conftest.pytest_xdist_make_scheduler(config, None)
-    collection = ["tests/test_serve.py::test_a", "tests/test_serve.py::test_b",
-                  "tests/test_etl.py::test_a", f"{whole}::test_a",
-                  f"{whole}::test_b", f"{longest}::test_a[x]",
-                  f"{longest}::test_a[y]"]
+    collection = [f"{SPLIT}::test_a[x]", f"{SPLIT}::test_a[y]",
+                  f"{SHARED}::test_a", f"{SHARED}::test_b",
+                  "tests/test_etl.py::test_a",
+                  "tests/test_serve.py::test_a", "tests/test_serve.py::test_b",
+                  "tests/test_serve.py::test_c"]
     nodes = [_Node("gw0"), _Node("gw1")]
     for node in nodes:
         scheduler.add_node(node)
@@ -147,12 +221,12 @@ def test_the_scheduler_splits_listed_files_and_hands_out_the_longest_first():
     scheduler.schedule()
     sent = [[collection[i] for i in batch] for node in nodes
             for batch in node.sent]
-    # a node starts with two units where it can: the longest file's two
-    # tests, then the next longest file whole, then the unlisted by count
-    assert sent == [[f"{longest}::test_a[x]"], [f"{whole}::test_a",
-                                               f"{whole}::test_b"],
-                    [f"{longest}::test_a[y]"],
-                    ["tests/test_serve.py::test_a",
-                     "tests/test_serve.py::test_b"]]
+    # the files by their count of tests, then the units of one test as they
+    # were collected (a node is sent more once it holds two tests or fewer)
+    assert sent == [["tests/test_serve.py::test_a",
+                     "tests/test_serve.py::test_b",
+                     "tests/test_serve.py::test_c"],
+                    [f"{SHARED}::test_a", f"{SHARED}::test_b"],
+                    [f"{SPLIT}::test_a[x]"]]
     assert conftest.pytest_xdist_make_scheduler(types.SimpleNamespace(
         getvalue={"dist": "load"}.get), None) is None

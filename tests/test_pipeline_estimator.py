@@ -68,12 +68,12 @@ def _gauge(name):
     return metrics.snapshot()["gauges"].get(name, {}).get("")
 
 
-def test_stage2_matches_stage1_losses_and_params(session):
+def test_stage2_matches_stage1_losses_and_params(shared_session):
     """The tentpole equivalence: a 2-stage pipelined fit (4 microbatches
     marching through the GPipe scan) reproduces the unstaged per-epoch
     losses AND the final parameters — the stage axis changes where layers
     live, never what they compute."""
-    ds = _linear_ds(session)
+    ds = _linear_ds(shared_session)
     r1 = _est(mesh=make_mesh(dict(stage=1, data=8)), accum_steps=4).fit(ds)
     r2 = _est(mesh=make_mesh(dict(stage=2, data=4)), accum_steps=4).fit(ds)
     np.testing.assert_allclose(_losses(r2), _losses(r1), rtol=5e-4)
@@ -87,13 +87,13 @@ def test_stage2_matches_stage1_losses_and_params(session):
                                    atol=1e-5)
 
 
-def test_unified_microbatching_accum_is_pipeline_microbatch(session):
+def test_unified_microbatching_accum_is_pipeline_microbatch(shared_session):
     """accum_steps IS the pipeline microbatch count: different accum
     values at stage=2 land the same losses (row-weighted masked stats keep
     microbatch size out of the math), and the estimator reports the staged
     geometry through the train_pipeline_stages / train_accum_steps
     gauges."""
-    ds = _linear_ds(session)
+    ds = _linear_ds(shared_session)
     base = _losses(_est(mesh=make_mesh(dict(stage=1, data=8))).fit(ds))
     for accum in (2, 4):
         r = _est(mesh=make_mesh(dict(stage=2, data=4)),
@@ -104,11 +104,11 @@ def test_unified_microbatching_accum_is_pipeline_microbatch(session):
         assert _gauge("train_accum_steps") == accum
 
 
-def test_per_role_remat_policy_trains_to_same_loss(session):
+def test_per_role_remat_policy_trains_to_same_loss(shared_session):
     """A role→mode remat policy is a schedule hint, not a math change:
     checkpointing kernels at ``dots`` and everything else at ``full``
     lands the same losses as no remat at all."""
-    ds = _linear_ds(session)
+    ds = _linear_ds(shared_session)
     base = _losses(_est(mesh=make_mesh(dict(stage=2, data=4)),
                         accum_steps=4).fit(ds))
     r = _est(mesh=make_mesh(dict(stage=2, data=4)), accum_steps=4,
@@ -116,10 +116,10 @@ def test_per_role_remat_policy_trains_to_same_loss(session):
     np.testing.assert_allclose(_losses(r), base, rtol=5e-4)
 
 
-def test_remat_policy_validates_before_compile(session):
+def test_remat_policy_validates_before_compile(shared_session):
     """Unknown remat modes and roles fail eagerly with the offending
     token named — not as a shape error three layers into tracing."""
-    ds = _linear_ds(session, n=64, parts=2)
+    ds = _linear_ds(shared_session, n=64, parts=2)
     mesh = make_mesh(dict(stage=2, data=4))
     with pytest.raises(ValueError, match="unknown remat mode 'huge'"):
         _est(mesh=mesh, remat="kernel=huge").fit(ds)
@@ -127,14 +127,14 @@ def test_remat_policy_validates_before_compile(session):
         _est(mesh=mesh, remat="attention=dots").fit(ds)
 
 
-def test_misplacement_fails_loud(session):
+def test_misplacement_fails_loud(shared_session):
     """Placement misconfigurations raise actionable errors before any
     compile: layers must divide over stages, a staged mesh needs the
     layer-list model description, and the microbatch count must divide
     the batch."""
     from raydp_tpu.models import MLP
 
-    ds = _linear_ds(session, n=64, parts=2)
+    ds = _linear_ds(shared_session, n=64, parts=2)
     mesh = make_mesh(dict(stage=2, data=4))
     with pytest.raises(ValueError, match="stage=2 must divide"):
         _est(model=_model(3), mesh=mesh).fit(ds)
@@ -163,12 +163,13 @@ def test_pipeline_model_description_contract():
             jax.random.PRNGKey(0), np.zeros((4, DIM), np.float32))
 
 
-def test_pipeline_chaos_epoch_crash_resumes_identically(session, tmp_path):
+def test_pipeline_chaos_epoch_crash_resumes_identically(shared_session,
+                                                        tmp_path):
     """Chaos leg: an injected crash at ``estimator.epoch`` mid-fit on the
     staged mesh restores the epoch-0 checkpoint (stage-stacked params save
     and restore under their placed shardings) and replays to weights
     bit-identical to an uninterrupted staged fit."""
-    ds = _linear_ds(session)
+    ds = _linear_ds(shared_session)
 
     def make(ckpt):
         return _est(mesh=make_mesh(dict(stage=2, data=4)), accum_steps=4,

@@ -11,6 +11,7 @@ racing recovery / pipelined streams / serving) lives in tests/test_chaos.py.
 
 import threading
 import time
+from concurrent.futures import Future
 from types import SimpleNamespace
 
 import numpy as np
@@ -21,6 +22,31 @@ from raydp_tpu import metrics
 from raydp_tpu.etl.engine import Engine, ExecutorPool
 
 from tests.test_scheduler import StubExecutor, _payloads, _tasks
+
+
+class GatedExecutor(StubExecutor):
+    """A stub whose tasks finish only once ``gate`` is set, and which logs
+    the payload of every dispatch, in order: what a test asserts is then an
+    order of events, whatever the host's load does to the clock."""
+
+    def __init__(self, gate, log=None, **kw):
+        super().__init__(**kw)
+        self.gate, self.log = gate, [] if log is None else log
+
+    def submit(self, method, payload):
+        self.log.append(payload)
+        done = Future()
+        super().submit(method, payload).add_done_callback(
+            lambda f: (self.gate.wait(60), done.set_result(f.result())))
+        return done
+
+
+def _wait_for(condition, what, timeout=60):
+    """Poll ``condition()`` with a deadline far beyond any load."""
+    deadline = time.monotonic() + timeout
+    while not condition():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.005)
 
 
 # ==== elastic membership units ================================================
@@ -549,36 +575,48 @@ def test_fair_share_interactive_not_starved(monkeypatch):
 
 
 def test_fair_share_tracks_weights(monkeypatch):
-    """Two saturating tenants at weights 3:1: the observed dispatch split
-    while both contend tracks the weight ratio within tolerance."""
+    """Two saturating tenants at weights 3:1: the dispatch split while both
+    contend tracks the weight ratio within tolerance. Read from the ORDER of
+    dispatches, not at an instant: no task finishes before both tenants have
+    queued work, and the split is what the log holds at heavy's last
+    dispatch (a thread that starts late, or a sample taken late, under
+    load moves neither)."""
     monkeypatch.setenv("RDT_SPECULATION", "0")
+    both_queued = threading.Event()
+    log = []                        # the tenant of every dispatch, in order
     # 16 slots: wide enough that the gate's one-task slack per tenant is
-    # small against the ideal 12/4 split (at 4 slots it would dominate)
-    pool = ExecutorPool([StubExecutor(name=f"e{i}", latency=0.01)
-                         for i in range(4)])
+    # small against the ideal 12/4 split (at 4 slots it would dominate);
+    # tasks long enough that the slots, not a tenant's dispatching thread
+    # on a loaded host, are what the tenants contend for
+    pool = ExecutorPool([GatedExecutor(both_queued, log, name=f"e{i}",
+                                       latency=0.05) for i in range(4)])
     boxes = {}
 
     def run(tenant, weight):
         boxes[tenant] = pool.run_tasks(
             _tasks(240), max_inflight_per_executor=4,
-            payloads=_payloads(240), tenant=tenant, tenant_weight=weight)
+            payloads=[tenant.encode()] * 240, tenant=tenant,
+            tenant_weight=weight)
 
     heavy = threading.Thread(target=run, args=("heavy", 3.0))
     light = threading.Thread(target=run, args=("light", 1.0))
     heavy.start()
     light.start()
-    # sample the split while BOTH tenants still have queued work
+    try:
+        _wait_for(lambda: all(
+            pool.load()["tenants"].get(t, {}).get("demand", 0)
+            for t in ("heavy", "light")), "a tenant never queued")
+    finally:
+        both_queued.set()
     heavy.join(timeout=120)
-    at_heavy_finish = pool.load()["tenants"]
     light.join(timeout=120)
     assert all(r is not None for r in boxes["heavy"])
     assert all(r is not None for r in boxes["light"])
-    h = at_heavy_finish["heavy"]["dispatched"]
-    l = at_heavy_finish["light"]["dispatched"]
-    assert h == 240
-    # ideal split at heavy's finish: light ran 1/3 of heavy's tasks (80);
-    # tolerance is generous — the contract is "tracks the ratio", not a
-    # cycle-exact scheduler
+    last_heavy = [i for i, t in enumerate(log) if t == b"heavy"][239]
+    h, l = 240, log[:last_heavy].count(b"light")
+    # ideal split at heavy's last dispatch: light ran 1/3 of heavy's tasks
+    # (80); tolerance is generous — the contract is "tracks the ratio", not
+    # a cycle-exact scheduler
     assert 0.15 <= l / h <= 0.55, f"weighted split off: heavy={h} light={l}"
 
 
@@ -663,9 +701,12 @@ def test_admission_parks_then_rejects_typed(monkeypatch):
 
     monkeypatch.setenv("RDT_SPECULATION", "0")
     monkeypatch.setenv("RDT_POOL_MAX_QUEUED", "10")
-    monkeypatch.setenv("RDT_ADMIT_TIMEOUT_S", "0.4")
+    monkeypatch.setenv("RDT_ADMIT_TIMEOUT_S", "1.0")
     metrics.reset()
-    pool = ExecutorPool([StubExecutor(name="e0", latency=0.05)])
+    # the flood's tasks finish when the test says so: its backlog stands
+    # while the late tenant parks and is refused, however loaded the host
+    drain = threading.Event()
+    pool = ExecutorPool([GatedExecutor(drain, name="e0", latency=0.05)])
 
     def flood():
         pool.run_tasks(_tasks(40), max_inflight_per_executor=2,
@@ -673,9 +714,7 @@ def test_admission_parks_then_rejects_typed(monkeypatch):
 
     t = threading.Thread(target=flood)
     t.start()
-    deadline = time.monotonic() + 5
-    while pool.load()["queued"] < 11 and time.monotonic() < deadline:
-        time.sleep(0.01)
+    _wait_for(lambda: pool.load()["queued"] >= 11, "the flood never queued")
     seen = {}
 
     def late():
@@ -688,20 +727,25 @@ def test_admission_parks_then_rejects_typed(monkeypatch):
 
     lt = threading.Thread(target=late)
     lt.start()
-    time.sleep(0.1)
-    load = pool.load()
-    assert load["parked"] == 4, load  # parked demand is visible
-    assert load["queued"] >= 11      # ... and counted in the autoscale signal
-    # a PARKED tenant is not a fair-share contender: the running flood
-    # keeps its full in-flight cap instead of being serialized to one
-    # task for the whole park (which would also keep the backlog from
-    # ever draining)
-    assert load["tenants"]["flood"]["busy"] == 2, load
-    assert pool._fair_ok("flood")
-    lt.join(timeout=30)
+    try:
+        _wait_for(lambda: pool.load()["parked"] == 4 or not lt.is_alive(),
+                  "the late tenant never parked")
+        load = pool.load()
+        assert load["parked"] == 4, load  # parked demand is visible
+        assert load["queued"] >= 11  # ... and counted in the autoscale signal
+        # a PARKED tenant is not a fair-share contender: the running flood
+        # keeps its full in-flight cap instead of being serialized to one
+        # task for the whole park (which would also keep the backlog from
+        # ever draining)
+        _wait_for(lambda: pool.load()["tenants"]["flood"]["busy"] == 2,
+                  "the flood lost its in-flight cap to a parked tenant")
+        assert pool._fair_ok("flood")
+        lt.join(timeout=30)
+    finally:
+        drain.set()
     t.join(timeout=60)
     assert isinstance(seen.get("err"), AdmissionRejected), seen
-    assert seen["wall"] >= 0.35
+    assert seen["wall"] >= 0.95
     assert_events = [e["kind"] for e in metrics.events()]
     assert "admission_reject" in assert_events
     snap = metrics.snapshot()["counters"]

@@ -19,10 +19,10 @@ def _linear_df(session, n=2048):
 
 
 @pytest.mark.parametrize("use_fs_directory", [False, True])
-def test_estimator_fit_on_frame(session, tmp_path, use_fs_directory):
+def test_estimator_fit_on_frame(shared_session, tmp_path, use_fs_directory):
     import optax
 
-    df = _linear_df(session)
+    df = _linear_df(shared_session)
     train_df, test_df = df.randomSplit([0.75, 0.25], seed=1)
     est = FlaxEstimator(
         model=MLP(features=(16,), use_batch_norm=False),
@@ -46,7 +46,7 @@ def test_estimator_fit_on_frame(session, tmp_path, use_fs_directory):
     assert kernel.shape == (2, 16)
 
 
-def test_estimator_predict(session):
+def test_estimator_predict(shared_session):
     """predict() runs the trained model over a dataset's feature columns,
     covers the full row count (ragged final batch included), and matches a
     manual model.apply on the same rows."""
@@ -55,7 +55,8 @@ def test_estimator_predict(session):
 
     from raydp_tpu.data.dataset import from_frame
 
-    df = _linear_df(session, n=1000)   # 1000 % 64 != 0: exercises the tail
+    # 1000 % 64 != 0: exercises the tail
+    df = _linear_df(shared_session, n=1000)
     est = FlaxEstimator(
         model=MLP(features=(16,), use_batch_norm=False),
         optimizer=optax.adam(1e-2),
@@ -84,12 +85,12 @@ def test_estimator_predict(session):
     assert np.corrcoef(preds, y)[0, 1] > 0.5
 
 
-def test_estimator_batchnorm_model(session):
+def test_estimator_batchnorm_model(shared_session):
     import optax
 
     from raydp_tpu.models import NYCTaxiModel
 
-    df = _linear_df(session, n=1024)
+    df = _linear_df(shared_session, n=1024)
     est = FlaxEstimator(
         model=NYCTaxiModel(),
         optimizer=optax.adam(1e-3),
@@ -105,11 +106,11 @@ def test_estimator_batchnorm_model(session):
     assert "batch_stats" in model
 
 
-def test_estimator_creators_and_retry(session):
+def test_estimator_creators_and_retry(shared_session):
     """Creator callables (parity torch/estimator.py:177-220) + checkpoint resume."""
     import optax
 
-    df = _linear_df(session, n=512)
+    df = _linear_df(shared_session, n=512)
     est = FlaxEstimator(
         model_creator=lambda: MLP(features=(8,), use_batch_norm=False),
         optimizer_creator=lambda: optax.sgd(1e-2),
@@ -126,7 +127,7 @@ def test_estimator_creators_and_retry(session):
     assert any(d.startswith("step_") for d in os.listdir(result.checkpoint_dir))
 
 
-def test_estimator_sharded_batch(session):
+def test_estimator_sharded_batch(shared_session):
     """Batch lands sharded over the 8-device data axis; loss still converges."""
     import jax
     import optax
@@ -135,7 +136,7 @@ def test_estimator_sharded_batch(session):
 
     assert len(jax.devices()) == 8
     mesh = make_mesh(MeshSpec(data=8))
-    df = _linear_df(session, n=2048)
+    df = _linear_df(shared_session, n=2048)
     est = FlaxEstimator(
         model=MLP(features=(16,), use_batch_norm=False),
         optimizer=optax.adam(1e-2),
@@ -150,7 +151,7 @@ def test_estimator_sharded_batch(session):
     assert result.history[-1]["train_loss"] < result.history[0]["train_loss"]
 
 
-def test_streaming_ragged_tail(session):
+def test_streaming_ragged_tail(shared_session):
     """drop_last=False on the streaming feed: the smaller epoch-tail batch
     travels as a step of its own, so training sees every row. A ragged batch
     only shards on a size-1 data axis (same rule the eval feed applies), so
@@ -161,7 +162,8 @@ def test_streaming_ragged_tail(session):
     from raydp_tpu.data import from_frame
     from raydp_tpu.parallel import MeshSpec, make_mesh
 
-    df = _linear_df(session, n=1350)  # 21 full batches of 64 + a 6-row tail
+    # 21 full batches of 64 + a 6-row tail
+    df = _linear_df(shared_session, n=1350)
     ds = from_frame(df)
     est = FlaxEstimator(
         model=MLP(features=(8,), use_batch_norm=False),
@@ -180,7 +182,7 @@ def test_streaming_ragged_tail(session):
     assert np.isfinite(result.history[-1]["train_loss"])
 
 
-def test_device_cache_parity_and_fallback(session, monkeypatch):
+def test_device_cache_parity_and_fallback(shared_session, monkeypatch):
     """The device-resident epoch path (whole epoch = one jitted scan over
     HBM-pinned arrays) must produce exactly the streaming feed's update
     sequence at shuffle=False — same batches, same order — and the
@@ -189,14 +191,15 @@ def test_device_cache_parity_and_fallback(session, monkeypatch):
 
     from raydp_tpu.data import from_frame
 
-    df = _linear_df(session, n=1344)
+    df = _linear_df(shared_session, n=1344)
     ds = from_frame(df)
     # pin the knobs: ambient RDT_DEVICE_CACHE*=... (e.g. exported while
     # debugging the streaming path) must not flip the first run
     monkeypatch.setenv("RDT_DEVICE_CACHE", "1")
     monkeypatch.delenv("RDT_DEVICE_CACHE_MB", raising=False)
 
-    eval_ds = from_frame(_linear_df(session, n=333))  # ragged vs batch 64
+    # ragged vs batch 64
+    eval_ds = from_frame(_linear_df(shared_session, n=333))
 
     def run():
         est = FlaxEstimator(
@@ -249,14 +252,15 @@ def _short_set_estimator(**kw):
         num_epochs=1, shuffle=False, seed=0, metrics=["mae"], **kw)
 
 
-def test_resident_eval_set_under_one_batch_is_the_tail(session, monkeypatch):
+def test_resident_eval_set_under_one_batch_is_the_tail(shared_session,
+                                                       monkeypatch):
     """An evaluation set of fewer rows than one batch rides beside a resident
     training set: its scan has no step to trace and the tail call serves
     every row — the streaming pass's numbers."""
     from raydp_tpu.data import from_frame
 
-    ds = from_frame(_linear_df(session, n=640))
-    eval_ds = from_frame(_linear_df(session, n=40))
+    ds = from_frame(_linear_df(shared_session, n=640))
+    eval_ds = from_frame(_linear_df(shared_session, n=40))
     monkeypatch.delenv("RDT_DEVICE_CACHE_MB", raising=False)
     reports = {}
     for cache in ("1", "0"):
@@ -268,12 +272,12 @@ def test_resident_eval_set_under_one_batch_is_the_tail(session, monkeypatch):
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_training_set_under_one_batch_is_refused_by_name(session):
+def test_training_set_under_one_batch_is_refused_by_name(shared_session):
     """drop_last leaves such a set no step: the fit says so, with both
     numbers, before a program is traced; drop_last=False trains on it."""
     from raydp_tpu.data import from_frame
 
-    ds = from_frame(_linear_df(session, n=40))
+    ds = from_frame(_linear_df(shared_session, n=40))
     with pytest.raises(ValueError, match=r"40 rows.*batch of 64"):
         _short_set_estimator().fit(ds)
     result = _short_set_estimator(drop_last=False).fit(ds)
@@ -281,7 +285,7 @@ def test_training_set_under_one_batch_is_refused_by_name(session):
     assert np.isfinite(result.history[-1]["train_loss"])
 
 
-def test_device_cache_shuffled_training_converges(session, monkeypatch):
+def test_device_cache_shuffled_training_converges(shared_session, monkeypatch):
     """With shuffle=True the resident path shuffles via an on-device
     permutation per epoch: training must still converge on the linear task
     and walk a different batch order every epoch (loss histories differ from
@@ -290,7 +294,7 @@ def test_device_cache_shuffled_training_converges(session, monkeypatch):
 
     from raydp_tpu.data import from_frame
 
-    df = _linear_df(session, n=1344)
+    df = _linear_df(shared_session, n=1344)
     ds = from_frame(df)
     monkeypatch.setenv("RDT_DEVICE_CACHE", "1")
     monkeypatch.delenv("RDT_DEVICE_CACHE_MB", raising=False)
@@ -321,7 +325,7 @@ def test_device_cache_shuffled_training_converges(session, monkeypatch):
         for a, b in zip(result.history, unshuffled.history))
 
 
-def test_checkpoint_interval(session, tmp_path):
+def test_checkpoint_interval(shared_session, tmp_path):
     """checkpoint_interval=N saves every N-th epoch plus always the final one
     (per-epoch checkpointing is reference parity and stays the default; the
     knob exists because a resident epoch can be cheaper than its save)."""
@@ -329,7 +333,7 @@ def test_checkpoint_interval(session, tmp_path):
 
     import optax
 
-    df = _linear_df(session, n=512)
+    df = _linear_df(shared_session, n=512)
     est = FlaxEstimator(
         model=MLP(features=(8,), use_batch_norm=False),
         optimizer=optax.adam(1e-2),
@@ -348,7 +352,7 @@ def test_checkpoint_interval(session, tmp_path):
     assert steps == ["step_2", "step_4"]
 
 
-def test_retry_before_first_interval_save_rebuilds(session):
+def test_retry_before_first_interval_save_rebuilds(shared_session):
     """A failure before the first interval checkpoint has nothing to
     restore; the retry must rebuild the state from scratch (the failed
     state's buffers may be donated away), not continue on dead buffers."""
@@ -361,7 +365,7 @@ def test_retry_before_first_interval_save_rebuilds(session):
             calls["n"] += 1
             raise RuntimeError("transient failure injected at epoch 0")
 
-    df = _linear_df(session, n=512)
+    df = _linear_df(shared_session, n=512)
     est = FlaxEstimator(
         model=MLP(features=(8,), use_batch_norm=False),
         optimizer=optax.adam(1e-2),
@@ -378,14 +382,14 @@ def test_retry_before_first_interval_save_rebuilds(session):
     assert np.isfinite(result.history[-1]["train_loss"])
 
 
-def test_retry_ignores_stale_checkpoint_dir(session, tmp_path):
+def test_retry_ignores_stale_checkpoint_dir(shared_session, tmp_path):
     """A fresh fit reusing a checkpoint_dir from an EARLIER run must not
     adopt that run's checkpoint on retry — only checkpoints this run wrote
     (or an explicit resume) may restore; otherwise the retry silently
     returns the old model and history."""
     import optax
 
-    df = _linear_df(session, n=512)
+    df = _linear_df(shared_session, n=512)
     ck = str(tmp_path / "ck")
 
     def make(**kw):

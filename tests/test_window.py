@@ -19,8 +19,8 @@ def _events(session, n=2000, users=13, parts=4):
     return pdf, session.createDataFrame(pdf, num_partitions=parts)
 
 
-def test_row_number(session):
-    pdf, df = _events(session)
+def test_row_number(shared_session):
+    pdf, df = _events(shared_session)
     w = Window.partitionBy("user").orderBy("ts")
     out = df.withColumn("rn", F.row_number().over(w)).to_pandas()
     exp = pdf.copy()
@@ -30,13 +30,13 @@ def test_row_number(session):
     pd.testing.assert_frame_equal(merged, expected, check_dtype=False)
 
 
-def test_rank_and_dense_rank_with_ties(session):
+def test_rank_and_dense_rank_with_ties(shared_session):
     rng = np.random.RandomState(1)
     pdf = pd.DataFrame({
         "k": rng.randint(0, 5, 600),
         "score": rng.randint(0, 10, 600),  # heavy ties
     })
-    df = session.createDataFrame(pdf, num_partitions=4)
+    df = shared_session.createDataFrame(pdf, num_partitions=4)
     w = Window.partitionBy("k").orderBy("score")
     out = (df.withColumn("r", F.rank().over(w))
              .withColumn("dr", F.dense_rank().over(w)).to_pandas())
@@ -49,8 +49,8 @@ def test_rank_and_dense_rank_with_ties(session):
         exp[key].sort_values(key).reset_index(drop=True), check_dtype=False)
 
 
-def test_lag_lead(session):
-    pdf, df = _events(session, n=500, users=7)
+def test_lag_lead(shared_session):
+    pdf, df = _events(shared_session, n=500, users=7)
     w = Window.partitionBy("user").orderBy("ts")
     out = (df.withColumn("prev", F.lag("amount", 1, -1.0).over(w))
              .withColumn("next", F.lead("amount", 1).over(w))
@@ -62,8 +62,8 @@ def test_lag_lead(session):
     pd.testing.assert_frame_equal(out, exp, check_dtype=False)
 
 
-def test_aggregate_over_partition(session):
-    pdf, df = _events(session, n=800, users=9)
+def test_aggregate_over_partition(shared_session):
+    pdf, df = _events(shared_session, n=800, users=9)
     w = Window.partitionBy("user")
     out = (df.withColumn("total", F.sum("amount").over(w))
              .withColumn("n", F.count("amount").over(w))
@@ -76,8 +76,8 @@ def test_aggregate_over_partition(session):
         assert (rows["n"] == exp_n[u]).all()
 
 
-def test_global_window_no_partition(session):
-    pdf, df = _events(session, n=300, users=3)
+def test_global_window_no_partition(shared_session):
+    pdf, df = _events(shared_session, n=300, users=3)
     w = Window.orderBy("ts")
     out = df.withColumn("rn", F.row_number().over(w)).to_pandas()
     assert sorted(out["rn"]) == list(range(1, 301))
@@ -85,8 +85,8 @@ def test_global_window_no_partition(session):
     assert (out.sort_values("ts")["rn"].to_numpy() == np.arange(1, 301)).all()
 
 
-def test_window_replaces_existing_column(session):
-    pdf, df = _events(session, n=200, users=4)
+def test_window_replaces_existing_column(shared_session):
+    pdf, df = _events(shared_session, n=200, users=4)
     w = Window.partitionBy("user").orderBy("ts")
     out = df.withColumn("amount2", F.lag("amount").over(w)) \
             .withColumn("amount2", F.lead("amount").over(w)).to_pandas()
@@ -94,14 +94,14 @@ def test_window_replaces_existing_column(session):
     assert list(out.columns).count("amount2") == 1
 
 
-def test_window_requires_order(session):
+def test_window_requires_order(shared_session):
     import pytest
 
     with pytest.raises(ValueError, match="orderBy"):
         F.row_number().over(Window.partitionBy("user"))
 
 
-def test_count_star_and_empty_bucket_types(session):
+def test_count_star_and_empty_bucket_types(shared_session):
     """count("*") over a partition (the Spark-standard spelling) and string
     min over few distinct keys (some hash buckets empty — the empty-bucket
     output type must match the non-empty buckets, code-review r4)."""
@@ -110,7 +110,7 @@ def test_count_star_and_empty_bucket_types(session):
         "name": ["bb", "aa", "cc"] * 50,
         "v": list(range(150)),
     })
-    df = session.createDataFrame(pdf, num_partitions=3)
+    df = shared_session.createDataFrame(pdf, num_partitions=3)
     out = (df.withColumn("n", F.count("*").over(Window.partitionBy("k")))
              .withColumn("lo", F.min("name").over(Window.partitionBy("k")))
              .to_pandas())
@@ -123,11 +123,11 @@ def test_count_star_and_empty_bucket_types(session):
     assert pd.api.types.is_integer_dtype(out2.to_pandas()["t"])
 
 
-def test_chained_window_columns_no_reexecution(session):
+def test_chained_window_columns_no_reexecution(shared_session):
     """Chaining window columns must derive the schema statically — listing
     columns between the two withColumn calls must not execute the first
     window's shuffle (code-review r4)."""
-    pdf, df = _events(session, n=300, users=4)
+    pdf, df = _events(shared_session, n=300, users=4)
     w = Window.partitionBy("user").orderBy("ts")
     one = df.withColumn("rn", F.row_number().over(w))
     # schema known without running the plan
@@ -139,7 +139,7 @@ def test_chained_window_columns_no_reexecution(session):
     assert {"rn", "prev"} <= set(out.columns)
 
 
-def test_running_aggregate_with_order(session):
+def test_running_aggregate_with_order(shared_session):
     """Spark's default frame WITH orderBy is unboundedPreceding..currentRow:
     sum over an ordered window is a RUNNING sum, and order-key ties share
     the frame (RANGE semantics) — verified against a pandas expanding sum
@@ -149,7 +149,7 @@ def test_running_aggregate_with_order(session):
         "ts": [1, 2, 2, 3, 1, 2, 3],   # a tie at (k=1, ts=2)
         "x": [10.0, 20.0, 30.0, 40.0, 1.0, 2.0, 3.0],
     })
-    df = session.createDataFrame(pdf, num_partitions=3)
+    df = shared_session.createDataFrame(pdf, num_partitions=3)
     w = Window.partitionBy("k").orderBy("ts")
     out = (df.withColumn("run", F.sum("x").over(w))
              .withColumn("n", F.count("*").over(w))
@@ -160,14 +160,14 @@ def test_running_aggregate_with_order(session):
     assert out[out["k"] == 2]["run"].tolist() == [1.0, 3.0, 6.0]
 
 
-def test_same_spec_windows_one_shuffle(session):
+def test_same_spec_windows_one_shuffle(shared_session):
     """Adjacent window columns over the same partition keys must collapse to
     ONE shuffle (code-review r4): the compiled plan's map stage runs once."""
-    pdf, df = _events(session, n=400, users=5)
+    pdf, df = _events(shared_session, n=400, users=5)
     w = Window.partitionBy("user").orderBy("ts")
     both = (df.withColumn("rn", F.row_number().over(w))
               .withColumn("prev", F.lag("amount").over(w)))
-    engine = session.engine
+    engine = shared_session.engine
     from raydp_tpu.etl import tasks as T
     tasks, _ = engine._compile(both._plan, temps=[])
     # every reduce task carries BOTH window steps (one shuffle, chained eval)
@@ -181,13 +181,13 @@ def test_same_spec_windows_one_shuffle(session):
         pdf.sort_values(["user", "ts"]).index].tolist()
 
 
-def test_split_shards_fallback_shuffle_varies(session):
+def test_split_shards_fallback_shuffle_varies(shared_session):
     """The more-ranks-than-blocks shard fallback must honor shuffle/seed:
     different seeds give different rank assignments, same seed is stable,
     and every variant keeps the equal-share invariant."""
     from raydp_tpu.data import from_frame
 
-    ds = from_frame(_events(session, n=1000, users=3, parts=2)[1])
+    ds = from_frame(_events(shared_session, n=1000, users=3, parts=2)[1])
     a = ds.split_shards(world_size=5, shuffle=True, seed=1)
     b = ds.split_shards(world_size=5, shuffle=True, seed=1)
     c = ds.split_shards(world_size=5, shuffle=True, seed=2)
@@ -198,7 +198,7 @@ def test_split_shards_fallback_shuffle_varies(session):
         assert counts == [200] * 5
 
 
-def test_running_aggregate_ignores_nulls(session):
+def test_running_aggregate_ignores_nulls(shared_session):
     """Spark ignores nulls inside the frame: a null row takes the prior
     running value (not null), an all-null prefix stays null, and a null tie
     peer does not poison the tie group (code-review r4 finding)."""
@@ -207,7 +207,7 @@ def test_running_aggregate_ignores_nulls(session):
         "ts": [1, 2, 3, 1, 2, 2, 3],
         "x": [None, None, 5.0, 10.0, None, 20.0, 30.0],
     })
-    df = session.createDataFrame(pdf, num_partitions=2)
+    df = shared_session.createDataFrame(pdf, num_partitions=2)
     w = Window.partitionBy("k").orderBy("ts")
     out = (df.withColumn("run", F.sum("x").over(w))
              .withColumn("avg", F.mean("x").over(w))
