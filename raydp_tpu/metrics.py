@@ -356,8 +356,9 @@ _ALL_METRICS = [
        "says how far the router's own scores are from balanced. "
        "doc/training.md."),
     _m("train_attention_layers_total", COUNTER, "1", "training",
-       "Attention layers of a training model, counted once a built train "
-       "step by kind: `window` (a sliding window: a query sees itself and "
+       "Attention layer executions of a training model's step (the layers; "
+       "times `total_ut_steps` where the model loops), counted once a built "
+       "train step by kind: `window` (a sliding window: a query sees itself and "
        "the window - 1 keys before it), `blockdiff` (the block-diffusion "
        "mask over a clean and a noised copy of each row) or `full` (every "
        "key up to its own); "
@@ -366,7 +367,8 @@ _ALL_METRICS = [
        "doc/models.md.",
        label="kind"),
     _m("train_attention_forward_total", COUNTER, "1", "training",
-       "Attention layers of a training model, counted once a built train "
+       "Attention layer executions of a training model's step (layers x "
+       "`total_ut_steps`), counted once a built train "
        "step by how often the step runs their forward attention: `once` "
        "(the block is not recomputed, or `remat_blocks` recomputes it and "
        "keeps the flash kernel's output and row sums, so the recomputation "
@@ -378,7 +380,8 @@ _ALL_METRICS = [
        "Sub-layer outputs that a second norm reads (`sandwich_norms`: the "
        "attention's and the feed-forward's, two a block) in a training "
        "model whose blocks are recomputed (`remat_blocks`), counted once a "
-       "built train step by what the recomputation does with them: `kept` "
+       "built train step (block executions: x `total_ut_steps` where the "
+       "model loops) by what the recomputation does with them: `kept` "
        "(the feed-forward's: no forward walk of the held experts and no "
        "down projection runs again) or `rebuilt` (the attention's: its "
        "output projection runs again). Absent where no block is recomputed "
@@ -462,6 +465,30 @@ _ALL_METRICS = [
        "noise replaced by the mask id (the only ones with a loss term; "
        "about half under t ~ U(0, 1]). models/transformer.py.",
        label="tokens"),
+    _m("train_loop_passes_total", COUNTER, "1", "training",
+       "Layer executions of a looped training model's step "
+       "(`total_ut_steps` passes of the whole stack on shared weights, one "
+       "`lax.scan` in the program: passes x layers), counted once a built "
+       "train step by what the loop keeps of each execution for the backward "
+       "pass: `recomputed` (`remat_blocks`: the block's input, its flash "
+       "kernel's output and row sums and the sub-layer output a second norm "
+       "reads, once a pass AND a layer, stacked by the loop) or `plain` "
+       "(everything). Absent where the stack runs once. "
+       "doc/training.md, a looped model.",
+       label="layers"),
+    _m("train_exit_mass_total", COUNTER, "1", "training",
+       "A looped language model's exit distribution, summed on the device "
+       "inside the train step and added here with each epoch's loss: the "
+       "sum over a step's positions (those that carry a loss) of the exit "
+       "probability p_t of pass t, a label a pass (`1` .. `total_ut_steps`); "
+       "the labels sum to train_exit_positions_total. sum_t t * mass_t / "
+       "positions is the pass at which the gate expects to stop (1.875 of 4 "
+       "at a fresh gate). models/transformer.py.",
+       label="pass"),
+    _m("train_exit_positions_total", COUNTER, "1", "training",
+       "Positions a looped language model's exit distribution was summed "
+       "over (train_exit_mass_total): every row's positions but the last, "
+       "a padded row's left out."),
     _m("jit_lowerings_total", COUNTER, "1", "training",
        "Programs jax lowered in this process (jaxpr to MLIR: every program "
        "jax compiles, or loads from the persistent compile cache, is lowered "
@@ -688,6 +715,19 @@ _ALL_SPANS = [
        "and one Bernoulli a token, the masked copy of the row, laying out "
        "`[clean ; noised]` and the per-position weights of the loss "
        "(`models/transformer.py`).", kind=SCOPE),
+    _s("loop", "model",
+       "A looped language model's passes (`total_ut_steps`: one `lax.scan` "
+       "over the whole stack on shared weights, forward and transposed). The "
+       "layers lie under it by their own names (`block_<i>`); what lies "
+       "under it and under no block is the loop's own cost: the stacked "
+       "residuals' writes and reads, the carry, the shared weights' "
+       "gradients summed pass by pass, and the final norm that ends every "
+       "pass (`models/transformer.py`).", kind=SCOPE),
+    _s("exit_gate", "model",
+       "A looped language model's exit gate: its product with every pass's "
+       "hidden states, the sigmoid, the exit distribution, the entropy and "
+       "the counts; forward and backward (`models/transformer.py`).",
+       kind=SCOPE),
     _s("attn/latent", "model",
        "latent attention's K/V path inside an `attn` scope: the "
        "down-projection to the K/V latent and the one rotary key all heads "

@@ -384,6 +384,12 @@ class TransformerLM(nn.Module):
     diffusion: Any = None                   # a BlockDiffusionSpec: the
     # model trains the block-diffusion objective, not next-token prediction
     embed_init_std: Optional[float] = None  # None: the embedding's is init_std
+    # a looped model (the ``ouro`` family; the section at the file's end)
+    total_ut_steps: int = 1                 # passes of the whole stack
+    exit_entropy_weight: Optional[float] = None     # a float: the exit gate
+    # exists and the loss is the expected loss less this times the entropy
+    exit_probs_out: bool = False            # a plain call's logits end in
+    # the exit distribution's ``total_ut_steps`` probabilities a position
 
     def _kind(self, layer: int) -> str:
         """``B`` the pair (attention, then a feed-forward part), or the one
@@ -409,9 +415,11 @@ class TransformerLM(nn.Module):
 
     @property
     def attention_layers(self):
-        """How many layers of each kind the model has: what
-        ``train_attention_layers_total`` counts once a built step. A latent
-        layer counts under its kernel's kind and under ``latent``."""
+        """How many attention layers of each kind a step executes (the
+        layers of that kind times ``total_ut_steps``: a looped model runs
+        each once a pass): what ``train_attention_layers_total`` counts once
+        a built step. A latent layer counts under its kernel's kind and under
+        ``latent``."""
         layers = self._layers_of("B*")
         windowed = sum(self._windowed(i) for i in layers)
         kinds = {"window": windowed, "full": len(layers) - windowed}
@@ -419,7 +427,7 @@ class TransformerLM(nn.Module):
             kinds = {"blockdiff": len(layers)}
         if self.kv_lora_rank is not None:
             kinds["latent"] = len(layers)
-        return kinds
+        return {k: n * self.total_ut_steps for k, n in kinds.items()}
 
     @property
     def ssm_layers(self):
@@ -430,7 +438,8 @@ class TransformerLM(nn.Module):
 
     @property
     def attention_forward(self):
-        """How often a train step runs each layer's forward attention: what
+        """How often a train step runs each layer's forward attention, by
+        the layers a step executes (layers times ``total_ut_steps``): what
         ``train_attention_forward_total`` counts once a built step. ``once``:
         the block is not recomputed, or it is and keeps its flash kernel's
         output and row sums; ``twice``: a recomputed block whose attention
@@ -444,7 +453,8 @@ class TransformerLM(nn.Module):
         kind = Attention(self.num_heads, self.attention,
                          self.mesh)._dispatch(8192, d_qk, d_v)
         kept = not self.remat_blocks or kind == "flash"
-        return {"once" if kept else "twice": len(self._layers_of("B*"))}
+        return {"once" if kept else "twice":
+                len(self._layers_of("B*")) * self.total_ut_steps}
 
     @property
     def _blockdiff(self) -> Optional[int]:
@@ -492,44 +502,13 @@ class TransformerLM(nn.Module):
                      dtype=self.dtype, embedding_init=init)(tokens)
         if self.embed_scale:
             x = x * jnp.asarray(np.sqrt(self.dim), x.dtype)
-        aux = []
-        block = Block
-        if self.remat_blocks:
-            from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
-
-            # a recomputed block keeps its input, its flash kernel's pair and
-            # its feed-forward's normed output: the backward reads them (end)
-            kept = (*RESIDUAL_NAMES, SUBLAYER_OUT)
-            block = nn.remat(Block, policy=jax.checkpoint_policies
-                             .save_only_these_names(*kept))
+        if _is_looped(self):
+            return _looped(self, x, return_hidden, labels, weights)
+        aux, kept = [], _kept(self)
+        block = _recomputed(Block, kept)        # one class for every pair
         for i in range(self.num_layers):
-            sparse = self._sparse(i)
-            if self._kind(i) != "B":
-                x = _one_sublayer(self, i, kept if self.remat_blocks
-                                  else None)(x)
-                if sparse:
-                    x, layer_aux = x
-                    aux.append(layer_aux)
-                continue
-            x = block(self.num_heads, self.mlp_ratio, self.attention,
-                      self.mesh, self.dtype,
-                      self.ffn_dim if sparse or self.dense_ffn_dim is None
-                      else self.dense_ffn_dim, self.rms_norm_eps,
-                      self.rope_theta, self.qk_norm,
-                      self.num_experts if sparse else 0,
-                      self.experts_per_token, self.init_std, self.head_dim,
-                      self.num_kv_heads,
-                      self.sliding_window if self._windowed(i) else None,
-                      self._rope(i), self.first_expert, self.experts_held,
-                      self.expert_activation, self.normalize_top_k,
-                      self.router_input, self.attention_gate,
-                      self.sandwich_norms, self.routing, self.route_scale,
-                      self.shared_expert_dim, self.kv_lora_rank,
-                      self.q_lora_rank, self.qk_nope_head_dim,
-                      self.qk_rope_head_dim, self.v_head_dim,
-                      self.rope_interleave, self.expert_gated,
-                      self._blockdiff, name=f"block_{i}")(x)
-            if sparse:
+            x = _layer(self, i, kept, block)(x)
+            if self._sparse(i):
                 x, layer_aux = x
                 aux.append(layer_aux)
         x = RMSNorm(self.rms_norm_eps, name="ln_f")(x)
@@ -576,6 +555,8 @@ class TransformerLM(nn.Module):
         """What the second output of :meth:`loss_rows` counts, as (registry
         metric, label) pairs: counters are summed over an epoch's steps, a
         gauge keeps the last step's value."""
+        if self.exit_entropy_weight is not None:    # dense: no other counts
+            return _exit_counters(self.total_ut_steps)
         noise = () if self.diffusion is None else tuple(
             ("train_diffusion_tokens_total", kind)
             for kind in ("masked", "all"))
@@ -619,14 +600,27 @@ class TransformerLM(nn.Module):
     def sublayer_out(self):
         """The sub-layer outputs a second norm reads (two a block under
         ``sandwich_norms``) by what a recomputed block does with them: what
-        ``train_sublayer_out_total`` counts once a built step. ``kept``: the
+        ``train_sublayer_out_total`` counts once a built step, by the blocks
+        a step executes (blocks times ``total_ut_steps``). ``kept``: the
         feed-forward's (``SUBLAYER_OUT``); ``rebuilt``: the attention's (its
         output projection runs again). Nothing where no block is recomputed
         or no norm reads them."""
         if not (self.remat_blocks and self.sandwich_norms):
             return {}
-        pairs = len(self._layers_of("B"))
+        pairs = len(self._layers_of("B")) * self.total_ut_steps
         return {"kept": pairs, "rebuilt": pairs}
+
+    @property
+    def loop_passes(self):
+        """The layer executions of a looped model's step, ``total_ut_steps``
+        times the layers, by what the loop keeps of each (``recomputed``:
+        ``remat_blocks``' set a pass and layer; ``plain``: all of it): what
+        ``train_loop_passes_total`` counts once a built step. Nothing where
+        the stack runs once."""
+        if not _is_looped(self):
+            return {}
+        return {"recomputed" if self.remat_blocks else "plain":
+                self.total_ut_steps * self.num_layers}
 
 
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -637,17 +631,21 @@ def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
         logits[:, :-1], tokens[:, 1:]).mean()
 
 
-def _head_chunks(hidden, tokens, chunk, position_weights=None):
+def _head_chunks(hidden, tokens, chunk, position_weights=None,
+                 shifted=False):
     """Positions 0..T-2 predict tokens 1..T-1: both cut into ``[N, B, C, ...]``
     chunks of ``C <= chunk`` positions (zero-padded to a whole number of
     chunks), with the ``[N, 1, C]`` mask of the real positions. With
     ``position_weights`` [B, T]: position ``i`` predicts token ``i`` (no
     shift, all T), and in the mask's place stand the weights, ``[N, B, C]``
-    (the padding's are zero)."""
+    (the padding's are zero); ``shifted``, they weigh the next-token form
+    (the last position's weight is not read)."""
     B, T, D = hidden.shape
     n = T - 1
     x, y = hidden[:, :-1], tokens[:, 1:]
-    if position_weights is not None:
+    if shifted:
+        position_weights = position_weights[:, :-1]
+    elif position_weights is not None:
         n, x, y = T, hidden, tokens
     chunk = min(chunk, n)
     pad = (-n) % chunk
@@ -664,7 +662,7 @@ def _head_chunks(hidden, tokens, chunk, position_weights=None):
 
 
 def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
-               position_weights=None):
+               position_weights=None, shifted=False):
     """One scan over the chunks of :func:`_head_chunks`. A chunk's logits
     (``[B, C, V]`` float32: operands in the activations' dtype, float32
     accumulation) exist once, inside the scan's body; from them come the
@@ -677,12 +675,16 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
     [B, T, D] in hidden's dtype, d kernel [D, V] float32)``, the last two
     ``None`` without gradients. ``position_weights`` [B, T]: a row is
     ``sum_i w_i CE(logits_i, token_i) / T``, same position, all T of them
-    (:func:`_head_chunks`)."""
+    (:func:`_head_chunks`); ``shifted``, ``sum_i w_i CE(logits_i, token_i+1)
+    / (T - 1)``, and a fourth result: every position's cross entropy
+    ``[B, T]`` float32 (the last position's is 0), which is the weights'
+    gradient."""
     from jax import lax
 
     B, T, D = hidden.shape
-    n = T - 1 if position_weights is None else T
-    xs, ys, ms = _head_chunks(hidden, tokens, chunk, position_weights)
+    n = T - 1 if position_weights is None or shifted else T
+    xs, ys, ms = _head_chunks(hidden, tokens, chunk, position_weights,
+                              shifted)
     k = kernel.astype(hidden.dtype)      # cast once, not once a chunk
     vocab = lax.broadcasted_iota(jnp.int32, (1, 1, k.shape[1]), 2)
     scale = weights.astype(jnp.float32)[:, None] / n            # [B, 1]
@@ -706,6 +708,8 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
                               preferred_element_type=jnp.float32)
         dk = dk + lax.dot_general(xc, dlogits, (((0, 1), (0, 1)), ((), ())),
                                   preferred_element_type=jnp.float32)
+        if shifted:
+            return (total, dk), (dxc.astype(xc.dtype), ce)
         return (total, dk), dxc.astype(xc.dtype)
 
     dk0 = jnp.zeros(k.shape, jnp.float32) if with_grads else None
@@ -714,10 +718,15 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
     rows = total / n
     if not with_grads:
         return rows, None, None
+    if shifted:
+        dxs, ces = dxs
+        ce = jnp.pad(ces.swapaxes(0, 1).reshape(B, -1)[:, :n],
+                     ((0, 0), (0, 1)))
     dx = dxs.swapaxes(0, 1).reshape(B, -1, D)[:, :n]
-    if position_weights is not None:
+    if position_weights is not None and not shifted:
         return rows, dx, dk
-    return rows, jnp.pad(dx, ((0, 0), (0, 1), (0, 0))), dk
+    dx = jnp.pad(dx, ((0, 0), (0, 1), (0, 0)))
+    return (rows, dx, dk, ce) if shifted else (rows, dx, dk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -748,7 +757,8 @@ _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
                  tokens: jnp.ndarray, weights: jnp.ndarray,
-                 chunk: int = 1024, position_weights=None):
+                 chunk: int = 1024, position_weights=None,
+                 next_token_weights=None):
     """Next-token cross entropy with the lm_head FUSED into the loss:
     ``(sum(weights * rows), rows)``, ``rows`` ``[B]`` float32 the mean
     cross entropy of each sequence (reported: no gradient flows from them).
@@ -773,8 +783,22 @@ def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
     cross entropy at the SAME position under a weight a position: a row is
     ``sum_i w_i CE(logits_i, tokens_i) / T`` over all T positions (a
     masked-token objective: ``w_i`` zero where a token carries no loss).
+
+    ``next_token_weights`` [B, T] float32 (off by default) weighs the
+    NEXT-token cross entropy a position, and the weights carry a gradient: a
+    row is ``sum_i w_i CE(logits_i, tokens_i+1) / (T - 1)`` (the last
+    position's weight is not read) and ``d loss / d w_i`` is that position's
+    cross entropy times the row's weight over ``T - 1``. A looped model
+    hands over its passes as rows (``[passes * B, T, D]``, the tokens and the
+    rows' weights repeated) under each pass's exit probabilities: all of
+    them share the one scan and its one ``[D, V]`` carry.
     """
     with jax.named_scope("lm_head_loss"):
+        if next_token_weights is not None:
+            if position_weights is not None:
+                raise ValueError("position_weights or next_token_weights")
+            return _weighted_head_loss(hidden, lm_head_kernel, tokens,
+                                       weights, chunk, next_token_weights)
         return _head_loss(hidden, lm_head_kernel, tokens, weights, chunk,
                           position_weights)
 
@@ -800,6 +824,9 @@ def transformer_param_rules(axis: str = "tensor"):
     the fastest ICI links (raydp_tpu/parallel/mesh.py axis order).
     """
     return [
+        # a looped model's exit gate ([D, 1] and a bias) is replicated; met
+        # first, because its path holds the SwiGLU's ``gate/kernel`` too
+        ("exit_gate/", ()),
         # q over the query heads, k and v over the (with grouped-query
         # attention fewer) K/V heads: the axis has to divide both. The held
         # experts' stacked kernels [held, in, out] are the ``expert`` role's
@@ -1082,10 +1109,47 @@ class Layer(nn.Module):
         return x + out
 
 
-def _one_sublayer(model, i: int, kept):
-    """Layer ``i`` of a model whose ``layer_kinds`` makes it one sub-layer;
-    recomputed in the backward pass where ``kept`` names what it keeps."""
+def _kept(model):
+    """What a recomputed layer of ``model`` keeps beside its input, by name:
+    its flash kernel's pair and its feed-forward's normed output (the
+    backward reads them). None where no layer is recomputed."""
+    if not model.remat_blocks:
+        return None
+    from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    return (*RESIDUAL_NAMES, SUBLAYER_OUT)
+
+
+def _recomputed(cls, kept):
+    """``cls``, recomputed in the backward pass where ``kept`` names what it
+    keeps."""
+    return cls if kept is None else nn.remat(
+        cls, policy=jax.checkpoint_policies.save_only_these_names(*kept))
+
+
+def _layer(model, i: int, kept, block):
+    """Layer ``i`` of a model, ``block_<i>``: the pair (``B``; ``block`` is
+    the class, ``_recomputed(Block, kept)`` made once a model so that the
+    layers share what they trace alike) or the one sub-layer ``layer_kinds``
+    makes it."""
     kind = model._kind(i)
+    if kind == "B":
+        sparse = model._sparse(i)
+        return block(
+            model.num_heads, model.mlp_ratio, model.attention, model.mesh,
+            model.dtype, model.ffn_dim if sparse
+            or model.dense_ffn_dim is None else model.dense_ffn_dim,
+            model.rms_norm_eps, model.rope_theta, model.qk_norm,
+            model.num_experts if sparse else 0, model.experts_per_token,
+            model.init_std, model.head_dim, model.num_kv_heads,
+            model.sliding_window if model._windowed(i) else None,
+            model._rope(i), model.first_expert, model.experts_held,
+            model.expert_activation, model.normalize_top_k,
+            model.router_input, model.attention_gate, model.sandwich_norms,
+            model.routing, model.route_scale, model.shared_expert_dim,
+            model.kv_lora_rank, model.q_lora_rank, model.qk_nope_head_dim,
+            model.qk_rope_head_dim, model.v_head_dim, model.rope_interleave,
+            model.expert_gated, model._blockdiff, name=f"block_{i}")
     if kind == "M":
         if model.ssm is None:
             raise ValueError("an 'M' layer needs the model's ssm=SSMSpec(..)")
@@ -1112,9 +1176,8 @@ def _one_sublayer(model, i: int, kept):
             model.first_expert, model.experts_held, model.expert_activation,
             model.normalize_top_k, model.routing, model.route_scale,
             model.shared_expert_dim, model.expert_gated, name="moe")
-    layer = Layer if kept is None else nn.remat(
-        Layer, policy=jax.checkpoint_policies.save_only_these_names(*kept))
-    return layer(mixer, model.rms_norm_eps, name=f"block_{i}")
+    return _recomputed(Layer, kept)(mixer, model.rms_norm_eps,
+                                    name=f"block_{i}")
 
 
 # ---------------------------------------------------------------------------
@@ -1193,3 +1256,151 @@ def _diffusion_loss(model, x, kernel, labels, weights, noise, aux):
         return loss, counts
     loss, slots = model._with_aux(loss, weights, aux)
     return loss, jnp.concatenate([slots, counts])
+
+
+# ---------------------------------------------------------------------------
+# Looped models (the ``ouro`` family: "layers run several times"). Down here
+# for the reason ``SUBLAYER_OUT`` is.
+#
+# ``total_ut_steps = P > 1`` applies the whole stack ``block_0..block_{N-1}``
+# and the final norm P times to its own output on the SAME parameters:
+# ``h_t = ln_f(Stack(h_{t-1}))``, ``h_0`` the embeddings. The passes are ONE
+# ``lax.scan`` (flax's ``nn.scan`` with the parameters broadcast), so the
+# program holds each layer once whatever P is, the parameter tree is the one
+# a plain model has, and a shared weight's gradient is summed over the passes
+# in the scan's transpose, into one float32 tree. A recomputed block
+# (``remat_blocks``) keeps its set a pass AND a layer, stacked by the scan.
+#
+# ``exit_entropy_weight = beta`` adds the exit gate (``exit_gate``: 2048 + 1
+# float32 parameters at a hidden size of 2048) and the objective of the
+# family's first training stage: ``lambda_t = sigmoid(h_t w_g + b_g)`` a
+# position, the exit distribution ``p_t = lambda_t S_{t-1}``, ``S_t = S_{t-1}
+# (1 - lambda_t)`` (``S_0 = 1``, ``p_P = S_{P-1}``: the P sum to 1), and
+#
+#     loss = sum_b w_b mean_{i < T-1} [ sum_t p_t(i) CE_t(i) - beta H(p(i)) ]
+#
+# with ``CE_t`` pass t's next-token cross entropy. All P passes' hidden
+# states go through the head as rows of ONE :func:`lm_head_loss` scan under
+# ``next_token_weights = p`` (one ``[D, V]`` float32 carry, not P). Without
+# the gate the loss is the last pass's. Called plainly the model returns the
+# last pass's logits (no position leaves early) and, ``exit_probs_out``, the
+# P exit probabilities after them in the last dimension.
+# ---------------------------------------------------------------------------
+def _is_looped(model) -> bool:
+    return model.total_ut_steps > 1 or model.exit_entropy_weight is not None
+
+
+def _exit_counters(passes: int):
+    """A gated model's ``loss_counters``: a pass's exit mass each, then the
+    positions they were summed over."""
+    return tuple(("train_exit_mass_total", str(t + 1))
+                 for t in range(passes)) + (
+                     ("train_exit_positions_total", ""),)
+
+
+def exit_distribution(gate_logits):
+    """``gate_logits`` [P, ...] float32, a pass's gate before its sigmoid ->
+    the logarithms of the exit probabilities [P, ...]: ``p_t = lambda_t
+    prod_{s<t} (1 - lambda_s)`` for ``t < P`` and ``p_P = prod_{s<P} (1 -
+    lambda_s)`` (the last pass's gate is not read). In logarithms, so that a
+    saturated gate gives a finite entropy and finite gradients."""
+    go = jax.nn.log_sigmoid(-gate_logits[:-1])          # log(1 - lambda_s)
+    survived = jnp.concatenate([jnp.zeros_like(gate_logits[:1]),
+                                jnp.cumsum(go, axis=0)])     # log S_{t-1}
+    return survived + jnp.concatenate([
+        jax.nn.log_sigmoid(gate_logits[:-1]),
+        jnp.zeros_like(gate_logits[:1])])
+
+
+def _looped(model, x, return_hidden, labels, weights):
+    """What :meth:`TransformerLM.__call__` returns for a looped model, from
+    the embeddings ``x`` on."""
+    if (model.num_experts or model.diffusion is not None
+            or set(model.layer_kinds) - {"B"}):
+        raise ValueError("a looped model (total_ut_steps > 1 or an exit "
+                         "gate) takes dense blocks alone: no experts, no "
+                         "layer of one sub-layer, no block diffusion")
+    kept, f32 = _kept(model), jnp.float32
+    block = _recomputed(Block, kept)
+
+    def one_pass(mdl, h, _):
+        for i in range(mdl.num_layers):
+            h = _layer(mdl, i, kept, block)(h)
+        h = RMSNorm(mdl.rms_norm_eps, name="ln_f")(h)
+        return h, h
+
+    with jax.named_scope("loop"):
+        x, hs = nn.scan(one_pass, variable_broadcast="params",
+                        split_rngs={"params": False},
+                        length=model.total_ut_steps)(model, x, None)
+    init = _init(model.init_std, nn.linear.default_kernel_init)
+    head = nn.Dense(model.vocab_size, use_bias=False, dtype=model.dtype,
+                    name="lm_head", kernel_init=init)
+    log_p = None
+    if model.exit_entropy_weight is not None:
+        with jax.named_scope("exit_gate"):
+            log_p = exit_distribution(nn.Dense(
+                1, dtype=f32, name="exit_gate", kernel_init=init)(hs)[..., 0])
+    if labels is None and not return_hidden:
+        logits = head(x).astype(f32)
+        if log_p is None or not model.exit_probs_out:
+            return logits
+        with jax.named_scope("exit_gate"):
+            return jnp.concatenate(
+                [logits, jnp.moveaxis(jnp.exp(log_p), 0, -1)], axis=-1)
+    head(x[:, :1])      # registers the kernel, as the plain model does
+    if labels is None:
+        return x
+    kernel = head.variables["params"]["kernel"]
+    passes, rows = hs.shape[:2]
+    if log_p is None:
+        loss, _ = lm_head_loss(x, kernel, labels, weights,
+                               chunk=max(128, 2048 // rows))
+        return loss, jnp.zeros((0,), f32)
+    with jax.named_scope("exit_gate"):
+        p = jnp.exp(log_p)                                  # [P, B, T]
+    loss, _ = lm_head_loss(
+        hs.reshape((passes * rows,) + hs.shape[2:]), kernel,
+        jnp.tile(labels, (passes, 1)), jnp.tile(weights, passes),
+        chunk=max(128, 2048 // (passes * rows)),
+        next_token_weights=p.reshape(passes * rows, -1))
+    with jax.named_scope("exit_gate"):
+        # over the positions that carry a loss (the last predicts nothing)
+        p, log_p = p[..., :-1], log_p[..., :-1]
+        entropy = -jnp.sum(p * log_p, axis=0).mean(axis=-1)         # [B]
+        loss = loss - model.exit_entropy_weight * jnp.sum(weights * entropy)
+        real = (weights > 0).astype(f32)                # a padded row: none
+        counts = jnp.concatenate([
+            jnp.einsum("pbt,b->p", p, real),
+            jnp.sum(real)[None] * p.shape[-1]])
+    return loss, counts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_head_loss(hidden, kernel, tokens, weights, chunk,
+                        position_weights):
+    """:func:`_head_loss` in the next-token form under weights a position
+    that carry a gradient (:func:`lm_head_loss`'s ``next_token_weights``)."""
+    rows, _, _ = _head_scan(hidden, kernel, tokens, weights, chunk, False,
+                            position_weights, shifted=True)
+    return jnp.sum(weights * rows), rows
+
+
+def _weighted_head_loss_fwd(hidden, kernel, tokens, weights, chunk,
+                            position_weights):
+    rows, dh, dk, ce = _head_scan(hidden, kernel, tokens, weights, chunk,
+                                  True, position_weights, shifted=True)
+    scale = weights.astype(jnp.float32)[:, None] / (hidden.shape[1] - 1)
+    return (jnp.sum(weights * rows), rows), (
+        dh, dk.astype(kernel.dtype), rows, scale * ce)
+
+
+def _weighted_head_loss_bwd(chunk, residuals, cotangents):
+    dh, dk, rows, dw = residuals
+    g, _ = cotangents           # the rows are reported, not differentiated
+    with jax.named_scope("lm_head_loss"):
+        return ((g * dh).astype(dh.dtype), (g * dk).astype(dk.dtype), None,
+                g * rows, g * dw)
+
+
+_weighted_head_loss.defvjp(_weighted_head_loss_fwd, _weighted_head_loss_bwd)
