@@ -35,27 +35,41 @@ or their exchange.
 
 A share moves and touches the held slots' rows and no others. The slots are
 sorted with the held experts' first; how many there are, ``S``, is a run-time
-value with no bound below ``top_k * N`` (dropless: no slot of a held expert
-is dropped, at any routing), so the share walks the first ``S`` positions of
-the sorted order ``c`` at a time, ``ceil(S / c)`` trips of a ``while``, none
-when no slot is held (:func:`_held_share`). A trip gathers its ``c`` token
-rows, gives the three grouped products the held groups' sizes clipped to the
-trip, masks the rows of the last trip after the held slots (whatever a
-grouped product leaves in rows of no group goes no further) and writes its
-``c`` output rows at their place in an expert-order buffer that nothing
-initialises; each token then sums its ``top_k`` slots' rows from that buffer
-(``top_k`` gathers of ``N`` rows, an absent slot reading nothing: the one move
-of ``top_k * N`` rows a pass keeps). The loop has no reverse rule, so the
-backward is written here and walks the same trips: a slot's output gradient
-is its token's row of ``g`` (a gather, token order -> expert order), its
-weight's gradient a row dot product in expert order, the kernels' gradients
-are summed over the trips in float32, and the input's gradient comes back as
-the forward's output does; it keeps the layer's inputs and the routing's small
-arrays and forms the two first products again, so a block that is recomputed
-anyway (``remat_blocks``) has nothing to recompute here. ``c`` follows from the shapes
-(:func:`_chunk_rows`: half the even share ``top_k * N * held / E``).
+value with no bound below ``top_k * N`` (dropless: no slot of a held expert is
+dropped, at any routing), so the share walks the first ``S`` positions of the
+sorted order ``c`` at a time, ``ceil(S / c)`` trips of a ``while``, none when
+no slot is held (:func:`_held_share`). A trip gathers its ``c`` token rows,
+gives the three grouped products the held groups' sizes clipped to the trip,
+masks the rows of the last trip after the held slots (whatever a grouped
+product leaves in rows of no group goes no further) and writes its ``c``
+output rows at their place in an expert-order buffer that nothing initialises.
+Back in token order a token's held slots are neighbours, so the held rows are
+summed where they lie (:func:`_runs_to_tokens`): one integer sort lists the
+held positions by token (:func:`_runs_by_token`, shared by the forward and the
+backward), the same trips gather the buffer's rows in that order, ``c`` at a
+time behind a halo of one tile, each row adds those of its predecessors that
+are its token's (a run is of ``min(top_k, held)`` rows at most; times their
+weights, in float32, one fused pass, no ``[N, top_k, D]`` array and no
+scatter-add), and each token
+reads the last row of its run, one gather of ``N`` rows: ``S + N`` rows moved
+a pass, exact at any routing, ``S = 0`` and ``S = top_k * N`` included
+(``top_k`` gathers of ``N`` rows, one a slot rank and every one over all the
+tokens, moved ``top_k * N``: doc/long_context.md has both forms' readings at
+0.5 to 2 held slots a token). The loop has no reverse rule, so the backward is
+written here and walks the same trips: a slot's output gradient is its token's
+row of ``g`` (a gather, token order -> expert order), its weight's gradient a
+row dot product in expert order, the kernels' gradients are summed over the
+trips in float32, and the input's gradient comes back as the forward's output
+does; it keeps the layer's inputs and the routing's small arrays and forms the
+two first products again, so a block that is recomputed anyway
+(``remat_blocks``) has nothing to recompute here. ``c`` follows from the
+shapes (:func:`_chunk_rows`: half the even share ``top_k * N * held / E``).
 ``slots_held`` counts the slots computed, ``slots_moved`` the rows the trips
-carried (``ceil(S / c) * c``).
+carried (``ceil(S / c) * c``). Forward and backward each run under one
+``cond`` on a slot being held, and the walk's buffers (``top_k * N`` rows each,
+whatever the trips write) are allocated inside its branch: an allocation that
+depends on nothing is one the compiler places where it likes, and it placed
+every layer's at the start of the step.
 
 The layer that holds every expert keeps the single-shot path above:
 ``top_k * N`` is then the exact number of rows, known when the step is built,
@@ -145,6 +159,11 @@ def _permute_bwd(inverse, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+def _tile_rows(dtype) -> int:
+    """The rows of the dtype's sublane tile: 8 of 32 bits."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def _chunk_rows(slots: int, held: int, experts: int, dtype) -> int:
     """Rows a trip of the held share's walk carries: half the even share
     ``slots * held / experts`` (even routing takes two trips, the held
@@ -155,7 +174,7 @@ def _chunk_rows(slots: int, held: int, experts: int, dtype) -> int:
     trip), a trip's rows are what the step's temporaries grow with, and the
     rows of the last trip after the held slots cost next to nothing:
     doc/long_context.md has the measurements."""
-    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    tile = _tile_rows(dtype)
     return max(tile, -(-slots * held // (experts * 2 * tile)) * tile)
 
 
@@ -212,41 +231,101 @@ def _put(buffer, rows, lo):
     return jax.lax.dynamic_update_slice_in_dim(buffer, rows, lo, axis=0)
 
 
-def _to_tokens(buffer, inverse, held, top_k, weights=None):
-    """Expert-order rows ``[rows, D]``, of which the first ``held`` are
-    written, -> ``[N, D]`` float32: each token's sum over its ``top_k``
-    slots' rows (times ``weights [N, top_k]``), a slot of no held expert
-    adding nothing. The one gather of ``top_k * N`` rows a pass keeps, as
-    ``top_k`` gathers of ``N`` rows: no ``[N, top_k, D]`` array is laid out
-    (``top_k`` is no multiple of a tile's 8 sublanes)."""
-    index = inverse.reshape(-1, top_k)
-    total = 0.0
-    for j in range(top_k):
-        rows = jnp.where((index[:, j] < held)[:, None],
-                         _rows_of(buffer, index[:, j]), 0)
-        rows = rows.astype(jnp.float32)
-        total = total + (rows if weights is None
-                         else rows * weights[:, j, None])
-    return total
+def _runs_by_token(inverse, total, top_k: int, held: int, chunk: int, dtype):
+    """The held positions ``[0, total)`` of the sorted order, sorted by
+    token: (the slots ``[halo + rows]`` and their positions in the sorted
+    order, ``halo`` entries of no token first (a run's ``min(top_k, held) -
+    1`` predecessors, up to a tile), then each token's held slots in a run,
+    the slots of no held expert last, ``rows`` a :class:`_Walk`'s; for each
+    token ``[N]`` where its run ends, -1 where it holds no slot). Integers
+    only, one sort; the forward's and the backward's return to token order
+    share it."""
+    slots = inverse.shape[0]
+    rows = -(-slots // chunk) * chunk
+    tile = _tile_rows(dtype)
+    halo = -(-(min(top_k, held) - 1) // tile) * tile
+    here = inverse < total
+    slot, position = jax.lax.sort(
+        (jnp.where(here, jnp.arange(slots, dtype=inverse.dtype), slots),
+         inverse), num_keys=1)
+    pad = (halo, rows - slots)
+    count = jnp.sum(here.reshape(-1, top_k), axis=1)
+    last = jnp.where(count > 0, jnp.cumsum(count) - 1, -1)
+    return (jnp.pad(slot, pad, constant_values=-1),
+            jnp.pad(position, pad), last)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _held_share(h, weights, gate, up, down, order, inverse, sizes,
+def _runs_to_tokens(buffer, runs, walk, top_k, held, weights=None):
+    """Expert-order rows ``[rows, D]``, of which the walk's trips wrote the
+    first, -> ``[N, D]`` of the rows' dtype: each token's float32 sum over
+    its held slots' rows (times ``weights [N, top_k]``), a token of no held
+    slot reading nothing. The walk's trips again, each gathering ``chunk``
+    rows of ``buffer`` in token order (``runs``: :func:`_runs_by_token`; and
+    the halo before them, so that a run may cross a trip's edge); a row then
+    adds those of its ``min(top_k, held) - 1`` predecessors that are its
+    token's, in one pass; a token reads the last row of its run, one gather
+    of ``N`` rows. What the last trip sums after the held slots, nothing
+    reads."""
+    slots, positions, last = runs
+    run = min(top_k, held)
+    halo = slots.shape[0] - walk.rows
+    wide = walk.chunk + halo
+    flat = None if weights is None else weights.reshape(-1)
+
+    def body(t, summed):
+        lo = t * walk.chunk
+        slot = jax.lax.dynamic_slice(slots, (lo,), (wide,))
+        rows = _rows_of(buffer, jax.lax.dynamic_slice(
+            positions, (lo,), (wide,)))
+        weight = None if flat is None else _rows_of(
+            flat, jnp.clip(slot, 0, flat.shape[0] - 1))[:, None]
+        token = slot // top_k
+
+        def back(by):
+            # the trip's rows, or those ``by`` before them: cut first, so
+            # that no float32 copy of the gathered rows is laid out
+            cut = slice(halo - by, wide - by)
+            part = rows[cut].astype(jnp.float32)
+            return part if weight is None else part * weight[cut]
+
+        total = back(0)
+        for by in range(1, run):
+            total = total + jnp.where(
+                (token[halo - by:wide - by] == token[halo:])[:, None],
+                back(by), 0)
+        return _put(summed, total.astype(buffer.dtype), lo)
+
+    summed = walk.run(body, walk.buffer(buffer.dtype, buffer.shape[1]))
+    return jnp.where((last >= 0)[:, None],
+                     _rows_of(summed, jnp.maximum(last, 0)), 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _held_share(h, weights, gate, up, down, order, inverse, sizes, runs,
                 top_k: int, activation: str, chunk: int):
     """What the held experts give each token: ``sum_j weights[n, j] *
     expert(h[n])`` over the token's slots ``j`` on a held expert, by a walk
     over the held slots, ``chunk`` of them a trip. A trip gathers its slots'
     token rows, runs the three grouped products over the held groups' sizes
     clipped to the trip and writes its rows of the expert-order output;
-    :func:`_to_tokens` brings the output to token order. The backward walks
-    the same trips (a ``while`` with a run-time bound has no reverse rule, so
-    it is written here): a slot's output gradient is its token's row of ``g``
+    :func:`_runs_to_tokens` brings the output to token order (``runs``: the
+    index of :func:`_runs_by_token`). The backward walks the same trips (a
+    ``while`` with a run-time bound has no reverse rule, so it is written
+    here): a slot's output gradient is its token's row of ``g``
     (a gather, token order -> expert order) times its weight, its weight's
     gradient a row dot product in expert order, the kernels' gradients add up
     over the trips in float32, the input's gradient comes back to token order
     as the output does. It keeps the inputs alone and forms both first
     products again: under a recomputed block (``remat_blocks``) the
-    recomputed forward then has nothing to do."""
+    recomputed forward then has nothing to do.
+
+    Either pass runs under ONE ``cond`` on a slot being held (with none the
+    result is zeros, exactly). The branch is also where the walk's buffers
+    are allocated, ``top_k * N`` rows each whatever the trips will write:
+    allocated outside, nothing they depend on kept the compiler from placing
+    every layer's allocations at the start of the step, where one
+    configuration's step held eight of them, 4.3 GiB, from its first
+    instruction to their layers' turn (PERF.md, PR 58)."""
     walk = _Walk(order, sizes, chunk)
     firsts, down = _kernels(h.dtype, gate, up, down)
 
@@ -262,20 +341,23 @@ def _held_share(h, weights, gate, up, down, order, inverse, sizes,
             out = jnp.where(live, jax.lax.ragged_dot(act, down, part), 0)
         return _put(out_rows, out, lo)
 
-    out = walk.run(body, walk.buffer(h.dtype, h.shape[1]))
-    with jax.named_scope("combine"):
-        return _to_tokens(out, inverse, walk.total, top_k,
-                          weights).astype(h.dtype)
+    def held():
+        out = walk.run(body, walk.buffer(h.dtype, h.shape[1]))
+        with jax.named_scope("combine"):
+            return _runs_to_tokens(out, runs, walk, top_k, sizes.shape[0],
+                                   weights)
+
+    return jax.lax.cond(walk.total > 0, held, lambda: jnp.zeros_like(h))
 
 
-def _held_share_fwd(h, weights, gate, up, down, order, inverse, sizes,
+def _held_share_fwd(h, weights, gate, up, down, order, inverse, sizes, runs,
                     top_k, activation, chunk):
-    inputs = (h, weights, gate, up, down, order, inverse, sizes)
+    inputs = (h, weights, gate, up, down, order, inverse, sizes, runs)
     return _held_share(*inputs, top_k, activation, chunk), inputs
 
 
 def _held_share_bwd(top_k, activation, chunk, residuals, g):
-    h, weights, gate, up, down, order, inverse, sizes = residuals
+    h, weights, gate, up, down, order, inverse, sizes, runs = residuals
     walk = _Walk(order, sizes, chunk)
     dtype, dim, f = h.dtype, h.shape[1], up.shape[-1]
     firsts, last = _kernels(dtype, gate, up, down)
@@ -320,19 +402,29 @@ def _held_share_bwd(top_k, activation, chunk, residuals, g):
         return (_put(d_xs_rows, d_xs, lo), _put(d_weight_rows, d_weight, lo),
                 d_kernels)
 
-    d_xs, d_weight, d_kernels = walk.run(body, (
-        walk.buffer(dtype, dim), walk.buffer(jnp.float32),
-        tuple(jnp.zeros(w.shape, jnp.float32) for w in kernels)))
-    d_kernels = tuple(d.astype(w.dtype) for d, w in zip(
-        d_kernels, (up, down) if gate is None else (gate, up, down)))
+    given = (up, down) if gate is None else (gate, up, down)
+
+    def held():
+        d_xs, d_weight, d_kernels = walk.run(body, (
+            walk.buffer(dtype, dim), walk.buffer(jnp.float32),
+            tuple(jnp.zeros(w.shape, jnp.float32) for w in kernels)))
+        d_kernels = tuple(d.astype(w.dtype) for d, w in zip(d_kernels, given))
+        with jax.named_scope("dispatch"):
+            # a token's gradient is the sum over its held slots
+            d_h = _runs_to_tokens(d_xs, runs, walk, top_k, sizes.shape[0])
+        d_weights = jnp.where(inverse < walk.total,
+                              _rows_of(d_weight, inverse),
+                              0).reshape(weights.shape)
+        return (d_h, d_weights) + d_kernels
+
+    def none():
+        return (jnp.zeros_like(h), jnp.zeros_like(weights)) + tuple(
+            jnp.zeros_like(w) for w in given)
+
+    d = jax.lax.cond(walk.total > 0, held, none)
     if gate is None:
-        d_kernels = (None,) + d_kernels
-    with jax.named_scope("dispatch"):
-        # a token's gradient is the sum over its top_k slots
-        d_h = _to_tokens(d_xs, inverse, walk.total, top_k).astype(dtype)
-    d_weights = jnp.where(inverse < walk.total, _rows_of(d_weight, inverse),
-                          0).reshape(weights.shape)
-    return (d_h, d_weights) + d_kernels + (None, None, None)
+        d = d[:2] + (None,) + d[2:]
+    return d + (None, None, None, None)
 
 
 _held_share.defvjp(_held_share_fwd, _held_share_bwd)
@@ -509,8 +601,12 @@ class MoE(nn.Module):
             order = jnp.argsort(slots, stable=True)
             inverse = jnp.argsort(order)
         if share:
+            with jax.named_scope("dispatch"):
+                runs = _runs_by_token(inverse, slots_held, k, held, chunk,
+                                      self.dtype)
             y = _held_share(h.astype(self.dtype), weights, gate, up, down,
-                            order, inverse, sizes, k, self.activation, chunk)
+                            order, inverse, sizes, runs, k, self.activation,
+                            chunk)
             return self._with_shared(y, h).reshape(*lead, dim), aux
 
         # every expert held: top_k * N is the exact number of rows, in one go

@@ -552,10 +552,11 @@ def test_a_model_without_streams_gets_no_key(monkeypatch):
 # family (e3766c2) with ``_step_text``. The other four are held, with the
 # hashes they had on that commit too, by ``tests/test_ssm_moe_lm.py`` (and
 # ``tests/test_mla_moe_lm.py``), which this PR leaves as they are. A PR that
-# means to change one of these programs replaces its line.
+# means to change one of these programs replaces its line (PR 58 its one
+# line: a share's held rows come back to token order in runs).
 PARENT_STEP = {
     "nemotron-3-nano-30b-a3b":
-        "38a10a96a56c090869a47dc6863bbc1ab6968dded88190c0e649e5966207aee2",
+        "995c7f6fa1cba806517977478e42725000ab485798384984048823bc818ee103",
 }
 
 

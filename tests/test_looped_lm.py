@@ -397,12 +397,13 @@ def test_the_lowered_step_holds_each_layer_once_whatever_the_passes():
 # (322b140) with ``_step_text``. The others are held, with the hashes they
 # had, by ``tests/test_blockdiff_moe_lm.py``, ``tests/test_ssm_moe_lm.py``
 # and ``tests/test_mla_moe_lm.py``, which this PR leaves as they are. A PR
-# that means to change one of these programs replaces its line.
+# that means to change one of these programs replaces its line (PR 58 both:
+# a share's held rows come back to token order in runs).
 PARENT_STEP = {
     "sdar-30b-a3b-chat":
-        "fa5a3aa31cc6c3a0eb6a3be22cd4a6dc1b6d0cbad5b4e9ce1d2de3ef810eee21",
+        "c522fafd2608364c82b3a2b6fbae56dda36091c1950cef00e58182155d0675f9",
     "trinity-mini":
-        "f025738ce9d12cdf712f0f3ccd0b1f429471430c7400ae420f61dbd3b8d5e698",
+        "4f799eb1e2a9e31cd1af83144d905c948f49bc7b8109d28fee20c8d37a5a9114",
 }
 
 

@@ -673,16 +673,17 @@ def test_an_expert_trip_forward_is_two_grouped_products(grouped_products):
 # no source locations; every op on its ``jax.numpy`` path) for the four older
 # families' CPU cuts, computed on the commit before this family (6e8d15d)
 # with ``_step_text``. A PR that means to change one of these programs
-# replaces its line.
+# replaces its line (PR 58 the three of the models that hold a share of
+# their experts: its held rows come back to token order in runs).
 PARENT_STEP = {
     "olmoe-1b-7b":
         "7116221b2cffe66cee500a7f382bd80cd42ca76514520e3b205d118bb54d4d84",
     "smallthinker-21b-a3b":
-        "b99d14d9db45a0e37eab3ecf7371976f77704f5a16fed54a871e0d8ffe2ab42a",
+        "854aaa17c4f423b181dc82fe57fa78e3689f724452461c93cf781cb0b9806a27",
     "trinity-mini":
-        "f025738ce9d12cdf712f0f3ccd0b1f429471430c7400ae420f61dbd3b8d5e698",
+        "4f799eb1e2a9e31cd1af83144d905c948f49bc7b8109d28fee20c8d37a5a9114",
     "kanana-2-30b-a3b":
-        "4a89659b2364d9de464b8b90da2cae401375f066d284ab2ce6d7ce904272ea4a",
+        "b7799bd0596e87de6c326f210ba3bfdb752df8beca49759474efecb6d5e8535b",
 }
 
 

@@ -825,14 +825,49 @@ def _primitives_over(jaxpr, rows, seen=None):
     return seen
 
 
-def test_a_share_moves_no_full_size_rows_but_the_gather_to_token_order():
+def _allocations(jaxpr, under=False, seen=None):
+    """(rows, whether under a ``cond``) of every ``empty`` in a jaxpr and
+    its sub-jaxprs."""
+    import jax
+    seen = [] if seen is None else seen
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "empty":
+            seen.append((eqn.outvars[0].aval.shape[0], under))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _allocations(sub, under or eqn.primitive.name == "cond", seen)
+    return seen
+
+
+def test_the_walks_buffers_are_allocated_under_the_cond_on_a_held_slot():
+    """Forward and backward each run under one ``cond`` on a slot being held,
+    and every buffer of ``top_k * N`` rows is allocated inside its branch:
+    an allocation with no operand outside it is one the compiler may place
+    at the start of the step (it did, every layer's at once)."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    full, _, m, _ = _expert_layer()
+    kernels, (logits, _) = _share_of(full, 2, 4), _routed("random")
+    layer = moe.MoE(8, 3, 16, first_expert=2, experts_held=4,
+                    activation="relu", normalize_top_k=True)
+    text = jax.make_jaxpr(jax.grad(lambda k, lg, m: jnp.sum(
+        layer.apply({"params": k}, m, lg)[0]), (0, 1, 2)))(kernels, logits, m)
+    found = _allocations(text.jaxpr)
+    # expert order and token order, forward and backward, and the weights'
+    # gradients' rows
+    assert sorted(found) == [(160, True)] * 5
+    assert str(text).count(" cond[") == 2
+
+
+def test_a_share_moves_no_full_size_rows_and_one_gather_of_the_tokens_rows():
     """The jaxpr of a share's forward and backward: arrays of ``top_k * N``
     rows of width D or F are the buffers the trips write into (uninitialised,
     updated a trip's rows at a time, carried by the loops) and nothing else:
     no gather, no ``where``, no elementwise op and no product over them. Rows
-    come back to token order as ``top_k`` gathers of ``N`` rows a pass, the
-    forward's and its mirror in the backward. The full-size formulation has
-    all of those."""
+    come back to token order a trip of held rows at a time (a gather of a
+    trip and its halo, summed in runs) and then as ONE gather of ``N`` rows a
+    pass, the forward's and its mirror in the backward, not ``top_k``. The
+    full-size formulation has all of those."""
     import jax
     import jax.numpy as jnp
     from raydp_tpu.models import moe
@@ -854,14 +889,198 @@ def test_a_share_moves_no_full_size_rows_but_the_gather_to_token_order():
     walk = ops(lambda k, lg, m: layer.apply({"params": k}, m, lg)[0], 144)
     assert set(walk) == {"empty", "dynamic_update_slice", "while"}, walk
     # the forward's output rows and the backward's gradient rows, in expert
-    # order (144 slots in trips of 40: 160 rows); nothing of width F is kept
-    assert sorted(walk["empty"]) == [(160, 32), (160, 32)]
+    # order and then summed in runs by token (144 slots in trips of 40: 160
+    # rows); nothing of width F is kept
+    assert sorted(walk["empty"]) == [(160, 32)] * 4
     by_tokens = ops(lambda k, lg, m: layer.apply({"params": k}, m, lg)[0], 40)
-    assert by_tokens["gather"].count((48, 32)) == 2 * 3
-    assert {s[0] for s in by_tokens["gather"]} == {48, 40}     # N, a trip
+    # a trip's token rows forward, and again with the gradient's backward
+    # (40 rows); a pass's trip of held rows behind its halo of 8 and its one
+    # gather of the N tokens' rows (48 rows both, here), forward and backward
+    assert sorted(by_tokens["gather"]) == [(40, 32)] * 3 + [(48, 32)] * 4
     full_size = ops(lambda k, lg, m: _full_size_share(k, lg, m, 2, 4), 144)
     assert len(full_size["gather"]) == 4 and len(full_size["select_n"]) >= 4
     assert {"ragged_dot_general", "max", "mul"} <= set(full_size)
+
+
+# experts 2-3 of 8 held, 3 a token over 48 tokens: at most one held slot a
+# token in the mean, so the held rows come back to token order in runs; a trip
+# carries 24 rows (half the even share of 36, up to a tile), a run is of two
+def _held_by(routing, seed=0):
+    """Seeded logits [48, 8] whose top three give token ``t`` the held
+    experts that ``routing(t)`` names (of 2 and 3), and the slots held."""
+    logits = np.random.default_rng(seed).normal(size=(48, 8))
+    if routing is None:
+        ids = np.argsort(-logits, axis=1)[:, :3]
+        return logits.astype(np.float32), int(np.isin(ids, [2, 3]).sum())
+    logits[:, [0, 1, 4]] += 20
+    for t in range(48):
+        logits[t, list(routing(t))] += 40
+    return logits.astype(np.float32), sum(len(routing(t)) for t in range(48))
+
+
+RUN_ROUTINGS = {
+    "as_seeded": (None, None),
+    "no_slot_held": (lambda t: (), 0),
+    "every_token_a_whole_run": (lambda t: (2, 3), 96),
+    # ranks 0-24: the twenty-fifth row is alone in the second trip
+    "one_row_past_a_trip": (lambda t: (2 + t % 2,) if t < 25 else (), 25),
+    # tokens 0-22 hold one slot, the others two: token 23's run lies on
+    # ranks 23 and 24, either side of the first trip's edge, token 35's on
+    # 47 and 48, either side of the second's
+    "a_run_across_a_trips_edge":
+        (lambda t: (2 + t % 2,) if t < 23 else (2, 3), 23 + 2 * 25),
+}
+
+
+def _every_held_expert_on_every_token(kernels, logits, m, gated):
+    """The share of experts 2-3 with no dispatch at all: each held expert on
+    all the tokens, times the weight the token gives it (0 for most)."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    _, ids, weights = moe.route(logits, 3, True)
+    out = 0.0
+    for i, expert in enumerate((2, 3)):
+        hidden = jax.nn.relu(m @ kernels["experts_up"][i])
+        if gated:
+            hidden = jax.nn.relu(m @ kernels["experts_gate"][i]) \
+                * (m @ kernels["experts_up"][i])
+        weight = jnp.sum(jnp.where(ids == expert, weights, 0), axis=1)
+        out = out + weight[:, None] * (hidden @ kernels["experts_down"][i])
+    return out
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "two_matrices"])
+@pytest.mark.parametrize("routing", list(RUN_ROUTINGS))
+def test_the_runs_give_every_held_experts_sum_at_any_routing(routing, gated):
+    """Output and all five gradients (the experts' input, the weights through
+    the handed-in logits, gate, up and down) of a share, against every held
+    expert run on every token and, gated, against the full-size formulation:
+    with no slot held (zeros, exactly), every token holding a whole run, one
+    row past a trip, a run that lies across a trip's edge, and as seeded;
+    gated experts and experts of two matrices."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    full, _, m, _ = _expert_layer(seed=7)
+    kernels = _share_of(full, 2, 2)
+    if not gated:
+        del kernels["experts_gate"]
+    by, want_held = RUN_ROUTINGS[routing]
+    logits, held = _held_by(by)
+    assert want_held in (None, held)
+    assert moe._chunk_rows(144, 2, 8, np.float32) == 24
+    layer = moe.MoE(8, 3, 16, first_expert=2, experts_held=2,
+                    activation="relu", normalize_top_k=True, gated=gated)
+    w = np.random.default_rng(9).normal(size=m.shape).astype(np.float32)
+
+    def graded(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda k, lg, m: jnp.sum(fn(k, lg, m) * w), (0, 1, 2)))(
+                kernels, logits, m)
+
+    def share(k, lg, m):
+        return layer.apply({"params": k}, m, lg)[0]
+
+    y, aux = layer.apply({"params": kernels}, m, logits)
+    assert float(aux["slots_held"]) == held
+    got = graded(share)
+    assert len(_leaves(got[1])) == (5 if gated else 4)
+    others = [functools.partial(_every_held_expert_on_every_token,
+                                gated=gated)]
+    if gated:
+        others.append(lambda k, lg, m: _full_size_share(k, lg, m, 2, 2))
+    for other in others:
+        np.testing.assert_allclose(y, other(kernels, logits, m),
+                                   rtol=1e-4, atol=1e-5)
+        _close(got[1], graded(other)[1])
+    if held == 0:
+        assert not np.any(np.asarray(y))
+        assert not any(np.any(g) for g in _leaves(got[1]).values())
+    else:
+        assert np.abs(np.asarray(got[1][2])).max() > 1e-4
+        # a token's one held expert has all its weight: nothing to move
+        assert routing == "one_row_past_a_trip" \
+            or np.abs(np.asarray(got[1][1])).max() > 1e-4
+
+
+def _sorted_slots(logits):
+    """(order, inverse) of the 144 slots sorted with experts 2-3 first."""
+    from raydp_tpu.models import moe
+    _, ids, _ = moe.route(logits, 3, True)
+    order = np.argsort((np.asarray(ids).reshape(-1) - 2) % 8, kind="stable")
+    return order.astype(np.int32), np.argsort(order).astype(np.int32)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("routing", list(RUN_ROUTINGS))
+def test_the_runs_sum_what_top_k_gathers_of_the_tokens_rows_sum(
+        routing, weighted, dtype):
+    """The walk's return to token order alone, on a buffer of seeded rows
+    with NaN in every row past the held slots: each token's sum over its
+    ``top_k`` slots' rows with the absent ones left out (the ``top_k``
+    gathers of ``N`` rows this form replaced, in numpy), with the router's
+    weights (the forward's call) and without (the backward's); float32 rows
+    in trips of 24 behind a halo of 8, bfloat16 rows in trips of 32 behind
+    their tile of 16, summed in float32 and cast once."""
+    import jax
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    logits, held = _held_by(RUN_ROUTINGS[routing][0])
+    order, inverse = _sorted_slots(logits)
+    chunk = moe._chunk_rows(144, 2, 8, dtype)
+    assert chunk == {"float32": 24, "bfloat16": 32}[dtype]
+    rng = np.random.default_rng(3)
+    sizes = np.asarray([held - held // 2, held // 2], np.int32)
+    walk = moe._Walk(jnp.asarray(order), jnp.asarray(sizes), chunk)
+    rows = rng.normal(size=(walk.rows, 32)).astype(np.float32)
+    rows = np.array(jnp.asarray(rows, dtype), np.float32)
+    rows[held:] = np.nan
+    weights = rng.uniform(0.5, 1.5, (48, 3)).astype(np.float32) \
+        if weighted else None
+    index = inverse.reshape(48, 3)
+    want = np.zeros((48, 32), np.float32)
+    for j in range(3):
+        picked = np.where((index[:, j] < held)[:, None],
+                          np.nan_to_num(rows[index[:, j]]), 0)
+        want += picked if weights is None else picked * weights[:, j, None]
+    got = jax.jit(lambda buffer, inverse, total: moe._runs_to_tokens(
+        buffer, moe._runs_by_token(inverse, total, 3, 2, chunk, dtype),
+        walk, 3, 2, weights))(jnp.asarray(rows, dtype), inverse, held)
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (48, 32)
+    tol = {"float32": 1e-6, "bfloat16": 2.0 ** -8}[dtype]
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
+    assert (held == 0) == (not np.any(np.asarray(got, np.float32)))
+
+
+def test_the_runs_index_lists_each_tokens_held_slots_where_they_lie():
+    """The index the forward and the backward share: after the halo of no
+    token, the held positions by token, each token's in a run, and where each
+    token's run ends (-1: no slot held); what follows the held slots is of
+    no token either."""
+    import jax.numpy as jnp
+    from raydp_tpu.models import moe
+    logits, held = _held_by(RUN_ROUTINGS["a_run_across_a_trips_edge"][0])
+    order, inverse = _sorted_slots(logits)
+    slot, position, last = moe._runs_by_token(
+        jnp.asarray(inverse, jnp.int32), held, 3, 2, 24, np.float32)
+    slot, position, last = map(np.asarray, (slot, position, last))
+    halo = 8                                # one predecessor, up to a tile
+    assert slot.shape == position.shape == (halo + 144,)
+    assert np.all(slot[:halo] == -1) and np.all(slot[halo + held:] == 144)
+    token = slot[halo:halo + held] // 3
+    assert np.all(np.diff(token) >= 0)                  # sorted by token
+    assert sorted(position[halo:halo + held]) == list(range(held))
+    assert np.all(order[position[halo:halo + held]] == slot[halo:halo + held])
+    assert list(token[22:26]) == [22, 23, 23, 24]       # across rank 24
+    assert list(last[:24]) == list(range(23)) + [24]
+    assert np.all(token[last[23:]] == np.arange(23, 48))
+    none_held = moe._runs_by_token(jnp.asarray(inverse, jnp.int32), 0, 3, 2,
+                                   24, np.float32)
+    assert np.all(np.asarray(none_held[2]) == -1)
 
 
 @pytest.mark.parametrize("handed_in", [False, True],
