@@ -499,8 +499,7 @@ class DeviceEpochCache:
     def make_epoch_fn(self, step, batch_size: int, shuffle: bool,
                       batch_sharding=None, seq_sharding=None):
         """Build THE resident epoch program both estimators jit — one source
-        for the permutation/slice/constraint/scan logic so the flax and keras
-        twins cannot drift.
+        for the permutation/slice/constraint/scan logic.
 
         ``step(carry, batch) -> carry`` is the caller's train step in scan
         form. Returns ``(epoch_fn, steps_per_epoch)`` with
@@ -577,9 +576,10 @@ class DeviceEpochCache:
     def eligible(cls, dataset,
                  columns: Dict[str, Tuple[ColumnSpec, np.dtype]],
                  batch_size: int, drop_last: bool) -> bool:
-        """THE residency gate — the single decision every call site (fit, the
-        fit_on_frame shuffle-skip, the keras twin) must share, or a drifted
-        copy could e.g. skip the dataset-level shuffle while fit() streams.
+        """THE residency gate — the single decision every call site (the feed
+        plan of ``train/loop.py``, the fit_on_frame shuffle-skip) must share, or
+        a drifted copy could e.g. skip the dataset-level shuffle while fit()
+        streams.
         Requires: opted in, single process (a gang rank only holds its shard —
         global batches there need the per-rank feed), static full batches
         (``drop_last`` with at least one batch of rows), and decoded arrays

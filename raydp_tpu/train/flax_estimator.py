@@ -21,8 +21,7 @@ Parity map (reference torch/estimator.py):
 
 from __future__ import annotations
 
-import contextlib
-import os
+import functools
 import tempfile
 import time
 from dataclasses import dataclass, field
@@ -30,13 +29,13 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from raydp_tpu import faults, knobs, profiler
+from raydp_tpu import knobs, profiler
 from raydp_tpu import metrics as rdt_metrics
 from raydp_tpu.log import get_logger
+from raydp_tpu.train import loop
 from raydp_tpu.train.estimator import (
     EstimatorInterface,
     FrameEstimatorInterface,
-    save_epoch_now,
 )
 from raydp_tpu.train.metrics import (Metric, build_metrics,
                                      model_counters)
@@ -76,6 +75,26 @@ def _init_variables(model, rng, inputs0):
 
     kwargs = {"train": False} if _takes_train(model) else {}
     return jax.jit(lambda key, x: model.init(key, x, **kwargs))(rng, inputs0)
+
+
+@functools.lru_cache(maxsize=None)
+def _state_class():
+    """``TrainState`` with the collection a model with BatchNorm carries
+    beside its params."""
+    from flax.training import train_state
+
+    class _State(train_state.TrainState):
+        batch_stats: Any = None
+
+    return _State
+
+
+def _init_state(model, tx, rng, inputs0):
+    """A state from nothing: :func:`_init_variables` under the optimizer."""
+    variables = _init_variables(model, rng, inputs0)
+    return _state_class().create(
+        apply_fn=model.apply, params=variables["params"], tx=tx,
+        batch_stats=variables.get("batch_stats"))
 
 
 def _cast_floating(inputs, dtype):
@@ -645,7 +664,7 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
 
 
 def _epoch_zeros(mesh, metrics, sums: int = 1):
-    """The program that makes an epoch's starting accumulators, ``(sums,
+    """The program that makes an epoch's starting accumulators, ``(*sums,
     mstats)``: ``sums`` f32 scalar zeros and every metric's ``init()``, each
     leaf strong-typed (``Metric.init`` gives Python floats) and replicated on
     ``mesh``: the types the step returns them with, so the step's first call
@@ -660,11 +679,39 @@ def _epoch_zeros(mesh, metrics, sums: int = 1):
     from raydp_tpu.parallel.mesh import replicated
 
     def zeros():
-        return (tuple(jnp.zeros((), jnp.float32) for _ in range(sums)),
+        return (*(jnp.zeros((), jnp.float32) for _ in range(sums)),
                 tuple(jax.tree.map(lambda x: jnp.array(np.asarray(x)),
                                    m.init()) for m in metrics))
 
     return jax.jit(zeros, out_shardings=replicated(mesh))
+
+
+def _metric_pairs(metrics, mstats):
+    """A report's (name, value) pairs of the metrics' states, fetched and
+    computed as they are iterated; a metric that publishes elsewhere (the
+    model's counters) gives None and no pair."""
+    import jax
+
+    for m, s in zip(metrics, mstats):
+        value = m.compute(jax.tree.map(np.asarray, s))
+        if value is not None:
+            yield m.name, value
+
+
+def _carry_hooks(jit_train, epoch_zeros, metrics):
+    """The loop's ``(step, zeros, read)`` (``loop.Trainee``) round the jitted
+    train step: the carry is what ``jit_train`` returns, ``(state, loss_sum,
+    mstats)``, handed back to it in its own argument order."""
+    def step(carry, batch):
+        return jit_train(carry[0], batch, carry[2], carry[1])
+
+    def zeros(carry):
+        return (carry[0], *epoch_zeros())
+
+    def read(carry):
+        return carry[1], _metric_pairs(metrics, carry[2])
+
+    return step, zeros, read
 
 
 class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
@@ -882,99 +929,26 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
     # -------------------------------------------------------------------- fit
     def fit(self, train_ds, evaluate_ds=None, max_retries: int = 0
             ) -> TrainingResult:
-        from raydp_tpu.data.feed import DeviceEpochCache, DeviceFeed
-
         profiler.watch_jit_builds()
         mesh = self._build_mesh()
-        columns = self._columns()
         ckpt_dir = self.checkpoint_dir or tempfile.mkdtemp(prefix="rdt-ckpt-")
-
-        # pad-and-mask rule, decided HERE for every feed below so train and
-        # eval cannot disagree: under a >1 data extent a ragged tail pads to
-        # a full (shardable) batch and carries a validity mask instead of
-        # silently dropping rows. A >1 STAGE extent needs the same rule for
-        # a different reason: the pipelined forward reshapes every batch
-        # into accum_steps microbatches, so a ragged tail must pad to the
-        # (divisible) full batch — its pad rows mask out of the loss exactly
-        # like dp pad rows. RDT_TRAIN_PAD_TAIL=0 — or a custom loss with no
-        # mask kwarg — restores the drop behavior.
-        from raydp_tpu.parallel.mesh import data_axes, stage_extent
-        dp_total = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
-        stage_total = stage_extent(mesh)
-        pad_tail = (dp_total > 1 or stage_total > 1) \
-            and self._may_pad_tail()
-        use_seq = self._use_seq(mesh)
-
-        # device-resident fast path: dataset pinned in HBM, whole epoch in one
-        # jitted dispatch with on-device shuffling (falls back to the
-        # streaming feed when too large / multi-process / ragged-batch)
-        with profiler.trace("fit:feed", "training") as feed_span:
-            cache = feed = None
-            if DeviceEpochCache.eligible(train_ds, columns, self.batch_size,
-                                         self.drop_last):
-                cache = DeviceEpochCache(train_ds, columns, mesh=mesh)
-            if cache is None:
-                feed = DeviceFeed(
-                    train_ds, self.batch_size, columns, mesh=mesh,
-                    shuffle=self.shuffle, seed=self.seed,
-                    drop_remainder=self.drop_last,
-                    pad_remainder=pad_tail and not self.drop_last,
-                    prefetch_to_device=self.prefetch_to_device, seq=use_seq)
-            eval_feed = eval_cache = None
-            eval_tail_ok = False
-            if evaluate_ds is not None:
-                # the ragged final batch: fine as-is under a size-1 data
-                # extent (and no pipeline — a stage>1 forward cannot reshape
-                # a ragged batch), pad-and-masked under a >1 one (dropped
-                # only when padding is opted out — the pre-PR-16 behavior)
-                eval_tail_ok = (dp_total == 1 and stage_total == 1) \
-                    or pad_tail
-                # eval goes resident alongside the train set: the whole eval
-                # pass becomes one scan dispatch (+ one for the ragged tail)
-                # instead of one dispatch per batch, every epoch. The budget
-                # is COMBINED: train + eval residency together stay under
-                # the cap
-                if (cache is not None
-                        and DeviceEpochCache.eligible(evaluate_ds, columns,
-                                                      1, True)
-                        and cache.nbytes + DeviceEpochCache.estimate_bytes(
-                            evaluate_ds, columns)
-                        <= DeviceEpochCache.cap_bytes()):
-                    eval_cache = DeviceEpochCache(evaluate_ds, columns,
-                                                  mesh=mesh)
-                else:
-                    eval_feed = DeviceFeed(
-                        evaluate_ds, self.batch_size, columns, mesh=mesh,
-                        shuffle=False, drop_remainder=not eval_tail_ok,
-                        pad_remainder=pad_tail,
-                        prefetch_to_device=self.prefetch_to_device,
-                        seq=use_seq)
-            profiler.add_args(
-                feed_span, route="resident" if cache is not None else "stream")
-
-        state, history = self._train_loop(
-            mesh, feed, eval_feed, ckpt_dir, max_retries=max_retries,
-            cache=cache, eval_cache=eval_cache, eval_tail_ok=eval_tail_ok,
-            eval_tail_pad=pad_tail)
+        feeds = loop.plan_feeds(
+            train_ds, evaluate_ds, self._columns(), mesh, self.batch_size,
+            shuffle=self.shuffle, seed=self.seed, drop_last=self.drop_last,
+            prefetch_to_device=self.prefetch_to_device,
+            may_pad=self._may_pad_tail(), seq=self._use_seq(mesh))
+        state, history = self._train_loop(mesh, feeds, ckpt_dir,
+                                          max_retries=max_retries)
         self._result = TrainingResult(state=state, history=history,
                                       checkpoint_dir=ckpt_dir)
         return self._result
 
-    @staticmethod
-    def _place_state(tree, shardings):
-        """Place a host pytree under global shardings (see
-        :func:`raydp_tpu.train.checkpoint.place_tree`)."""
-        from raydp_tpu.train import checkpoint as ckpt
-        return ckpt.place_tree(tree, shardings)
-
-    def _train_loop(self, mesh, feed, eval_feed, ckpt_dir: str,
-                    max_retries: int = 0, resume: bool = False, cache=None,
-                    eval_cache=None, eval_tail_ok: bool = False,
-                    eval_tail_pad: bool = False):
+    def _train_loop(self, mesh, feeds, ckpt_dir: str, max_retries: int = 0,
+                    resume: bool = False):
+        """Build the fit's state and its jitted programs, and hand them to
+        the loop (``train/loop.py``) as its :class:`~loop.Trainee`."""
         import jax
         import jax.numpy as jnp
-        import optax
-        from flax.training import train_state
 
         from raydp_tpu.parallel import batch_sharding, param_sharding_rules
         from raydp_tpu.train import checkpoint as ckpt
@@ -986,38 +960,20 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         loss_fn = _resolve_loss(self._loss)
         metrics = self._metrics
 
+        cache, eval_cache = feeds.cache, feeds.eval_cache
         # ---- init params from one host batch's shapes ----
-        with profiler.trace("fit:feed", "training", what="first_batch"):
-            first = cache.init_row if cache is not None \
-                else next(iter(feed.host_iter), None)
-        if first is None:
-            rows = sum(feed.host_iter.dataset.block_sizes())
-            raise ValueError(
-                f"the training set has {rows} rows and yields no batch of "
-                f"{self.batch_size} (drop_last={self.drop_last}): no step "
-                f"would run. Lower batch_size, or pass drop_last=False to "
-                f"train on a ragged batch.")
+        first = feeds.first_batch(self.batch_size, self.drop_last)
         with profiler.trace("fit:init", "training"):
             inputs0, _ = self._split_batch(
                 {k: jnp.asarray(v[:1]) for k, v in first.items()})
             rng = jax.random.PRNGKey(self.seed)
             takes_train = _takes_train(model)
-            variables = _init_variables(model, rng, inputs0)
-            batch_stats = variables.get("batch_stats")
-
-            class _State(train_state.TrainState):
-                # models with BatchNorm carry running stats beside params
-                batch_stats: Any = None
-
-            state = _State.create(
-                apply_fn=model.apply, params=variables["params"], tx=tx,
-                batch_stats=batch_stats)
-
-            shardings_of = param_sharding_rules(mesh, self.param_rules)
-            state_sharding = shardings_of(state)
+            state = _init_state(model, tx, rng, inputs0)
+            state_sharding = param_sharding_rules(mesh,
+                                                  self.param_rules)(state)
         from raydp_tpu.parallel.roles import addressable_nbytes
         with profiler.trace("train:place", "training"):
-            state = self._place_state(state, state_sharding)
+            state = ckpt.place_tree(state, state_sharding)
         # the fsdp memory claim, observed where it is true: bytes of params
         # + optimizer state resident on THIS process's devices after
         # placement (replicated leaves count one copy per device)
@@ -1025,7 +981,7 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                             addressable_nbytes(state))
         b_sharding = batch_sharding(mesh)
         # seq-extended sharding for ndim >= 2 batch leaves on the resident
-        # path (the streaming DeviceFeed carries its own — decided in fit());
+        # path (the streaming DeviceFeed carries its own: the feed plan's);
         # None when the mesh has no >1 seq extent
         seq_sharding = batch_sharding(mesh, seq=True) \
             if self._use_seq(mesh) else None
@@ -1043,17 +999,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         if pipelined:
             rdt_metrics.set_gauge("train_pipeline_stages", n_stages)
 
-        # Loss accumulators are threaded THROUGH the jitted steps rather than
-        # collected as a host-side list: under a multi-process gang, an eager
-        # op over global arrays (e.g. jnp.stack of per-step losses) is a
-        # cross-process computation that every process must dispatch in the
-        # same order — a rank that is one step behind deadlocks the gang. With
-        # in-jit accumulation the only host reads are float() of replicated
-        # scalars at epoch end (also one fewer host sync single-process).
-        # An epoch's zeros come from a program too (``_epoch_zeros``), typed
-        # as the step returns them: replicated on the fit's mesh, strong f32.
-        # A host zero names no mesh, and the call that took one would be a
-        # signature, and a build of the step, of its own.
         loss_fn = _step_loss(_apply, loss_fn)      # eval_step's, below
         # what the model's own loss counts rides the train metrics' slot:
         # summed inside the step, fetched with the epoch's loss
@@ -1112,310 +1057,82 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
 
         jit_train = jax.jit(train_step, donate_argnums=(0, 3))
         jit_eval = jax.jit(eval_step, donate_argnums=(3, 4))
-        train_zeros = _epoch_zeros(mesh, train_metrics)
         eval_zeros = _epoch_zeros(mesh, metrics, sums=2)
 
-        step_span = profiler.step
-        first_dispatch = [True]
+        def restore(carry, max_step):
+            restored = ckpt.restore_placed(ckpt_dir, carry[0], state_sharding,
+                                           max_step=max_step)
+            if restored is None:
+                return None
+            extra = ckpt.restore_extra(ckpt_dir, max_step=max_step) or {}
+            return (restored[0], None, None), restored[1], \
+                list(extra.get("history", ()))
 
-        def _dispatch(fn, *args):
-            """Call the fit's step program (the resident epoch scan or the
-            streaming step). Its first call (the program's first build:
-            trace, lower, compile or compile-cache load, all synchronous) is
-            a span, and is where an engaged activation plane publishes the
-            step's temp bytes. A later call whose argument types differ
-            builds the program again, as ``jit:*`` spans under its epoch: the
-            loop gives it none, since every epoch starts from
-            ``_epoch_zeros``'s accumulators, typed as the program's own."""
-            if not first_dispatch[0]:
-                return fn(*args)
-            first_dispatch[0] = False
-            if engaged:
-                _note_activation(fn, *args)
-            with profiler.trace("train:first_dispatch", "training"):
-                return fn(*args)
+        trainee = loop.Trainee(
+            (state, None, None), *_carry_hooks(
+                jit_train, _epoch_zeros(mesh, train_metrics), train_metrics),
+            save=lambda carry, epoch, history: ckpt.save(
+                ckpt_dir, carry[0], step=epoch, extra={"history": history}),
+            restore=restore, fresh=lambda: (ckpt.place_tree(
+                _init_state(model, tx, rng, inputs0), state_sharding),
+                None, None))
 
-        jit_epoch = None
-        cache_steps = 0
         if cache is not None:
-            # device-resident path: the WHOLE epoch is one jitted dispatch
-            # (the shared scan program built by DeviceEpochCache — one source
-            # for the permutation/slice logic across estimators). Steady-state
-            # host work per epoch: one dispatch + one scalar fetch.
+            # the resident epoch: the shared scan program built by
+            # DeviceEpochCache round the step in scan form
             def _step(carry, batch):
                 state, loss_sum, mstats = carry
                 return train_step(state, batch, mstats, loss_sum)
 
-            epoch_fn, cache_steps = cache.make_epoch_fn(
+            epoch_fn, _ = cache.make_epoch_fn(
                 _step, self.batch_size, self.shuffle,
                 batch_sharding=b_sharding, seq_sharding=seq_sharding)
             jit_epoch = jax.jit(epoch_fn, donate_argnums=(0,))
+            trainee.epoch = lambda carry, key: jit_epoch(carry, cache.arrays,
+                                                         key)
+        if engaged:
+            # the step's temp bytes, read off the program the first call is
+            # about to run (the resident scan's, or the streaming step's)
+            if cache is not None:
+                trainee.before_first = lambda carry, key: _note_activation(
+                    jit_epoch, carry, cache.arrays, key)
+            else:
+                trainee.before_first = lambda carry, batch: _note_activation(
+                    jit_train, carry[0], batch, carry[2], carry[1])
 
-        jit_eval_epoch = None
-        eval_tail = None
+        if feeds.eval_feed is not None or eval_cache is not None:
+            # an eval pass's accumulators are what ``jit_eval`` returns and
+            # ``eval_zeros`` makes: (loss sum, row count, mstats)
+            def eval_read(acc):
+                rows = float(acc[1])    # real rows only: pad rows mask to 0
+                yield "loss", float(acc[0]) / rows if rows else float("nan")
+                yield from _metric_pairs(metrics, acc[2])
+
+            trainee.evaluation = ev = loop.Evaluation(
+                zeros=eval_zeros, read=eval_read,
+                step=lambda carry, acc, batch: jit_eval(
+                    carry[0], batch, acc[2], acc[0], acc[1]))
         if eval_cache is not None:
-            # the whole eval pass as ONE scan dispatch, built by the same
-            # make_epoch_fn as the train scan (one source for the
-            # slice/constraint/scan logic); the ragged tail travels as one
-            # extra jitted call — as-is where a single data shard allows it,
-            # zero-padded to a full batch with a validity mask under a >1
-            # data extent (eval_tail_ok/eval_tail_pad, decided in fit()
-            # beside the streaming feed's rule so the two cannot disagree).
-            # The carry rides the state through unchanged — NOT donated (it
-            # lives on into the next epoch)
-            def _eval_scan_step(carry, batch):
-                state, estats, esum, ecnt = carry
-                esum, ecnt, estats = eval_step(state, batch, estats, esum,
-                                               ecnt)
-                return state, estats, esum, ecnt
-
-            eval_epoch_fn, esteps = eval_cache.make_epoch_fn(
-                _eval_scan_step, self.batch_size, shuffle=False,
+            # every full batch of the resident eval set as ONE scan dispatch,
+            # built by the same make_epoch_fn as the train scan (the ragged
+            # tail is the loop's: one more call of the step). The state
+            # rides the scan's carry through, before the accumulators
+            eval_epoch_fn, _ = eval_cache.make_epoch_fn(
+                lambda c, batch: (c[0], *eval_step(c[0], batch, c[3], c[1],
+                                                   c[2])),
+                self.batch_size, shuffle=False,
                 batch_sharding=b_sharding, seq_sharding=seq_sharding)
             jit_eval_epoch = jax.jit(eval_epoch_fn)
-            tail_off = esteps * self.batch_size
-            tail_rows = eval_cache.num_rows - tail_off
-            if tail_rows > 0 and eval_tail_ok:
-                eval_tail = {n: a[tail_off:]
-                             for n, a in eval_cache.arrays.items()}
-                if eval_tail_pad:
-                    from raydp_tpu.data.feed import MASK_KEY
-                    pad = self.batch_size - tail_rows
-                    eval_tail = {
-                        n: jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
-                        for n, a in eval_tail.items()}
-                    eval_tail[MASK_KEY] = (
-                        jnp.arange(self.batch_size) < tail_rows
-                    ).astype(jnp.float32)
+            ev.epoch = lambda carry, acc: jit_eval_epoch(
+                (carry[0], *acc), eval_cache.arrays,
+                jax.random.PRNGKey(0))[1:]      # the key: unused, no shuffle
 
-        history: List[Dict[str, float]] = []
-        epoch = 0
-        retries = 0
-        #: highest checkpoint step THIS run wrote — a retry may only restore
-        #: up to it; a reused dir's stale steps (possibly HIGHER-numbered,
-        #: which latest-step selection would otherwise prefer) are foreign
-        last_written_step: Optional[int] = None
-        if resume:
-            restored = ckpt.restore_placed(ckpt_dir, state, state_sharding)
-            if restored is not None:
-                state, done_epoch = restored
-                epoch = done_epoch + 1
-                extra = ckpt.restore_extra(ckpt_dir)
-                if extra and "history" in extra:
-                    history = list(extra["history"])
-                logger.info("resuming from checkpoint step %d", done_epoch)
-
-        # train:epoch_turn crosses the train:epoch phase span's close and
-        # open, so no ``with`` block can hold it: the stack does, and is
-        # closed (a no-op when empty) where the turn ends
-        turn = contextlib.ExitStack()
-        turn.enter_context(step_span("train:epoch_turn"))
-        #: when the device last ran dry for the loop: its start, then the
-        #: return of each epoch's loss fetch (lead_time_s counts from here)
-        t_ready = time.perf_counter()
-        while epoch < self.num_epochs:
-            try:
-                rule = faults.check("estimator.epoch", key=str(epoch))
-                if rule is not None:  # chaos tests provoke the retry path here
-                    faults.apply(rule, "estimator.epoch")
-                with profiler.trace("train:epoch", "training",
-                                    epoch=epoch) as epoch_span:
-                    t0 = time.perf_counter()
-                    (loss_sum,), mstats = train_zeros()
-                    steps, samples = 0, 0
-                    t_feed = t_disp = t_pull = 0.0
-                    if cache is not None:
-                        td = time.perf_counter()
-                        ekey = jax.random.fold_in(
-                            jax.random.PRNGKey(self.seed), epoch)
-                        turn.close()
-                        with step_span("train:dispatch"):
-                            state, loss_sum, mstats = _dispatch(
-                                jit_epoch, (state, loss_sum, mstats),
-                                cache.arrays, ekey)
-                            t_handed = time.perf_counter()
-                            # dispatch is async: fetch the loss scalar INSIDE
-                            # this window so dispatch_time_s carries the
-                            # epoch's device time (otherwise the report's sync
-                            # slot absorbs it and this path reads as "zero
-                            # dispatch cost")
-                            loss_sum = np.float32(loss_sum)
-                        t_disp = time.perf_counter() - td
-                        steps = cache_steps
-                        samples = cache_steps * self.batch_size
-                    else:
-                        feed.set_epoch(epoch)
-                        it = iter(feed)
-                        turn.close()
-                        # the epoch's first step, apart from the loop below so
-                        # that the loop itself reads nothing more: how long
-                        # the first pull took (the feed's restart) and when
-                        # the first program was handed over
-                        tf = time.perf_counter()
-                        with step_span("train:feed_wait"):
-                            batch = next(it, None)
-                        td = t_handed = time.perf_counter()
-                        t_feed = t_pull = td - tf
-                        if batch is not None:
-                            with step_span("train:dispatch"):
-                                state, loss_sum, mstats = _dispatch(
-                                    jit_train, state, batch, mstats, loss_sum)
-                            t_handed = time.perf_counter()
-                            t_disp = t_handed - td
-                            steps, samples = 1, self.batch_size
-                            while True:
-                                tf = time.perf_counter()
-                                with step_span("train:feed_wait"):
-                                    batch = next(it, None)
-                                t_feed += time.perf_counter() - tf
-                                if batch is None:
-                                    break
-                                td = time.perf_counter()
-                                with step_span("train:dispatch"):
-                                    state, loss_sum, mstats = _dispatch(
-                                        jit_train, state, batch, mstats,
-                                        loss_sum)
-                                t_disp += time.perf_counter() - td
-                                steps += 1
-                                samples += self.batch_size
-                    with step_span("train:epoch_end"):
-                        # fetch the accumulated loss BEFORE reading the
-                        # clock: dispatch is async, so only a host scalar
-                        # fetch makes the epoch wall include the device work
-                        with step_span("train:loss_fetch"):
-                            ts = time.perf_counter()
-                            train_loss = float(loss_sum) / steps if steps \
-                                else float("nan")
-                            t_fetched = time.perf_counter()
-                        t_sync = t_fetched - ts
-                        dt = time.perf_counter() - t0
-                        # registry twin of the epoch report (metrics_report()
-                        # sees epoch walls without re-publishing the history
-                        # dicts)
-                        rdt_metrics.observe("train_epoch_seconds", dt)
-                        with step_span("train:report"):
-                            # the feed's thread-side phase split (decode,
-                            # h2d): these walls OVERLAP dispatch by design
-                            # (that is the prefetch win), so they attribute
-                            # the epoch, they don't sum to it
-                            pipe = feed.timings.take() if feed is not None \
-                                else {}
-                            report = {
-                                "epoch": epoch,
-                                "train_loss": train_loss,
-                                "steps": steps,
-                                "samples_per_s": samples / dt if dt > 0
-                                else 0.0,
-                                "epoch_time_s": dt,
-                                "feed_time_s": t_feed,
-                                "decode_time_s": pipe.get("decode", 0.0),
-                                "h2d_time_s": pipe.get("h2d", 0.0),
-                                "dispatch_time_s": t_disp,
-                                "sync_time_s": t_sync,
-                                # the device had nothing of this epoch queued
-                                # from the last loss fetch's return (epoch 0:
-                                # the loop's start) until its first program
-                                # was handed over; the first pull, which waits
-                                # for the feed's new chain, is part of that
-                                "lead_time_s": t_handed - t_ready,
-                                "first_pull_time_s": t_pull,
-                            }
-                            t_ready = t_fetched
-                            for m, s in zip(train_metrics, mstats):
-                                value = m.compute(jax.tree.map(np.asarray, s))
-                                if value is not None:
-                                    report[f"train_{m.name}"] = value
-
-                        if eval_feed is not None or eval_cache is not None:
-                            with step_span("train:eval"):
-                                (esum, ecnt), estats = eval_zeros()
-                                if eval_cache is not None:
-                                    _, estats, esum, ecnt = jit_eval_epoch(
-                                        (state, estats, esum, ecnt),
-                                        eval_cache.arrays,
-                                        jax.random.PRNGKey(0))  # no shuffle
-                                    if eval_tail is not None:
-                                        esum, ecnt, estats = jit_eval(
-                                            state, eval_tail, estats, esum,
-                                            ecnt)
-                                else:
-                                    for batch in eval_feed:
-                                        esum, ecnt, estats = jit_eval(
-                                            state, batch, estats, esum, ecnt)
-                                # real rows only: pad rows mask to 0
-                                rows = float(ecnt)
-                                report["eval_loss"] = (float(esum) / rows) \
-                                    if rows else float("nan")
-                                for m, s in zip(metrics, estats):
-                                    report[f"eval_{m.name}"] = m.compute(
-                                        jax.tree.map(np.asarray, s))
-
-                        history.append(report)
-                        with step_span("train:callbacks"):
-                            for cb in self.callbacks:
-                                cb(report)
-                        logger.info(
-                            "epoch %d: %s", epoch,
-                            {k: (round(v, 5) if isinstance(v, float) else v)
-                             for k, v in report.items()})
-                    turn.enter_context(step_span("train:epoch_turn"))
-                    profiler.add_args(epoch_span, steps=steps)
-                if save_epoch_now(epoch, self.checkpoint_interval,
-                                  self.num_epochs):
-                    ckpt.save(ckpt_dir, state, step=epoch,
-                              extra={"history": history})
-                    last_written_step = epoch
-                epoch += 1
-            except (KeyboardInterrupt, SystemExit):
-                raise
-            except Exception as e:  # noqa: BLE001 - retry path (FailureConfig)
-                turn.close()
-                retries += 1
-                if retries > max_retries:
-                    raise
-                logger.warning("epoch %d failed (%s); restoring from checkpoint "
-                               "(retry %d/%d)", epoch, e, retries, max_retries)
-                # adopt a checkpoint only if an explicit resume claimed the
-                # dir, or THIS run wrote it — and then only up to the step
-                # this run wrote (a reused dir's stale higher-numbered steps
-                # would otherwise win latest-step selection and silently
-                # return an earlier run's model)
-                if resume:
-                    restored = ckpt.restore_placed(ckpt_dir, state,
-                                                   state_sharding)
-                elif last_written_step is not None:
-                    restored = ckpt.restore_placed(
-                        ckpt_dir, state, state_sharding,
-                        max_step=last_written_step)
-                else:
-                    restored = None
-                if restored is not None:
-                    state, done_epoch = restored
-                    epoch = done_epoch + 1
-                    extra = ckpt.restore_extra(
-                        ckpt_dir,
-                        max_step=None if resume else last_written_step)
-                    if extra and "history" in extra:
-                        history = list(extra["history"])
-                else:
-                    # no checkpoint from this run (a failure before the
-                    # first interval save): the failed state's buffers may
-                    # already be donated away — rebuild from scratch like a
-                    # fresh fit (the keras twin's no-checkpoint branch)
-                    variables = _init_variables(model, rng, inputs0)
-                    state = self._place_state(
-                        _State.create(apply_fn=model.apply,
-                                      params=variables["params"], tx=tx,
-                                      batch_stats=variables.get("batch_stats")),
-                        state_sharding)
-                    epoch = 0
-                    history = []
-                # the retried epoch gets a turn of its own
-                turn.enter_context(step_span("train:epoch_turn"))
-                t_ready = time.perf_counter()
-
-        turn.close()
-        return state, history
+        carry, history = loop.run(
+            trainee, feeds, num_epochs=self.num_epochs,
+            batch_size=self.batch_size, seed=self.seed,
+            checkpoint_interval=self.checkpoint_interval,
+            callbacks=self.callbacks, max_retries=max_retries, resume=resume)
+        return carry[0], history
 
     # ------------------------------------------------------------ partial_fit
     def _partial_fit_epoch(self, ds, epoch: int) -> Dict[str, float]:
@@ -1424,9 +1141,6 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         overlap the jitted steps, as in ``fit``). State persists on the
         estimator across epochs; ``self._result`` tracks it so
         ``get_model``/``export_serving`` work mid-stream."""
-        import jax
-        import time as _time
-
         from raydp_tpu.data.feed import DeviceFeed
 
         o = getattr(self, "_online", None)
@@ -1436,39 +1150,22 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                 # an empty first epoch (a filter matching nothing is
                 # routine in streaming) has no schema to init from: report
                 # it and keep waiting for rows
-                return {"epoch": epoch, "train_loss": float("nan"),
-                        "steps": 0, "samples_per_s": 0.0,
-                        "epoch_time_s": 0.0, "decode_time_s": 0.0,
-                        "h2d_time_s": 0.0}
+                return loop.epoch_report(
+                    epoch, "train_{}", 0.0, (), 0, self.batch_size,
+                    time.perf_counter(), 0.0, (0.0,) * 4)[0]
             self._online = o
         feed = DeviceFeed(ds, self.batch_size, o["columns"], mesh=o["mesh"],
                           shuffle=False, drop_remainder=o["drop_last"],
                           pad_remainder=o["pad_tail"],
                           prefetch_to_device=self.prefetch_to_device,
                           seq=o.get("seq", False))
-        t0 = _time.perf_counter()
-        (loss_sum,), mstats = o["zeros"]()
-        steps = 0
-        for batch in feed:
-            o["state"], loss_sum, mstats = o["jit_train"](
-                o["state"], batch, mstats, loss_sum)
-            steps += 1
-        train_loss = float(loss_sum) / steps if steps else float("nan")
-        dt = _time.perf_counter() - t0
-        pipe = feed.timings.take()
-        report = {
-            "epoch": epoch,
-            "train_loss": train_loss,
-            "steps": steps,
-            "samples_per_s": (steps * self.batch_size / dt) if dt > 0
-            else 0.0,
-            "epoch_time_s": dt,
-            "decode_time_s": pipe.get("decode", 0.0),
-            "h2d_time_s": pipe.get("h2d", 0.0),
-        }
-        for m, s in zip(self._metrics, mstats):
-            report[f"train_{m.name}"] = m.compute(
-                jax.tree.map(np.asarray, s))
+        t0 = time.perf_counter()
+        carry, steps, walls = loop.stream_epoch(
+            iter(feed), o["step"], o["zeros"]((o["state"], None, None)))
+        o["state"] = carry[0]
+        report, _ = loop.epoch_report(epoch, "train_{}", *o["read"](carry),
+                                      steps, self.batch_size, t0, t0, walls,
+                                      feed)
         o["history"].append(report)
         self._result = TrainingResult(state=o["state"],
                                       history=o["history"])
@@ -1482,11 +1179,11 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         None when the epoch holds no rows to init from."""
         import jax
         import jax.numpy as jnp
-        from flax.training import train_state
 
         from raydp_tpu.data.feed import HostBatchIterator
         from raydp_tpu.parallel import param_sharding_rules
-        from raydp_tpu.parallel.mesh import batch_sharding, data_axes
+        from raydp_tpu.parallel.mesh import batch_sharding
+        from raydp_tpu.train.checkpoint import place_tree
 
         mesh = self._build_mesh()
         columns = self._columns()
@@ -1502,16 +1199,9 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
             {k: jnp.asarray(v[:1]) for k, v in first.items()})
         rng = jax.random.PRNGKey(self.seed)
         takes_train = _takes_train(model)
-        variables = _init_variables(model, rng, inputs0)
-
-        class _State(train_state.TrainState):
-            batch_stats: Any = None
-
-        state = _State.create(apply_fn=model.apply,
-                              params=variables["params"], tx=tx,
-                              batch_stats=variables.get("batch_stats"))
+        state = _init_state(model, tx, rng, inputs0)
         state_sharding = param_sharding_rules(mesh, self.param_rules)(state)
-        state = self._place_state(state, state_sharding)
+        state = place_tree(state, state_sharding)
 
         # the SAME step body as fit()'s (one source): the online path gets
         # gradient accumulation, remat AND pipeline placement for free, and
@@ -1528,21 +1218,19 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
                           if self._use_seq(mesh) else None),
             state_shardings=state_sharding, seed=self.seed)
 
-        dp_total = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
-        # the ragged micro-batch tail under a >1 data extent (or a >1 stage
-        # extent — the pipelined forward cannot reshape a ragged batch):
-        # pad-and-mask like fit()'s feeds (an online epoch is often SMALLER
-        # than one batch — dropping its tail silently skipped whole
-        # micro-batches); RDT_TRAIN_PAD_TAIL=0 or a mask-blind custom loss
-        # restores drop
-        pad_tail = (dp_total > 1 or n_stages > 1) and self._may_pad_tail()
+        # the ragged micro-batch tail goes as fit()'s eval tail does (an
+        # online epoch is often SMALLER than one batch — dropping its tail
+        # silently skipped whole micro-batches)
+        tail_ok, pad_tail = loop.tail_rule(mesh, self._may_pad_tail())
+        step, zeros, read = _carry_hooks(
+            jax.jit(train_step, donate_argnums=(0, 3)),
+            _epoch_zeros(mesh, metrics), metrics)
         return {
             "mesh": mesh,
             "columns": columns,
             "state": state,
-            "jit_train": jax.jit(train_step, donate_argnums=(0, 3)),
-            "zeros": _epoch_zeros(mesh, metrics),
-            "drop_last": (dp_total > 1 or n_stages > 1) and not pad_tail,
+            "step": step, "zeros": zeros, "read": read,
+            "drop_last": not tail_ok,
             "pad_tail": pad_tail,
             "seq": self._use_seq(mesh),
             "history": [],
@@ -1649,50 +1337,18 @@ class FlaxEstimator(EstimatorInterface, FrameEstimatorInterface):
         torch/estimator.py:177-310)."""
         import jax
 
-        from raydp_tpu.data.dataset import DistributedDataset
-        from raydp_tpu.data.feed import DeviceFeed, GangShardIterator
-
-        columns = self._columns()
         mesh = self._build_mesh()  # jax.devices() is global under the gang
         # sharded multi-writer checkpoints assume ONE filesystem: the chief
         # mkdirs each step dir and its COMPLETE marker must be visible to
         # every rank on resume — fail fast on per-host paths, don't deadlock
         from raydp_tpu.train.checkpoint import ensure_shared_dir
         ensure_shared_dir(ckpt_dir, "rdt_ckpt_dir_probe")
-        from raydp_tpu.data.feed import process_local_batch_rows
-        from raydp_tpu.parallel import batch_sharding
-
-        # this process's addressable slice of each global batch, derived from
-        # the actual batch sharding: with the batch replicated over a size-1
-        # data axis (e.g. pure fsdp/expert meshes) EVERY process feeds the
-        # full batch; with a >1 data axis each feeds its contiguous rows
-        row_range = process_local_batch_rows(batch_sharding(mesh),
-                                             self.batch_size)
-        train_ds = DistributedDataset.from_portable(train_payload)
-        feed = DeviceFeed(
-            train_ds, self.batch_size, columns, mesh=mesh,
+        feeds = loop.gang_feeds(
+            ctx, train_payload, eval_payload, self._columns(), mesh,
+            self.batch_size, shuffle=self.shuffle, seed=self.seed,
             prefetch_to_device=self.prefetch_to_device,
-            seq=self._use_seq(mesh),
-            host_iter=GangShardIterator(
-                train_ds, self.batch_size, ctx.world_size, ctx.rank, columns,
-                shuffle=self.shuffle, seed=self.seed, row_range=row_range))
-        eval_feed = None
-        if eval_payload is not None:
-            eval_ds = DistributedDataset.from_portable(eval_payload)
-            # the ragged eval tail pads and masks, as in fit(): the gang's
-            # eval mean is over every row, like the single process's (where
-            # padding is opted out, or the loss takes no mask, it is dropped)
-            eval_feed = DeviceFeed(
-                eval_ds, self.batch_size, columns, mesh=mesh,
-                prefetch_to_device=self.prefetch_to_device,
-                seq=self._use_seq(mesh),
-                host_iter=GangShardIterator(
-                    eval_ds, self.batch_size, ctx.world_size, ctx.rank,
-                    columns, shuffle=False, seed=self.seed,
-                    row_range=row_range,
-                    pad_remainder=self._may_pad_tail()))
-
-        state, history = self._train_loop(mesh, feed, eval_feed, ckpt_dir,
+            may_pad=self._may_pad_tail(), seq=self._use_seq(mesh))
+        state, history = self._train_loop(mesh, feeds, ckpt_dir,
                                           max_retries=0, resume=True)
         out = {"history": history}
         # collect the trained variables on every host (collective — all ranks

@@ -37,7 +37,9 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from raydp_tpu import profiler
 from raydp_tpu.log import get_logger
+from raydp_tpu.train import loop
 from raydp_tpu.train.estimator import (
     EstimatorInterface,
     FrameEstimatorInterface,
@@ -201,48 +203,19 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
 
     def _fit_stateless(self, train_ds, evaluate_ds=None, max_retries: int = 0
                        ) -> TrainingResult:
-        import numpy as _np
-
-        from raydp_tpu.data.feed import DeviceFeed
-        from raydp_tpu.parallel.mesh import data_axes
-
+        profiler.watch_jit_builds()
         mesh = self._mesh()
-        columns = self._columns()
         ckpt_dir = self.checkpoint_dir or tempfile.mkdtemp(
             prefix="rdt-keras-ckpt-")
         os.makedirs(ckpt_dir, exist_ok=True)
-        # device-resident fast path (see feed.DeviceEpochCache): whole epoch
-        # in one dispatch, on-device shuffling — streaming feed otherwise
-        from raydp_tpu.data.feed import DeviceEpochCache
-        cache = feed = None
-        if DeviceEpochCache.eligible(train_ds, columns, self.batch_size,
-                                     self.drop_last):
-            cache = DeviceEpochCache(train_ds, columns, mesh=mesh)
-        if cache is None:
-            feed = DeviceFeed(train_ds, self.batch_size, columns, mesh=mesh,
-                              shuffle=self.shuffle, seed=self.seed,
-                              drop_remainder=self.drop_last,
-                              prefetch_to_device=self.prefetch_to_device)
-        eval_feed = eval_cache = None
-        if evaluate_ds is not None:
-            dp_total = int(_np.prod([mesh.shape[a] for a in data_axes(mesh)]))
-            # resident eval beside resident train: one scan dispatch per
-            # eval pass, under a COMBINED train+eval budget (see flax twin)
-            if (cache is not None
-                    and DeviceEpochCache.eligible(evaluate_ds, columns,
-                                                  1, True)
-                    and cache.nbytes + DeviceEpochCache.estimate_bytes(
-                        evaluate_ds, columns) <= DeviceEpochCache.cap_bytes()):
-                eval_cache = DeviceEpochCache(evaluate_ds, columns, mesh=mesh)
-            else:
-                eval_feed = DeviceFeed(evaluate_ds, self.batch_size, columns,
-                                       mesh=mesh, shuffle=False,
-                                       drop_remainder=dp_total > 1,
-                                       prefetch_to_device=self.prefetch_to_device)
+        # the step takes no validity mask, so no tail is ever padded
+        feeds = loop.plan_feeds(
+            train_ds, evaluate_ds, self._columns(), mesh, self.batch_size,
+            shuffle=self.shuffle, seed=self.seed, drop_last=self.drop_last,
+            prefetch_to_device=self.prefetch_to_device, may_pad=False,
+            seq=False)
         model, history = self._stateless_train_loop(
-            mesh, feed, eval_feed, ckpt_dir, max_retries=max_retries,
-            cache=cache, eval_cache=eval_cache,
-            eval_tail_ok=evaluate_ds is not None and dp_total == 1)
+            mesh, feeds, ckpt_dir, max_retries=max_retries)
         self._trained_model = model
         self._result = TrainingResult(state=model, history=history,
                                       checkpoint_dir=ckpt_dir)
@@ -276,20 +249,19 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
                     keras.saving.serialize_keras_object(m)))
         return out
 
-    def _stateless_train_loop(self, mesh, feed, eval_feed, ckpt_dir: str,
-                              max_retries: int = 0, resume: bool = False,
-                              cache=None, eval_cache=None,
-                              eval_tail_ok: bool = False):
+    def _stateless_train_loop(self, mesh, feeds, ckpt_dir: str,
+                              max_retries: int = 0, resume: bool = False):
         """One jitted train step over stateless Keras calls; in-jit loss and
         metric accumulation; donated state buffers; chief-only per-epoch
-        ``model.keras`` checkpoint with a JSON epoch/history sidecar.
+        ``model.keras`` checkpoint with a JSON epoch/history sidecar. Built
+        here and handed to the loop (``train/loop.py``) as its
+        :class:`~loop.Trainee`.
 
         Parity: the role ``model.fit`` under an MWMS scope plays for the
         reference (tf/estimator.py:171-210) — redesigned as an XLA-compiled
         step because per-batch Python dispatch is what made the round-2 Keras
         path 14× slower than the Flax path on the same chip."""
         import json as _json
-        import time as _time
 
         import jax
         import jax.numpy as jnp
@@ -297,12 +269,11 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
 
         keras = _import_keras()
 
-        keras.utils.set_random_seed(self.seed)
-        model = self._build_model()
-        optimizer = keras.saving.deserialize_keras_object(self._optimizer_spec)
+        model = optimizer = None     # made by fresh() / restore(), below
         loss_obj = keras.losses.get(self._loss)
         train_metrics = self._metric_objects()
         eval_metrics = self._metric_objects()
+        cache, eval_cache = feeds.cache, feeds.eval_cache
 
         saved_model = os.path.join(ckpt_dir, "model.keras")
         saved_meta = os.path.join(ckpt_dir, "state.json")
@@ -312,107 +283,54 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
             return (os.path.exists(saved_model)
                     and os.path.exists(saved_meta))
 
-        history: list = []
-        epoch0 = 0
-        restored = False
         if not resume and self.checkpoint_dir and _ckpt_available():
-            # the flax twin's reused-dir warning (checkpoint.warn_if_reused_dir)
-            # for the keras model.keras/state.json format: this fit will
-            # overwrite, but the user should learn the dir held an earlier
-            # run before a later resume silently adopts whichever run wrote
-            # last
+            # checkpoint.warn_if_reused_dir, for the keras
+            # model.keras/state.json format: this fit will overwrite, but
+            # the user should learn the dir held an earlier run before a
+            # later resume silently adopts whichever run wrote last
             logger.warning(
                 "checkpoint_dir %r already holds a model.keras/state.json "
                 "from an earlier run; this fit overwrites them — use a fresh "
                 "checkpoint_dir per run to keep runs separate", ckpt_dir)
-        if resume:
-            # gang: all ranks must resume the SAME epoch or their collective
-            # counts diverge and the first psum deadlocks — take the CHIEF's
-            # view of the sidecar (lagging visibility on networked storage
-            # can make ranks disagree), exactly like checkpoint._latest_agreed
-            local_epoch = -1
-            if _ckpt_available():
-                with open(saved_meta) as f:
-                    meta = _json.load(f)
-                local_epoch = int(meta["epoch"])
-            chief_epoch = local_epoch
-            if jax.process_count() > 1:
-                from jax.experimental import multihost_utils
-                import numpy as _np
-                chief_epoch = int(multihost_utils.broadcast_one_to_all(
-                    _np.int32(local_epoch)))
-            if chief_epoch >= 0:
-                if not _ckpt_available():
-                    raise FileNotFoundError(
-                        f"chief resumes keras checkpoint epoch {chief_epoch} "
-                        f"but this rank cannot see {ckpt_dir!r}; gangs need "
-                        "shared checkpoint storage")
-                model = keras.saving.load_model(saved_model)
-                with open(saved_meta) as f:
-                    meta = _json.load(f)
-                epoch0 = chief_epoch + 1
-                history = list(meta["history"])[:chief_epoch + 1]
-                restored = True
-                logger.info("keras resuming from checkpoint epoch %d",
-                            chief_epoch)
 
-        # build weights + optimizer slots from one sample batch's shapes
-        first = cache.init_row if cache is not None \
-            else next(iter(feed.host_iter))
-        if not model.built:
-            model.build(first["features"][:1].shape)
-        optimizer.build(model.trainable_variables)
+        # weights + optimizer slots are built from one sample batch's shapes
+        first = feeds.first_batch(self.batch_size, self.drop_last)
 
         rep = NamedSharding(mesh, PartitionSpec())
 
-        def _place(values):
-            return [jax.device_put(jnp.asarray(v), rep) for v in values]
+        def _carry(opt_values=None, chief_sync=False):
+            """The current model's and optimizer's values placed on the mesh,
+            ``(tv, ntv, ov, None, None)``. ``chief_sync``: on a restored
+            gang, every rank takes the CHIEF's host values — a rank that read
+            a staler file version must not train different weights (the
+            collective math would silently diverge)."""
+            groups = [[v.value for v in model.trainable_variables],
+                      [v.value for v in model.non_trainable_variables],
+                      opt_values if opt_values is not None
+                      else [v.value for v in optimizer.variables]]
+            if chief_sync and jax.process_count() > 1:
+                from jax.experimental import multihost_utils
+                groups = multihost_utils.broadcast_one_to_all(
+                    [[np.asarray(v) for v in g] for g in groups])
+            return tuple([jax.device_put(jnp.asarray(v), rep) for v in g]
+                         for g in groups) + (None, None)
 
-        def _restore_opt():
+        def _saved_opt_values():
             """Optimizer slots (Adam moments, iteration) from the sidecar —
             resuming with zeroed slots would silently diverge from an
             uninterrupted run (the FlaxEstimator checkpoints its full
-            TrainState; this is the keras-format equivalent). Gang ranks take
-            the chief's slot values like the weights."""
-            vals = None
-            if os.path.exists(saved_opt):
-                with np.load(saved_opt) as z:
-                    vals = [z[f"v{i}"] for i in range(len(z.files))]
-                if len(vals) != len(optimizer.variables):
-                    logger.warning("optimizer sidecar has %d slots, expected "
-                                   "%d; starting slots fresh", len(vals),
-                                   len(optimizer.variables))
-                    vals = None
-            if vals is None:
-                vals = [np.asarray(v.value) for v in optimizer.variables]
-            return _place(_chief_sync(vals))
-
-        def _chief_sync(values):
-            """On a restored gang, every rank takes the CHIEF's host values —
-            a rank that read a staler file version must not train different
-            weights (the collective math would silently diverge)."""
-            if not (restored and jax.process_count() > 1):
-                return values
-            from jax.experimental import multihost_utils
-            return multihost_utils.broadcast_one_to_all(
-                [np.asarray(v) for v in values])
-
-        tv = _place(_chief_sync([v.value for v in model.trainable_variables]))
-        ntv = _place(_chief_sync(
-            [v.value for v in model.non_trainable_variables]))
-        ov = _restore_opt() if restored \
-            else _place([v.value for v in optimizer.variables])
-
-        # initial metric states snapshotted to HOST: the per-epoch device
-        # copies are donated into the jitted steps, so re-reading the keras
-        # variables' (consumed) buffers next epoch would use deleted arrays
-        tm_init = tuple(tuple(np.asarray(v.value) for v in m.variables)
-                        for m in train_metrics)
-        em_init = tuple(tuple(np.asarray(v.value) for v in m.variables)
-                        for m in eval_metrics)
-
-        def _mvars(init):
-            return tuple(tuple(jnp.asarray(v) for v in t) for t in init)
+            TrainState; this is the keras-format equivalent). None: start
+            the slots fresh."""
+            if not os.path.exists(saved_opt):
+                return None
+            with np.load(saved_opt) as z:
+                vals = [z[f"v{i}"] for i in range(len(z.files))]
+            if len(vals) != len(optimizer.variables):
+                logger.warning("optimizer sidecar has %d slots, expected "
+                               "%d; starting slots fresh", len(vals),
+                               len(optimizer.variables))
+                return None
+            return vals
 
         def _match_rank(y, preds):
             if y.ndim == preds.ndim - 1 and preds.shape[-1] == 1:
@@ -438,7 +356,7 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
                 for m, mv in zip(train_metrics, mvars))
             return tv2, ntv2, ov2, mvars2, loss_sum + loss
 
-        def eval_step(tv, ntv, mvars, loss_sum, batch):
+        def eval_step(tv, ntv, mvars, loss_sum, rows, batch):
             x, y = batch["features"], batch["label"]
             preds, _ = model.stateless_call(tv, ntv, x, training=False)
             y2 = _match_rank(y, preds)
@@ -446,50 +364,31 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
             mvars2 = tuple(
                 tuple(m.stateless_update_state(list(mv), y2, preds))
                 for m, mv in zip(eval_metrics, mvars))
-            return mvars2, loss_sum + loss * y.shape[0]
+            return mvars2, loss_sum + loss * y.shape[0], rows + y.shape[0]
 
         jit_train = jax.jit(train_step, donate_argnums=(0, 1, 2, 3, 4))
-        jit_eval = jax.jit(eval_step, donate_argnums=(2, 3))
+        jit_eval = jax.jit(eval_step, donate_argnums=(2, 3, 4))
 
-        jit_epoch = None
-        cache_steps = 0
-        if cache is not None:
-            # device-resident epoch: the shared scan program built by
-            # DeviceEpochCache (one source for the permutation/slice logic
-            # across estimators; see the flax twin)
-            from raydp_tpu.parallel.mesh import batch_sharding
+        def _zeros(metrics, sums):
+            """The program that makes a pass's starting accumulators,
+            ``(mvars, *sums)``, replicated on the mesh as the steps return
+            them (``loop``'s module text says why a program). The metrics'
+            initial states are read off the keras variables here, once: the
+            device copies are donated into the jitted steps."""
+            init = tuple(tuple(np.asarray(v.value) for v in m.variables)
+                         for m in metrics)
+            return jax.jit(
+                lambda: (tuple(tuple(jnp.asarray(v) for v in t)
+                               for t in init),
+                         *(jnp.zeros((), jnp.float32) for _ in range(sums))),
+                out_shardings=rep)
 
-            epoch_fn, cache_steps = cache.make_epoch_fn(
-                lambda carry, batch: train_step(*carry, batch),
-                self.batch_size, self.shuffle,
-                batch_sharding=batch_sharding(mesh))
-            jit_epoch = jax.jit(epoch_fn, donate_argnums=(0,))
+        train_zeros = _zeros(train_metrics, 1)
+        eval_zeros = _zeros(eval_metrics, 2)    # the loss sum, the row count
 
-        jit_eval_epoch = None
-        eval_tail = None
-        eval_cache_rows = 0
-        if eval_cache is not None:
-            # whole eval pass as one scan dispatch, built by the shared
-            # make_epoch_fn; ragged tail as one jitted call where the
-            # caller-decided eval_tail_ok rule allows (the flax twin's
-            # shape). Carry rides tv/ntv through unchanged — not donated
-            from raydp_tpu.parallel.mesh import batch_sharding
-
-            def _eval_scan_step(carry, batch):
-                tv, ntv, mvars, loss_sum = carry
-                mvars, loss_sum = eval_step(tv, ntv, mvars, loss_sum, batch)
-                return tv, ntv, mvars, loss_sum
-
-            eval_epoch_fn, esteps = eval_cache.make_epoch_fn(
-                _eval_scan_step, self.batch_size, shuffle=False,
-                batch_sharding=batch_sharding(mesh))
-            jit_eval_epoch = jax.jit(eval_epoch_fn)
-            eval_cache_rows = esteps * self.batch_size
-            tail_rows = eval_cache.num_rows - eval_cache_rows
-            if tail_rows > 0 and eval_tail_ok:
-                eval_tail = {n: a[eval_cache_rows:]
-                             for n, a in eval_cache.arrays.items()}
-                eval_cache_rows += tail_rows
+        def _results(metrics, mvars):
+            for m, mv in zip(metrics, mvars):
+                yield m.name, float(m.stateless_result(list(mv)))
 
         def _host_val(a):
             """Host copy of a replicated array (the local replica shard IS
@@ -498,164 +397,135 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
                 return np.asarray(a.addressable_data(0))
             return np.asarray(a)
 
-        def _sync_model():
+        def _sync_model(carry):
             """Write the device state back into the keras variables."""
-            for var, val in zip(model.trainable_variables, tv):
+            for var, val in zip(model.trainable_variables, carry[0]):
                 var.assign(_host_val(val))
-            for var, val in zip(model.non_trainable_variables, ntv):
+            for var, val in zip(model.non_trainable_variables, carry[1]):
                 var.assign(_host_val(val))
 
         chief = jax.process_index() == 0
-        epoch = epoch0
-        retries = 0
-        saved_this_run = False
-        while epoch < self.num_epochs:
-            try:
-                t0 = _time.perf_counter()
-                mvars = _mvars(tm_init)
-                loss_sum = jnp.zeros((), jnp.float32)
-                steps, samples = 0, 0
-                t_feed = t_disp = 0.0
-                if cache is not None:
-                    td = _time.perf_counter()
-                    ekey = jax.random.fold_in(
-                        jax.random.PRNGKey(self.seed), epoch)
-                    tv, ntv, ov, mvars, loss_sum = jit_epoch(
-                        (tv, ntv, ov, mvars, loss_sum), cache.arrays, ekey)
-                    # fetch the loss scalar INSIDE this window: dispatch is
-                    # async, and dispatch_time_s must carry the epoch's
-                    # device time (see the flax twin)
-                    loss_sum = np.float32(loss_sum)
-                    t_disp = _time.perf_counter() - td
-                    steps = cache_steps
-                    samples = cache_steps * self.batch_size
-                else:
-                    feed.set_epoch(epoch)
-                    it = iter(feed)
-                    while True:
-                        tf = _time.perf_counter()
-                        batch = next(it, None)
-                        t_feed += _time.perf_counter() - tf
-                        if batch is None:
-                            break
-                        td = _time.perf_counter()
-                        tv, ntv, ov, mvars, loss_sum = jit_train(
-                            tv, ntv, ov, mvars, loss_sum, batch)
-                        t_disp += _time.perf_counter() - td
-                        steps += 1
-                        samples += self.batch_size
-                # fetch the loss scalar BEFORE reading the clock: dispatch is
-                # async, so only a host fetch makes the epoch wall include
-                # the device work (stable across runs; see flax_estimator)
-                ts = _time.perf_counter()
-                loss_host = float(loss_sum) / steps if steps else float("nan")
-                t_sync = _time.perf_counter() - ts
-                dt = _time.perf_counter() - t0
-                # registry twin of the epoch report (see the flax estimator)
-                from raydp_tpu import metrics as rdt_metrics
-                rdt_metrics.observe("train_epoch_seconds", dt)
-                # the feed's thread-side decode / h2d split — these walls
-                # OVERLAP dispatch (the prefetch win), see the flax twin
-                pipe = feed.timings.take() if feed is not None else {}
-                report = {
-                    "epoch": epoch,
-                    "loss": loss_host,
-                    "epoch_time_s": dt,
-                    "samples_per_s": samples / dt if dt > 0 else 0.0,
-                    "feed_time_s": t_feed,
-                    "decode_time_s": pipe.get("decode", 0.0),
-                    "h2d_time_s": pipe.get("h2d", 0.0),
-                    "dispatch_time_s": t_disp,
-                    "sync_time_s": t_sync,
-                }
-                for m, mv in zip(train_metrics, mvars):
-                    report[m.name] = float(m.stateless_result(list(mv)))
 
-                if eval_feed is not None or eval_cache is not None:
-                    emv = _mvars(em_init)
-                    esum = jnp.zeros((), jnp.float32)
-                    if eval_cache is not None:
-                        ecnt = eval_cache_rows
-                        _, _, emv, esum = jit_eval_epoch(
-                            (tv, ntv, emv, esum), eval_cache.arrays,
-                            jax.random.PRNGKey(0))  # unused: shuffle=False
-                        if eval_tail is not None:
-                            emv, esum = jit_eval(tv, ntv, emv, esum,
-                                                 eval_tail)
-                    else:
-                        ecnt = 0
-                        for batch in eval_feed:
-                            ecnt += int(next(iter(batch.values())).shape[0])
-                            emv, esum = jit_eval(tv, ntv, emv, esum, batch)
-                    report["val_loss"] = (float(esum) / ecnt) if ecnt \
-                        else float("nan")
-                    for m, mv in zip(eval_metrics, emv):
-                        report[f"val_{m.name}"] = float(
-                            m.stateless_result(list(mv)))
+        def save(carry, epoch, history):
+            if not chief:
+                return
+            # chief-only checkpoint (parity: tf/estimator.py:202-210) +
+            # optimizer sidecar so a resume keeps Adam slots. Every file
+            # lands via tmp+rename and the meta sidecar is written LAST: a
+            # crash mid-save leaves the previous complete trio, never a torn
+            # archive resume trusts
+            _sync_model(carry)
+            tmp_model = saved_model + ".tmp.keras"
+            model.save(tmp_model)
+            os.replace(tmp_model, saved_model)
+            tmp_opt = saved_opt + ".tmp.npz"
+            np.savez(tmp_opt, **{
+                f"v{i}": _host_val(v) for i, v in enumerate(carry[2])})
+            os.replace(tmp_opt, saved_opt)
+            tmp_meta = saved_meta + ".tmp"
+            with open(tmp_meta, "w") as f:
+                _json.dump({"epoch": epoch, "history": history}, f)
+            os.replace(tmp_meta, saved_meta)
 
-                history.append(report)
-                logger.info("keras epoch %d: %s", epoch,
-                            {k: (round(v, 5) if isinstance(v, float) else v)
-                             for k, v in report.items()})
-                save_now = save_epoch_now(epoch, self.checkpoint_interval,
-                                          self.num_epochs)
-                if chief and save_now:
-                    # chief-only checkpoint (parity: tf/estimator.py:202-210)
-                    # + optimizer sidecar so a resume keeps Adam slots.
-                    # Every file lands via tmp+rename and the meta sidecar is
-                    # written LAST: a crash mid-save leaves the previous
-                    # complete trio, never a torn archive resume trusts
-                    _sync_model()
-                    tmp_model = saved_model + ".tmp.keras"
-                    model.save(tmp_model)
-                    os.replace(tmp_model, saved_model)
-                    tmp_opt = saved_opt + ".tmp.npz"
-                    np.savez(tmp_opt, **{
-                        f"v{i}": _host_val(v) for i, v in enumerate(ov)})
-                    os.replace(tmp_opt, saved_opt)
-                    tmp_meta = saved_meta + ".tmp"
-                    with open(tmp_meta, "w") as f:
-                        _json.dump({"epoch": epoch, "history": history}, f)
-                    os.replace(tmp_meta, saved_meta)
-                if save_now:
-                    saved_this_run = True
-                epoch += 1
-            except (KeyboardInterrupt, SystemExit):
-                raise
-            except Exception as e:  # noqa: BLE001 - FailureConfig parity
-                retries += 1
-                if retries > max_retries:
-                    raise
-                logger.warning("keras epoch %d failed (%s); restoring from "
-                               "checkpoint (retry %d/%d)", epoch, e, retries,
-                               max_retries)
-                # adopt a checkpoint only if THIS run (or an explicit resume)
-                # wrote/claimed it — a stale dir from an earlier run must not
-                # short-circuit a fresh fit to zero epochs
-                use_ckpt = (restored or saved_this_run) and _ckpt_available()
-                optimizer = keras.saving.deserialize_keras_object(
-                    self._optimizer_spec)
-                if use_ckpt:
-                    model = keras.saving.load_model(saved_model)
-                    with open(saved_meta) as f:
-                        meta = _json.load(f)
-                    epoch = int(meta["epoch"]) + 1
-                    history = list(meta["history"])
-                    optimizer.build(model.trainable_variables)
-                    ov = _restore_opt()
-                else:
-                    keras.utils.set_random_seed(self.seed)
-                    model = self._build_model()
-                    model.build(first["features"][:1].shape)
-                    epoch = 0
-                    history = []
-                    optimizer.build(model.trainable_variables)
-                    ov = _place([v.value for v in optimizer.variables])
-                tv = _place([v.value for v in model.trainable_variables])
-                ntv = _place([v.value
-                              for v in model.non_trainable_variables])
+        def restore(carry, max_step):
+            """The directory's one checkpoint (each save overwrites the
+            last: ``max_step`` has nothing to choose from). A gang's ranks
+            must resume the SAME epoch or their collective counts diverge
+            and the first psum deadlocks: they take the CHIEF's view of the
+            sidecar (lagging visibility on networked storage can make ranks
+            disagree), exactly like checkpoint._latest_agreed."""
+            nonlocal model, optimizer
+            del carry, max_step
+            meta = None
+            if _ckpt_available():
+                with open(saved_meta) as f:
+                    meta = _json.load(f)
+            chief_epoch = -1 if meta is None else int(meta["epoch"])
+            if jax.process_count() > 1:
+                from jax.experimental import multihost_utils
+                chief_epoch = int(multihost_utils.broadcast_one_to_all(
+                    np.int32(chief_epoch)))
+            if chief_epoch < 0:
+                return None
+            if meta is None:
+                raise FileNotFoundError(
+                    f"chief resumes keras checkpoint epoch {chief_epoch} "
+                    f"but this rank cannot see {ckpt_dir!r}; gangs need "
+                    "shared checkpoint storage")
+            model = keras.saving.load_model(saved_model)
+            optimizer = keras.saving.deserialize_keras_object(
+                self._optimizer_spec)
+            optimizer.build(model.trainable_variables)
+            return (_carry(_saved_opt_values(), chief_sync=True), chief_epoch,
+                    list(meta["history"])[:chief_epoch + 1])
 
-        _sync_model()
+        def fresh():
+            """The model and the optimizer from their specs and the seed."""
+            nonlocal model, optimizer
+            keras.utils.set_random_seed(self.seed)
+            model = self._build_model()
+            if not model.built:
+                model.build(first["features"][:1].shape)
+            optimizer = keras.saving.deserialize_keras_object(
+                self._optimizer_spec)
+            optimizer.build(model.trainable_variables)
+            return _carry()
+
+        trainee = loop.Trainee(
+            carry=fresh(),
+            step=lambda carry, batch: jit_train(*carry, batch),
+            zeros=lambda carry: (*carry[:3], *train_zeros()),
+            read=lambda carry: (carry[4], _results(train_metrics, carry[3])),
+            save=save, restore=restore, fresh=fresh,
+            train_key="{}", eval_key="val_{}")
+
+        if cache is not None:
+            # the resident epoch: the shared scan program built by
+            # DeviceEpochCache round the step in scan form
+            from raydp_tpu.parallel.mesh import batch_sharding
+
+            epoch_fn, _ = cache.make_epoch_fn(
+                lambda carry, batch: train_step(*carry, batch),
+                self.batch_size, self.shuffle,
+                batch_sharding=batch_sharding(mesh))
+            jit_epoch = jax.jit(epoch_fn, donate_argnums=(0,))
+            trainee.epoch = lambda carry, key: jit_epoch(carry, cache.arrays,
+                                                         key)
+
+        if feeds.eval_feed is not None or eval_cache is not None:
+            # an eval pass's accumulators are what ``jit_eval`` returns and
+            # ``eval_zeros`` makes: (mvars, loss sum, row count)
+            def eval_read(acc):
+                rows = float(acc[2])
+                yield "loss", float(acc[1]) / rows if rows else float("nan")
+                yield from _results(eval_metrics, acc[0])
+
+            trainee.evaluation = ev = loop.Evaluation(
+                zeros=eval_zeros, read=eval_read,
+                step=lambda carry, acc, batch: jit_eval(
+                    carry[0], carry[1], *acc, batch))
+        if eval_cache is not None:
+            # every full batch of the resident eval set as ONE scan
+            # dispatch, built by the shared make_epoch_fn. The carry rides
+            # tv/ntv through unchanged — not donated
+            from raydp_tpu.parallel.mesh import batch_sharding
+
+            eval_epoch_fn, _ = eval_cache.make_epoch_fn(
+                lambda c, batch: (c[0], c[1], *eval_step(*c, batch)),
+                self.batch_size, shuffle=False,
+                batch_sharding=batch_sharding(mesh))
+            jit_eval_epoch = jax.jit(eval_epoch_fn)
+            ev.epoch = lambda carry, acc: jit_eval_epoch(
+                (carry[0], carry[1], *acc), eval_cache.arrays,
+                jax.random.PRNGKey(0))[2:]      # the key: unused, no shuffle
+
+        # Keras has no ``callbacks`` argument: the loop's list is empty
+        carry, history = loop.run(
+            trainee, feeds, num_epochs=self.num_epochs,
+            batch_size=self.batch_size, seed=self.seed,
+            checkpoint_interval=self.checkpoint_interval,
+            max_retries=max_retries, resume=resume)
+        _sync_model(carry)
         return model, history
 
     def _fit_keras_loop(self, train_ds, evaluate_ds=None, max_retries: int = 0
@@ -887,40 +757,18 @@ class KerasEstimator(EstimatorInterface, FrameEstimatorInterface):
     def _gang_rank_fit(self, ctx, train_payload, eval_payload, ckpt_dir: str):
         """Runs inside each SPMD rank: global mesh, rank-sharded host feed,
         the same jitted stateless loop, resume from the chief checkpoint."""
-        import jax
+        from raydp_tpu.parallel import make_mesh
 
-        from raydp_tpu.data.dataset import DistributedDataset
-        from raydp_tpu.data.feed import (
-            DeviceFeed, GangShardIterator, process_local_batch_rows,
-        )
-        from raydp_tpu.parallel import batch_sharding, make_mesh
-
-        columns = self._columns()
         mesh = make_mesh()  # jax.devices() is global under the gang
         from raydp_tpu.train.checkpoint import ensure_shared_dir
         ensure_shared_dir(ckpt_dir, "rdt_keras_ckpt_probe")
-
-        row_range = process_local_batch_rows(batch_sharding(mesh),
-                                             self.batch_size)
-        train_ds = DistributedDataset.from_portable(train_payload)
-        feed = DeviceFeed(
-            train_ds, self.batch_size, columns, mesh=mesh,
-            prefetch_to_device=self.prefetch_to_device,
-            host_iter=GangShardIterator(
-                train_ds, self.batch_size, ctx.world_size, ctx.rank, columns,
-                shuffle=self.shuffle, seed=self.seed, row_range=row_range))
-        eval_feed = None
-        if eval_payload is not None:
-            eval_ds = DistributedDataset.from_portable(eval_payload)
-            eval_feed = DeviceFeed(
-                eval_ds, self.batch_size, columns, mesh=mesh,
-                prefetch_to_device=self.prefetch_to_device,
-                host_iter=GangShardIterator(
-                    eval_ds, self.batch_size, ctx.world_size, ctx.rank,
-                    columns, shuffle=False, seed=self.seed,
-                    row_range=row_range))
+        feeds = loop.gang_feeds(
+            ctx, train_payload, eval_payload, self._columns(), mesh,
+            self.batch_size, shuffle=self.shuffle, seed=self.seed,
+            prefetch_to_device=self.prefetch_to_device, may_pad=False,
+            seq=False)
         _, history = self._stateless_train_loop(
-            mesh, feed, eval_feed, ckpt_dir, max_retries=0, resume=True)
+            mesh, feeds, ckpt_dir, max_retries=0, resume=True)
         return history
 
     # ----------------------------------------------------------- fit_on_frame
