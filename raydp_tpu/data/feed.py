@@ -29,8 +29,9 @@ from __future__ import annotations
 import queue
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 import pyarrow as pa
@@ -152,6 +153,18 @@ class HostBatchIterator:
     cast is the dominant host cost of an epoch once the train step is fast,
     and multi-epoch training re-reads the same immutable blocks. Per-epoch
     shuffling permutes indices over the cached arrays instead of re-decoding.
+
+    How a batch is cut: an epoch is its parts in (shuffled) order, each part
+    permuted whole — ``rng.shuffle(parts)``, then ONE ``rng.permutation`` a
+    part — and cut every ``batch_size`` rows. Only the permutation is drawn a
+    part at a time; the rows are copied a batch at a time, ``a[idx[s:s +
+    batch_size]]`` a column: one gather of ``batch_size`` rows, so the first
+    batch of an epoch costs a batch and not its block, and an epoch copies
+    each row once. A batch that spans two parts is the tail gather of one
+    joined to the head gather of the next; without shuffling a batch inside a
+    part is a contiguous view (read-only where the cache holds the block). A
+    block the cache does not keep lives until its last batch is cut.
+    ``feed_batches_cut_total{gathered|joined|sliced}`` counts which.
     """
 
     def __init__(
@@ -238,40 +251,61 @@ class HostBatchIterator:
         parts = self._parts()
         if self.shuffle:
             rng.shuffle(parts)
-        buffers: Dict[str, List[np.ndarray]] = {n: [] for n in self.columns}
+        # rows decoded and not yet cut, in the epoch's order: (arrays, rows)
+        # runs, where ``rows`` names what is left of a part in its block's
+        # arrays — the rest of the part's permutation, or a range — and is
+        # never empty. A run keeps its block's arrays until its last row is
+        # cut, cached or not
+        runs: Deque[Tuple[Dict[str, np.ndarray],
+                          Union[np.ndarray, range]]] = deque()
         buffered = 0
         for block_idx, off, length in parts:
+            if not length:
+                continue
             full_block = off == 0 and length == self._block_rows(block_idx)
             if full_block or block_idx in self._decoded:
-                arrays = self._decode_block(block_idx)
-                if self.shuffle and length > 1:
-                    idx = off + rng.permutation(length)
-                    sel = {n: a[idx] for n, a in arrays.items()}
-                else:
-                    sel = {n: a[off:off + length] for n, a in arrays.items()}
+                arrays, start = self._decode_block(block_idx), off
             else:
-                sel = self._decode_slice(block_idx, off, length)
-                if self.shuffle and length > 1:
-                    idx = rng.permutation(length)
-                    sel = {n: a[idx] for n, a in sel.items()}
-            for name in self.columns:
-                buffers[name].append(sel[name])
+                arrays, start = self._decode_slice(block_idx, off, length), 0
+            # the permutation is drawn a part, whole; the rows it names are
+            # copied a batch at a time, by the batch's slice of it
+            runs.append((arrays, start + rng.permutation(length)
+                         if self.shuffle and length > 1
+                         else range(start, start + length)))
             buffered += length
             while buffered >= self.batch_size:
-                batch, buffers, buffered = self._cut_batch(buffers, buffered)
-                yield pad_batch(batch, self.batch_size) \
-                    if self.pad_remainder else batch
+                yield self._cut_batch(runs, self.batch_size)
+                buffered -= self.batch_size
         if buffered > 0 and not self.drop_remainder:
-            batch = {n: np.concatenate(v, axis=0) for n, v in buffers.items()}
-            yield pad_batch(batch, self.batch_size) \
-                if self.pad_remainder else batch
+            yield self._cut_batch(runs, buffered)
 
-    def _cut_batch(self, buffers, buffered):
-        joined = {n: (np.concatenate(v, axis=0) if len(v) > 1 else v[0])
-                  for n, v in buffers.items()}
-        batch = {n: a[: self.batch_size] for n, a in joined.items()}
-        rest = {n: [a[self.batch_size:]] for n, a in joined.items()}
-        return batch, rest, buffered - self.batch_size
+    def _cut_batch(self, runs, want: int) -> Dict[str, np.ndarray]:
+        """The next ``want`` rows off the front of ``runs``: ONE gather of a
+        permutation's slice (or one contiguous view) where a run holds them
+        all, else the tail of one part joined to the head of the next."""
+        pieces = []
+        while want:
+            arrays, rows = runs[0]
+            head, rest = rows[:want], rows[want:]
+            if len(rest):
+                runs[0] = (arrays, rest)
+            else:
+                runs.popleft()
+            want -= len(head)
+            sliced = isinstance(head, range)
+            if sliced:
+                head = slice(head.start, head.stop)
+            pieces.append({n: a[head] for n, a in arrays.items()})
+        if len(pieces) > 1:
+            cut = "joined"
+            batch = {n: np.concatenate([p[n] for p in pieces], axis=0)
+                     for n in self.columns}
+        else:
+            cut = "sliced" if sliced else "gathered"
+            batch = pieces[0]
+        metrics.inc("feed_batches_cut_total", label=cut)
+        return pad_batch(batch, self.batch_size) \
+            if self.pad_remainder else batch
 
 
 def process_local_batch_rows(sharding, global_batch: int) -> Tuple[int, int]:
@@ -932,7 +966,9 @@ class DeviceFeed:
         async stage only moves the placement off the consumer's critical
         path."""
         # feed:start: an epoch's chain of stage threads is built anew, and
-        # the consumer waits for its first batch to come all the way through
+        # the consumer waits for its first batch to come all the way through:
+        # the threads' start, the host stage's gather of that ONE batch (its
+        # slice of the first part's permutation, drawn whole) and its H2D
         with profiler.step("feed:start"):
             if self.prefetch_to_device <= 0:
                 batches = map(self._timed_place, self._host_batches())

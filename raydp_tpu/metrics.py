@@ -288,6 +288,11 @@ _ALL_METRICS = [
        "ramping up its run-ahead at an epoch's start also pulls from an "
        "empty queue).",
        label="state"),
+    _m("feed_batches_cut_total", COUNTER, "1", "feed",
+       "Host batches HostBatchIterator cut, by how: gathered (one gather of "
+       "the batch's slice of a part's permutation), joined (the tail of one "
+       "part and the head of the next, concatenated) or sliced (a contiguous "
+       "view of a decoded block: no shuffle, no copy).", label="cut"),
     _m("train_epoch_seconds", HISTOGRAM, "s", "training",
        "Wall-clock of one training epoch (both estimators)."),
     _m("train_param_bytes_per_process", GAUGE, "bytes", "training",

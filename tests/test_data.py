@@ -144,6 +144,114 @@ def test_host_batch_iterator(shared_session):
         assert b["label"].shape == (128,)
 
 
+class _Blocks:
+    """An in-memory dataset of Arrow blocks: what HostBatchIterator asks of
+    one (``block_sizes``, ``get_block``), with every row's ``id`` unique."""
+
+    SIZES = (64, 64, 64, 40)
+
+    def __init__(self):
+        import pyarrow as pa
+        starts = np.cumsum((0,) + self.SIZES)
+        self.tables = [pa.table({
+            "id": np.arange(a, b, dtype=np.int64),
+            "x": np.arange(a, b, dtype=np.float64) * 0.5,
+            "y": np.arange(a, b, dtype=np.float64) % 7}) for a, b in
+            zip(starts[:-1], starts[1:])]
+
+    def block_sizes(self):
+        return list(self.SIZES)
+
+    def get_block(self, i, zero_copy=False):
+        return self.tables[i]
+
+
+_CUT_COLUMNS = {"feat": (["x", "y"], np.float32), "label": ("id", np.int64)}
+#: whole blocks, and a rank's parts: a whole block first (it is cached), a
+#: slice of a block that is not, a slice of the cached one, a block's head,
+#: one row (no permutation drawn), the rest of the second block
+_CUT_PARTS = {"blocks": None,
+              "parts": [(0, 0, 64), (1, 10, 30), (0, 5, 20), (2, 0, 50),
+                        (3, 39, 1), (1, 40, 24)]}
+_BLOCK_BYTES = 64 * (2 * 4 + 8)     # one decoded block of 64 rows
+
+
+def _parent_batches(ds, parts, batch_size, shuffle, seed, tail):
+    """The batches the way the iterator made them before it cut a batch by
+    its slice of the permutation: permute each part WHOLE with the same
+    ``RandomState``, concatenate the epoch, cut every ``batch_size`` rows."""
+    from raydp_tpu.data.feed import MASK_KEY
+    decoded = [{"feat": np.stack([t.column("x").to_numpy(),
+                                  t.column("y").to_numpy()],
+                                 axis=1).astype(np.float32),
+                "label": t.column("id").to_numpy().astype(np.int64)}
+               for t in ds.tables]
+    rng = np.random.RandomState(seed)
+    parts = list(parts) if parts is not None else [
+        (i, 0, n) for i, n in enumerate(ds.block_sizes())]
+    if shuffle:
+        rng.shuffle(parts)
+    permuted = []
+    for block, off, length in parts:
+        rows = {n: a[off:off + length] for n, a in decoded[block].items()}
+        if shuffle and length > 1:
+            idx = rng.permutation(length)
+            rows = {n: a[idx] for n, a in rows.items()}
+        permuted.append(rows)
+    epoch = {n: np.concatenate([r[n] for r in permuted]) for n in decoded[0]}
+    total = len(epoch["label"])
+    stop = total if tail != "drop" else total - total % batch_size
+    batches = []
+    for s in range(0, stop, batch_size):
+        batch = {n: a[s:s + batch_size] for n, a in epoch.items()}
+        if tail == "pad":
+            rows = len(batch["label"])
+            batch = {n: np.concatenate([a, np.zeros(
+                (batch_size - rows,) + a.shape[1:], a.dtype)])
+                for n, a in batch.items()}
+            batch[MASK_KEY] = (np.arange(batch_size) < rows).astype(np.float32)
+        batches.append(batch)
+    return batches
+
+
+@pytest.mark.parametrize("tail", ["drop", "ragged", "pad"])
+@pytest.mark.parametrize("cache", ["cached", "uncached", "capped"])
+@pytest.mark.parametrize("parts", ["blocks", "parts"])
+@pytest.mark.parametrize("batch_size", [16, 24, 100])
+@pytest.mark.parametrize("shuffle", [True, False])
+def test_host_batches_are_the_whole_part_permutation_cut(
+        shuffle, batch_size, parts, cache, tail):
+    """A batch is gathered by ITS slice of a part's permutation (or cut as a
+    view, or joined across parts): array for array and in order the batches
+    are those of permuting every part whole, over two epochs reseeded
+    through ``DeviceFeed.set_epoch``, whatever the cache holds."""
+    from raydp_tpu.data.feed import epoch_seed
+    ds = _Blocks()
+    shard = _CUT_PARTS[parts]
+    it = HostBatchIterator(
+        ds, batch_size, _CUT_COLUMNS,
+        shard=None if shard is None else ShardSpec(list(shard)),
+        shuffle=shuffle, seed=11, drop_remainder=tail == "drop",
+        pad_remainder=tail == "pad", cache_decoded=cache != "uncached",
+        cache_cap_bytes=2 * _BLOCK_BYTES if cache == "capped" else None)
+    feed = DeviceFeed(ds, batch_size, _CUT_COLUMNS, host_iter=it)
+    for epoch in range(2):
+        feed.set_epoch(epoch)
+        got = list(it)      # held past the epoch: no batch is overwritten
+        want = _parent_batches(ds, shard, batch_size, shuffle,
+                               epoch_seed(11, epoch + 1), tail)
+        assert len(got) == len(want) > 0
+        for g, w in zip(got, want):
+            assert list(g) == list(w)
+            for name in w:
+                assert g[name].dtype == w[name].dtype
+                np.testing.assert_array_equal(g[name], w[name])
+    # a block the cache does not keep is held for its batches, never cached
+    assert len(it._decoded) <= {"cached": 4, "uncached": 0, "capped": 2}[cache]
+    assert all(not a.flags.writeable for arrays in it._decoded.values()
+               for a in arrays.values())
+
+
 def test_device_feed_sharded(shared_session):
     import jax
     from jax.sharding import Mesh

@@ -75,6 +75,83 @@ def test_device_prefetcher_backpressure_bounds_readahead():
     assert list(it) == list(range(1, 100))  # drains cleanly afterwards
 
 
+class _TwoBlocks:
+    """Two Arrow blocks of 65,536 rows: the shape of a DLRM cell's feed."""
+
+    ROWS = 65_536
+
+    def __init__(self):
+        import pyarrow as pa
+        self.tables = [pa.table({
+            "id": np.arange(b * self.ROWS, (b + 1) * self.ROWS),
+            "x": np.arange(self.ROWS, dtype=np.float64) + b}) for b in (0, 1)]
+
+    def block_sizes(self):
+        return [self.ROWS, self.ROWS]
+
+    def get_block(self, i, zero_copy=False):
+        return self.tables[i]
+
+
+class _CountedRows:
+    """A decoded column that counts the rows every ``[]`` on it copies or
+    views, the way the host stage cuts its batches out of a block."""
+
+    def __init__(self, array, counts):
+        self.array, self.counts = array, counts
+
+    def __getitem__(self, key):
+        out = self.array[key]
+        self.counts.append(len(out))
+        return out
+
+
+def test_first_batch_of_an_epoch_costs_a_batch_not_a_block():
+    """Before the first ``yield`` of an epoch the host stage gathers the
+    4,096 rows of that batch, not the block's 65,536: the chip waits for a
+    batch at an epoch's boundary."""
+    from raydp_tpu.data.feed import HostBatchIterator
+    columns = {"feat": ("x", np.float32), "label": ("id", np.int64)}
+    it = HostBatchIterator(_TwoBlocks(), 4096, columns, shuffle=True, seed=3)
+    list(it)                # every block is in the cache, as after epoch 0
+    counts = []
+    decode_block = it._decode_block
+    it._decode_block = lambda b: {
+        n: _CountedRows(a, counts) for n, a in decode_block(b).items()}
+    batches = iter(it)
+    first = next(batches)
+    assert counts == [4096, 4096]       # one gather a column
+    assert first["label"].flags.owndata and first["label"].flags.writeable
+    assert sum(1 for _ in batches) == 31
+    assert counts == [4096] * 64        # an epoch gathers each row once
+
+
+@pytest.mark.parametrize("shuffle, batch_size", [
+    (True, 4096), (True, 24_576), (False, 4096), (False, 24_576)])
+def test_batches_cut_are_counted_by_how(shuffle, batch_size):
+    """``feed_batches_cut_total``: a batch inside one part is gathered (or,
+    unshuffled, sliced), one that crosses the block's end is joined; the
+    three labels add up to the batches yielded."""
+    from raydp_tpu import metrics
+    from raydp_tpu.data.feed import HostBatchIterator
+    ds = _TwoBlocks()
+    it = HostBatchIterator(ds, batch_size, {"label": ("id", np.int64)},
+                           shuffle=shuffle, seed=5)
+    metrics.reset()
+    batches = list(it)
+    cut = metrics.snapshot()["counters"]["feed_batches_cut_total"]
+    assert sum(cut.values()) == len(batches) == 2 * ds.ROWS // batch_size
+    joined = sum(1 for k in range(len(batches))
+                 if k * batch_size // ds.ROWS
+                 != ((k + 1) * batch_size - 1) // ds.ROWS)
+    assert joined == (0 if batch_size == 4096 else 1)
+    inside = "gathered" if shuffle else "sliced"
+    want = {inside: len(batches) - joined}
+    if joined:
+        want["joined"] = joined
+    assert cut == want
+
+
 # ---------------------------------------------------------- estimator level
 def _linear_df(session, n=1344):
     rng = np.random.RandomState(0)
