@@ -106,8 +106,27 @@ def _faults(ref):
                 "exit_probabilities": gate_without_survival}}
 
 
-def control(cell, seed: int) -> dict:
-    """Fit, then check (a) and its controls; returns name -> error."""
+def _exit_probabilities(cell, got, want) -> None:
+    """The mean exit distribution the program and the reference compared."""
+    import numpy as np
+
+    scale = cell.pipeline.exit_scale(cell.cfg)
+    passes = cell.cfg["total_ut_steps"]
+    print("EXIT_PROBABILITIES program", (
+        got[..., -passes:].mean(axis=(0, 1)) / scale).tolist(),
+        "reference", (want[..., -passes:].mean(axis=(0, 1))
+                      / scale).tolist(), "largest difference",
+        float(np.abs(got[..., -passes:] - want[..., -passes:]).max()
+              / scale), flush=True)
+
+
+def control(cell, seed: int, faults=_faults, report=_exit_probabilities,
+            name: str = "looped_control") -> dict:
+    """Fit, then check (a) and its controls; returns name -> error.
+    ``faults(reference module)``: the pieces to plant; ``report(cell, got,
+    want)``: what else to print of the compared outputs (None: nothing);
+    ``name``: the session's and the output directory's (another cell's
+    control, ``benchmarks/conv_control.py``, hands its own)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -117,12 +136,12 @@ def control(cell, seed: int) -> dict:
     from raydp_tpu.parallel import make_mesh
 
     ref, pipeline, cfg = cell.reference, cell.pipeline, cell.cfg
-    out_dir = os.path.join(ROOT, "chipbench", "out", "looped_control")
+    out_dir = os.path.join(ROOT, "chipbench", "out", name)
     os.makedirs(out_dir, exist_ok=True)
     rows = int(cell.wl["rows"])
     path = harness.write_input(cell, rows, seed, out_dir)
     os.environ.update(harness.residency_env(cell, rows))
-    session = raydp_tpu.init("looped_control", num_executors=2,
+    session = raydp_tpu.init(name, num_executors=2,
                              executor_cores=2, executor_memory="2GB")
     errors = {}
 
@@ -155,20 +174,14 @@ def control(cell, seed: int) -> dict:
 
         want = reference()
         said("program", harness.relative_rms_error(got, want))
-        scale = pipeline.exit_scale(cfg)
-        passes = cfg["total_ut_steps"]
-        print("EXIT_PROBABILITIES program", (
-            got[..., -passes:].mean(axis=(0, 1)) / scale).tolist(),
-            "reference", (want[..., -passes:].mean(axis=(0, 1))
-                          / scale).tolist(), "largest difference",
-            float(np.abs(got[..., -passes:] - want[..., -passes:]).max()
-                  / scale), flush=True)
-        for name in LOW_PRECISIONS:
-            said("reference_at_" + name, harness.relative_rms_error(
-                ref.at_precision(jnp.dtype(name), reference), want))
-        for name, patch in _faults(ref).items():
+        if report is not None:
+            report(cell, got, want)
+        for dtype in LOW_PRECISIONS:
+            said("reference_at_" + dtype, harness.relative_rms_error(
+                ref.at_precision(jnp.dtype(dtype), reference), want))
+        for fault, patch in faults(ref).items():
             with patched(ref, patch):
-                said(name, harness.relative_rms_error(got, reference()))
+                said(fault, harness.relative_rms_error(got, reference()))
     finally:
         raydp_tpu.stop()
         harness.reap_children()
@@ -190,15 +203,22 @@ LOOP_SCOPES = {
 }
 
 
-def by_scope(trace_dir: str, top: int = 40) -> None:
+def _loop_labels() -> dict:
     from chipbench import manifest
-    from chipbench.trace import reduce as reducer, scopes
 
     # the reader's own rule for what lies under the loop and under no block
     inside = manifest.load_module(ROOT, "layer_metrics",
                                   "loop_carry_share.py").INSIDE
-    labels = {"loop, under no block (loop_carry_share)":
-              lambda s: "/loop/" in s and not inside.search(s), **LOOP_SCOPES}
+    return {"loop, under no block (loop_carry_share)":
+            lambda s: "/loop/" in s and not inside.search(s), **LOOP_SCOPES}
+
+
+def by_scope(trace_dir: str, labels=None, top: int = 40) -> None:
+    """``labels``: name -> predicate on an ``op_name`` (None: the looped
+    cell's)."""
+    from chipbench.trace import reduce as reducer, scopes
+
+    labels = _loop_labels() if labels is None else labels
     xplane = reducer.find_xplane(trace_dir)
     reduced = reducer.reduce(xplane)
     names = scopes.op_names(xplane)

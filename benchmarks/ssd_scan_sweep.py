@@ -55,8 +55,14 @@ Needs a TPU; ``--interpret`` runs the kernels through the Pallas interpreter
 instead (any platform, toy shapes: the tier-1 smoke test), where a time means
 nothing.
 
+``--gated`` times the gated short convolution of a convolution operator
+(``raydp_tpu/ops/short_conv.py``: ``rdt_gated_conv_fwd|bwd``) beside its
+``jax.numpy`` form the same way (``--batch 2 --seq-len 8192`` is
+lfm2-8b-a1b's step: ``W_in u [2, 8192, 6144]``).
+
 Run: python benchmarks/ssd_scan_sweep.py [--forms] [--beside <ssd_scan.py>]
      python benchmarks/ssd_scan_sweep.py --glue [--rows R --lanes L --walk W]
+     python benchmarks/ssd_scan_sweep.py --gated --batch 2 --seq-len 8192
 """
 
 from __future__ import annotations
@@ -205,8 +211,7 @@ def glue(sg, args) -> dict:
                  interpret=args.interpret)
     conv = dict(offset=0, widths=widths)
     norm = dict(groups=args.groups, eps=1e-5, offset=0)
-    traced, size = not args.interpret, dtype.itemsize
-    nbytes = lambda columns: columns * b * t * size  # noqa: E731
+    nbytes = lambda columns: columns * b * t * dtype.itemsize  # noqa: E731
     stages = {
         "conv": dict(
             operands=(xbc, kernel, bias), grads=grads,
@@ -222,43 +227,89 @@ def glue(sg, args) -> dict:
             bytes=(nbytes(3 * inner), nbytes(5 * inner)))}
     out = {}
     for stage, of in stages.items():
-        operands, g = of["operands"], of["grads"]
-        both = lambda *a: jax.vjp(of["jnp"], *a[:3])[1](a[3])  # noqa: E731
         print(f"{stage} (tiles: {rules['tile']} rows, {sg.LANE_TILE} lanes, "
               f"walked {sg.CONV_WALK} / {sg.NORM_WALK} rows at a time):",
               flush=True)
-        read = {}
-        got, *read["forward"] = _timed(
-            "forward, kernels", f"rdt_ssm_{stage}_fwd", jax.jit(of["forward"]),
-            operands, args.iters, traced)
-        d_got, *read["backward"] = _timed(
-            "backward, kernels", f"rdt_ssm_{stage}_bwd",
-            jax.jit(of["backward"]), operands + (g,), args.iters, traced)
-        want, *read["forward_jnp"] = _timed(
-            "forward, jax.numpy", "", jax.jit(of["jnp"]), operands,
-            args.iters, traced)
-        d_want, *read["both_jnp"] = _timed(
-            "forward + backward, jax.numpy", "", jax.jit(both),
-            operands + (g,), args.iters, traced)
-        flat = lambda v: [a.astype(f32) for a in jax.tree.leaves(v)]  # noqa: E731
-        read["worst"] = max(
-            float(jnp.abs(a - w).max() / jnp.maximum(jnp.abs(w).max(), 1e-6))
-            for a, w in zip(flat((got, d_got)), flat((want, d_want))))
-        floors = [1e3 * n / HBM_BYTES_A_SECOND for n in of["bytes"]]
-        print(f"  against the jax.numpy form, value and gradients: worst "
-              f"|difference| / max {read['worst']:.3e}; bytes at 819 GB/s: "
-              f"forward {floors[0]:.3f} ms, backward {floors[1]:.3f} ms",
-              flush=True)
-        if traced:
-            ours = 2 * read["forward"][1] + read["backward"][1]
-            xla = read["forward_jnp"][1] + read["both_jnp"][1]
-            read["layer_ms"] = (ours, xla)
-            print(f"  a layer (forward + forward + backward): kernels "
-                  f"{ours:.3f} ms, jax.numpy {xla:.3f} ms: "
-                  f"{100 * ours / xla:.1f}% (the gate: at most 50%)",
-                  flush=True)
-        out[stage] = read
+        out[stage] = _stage(of, f"rdt_ssm_{stage}", args,
+                            "the gate: the kernels' at most half")
     return out
+
+
+def _stage(of: dict, kernel: str, args, gate: str) -> dict:
+    """One stage both ways: ``of["forward"]`` and ``of["backward"]`` (the
+    kernels ``<kernel>_fwd`` and ``<kernel>_bwd``: their own ms from a trace)
+    on ``of["operands"]`` (and ``of["grads"]``), beside ``of["jnp"]`` as XLA
+    runs it (forward; forward and backward by autodiff: every op's ms), the
+    worst difference between the two, ``of["bytes"]`` (forward, backward) at
+    819 GB/s, and a recomputed layer's forward + forward + backward both
+    ways against ``gate``."""
+    import jax
+    import jax.numpy as jnp
+
+    operands, g = of["operands"], of["grads"]
+    n, traced, f32 = len(operands), not args.interpret, jnp.float32
+    both = lambda *a: jax.vjp(of["jnp"], *a[:n])[1](a[n])  # noqa: E731
+    read = {}
+    got, *read["forward"] = _timed(
+        "forward, kernels", f"{kernel}_fwd", jax.jit(of["forward"]),
+        operands, args.iters, traced)
+    d_got, *read["backward"] = _timed(
+        "backward, kernels", f"{kernel}_bwd", jax.jit(of["backward"]),
+        operands + (g,), args.iters, traced)
+    want, *read["forward_jnp"] = _timed(
+        "forward, jax.numpy", "", jax.jit(of["jnp"]), operands, args.iters,
+        traced)
+    d_want, *read["both_jnp"] = _timed(
+        "forward + backward, jax.numpy", "", jax.jit(both), operands + (g,),
+        args.iters, traced)
+    flat = lambda v: [a.astype(f32) for a in jax.tree.leaves(v)]  # noqa: E731
+    read["worst"] = max(
+        float(jnp.abs(a - w).max() / jnp.maximum(jnp.abs(w).max(), 1e-6))
+        for a, w in zip(flat((got, d_got)), flat((want, d_want))))
+    floors = [1e3 * n / HBM_BYTES_A_SECOND for n in of["bytes"]]
+    print(f"  against the jax.numpy form, value and gradients: worst "
+          f"|difference| / max {read['worst']:.3e}; bytes at 819 GB/s: "
+          f"forward {floors[0]:.3f} ms, backward {floors[1]:.3f} ms",
+          flush=True)
+    if traced:
+        ours = 2 * read["forward"][1] + read["backward"][1]
+        xla = read["forward_jnp"][1] + read["both_jnp"][1]
+        read["layer_ms"] = (ours, xla)
+        print(f"  a layer (forward + forward + backward): kernels "
+              f"{ours:.3f} ms, jax.numpy {xla:.3f} ms: "
+              f"{100 * ours / xla:.1f}%, jax.numpy {xla / ours:.2f}x the "
+              f"kernels ({gate})", flush=True)
+    return read
+
+
+def gated(sc, args) -> dict:
+    """The gated short convolution of ``sc`` (``raydp_tpu/ops/
+    short_conv.py``) at one layer's shape (``W_in u [B, T, 3 width]``),
+    kernels alone beside the ``jax.numpy`` form in one process, as
+    :func:`glue` times a state-space mixer's stages (the gate this stage
+    was built against: XLA's within 1.3x of the kernels' and the simpler
+    form is kept)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, t, width = args.batch, args.seq_len, args.width
+    dtype = jnp.dtype(args.dtype)
+    r = np.random.default_rng(0)
+    src = jnp.asarray(r.normal(size=(b, t, 3 * width)), dtype)
+    taps = jnp.asarray(0.5 * r.normal(size=(3, width)), jnp.float32)
+    rules = dict(width=width, interpret=args.interpret,
+                 tile=sc._row_tile(t, args.rows or sc.ROW_TILE))
+    size = b * t * width * dtype.itemsize
+    print(f"gated conv (tiles: {rules['tile']} rows, walked {sc.WALK} rows "
+          f"at a time):", flush=True)
+    return _stage(dict(
+        operands=(src, taps),
+        grads=jnp.asarray(r.normal(size=(b, t, width)), dtype),
+        forward=lambda *a: sc._fwd_pallas(*a, **rules),
+        backward=lambda *a: sc._bwd_pallas(*a, **rules),
+        jnp=lambda *a: sc.gated_conv_jnp(*a, width),
+        bytes=(4 * size, 7 * size)), "rdt_gated_conv", args,
+        "the gate: jax.numpy within 1.3x and the kernels go")
 
 
 def _whole_width(hg: int, p: int):
@@ -384,6 +435,12 @@ def main(argv=None) -> dict:
                     help="the convolution and the gated norm round the scan "
                          "(ops/ssm_glue.py) beside their jax.numpy forms, "
                          "and not the scan")
+    ap.add_argument("--gated", action="store_true",
+                    help="the gated short convolution (ops/short_conv.py) "
+                         "beside its jax.numpy form, and not the scan; "
+                         "--batch, --seq-len, --width, --rows")
+    ap.add_argument("--width", type=int, default=2048,
+                    help="--gated: the channels of each of B, C and z")
     ap.add_argument("--rows", type=int, default=0,
                     help="--glue: rows a grid step (default: the op's)")
     ap.add_argument("--lanes", type=int, default=0,
@@ -403,6 +460,13 @@ def main(argv=None) -> dict:
         raise SystemExit(f"ssd_scan_sweep needs a TPU, found platform "
                          f"{jax.default_backend()!r} (--interpret runs the "
                          f"kernels interpreted)")
+    if args.gated:
+        from raydp_tpu.ops import short_conv
+
+        print(f"B={args.batch} T={args.seq_len} 3 x {args.width} channels "
+              f"{args.dtype} on {jax.devices()[0].device_kind}"
+              + (" (interpreted)" if args.interpret else ""), flush=True)
+        return {"gated": gated(short_conv, args)}
     if args.glue:
         from raydp_tpu.ops import ssm_glue
 

@@ -415,6 +415,24 @@ _ALL_METRICS = [
        "runs in its place) or `jnp` (a shape the kernels do not take: "
        "`ssm_glue.kernel_ineligible` says why). ops/ssm_glue.py.",
        label="path"),
+    _m("train_conv_layers_total", COUNTER, "1", "training",
+       "Pairs whose operator is a gated short convolution (`layer_kinds` "
+       "`C`: a `ShortConv`, module `short_conv`, where the other pairs have "
+       "attention) of a training model, counted once a built train step by "
+       "what a recomputed one does with its operator: `plain` (the layer is "
+       "not recomputed) or `recomputed` (`remat_blocks`: nothing of the "
+       "operator is kept; `W_in u`, the gated convolution and `W_out` run "
+       "again in the backward pass). doc/training.md.",
+       label="operator"),
+    _m("short_conv_total", COUNTER, "1", "training",
+       "Gated short convolutions (`C * conv(B * z)` between a convolution "
+       "operator's two projections), counted once a built layer call by the "
+       "path the call's shapes take: `kernel` (`rdt_gated_conv_fwd|bwd`, one "
+       "Pallas pass over HBM each way; where the program is lowered for "
+       "anything but a TPU the `jax.numpy` form runs in its place) or `jnp` "
+       "(a shape the kernels do not take: `short_conv.kernel_ineligible` "
+       "says why). ops/short_conv.py.",
+       label="path"),
     _m("flash_backward_total", COUNTER, "1", "training",
        "Backward passes of the flash-attention kernels, counted where one "
        "is built (a layer call each), by what it is made of: `fused` (one "
@@ -762,6 +780,22 @@ _ALL_SPANS = [
        "group of channels after it.", kind=SCOPE),
     _s("ssm/out_proj", "model",
        "a state-space mixer's output projection.", kind=SCOPE),
+    _s("short_conv", "model",
+       "every op of a pair's gated short convolution operator (`layer_kinds` "
+       "`C`, module name `short_conv`): its two projections and the gated "
+       "convolution between them; forward, recomputed and backward.",
+       kind=SCOPE),
+    _s("short_conv/in_proj", "model",
+       "a convolution operator's input projection to `B`, `C` and `z` "
+       "(three widths of the hidden size).", kind=SCOPE),
+    _s("short_conv/conv", "model",
+       "a convolution operator's stage between its projections, "
+       "`C * conv(B * z)`: two gates and a depthwise causal convolution of "
+       "a few taps, no bias, no activation (the kernels "
+       "`rdt_gated_conv_fwd` and `rdt_gated_conv_bwd`, or the `jax.numpy` "
+       "form's passes).", kind=SCOPE),
+    _s("short_conv/out_proj", "model",
+       "a convolution operator's output projection.", kind=SCOPE),
     _s("attn_gate", "model",
        "Under `attn`: the attention output times sigmoid of its gate "
        "projection (`attention_gate`), before the output projection.",

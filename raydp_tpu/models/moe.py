@@ -84,7 +84,8 @@ activation than the experts' input computes them itself with
 **Sigmoid routing with a balancing bias** (``routing="sigmoid"``; auxiliary-
 loss-free balancing). The scores are ``sigmoid(logits)``, each expert's own.
 The ``top_k`` experts are *picked* by ``score + bias`` and *weighed* by the
-bare scores, renormalised over the chosen (``normalize_top_k``) and scaled
+bare scores, renormalised over the chosen (``normalize_top_k``: over their
+sum plus ``normalize_eps``, 1e-20 or what a family states) and scaled
 (``route_scale``). ``bias [E]`` is float32 state that no gradient moves: it
 lies in the variable collection :data:`STATE` (the one
 :class:`raydp_tpu.train.FlaxEstimator` carries, shards and checkpoints beside
@@ -440,7 +441,7 @@ def router_logits(h: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
 
 def route(logits: jnp.ndarray, top_k: int, normalize: bool = False,
           kind: str = "softmax", bias: Optional[jnp.ndarray] = None,
-          scale: float = 1.0
+          scale: float = 1.0, eps: float = 1e-20
           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Float32 router logits ``[N, E]`` -> (scores ``[N, E]``, the ``top_k``
     expert ids ``[N, top_k]``, their weights). ``kind="softmax"``: the scores
@@ -449,7 +450,7 @@ def route(logits: jnp.ndarray, top_k: int, normalize: bool = False,
     softmax over the chosen logits alone). ``kind="sigmoid"``: the scores are
     ``sigmoid(logits)``, the experts are picked by ``score + bias`` (``bias
     [E]``, outside the gradient) and weighed by the bare scores, over their
-    sum where ``normalize``, times ``scale``."""
+    sum plus ``eps`` where ``normalize``, times ``scale``."""
     logits = logits.astype(jnp.float32)
     if kind == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
@@ -465,7 +466,7 @@ def route(logits: jnp.ndarray, top_k: int, normalize: bool = False,
     _, ids = jax.lax.top_k(picked_by, top_k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if normalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return scores, ids, weights * scale
 
 
@@ -533,6 +534,7 @@ class MoE(nn.Module):
     route_scale: float = 1.0
     shared_dim: int = 0                     # 0: no shared expert
     gated: bool = True                      # False: experts of two matrices
+    normalize_eps: float = 1e-20            # sigmoid routing: beside the sum
 
     @nn.compact
     def __call__(self, x, logits=None
@@ -564,7 +566,8 @@ class MoE(nn.Module):
                                        jnp.float32)
             probs, ids, weights = route(
                 logits, k, self.normalize_top_k, self.routing,
-                None if bias is None else bias.value, self.route_scale)
+                None if bias is None else bias.value, self.route_scale,
+                self.normalize_eps)
             # read back only by a caller that asks (mutable="intermediates")
             self.sow("intermediates", "top_k_ids", ids)
             slots = ids.reshape(-1)                              # [k * N]

@@ -91,6 +91,18 @@ the weight ``masked_i / t`` (:func:`lm_head_loss`'s ``position_weights``).
 ``embed_init_std`` gives the embedding's rows a std of their own beside
 ``init_std`` (doc/training.md says when that matters).
 
+And, since the ``lfm2`` family (convolution hybrids): the letter ``C`` in
+``layer_kinds``, the pair of ``B`` with a :class:`ShortConv` (module
+``short_conv``: a gated short convolution of ``conv_taps`` taps between two
+projections, :mod:`raydp_tpu.ops.short_conv`) where ``B`` has ``attn``; the
+letters are then ``B``, ``C``, ``M``, ``*``, ``E``, and ``B`` and ``C`` share
+``dense_layers``. A recomputed ``C`` layer keeps nothing of its operator
+(:attr:`TransformerLM.conv_layers`). ``tie_embeddings`` makes the head the
+embedding (no ``lm_head``; the fused loss takes ``embed.embedding`` as it
+lies, :func:`lm_head_loss`'s ``vocab_major``), and ``route_norm_eps`` is the
+epsilon beside the sum a sigmoid routing's chosen scores are renormalised
+over.
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -257,7 +269,9 @@ class Block(nn.Module):
     input; its logits are handed to the expert layer. ``sandwich_norms``: each
     sub-layer's output is normed too (``ln1_post``, ``ln2_post``) before it is
     added to the stream. ``kv_lora_rank``: the attention is a
-    :class:`LatentAttention` of the four widths given with it."""
+    :class:`LatentAttention` of the four widths given with it.
+    ``conv_taps``: the block's operator is a :class:`ShortConv`
+    (``short_conv``) where the others have ``attn``; the rest is the same."""
 
     num_heads: int
     mlp_ratio: int = 4
@@ -293,6 +307,9 @@ class Block(nn.Module):
     rope_interleave: bool = False
     expert_gated: bool = True
     blockdiff: Optional[int] = None         # Attention's, handed on
+    conv_taps: Optional[int] = None         # a number: the operator is a
+    # ShortConv of that many taps (``short_conv``) in the attention's place
+    route_norm_eps: float = 1e-20           # the expert layer's normalize_eps
 
     @nn.compact
     def __call__(self, x):
@@ -312,7 +329,7 @@ class Block(nn.Module):
                              f"'experts' or 'attention'")
         post = (lambda name, y: RMSNorm(eps, name=name)(y)) \
             if self.sandwich_norms else (lambda name, y: y)
-        x = x + post("ln1_post", _attention(self)(u))
+        x = x + post("ln1_post", _operator(self)(u))
         h = RMSNorm(eps, name="ln2")(x)
         hidden = self.ffn_dim or self.mlp_ratio * dim
         if self.num_experts:
@@ -321,7 +338,7 @@ class Block(nn.Module):
                          self.experts_held, self.expert_activation,
                          self.normalize_top_k, self.routing, self.route_scale,
                          self.shared_expert_dim, self.expert_gated,
-                         name="moe")(h, logits)
+                         self.route_norm_eps, name="moe")(h, logits)
             return x + post("ln2_post", _ffn_out(self, y)), aux
         # SwiGLU
         dense = lambda n, name: nn.Dense(  # noqa: E731
@@ -390,15 +407,20 @@ class TransformerLM(nn.Module):
     # exists and the loss is the expected loss less this times the entropy
     exit_probs_out: bool = False            # a plain call's logits end in
     # the exit distribution's ``total_ut_steps`` probabilities a position
+    # the ``lfm2`` family (the section at the file's end)
+    conv_taps: int = 3                      # a "C" layer's ShortConv
+    tie_embeddings: bool = False            # the head IS the embedding
+    route_norm_eps: float = 1e-20           # beside a renormalised top-k's sum
 
     def _kind(self, layer: int) -> str:
-        """``B`` the pair (attention, then a feed-forward part), or the one
+        """``B`` the pair (attention, then a feed-forward part), ``C`` the
+        pair whose operator is a gated short convolution, or the one
         sub-layer the layer is: ``M`` state-space mixer, ``*`` attention,
         ``E`` experts."""
         kinds = self.layer_kinds or "B" * self.num_layers
-        if len(kinds) != self.num_layers or set(kinds) - set("BM*E"):
+        if len(kinds) != self.num_layers or set(kinds) - set("BCM*E"):
             raise ValueError(f"layer_kinds {kinds!r}: {self.num_layers} "
-                             f"letters of 'B', 'M', '*', 'E'")
+                             f"letters of 'B', 'C', 'M', '*', 'E'")
         return kinds[layer]
 
     def _layers_of(self, kinds: str):
@@ -419,7 +441,7 @@ class TransformerLM(nn.Module):
         layers of that kind times ``total_ut_steps``: a looped model runs
         each once a pass): what ``train_attention_layers_total`` counts once
         a built step. A latent layer counts under its kernel's kind and under
-        ``latent``."""
+        ``latent``; a ``C`` layer has no attention and counts under none."""
         layers = self._layers_of("B*")
         windowed = sum(self._windowed(i) for i in layers)
         kinds = {"window": windowed, "full": len(layers) - windowed}
@@ -437,6 +459,16 @@ class TransformerLM(nn.Module):
                 len(self._layers_of("M"))}
 
     @property
+    def conv_layers(self):
+        """The pairs whose operator is a gated short convolution (``C``) by
+        what a recomputed one does with it: what ``train_conv_layers_total``
+        counts once a built step. ``recomputed``: nothing of the operator is
+        kept (``W_in u``, the stage and ``W_out`` run again in the backward
+        pass); ``plain``: the layer is not recomputed."""
+        return {"recomputed" if self.remat_blocks else "plain":
+                len(self._layers_of("C"))}
+
+    @property
     def attention_forward(self):
         """How often a train step runs each layer's forward attention, by
         the layers a step executes (layers times ``total_ut_steps``): what
@@ -445,7 +477,7 @@ class TransformerLM(nn.Module):
         output and row sums; ``twice``: a recomputed block whose attention
         names nothing to keep (``dense`` and ``ring``: their ``[T, T]``
         scores must not be kept). ``auto`` counts as what it picks for a
-        shape the kernel takes."""
+        shape the kernel takes. A ``C`` layer has no attention to run."""
         d_qk, d_v = self.head_dim or self.dim // self.num_heads, None
         if self.kv_lora_rank is not None:
             d_qk, d_v = (self.qk_nope_head_dim + self.qk_rope_head_dim,
@@ -475,7 +507,7 @@ class TransformerLM(nn.Module):
     def _sparse(self, layer: int) -> bool:
         kind = self._kind(layer)
         return bool(self.num_experts) and (
-            kind == "E" or (kind == "B" and layer >= self.dense_layers))
+            kind == "E" or (kind in "BC" and layer >= self.dense_layers))
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False, labels=None,
@@ -498,12 +530,13 @@ class TransformerLM(nn.Module):
             tokens, noise = _noised_row(self, tokens)
         init = _init(self.init_std if self.embed_init_std is None
                      else self.embed_init_std, nn.linear.default_embed_init)
-        x = nn.Embed(self.vocab_size, self.dim, name="embed",
-                     dtype=self.dtype, embedding_init=init)(tokens)
+        embed = nn.Embed(self.vocab_size, self.dim, name="embed",
+                         dtype=self.dtype, embedding_init=init)
+        x = embed(tokens)
         if self.embed_scale:
             x = x * jnp.asarray(np.sqrt(self.dim), x.dtype)
         if _is_looped(self):
-            return _looped(self, x, return_hidden, labels, weights)
+            return _looped(self, x, return_hidden, labels, weights, embed)
         aux, kept = [], _kept(self)
         block = _recomputed(Block, kept)        # one class for every pair
         for i in range(self.num_layers):
@@ -512,9 +545,7 @@ class TransformerLM(nn.Module):
                 x, layer_aux = x
                 aux.append(layer_aux)
         x = RMSNorm(self.rms_norm_eps, name="ln_f")(x)
-        head = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
-                        name="lm_head", kernel_init=_init(
-                            self.init_std, nn.linear.default_kernel_init))
+        head = _head(self, embed)
         if labels is None and not return_hidden:
             if noise is not None:       # the noised half's, [B, T, vocab]
                 x = x[:, x.shape[1] // 2:]
@@ -522,11 +553,13 @@ class TransformerLM(nn.Module):
         head(x[:, :1])      # registers the kernel (result DCE'd); the head
         if labels is None:  # itself is applied chunk-wise by the fused loss
             return x
+        kernel = _head_kernel(self, embed, head)
         if noise is not None:
-            return _diffusion_loss(self, x, head.variables["params"][
-                "kernel"], labels, weights, noise, aux)
-        loss, _ = lm_head_loss(x, head.variables["params"]["kernel"], labels,
-                               weights, chunk=max(128, 2048 // x.shape[0]))
+            return _diffusion_loss(self, x, kernel, labels, weights, noise,
+                                   aux)
+        loss, _ = lm_head_loss(x, kernel, labels, weights,
+                               chunk=max(128, 2048 // x.shape[0]),
+                               vocab_major=self.tie_embeddings)
         if not aux:
             return loss, jnp.zeros((0,), jnp.float32)
         return self._with_aux(loss, weights, aux)
@@ -602,12 +635,13 @@ class TransformerLM(nn.Module):
         ``sandwich_norms``) by what a recomputed block does with them: what
         ``train_sublayer_out_total`` counts once a built step, by the blocks
         a step executes (blocks times ``total_ut_steps``). ``kept``: the
-        feed-forward's (``SUBLAYER_OUT``); ``rebuilt``: the attention's (its
-        output projection runs again). Nothing where no block is recomputed
+        feed-forward's (``SUBLAYER_OUT``); ``rebuilt``: the operator's (the
+        attention's output projection, or a ``C`` pair's whole operator, runs
+        again). Nothing where no block is recomputed
         or no norm reads them."""
         if not (self.remat_blocks and self.sandwich_norms):
             return {}
-        pairs = len(self._layers_of("B")) * self.total_ut_steps
+        pairs = len(self._layers_of("BC")) * self.total_ut_steps
         return {"kept": pairs, "rebuilt": pairs}
 
     @property
@@ -662,7 +696,7 @@ def _head_chunks(hidden, tokens, chunk, position_weights=None,
 
 
 def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
-               position_weights=None, shifted=False):
+               position_weights=None, shifted=False, vocab_major=False):
     """One scan over the chunks of :func:`_head_chunks`. A chunk's logits
     (``[B, C, V]`` float32: operands in the activations' dtype, float32
     accumulation) exist once, inside the scan's body; from them come the
@@ -678,7 +712,10 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
     (:func:`_head_chunks`); ``shifted``, ``sum_i w_i CE(logits_i, token_i+1)
     / (T - 1)``, and a fourth result: every position's cross entropy
     ``[B, T]`` float32 (the last position's is 0), which is the weights'
-    gradient."""
+    gradient. ``vocab_major``: the kernel is ``[V, D]`` (a tied model's
+    embedding, as it lies) and so is its gradient: the three products
+    contract over the dimensions that layout gives them, and nothing of the
+    embedding's size is transposed."""
     from jax import lax
 
     B, T, D = hidden.shape
@@ -686,13 +723,18 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
     xs, ys, ms = _head_chunks(hidden, tokens, chunk, position_weights,
                               shifted)
     k = kernel.astype(hidden.dtype)      # cast once, not once a chunk
-    vocab = lax.broadcasted_iota(jnp.int32, (1, 1, k.shape[1]), 2)
+    vocab = lax.broadcasted_iota(
+        jnp.int32, (1, 1, k.shape[0 if vocab_major else 1]), 2)
     scale = weights.astype(jnp.float32)[:, None] / n            # [B, 1]
 
     def body(carry, chunk_of):
         total, dk = carry
         xc, yc, mc = chunk_of
-        logits = jnp.dot(xc, k, preferred_element_type=jnp.float32)
+        if vocab_major:
+            logits = lax.dot_general(xc, k, (((2,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.dot(xc, k, preferred_element_type=jnp.float32)
         top = logits.max(axis=-1, keepdims=True)
         e = jnp.exp(logits - top)
         z = e.sum(axis=-1, keepdims=True)
@@ -704,9 +746,11 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
             return (total, None), None
         g = (scale * mc)[..., None]                             # [B, C, 1]
         dlogits = e * (g / z) - jnp.where(label, g, 0.0)
-        dxc = lax.dot_general(dlogits, k, (((2,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-        dk = dk + lax.dot_general(xc, dlogits, (((0, 1), (0, 1)), ((), ())),
+        dxc = lax.dot_general(
+            dlogits, k, (((2,), (0 if vocab_major else 1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        pair = (dlogits, xc) if vocab_major else (xc, dlogits)
+        dk = dk + lax.dot_general(*pair, (((0, 1), (0, 1)), ((), ())),
                                   preferred_element_type=jnp.float32)
         if shifted:
             return (total, dk), (dxc.astype(xc.dtype), ce)
@@ -729,21 +773,23 @@ def _head_scan(hidden, kernel, tokens, weights, chunk, with_grads,
     return (rows, dx, dk, ce) if shifted else (rows, dx, dk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _head_loss(hidden, kernel, tokens, weights, chunk, position_weights):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6))
+def _head_loss(hidden, kernel, tokens, weights, chunk, position_weights,
+               vocab_major=False):
     rows, _, _ = _head_scan(hidden, kernel, tokens, weights, chunk, False,
-                            position_weights)
+                            position_weights, vocab_major=vocab_major)
     return jnp.sum(weights * rows), rows
 
 
-def _head_loss_fwd(hidden, kernel, tokens, weights, chunk, position_weights):
+def _head_loss_fwd(hidden, kernel, tokens, weights, chunk, position_weights,
+                   vocab_major):
     rows, dh, dk = _head_scan(hidden, kernel, tokens, weights, chunk, True,
-                              position_weights)
+                              position_weights, vocab_major=vocab_major)
     return (jnp.sum(weights * rows), rows), (dh, dk.astype(kernel.dtype),
                                              rows)
 
 
-def _head_loss_bwd(chunk, residuals, cotangents):
+def _head_loss_bwd(chunk, vocab_major, residuals, cotangents):
     dh, dk, rows = residuals
     g, _ = cotangents           # the rows are reported, not differentiated
     with jax.named_scope("lm_head_loss"):
@@ -758,7 +804,7 @@ _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
                  tokens: jnp.ndarray, weights: jnp.ndarray,
                  chunk: int = 1024, position_weights=None,
-                 next_token_weights=None):
+                 next_token_weights=None, vocab_major: bool = False):
     """Next-token cross entropy with the lm_head FUSED into the loss:
     ``(sum(weights * rows), rows)``, ``rows`` ``[B]`` float32 the mean
     cross entropy of each sequence (reported: no gradient flows from them).
@@ -792,15 +838,23 @@ def lm_head_loss(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
     hands over its passes as rows (``[passes * B, T, D]``, the tokens and the
     rows' weights repeated) under each pass's exit probabilities: all of
     them share the one scan and its one ``[D, V]`` carry.
+
+    ``vocab_major`` (off by default): ``lm_head_kernel`` is ``[V, D]``, a
+    tied model's ``params["embed"]["embedding"]`` as it lies, and so is its
+    gradient. The plain next-token form contracts over the dimensions that
+    layout gives it; the two weighted forms take its transpose.
     """
     with jax.named_scope("lm_head_loss"):
+        if vocab_major and (position_weights is not None
+                            or next_token_weights is not None):
+            lm_head_kernel, vocab_major = lm_head_kernel.T, False
         if next_token_weights is not None:
             if position_weights is not None:
                 raise ValueError("position_weights or next_token_weights")
             return _weighted_head_loss(hidden, lm_head_kernel, tokens,
                                        weights, chunk, next_token_weights)
         return _head_loss(hidden, lm_head_kernel, tokens, weights, chunk,
-                          position_weights)
+                          position_weights, vocab_major)
 
 
 def lm_loss_fused(hidden: jnp.ndarray, lm_head_kernel: jnp.ndarray,
@@ -845,6 +899,9 @@ def transformer_param_rules(axis: str = "tensor"):
         ("gate/kernel", (None, axis)),
         ("up/kernel", (None, axis)),
         ("down/kernel", (axis, None)),
+        # a tied model (``tie_embeddings``) has the one array, placed as an
+        # embedding is; a convolution operator (short_conv/in_proj, conv,
+        # out_proj) matches no rule and stays whole on every device
         ("embed/embedding", (None, axis)),
         ("lm_head/kernel", (None, axis)),
     ]
@@ -957,9 +1014,13 @@ class LatentAttention(nn.Module):
                                use_bias=False, kernel_init=init)(out)
 
 
-def _attention(block):
-    """A block's attention sub-layer, ``attn``: :class:`Attention` or, where
-    the block states a K/V latent, :class:`LatentAttention`."""
+def _operator(block):
+    """A block's first sub-layer: ``attn``, :class:`Attention` or, where the
+    block states a K/V latent, :class:`LatentAttention`; or, where it states
+    a convolution's taps, ``short_conv``, a :class:`ShortConv`."""
+    if block.conv_taps is not None:
+        return ShortConv(block.conv_taps, block.dtype, block.init_std,
+                         block.mesh, name="short_conv")
     if block.kv_lora_rank is None:
         return Attention(
             block.num_heads, block.attention, block.mesh, block.dtype,
@@ -1130,10 +1191,10 @@ def _recomputed(cls, kept):
 def _layer(model, i: int, kept, block):
     """Layer ``i`` of a model, ``block_<i>``: the pair (``B``; ``block`` is
     the class, ``_recomputed(Block, kept)`` made once a model so that the
-    layers share what they trace alike) or the one sub-layer ``layer_kinds``
-    makes it."""
+    layers share what they trace alike; ``C``: the same class with a
+    convolution operator) or the one sub-layer ``layer_kinds`` makes it."""
     kind = model._kind(i)
-    if kind == "B":
+    if kind in "BC":
         sparse = model._sparse(i)
         return block(
             model.num_heads, model.mlp_ratio, model.attention, model.mesh,
@@ -1149,7 +1210,9 @@ def _layer(model, i: int, kept, block):
             model.routing, model.route_scale, model.shared_expert_dim,
             model.kv_lora_rank, model.q_lora_rank, model.qk_nope_head_dim,
             model.qk_rope_head_dim, model.v_head_dim, model.rope_interleave,
-            model.expert_gated, model._blockdiff, name=f"block_{i}")
+            model.expert_gated, model._blockdiff,
+            model.conv_taps if kind == "C" else None, model.route_norm_eps,
+            name=f"block_{i}")
     if kind == "M":
         if model.ssm is None:
             raise ValueError("an 'M' layer needs the model's ssm=SSMSpec(..)")
@@ -1175,7 +1238,8 @@ def _layer(model, i: int, kept, block):
             _init(model.init_std, nn.linear.default_kernel_init),
             model.first_expert, model.experts_held, model.expert_activation,
             model.normalize_top_k, model.routing, model.route_scale,
-            model.shared_expert_dim, model.expert_gated, name="moe")
+            model.shared_expert_dim, model.expert_gated,
+            model.route_norm_eps, name="moe")
     return _recomputed(Layer, kept)(mixer, model.rms_norm_eps,
                                     name=f"block_{i}")
 
@@ -1250,7 +1314,8 @@ def _diffusion_loss(model, x, kernel, labels, weights, noise, aux):
     half = x.shape[1] // 2
     loss, _ = lm_head_loss(x[:, half:], kernel, labels, weights,
                            chunk=max(128, 2048 // x.shape[0]),
-                           position_weights=weight)
+                           position_weights=weight,
+                           vocab_major=model.tie_embeddings)
     counts = jnp.stack([masked, jnp.float32(labels.size)])
     if not aux:
         return loss, counts
@@ -1312,9 +1377,9 @@ def exit_distribution(gate_logits):
         jnp.zeros_like(gate_logits[:1])])
 
 
-def _looped(model, x, return_hidden, labels, weights):
+def _looped(model, x, return_hidden, labels, weights, embed):
     """What :meth:`TransformerLM.__call__` returns for a looped model, from
-    the embeddings ``x`` on."""
+    the embeddings ``x`` (of the module ``embed``) on."""
     if (model.num_experts or model.diffusion is not None
             or set(model.layer_kinds) - {"B"}):
         raise ValueError("a looped model (total_ut_steps > 1 or an exit "
@@ -1334,8 +1399,7 @@ def _looped(model, x, return_hidden, labels, weights):
                         split_rngs={"params": False},
                         length=model.total_ut_steps)(model, x, None)
     init = _init(model.init_std, nn.linear.default_kernel_init)
-    head = nn.Dense(model.vocab_size, use_bias=False, dtype=model.dtype,
-                    name="lm_head", kernel_init=init)
+    head = _head(model, embed)
     log_p = None
     if model.exit_entropy_weight is not None:
         with jax.named_scope("exit_gate"):
@@ -1351,11 +1415,13 @@ def _looped(model, x, return_hidden, labels, weights):
     head(x[:, :1])      # registers the kernel, as the plain model does
     if labels is None:
         return x
-    kernel = head.variables["params"]["kernel"]
+    kernel = _head_kernel(model, embed, head)
     passes, rows = hs.shape[:2]
+    tied = model.tie_embeddings
     if log_p is None:
         loss, _ = lm_head_loss(x, kernel, labels, weights,
-                               chunk=max(128, 2048 // rows))
+                               chunk=max(128, 2048 // rows),
+                               vocab_major=tied)
         return loss, jnp.zeros((0,), f32)
     with jax.named_scope("exit_gate"):
         p = jnp.exp(log_p)                                  # [P, B, T]
@@ -1363,7 +1429,7 @@ def _looped(model, x, return_hidden, labels, weights):
         hs.reshape((passes * rows,) + hs.shape[2:]), kernel,
         jnp.tile(labels, (passes, 1)), jnp.tile(weights, passes),
         chunk=max(128, 2048 // (passes * rows)),
-        next_token_weights=p.reshape(passes * rows, -1))
+        next_token_weights=p.reshape(passes * rows, -1), vocab_major=tied)
     with jax.named_scope("exit_gate"):
         # over the positions that carry a loss (the last predicts nothing)
         p, log_p = p[..., :-1], log_p[..., :-1]
@@ -1404,3 +1470,72 @@ def _weighted_head_loss_bwd(chunk, residuals, cotangents):
 
 
 _weighted_head_loss.defvjp(_weighted_head_loss_fwd, _weighted_head_loss_bwd)
+
+
+# ---------------------------------------------------------------------------
+# A pair whose operator is a gated short convolution, and tied embeddings
+# (the ``lfm2`` family). Down here for the reason ``SUBLAYER_OUT`` is.
+# ---------------------------------------------------------------------------
+class ShortConv(nn.Module):
+    """A gated short convolution on a normed input ``u [B, T, D]``, the
+    operator of a ``C`` pair::
+
+        B, C, z = split(W_in u)           three widths of D, in this order
+        out = W_out (C * conv(B * z))     depthwise, causal, ``taps`` taps,
+                                          zeros before the sequence, no bias,
+                                          no activation
+
+    The stage between the projections is :func:`raydp_tpu.ops.short_conv.
+    gated_conv`: float32 arithmetic, one Pallas pass over HBM each way where
+    the program is lowered for a TPU and the shapes allow (``rdt_gated_conv_
+    fwd|bwd``, reading the three widths out of ``W_in u`` by block index), its
+    ``jax.numpy`` form elsewhere. Each part lies under a scope of its own
+    (``in_proj``, ``conv``, ``out_proj``) so that a trace prices it. The
+    window runs through the whole sequence (a packed row's documents are not
+    told apart, as attention attends across them). A recomputed pair
+    (``remat_blocks``) keeps nothing of it: ``W_in u`` is rebuilt. Over a
+    mesh the stage is mapped over the batch; a ``seq`` axis raises."""
+
+    taps: int = 3
+    dtype: Any = jnp.float32
+    init_std: Optional[float] = None
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, u):
+        from raydp_tpu.ops.short_conv import gated_conv_sharded
+        from raydp_tpu.parallel.mesh import seq_extent
+
+        if self.mesh is not None and seq_extent(self.mesh) > 1:
+            raise NotImplementedError(
+                "a short convolution takes no seq axis: its window reaches "
+                "back over the rows before a position")
+        dim = u.shape[-1]
+        init = _init(self.init_std, nn.linear.default_kernel_init)
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name, kernel_init=init)
+        proj = dense(3 * dim, "in_proj")(u)
+        taps = self.param("conv", init, (self.taps, dim))
+        with jax.named_scope("conv"):
+            y = gated_conv_sharded(proj, taps, dim, self.mesh)
+        return dense(dim, "out_proj")(y)
+
+
+def _head(model, embed):
+    """The head of a model: ``lm_head``, a kernel of its own, or
+    (``tie_embeddings``) the embedding read the other way: no parameter."""
+    if model.tie_embeddings:
+        return embed.attend
+    return nn.Dense(model.vocab_size, use_bias=False, dtype=model.dtype,
+                    name="lm_head", kernel_init=_init(
+                        model.init_std, nn.linear.default_kernel_init))
+
+
+def _head_kernel(model, embed, head):
+    """What the fused loss takes as the head's kernel: ``lm_head``'s ``[D,
+    V]`` or, tied, the embedding ``[V, D]`` as it lies (``lm_head_loss``'s
+    ``vocab_major``): ONE float32 leaf then takes the gather's gradient and
+    the head's, and has one optimizer state and one checkpoint array."""
+    if model.tie_embeddings:
+        return embed.embedding
+    return head.variables["params"]["kernel"]

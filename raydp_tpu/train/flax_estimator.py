@@ -299,6 +299,8 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     apply_fn.ssm_layers = getattr(model, "ssm_layers", None) or {}
     # {"recomputed": n} or {"plain": n}: a looped model's layers x passes
     apply_fn.loop_passes = getattr(model, "loop_passes", None) or {}
+    # {"recomputed": n} or {"plain": n}: pairs with a convolution operator
+    apply_fn.conv_layers = getattr(model, "conv_layers", None) or {}
     if callable(getattr(model, "lookups", None)):
         apply_fn.lookups = lambda batch: model.lookups(split_batch(batch)[0])
     return apply_fn
@@ -525,7 +527,8 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
             ("train_attention_forward_total", "attention_forward"),
             ("train_sublayer_out_total", "sublayer_out"),
             ("train_ssm_layers_total", "ssm_layers"),
-            ("train_loop_passes_total", "loop_passes")):
+            ("train_loop_passes_total", "loop_passes"),
+            ("train_conv_layers_total", "conv_layers")):
         for kind, layers in getattr(apply_fn, of_model, {}).items():
             if layers:      # once a built step, by kind of layer
                 rdt_metrics.inc(metric, layers, kind)

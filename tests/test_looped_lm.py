@@ -398,8 +398,13 @@ def test_the_lowered_step_holds_each_layer_once_whatever_the_passes():
 # had, by ``tests/test_blockdiff_moe_lm.py``, ``tests/test_ssm_moe_lm.py``
 # and ``tests/test_mla_moe_lm.py``, which this PR leaves as they are. A PR
 # that means to change one of these programs replaces its line (PR 58 both:
-# a share's held rows come back to token order in runs).
+# a share's held rows come back to token order in runs). Since PR 61 (a pair
+# with a convolution operator, tied embeddings, the routing's epsilon a
+# field: all off by default) the looped family's own cut is held too, with
+# the hash it had on the commit before that PR (3eb6cc9).
 PARENT_STEP = {
+    "ouro-2.6b":
+        "31a058df325c6a3362ae0e92c7fe6efcf05781d1f51c21a7f03d65bcb7bd8cce",
     "sdar-30b-a3b-chat":
         "c522fafd2608364c82b3a2b6fbae56dda36091c1950cef00e58182155d0675f9",
     "trinity-mini":
@@ -409,12 +414,20 @@ PARENT_STEP = {
 
 @pytest.mark.parametrize("config,cell", [
     ("sdar-30b-a3b-chat", "sdar_30ba3b_8k_blockdiff_train"),
-    ("trinity-mini", "trinity_mini_8k_train")])
+    ("trinity-mini", "trinity_mini_8k_train"),
+    ("ouro-2.6b", "ouro_2p6b_8k_train")])
 def test_a_model_of_one_pass_and_no_gate_is_the_step_it_was(config, cell):
     """``total_ut_steps`` 1 and no gate are the defaults: the layers are
     built by the one helper the loop's body uses too, and the lowered step of
-    an older family is the text it was, to the letter."""
-    model, text, _ = _step_text(config, cell)
-    assert model.total_ut_steps == 1 and model.exit_entropy_weight is None
-    assert model.loop_passes == {} and not model.exit_probs_out
+    an older family is the text it was, to the letter. So are no ``C`` layer,
+    a head of its own and the routing's 1e-20: the looped family's step (its
+    head and its loop handed the embedding's module since) is its text too."""
+    model, text, params = _step_text(config, cell)
+    assert (model.total_ut_steps == 1) == (config != "ouro-2.6b")
+    assert (model.exit_entropy_weight is None) == (config != "ouro-2.6b")
+    assert bool(model.loop_passes) == model.exit_probs_out == (
+        config == "ouro-2.6b")
+    assert not model.tie_embeddings and "lm_head" in params
+    assert model.conv_layers == {"recomputed": 0}
+    assert model.route_norm_eps == 1e-20 and "C" not in model.layer_kinds
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]
