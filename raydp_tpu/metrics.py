@@ -381,6 +381,17 @@ _ALL_METRICS = [
        "attention names nothing to keep: `dense`, `ring`). "
        "doc/long_context.md.",
        label="times"),
+    _m("train_attention_inputs_total", COUNTER, "1", "training",
+       "Attention layer executions of a training model's step (layers x "
+       "`total_ut_steps`) in a model whose layers are recomputed "
+       "(`remat_blocks`), counted once a built train step by what the "
+       "recomputation does with the attention's inputs (q, k and v as the "
+       "flash kernel takes them, and the raw projections a head norm or a "
+       "gate reads): `kept` (the checkpoint's policy lists them by name: the "
+       "backward runs no projection, head norm or RoPE a second time) or "
+       "`rebuilt` (it lists none: latent attention, a looped stack, `dense`, "
+       "`ring`). Absent where no layer is recomputed. doc/long_context.md.",
+       label="inputs"),
     _m("train_sublayer_out_total", COUNTER, "1", "training",
        "Sub-layer outputs that a second norm reads (`sandwich_norms`: the "
        "attention's and the feed-forward's, two a block) in a training "

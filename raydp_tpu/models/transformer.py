@@ -38,13 +38,13 @@ layer's ``expert_activation``, ``normalize_top_k``, ``first_expert`` and
 ``router_input="attention"`` (the router reads the attention's normed input,
 not the experts'). ``remat_blocks`` recomputes each block in the backward
 pass from what it saved: its input, its flash kernel's output and row sums
-(``flash_attention.RESIDUAL_NAMES``) and, under ``sandwich_norms``, the
-feed-forward sub-layer's output (``SUBLAYER_OUT``, ``B*T x dim``: the second
-norm on it reads it in the backward). So norms, q, k, v, RoPE, gate, ``W_o``
-and router run again; the forward kernel and, where that output is kept, the
-feed-forward's (the held experts') forward walk do not
-(:attr:`TransformerLM.attention_forward`, ``.sublayer_out``). A vocabulary-
-parallel share is a smaller ``vocab_size``, ids drawn from the rows held.
+(``flash_attention.RESIDUAL_NAMES``), its attention's inputs (the file's end)
+and, under ``sandwich_norms``, the feed-forward's output (``SUBLAYER_OUT``: the
+second norm reads it in the backward). So norms, ``W_o`` and router run
+again; q, k, v with head norms and RoPE, the gate's projection, the forward
+kernel and, where its output is kept, the held experts' forward walk do not
+(``.attention_forward``, ``.attention_inputs``, ``.sublayer_out``). A
+vocabulary-parallel share is a smaller ``vocab_size``, ids from the rows held.
 
 And, since the ``afmoe`` family (Trinity): ``qk_norm="head"`` (RMSNorm over
 each head's ``head_dim``, one weight for the query heads and one for the K/V
@@ -210,9 +210,9 @@ class Attention(nn.Module):
         head_dim = self.head_dim or dim // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
         init = _init(self.init_std, nn.linear.default_kernel_init)
-        dense = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+        dense = lambda name, heads: _raw(nn.DenseGeneral(  # noqa: E731
             (heads, head_dim), axis=-1, name=name, dtype=self.dtype,
-            use_bias=False, kernel_init=init)
+            use_bias=False, kernel_init=init))
         q = dense("q", self.num_heads)(x)
         k, v = dense("k", kv_heads)(x), dense("v", kv_heads)(x)
         if self.qk_norm == "head":
@@ -1172,20 +1172,20 @@ class Layer(nn.Module):
 
 def _kept(model):
     """What a recomputed layer of ``model`` keeps beside its input, by name:
-    its flash kernel's pair and its feed-forward's normed output (the
-    backward reads them). None where no layer is recomputed."""
+    its flash kernel's pair, its feed-forward's normed output and its
+    attention's inputs (``_kept_inputs``). None where none is recomputed."""
     if not model.remat_blocks:
         return None
     from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
 
-    return (*RESIDUAL_NAMES, SUBLAYER_OUT)
+    return (*RESIDUAL_NAMES, SUBLAYER_OUT, *_kept_inputs(model))
 
 
 def _recomputed(cls, kept):
     """``cls``, recomputed in the backward pass where ``kept`` names what it
     keeps."""
-    return cls if kept is None else nn.remat(
-        cls, policy=jax.checkpoint_policies.save_only_these_names(*kept))
+    return cls if kept is None else _keeping(kept, nn.remat(
+        cls, policy=jax.checkpoint_policies.save_only_these_names(*kept)))
 
 
 def _layer(model, i: int, kept, block):
@@ -1539,3 +1539,105 @@ def _head_kernel(model, embed, head):
     if model.tie_embeddings:
         return embed.embedding
     return head.variables["params"]["kernel"]
+
+
+# ---------------------------------------------------------------------------
+# What a recomputed attention sub-layer keeps of its INPUTS. Down here for the
+# reason ``SUBLAYER_OUT`` is.
+#
+# The flash kernels' backward reads q, k and v as the forward kernel took them
+# (``flash_attention.INPUT_NAMES``: after a head norm, RoPE and the transposes
+# into the kernels' layout), a head norm's backward reads the raw projection it
+# normed (``W_q u``, ``W_k u``) and a gate's and ``W_o``'s read the raw ``W_g
+# u`` (``RAW_NAMES``). Kept by name, none of them is formed again in the
+# backward pass: the recomputation of a block is then ``ln1``, ``W_o`` and the
+# feed-forward's part. A name is bound only while a layer is traced whose
+# checkpoint's policy lists it (``_keeping``), so a layer that keeps none (not
+# recomputed, latent, looped, ``dense``) traces the program it traced before
+# these names were.
+# ---------------------------------------------------------------------------
+RAW_NAMES = {"q": "rdt_attn_q_raw", "k": "rdt_attn_k_raw",
+             "gate": "rdt_attn_gate_raw"}
+
+
+def _keeping(kept, cls):
+    """``cls``, a recomputed layer's class, traced under the names of ``kept``
+    that an attention or its kernels bind (``flash_attention.kept_names``); as
+    it is where there is none."""
+    from raydp_tpu.ops.flash_attention import INPUT_NAMES, kept_names
+
+    bound = frozenset(kept) & {*INPUT_NAMES, *RAW_NAMES.values()}
+    if not bound:
+        return cls
+    call = cls.__call__
+
+    def under_names(self, *args):
+        token = kept_names.set(bound)
+        try:
+            return call(self, *args)
+        finally:
+            kept_names.reset(token)
+
+    cls.__call__ = under_names
+    return cls
+
+
+def _raw(projection):
+    """An :class:`Attention` projection with its output under its name of
+    ``RAW_NAMES`` where the layer being traced keeps it (``v`` has none: the
+    kernel's own input is all of it)."""
+    from raydp_tpu.ops.flash_attention import kept_names
+
+    name = RAW_NAMES.get(projection.name)
+    if name not in kept_names.get():
+        return projection
+    from jax.ad_checkpoint import checkpoint_name
+
+    return lambda x: checkpoint_name(projection(x), name)
+
+
+def _kept_inputs(model):
+    """The names of its attention's inputs that a recomputed layer of
+    ``model`` keeps: THE rule, from the attention's class and fields, the
+    stack's shape and the kind the attention dispatches to, nothing else.
+
+    - :class:`Attention` on the flash kernels: the kernel's k and v (an
+      eighth to a quarter of q under grouped K/V heads) and its q; where a
+      head norm stands between projection and kernel (``qk_norm``) the raw
+      ``W_q u`` and ``W_k u`` too, which that norm's backward reads (with the
+      kernel's q alone the projection runs again for it, with the raw one
+      alone norm, RoPE and the transposes do); where the attention is gated
+      the raw ``W_g u``. Measured set by set on the chip (PERF.md, PR 62).
+    - :class:`LatentAttention`: nothing (its k is a broadcast of one rotary key
+      over every head: kept, it is the widest array of the layer).
+    - a looped stack: nothing (the scan keeps a set a pass AND a layer).
+    - ``dense`` and ``ring`` attention: nothing (they keep no output either:
+      their whole forward runs again)."""
+    if (_is_looped(model) or model.kv_lora_rank is not None
+            or "once" not in model.attention_forward):
+        return ()
+    from raydp_tpu.ops.flash_attention import INPUT_NAMES
+
+    raw = ("q", "k") * bool(model.qk_norm) + ("gate",) * model.attention_gate
+    return (*INPUT_NAMES, *(RAW_NAMES[name] for name in raw))
+
+
+def _attention_inputs(model):
+    """The attention layers a step executes (layers times ``total_ut_steps``)
+    in a model whose layers are recomputed, by what the recomputation does
+    with the attention's inputs (q, k and v as the flash kernel takes them,
+    and the raw projections a head norm or a gate reads): what
+    ``train_attention_inputs_total`` counts once a built step. ``kept``: the
+    policy lists them (``_kept_inputs``) and the backward runs no projection,
+    head norm or RoPE a second time; ``rebuilt``: it lists none (latent
+    attention, a looped stack, ``dense`` and ``ring``). Nothing where no layer
+    is recomputed."""
+    if not model.remat_blocks:
+        return {}
+    return {"kept" if _kept_inputs(model) else "rebuilt":
+            len(model._layers_of("B*")) * model.total_ut_steps}
+
+
+# attached here and not written in the class: its lines lie on the flash
+# kernels' call stack (this section's first lines)
+TransformerLM.attention_inputs = property(_attention_inputs)

@@ -1057,11 +1057,11 @@ RESIDUAL_NAMES = ("rdt_flash_out", "rdt_flash_lse")
 
 def _named(q3, k3, v3, out, lse):
     """What ``_flash_fwd`` returns: the output and the residuals, with the
-    kernel's two products under their names in both."""
+    kernel's two products under their names in both (and ``INPUT_NAMES``)."""
     from jax.ad_checkpoint import checkpoint_name
 
     out, lse = map(checkpoint_name, (out, lse), RESIDUAL_NAMES)
-    return out, (q3, k3, v3, out, lse)
+    return out, (*_named_inputs(q3, k3, v3), out, lse)
 
 
 # ---------------------------------------------------------------------------
@@ -1374,3 +1374,29 @@ def _count_mask(causal: bool, window, blockdiff) -> None:
     rdt_metrics.inc("flash_mask_total", label=(
         "blockdiff" if blockdiff is not None else "window"
         if window is not None else "causal" if causal else "none"))
+
+
+# What the backward kernels read beside ``RESIDUAL_NAMES``: the forward
+# kernel's three inputs as it takes them (``[B * heads, T, d]``: after a head
+# norm, RoPE and the transposes into that layout), by the names a
+# ``jax.checkpoint`` policy can keep them under, so that a recomputed layer
+# forms none of them again. Only the residuals carry a name, and only while
+# the layer being traced keeps it (``kept_names``, which the model sets round
+# such a layer: ``transformer._keeping``): a ``name`` equation renumbers the
+# functions of a lowered module, so a call that keeps none binds none and
+# traces the program it traced before these names were. On q, k and v as the
+# caller hands them over (``[B, T, heads, d]``) the transposes ran again in
+# the backward pass: 1.9-3.5 ms a step slower in three cells (PERF.md, PR 62).
+# At the file's end for the reason ``RESIDUAL_NAMES`` is.
+import contextvars  # noqa: E402  (with its section: no line above moves)
+
+INPUT_NAMES = ("rdt_flash_q", "rdt_flash_k", "rdt_flash_v")
+kept_names = contextvars.ContextVar("rdt_kept_names", default=frozenset())
+
+
+def _named_inputs(q3, k3, v3):
+    from jax.ad_checkpoint import checkpoint_name
+
+    kept = kept_names.get()
+    return tuple(checkpoint_name(x, name) if name in kept else x
+                 for x, name in zip((q3, k3, v3), INPUT_NAMES))

@@ -293,6 +293,9 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     # {"once": n} or {"twice": n}: how often a step runs their forward
     apply_fn.attention_forward = getattr(
         model, "attention_forward", None) or {}
+    # {"kept": n} or {"rebuilt": n}: a recomputed attention's q, k and v
+    apply_fn.attention_inputs = getattr(
+        model, "attention_inputs", None) or {}
     # {"kept": n, "rebuilt": m}: second norms' inputs in a recomputed block
     apply_fn.sublayer_out = getattr(model, "sublayer_out", None) or {}
     # {"plain": n} or {"rescanned": n}: state-space layers, by their scan
@@ -525,6 +528,7 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
     for metric, of_model in (
             ("train_attention_layers_total", "attention_layers"),
             ("train_attention_forward_total", "attention_forward"),
+            ("train_attention_inputs_total", "attention_inputs"),
             ("train_sublayer_out_total", "sublayer_out"),
             ("train_ssm_layers_total", "ssm_layers"),
             ("train_loop_passes_total", "loop_passes"),

@@ -207,17 +207,22 @@ def estimator(cfg, pipeline, info, mesh, **fit):
         shuffle=False, seed=0, **fit)
 
 
-def step_text(config, cell):
-    """(model, the StableHLO text of the estimator's train step, the shapes
-    of the parameters) of a configuration's CPU cut of a cell."""
-    import jax
-    import optax
+def cut_model(config, cell):
+    """(the model, the workload) of a configuration's CPU cut of a cell."""
     from chipbench import manifest
     cfg = manifest.load_json(ROOT, "configs", f"{config}.json")
     pipeline = manifest.load_module(ROOT, "pipelines", f"{config}.py")
     wl = manifest.load_json(ROOT, "workloads", f"{cell}.json")
     pipeline.cpu_cut(cfg, wl, 1)
-    model = pipeline.build_model(cfg)
+    return pipeline.build_model(cfg), wl
+
+
+def step_text(config, cell):
+    """(model, the StableHLO text of the estimator's train step, the shapes
+    of the parameters) of a configuration's CPU cut of a cell."""
+    import jax
+    import optax
+    model, wl = cut_model(config, cell)
     tokens = np.zeros((1, wl["seq_len"]), np.int32)
     shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
                                                tokens[:, :8]))

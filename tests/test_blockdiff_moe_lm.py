@@ -553,10 +553,11 @@ def test_a_model_without_streams_gets_no_key(monkeypatch):
 # hashes they had on that commit too, by ``tests/test_ssm_moe_lm.py`` (and
 # ``tests/test_mla_moe_lm.py``), which this PR leaves as they are. A PR that
 # means to change one of these programs replaces its line (PR 58 its one
-# line: a share's held rows come back to token order in runs).
+# line: a share's held rows come back to token order in runs; PR 62 too:
+# its one attention layer keeps its inputs).
 PARENT_STEP = {
     "nemotron-3-nano-30b-a3b":
-        "995c7f6fa1cba806517977478e42725000ab485798384984048823bc818ee103",
+        "9b91afeb9d12fc00b28cc06749aee99ca1b7fa8d56ff779cf4d86707faa440cf",
 }
 
 

@@ -401,14 +401,17 @@ def test_the_lowered_step_holds_each_layer_once_whatever_the_passes():
 # a share's held rows come back to token order in runs). Since PR 61 (a pair
 # with a convolution operator, tied embeddings, the routing's epsilon a
 # field: all off by default) the looped family's own cut is held too, with
-# the hash it had on the commit before that PR (3eb6cc9).
+# the hash it had on the commit before that PR (3eb6cc9). PR 62 (a recomputed
+# block keeps its attention's inputs) replaced the lines of ``sdar`` and
+# ``trinity``, which keep them, and left the looped cut's: a looped stack
+# keeps none and binds none of their names, so its text is the parent's.
 PARENT_STEP = {
     "ouro-2.6b":
         "31a058df325c6a3362ae0e92c7fe6efcf05781d1f51c21a7f03d65bcb7bd8cce",
     "sdar-30b-a3b-chat":
-        "c522fafd2608364c82b3a2b6fbae56dda36091c1950cef00e58182155d0675f9",
+        "6f62a2c1355f5dbe6b17d318cb0b10482b563ff9e59990148a82147a9995b68a",
     "trinity-mini":
-        "4f799eb1e2a9e31cd1af83144d905c948f49bc7b8109d28fee20c8d37a5a9114",
+        "6149ea892919880ca66abfe82453aa7cf17090c637cd687392f314e990c03a9a",
 }
 
 
@@ -430,4 +433,6 @@ def test_a_model_of_one_pass_and_no_gate_is_the_step_it_was(config, cell):
     assert not model.tie_embeddings and "lm_head" in params
     assert model.conv_layers == {"recomputed": 0}
     assert model.route_norm_eps == 1e-20 and "C" not in model.layer_kinds
+    assert set(model.attention_inputs) == {
+        "rebuilt" if config == "ouro-2.6b" else "kept"}
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]

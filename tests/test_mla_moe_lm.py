@@ -308,20 +308,22 @@ def test_a_recomputed_latent_block_keeps_its_kernels_pair(
 # attention (74af897) with ``_step_text``; with the backward as one kernel,
 # on the commit that built it (PR 43). A PR that means to change one of
 # these programs replaces its line (PR 58 the four of the models that hold
-# a share of their experts: its held rows come back to token order in runs).
+# a share of their experts: its held rows come back to token order in runs;
+# PR 62 the four of the two recomputed models, which keep their attention's
+# inputs: the two of ``olmoe``, which is not recomputed, are the parent's).
 PARENT_STEP = {
     ("olmoe-1b-7b", "split"):
         "fc12cbdf792919544024982de7e9d345a78459791d3896cc61eac78be79ff455",
     ("smallthinker-21b-a3b", "split"):
-        "11873ab1cf9809db47b11acb4c6855eb731ce7596bbd9fba60bc78cc2350d903",
+        "8f009551937607b21c9003d18c667001ed179ee8e4809b5f3c6d0efcbf729be6",
     ("trinity-mini", "split"):
-        "d7e8563e7130efaee666cc47441c2c6c7e1098dbc69eb89936b36deced183a21",
+        "4f0029dd34cdc1fb95a7a1f8a3dce768c34226ad1747bb1d35b8e13ad8c73676",
     ("olmoe-1b-7b", "fused"):
         "8f37bf58d0cd1236e4ebe06e3aaa2d7ba11414a3ba5e485f65347853ae8aadec",
     ("smallthinker-21b-a3b", "fused"):
-        "e4532a9bf888034bb03b07dff0b53058d44b87417f1c7506fa1c3c2084691892",
+        "5532bc0ad438c3cf87f4ac2fb00df7db4a9c9dc2ef26e05fe280a3a5e22a8b3c",
     ("trinity-mini", "fused"):
-        "760becc6cfc04e181c689b9ce429f8f11b39081393d0861297f868eda2ab3208",
+        "eda453dbe06d3815de71073821a63860678bce1bd18a786b4d2abfeba1523ecf",
 }
 
 
@@ -348,5 +350,7 @@ def test_an_older_familys_step_is_the_parents_text(config, cell, backward,
             model.rope_interleave) == (None,) * 5 + (False,)
     assert "latent" not in model.attention_layers
     assert {"q", "k", "v", "o"} <= set(attn) and "kv_a" not in attn
+    assert model.attention_inputs == (
+        {} if config == "olmoe-1b-7b" else {"kept": model.num_layers})
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[
         config, backward]

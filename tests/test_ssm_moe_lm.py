@@ -674,14 +674,17 @@ def test_an_expert_trip_forward_is_two_grouped_products(grouped_products):
 # families' CPU cuts, computed on the commit before this family (6e8d15d)
 # with ``_step_text``. A PR that means to change one of these programs
 # replaces its line (PR 58 the three of the models that hold a share of
-# their experts: its held rows come back to token order in runs).
+# their experts: its held rows come back to token order in runs; PR 62 the
+# two of the recomputed models that keep their attention's inputs: ``olmoe``,
+# not recomputed, and ``kanana-2``, whose latent attention keeps none and
+# binds none of their names, are the parent's).
 PARENT_STEP = {
     "olmoe-1b-7b":
         "7116221b2cffe66cee500a7f382bd80cd42ca76514520e3b205d118bb54d4d84",
     "smallthinker-21b-a3b":
-        "854aaa17c4f423b181dc82fe57fa78e3689f724452461c93cf781cb0b9806a27",
+        "488f046c56e147acc45b7d9f0520c168c1c7dbcff72c4cdd547dcf966b71b1eb",
     "trinity-mini":
-        "4f799eb1e2a9e31cd1af83144d905c948f49bc7b8109d28fee20c8d37a5a9114",
+        "6149ea892919880ca66abfe82453aa7cf17090c637cd687392f314e990c03a9a",
     "kanana-2-30b-a3b":
         "b7799bd0596e87de6c326f210ba3bfdb752df8beca49759474efecb6d5e8535b",
 }
@@ -704,4 +707,7 @@ def test_an_older_familys_step_is_the_parents_text(config, cell):
     block = params[f"block_{model.num_layers - 1}"]
     assert {"ln1", "attn", "ln2", "moe"} <= set(block)
     assert "experts_gate" in block["moe"] and "norm" not in block
+    assert set(model.attention_inputs) == {
+        "olmoe-1b-7b": set(), "kanana-2-30b-a3b": {"rebuilt"}}.get(
+            config, {"kept"})
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]

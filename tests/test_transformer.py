@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raydp_tpu.models.transformer import BlockDiffusionSpec
 from raydp_tpu.ops.flash_attention import flash_attention
 from raydp_tpu.ops.ring_attention import dense_attention
 
@@ -496,3 +497,189 @@ def test_return_hidden_registers_head_params():
     params = model.init(jax.random.PRNGKey(0), tokens,
                         return_hidden=True)["params"]
     assert params["lm_head"]["kernel"].shape == (16, 64)
+
+
+# ---------------------------------------------------------------------------
+# What a recomputed attention keeps of its inputs
+# ---------------------------------------------------------------------------
+_TINY_LM = dict(vocab_size=64, dim=32, num_heads=4, head_dim=8, num_layers=2,
+                ffn_dim=48, attention="flash", init_std=0.3)
+_LATENT = dict(kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+               v_head_dim=8, head_dim=None)
+
+
+_GROUPED = {"num_kv_heads": 2}
+_ATTENTION_CASES = {
+    "no_norm": {},
+    "grouped": _GROUPED,
+    "head_norm": dict(_GROUPED, qk_norm="head"),
+    "whole_norm": {"qk_norm": True},
+    "gated": dict(_GROUPED, attention_gate=True),
+    "gated_head_norm_sandwich": dict(
+        _GROUPED, qk_norm="head", attention_gate=True, sandwich_norms=True),
+    "windowed": dict(_GROUPED, sliding_window=8, window_layers=(1, 0),
+                     rope_layers=(1, 0)),
+    "block_diffusion": dict(_GROUPED, qk_norm="head",
+                            diffusion=BlockDiffusionSpec(4, 63)),
+    "one_sublayer": dict(_GROUPED, layer_kinds="*B"),
+}
+
+
+def _lm(remat, **fields):
+    from raydp_tpu.models import TransformerLM
+
+    return TransformerLM(**{**_TINY_LM, **fields}, remat_blocks=remat)
+
+
+def _lm_case(model, seed=0):
+    import lm_testing
+
+    tokens = np.random.default_rng(seed).integers(0, 62, (2, 16),
+                                                  dtype=np.int32)
+    params, state = lm_testing.variables(model, tokens, seed)
+    return params, state, tokens, np.full((2,), 0.5, np.float32)
+
+
+@pytest.mark.parametrize("case", list(_ATTENTION_CASES))
+def test_a_block_that_keeps_its_attentions_inputs_is_the_plain_block(case):
+    """A recomputed block reads q, k, v and the raw projections a head norm
+    or a gate reads from what it kept, and they are the arrays the plain
+    block's backward reads: the loss and every gradient leaf are the
+    un-recomputed model's, whatever stands between projection and kernel
+    (no norm, a head norm, a norm over the whole projection, a gate, a
+    window, the block-diffusion mask, grouped K/V heads, a layer of one
+    sub-layer)."""
+    import lm_testing
+
+    fields = _ATTENTION_CASES[case]
+    plain, kept = _lm(False, **fields), _lm(True, **fields)
+    assert plain.attention_inputs == {} and kept.attention_inputs == {
+        "kept": 2}
+    args = _lm_case(plain)
+    (want, _), want_g = lm_testing.loss_and_grads(plain, *args)
+    (got, _), got_g = lm_testing.loss_and_grads(kept, *args)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    lm_testing.close(got_g, want_g, tol=1e-5)
+    assert all(np.abs(leaf).max() > 0 for leaf in jax.tree.leaves(want_g))
+
+
+def _head_projections(jaxpr):
+    """How many ``dot_general``s of a traced program (through every
+    checkpoint, loop and call it holds) multiply an activation by a kernel
+    ``[D, heads, d]`` into ``[B, T, heads, d]``: the projections to heads,
+    forward or run again. A backward product's result has three axes, or
+    (``W_o``'s) contracts the kernel's last."""
+    jaxpr, found = getattr(jaxpr, "jaxpr", jaxpr), 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shapes = [len(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)]
+            (_, kernel_axes), _ = eqn.params["dimension_numbers"]
+            found += shapes == [3, 3, 4] and tuple(kernel_axes) == (0,)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    found += _head_projections(sub)
+    return found
+
+
+def _backward_program(model):
+    import lm_testing
+
+    params, state, tokens, weights = _lm_case(model)
+    return jax.make_jaxpr(lm_testing.loss_program(model))(
+        params, state, tokens, weights)
+
+
+@pytest.mark.parametrize("fields,projections,kept", [
+    ({"num_kv_heads": 2}, 3, True),
+    ({"num_kv_heads": 2, "qk_norm": "head", "attention_gate": True}, 4, True),
+    ({"layer_kinds": "*B"}, 3, True),
+    (_LATENT, 2, False),                    # q and the latent's kv_b
+    ({"total_ut_steps": 2}, 3, False),
+    ({"attention": "dense"}, 3, False),
+], ids=["grouped", "gated_head_norm", "one_sublayer", "latent", "looped",
+        "dense"])
+def test_the_recomputed_backward_runs_no_projection_it_kept(
+        fields, projections, kept):
+    """Where the rule keeps an attention's inputs the differentiated program
+    holds each projection to heads once, as the plain model's does; where it
+    keeps none (latent attention, a looped stack, ``dense``) the
+    recomputation runs them again, as it did, and no name of theirs is bound
+    (the traced program is the one it was)."""
+    from raydp_tpu.models import transformer
+
+    plain, model = _lm(False, **fields), _lm(True, **fields)
+    layers = 2 * model.total_ut_steps
+    assert model.attention_inputs == {"kept" if kept else "rebuilt": layers}
+    assert bool(transformer._kept_inputs(model)) == kept
+    once = _head_projections(_backward_program(plain))
+    assert once == 2 * projections          # a looped body is traced once
+    program = _backward_program(model)
+    assert _head_projections(program) == (once if kept else 2 * once)
+    from raydp_tpu.ops.flash_attention import INPUT_NAMES
+
+    bound = set(INPUT_NAMES) | set(transformer.RAW_NAMES.values())
+    named = {name for name in bound if f"name={name}]" in str(program)}
+    assert named == (set(transformer._kept_inputs(model)) if kept else set())
+    assert not any(f"name={name}]" in str(_backward_program(plain))
+                   for name in bound)
+
+
+_QKV = ("rdt_flash_q", "rdt_flash_k", "rdt_flash_v")
+_RAW_QK = ("rdt_attn_q_raw", "rdt_attn_k_raw")
+
+
+@pytest.mark.parametrize("config,cell,inputs,counted", [
+    ("olmoe-1b-7b", "olmoe_1b7b_train", None, {}),
+    ("smallthinker-21b-a3b", "smallthinker_21ba3b_16k_train", _QKV,
+     {"kept": 4}),
+    ("trinity-mini", "trinity_mini_8k_train",
+     _QKV + _RAW_QK + ("rdt_attn_gate_raw",), {"kept": 2}),
+    ("kanana-2-30b-a3b", "kanana2_30ba3b_16k_train", (), {"rebuilt": 2}),
+    ("nemotron-3-nano-30b-a3b", "nemotron3_nano_30ba3b_16k_train", _QKV,
+     {"kept": 1}),
+    ("sdar-30b-a3b-chat", "sdar_30ba3b_8k_blockdiff_train", _QKV + _RAW_QK,
+     {"kept": 1}),
+    ("ouro-2.6b", "ouro_2p6b_8k_train", (), {"rebuilt": 8}),
+    ("lfm2-8b-a1b", "lfm2_8ba1b_8k_train", _QKV + _RAW_QK, {"kept": 1}),
+])
+def test_what_each_benchmark_model_keeps_of_its_attentions_inputs(
+        config, cell, inputs, counted):
+    """The rule, model by model, at the CPU cut of each LM cell: a model that
+    is not recomputed keeps nothing by name (no policy at all); latent
+    attention and a looped stack keep the parent's three names; the others
+    q, k, v as the kernel takes them, the raw ``W_q u`` and ``W_k u`` under a
+    head norm and the raw gate where the attention is gated."""
+    import lm_testing
+    from raydp_tpu.models import transformer
+    from raydp_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    model, _ = lm_testing.cut_model(config, cell)
+    kept = transformer._kept(model)
+    assert kept == (None if inputs is None else (
+        *RESIDUAL_NAMES, transformer.SUBLAYER_OUT, *inputs))
+    assert model.attention_inputs == counted
+
+
+@pytest.mark.parametrize("remat,fields,counted", [
+    (True, {"num_kv_heads": 2, "qk_norm": "head"}, {"kept": 2}),
+    (True, {"total_ut_steps": 3}, {"rebuilt": 6}),
+    (True, _LATENT, {"rebuilt": 2}),
+    (False, {"num_kv_heads": 2}, {}),
+], ids=["kept", "looped", "latent", "not_recomputed"])
+def test_a_built_step_counts_what_becomes_of_its_attentions_inputs(
+        remat, fields, counted):
+    """``train_attention_inputs_total`` is bumped once a built train step,
+    beside ``train_attention_forward_total``, by the attention layers the
+    step executes: ``kept`` where the recomputation reads q, k and v from
+    what the block kept, ``rebuilt`` where the rule keeps none, nothing where
+    no layer is recomputed."""
+    import lm_testing
+    import optax
+
+    model = _lm(remat, **fields)
+    before = lm_testing.counters()
+    lm_testing.train_step(model, optax.sgd(0.05))
+    assert lm_testing.moved(before, "train_attention_inputs_total") == counted
+    assert lm_testing.moved(before, "train_attention_forward_total") == {
+        "once": 2 * model.total_ut_steps}
