@@ -582,10 +582,9 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
             out, grads = _microbatch_grads(state.params, state.batch_stats,
                                            batch, mask, rng=rng)
             return state.apply_gradients(grads=grads), out
-        uniq, inv = {}, {}
-        for path, ids in tables.items():
-            uniq[path], inv[path] = rowwise.unique_rows(
-                ids, rowwise.leaf_at(state.params, path).shape[0])
+        uniq, inv = rowwise.unique_rows_of(
+            tables, {path: rowwise.leaf_at(state.params, path).shape[0]
+                     for path in tables})
         whole = (state.params, state.opt_state)
         idx = rowwise.index_trees(state.tx, *whole, uniq)
         view_params, view_opt = rowwise.take_rows(whole, idx, placed)

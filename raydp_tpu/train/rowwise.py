@@ -6,7 +6,8 @@ A model *declares* its lookups (``lookups(inputs) -> {parameter path: ids}``
 and a forward that takes pre-gathered ``rows``; :class:`raydp_tpu.models.DLRM`
 is the one that does). For each declared table with ids ``[B]`` the step
 
-1. de-duplicates the ids at the static size ``B`` (:func:`unique_rows`);
+1. de-duplicates the ids at the static size ``B``: all the tables' ids in
+   one stacked pass ``[T, B]`` of sorts (:func:`unique_rows_of`);
 2. builds a *row view* of the parameters and of the optimizer state: every
    leaf that mirrors the table replaced by its ``uniq`` rows, everything else
    whole (:func:`index_trees`, :func:`take_rows`);
@@ -72,6 +73,9 @@ STAGED_BYTES = 256 << 20
 #: of which ~2300 distinct, ten tables: passes of 512 ran the step in 10.96
 #: ms, of 1024 in 10.98, one sum of all 8192 rows in 12.04 (CHANGES.md PR 34)
 SUM_PASS = 512
+#: how far the probe's two sides may lie apart, relative to the dense one:
+#: eight units in a float32's last place
+PROBE_RTOL = 1e-6
 
 Path = Tuple[str, ...]
 
@@ -217,19 +221,65 @@ def tables_to_update(apply_fn, state, batch, accum: int, seen: list,
     return {p: i for p, i in ids.items() if p not in because}
 
 
+def _dedup(ids, num_rows):
+    """``(uniq, count, inv)`` ``[T, B]``, ``[T]``, ``[T, B]`` of the stacked
+    ids ``[T, B]`` of tables of ``num_rows`` ``[T]`` rows: every table's ids
+    de-duplicated at the static size ``B`` in one batched computation. A
+    table's ``uniq`` is sorted, its tail filled with *distinct* ids past the
+    table's end, ``num_rows[t] + arange(B)``; ``uniq[inv] == ids``. The
+    arrays are ``jnp.unique(ids[t], size=B, return_inverse=True)``'s, bit for
+    bit, but made of three sorts over the last axis and a running sum, with
+    no gather and no scatter: the chip sorts 8192 ids with a payload in 7 us
+    and moves 8192 scalars one index at a time in 60 to 70 (PERF.md, PR 63).
+
+    The ids sorted with their positions; an id that differs from the one
+    before it is the first of its run, and the running count of firsts is
+    every sorted id's place in ``uniq``; sorted back by position that is
+    ``inv``; the firsts sorted alone (the rest pushed to the end) are
+    ``uniq``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    t, b = ids.shape
+    place = lax.broadcasted_iota(jnp.int32, (t, b), 1)
+    ordered, came_from = lax.sort((ids, place), dimension=1, num_keys=1)
+    first = jnp.concatenate([jnp.ones((t, 1), bool),
+                             ordered[:, 1:] != ordered[:, :-1]], axis=1)
+    rank = jnp.cumsum(first, axis=1, dtype=jnp.int32) - 1
+    _, inv = lax.sort((came_from, rank), dimension=1, num_keys=1)
+    uniq = lax.sort(jnp.where(first, ordered, jnp.iinfo(ids.dtype).max),
+                    dimension=1)
+    real = uniq < num_rows[:, None]
+    uniq = jnp.where(real, uniq, num_rows[:, None] + place.astype(ids.dtype))
+    return uniq, jnp.sum(real, axis=1, dtype=jnp.int32), inv
+
+
+def unique_rows_of(tables: Dict[Path, object], num_rows: Dict[Path, int]):
+    """``({path: rows}, {path: inv})`` of the ids ``[B]`` a step looked up in
+    each of its row-wise ``tables`` (:func:`unique_rows` says what they
+    hold), all in ONE pass over the stacked ids ``[T, B]`` (:func:`_dedup`).
+    On a mesh the pass is written whole and the partitioner's to shard."""
+    import jax
+    import jax.numpy as jnp
+
+    paths = list(tables)
+    with jax.named_scope("table_dedup"):
+        ids = jnp.stack([tables[path] for path in paths])
+        sizes = jnp.asarray([num_rows[path] for path in paths], ids.dtype)
+        uniq, count, inv = _dedup(ids, sizes)
+        return ({path: Rows(uniq[i], count[i])
+                 for i, path in enumerate(paths)},
+                {path: inv[i] for i, path in enumerate(paths)})
+
+
 def unique_rows(ids, num_rows: int):
     """``(rows, inv)`` of the ids ``[B]`` at the static size ``B``:
     ``rows.uniq`` sorted, its tail filled with *distinct* ids past the table's
     end (so a scatter may be told its indices are sorted and unique, and
-    ``mode="drop"`` drops them); ``rows.uniq[inv] == ids``."""
-    import jax.numpy as jnp
-
-    b = ids.shape[0]
-    uniq, inv = jnp.unique(ids, size=b, fill_value=num_rows,
-                           return_inverse=True)
-    real = uniq < num_rows
-    uniq = jnp.where(real, uniq, num_rows + jnp.arange(b, dtype=uniq.dtype))
-    return Rows(uniq, jnp.sum(real, dtype=jnp.int32)), inv.reshape(b)
+    ``mode="drop"`` drops them); ``rows.uniq[inv] == ids``. The one-table
+    case of :func:`unique_rows_of`."""
+    rows, inv = unique_rows_of({(): ids}, {(): num_rows})
+    return rows[()], inv[()]
 
 
 def index_trees(tx, params, opt_state, uniq: Dict[Path, Rows]):
@@ -395,15 +445,27 @@ def put_rows(tree, view, idx, placed=None):
 def same_as_dense(tx, params, tables) -> bool:
     """The probe: does updating a row view give ``tx``'s own dense result?
 
-    Tried, eagerly on the host, on a tiny tree of ``params``' structure (so a
-    transformation that treats parameters by name sees the names): after one
-    ordinary update, a gradient whose table rows 2 and 3 are zero goes through
+    Tried on the host, as ONE compiled program that is dropped once it has
+    answered, on a tiny tree of ``params``' structure (so a transformation
+    that treats parameters by name sees the names): after one ordinary
+    update, a gradient whose table rows 2 and 3 are zero goes through
     ``tx.update`` dense and through the row view of rows 0 and 1. They must
-    agree bit for bit in the updates (zero on the rows the view skipped) and in
-    every state leaf. ``adagrad`` and plain ``sgd`` do; ``adam`` (its moments
-    decay on a skipped row), momentum and weight decay do not — for them a
-    skipped row is a different result, and the step stays dense. Anything that
-    raises on the way stays dense too."""
+    agree to rounding (:data:`PROBE_RTOL`, relative alone: a zero on the rows
+    the view skipped is a zero) in the updates and in every state leaf.
+    ``adagrad`` and plain ``sgd`` do; ``adam`` (its moments decay on a
+    skipped row), momentum and weight decay do not — for them a skipped row
+    is a different result, and the step stays dense. Anything that raises on
+    the way stays dense too.
+
+    One dropped program, not eager ops: a program of the host's CPU client
+    that is live while ``jax.profiler`` traces takes the place of every TPU
+    program in the trace's ``/host:metadata``, and eager ops' ~70 small
+    programs live as long as the process, so a trace of a row-wise fit named
+    no op's scope (PERF.md, PR 63). The tiny tree is the program's argument,
+    so that the compiler folds neither side into constants; what is left
+    between the two sides of a passing optimizer is the order of a sum (a
+    global norm over ``[4, 2]`` and over ``[3, 2]``), one unit in the last
+    place."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -412,7 +474,7 @@ def same_as_dense(tx, params, tables) -> bool:
         shape = (4,) + (2,) * (p.ndim - 1) if _names(path) in tables \
             else (2,) * p.ndim
         n = int(np.prod(shape))
-        return jnp.asarray(np.linspace(0.25, 1.0, n).reshape(shape), p.dtype)
+        return np.asarray(np.linspace(0.25, 1.0, n).reshape(shape), p.dtype)
 
     def touched(path, g):
         # the table rows a batch of ids {0, 1} looked up; 2 and 3 get nothing
@@ -421,31 +483,35 @@ def same_as_dense(tx, params, tables) -> bool:
         return g * (jnp.arange(4) < 2).astype(g.dtype).reshape(
             (4,) + (1,) * (g.ndim - 1))
 
+    def both(p0):
+        g1 = jax.tree.map(lambda p: p * 0.5 - 0.4, p0)
+        g2 = jax.tree_util.tree_map_with_path(
+            touched, jax.tree.map(lambda p: p * -0.75 + 0.3, p0))
+        u1, s1 = tx.update(g1, tx.init(p0), p0)
+        p1 = optax.apply_updates(p0, u1)
+        u_dense, s_dense = tx.update(g2, s1, p1)
+
+        rows = Rows(jnp.asarray([0, 1, 6], jnp.int32), 2)  # 6: a fill id
+        p_idx, s_idx = index_trees(tx, p1, s1, {t: rows for t in tables})
+        u_view, s_view = tx.update(take_rows(g2, p_idx),
+                                   take_rows(s1, s_idx),
+                                   take_rows(p1, p_idx))
+        u_rows = put_rows(jax.tree.map(jnp.zeros_like, u_dense), u_view,
+                          p_idx)
+        return (u_dense, s_dense), (u_rows, put_rows(s1, s_view, s_idx))
+
     try:
         cpu = jax.local_devices(backend="cpu")[0]
     except RuntimeError:
         cpu = None
     try:
-        with jax.ensure_compile_time_eval(), jax.default_device(cpu):
-            p0 = jax.tree_util.tree_map_with_path(tiny, params)
-            g1 = jax.tree.map(lambda p: p * 0.5 - 0.4, p0)
-            g2 = jax.tree_util.tree_map_with_path(
-                touched, jax.tree.map(lambda p: p * -0.75 + 0.3, p0))
-            u1, s1 = tx.update(g1, tx.init(p0), p0)
-            p1 = optax.apply_updates(p0, u1)
-            u_dense, s_dense = tx.update(g2, s1, p1)
-
-            rows = Rows(jnp.asarray([0, 1, 6], jnp.int32), 2)  # 6: a fill id
-            p_idx, s_idx = index_trees(tx, p1, s1, {t: rows for t in tables})
-            u_view, s_view = tx.update(take_rows(g2, p_idx),
-                                       take_rows(s1, s_idx),
-                                       take_rows(p1, p_idx))
-            u_rows = put_rows(jax.tree.map(jnp.zeros_like, u_dense), u_view,
-                              p_idx)
-            s_rows = put_rows(s1, s_view, s_idx)
-            dense, dense_def = jax.tree.flatten((u_dense, s_dense))
-            rowwise, rowwise_def = jax.tree.flatten((u_rows, s_rows))
-            return dense_def == rowwise_def and all(
-                np.array_equal(a, b) for a, b in zip(dense, rowwise))
+        p0 = jax.tree_util.tree_map_with_path(tiny, params)
+        with jax.default_device(cpu):
+            dense, rowwise = jax.jit(both).lower(p0).compile()(p0)
+        dense, dense_def = jax.tree.flatten(dense)
+        rowwise, rowwise_def = jax.tree.flatten(rowwise)
+        return dense_def == rowwise_def and all(
+            np.allclose(a, b, rtol=PROBE_RTOL, atol=0.0)
+            for a, b in zip(dense, rowwise))
     except Exception:  # noqa: BLE001 - an optimizer the view cannot serve
         return False

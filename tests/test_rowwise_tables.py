@@ -302,6 +302,24 @@ def test_probe_on_other_transformations(name, expected):
     assert rowwise.same_as_dense(tx, params, tables) is expected
 
 
+def test_probe_leaves_no_program_behind():
+    """The probe is one compiled program of the host's, gone when it has
+    answered (a live program of the CPU client hides the chip's programs from
+    a profiler trace: PERF.md, PR 63)."""
+    import jax
+    import optax
+
+    from raydp_tpu.train import rowwise
+
+    params = _state(_model(), optax.sgd(0.1)).params
+    tables = {(f"embedding_{j}", "embedding") for j in WIDE}
+    client = jax.local_devices(backend="cpu")[0].client
+    live = len(client.live_executables())
+    assert rowwise.same_as_dense(optax.adagrad(0.05), params, tables) is True
+    assert rowwise.same_as_dense(optax.adam(1e-2), params, tables) is False
+    assert len(client.live_executables()) <= live
+
+
 def test_two_tables_of_one_shape_are_told_apart_by_path():
     """State leaves are matched to their parameter by path: with two tables of
     one shape and only one of them row-wise, the other's accumulator is swept
@@ -1067,6 +1085,260 @@ def test_sum_counter_counts_the_tables_a_sum_puts_together(where,
               _state(model, tx, mesh, rules), _batches(2), mesh)
     assert _summed(before) == {"real_rows": 3 if where == "told" else 0,
                                "all_rows": 0}
+
+
+# ----------------------- (f3) the de-duplication: one stacked pass of sorts
+def _unique_rows_alone(ids, num_rows):
+    """One table's de-duplication as the step ran it before the stacked
+    pass, a ``jnp.unique`` a table: what the pass is held to, bit for bit."""
+    import jax.numpy as jnp
+
+    from raydp_tpu.train import rowwise
+
+    b = ids.shape[0]
+    uniq, inv = jnp.unique(ids, size=b, fill_value=num_rows,
+                           return_inverse=True)
+    real = uniq < num_rows
+    uniq = jnp.where(real, uniq, num_rows + jnp.arange(b, dtype=uniq.dtype))
+    return rowwise.Rows(uniq, jnp.sum(real, dtype=jnp.int32)), inv.reshape(b)
+
+
+def _unique_rows_in_a_loop(tables, num_rows):
+    """``rowwise.unique_rows_of`` as a loop over the tables."""
+    out = {path: _unique_rows_alone(ids, num_rows[path])
+           for path, ids in tables.items()}
+    return ({path: rows for path, (rows, _) in out.items()},
+            {path: inv for path, (_, inv) in out.items()})
+
+
+def _zipf(v, rng, b=B):
+    return np.minimum(rng.zipf(1.2, b), v) - 1
+
+
+#: name -> (rows of each table, its ids [B] from a generator)
+_DEDUP_CASES = {
+    "one_table": ([1000], _zipf),
+    "three_tables": ([1000, 600, 300], _zipf),
+    "ten_tables": ([1000, 600, 300, 70, 65, 4096, 143091, 2_000_000, 128,
+                    99], _zipf),
+    "differing_rows": ([3, 100_000, 64, 65], _zipf),
+    "all_equal": ([500, 80, 7], lambda v, rng: np.full(B, v - 1)),
+    "all_distinct": ([500, 64, 4096], lambda v, rng: rng.permutation(v)[:B]),
+    # a table's ids lie past the end of the one before it (and below the
+    # next one's fill ids): tables are told apart by position, not by value
+    "another_tables_range": ([40, 1000, 100_000], lambda v, rng: (
+        v - 1 - rng.integers(0, max(v // 2, 1), B))),
+}
+
+
+def _dedup_case(name, seed=0):
+    import jax.numpy as jnp
+
+    sizes, draw = _DEDUP_CASES[name]
+    rng = np.random.default_rng(seed)
+    tables = {(f"t{j}", "embedding"): jnp.asarray(draw(v, rng), jnp.int32)
+              for j, v in enumerate(sizes)}
+    return tables, {path: v for path, v in zip(tables, sizes)}
+
+
+def _same_rows(got, expected):
+    rows, inv = got
+    rows_, inv_ = expected
+    assert list(rows) == list(rows_) and list(inv) == list(inv_)
+    for path in rows:
+        for a, b in ((rows[path].uniq, rows_[path].uniq),
+                     (rows[path].count, rows_[path].count),
+                     (inv[path], inv_[path])):
+            assert a.dtype == b.dtype and a.shape == b.shape, path
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=str(path))
+        assert rows[path].first is None
+
+
+def _jitted_rows(fn, tables, num_rows):
+    """``fn``'s rows computed inside one jitted program, as a step does."""
+    import jax
+
+    from raydp_tpu.train import rowwise
+
+    paths = list(tables)
+
+    def run(ids):
+        rows, inv = fn(dict(zip(paths, ids)), num_rows)
+        return [(rows[p].uniq, rows[p].count, inv[p]) for p in paths]
+
+    out = jax.jit(run)([tables[p] for p in paths])
+    return ({p: rowwise.Rows(u, c) for p, (u, c, _) in zip(paths, out)},
+            {p: i for p, (_, _, i) in zip(paths, out)})
+
+
+@pytest.mark.parametrize("case", list(_DEDUP_CASES))
+def test_stacked_pass_is_the_per_table_pass_bit_for_bit(case):
+    """``unique_rows_of``: every table's ``(uniq, count, inv)`` from one pass
+    of sorts over the stacked ids is what a ``jnp.unique`` a table gave;
+    ``uniq`` sorted and without repeats, its tail the table's own fill
+    ids."""
+    from raydp_tpu.train import rowwise
+
+    tables, num_rows = _dedup_case(case)
+    got = _jitted_rows(rowwise.unique_rows_of, tables, num_rows)
+    _same_rows(got, _jitted_rows(_unique_rows_in_a_loop, tables, num_rows))
+    for path, rows in got[0].items():
+        uniq, count = np.asarray(rows.uniq), int(rows.count)
+        assert (np.diff(uniq) > 0).all()
+        assert count == len(np.unique(np.asarray(tables[path])))
+        assert list(uniq[count:]) == list(num_rows[path]
+                                          + np.arange(count, B))
+        assert (uniq[np.asarray(got[1][path])]
+                == np.asarray(tables[path])).all()
+
+
+@pytest.mark.parametrize("placement,case", [
+    ("data2_expert2", "three_tables"), ("data2_expert2", "ten_tables"),
+    ("fsdp2_tensor2", "ten_tables"), ("fsdp2_tensor2", "one_table"),
+    ("data2_expert4", "ten_tables"), ("data2_expert4", "all_distinct"),
+    ("data2_expert4", "another_tables_range")])
+def test_pass_on_a_mesh_is_the_one_chip_pass_bit_for_bit(placement, case):
+    """The ids as a step on a mesh meets them, a batch's, split over the
+    batch axes: every chip runs the pass whole and holds the arrays one chip
+    computes, bit for bit."""
+    import jax
+
+    from raydp_tpu.parallel import batch_sharding
+    from raydp_tpu.train import rowwise
+
+    mesh, _, _ = _placement(placement)
+    tables, num_rows = _dedup_case(case)
+    alone = _jitted_rows(rowwise.unique_rows_of, tables, num_rows)
+    on_mesh = _jitted_rows(rowwise.unique_rows_of,
+                           jax.device_put(tables, batch_sharding(mesh)),
+                           num_rows)
+    _same_rows(on_mesh, alone)
+
+
+def test_one_table_is_the_one_table_case_of_the_pass():
+    """``unique_rows`` is ``unique_rows_of`` of one table: one trace of the
+    same body, no collective and no ``shard_map`` in it."""
+    import jax
+
+    from raydp_tpu.train import rowwise
+
+    tables, num_rows = _dedup_case("one_table")
+    (path, ids), = tables.items()
+    _same_rows(_jitted_rows(
+        lambda t, n: tuple({path: x} for x in rowwise.unique_rows(
+            t[path], n[path])), tables, num_rows),
+        _jitted_rows(rowwise.unique_rows_of, tables, num_rows))
+    text = str(jax.make_jaxpr(
+        lambda i: rowwise.unique_rows(i, num_rows[path])[1])(ids))
+    assert "shard_map" not in text and "all_gather" not in text
+    assert text.count("sort[") == 3
+
+
+@pytest.mark.parametrize("placement", ["data2_expert2", "fsdp2_tensor2",
+                                       "data2_expert4"])
+def test_step_with_the_stacked_pass_matches_dense_and_the_loop(placement,
+                                                               monkeypatch):
+    """Whole Adagrad steps on a mesh with the de-duplication as one stacked
+    pass against the dense step, and against the same step built with a
+    ``jnp.unique`` a table: the same state and losses, to the bit."""
+    import jax
+    import optax
+
+    from raydp_tpu.parallel import param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    monkeypatch.setattr(rowwise, "STAGED_BYTES", 0)
+    monkeypatch.setattr(rowwise, "CHUNK", 16)
+    mesh, rules, _ = _placement(placement)
+    model, tx = _model(), optax.adagrad(0.05)
+    batches = _batches(6)
+    placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+
+    stacked, stacked_sums = _run_each(_step(model, mesh, placed=placed),
+                                  _state(model, tx, mesh, rules), batches,
+                                  mesh)
+    monkeypatch.setattr(rowwise, "unique_rows_of", _unique_rows_in_a_loop)
+    loop, loop_sums = _run_each(_step(model, mesh, placed=placed),
+                                _state(model, tx, mesh, rules), batches, mesh)
+    assert stacked_sums == loop_sums
+    _same_state(stacked, loop)
+    dense, dense_sums = _run_each(_step(_Undeclared(model), mesh),
+                                  _state(model, tx, mesh, rules), batches,
+                                  mesh)
+    assert stacked_sums[-1] == pytest.approx(dense_sums[-1], rel=1e-6)
+    for x, y in zip(jax.tree.leaves((stacked.params, stacked.opt_state)),
+                    jax.tree.leaves((dense.params, dense.opt_state))):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=1e-7)
+
+
+def test_one_device_step_is_the_loops_step_bit_for_bit(monkeypatch):
+    """On one device the stacked pass against a ``jnp.unique`` a table:
+    twenty Adagrad steps, the same state and losses to the bit."""
+    import optax
+
+    from raydp_tpu.train import rowwise
+
+    model, tx = _model(), optax.adagrad(0.05)
+    batches = _batches()
+    stacked, stacked_sums = _run_each(_step(model), _state(model, tx),
+                                      batches)
+    monkeypatch.setattr(rowwise, "unique_rows_of", _unique_rows_in_a_loop)
+    loop, loop_sums = _run_each(_step(model), _state(model, tx), batches)
+    assert stacked_sums == loop_sums
+    _same_state(stacked, loop)
+
+
+def _sorts(hlo: str):
+    """The shapes a compiled program's ``sort`` instructions sort."""
+    return [re.match(r"\(?(\w+\[[\d,]*\])", line.split(" = ")[1]).group(1)
+            for line in hlo.splitlines() if re.search(r" sort\(", line)]
+
+
+@pytest.mark.parametrize("where,built", [
+    ("one_device", "stacked"), ("data2_expert2", "stacked"),
+    ("data2_expert4", "stacked"), ("data2_expert2", "loop_for_contrast")])
+def test_compiled_step_sorts_the_tables_ids_together(where, built,
+                                                     monkeypatch):
+    """The compiled step (a count, not a timing) de-duplicates the ids of its
+    three row-wise tables in ONE stacked pass: three ``sort`` instructions
+    (the ids with their positions, the ranks back by position, the firsts
+    alone), each of ``[3, B]``, on one device and on every chip of a mesh; no
+    sort of a ``[B]`` operand is left, and the pass holds no gather and no
+    scatter. Built with a ``jnp.unique`` a table it sorts ``[B]`` three times
+    and gathers and scatters under no scope: the check can see."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.parallel import batch_sharding, param_sharding_rules
+    from raydp_tpu.train import rowwise
+
+    if built != "stacked":
+        monkeypatch.setattr(rowwise, "unique_rows_of",
+                            _unique_rows_in_a_loop)
+    model, tx = _model(), optax.adagrad(0.05)
+    if where == "one_device":
+        mesh, placed = None, None
+        state, batch = _state(model, tx), _batches(1)[0]
+    else:
+        mesh, rules, _ = _placement(where)
+        state = _state(model, tx, mesh, rules)
+        placed = param_sharding_rules(mesh, rules)(_state(model, tx))
+        batch = jax.device_put(_batches(1)[0], batch_sharding(mesh))
+    hlo = jax.jit(_step(model, mesh, placed=placed),
+                  donate_argnums=(0, 3)).lower(
+                      state, batch, (), jnp.zeros(())).compile().as_text()
+    moved = [line for line in hlo.splitlines() if "/table_dedup/" in line
+             and re.search(r" (gather|scatter)\(", line)]
+    assert not moved
+    if built != "stacked":
+        assert _sorts(hlo) == [f"s32[{B}]"] * len(WIDE)
+        assert "/table_dedup/" not in hlo
+        return
+    assert _sorts(hlo) == [f"s32[{len(WIDE)},{B}]"] * 3
+    assert "/table_dedup/" in hlo
 
 
 # ------------------------------- (g) a dense fit's checkpoint, row-wise step
