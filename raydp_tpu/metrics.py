@@ -444,6 +444,29 @@ _ALL_METRICS = [
        "(a shape the kernels do not take: `short_conv.kernel_ineligible` "
        "says why). ops/short_conv.py.",
        label="path"),
+    _m("train_kda_layers_total", COUNTER, "1", "training",
+       "Pairs whose operator is Kimi Delta Attention (`layer_kinds` `K`: a "
+       "`KimiDeltaAttention`, module `kda`, where the other pairs have "
+       "attention) of a training model, counted once a built train step by "
+       "what a recomputed one does with its scan: `plain` (the layer is not "
+       "recomputed) or `rescanned` (`remat_blocks`: nothing of the operator "
+       "is kept; the projections, the convolution, the gates and the chunked "
+       "scan run again in the backward pass). doc/long_context.md.",
+       label="scan"),
+    _m("kda_scan_total", COUNTER, "1", "training",
+       "Scans of a Kimi Delta Attention layer (a delta rule with a decay a "
+       "key channel, in chunks), counted once a built layer call by the path "
+       "it takes: `jnp` (the chunked `jax.numpy` form with XLA's triangular "
+       "solve: the one path today, on every platform; a `kernel` label is "
+       "what a later kernel pair would count under). ops/kda_scan.py.",
+       label="path"),
+    _m("kda_chunks_total", COUNTER, "1", "training",
+       "Chunks a Kimi Delta Attention scan walks, counted where a pass is "
+       "built (sequences x heads x chunks), by pass: `forward` (once a built "
+       "forward scan, a recomputed layer's second one included) or "
+       "`backward` (the chunked form formed again and transposed). "
+       "ops/kda_scan.py.",
+       label="pass"),
     _m("flash_backward_total", COUNTER, "1", "training",
        "Backward passes of the flash-attention kernels, counted where one "
        "is built (a layer call each), by what it is made of: `fused` (one "
@@ -807,6 +830,34 @@ _ALL_SPANS = [
        "form's passes).", kind=SCOPE),
     _s("short_conv/out_proj", "model",
        "a convolution operator's output projection.", kind=SCOPE),
+    _s("kda", "model",
+       "every op of a pair's Kimi Delta Attention operator (`layer_kinds` "
+       "`K`, module name `kda`): its projections, the convolution, the "
+       "gates, the chunked scan and the gated norm; forward, recomputed and "
+       "backward.", kind=SCOPE),
+    _s("kda/in_proj", "model",
+       "a delta-rule operator's ONE fused input projection to q, k and v "
+       "(three widths of heads x head_dim).", kind=SCOPE),
+    _s("kda/conv", "model",
+       "a delta-rule operator's depthwise causal convolution of a few taps "
+       "over q, k and v and the SiLU after it (`ssm_glue.conv_silu`: the "
+       "kernels `rdt_ssm_conv_fwd` and `rdt_ssm_conv_bwd`, or the "
+       "`jax.numpy` form's passes).", kind=SCOPE),
+    _s("kda/gate", "model",
+       "a delta-rule operator's decay (two low-rank products, the bias, "
+       "softplus, times `-exp(A_log)`; float32), `beta` (a projection and a "
+       "sigmoid) and the L2 norms of q and k a head.", kind=SCOPE),
+    _s("kda/scan", "model",
+       "a delta-rule operator's chunked scan (`ops/kda_scan.py`): the "
+       "decays' running sums in a chunk, the two score matrices, the "
+       "unit-triangular solve, and the walk over the chunks with the state "
+       "carried.", kind=SCOPE),
+    _s("kda/norm", "model",
+       "a delta-rule operator's output stage: the RMSNorm over a head's "
+       "channels times the sigmoid of the output gate's two low-rank "
+       "products.", kind=SCOPE),
+    _s("kda/out_proj", "model",
+       "a delta-rule operator's output projection.", kind=SCOPE),
     _s("attn_gate", "model",
        "Under `attn`: the attention output times sigmoid of its gate "
        "projection (`attention_gate`), before the output projection.",

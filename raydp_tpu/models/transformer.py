@@ -103,6 +103,18 @@ lies, :func:`lm_head_loss`'s ``vocab_major``), and ``route_norm_eps`` is the
 epsilon beside the sum a sigmoid routing's chosen scores are renormalised
 over.
 
+And, since the ``kimi_linear`` family (delta-rule hybrids): the letter ``K``
+in ``layer_kinds``, the pair of ``B`` with a :class:`KimiDeltaAttention`
+(module ``kda``; its sizes are the one field ``kda``, a :class:`KDASpec`)
+where ``B`` has ``attn``: a delta rule with a decay a key channel, scanned in
+chunks (:mod:`raydp_tpu.ops.kda_scan`) between a 4-tap convolution and a
+gated norm a head; the letters are then ``B``, ``C``, ``K``, ``M``, ``*``,
+``E``, and ``B``, ``C`` and ``K`` share ``dense_layers``. A recomputed ``K``
+layer keeps nothing of its operator (:attr:`TransformerLM.kda_layers`). A
+latent attention layer takes ``rope_layers`` too: where the pattern says 0
+the layer has no position embedding (the shared key and the queries' last
+``qk_rope_head_dim`` dimensions are kept and not rotated).
+
 A model that is trained by :class:`raydp_tpu.train.FlaxEstimator` hands the
 train step its loss itself (``loss_rows``): next-token cross entropy with the
 head applied chunk by chunk (:func:`lm_head_loss`'s scan, which takes the
@@ -114,7 +126,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+from typing import Any, ClassVar, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -271,7 +283,8 @@ class Block(nn.Module):
     added to the stream. ``kv_lora_rank``: the attention is a
     :class:`LatentAttention` of the four widths given with it.
     ``conv_taps``: the block's operator is a :class:`ShortConv`
-    (``short_conv``) where the others have ``attn``; the rest is the same."""
+    (``short_conv``) where the others have ``attn``; the rest is the same.
+    ``kda``: it is a :class:`KimiDeltaAttention` of those sizes (``kda``)."""
 
     num_heads: int
     mlp_ratio: int = 4
@@ -310,6 +323,8 @@ class Block(nn.Module):
     conv_taps: Optional[int] = None         # a number: the operator is a
     # ShortConv of that many taps (``short_conv``) in the attention's place
     route_norm_eps: float = 1e-20           # the expert layer's normalize_eps
+    kda: Any = None                         # a KDASpec: the operator is a
+    # KimiDeltaAttention of those sizes (``kda``) in the attention's place
 
     @nn.compact
     def __call__(self, x):
@@ -411,16 +426,17 @@ class TransformerLM(nn.Module):
     conv_taps: int = 3                      # a "C" layer's ShortConv
     tie_embeddings: bool = False            # the head IS the embedding
     route_norm_eps: float = 1e-20           # beside a renormalised top-k's sum
+    kda: Any = None                         # the "K" layers' KDASpec
 
     def _kind(self, layer: int) -> str:
         """``B`` the pair (attention, then a feed-forward part), ``C`` the
-        pair whose operator is a gated short convolution, or the one
-        sub-layer the layer is: ``M`` state-space mixer, ``*`` attention,
-        ``E`` experts."""
+        pair whose operator is a gated short convolution, ``K`` the pair
+        whose operator is Kimi Delta Attention, or the one sub-layer the
+        layer is: ``M`` state-space mixer, ``*`` attention, ``E`` experts."""
         kinds = self.layer_kinds or "B" * self.num_layers
-        if len(kinds) != self.num_layers or set(kinds) - set("BCM*E"):
+        if len(kinds) != self.num_layers or set(kinds) - set("BCKM*E"):
             raise ValueError(f"layer_kinds {kinds!r}: {self.num_layers} "
-                             f"letters of 'B', 'C', 'M', '*', 'E'")
+                             f"letters of 'B', 'C', 'K', 'M', '*', 'E'")
         return kinds[layer]
 
     def _layers_of(self, kinds: str):
@@ -441,7 +457,8 @@ class TransformerLM(nn.Module):
         layers of that kind times ``total_ut_steps``: a looped model runs
         each once a pass): what ``train_attention_layers_total`` counts once
         a built step. A latent layer counts under its kernel's kind and under
-        ``latent``; a ``C`` layer has no attention and counts under none."""
+        ``latent``; a ``C`` or ``K`` layer has no attention and counts under
+        none."""
         layers = self._layers_of("B*")
         windowed = sum(self._windowed(i) for i in layers)
         kinds = {"window": windowed, "full": len(layers) - windowed}
@@ -467,6 +484,16 @@ class TransformerLM(nn.Module):
         pass); ``plain``: the layer is not recomputed."""
         return {"recomputed" if self.remat_blocks else "plain":
                 len(self._layers_of("C"))}
+
+    @property
+    def kda_layers(self):
+        """The pairs whose operator is Kimi Delta Attention (``K``) by what a
+        recomputed one does with its scan: what ``train_kda_layers_total``
+        counts once a built step. ``rescanned``: nothing of the operator is
+        kept (projections, convolution, gates and the chunked scan run again
+        in the backward pass); ``plain``: the layer is not recomputed."""
+        return {"rescanned" if self.remat_blocks else "plain":
+                len(self._layers_of("K"))}
 
     @property
     def attention_forward(self):
@@ -507,7 +534,7 @@ class TransformerLM(nn.Module):
     def _sparse(self, layer: int) -> bool:
         kind = self._kind(layer)
         return bool(self.num_experts) and (
-            kind == "E" or (kind in "BC" and layer >= self.dense_layers))
+            kind == "E" or (kind in "BCK" and layer >= self.dense_layers))
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False, labels=None,
@@ -636,12 +663,12 @@ class TransformerLM(nn.Module):
         ``train_sublayer_out_total`` counts once a built step, by the blocks
         a step executes (blocks times ``total_ut_steps``). ``kept``: the
         feed-forward's (``SUBLAYER_OUT``); ``rebuilt``: the operator's (the
-        attention's output projection, or a ``C`` pair's whole operator, runs
-        again). Nothing where no block is recomputed
+        attention's output projection, or a ``C`` or ``K`` pair's whole
+        operator, runs again). Nothing where no block is recomputed
         or no norm reads them."""
         if not (self.remat_blocks and self.sandwich_norms):
             return {}
-        pairs = len(self._layers_of("BC")) * self.total_ut_steps
+        pairs = len(self._layers_of("BCK")) * self.total_ut_steps
         return {"kept": pairs, "rebuilt": pairs}
 
     @property
@@ -901,7 +928,8 @@ def transformer_param_rules(axis: str = "tensor"):
         ("down/kernel", (axis, None)),
         # a tied model (``tie_embeddings``) has the one array, placed as an
         # embedding is; a convolution operator (short_conv/in_proj, conv,
-        # out_proj) matches no rule and stays whole on every device
+        # out_proj) and a delta-rule one (kda/in_proj, conv, gate_a, ...,
+        # out_proj) match no rule and stay whole on every device
         ("embed/embedding", (None, axis)),
         ("lm_head/kernel", (None, axis)),
     ]
@@ -948,7 +976,9 @@ class LatentAttention(nn.Module):
     flash kernels take the two widths as they are, nothing is padded. ``k`` is
     built in HBM by broadcasting ``k_rope`` over the heads (the scope
     ``latent`` holds that, both projections, the norm and RoPE, so a trace
-    prices it: ``latent_kv_share``). Defined at the file's end for the reason
+    prices it: ``latent_kv_share``). ``rope=False``: no position embedding
+    (``k_rope`` is broadcast as it is and ``q`` is taken whole; the widths
+    and the scope stay). Defined at the file's end for the reason
     ``SUBLAYER_OUT`` is."""
 
     num_heads: int
@@ -964,6 +994,7 @@ class LatentAttention(nn.Module):
     rope_interleave: bool = False
     rms_norm_eps: float = 1e-6
     init_std: Optional[float] = None
+    rope: bool = True                       # False: nothing is rotated
 
     window = None                           # every key up to a query's own
     _dispatch = Attention._dispatch
@@ -992,8 +1023,9 @@ class LatentAttention(nn.Module):
                 down[..., :self.kv_lora_rank])
             kv = dense("kv_b", (heads, nope + self.v_head_dim))(latent)
             positions = jnp.arange(t)
-            turn = lambda a: rotary_embedding(  # noqa: E731
+            turn = (lambda a: rotary_embedding(  # noqa: E731
                 a, positions, self.rope_theta, self.rope_interleave)
+                    ) if self.rope else (lambda a: a)
             k_rope = turn(down[..., None, self.kv_lora_rank:])  # [B, T, 1, r]
             q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
             k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
@@ -1017,27 +1049,31 @@ class LatentAttention(nn.Module):
 def _operator(block):
     """A block's first sub-layer: ``attn``, :class:`Attention` or, where the
     block states a K/V latent, :class:`LatentAttention`; or, where it states
-    a convolution's taps, ``short_conv``, a :class:`ShortConv`."""
+    a convolution's taps, ``short_conv``, a :class:`ShortConv`; or, where it
+    states a :class:`KDASpec`, ``kda``, a :class:`KimiDeltaAttention`."""
     if block.conv_taps is not None:
         return ShortConv(block.conv_taps, block.dtype, block.init_std,
                          block.mesh, name="short_conv")
+    if block.kda is not None:
+        return KimiDeltaAttention(block.kda, block.dtype, block.rms_norm_eps,
+                                  block.init_std, block.mesh, name="kda")
     if block.kv_lora_rank is None:
         return Attention(
             block.num_heads, block.attention, block.mesh, block.dtype,
             block.rope_theta, block.qk_norm, block.rms_norm_eps,
             block.init_std, block.head_dim, block.num_kv_heads, block.window,
             block.rope, block.attention_gate, block.blockdiff, name="attn")
-    if (block.window or not block.rope or block.attention_gate
+    if (block.window or block.attention_gate
             or block.qk_norm or block.num_kv_heads or block.blockdiff):
-        raise ValueError("latent attention has no window, no layer without "
-                         "RoPE, no gate, no QK norm, no grouped K/V and no "
+        raise ValueError("latent attention has no window, "
+                         "no gate, no QK norm, no grouped K/V and no "
                          "block-diffusion mask")
     return LatentAttention(
         block.num_heads, block.kv_lora_rank, block.qk_nope_head_dim,
         block.qk_rope_head_dim, block.v_head_dim, block.q_lora_rank,
         block.attention, block.mesh, block.dtype, block.rope_theta,
         block.rope_interleave, block.rms_norm_eps, block.init_std,
-        name="attn")
+        block.rope, name="attn")
 
 
 # ---------------------------------------------------------------------------
@@ -1192,9 +1228,12 @@ def _layer(model, i: int, kept, block):
     """Layer ``i`` of a model, ``block_<i>``: the pair (``B``; ``block`` is
     the class, ``_recomputed(Block, kept)`` made once a model so that the
     layers share what they trace alike; ``C``: the same class with a
-    convolution operator) or the one sub-layer ``layer_kinds`` makes it."""
+    convolution operator; ``K``: with a delta-rule one) or the one sub-layer
+    ``layer_kinds`` makes it."""
     kind = model._kind(i)
-    if kind in "BC":
+    if kind == "K" and model.kda is None:
+        raise ValueError("a 'K' layer needs the model's kda=KDASpec(..)")
+    if kind in "BCK":
         sparse = model._sparse(i)
         return block(
             model.num_heads, model.mlp_ratio, model.attention, model.mesh,
@@ -1212,7 +1251,7 @@ def _layer(model, i: int, kept, block):
             model.qk_rope_head_dim, model.v_head_dim, model.rope_interleave,
             model.expert_gated, model._blockdiff,
             model.conv_taps if kind == "C" else None, model.route_norm_eps,
-            name=f"block_{i}")
+            model.kda if kind == "K" else None, name=f"block_{i}")
     if kind == "M":
         if model.ssm is None:
             raise ValueError("an 'M' layer needs the model's ssm=SSMSpec(..)")
@@ -1641,3 +1680,117 @@ def _attention_inputs(model):
 # attached here and not written in the class: its lines lie on the flash
 # kernels' call stack (this section's first lines)
 TransformerLM.attention_inputs = property(_attention_inputs)
+
+
+# ---------------------------------------------------------------------------
+# A pair whose operator is Kimi Delta Attention (the ``kimi_linear`` family).
+# Down here for the reason ``SUBLAYER_OUT`` is.
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class KDASpec:
+    """The sizes of a Kimi Delta Attention operator, as one field of a model:
+    ``num_heads`` heads of ``head_dim`` channels for keys and values alike
+    (the inner width is their product, whatever the model's ``dim``), a
+    causal convolution of ``conv_taps`` taps, the rank ``gate_rank`` of the
+    two low-rank gates (the decay's and the output's) and a scan in chunks of
+    ``chunk``. The limits the decay's bias is initialised between
+    (:func:`_dt_bias_init`'s rule) are the family's, not arguments."""
+
+    num_heads: int
+    head_dim: int
+    conv_taps: int = 4
+    gate_rank: int = 128
+    chunk: int = 64
+    dt_min: ClassVar[float] = 0.001
+    dt_max: ClassVar[float] = 0.1
+    dt_floor: ClassVar[float] = 1e-4
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention on a normed input ``u [B, T, D]``, the operator of
+    a ``K`` pair (``H`` heads of ``P`` channels, ``W = H P``)::
+
+        q~ | k~ | v~ = W_in u                 ONE matrix D -> 3 W, this order
+        q^, k^, v = silu(conv(q~)), silu(conv(k~)), silu(conv(v~))
+                                              depthwise, causal, ``conv_taps``
+                                              taps, zeros before the row, no bias
+        q, k = q^ / |q^|_2 * P^-1/2, k^ / |k^|_2      head by head, eps 1e-6
+        g = -exp(A_log) * softplus(W_gb (W_ga u) + dt_bias)   [H, P], float32
+        b = sigmoid(W_b u)                                    [H], float32
+        o = scan(q, k, v, g, b)               :func:`raydp_tpu.ops.kda_scan`
+        out = W_out (RMSNorm_P(o) * weight * sigmoid(W_zb (W_za u)))
+
+    The norm's weight ``[P]`` is one for all heads; the decay, ``b``, the L2
+    norms and the gated norm are float32 whatever ``dtype`` is. The state is
+    carried through the whole sequence (a packed row's documents are not told
+    apart, as attention attends across them). Each part lies under a scope
+    of its own so that a trace prices it: ``in_proj``, ``conv`` (the
+    convolution of :func:`raydp_tpu.ops.ssm_glue.conv_silu`, reading the
+    three widths out of ``W_in u`` by block index where its kernels run),
+    ``gate`` (the decay's two products, its softplus, ``b``, the L2 norms),
+    ``scan``, ``norm`` (the gated norm and the output gate's two products),
+    ``out_proj``. A recomputed pair (``remat_blocks``) keeps nothing of it.
+    Over a mesh heads stay whole on every device (``tensor`` replicates
+    them), and a ``seq`` axis raises."""
+
+    spec: KDASpec
+    dtype: Any = jnp.float32
+    rms_norm_eps: float = 1e-6
+    init_std: Optional[float] = None
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, u):
+        from raydp_tpu.ops.kda_scan import kda_scan
+        from raydp_tpu.parallel.mesh import seq_extent
+
+        if self.mesh is not None and seq_extent(self.mesh) > 1:
+            raise NotImplementedError(
+                "a delta-rule layer takes no seq axis: its state passes "
+                "from a position to the next")
+        s, f32 = self.spec, jnp.float32
+        b, t, dim = u.shape
+        heads, width = s.num_heads, s.head_dim
+        inner = heads * width
+        init = _init(self.init_std, nn.linear.default_kernel_init)
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name, kernel_init=init)
+        matrix = lambda name, *shape: self.param(  # noqa: E731
+            name, init, shape).astype(self.dtype)
+        proj = dense(3 * inner, "in_proj")(u)
+        taps = self.param("conv", init, (s.conv_taps, 3 * inner))
+        with jax.named_scope("conv"):
+            q, k, v = (a.reshape(b, t, heads, width)
+                       for a in ssm_glue.conv_silu_sharded(
+                           proj, taps, jnp.zeros((3 * inner,), taps.dtype),
+                           (inner,) * 3, self.mesh))
+        with jax.named_scope("gate"):
+            a_log = self.param("A_log", lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
+                (heads,))
+            dt_bias = self.param("dt_bias", _dt_bias_init(s), (inner,))
+            step = jnp.dot(jnp.dot(u, matrix("gate_a", dim, s.gate_rank)),
+                           matrix("gate_b", s.gate_rank, inner),
+                           preferred_element_type=f32)
+            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+                step + dt_bias.astype(f32)).reshape(b, t, heads, width)
+            beta = jax.nn.sigmoid(jnp.dot(u, matrix("beta", dim, heads),
+                                          preferred_element_type=f32))
+            unit = lambda a: a.astype(f32) * jax.lax.rsqrt(  # noqa: E731
+                jnp.sum(jnp.square(a.astype(f32)), axis=-1, keepdims=True)
+                + 1e-6)
+            q = (unit(q) * width ** -0.5).astype(self.dtype)
+            k = unit(k).astype(self.dtype)
+        with jax.named_scope("scan"):
+            o = kda_scan(q, k, v, g, beta, chunk=s.chunk)
+        with jax.named_scope("norm"):
+            z = jnp.dot(jnp.dot(u, matrix("out_gate_a", dim, s.gate_rank)),
+                        matrix("out_gate_b", s.gate_rank, inner))
+            weight = self.param("norm", nn.initializers.ones, (width,))
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + self.rms_norm_eps)
+            o = (o * weight.astype(f32) * jax.nn.sigmoid(
+                z.astype(f32)).reshape(o.shape)).astype(self.dtype).reshape(
+                    b, t, inner)
+        return dense(dim, "out_proj")(o)

@@ -304,6 +304,8 @@ def _make_apply(model, takes_train, split_batch, compute_dtype):
     apply_fn.loop_passes = getattr(model, "loop_passes", None) or {}
     # {"recomputed": n} or {"plain": n}: pairs with a convolution operator
     apply_fn.conv_layers = getattr(model, "conv_layers", None) or {}
+    # {"rescanned": n} or {"plain": n}: pairs with a delta-rule operator
+    apply_fn.kda_layers = getattr(model, "kda_layers", None) or {}
     if callable(getattr(model, "lookups", None)):
         apply_fn.lookups = lambda batch: model.lookups(split_batch(batch)[0])
     return apply_fn
@@ -532,7 +534,8 @@ def _make_train_step(apply_fn, loss_fn, metrics, accum: int, remat_mode: str,
             ("train_sublayer_out_total", "sublayer_out"),
             ("train_ssm_layers_total", "ssm_layers"),
             ("train_loop_passes_total", "loop_passes"),
-            ("train_conv_layers_total", "conv_layers")):
+            ("train_conv_layers_total", "conv_layers"),
+            ("train_kda_layers_total", "kda_layers")):
         for kind, layers in getattr(apply_fn, of_model, {}).items():
             if layers:      # once a built step, by kind of layer
                 rdt_metrics.inc(metric, layers, kind)

@@ -43,7 +43,8 @@ def files(config, tiny, **changed):
 def tokens(cfg, rows, seed=0, pipeline=None):
     """``rows`` seeded rows of the vocabulary rows held: uniform, or what
     ``pipeline`` generates for the configuration."""
-    length = cfg.get("seq_len", cfg["max_position_embeddings"])
+    length = cfg["seq_len"] if "seq_len" in cfg \
+        else cfg["max_position_embeddings"]
     if pipeline is not None:
         col = pipeline.generate(rows, seed, cfg)["tokens"].combine_chunks()
         return col.flatten().to_numpy().reshape(rows, length)

@@ -555,22 +555,30 @@ def test_a_model_without_streams_gets_no_key(monkeypatch):
 # means to change one of these programs replaces its line (PR 58 its one
 # line: a share's held rows come back to token order in runs; PR 62 too:
 # its one attention layer keeps its inputs).
+# Since PR 64 (a pair with a delta-rule operator, latent attention without
+# positions: both off by default) the convolution family's cut is held too,
+# with the hash it had on the commit before that PR (17a7a40).
 PARENT_STEP = {
     "nemotron-3-nano-30b-a3b":
         "9b91afeb9d12fc00b28cc06749aee99ca1b7fa8d56ff779cf4d86707faa440cf",
+    "lfm2-8b-a1b":
+        "ba865e986f9083cbcd3290636a0a25f21a201abf9fa0852079acf3702d04b74c",
 }
 
 
 @pytest.mark.parametrize("config,cell", [
-    ("nemotron-3-nano-30b-a3b", "nemotron3_nano_30ba3b_16k_train")])
+    ("nemotron-3-nano-30b-a3b", "nemotron3_nano_30ba3b_16k_train"),
+    ("lfm2-8b-a1b", "lfm2_8ba1b_8k_train")])
 def test_an_older_familys_step_is_the_parents_text(config, cell):
     """The new mask, the per-position weights and the step's key are off by
     default: a model without ``diffusion`` names no stream, its attention
     takes the mask it took, its head loss shifts the labels, and the lowered
-    step is the text it was."""
+    step is the text it was. So is no ``K`` layer."""
     model, text, _ = _step_text(config, cell)
     assert model.diffusion is None and model.rng_streams == ()
     assert "blockdiff" not in model.attention_layers
+    assert model.kda is None and not any(model.kda_layers.values())
+    assert "K" not in model.layer_kinds
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP[config]
 
 

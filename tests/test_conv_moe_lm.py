@@ -305,7 +305,7 @@ def test_a_model_of_b_and_c_letters_under_dense_layers():
     assert model.attention_layers == {"window": 0, "full": 2}
     assert model.sublayer_out == {"kept": 4, "rebuilt": 4}
     assert [model._sparse(i) for i in range(4)] == [0, 0, 1, 1]
-    with pytest.raises(ValueError, match="'B', 'C', 'M'"):
+    with pytest.raises(ValueError, match="'B', 'C', 'K', 'M'"):
         model.clone(layer_kinds="BCXB")._kind(0)
     with pytest.raises(ValueError, match="dense blocks alone"):
         jax.eval_shape(TransformerLM(

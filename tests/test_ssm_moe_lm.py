@@ -704,6 +704,7 @@ def test_an_older_familys_step_is_the_parents_text(config, cell):
     assert (model.layer_kinds, model.ssm, model.expert_gated) == (
         "", None, True)
     assert not any(model.ssm_layers.values())
+    assert model.kda is None and not any(model.kda_layers.values())
     block = params[f"block_{model.num_layers - 1}"]
     assert {"ln1", "attn", "ln2", "moe"} <= set(block)
     assert "experts_gate" in block["moe"] and "norm" not in block
