@@ -455,17 +455,20 @@ _ALL_METRICS = [
        label="scan"),
     _m("kda_scan_total", COUNTER, "1", "training",
        "Scans of a Kimi Delta Attention layer (a delta rule with a decay a "
-       "key channel, in chunks), counted once a built layer call by the path "
-       "it takes: `jnp` (the chunked `jax.numpy` form with XLA's triangular "
-       "solve: the one path today, on every platform; a `kernel` label is "
-       "what a later kernel pair would count under). ops/kda_scan.py.",
+       "key channel, in chunks), counted once a built layer call by what the "
+       "call holds: `kernel` (the shapes are ones `rdt_kda_fwd|bwd` take: in "
+       "a program lowered for a TPU the kernels run) and `jnp` (the chunked "
+       "`jax.numpy` form with XLA's triangular solve is traced into the "
+       "program: alone where `kda_scan.kernel_ineligible` names a reason, and "
+       "beside `kernel` as the branch every platform but a TPU runs; only an "
+       "interpreted call holds the kernels alone). ops/kda_scan.py.",
        label="path"),
     _m("kda_chunks_total", COUNTER, "1", "training",
        "Chunks a Kimi Delta Attention scan walks, counted where a pass is "
        "built (sequences x heads x chunks), by pass: `forward` (once a built "
        "forward scan, a recomputed layer's second one included) or "
-       "`backward` (the chunked form formed again and transposed). "
-       "ops/kda_scan.py.",
+       "`backward` (the chunks formed again and transposed), once a pass "
+       "whichever path runs it. ops/kda_scan.py.",
        label="pass"),
     _m("flash_backward_total", COUNTER, "1", "training",
        "Backward passes of the flash-attention kernels, counted where one "
@@ -848,9 +851,10 @@ _ALL_SPANS = [
        "softplus, times `-exp(A_log)`; float32), `beta` (a projection and a "
        "sigmoid) and the L2 norms of q and k a head.", kind=SCOPE),
     _s("kda/scan", "model",
-       "a delta-rule operator's chunked scan (`ops/kda_scan.py`): the "
-       "decays' running sums in a chunk, the two score matrices, the "
-       "unit-triangular solve, and the walk over the chunks with the state "
+       "a delta-rule operator's chunked scan (`ops/kda_scan.py`; on a TPU "
+       "the kernels `rdt_kda_fwd` and `rdt_kda_bwd`, else the `jax.numpy` "
+       "form): the decays' running sums in a chunk, the two score matrices, "
+       "the unit-triangular solve, and the walk over the chunks with the state "
        "carried.", kind=SCOPE),
     _s("kda/norm", "model",
        "a delta-rule operator's output stage: the RMSNorm over a head's "

@@ -1741,7 +1741,7 @@ class KimiDeltaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from raydp_tpu.ops.kda_scan import kda_scan
+        from raydp_tpu.ops.kda_scan import kda_scan_sharded
         from raydp_tpu.parallel.mesh import seq_extent
 
         if self.mesh is not None and seq_extent(self.mesh) > 1:
@@ -1782,7 +1782,7 @@ class KimiDeltaAttention(nn.Module):
             q = (unit(q) * width ** -0.5).astype(self.dtype)
             k = unit(k).astype(self.dtype)
         with jax.named_scope("scan"):
-            o = kda_scan(q, k, v, g, beta, chunk=s.chunk)
+            o = kda_scan_sharded(q, k, v, g, beta, self.mesh, chunk=s.chunk)
         with jax.named_scope("norm"):
             z = jnp.dot(jnp.dot(u, matrix("out_gate_a", dim, s.gate_rank)),
                         matrix("out_gate_b", s.gate_rank, inner))
